@@ -214,12 +214,9 @@ def default_resolvers(
             # one JAX process per host: the node-local rank is always 0
             return 0
         if var_name in ("RANK", "WORLD_SIZE"):
-            try:
-                import jax
+            import jax
 
-                return jax.process_index() if var_name == "RANK" else jax.process_count()
-            except Exception:
-                return 0 if var_name == "RANK" else 1
+            return jax.process_index() if var_name == "RANK" else jax.process_count()
         return os.getenv(var_name)
 
     env_kwargs: dict[str, Any] = {}

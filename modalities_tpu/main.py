@@ -67,6 +67,30 @@ class Main:
     def build_components(self, components_model_type: Type = TrainingComponentsInstantiationModel):
         return self.component_factory.build_components(self.config_dict, components_model_type)
 
+    @staticmethod
+    def build_step_functions(
+        components, expose_grads: bool = False, stop_consensus: bool = False, materialize: bool = True
+    ):
+        """The one TrainStepBuilder assembled from the declarative components:
+        what `run` trains with, what `validate_recipe` lowers with an abstract
+        state (`materialize=False`), and what `chip_smoke.py` times."""
+        app_state_spec = components.app_state
+        clipper = components.gradient_clipper
+        resilience = getattr(components, "resilience", None)
+        return TrainStepBuilder(
+            model=app_state_spec.model,
+            loss_fn=components.loss_fn,
+            optimizer_spec=app_state_spec.optimizer,
+            scheduler_spec=app_state_spec.lr_scheduler,
+            mesh_handle=components.device_mesh,
+            gradient_acc_steps=components.settings.step_profile.gradient_accumulation_steps,
+            grad_clip_norm=getattr(clipper, "max_norm", None),
+            grad_clipper=clipper if hasattr(clipper, "build_transform") else None,
+            expose_grads=expose_grads,
+            anomaly_policy=resilience.anomaly_policy if resilience is not None else None,
+            stop_consensus=stop_consensus,
+        ).build(materialize=materialize)
+
     def run(self, components: TrainingComponentsInstantiationModel) -> None:
         # telemetry is on by default: use the configured component when present,
         # otherwise a default instance. The sink/artifact folder rides with the
@@ -119,7 +143,6 @@ class Main:
                 yaml.safe_dump(_to_plain(self.config_dict), f, sort_keys=False)
 
         app_state_spec = components.app_state
-        clipper = components.gradient_clipper
         step_profile = settings.step_profile
         resilience = getattr(components, "resilience", None)
 
@@ -183,20 +206,11 @@ class Main:
                 )
 
         with telemetry.span("init"):
-            builder = TrainStepBuilder(
-                model=app_state_spec.model,
-                loss_fn=components.loss_fn,
-                optimizer_spec=app_state_spec.optimizer,
-                scheduler_spec=app_state_spec.lr_scheduler,
-                mesh_handle=components.device_mesh,
-                gradient_acc_steps=step_profile.gradient_accumulation_steps,
-                grad_clip_norm=getattr(clipper, "max_norm", None),
-                grad_clipper=clipper if hasattr(clipper, "build_transform") else None,
+            step_functions = self.build_step_functions(
+                components,
                 expose_grads=debug_stats_logger is not None,
-                anomaly_policy=resilience.anomaly_policy if resilience is not None else None,
                 stop_consensus=consensus_enabled,
             )
-            step_functions = builder.build()
 
             if app_state_spec.checkpoint_dir_path is not None:
                 with telemetry.span("checkpoint_restore"):

@@ -18,11 +18,6 @@ from typing import Optional, Tuple
 import jax.numpy as jnp
 
 from modalities_tpu.ops.tiers import KernelTier, on_tpu, resolve_tier
-from modalities_tpu.utils.logging import get_logger
-
-logger = get_logger(__name__)
-
-_warned = False
 
 DEFAULT_BLOCK_ROWS = 256
 DEFAULT_BLOCK_VOCAB = 512
@@ -60,40 +55,29 @@ def fused_ce_sum_and_count(hidden, head_weight, labels, *, ignore_index: int = -
     """(total_loss, token_count) over hidden @ head_weight.T without the logits
     buffer. Drop-in for `loss_fn.sum_and_count(head_logits(...), labels)`.
 
-    On TPU, a trace-time Pallas failure falls back (with a one-time warning) to
-    the dense reference — correctness over memory, mirroring attention's SDPA
-    fallback. In interpret mode (tests) nothing is caught: a kernel bug must
-    fail the test, not silently pass via the fallback."""
-    global _warned
+    Whatever the kernel raises is raised, on a TPU as in interpret mode (tests):
+    there is no dense tier behind it. Under a mesh the kernel runs per shard of
+    the rows with the head gathered whole, and the two sums are added up over the
+    axes the rows were split on (parallel/sharding.per_shard)."""
+    import jax
     import numpy as np
+
+    from modalities_tpu.ops.pallas.fused_ce import fused_ce_sum_and_count as pallas_fused_ce
+    from modalities_tpu.parallel.sharding import per_shard
 
     rows = int(np.prod(hidden.shape[:-1])) if hidden.ndim > 1 else hidden.shape[0]
     block_rows, block_vocab = resolve_ce_blocks(rows, head_weight.shape[0], hidden.shape[-1], hidden.dtype)
+    interpret = interpret or not on_tpu()
 
-    from modalities_tpu.ops.pallas.fused_ce import fused_ce_sum_and_count as pallas_fused_ce
-
-    if interpret or not on_tpu():
-        return pallas_fused_ce(
+    def kernel(axes, hidden, head_weight, labels):
+        sums = pallas_fused_ce(
             hidden, head_weight, labels,
-            ignore_index=ignore_index, block_rows=block_rows, block_vocab=block_vocab, interpret=True,
+            ignore_index=ignore_index, block_rows=block_rows, block_vocab=block_vocab, interpret=interpret,
         )
-    try:
-        return pallas_fused_ce(
-            hidden, head_weight, labels,
-            ignore_index=ignore_index, block_rows=block_rows, block_vocab=block_vocab, interpret=False,
-        )
-    except Exception as e:  # pragma: no cover - TPU only
-        if not _warned:
-            logger.warning("Pallas fused CE unavailable (%s); using dense logits fallback.", e)
-            _warned = True
-        return _dense_sum_and_count(hidden, head_weight, labels, ignore_index)
+        return jax.lax.psum(sums, axes) if axes else sums
 
-
-def _dense_sum_and_count(hidden, head_weight, labels, ignore_index):
-    import optax
-
-    logits = jnp.einsum("...e,ve->...v", hidden.astype(jnp.float32), head_weight.astype(jnp.float32))
-    mask = (labels != ignore_index).astype(jnp.float32)
-    safe = jnp.where(labels != ignore_index, labels, 0)
-    token_losses = optax.softmax_cross_entropy_with_integer_labels(logits, safe)
-    return (token_losses * mask).sum(), mask.sum()
+    # [B, S] rows split over batch and sequence; any other layout stays whole
+    row_axes = ("batch", "seq_sp") if labels.ndim == 2 else (None,) * labels.ndim
+    return per_shard(kernel, (row_axes + (None,), (None, None), row_axes), ((), ()))(
+        hidden, head_weight, labels
+    )

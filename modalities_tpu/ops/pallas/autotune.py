@@ -67,12 +67,9 @@ def device_kind_slug(device_kind: Optional[str] = None) -> str:
     file stem. Unknown kinds get a sanitized slug so operator sweeps on new
     hardware still round-trip to a loadable file name."""
     if device_kind is None:
-        try:
-            import jax
+        import jax
 
-            device_kind = jax.devices()[0].device_kind
-        except Exception:
-            device_kind = "cpu"
+        device_kind = jax.devices()[0].device_kind
     lowered = device_kind.lower()
     for marker, slug in _DEVICE_SLUGS:
         if marker in lowered:

@@ -252,6 +252,7 @@ def _flash_fwd(q, k, v, sm_scale, causal, block_q, block_k, interpret):
             pltpu.VMEM((block_q, 1), jnp.float32),
         ],
         interpret=interpret,
+        name="flash_attention_fwd",
     )(q, k, v)
     return out, (q, k, v, out, lse)
 
@@ -298,6 +299,7 @@ def flash_bwd_dq(q, k, v, do, lse, delta, *, causal, sm_scale, block_q, block_k,
         out_shape=jax.ShapeDtypeStruct(q.shape, q.dtype),
         scratch_shapes=[pltpu.VMEM((block_q, head_dim), jnp.float32)],
         interpret=interpret,
+        name="flash_attention_bwd_dq",
     )(q, k, v, do, lse, delta)
 
 def flash_bwd_dkv(q, k, v, do, lse, delta, *, causal, sm_scale, block_q, block_k, interpret):
@@ -335,6 +337,7 @@ def flash_bwd_dkv(q, k, v, do, lse, delta, *, causal, sm_scale, block_q, block_k
             pltpu.VMEM((block_k, head_dim), jnp.float32),
         ],
         interpret=interpret,
+        name="flash_attention_bwd_dkv",
     )(q, k, v, do, lse, delta)
 
     if group > 1:

@@ -53,6 +53,35 @@ def _vocab_block(v: int, preferred: int) -> int:
     return max(128, min(preferred, _pow2_ceil(v)))
 
 
+# Mosaic gives a kernel 16 MiB of scoped VMEM on a v5e, the smallest of the
+# supported chips. The estimate below ran 0.1-0.25 MiB under what the compiler
+# reported for bf16 at E 2560 and 4096, hence the margin.
+_VMEM_BUDGET_BYTES = 15 * 2**20
+
+
+def _fit_blocks_to_vmem(block_n: int, block_v: int, e: int, itemsize: int) -> tuple[int, int]:
+    """Halve the larger block until both backward kernels fit scoped VMEM.
+
+    Each backward kernel holds, for the side it accumulates over (vocab tiles
+    for d_head_weight, row tiles for d_hidden), a double-buffered input block,
+    a double-buffered output block and an fp32 accumulator; for the side it
+    streams, a double-buffered input block; plus one fp32 score tile. At
+    256x512 that is 18 MiB for bf16 at E 2560 — the shipped blocks were sized
+    at E 1536, where it is 11."""
+
+    def need(acc_rows: int, stream_rows: int) -> int:
+        return e * (acc_rows * (4 * itemsize + 4) + stream_rows * 2 * itemsize) + 4 * block_n * block_v
+
+    while max(need(block_v, block_n), need(block_n, block_v)) > _VMEM_BUDGET_BYTES:
+        if block_v >= block_n and block_v > 128:
+            block_v //= 2
+        elif block_n > 8:
+            block_n //= 2
+        else:
+            break
+    return block_n, block_v
+
+
 # ------------------------------------------------------------------ forward
 
 
@@ -116,6 +145,7 @@ def _ce_forward(h, w, labels2, block_n, block_v, vocab, interpret):
             pltpu.VMEM((block_n, 1), jnp.float32),
         ],
         interpret=interpret,
+        name="fused_ce_fwd",
     )(h, w, labels2)
 
 
@@ -191,6 +221,7 @@ def _ce_backward(h, w, labels2, lse, gm, block_n, block_v, vocab, interpret):
         out_shape=jax.ShapeDtypeStruct((n, e), h.dtype),
         scratch_shapes=[pltpu.VMEM((block_n, e), jnp.float32)],
         interpret=interpret,
+        name="fused_ce_bwd_dh",
     )(h, w, labels2, lse, gm)
     dw = pl.pallas_call(
         functools.partial(_bwd_dw_kernel, block_v=block_v, vocab=vocab),
@@ -206,6 +237,7 @@ def _ce_backward(h, w, labels2, lse, gm, block_n, block_v, vocab, interpret):
         out_shape=jax.ShapeDtypeStruct((v_padded, e), w.dtype),
         scratch_shapes=[pltpu.VMEM((block_v, e), jnp.float32)],
         interpret=interpret,
+        name="fused_ce_bwd_dw",
     )(h, w, labels2, lse, gm)
     return dh, dw
 
@@ -267,8 +299,9 @@ def fused_ce_sum_and_count(
     h2 = hidden.reshape(n, e)
     lab2 = labels.reshape(n, 1).astype(jnp.int32)
 
-    bn = _row_block(n, block_rows)
-    bv = _vocab_block(v, block_vocab)
+    bn, bv = _fit_blocks_to_vmem(
+        _row_block(n, block_rows), _vocab_block(v, block_vocab), e, jnp.dtype(hidden.dtype).itemsize
+    )
     n_pad = -n % bn
     v_pad = -v % bv
     if n_pad:

@@ -10,8 +10,11 @@ Backward math (g = dy * scale, x_hat = x * r):
     dx     = r * (g - x_hat * mean(g * x_hat, axis=-1))
     dscale = sum_rows dy * x_hat
     dbias  = sum_rows dy
-dscale/dbias are emitted as per-row-block partials `[n_blocks, E]` (each grid
+dscale/dbias are emitted as per-row-block partials `[n_blocks, 1, E]` (each grid
 step owns one output row — no cross-step races) and summed outside the kernel.
+The singleton middle dim is there for Mosaic: a block's last two dims must tile
+(8, 128) or equal the array's, and a `(1, E)` block of an `[n_blocks, E]` array
+does neither.
 
 `interpret=True` runs the same kernel under the Pallas CPU emulator for exact
 tier-1 parity tests, mirroring flash_attention.py / fused_ce.py.
@@ -49,8 +52,8 @@ def _bwd_kernel(x_ref, s_ref, r_ref, dy_ref, dx_ref, dsp_ref, dbp_ref):
     g = dy * scale
     dx = r * (g - x_hat * (g * x_hat).mean(axis=-1, keepdims=True))
     dx_ref[...] = dx.astype(dx_ref.dtype)
-    dsp_ref[...] = (dy * x_hat).sum(axis=0, keepdims=True)
-    dbp_ref[...] = dy.sum(axis=0, keepdims=True)
+    dsp_ref[0] = (dy * x_hat).sum(axis=0, keepdims=True)
+    dbp_ref[0] = dy.sum(axis=0, keepdims=True)
 
 
 @functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4, 5))
@@ -78,6 +81,7 @@ def _fused_rms_fwd(x2, scale2, bias2, eps, block_n, interpret):
             jax.ShapeDtypeStruct((n, 1), jnp.float32),
         ],
         interpret=interpret,
+        name="fused_rmsnorm_fwd",
     )(x2, scale2, bias2)
     return y, (x2, scale2, bias2, r)
 
@@ -97,18 +101,19 @@ def _fused_rms_bwd(eps, block_n, interpret, residuals, dy):
         ],
         out_specs=[
             pl.BlockSpec((block_n, e), lambda i: (i, 0)),
-            pl.BlockSpec((1, e), lambda i: (i, 0)),
-            pl.BlockSpec((1, e), lambda i: (i, 0)),
+            pl.BlockSpec((1, 1, e), lambda i: (i, 0, 0)),
+            pl.BlockSpec((1, 1, e), lambda i: (i, 0, 0)),
         ],
         out_shape=[
             jax.ShapeDtypeStruct((n, e), x2.dtype),
-            jax.ShapeDtypeStruct((n_blocks, e), jnp.float32),
-            jax.ShapeDtypeStruct((n_blocks, e), jnp.float32),
+            jax.ShapeDtypeStruct((n_blocks, 1, e), jnp.float32),
+            jax.ShapeDtypeStruct((n_blocks, 1, e), jnp.float32),
         ],
         interpret=interpret,
+        name="fused_rmsnorm_bwd",
     )(x2, scale2, r, dy)
-    dscale = dscale_partial.sum(axis=0, keepdims=True).astype(scale2.dtype)
-    dbias = dbias_partial.sum(axis=0, keepdims=True).astype(bias2.dtype)
+    dscale = dscale_partial.sum(axis=0).astype(scale2.dtype)
+    dbias = dbias_partial.sum(axis=0).astype(bias2.dtype)
     return dx, dscale, dbias
 
 

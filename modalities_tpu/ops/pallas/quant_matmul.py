@@ -81,6 +81,7 @@ def quant_matmul(
         out_specs=pl.BlockSpec((bm, bn), lambda i, j: (i, j)),
         out_shape=jax.ShapeDtypeStruct((mp, np_), x.dtype),
         interpret=interpret,
+        name="quant_matmul",
     )(x, wq, scale.reshape(1, np_))
     if m_pad or n_pad:
         y = y[:m, :n]

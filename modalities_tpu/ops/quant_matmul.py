@@ -23,11 +23,6 @@ from modalities_tpu.ops.pallas.quant_matmul import (
     reference_quant_matmul,
 )
 from modalities_tpu.ops.tiers import KernelTier, on_tpu, resolve_tier
-from modalities_tpu.utils.logging import get_logger
-
-logger = get_logger(__name__)
-
-_warned = False
 
 
 def quant_matmul_tier(spec_setting=None) -> KernelTier:
@@ -57,21 +52,14 @@ def resolve_quant_matmul_blocks(m: int, dtype) -> tuple[int, int]:
 def quant_matmul_or_fallback(x, wq, scale, *, tier: KernelTier | None = None, interpret: bool = False):
     """`(x [M,K] @ wq [K,N] quantized) * scale [N]` through the tier ladder.
 
-    In interpret mode (tests) kernel exceptions propagate — a kernel bug must
-    fail the parity test, not vanish into the fallback."""
-    global _warned
+    Whatever the kernel raises is raised, on a TPU as in interpret mode (tests):
+    the jnp dequant expression is the `off` tier, not a net under the kernel."""
     if tier is None:
         tier = quant_matmul_tier()
     if not tier.enabled and not interpret:
         return reference_quant_matmul(x, wq, scale)
     block_m, block_n = resolve_quant_matmul_blocks(x.shape[0], x.dtype)
-
-    if interpret or tier.interpret or not on_tpu():
-        return quant_matmul(x, wq, scale, block_m=block_m, block_n=block_n, interpret=True)
-    try:
-        return quant_matmul(x, wq, scale, block_m=block_m, block_n=block_n, interpret=False)
-    except Exception as e:  # pragma: no cover - TPU only
-        if not _warned:
-            logger.warning("Pallas quant matmul unavailable (%s); using jnp dequant fallback.", e)
-            _warned = True
-        return reference_quant_matmul(x, wq, scale)
+    return quant_matmul(
+        x, wq, scale, block_m=block_m, block_n=block_n,
+        interpret=interpret or tier.interpret or not on_tpu(),
+    )

@@ -10,17 +10,13 @@ Block size: `MODALITIES_TPU_RMSNORM_BLOCK_ROWS` > autotune table > 256.
 
 from __future__ import annotations
 
+import functools
 import os
 
 import jax
 import jax.numpy as jnp
 
 from modalities_tpu.ops.tiers import KernelTier, on_tpu, resolve_tier
-from modalities_tpu.utils.logging import get_logger
-
-logger = get_logger(__name__)
-
-_warned = False
 
 DEFAULT_BLOCK_ROWS = 256
 
@@ -41,25 +37,33 @@ def resolve_rmsnorm_block_rows(n_embd: int, dtype) -> int:
     return DEFAULT_BLOCK_ROWS
 
 
+# how the rows of a norm's input lie on the mesh, by rank: the residual stream
+# [B, S, E] (split over the sequence too, as sequence parallelism does) and the
+# per-head q/k of QK-norm [B, S, H, D]; anything else is split over its batch only
+_ROW_AXES = {3: ("batch", "seq_sp", None), 4: ("batch", "seq", "heads", None)}
+
+
 def rms_norm_or_fallback(x, scale=None, bias=None, *, eps: float = 1e-6, interpret: bool = False):
-    """Single-HBM-round-trip RMSNorm with the reference as the fallback tier.
-
-    In interpret mode (tests) exceptions propagate — a kernel bug must fail the
-    parity test, not vanish into the fallback."""
-    global _warned
-    block_rows = resolve_rmsnorm_block_rows(x.shape[-1], x.dtype)
-
+    """Single-HBM-round-trip RMSNorm. Whatever the kernel raises is raised, on a
+    TPU as in interpret mode (tests): there is no reference tier behind it. Under
+    a mesh the kernel runs per shard of the rows (parallel/sharding.per_shard)."""
     from modalities_tpu.ops.pallas.fused_rmsnorm import fused_rms_norm
+    from modalities_tpu.parallel.sharding import per_shard
 
-    if interpret or not on_tpu():
-        return fused_rms_norm(x, scale, bias, eps=eps, block_rows=block_rows, interpret=True)
-    try:
-        return fused_rms_norm(x, scale, bias, eps=eps, block_rows=block_rows, interpret=False)
-    except Exception as e:  # pragma: no cover - TPU only
-        if not _warned:
-            logger.warning("Pallas fused RMSNorm unavailable (%s); using reference ops.", e)
-            _warned = True
-        return reference_rms_norm(x, scale, bias, eps=eps)
+    kernel = functools.partial(
+        fused_rms_norm,
+        eps=eps,
+        block_rows=resolve_rmsnorm_block_rows(x.shape[-1], x.dtype),
+        interpret=interpret or not on_tpu(),
+    )
+    # the identity params the kernel would make for itself, made here so that
+    # every shard is handed the same three operands
+    scale = jnp.ones((x.shape[-1],), jnp.float32) if scale is None else scale
+    bias = jnp.zeros((x.shape[-1],), jnp.float32) if bias is None else bias
+    x_axes = _ROW_AXES.get(x.ndim, ("batch",) + (None,) * (x.ndim - 1))
+    return per_shard(
+        lambda _axes, x, scale, bias: kernel(x, scale, bias), (x_axes, (None,), (None,)), x_axes
+    )(x, scale, bias)
 
 
 def reference_rms_norm(x, scale=None, bias=None, *, eps: float = 1e-6):

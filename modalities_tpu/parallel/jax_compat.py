@@ -1,73 +1,28 @@
-"""Compatibility shims over JAX API skew.
+"""The two `jax.shard_map` conventions the parallelism layer shares.
 
-The parallelism layer is written against the consolidated `jax.shard_map` /
-`jax.sharding.get_abstract_mesh()` surface; older runtimes (<= 0.4.x) ship
-shard_map under `jax.experimental.shard_map` with the inverted `auto=` manual-axes
-convention (`check_rep` instead of `check_vma`) and have no ambient abstract-mesh
-query at all. These two helpers keep every call site identical across both:
-
-- `shard_map(...)`: the new keyword surface (`axis_names` = MANUAL axes,
-  `check_vma`); lowered to `auto = mesh.axis_names - axis_names` / `check_rep`
-  on runtimes without `jax.shard_map`.
+- `shard_map(...)`: `jax.shard_map` with `axis_names` = the MANUAL axes and
+  replication checking off by default (the bodies here call Pallas kernels and
+  hand-written collectives, whose outputs carry no varying-axes type).
 - `manual_axes()`: the axis names bound manually by an enclosing shard_map region
-  at trace time — `get_abstract_mesh().manual_axes` when available, else the
-  trace-time axis environment (inside a legacy shard_map body the manual axes are
-  exactly the bound named axes).
+  at trace time.
 """
 
 from __future__ import annotations
 
 import jax
 
-# True when the runtime can compile shard_map programs that leave some mesh axes
-# auto (the consolidated `jax.shard_map` surface). Legacy runtimes hard-abort in
-# the SPMD partitioner on such programs, so the shim below refuses them at trace
-# time; tests that inherently need a partial-auto mesh skip on this flag.
-PARTIAL_AUTO_SUPPORTED: bool = hasattr(jax, "shard_map")
-
 
 def manual_axes() -> tuple:
     """Axis names bound manually by an enclosing shard_map region (trace time)."""
-    get_am = getattr(jax.sharding, "get_abstract_mesh", None)
-    if get_am is not None:
-        ambient = get_am()
-        return tuple(getattr(ambient, "manual_axes", ()) or ())
-    from jax._src import core
-
-    return tuple(core.get_axis_env().axis_sizes)
+    return tuple(jax.sharding.get_abstract_mesh().manual_axes)
 
 
 def shard_map(f, *, mesh, in_specs, out_specs, axis_names=frozenset(), check_vma=False):
-    """`jax.shard_map` keyword surface on both new and legacy runtimes."""
-    if hasattr(jax, "shard_map"):
-        return jax.shard_map(
-            f,
-            mesh=mesh,
-            in_specs=in_specs,
-            out_specs=out_specs,
-            axis_names=frozenset(axis_names),
-            check_vma=check_vma,
-        )
-    from jax.experimental.shard_map import shard_map as _shard_map
-
-    auto = frozenset(mesh.axis_names) - frozenset(axis_names)
-    nontrivial_auto = {a for a in auto if mesh.shape[a] > 1}
-    if nontrivial_auto:
-        # The legacy partitioner cannot compile partial-auto programs: at best it
-        # raises UNIMPLEMENTED (PartitionId under SPMD), at worst it hard-aborts
-        # the process (spmd_partitioner.cc IsManualSubgroup check). Refuse at
-        # trace time with a Python error instead of letting XLA crash the host.
-        raise NotImplementedError(
-            f"partial-auto shard_map (manual axes {sorted(axis_names)} with "
-            f"non-trivial auto axes {sorted(nontrivial_auto)}) is not supported "
-            f"on jax {jax.__version__} without jax.shard_map; use a fully-manual "
-            "mesh (auto axes of size 1) or a newer jax runtime"
-        )
-    return _shard_map(
+    return jax.shard_map(
         f,
         mesh=mesh,
         in_specs=in_specs,
         out_specs=out_specs,
-        auto=auto,
-        check_rep=check_vma,
+        axis_names=frozenset(axis_names),
+        check_vma=check_vma,
     )

@@ -327,10 +327,7 @@ _platform_is_tpu: bool | None = None
 def _probe_tpu_platform() -> bool:
     global _platform_is_tpu
     if _platform_is_tpu is None:
-        try:
-            _platform_is_tpu = jax.devices()[0].platform == "tpu"
-        except Exception:
-            _platform_is_tpu = False
+        _platform_is_tpu = jax.devices()[0].platform == "tpu"
     return _platform_is_tpu
 
 

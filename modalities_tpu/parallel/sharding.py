@@ -168,6 +168,70 @@ def constrain_activation(x, logical_axes, explicit: bool = False):
         return x
 
 
+def per_shard(fn, in_logical, out_logical):
+    """`fn(axes, *arrays)` run once per shard of the ambient mesh.
+
+    GSPMD cannot partition a Mosaic kernel: on a TPU, a `pallas_call` traced under
+    a multi-device `jit` is refused at lowering ("Mosaic kernels cannot be
+    automatically partitioned. Please wrap the call in a shard_map"), and inside a
+    `shard_map` every mesh axis has to be manual. The kernels on the train path are
+    independent per row or per (batch, head), so each dispatcher names its
+    operands' dims with logical axes and this runs the kernel under a fully manual
+    `shard_map` over the mesh the step installed with `activation_rules`. `axes`
+    are the mesh axes the operands ended up split over, for a wrapper that has to
+    `psum` a partial result; an operand with no axis on a mesh axis is gathered.
+
+    A mesh axis that does not divide every dim it would split is left out of the
+    call, and the kernel runs replicated over it: q and kv heads must split
+    together or not at all, and a decode step's one-token sequence cannot split.
+    With no rules installed, one device, or an enclosing manual region (pp, cp),
+    `fn` runs as it is.
+    """
+
+    def call(*arrays):
+        state = getattr(_ACTIVATION_RULES, "state", None)
+        from modalities_tpu.parallel.jax_compat import manual_axes, shard_map
+
+        if not state or state[1].size == 1 or manual_axes():
+            return fn((), *arrays)
+        rules, mesh = state
+
+        def names(entry) -> tuple[str, ...]:
+            return () if entry is None else entry if isinstance(entry, tuple) else (entry,)
+
+        in_specs = [logical_to_mesh_spec(tuple(axes), rules) for axes in in_logical]
+        unusable = {
+            name
+            for spec, array in zip(in_specs, arrays)
+            for dim, entry in zip(array.shape, spec)
+            if dim % int(np.prod([mesh.shape[n] for n in names(entry)], dtype=np.int64))
+            for name in names(entry)
+        }
+
+        def keep(spec: P) -> P:
+            kept = [tuple(n for n in names(entry) if n not in unusable) for entry in spec]
+            return P(*(k[0] if len(k) == 1 else (k or None) for k in kept))
+
+        in_specs = tuple(keep(spec) for spec in in_specs)
+        split_over = tuple(
+            n for n in mesh.axis_names if any(n in names(e) for spec in in_specs for e in spec)
+        )
+        out_specs = jax.tree.map(
+            lambda axes: keep(logical_to_mesh_spec(tuple(axes), rules)),
+            out_logical,
+            is_leaf=lambda x: isinstance(x, tuple) and all(a is None or isinstance(a, str) for a in x),
+        )
+        return shard_map(
+            lambda *local: fn(split_over, *local),
+            mesh=mesh,
+            in_specs=in_specs,
+            out_specs=out_specs,
+            axis_names=frozenset(mesh.axis_names),
+        )(*arrays)
+
+    return call
+
+
 # ------------------------------------------------------------ ZeRO optimizer state
 # Cross-replica sharding of the weight update (arXiv 2004.13336, ZeRO-1 semantics):
 # every dp_replicate replica holding a full copy of the Adam moments is pure waste —

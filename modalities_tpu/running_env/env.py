@@ -11,21 +11,37 @@ from __future__ import annotations
 
 import os
 import traceback
+from pathlib import Path
 from typing import Optional
 
 from modalities_tpu.utils.logging import get_logger
 
 logger = get_logger(__name__)
 
+# The path is part of a cache entry's key, so a directory that moves with the user,
+# the process or the clock never hits: one fixed place inside the checkout.
+COMPILATION_CACHE_DIR = Path(__file__).resolve().parents[2] / ".jax_compilation_cache"
+
+
+def configure_compilation_cache() -> str:
+    """Where this process keeps JAX's persistent compilation cache — the one seam
+    `run`, `warmstart`, `serve` and `chip_smoke.py` all pass. Where
+    ``JAX_COMPILATION_CACHE_DIR`` is set, JAX's own setting stands and nothing is
+    set here; otherwise ``COMPILATION_CACHE_DIR``."""
+    from_env = os.environ.get("JAX_COMPILATION_CACHE_DIR")
+    if from_env:
+        return from_env
+    import jax
+
+    jax.config.update("jax_compilation_cache_dir", str(COMPILATION_CACHE_DIR))
+    return str(COMPILATION_CACHE_DIR)
+
 
 class TpuEnv:
-    """Context manager for the distributed runtime (CudaEnv equivalent).
-
-    Also enables JAX's persistent compilation cache (XLA first-compiles of a large
-    train step run 20-40 s+; restarts and warmstarts then reuse the compiled
-    program). Default cache dir ``~/.cache/modalities_tpu_xla``; override with
-    ``MODALITIES_TPU_COMPILATION_CACHE`` (empty string disables).
-    """
+    """Context manager for the distributed runtime (CudaEnv equivalent). Also
+    places the compilation cache (`configure_compilation_cache`): first compiles of
+    a large train step take a quarter of a minute and more, and restarts and
+    warmstarts then reuse the compiled program."""
 
     def __init__(self, process_group_backend: Optional[str] = None, timeout_s: int = 600):
         # backend arg accepted for config parity; collectives are XLA's
@@ -36,15 +52,7 @@ class TpuEnv:
     def __enter__(self) -> "TpuEnv":
         import jax
 
-        cache_dir = os.environ.get(
-            "MODALITIES_TPU_COMPILATION_CACHE",
-            os.path.join(os.path.expanduser("~"), ".cache", "modalities_tpu_xla"),
-        )
-        if cache_dir:
-            try:
-                jax.config.update("jax_compilation_cache_dir", cache_dir)
-            except Exception:  # older jaxlib without the knob: run uncached
-                logger.warning("persistent compilation cache unavailable; continuing without")
+        configure_compilation_cache()
 
         coordinator = os.environ.get("JAX_COORDINATOR_ADDRESS") or os.environ.get("COORDINATOR_ADDRESS")
         num_processes = os.environ.get("JAX_NUM_PROCESSES") or os.environ.get("NNODES")

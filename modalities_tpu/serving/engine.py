@@ -2723,6 +2723,12 @@ class ServingEngine:
         sharding acceptance test greps this for mesh annotations."""
         return self._decode_lowered().as_text()
 
+    def decode_compiled_text(self) -> str:
+        """Optimized HLO of the decode step as the backend compiled it — where a
+        Pallas kernel shows as a `tpu_custom_call`."""
+        with self._rules_ctx():
+            return self._decode_lowered().compile().as_text()
+
     def _decode_lowered(self):
         """The decode step's `jax.stages.Lowered` with the CURRENT arg shardings."""
         jnp = self._jnp

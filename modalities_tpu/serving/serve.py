@@ -466,8 +466,10 @@ def serve(
     `MODALITIES_TPU_SERVE_WATCHDOG_S` overrides the serve watchdog deadline
     (default 300 s; 0 disables)."""
     from modalities_tpu.resilience.preemption import PreemptionHandler
+    from modalities_tpu.running_env.env import configure_compilation_cache
     from modalities_tpu.telemetry import Telemetry, set_active_telemetry
 
+    configure_compilation_cache()
     telemetry = None
     prior_telemetry = None
     telemetry_dir = os.environ.get("MODALITIES_TPU_SERVE_TELEMETRY_DIR")

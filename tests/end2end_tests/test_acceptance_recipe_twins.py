@@ -243,11 +243,6 @@ def _twin_7b_warmstart(tmp_path, seen_tokens, steps=6, seq=256, mbs=1, dp=1, cp=
 @pytest.mark.slow  # ~14 s for a strict=False xfail (no tier-1 signal either
 # way); the e2e train chain stays pinned fast by test_main_end_to_end and the
 # recipe-twin seam by test_2p7b_dp_twin_trains_checkpoints_and_resumes (slow)
-@pytest.mark.xfail(
-    strict=False,
-    reason="jax 0.4.37: partial-auto shard_map (auto axes) unsupported — "
-    "parallel/jax_compat.py guard; see docs/known_failures.md",
-)
 def test_7b_tp_fsdp_twin_then_32k_warmstart_twin(workdir):
     """The production chain the recipes document: pretrain under the recipe-2 graph
     (tp x fsdp hybrid, loss-parallel vocab), then resume its checkpoint under the

@@ -31,11 +31,6 @@ def _run(config_path, experiment_id, workdir, resolver=None):  # noqa: F811
     return [json.loads(line) for line in results.read_text().splitlines()]
 
 
-@pytest.mark.xfail(
-    strict=False,
-    reason="jax 0.4.37: partial-auto shard_map (auto axes) unsupported — "
-    "parallel/jax_compat.py guard; see docs/known_failures.md",
-)
 def test_warmstart_pp_tp_to_dp_continues_training(workdir):  # noqa: F811
     # phase 1: 8 steps under pp2 x dp2 x tp2 with the scheduled 1F1B executor
     lines = _run(PP_TP_CONFIG, "phase1", workdir)

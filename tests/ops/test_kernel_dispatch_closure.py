@@ -2,11 +2,15 @@
 wrapper must be drivable in interpret mode, so CPU parity tests can always
 exercise the real kernel code path (never just the fallback tier).
 
-Pure AST/inspect — no tracing, runs in milliseconds."""
+Pure AST/inspect — no tracing, runs in milliseconds. Below it, the other half of
+the dispatch contract: nothing stands behind a kernel on a TPU, and no probe
+mistakes a dead backend for a CPU."""
 
 import ast
 import inspect
 from pathlib import Path
+
+import pytest
 
 import modalities_tpu.ops.pallas as pallas_pkg
 
@@ -73,3 +77,92 @@ def test_dispatch_wrappers_cover_every_kernel_module():
                 imported.add(mod)
     missing = kernel_modules - imported
     assert not missing, f"kernel modules with no dispatch-tier consumer under ops/: {missing}"
+
+
+# ------------------------------------------------------- no tier behind a kernel
+
+
+def _call_attention():
+    import jax.numpy as jnp
+
+    from modalities_tpu.ops.attention import flash_attention_or_fallback
+
+    q = jnp.ones((1, 16, 2, 8))
+    return flash_attention_or_fallback(q, q, q)
+
+
+def _call_fused_ce():
+    import jax.numpy as jnp
+
+    from modalities_tpu.ops.cross_entropy import fused_ce_sum_and_count
+
+    return fused_ce_sum_and_count(jnp.ones((8, 16)), jnp.ones((32, 16)), jnp.zeros((8,), jnp.int32))
+
+
+def _call_rmsnorm():
+    import jax.numpy as jnp
+
+    from modalities_tpu.ops.rmsnorm import rms_norm_or_fallback
+
+    return rms_norm_or_fallback(jnp.ones((8, 16)), jnp.ones((16,)))
+
+
+def _call_quant_matmul():
+    import jax.numpy as jnp
+
+    from modalities_tpu.ops.quant_matmul import quant_matmul_or_fallback
+    from modalities_tpu.ops.tiers import KernelTier
+
+    return quant_matmul_or_fallback(
+        jnp.ones((8, 16)), jnp.ones((16, 8), jnp.int8), jnp.ones((8,)),
+        tier=KernelTier(enabled=True, interpret=False),
+    )
+
+
+@pytest.mark.parametrize(
+    "module, call",
+    [
+        ("attention", _call_attention),
+        ("cross_entropy", _call_fused_ce),
+        ("rmsnorm", _call_rmsnorm),
+        ("quant_matmul", _call_quant_matmul),
+    ],
+)
+def test_dispatcher_raises_what_the_kernel_raises_on_a_tpu(module, call, monkeypatch, caplog):
+    """With the platform probe answering "TPU" on this CPU, the real kernel is
+    asked for a Mosaic lowering and refuses. The dispatcher hands that on: it used
+    to warn once and run the reference, which would hide a kernel the chip's
+    compiler rejects."""
+    import importlib
+
+    monkeypatch.setattr(importlib.import_module(f"modalities_tpu.ops.{module}"), "on_tpu", lambda: True)
+    with pytest.raises(ValueError, match="Only interpret mode is supported on CPU backend"):
+        call()
+    assert not [r for r in caplog.records if "unavailable" in r.getMessage()]
+
+
+@pytest.mark.parametrize(
+    "probe",
+    [
+        "modalities_tpu.ops.tiers:on_tpu",
+        "modalities_tpu.parallel.ring_attention:_probe_tpu_platform",
+        "modalities_tpu.ops.pallas.autotune:device_kind_slug",
+        "modalities_tpu.utils.mfu:get_peak_flops",
+    ],
+)
+def test_platform_probe_raises_when_the_backend_does(probe, monkeypatch):
+    """A backend that fails to initialise is an error, never "not a TPU"."""
+    import importlib
+
+    import jax
+
+    def no_backend(*args, **kwargs):
+        raise RuntimeError("Unable to initialize backend 'tpu'")
+
+    module_name, function = probe.split(":")
+    module = importlib.import_module(module_name)
+    monkeypatch.setattr(jax, "devices", no_backend)
+    if hasattr(module, "_platform_is_tpu"):
+        monkeypatch.setattr(module, "_platform_is_tpu", None)  # the probe's own memo
+    with pytest.raises(RuntimeError, match="Unable to initialize backend"):
+        getattr(module, function)()

@@ -8,16 +8,11 @@ import numpy as np
 import pytest
 
 from modalities_tpu.parallel import ring_attention as ra
-from modalities_tpu.parallel.jax_compat import PARTIAL_AUTO_SUPPORTED
 from modalities_tpu.running_env.device_mesh import get_device_mesh
 from tests.models.test_gpt2_model import tiny_gpt2
 from tests.training.test_train_step import _batch, _builder
 
 
-@pytest.mark.skipif(
-    not PARTIAL_AUTO_SUPPORTED,
-    reason="partial-auto shard_map (dp_shard=2 x cp=4) unsupported on this jax runtime",
-)
 def test_long_context_cp_step_uses_blocked_path(monkeypatch):
     # shrink the block threshold so the CP chunk attention takes the fused path at
     # test scale; the blocked-vs-dense unit tests pin its numerics at any block size

@@ -9,14 +9,6 @@ from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
 from modalities_tpu.models.gpt2.gpt2_model import manual_attention
 from modalities_tpu.parallel.ring_attention import ring_attention
-from modalities_tpu.parallel.jax_compat import PARTIAL_AUTO_SUPPORTED
-
-# the dp_shard=2 meshes leave dp auto while cp is manual — a partial-auto program
-# legacy jax runtimes cannot compile (jax_compat refuses at trace time)
-requires_partial_auto = pytest.mark.skipif(
-    not PARTIAL_AUTO_SUPPORTED,
-    reason="partial-auto shard_map unsupported on this jax runtime (see jax_compat)",
-)
 
 
 def _mesh(cp=4, dp=2):
@@ -33,7 +25,6 @@ def _rand(seed, b, s, hq, hkv, d):
 
 
 @pytest.mark.parametrize("hq,hkv", [(4, 4), (4, 2)])
-@requires_partial_auto
 def test_ring_attention_matches_oracle(hq, hkv):
     mesh = _mesh(cp=4, dp=2)
     q, k, v = _rand(0, 2, 32, hq, hkv, 16)
@@ -46,7 +37,6 @@ def test_ring_attention_matches_oracle(hq, hkv):
     np.testing.assert_allclose(np.asarray(got), np.asarray(expected), rtol=2e-5, atol=2e-5)
 
 
-@requires_partial_auto
 def test_ring_attention_non_causal():
     mesh = _mesh(cp=4, dp=2)
     q, k, v = _rand(1, 1, 16, 2, 2, 16)
@@ -57,7 +47,6 @@ def test_ring_attention_non_causal():
     np.testing.assert_allclose(np.asarray(got), np.asarray(expected), rtol=2e-5, atol=2e-5)
 
 
-@requires_partial_auto
 def test_ring_attention_gradients_match():
     mesh = _mesh(cp=4, dp=2)
     q, k, v = _rand(2, 1, 16, 2, 1, 8)
@@ -135,7 +124,6 @@ def flash_ring(monkeypatch):
 
 
 @pytest.mark.parametrize("hq,hkv", [(4, 4), (4, 2)])
-@requires_partial_auto
 def test_flash_ring_matches_oracle(flash_ring, hq, hkv):
     """Flash-hop ring (interpret mode) vs single-device oracle, causal + GQA."""
     mesh = _mesh(cp=4, dp=2)
@@ -147,7 +135,6 @@ def test_flash_ring_matches_oracle(flash_ring, hq, hkv):
     np.testing.assert_allclose(np.asarray(got), np.asarray(expected), rtol=2e-5, atol=2e-5)
 
 
-@requires_partial_auto
 def test_flash_ring_non_causal(flash_ring):
     mesh = _mesh(cp=4, dp=2)
     q, k, v = _rand(1, 1, 16, 2, 2, 16)
@@ -159,7 +146,6 @@ def test_flash_ring_non_causal(flash_ring):
 
 
 @pytest.mark.parametrize("hq,hkv", [(2, 1), (2, 2)])
-@requires_partial_auto
 def test_flash_ring_gradients_match_oracle(flash_ring, hq, hkv):
     """The custom_vjp ring backward (flash bwd kernels + rotating dk/dv accumulators)
     vs plain autodiff through the single-device oracle."""

@@ -10,28 +10,15 @@ from pathlib import Path
 
 import pytest
 
-from modalities_tpu.parallel.jax_compat import PARTIAL_AUTO_SUPPORTED
 from modalities_tpu.utils.recipe_validation import run_validation_subprocess
 
-# the 32k warmstart recipe's cp mesh axis makes its step a partial-auto shard_map
-# program, which legacy jax runtimes cannot compile (jax_compat refuses at trace time)
-requires_partial_auto = pytest.mark.skipif(
-    not PARTIAL_AUTO_SUPPORTED,
-    reason="partial-auto shard_map unsupported on this jax runtime (see jax_compat)",
-)
 
 CONFIGS_DIR = Path(__file__).parents[2] / "configs"
 
 RECIPES = [
     ("config_2p7b_dp.yaml", {"dp_shard": 64}, 2.6e9, 2.8e9),
     ("config_7b_tp_fsdp.yaml", {"dp_shard": 8, "tp": 8}, 7.3e9, 7.5e9),
-    pytest.param(
-        "config_7b_warmstart_32k.yaml", {"dp_shard": 2, "cp": 4, "tp": 8}, 7.3e9, 7.5e9,
-        marks=pytest.mark.skipif(
-            not PARTIAL_AUTO_SUPPORTED,
-            reason="partial-auto shard_map unsupported on this jax runtime (see jax_compat)",
-        ),
-    ),
+    ("config_7b_warmstart_32k.yaml", {"dp_shard": 2, "cp": 4, "tp": 8}, 7.3e9, 7.5e9),
 ]
 
 
@@ -69,7 +56,6 @@ def test_warmstart_recipe_full_remat_detected():
     assert report["per_device"]["activation_estimate"]["remat_mode"] == "full"
 
 
-@requires_partial_auto
 def test_compile_memory_check_reports_xla_accounting(tmp_path):
     """--compile_memory_check compiles the lowered step and records XLA's own
     per-device memory next to the formula, with the known CPU-graph deltas

@@ -13,16 +13,6 @@ from modalities_tpu.optimizers.scheduler_factory import DummyLRScheduler
 from modalities_tpu.running_env.device_mesh import get_device_mesh
 from modalities_tpu.training.train_step import TrainStepBuilder
 from tests.models.test_gpt2_model import tiny_gpt2
-from modalities_tpu.parallel.jax_compat import PARTIAL_AUTO_SUPPORTED
-
-# pp/cp step programs shard_map over a subset of mesh axes (dp stays auto); legacy
-# jax runtimes cannot compile partial-auto programs at all (jax_compat refuses at
-# trace time), so these equivalence tests skip there instead of burning their dp
-# oracle before the inevitable NotImplementedError.
-requires_partial_auto = pytest.mark.skipif(
-    not PARTIAL_AUTO_SUPPORTED,
-    reason="partial-auto shard_map unsupported on this jax runtime (see jax_compat)",
-)
 
 
 def _builder(model, mesh_handle, acc=1, clip=None):
@@ -217,7 +207,6 @@ def test_unknown_weight_decay_group_raises():
         build_weight_decay_mask(params, model, ["bogus"])
 
 
-@requires_partial_auto
 def test_dp_cp_equivalence():
     """dp8 vs dp2 x cp4 (ring attention) must produce identical losses — the
     CP-vs-single-device oracle for the cp mesh dim."""
@@ -242,7 +231,6 @@ def test_dp_cp_equivalence():
     np.testing.assert_allclose(losses["dp"], losses["dp_cp"], rtol=3e-4, atol=3e-4)
 
 
-@requires_partial_auto
 def test_dp_pp_equivalence():
     """dp8 vs pp2 x dp4 (GPipe schedule) must produce identical losses — the PP
     fwd/bwd-vs-FSDP oracle (reference test_pp_fwd_bwd_pass.py)."""
@@ -266,7 +254,6 @@ def test_dp_pp_equivalence():
     np.testing.assert_allclose(losses["dp"], losses["pp_dp"], rtol=3e-4, atol=3e-4)
 
 
-@requires_partial_auto
 def test_dp_vs_pp_cp_combined_equivalence():
     """dp8 vs pp2 x dp2 x cp2 — all schedule-bearing parallelism forms composed."""
     mesh_dp = get_device_mesh(device_type="cpu", data_parallel_shard_degree=8, world_size=8)
@@ -292,7 +279,6 @@ def test_dp_vs_pp_cp_combined_equivalence():
     np.testing.assert_allclose(losses["dp"], losses["mix"], rtol=5e-4, atol=5e-4)
 
 
-@requires_partial_auto
 def test_rope_global_positions_under_pp_cp():
     """Positionwise f32 logit equality: single-device vs pp2 x cp2 x dp2 forward.
     Inside the pipeline's manual region each cp shard holds a LOCAL sequence chunk,
@@ -325,7 +311,6 @@ def test_rope_global_positions_under_pp_cp():
 
 
 @pytest.mark.parametrize("schedule", ["1f1b", "zbv"])
-@requires_partial_auto
 def test_dp_pp_cp_scheduled_equivalence(schedule):
     """dp8 vs pp2 x dp2 x cp2 under the SCHEDULED executors: ring attention runs
     inside the 1F1B/ZBV shard_map region (cp joins the manual axes; F/B slots go
@@ -353,7 +338,6 @@ def test_dp_pp_cp_scheduled_equivalence(schedule):
     np.testing.assert_allclose(losses["dp"], losses["mix"], rtol=5e-4, atol=5e-4)
 
 
-@requires_partial_auto
 def test_absolute_positions_under_scheduled_pp_cp():
     """ABSOLUTE position embeddings under 1F1B x cp: the embed stage slices wpe at
     the shard's global offset (local chunks restart at 0 otherwise)."""
@@ -380,7 +364,6 @@ def test_absolute_positions_under_scheduled_pp_cp():
     np.testing.assert_allclose(losses["dp"], losses["mix"], rtol=5e-4, atol=5e-4)
 
 
-@requires_partial_auto
 def test_dp_pp_1f1b_equivalence():
     """dp8 vs pp2 x dp4 under the scheduled 1F1B executor: identical losses to pure
     DP — the oracle for the hand-rolled fwd/bwd (reference 1F1B schedule,
@@ -407,7 +390,6 @@ def test_dp_pp_1f1b_equivalence():
     np.testing.assert_allclose(losses["dp"], losses["pp_1f1b"], rtol=3e-4, atol=3e-4)
 
 
-@requires_partial_auto
 def test_pp_1f1b_dropout_deterministic():
     """dropout > 0 under scheduled PP: same seed reproduces identical losses,
     different seed diverges, and the model trains (VERDICT r1 #5)."""
@@ -434,7 +416,6 @@ def test_pp_1f1b_dropout_deterministic():
     assert a[-1] < a[0], f"did not train with dropout under 1F1B: {a}"
 
 
-@requires_partial_auto
 def test_pp_gpipe_dropout_deterministic():
     """dropout > 0 under the default (autodiff GPipe) PP path: same-seed determinism
     and training progress — reference default GPT2 configs run unmodified."""
@@ -478,7 +459,6 @@ def test_pipelined_model_variant_selects_schedule():
 
 
 @pytest.mark.parametrize("schedule", ["zbv", "dualpipev"])
-@requires_partial_auto
 def test_dp_pp_zbv_equivalence(schedule):
     """dp8 vs pp2 x dp4 under ZBVZeroBubble and DualPipeV (each with its OWN
     tables — dualpipev's dual-direction pairing included): V-shaped chunk
@@ -509,7 +489,6 @@ def test_dp_pp_zbv_equivalence(schedule):
     np.testing.assert_allclose(losses["dp"], losses["pp_zbv"], rtol=3e-4, atol=3e-4)
 
 
-@requires_partial_auto
 def test_dp_pp4_zbv_equivalence():
     """dp8 vs pp4 x dp2 under ZBV: exercises the MIDDLE devices of the V (stages
     strictly between 0 and P-1), which pp=2 never does — simultaneous descend/ascend
@@ -538,7 +517,6 @@ def test_dp_pp4_zbv_equivalence():
     np.testing.assert_allclose(losses["dp"], losses["pp4_zbv"], rtol=3e-4, atol=3e-4)
 
 
-@requires_partial_auto
 def test_pp_zbv_dropout_deterministic():
     """dropout > 0 under ZBV: the B-slot recompute and the post-scan W re-forward
     must fold the same per-(microbatch, layer) rng as the F pass — same seed is
@@ -567,7 +545,6 @@ def test_pp_zbv_dropout_deterministic():
 
 
 @pytest.mark.parametrize("schedule", ["1f1b", "zbv"])
-@requires_partial_auto
 def test_dp_pp_equivalence_with_ignore_index(schedule):
     """Unequal valid-token counts across pp microbatches (ignore_index=-100) must not
     skew the scheduled-executor loss: contributions are token-weighted, matching the
@@ -633,7 +610,6 @@ def test_loss_parallel_equivalence_and_rule():
     np.testing.assert_allclose(losses[False], losses[True], rtol=2e-4, atol=2e-4)
 
 
-@requires_partial_auto
 def test_dp_pp_interleaved_1f1b_equivalence():
     """dp8 vs pp2 x dp4 under interleaved 1F1B (2 virtual chunks per device): losses
     must match pure DP — the oracle for virtual-stage layer routing, the chunk-
@@ -695,7 +671,6 @@ def test_chunked_lm_head_loss_equivalence():
     np.testing.assert_allclose(evals[None], evals[8], rtol=2e-5, atol=2e-5)
 
 
-@requires_partial_auto
 def test_chunked_lm_head_under_scheduled_pp():
     """lm_head_chunk_size must be honored INSIDE the scheduled pipeline executor's
     head slot (per-chunk head+CE under jax.checkpoint, no [B,S,V] logits) — losses
@@ -726,7 +701,6 @@ def test_chunked_lm_head_under_scheduled_pp():
     np.testing.assert_allclose(losses[None], losses[8], rtol=2e-5, atol=2e-5)
 
 
-@requires_partial_auto
 def test_chunked_lm_head_under_gpipe_pp():
     """lm_head_chunk_size composes with the autodiff GPipe path too: apply_hidden
     (output_hidden=True) runs the in-module pipeline before the head cut, and the
@@ -932,7 +906,6 @@ def test_chunked_lm_head_ragged_tail():
     np.testing.assert_allclose(evals[None], evals[5], rtol=2e-5, atol=2e-5)
 
 
-@requires_partial_auto
 def test_chunked_lm_head_ragged_tail_under_scheduled_pp():
     """The ragged tail must also work inside the scheduled pipeline executor's
     head slot (prefix scan + short tail under jax.checkpoint)."""

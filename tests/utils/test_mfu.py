@@ -156,13 +156,12 @@ def test_peak_flops_kind_string_variants(kind, expected):
     assert get_peak_flops(kind) == expected
 
 
-def test_peak_flops_unknown_kind_warns():
-    """An unrecognized chip must warn, never silently score MFU against the v5e peak."""
+def test_peak_flops_unknown_kind_raises():
+    """An unrecognized chip is an error: MFU is never scored against another chip's peak."""
     from modalities_tpu.utils.mfu import get_peak_flops
 
-    with pytest.warns(UserWarning, match="Unknown accelerator kind"):
-        peak = get_peak_flops("TPU v99x")
-    assert peak == 197e12  # documented fallback, but loudly
+    with pytest.raises(ValueError, match="Unknown accelerator kind 'TPU v9'"):
+        get_peak_flops("TPU v9")
 
 
 def test_mfu_sane_range_for_realistic_numbers():
