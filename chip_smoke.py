@@ -192,44 +192,6 @@ def phase_prep(workdir: Path, args) -> dict:
 # ------------------------------------------------------------------ what a jax phase observes
 
 
-class CompileLog:
-    """Every backend compile of this process, by jitted function, and which of
-    them the persistent cache answered."""
-
-    def __init__(self):
-        import jax
-
-        self.compiles: list[tuple[str, float, bool]] = []  # (function, seconds, from the cache)
-        self._hit = False
-        jax.monitoring.register_event_duration_secs_listener(self._on_duration)
-        jax.monitoring.register_event_listener(self._on_event)
-
-    def _on_event(self, event: str, **kwargs) -> None:
-        if event == "/jax/compilation_cache/cache_hits":
-            self._hit = True  # raised inside the compile whose duration comes next
-
-    def _on_duration(self, event: str, seconds: float, **kwargs) -> None:
-        if event == "/jax/core/compile/backend_compile_duration":
-            self.compiles.append((str(kwargs.get("fun_name", "?")), seconds, self._hit))
-            self._hit = False
-
-    def summary(self, *names: str) -> dict:
-        """Per name in `names` (a substring of the jitted function's name), and for
-        all the rest together: how many compiles, how many of them cache hits, and
-        the seconds of the first and of all."""
-        groups = {name: [c for c in self.compiles if name in c[0]] for name in names}
-        groups["other"] = [c for c in self.compiles if not any(name in c[0] for name in names)]
-        return {
-            name: {
-                "count": len(group),
-                "cache_hits": sum(hit for _, _, hit in group),
-                "first_s": round(group[0][1], 2) if group else None,
-                "total_s": round(sum(secs for _, secs, _ in group), 2),
-            }
-            for name, group in groups.items()
-        }
-
-
 def kernels_in(compiled_text: str) -> dict[str, int]:
     """Pallas kernels in a compiled program, by the `name=` each pallas_call carries."""
     import re
@@ -270,6 +232,8 @@ def phase_train(workdir: Path, args) -> dict:
     """`python -m modalities_tpu run --test_comm` on the packed corpus, then: every
     loss finite, the first near ln V, the last checkpoint sealed."""
     from modalities_tpu.resilience.manifest import verify_manifest
+
+    from modalities_tpu.telemetry.compile_log import CompileLog
 
     compile_log = CompileLog()
     # where the config's own relative paths put results, under the work directory
@@ -333,6 +297,8 @@ def phase_step(workdir: Path, args) -> dict:
 
     apply_xla_flags_from_config(args.train_config)  # as `run` does: they are part of the cache key
     cache_dir = configure_compilation_cache()
+    from modalities_tpu.telemetry.compile_log import CompileLog
+
     compile_log = CompileLog()
     main = Main(args.train_config)
     components = main.build_components()
@@ -395,6 +361,8 @@ def phase_serve(workdir: Path, args) -> dict:
 
     from modalities_tpu.config.yaml_interp import load_app_config_dict
     from modalities_tpu.serving.serve import build_serving_components, load_serving_params
+
+    from modalities_tpu.telemetry.compile_log import CompileLog
 
     compile_log = CompileLog()
     checkpoint = json.loads((workdir / "phase_train.json").read_text())["checkpoint"]

@@ -37,6 +37,7 @@ from modalities_tpu.models.components.layer_norms import (
     build_norm,
 )
 from modalities_tpu.models.model import NNModel
+from modalities_tpu.telemetry import scopes
 
 
 def with_logical_constraint(x, axes, spec=None, explicit=False):
@@ -517,62 +518,64 @@ class CausalSelfAttention(nn.Module):
             # phases must use the chunk's global offset or cross-chunk relative
             # positions in the ring come out shifted by cp_rank * S_local
             offset = cp_shard_offset(spec.context_parallel_axis, x.shape[1])
-            cos, sin = _rope_tables(head_dim, x.shape[1], spec.rope_base_freq, dtype=x.dtype, offset=offset)
-            q = apply_rope(q, cos, sin)
-            k = apply_rope(k, cos, sin)
+            with jax.named_scope(scopes.ROPE):
+                cos, sin = _rope_tables(head_dim, x.shape[1], spec.rope_base_freq, dtype=x.dtype, offset=offset)
+                q = apply_rope(q, cos, sin)
+                k = apply_rope(k, cos, sin)
 
-        q = with_logical_constraint(q, ("batch", "seq", "heads", "head_dim"), spec)
-        k = with_logical_constraint(k, ("batch", "seq", "kv_heads", "head_dim"), spec)
+        with jax.named_scope(scopes.ATTN_CORE):
+            q = with_logical_constraint(q, ("batch", "seq", "heads", "head_dim"), spec)
+            k = with_logical_constraint(k, ("batch", "seq", "kv_heads", "head_dim"), spec)
 
-        impl = spec.attention_impl
-        # attention-probability dropout (reference gpt2_model.py:595-658: every tier
-        # passes `dropout` into the attention itself — manual attn_dropout(att) /
-        # SDPA+flash dropout_p). The unfused path implements it exactly; the Pallas
-        # flash kernel and the ring do not sample inside the kernel, so they refuse
-        # rather than silently training a different model (docs/components.md §2.4).
-        attn_dropout_active = spec.dropout > 0.0 and not self.deterministic
-        if spec.context_parallel_axis is not None:
-            if attn_dropout_active:
-                raise NotImplementedError(
-                    "attention-probability dropout (dropout > 0) is not implemented for "
-                    "ring attention (context parallelism): the ring merges per-chunk "
-                    "softmax statistics that dropout would invalidate. Set dropout: 0.0 "
-                    "or run without a cp mesh axis."
+            impl = spec.attention_impl
+            # attention-probability dropout (reference gpt2_model.py:595-658: every tier
+            # passes `dropout` into the attention itself — manual attn_dropout(att) /
+            # SDPA+flash dropout_p). The unfused path implements it exactly; the Pallas
+            # flash kernel and the ring do not sample inside the kernel, so they refuse
+            # rather than silently training a different model (docs/components.md §2.4).
+            attn_dropout_active = spec.dropout > 0.0 and not self.deterministic
+            if spec.context_parallel_axis is not None:
+                if attn_dropout_active:
+                    raise NotImplementedError(
+                        "attention-probability dropout (dropout > 0) is not implemented for "
+                        "ring attention (context parallelism): the ring merges per-chunk "
+                        "softmax statistics that dropout would invalidate. Set dropout: 0.0 "
+                        "or run without a cp mesh axis."
+                    )
+                # real context parallelism: ring attention over the cp axis (the slot the
+                # reference leaves unfilled, SURVEY.md §5.7)
+                from modalities_tpu.parallel.ring_attention import ring_attention
+                from modalities_tpu.running_env.device_mesh import current_mesh
+
+                y = ring_attention(q, k, v, current_mesh(), axis_name=spec.context_parallel_axis)
+            elif attn_dropout_active:
+                if impl == AttentionImplementation.DAO_FLASH.value:
+                    raise NotImplementedError(
+                        "attention-probability dropout (dropout > 0) is not implemented in "
+                        "the dao_flash Pallas kernel. Use attention_implementation: manual "
+                        "or pytorch_flash (both apply the reference's attention-weight "
+                        "dropout semantics), or set dropout: 0.0."
+                    )
+                # manual AND pytorch_flash: the reference applies dropout_p inside SDPA;
+                # the fused XLA SDPA has no dropout hook, so both tiers drop to the exact
+                # unfused path — same math, probabilities dropped out as the reference does
+                y = manual_attention(
+                    q, k, v, dropout_rate=spec.dropout, dropout_rng=self.make_rng("dropout")
                 )
-            # real context parallelism: ring attention over the cp axis (the slot the
-            # reference leaves unfilled, SURVEY.md §5.7)
-            from modalities_tpu.parallel.ring_attention import ring_attention
-            from modalities_tpu.running_env.device_mesh import current_mesh
+            elif impl == AttentionImplementation.MANUAL.value:
+                y = manual_attention(q, k, v)
+            elif impl == AttentionImplementation.DAO_FLASH.value:
+                y = flash_attention(q, k, v)
+            else:
+                y = sdpa_attention(q, k, v)
 
-            y = ring_attention(q, k, v, current_mesh(), axis_name=spec.context_parallel_axis)
-        elif attn_dropout_active:
-            if impl == AttentionImplementation.DAO_FLASH.value:
-                raise NotImplementedError(
-                    "attention-probability dropout (dropout > 0) is not implemented in "
-                    "the dao_flash Pallas kernel. Use attention_implementation: manual "
-                    "or pytorch_flash (both apply the reference's attention-weight "
-                    "dropout semantics), or set dropout: 0.0."
-                )
-            # manual AND pytorch_flash: the reference applies dropout_p inside SDPA;
-            # the fused XLA SDPA has no dropout hook, so both tiers drop to the exact
-            # unfused path — same math, probabilities dropped out as the reference does
-            y = manual_attention(
-                q, k, v, dropout_rate=spec.dropout, dropout_rng=self.make_rng("dropout")
-            )
-        elif impl == AttentionImplementation.MANUAL.value:
-            y = manual_attention(q, k, v)
-        elif impl == AttentionImplementation.DAO_FLASH.value:
-            y = flash_attention(q, k, v)
-        else:
-            y = sdpa_attention(q, k, v)
+            # named save point for selective-op remat (reference SAVE_DICT saves the SDPA
+            # output, activation_checkpointing.py:67-83): save_list=("attn_out",) stores
+            # only this tensor and recomputes the rest of the block — the backward then
+            # skips re-running the attention kernel, the block's most expensive op
+            from jax.ad_checkpoint import checkpoint_name
 
-        # named save point for selective-op remat (reference SAVE_DICT saves the SDPA
-        # output, activation_checkpointing.py:67-83): save_list=("attn_out",) stores
-        # only this tensor and recomputes the rest of the block — the backward then
-        # skips re-running the attention kernel, the block's most expensive op
-        from jax.ad_checkpoint import checkpoint_name
-
-        y = checkpoint_name(y, "attn_out")
+            y = checkpoint_name(y, "attn_out")
         return self._project_out(x, y)
 
     def _decode_attention(self, x, q, k, v):
@@ -593,22 +596,24 @@ class CausalSelfAttention(nn.Module):
         i = cache_index.value
 
         if spec.use_rope:
-            cos, sin = _rope_tables(head_dim, max_len, spec.rope_base_freq, dtype=x.dtype)
-            cos_i = jax.lax.dynamic_slice_in_dim(cos, i, s_in)
-            sin_i = jax.lax.dynamic_slice_in_dim(sin, i, s_in)
-            q = apply_rope(q, cos_i, sin_i)
-            k = apply_rope(k, cos_i, sin_i)
+            with jax.named_scope(scopes.ROPE):
+                cos, sin = _rope_tables(head_dim, max_len, spec.rope_base_freq, dtype=x.dtype)
+                cos_i = jax.lax.dynamic_slice_in_dim(cos, i, s_in)
+                sin_i = jax.lax.dynamic_slice_in_dim(sin, i, s_in)
+                q = apply_rope(q, cos_i, sin_i)
+                k = apply_rope(k, cos_i, sin_i)
 
-        k_all = jax.lax.dynamic_update_slice(cached_k.value, k, (0, i, 0, 0))
-        v_all = jax.lax.dynamic_update_slice(cached_v.value, v, (0, i, 0, 0))
-        if not self.is_initializing():
-            cached_k.value = k_all
-            cached_v.value = v_all
-            cache_index.value = i + s_in
+        with jax.named_scope(scopes.ATTN_CORE):
+            k_all = jax.lax.dynamic_update_slice(cached_k.value, k, (0, i, 0, 0))
+            v_all = jax.lax.dynamic_update_slice(cached_v.value, v, (0, i, 0, 0))
+            if not self.is_initializing():
+                cached_k.value = k_all
+                cached_v.value = v_all
+                cache_index.value = i + s_in
 
-        # position t of this call attends to cache positions <= i + t
-        mask = jnp.arange(max_len)[None, :] <= (i + jnp.arange(s_in))[:, None]
-        y = masked_attention(q, k_all, v_all, mask)
+            # position t of this call attends to cache positions <= i + t
+            mask = jnp.arange(max_len)[None, :] <= (i + jnp.arange(s_in))[:, None]
+            y = masked_attention(q, k_all, v_all, mask)
         return self._project_out(x, y)
 
     def _paged_slot_attention(self, x, q, k, v, positions):
@@ -656,69 +661,71 @@ class CausalSelfAttention(nn.Module):
             )
 
         if spec.use_rope:
-            cos, sin = _rope_tables(head_dim, ss.capacity, spec.rope_base_freq, dtype=x.dtype)
-            if ss.mode == "prefill":  # pos [R, C] -> per-token tables [R, C, D]
-                cos_i, sin_i = jnp.take(cos, pos, axis=0), jnp.take(sin, pos, axis=0)
-            else:  # pos [S] -> [S, 1, D]
-                cos_i = jnp.take(cos, pos, axis=0)[:, None, :]
-                sin_i = jnp.take(sin, pos, axis=0)[:, None, :]
-            q = apply_rope(q, cos_i, sin_i)
-            k = apply_rope(k, cos_i, sin_i)
+            with jax.named_scope(scopes.ROPE):
+                cos, sin = _rope_tables(head_dim, ss.capacity, spec.rope_base_freq, dtype=x.dtype)
+                if ss.mode == "prefill":  # pos [R, C] -> per-token tables [R, C, D]
+                    cos_i, sin_i = jnp.take(cos, pos, axis=0), jnp.take(sin, pos, axis=0)
+                else:  # pos [S] -> [S, 1, D]
+                    cos_i = jnp.take(cos, pos, axis=0)[:, None, :]
+                    sin_i = jnp.take(sin, pos, axis=0)[:, None, :]
+                q = apply_rope(q, cos_i, sin_i)
+                k = apply_rope(k, cos_i, sin_i)
 
-        # scatter the incoming k/v into the pool at explicit (block, offset)
-        # coordinates; out-of-range blocks are dropped, never clamped.
-        # Quantize-on-write: int8 mode quantizes each incoming row (symmetric
-        # absmax over head_dim, one scale per kv-head) and scatters value and
-        # scale with the SAME coordinates — a dropped write drops both.
-        k_flat = k.reshape(-1, spec.n_head_kv, head_dim)
-        v_flat = v.reshape(-1, spec.n_head_kv, head_dim)
-        blk, off = wblk.reshape(-1), woff.reshape(-1)
-        if kv_int8:
-            from modalities_tpu.quant.core import quantize_per_channel
-
-            k_flat, k_s = quantize_per_channel(k_flat, axis=-1)
-            v_flat, v_s = quantize_per_channel(v_flat, axis=-1)
-            ks_pool = k_scale.value.at[blk, off].set(k_s, mode="drop")
-            vs_pool = v_scale.value.at[blk, off].set(v_s, mode="drop")
-        k_pool = cached_k.value.at[blk, off].set(k_flat, mode="drop")
-        v_pool = cached_v.value.at[blk, off].set(v_flat, mode="drop")
-        if not self.is_initializing():
-            cached_k.value = k_pool
-            cached_v.value = v_pool
+        with jax.named_scope(scopes.ATTN_CORE):
+            # scatter the incoming k/v into the pool at explicit (block, offset)
+            # coordinates; out-of-range blocks are dropped, never clamped.
+            # Quantize-on-write: int8 mode quantizes each incoming row (symmetric
+            # absmax over head_dim, one scale per kv-head) and scatters value and
+            # scale with the SAME coordinates — a dropped write drops both.
+            k_flat = k.reshape(-1, spec.n_head_kv, head_dim)
+            v_flat = v.reshape(-1, spec.n_head_kv, head_dim)
+            blk, off = wblk.reshape(-1), woff.reshape(-1)
             if kv_int8:
-                k_scale.value = ks_pool
-                v_scale.value = vs_pool
+                from modalities_tpu.quant.core import quantize_per_channel
 
-        # gather each row's K/V tiles via its block table -> [B, MB*bs, Hkv, D];
-        # gathered index IS the logical position (tables are position-ordered).
-        # Dequant-at-gather: int8 mode gathers the quantized pool and its scale
-        # pool through the same tables and broadcasts the multiply back to
-        # x.dtype before the softmax.
-        b_rows, mb = tables.shape
+                k_flat, k_s = quantize_per_channel(k_flat, axis=-1)
+                v_flat, v_s = quantize_per_channel(v_flat, axis=-1)
+                ks_pool = k_scale.value.at[blk, off].set(k_s, mode="drop")
+                vs_pool = v_scale.value.at[blk, off].set(v_s, mode="drop")
+            k_pool = cached_k.value.at[blk, off].set(k_flat, mode="drop")
+            v_pool = cached_v.value.at[blk, off].set(v_flat, mode="drop")
+            if not self.is_initializing():
+                cached_k.value = k_pool
+                cached_v.value = v_pool
+                if kv_int8:
+                    k_scale.value = ks_pool
+                    v_scale.value = vs_pool
 
-        def gather(pool):
-            return jnp.take(pool, tables, axis=0).reshape(
-                b_rows, mb * bs, spec.n_head_kv, pool.shape[-1]
-            )
+            # gather each row's K/V tiles via its block table -> [B, MB*bs, Hkv, D];
+            # gathered index IS the logical position (tables are position-ordered).
+            # Dequant-at-gather: int8 mode gathers the quantized pool and its scale
+            # pool through the same tables and broadcasts the multiply back to
+            # x.dtype before the softmax.
+            b_rows, mb = tables.shape
 
-        if kv_int8:
-            k_all = (gather(k_pool).astype(jnp.float32) * gather(ks_pool)).astype(x.dtype)
-            v_all = (gather(v_pool).astype(jnp.float32) * gather(vs_pool)).astype(x.dtype)
-        else:
-            k_all, v_all = gather(k_pool), gather(v_pool)
-        key_pos = jnp.arange(mb * bs)
-        if ss.mode == "prefill":
-            mask = key_pos[None, None, :] <= pos[:, :, None]  # [R, C, L]
-        else:
-            mask = key_pos[None, None, :] <= pos[:, None, None]  # [S, 1, L]
-        # recycled pool blocks hold whatever their previous owner wrote — and a
-        # masked logit drops out of the softmax, but 0-weight x NaN/inf V still
-        # poisons the output einsum. Zero every V row no query references, so a
-        # dirty recycled block behaves exactly like a fresh zeroed one (K needs
-        # no scrub: masked logits are replaced before the softmax).
-        valid = mask.any(axis=-2)  # [B, L] key rows referenced by any query
-        v_all = jnp.where(valid[:, :, None, None], v_all, 0.0)
-        y = masked_attention(q, k_all, v_all, mask)
+            def gather(pool):
+                return jnp.take(pool, tables, axis=0).reshape(
+                    b_rows, mb * bs, spec.n_head_kv, pool.shape[-1]
+                )
+
+            if kv_int8:
+                k_all = (gather(k_pool).astype(jnp.float32) * gather(ks_pool)).astype(x.dtype)
+                v_all = (gather(v_pool).astype(jnp.float32) * gather(vs_pool)).astype(x.dtype)
+            else:
+                k_all, v_all = gather(k_pool), gather(v_pool)
+            key_pos = jnp.arange(mb * bs)
+            if ss.mode == "prefill":
+                mask = key_pos[None, None, :] <= pos[:, :, None]  # [R, C, L]
+            else:
+                mask = key_pos[None, None, :] <= pos[:, None, None]  # [S, 1, L]
+            # recycled pool blocks hold whatever their previous owner wrote — and a
+            # masked logit drops out of the softmax, but 0-weight x NaN/inf V still
+            # poisons the output einsum. Zero every V row no query references, so a
+            # dirty recycled block behaves exactly like a fresh zeroed one (K needs
+            # no scrub: masked logits are replaced before the softmax).
+            valid = mask.any(axis=-2)  # [B, L] key rows referenced by any query
+            v_all = jnp.where(valid[:, :, None, None], v_all, 0.0)
+            y = masked_attention(q, k_all, v_all, mask)
         return self._project_out(x, y)
 
     def _slot_attention(self, x, q, k, v, slot, positions):
@@ -748,42 +755,46 @@ class CausalSelfAttention(nn.Module):
             s_in = x.shape[1]
             start = positions  # scalar: tokens occupy cache positions start..start+s_in-1
             if spec.use_rope:
-                cos, sin = _rope_tables(head_dim, cap, spec.rope_base_freq, dtype=x.dtype)
-                cos_i = jax.lax.dynamic_slice_in_dim(cos, start, s_in)
-                sin_i = jax.lax.dynamic_slice_in_dim(sin, start, s_in)
-                q = apply_rope(q, cos_i, sin_i)
-                k = apply_rope(k, cos_i, sin_i)
-            row_k = jax.lax.dynamic_slice(
-                cached_k.value, (slot, 0, 0, 0), (1, cap, spec.n_head_kv, head_dim)
-            )
-            row_v = jax.lax.dynamic_slice(
-                cached_v.value, (slot, 0, 0, 0), (1, cap, spec.n_head_kv, head_dim)
-            )
-            k_all = jax.lax.dynamic_update_slice(row_k, k, (0, start, 0, 0))
-            v_all = jax.lax.dynamic_update_slice(row_v, v, (0, start, 0, 0))
-            if not self.is_initializing():
-                cached_k.value = jax.lax.dynamic_update_slice(cached_k.value, k_all, (slot, 0, 0, 0))
-                cached_v.value = jax.lax.dynamic_update_slice(cached_v.value, v_all, (slot, 0, 0, 0))
-            mask = jnp.arange(cap)[None, :] <= (start + jnp.arange(s_in))[:, None]
-            y = masked_attention(q, k_all, v_all, mask)
+                with jax.named_scope(scopes.ROPE):
+                    cos, sin = _rope_tables(head_dim, cap, spec.rope_base_freq, dtype=x.dtype)
+                    cos_i = jax.lax.dynamic_slice_in_dim(cos, start, s_in)
+                    sin_i = jax.lax.dynamic_slice_in_dim(sin, start, s_in)
+                    q = apply_rope(q, cos_i, sin_i)
+                    k = apply_rope(k, cos_i, sin_i)
+            with jax.named_scope(scopes.ATTN_CORE):
+                row_k = jax.lax.dynamic_slice(
+                    cached_k.value, (slot, 0, 0, 0), (1, cap, spec.n_head_kv, head_dim)
+                )
+                row_v = jax.lax.dynamic_slice(
+                    cached_v.value, (slot, 0, 0, 0), (1, cap, spec.n_head_kv, head_dim)
+                )
+                k_all = jax.lax.dynamic_update_slice(row_k, k, (0, start, 0, 0))
+                v_all = jax.lax.dynamic_update_slice(row_v, v, (0, start, 0, 0))
+                if not self.is_initializing():
+                    cached_k.value = jax.lax.dynamic_update_slice(cached_k.value, k_all, (slot, 0, 0, 0))
+                    cached_v.value = jax.lax.dynamic_update_slice(cached_v.value, v_all, (slot, 0, 0, 0))
+                mask = jnp.arange(cap)[None, :] <= (start + jnp.arange(s_in))[:, None]
+                y = masked_attention(q, k_all, v_all, mask)
         else:  # decode: one new token per slot, each at its own position
             if spec.use_rope:
-                cos, sin = _rope_tables(head_dim, cap, spec.rope_base_freq, dtype=x.dtype)
-                cos_i = jnp.take(cos, positions, axis=0)[:, None, :]
-                sin_i = jnp.take(sin, positions, axis=0)[:, None, :]
-                q = apply_rope(q, cos_i, sin_i)
-                k = apply_rope(k, cos_i, sin_i)
+                with jax.named_scope(scopes.ROPE):
+                    cos, sin = _rope_tables(head_dim, cap, spec.rope_base_freq, dtype=x.dtype)
+                    cos_i = jnp.take(cos, positions, axis=0)[:, None, :]
+                    sin_i = jnp.take(sin, positions, axis=0)[:, None, :]
+                    q = apply_rope(q, cos_i, sin_i)
+                    k = apply_rope(k, cos_i, sin_i)
 
             def write_row(buf, new, p):
                 return jax.lax.dynamic_update_slice(buf, new, (p, 0, 0))
 
-            k_all = jax.vmap(write_row)(cached_k.value, k, positions)
-            v_all = jax.vmap(write_row)(cached_v.value, v, positions)
-            if not self.is_initializing():
-                cached_k.value = k_all
-                cached_v.value = v_all
-            mask = jnp.arange(cap)[None, None, :] <= positions[:, None, None]
-            y = masked_attention(q, k_all, v_all, mask)
+            with jax.named_scope(scopes.ATTN_CORE):
+                k_all = jax.vmap(write_row)(cached_k.value, k, positions)
+                v_all = jax.vmap(write_row)(cached_v.value, v, positions)
+                if not self.is_initializing():
+                    cached_k.value = k_all
+                    cached_v.value = v_all
+                mask = jnp.arange(cap)[None, None, :] <= positions[:, None, None]
+                y = masked_attention(q, k_all, v_all, mask)
         return self._project_out(x, y)
 
     def _project_out(self, x, y):
@@ -853,11 +864,15 @@ class GPT2Block(nn.Module):
         spec = self.spec
         x = with_logical_constraint(x, ("batch", "seq", "embed"), spec)
         h = build_norm(spec.attn_norm, "attention_norm", dtype=x.dtype)(x)
-        x = x + CausalSelfAttention(
+        a = CausalSelfAttention(
             spec, self.deterministic, self.decode, slot_spec=self.slot_spec, name="attn"
         )(h, slot, positions)
+        with jax.named_scope(scopes.RESIDUAL):
+            x = x + a
         h2 = build_norm(spec.ffn_norm, "ffn_norm", dtype=x.dtype)(x)
-        x = x + MLP(spec, self.deterministic, name="mlp")(h2)
+        m = MLP(spec, self.deterministic, name="mlp")(h2)
+        with jax.named_scope(scopes.RESIDUAL):
+            x = x + m
         if spec.debug_print_activations == "shape":
             jax.debug.print(
                 "block out shape=" + str(tuple(x.shape)) + " dtype=" + str(x.dtype)
@@ -990,9 +1005,10 @@ class GPT2Module(nn.Module):
         # activation layout via an involuntary full rematerialization of the
         # activations (spmd_partitioner.cc:652 warnings in the pp×dp×cp dryrun) —
         # at scale that all-gathers [B,S,E] per step instead of the [V,E] table
-        wte_lookup = with_logical_constraint(wte, ("vocab", "embed_lookup"), explicit=True)
-        x = jnp.take(wte_lookup, input_ids, axis=0).astype(compute_dtype)
-        x = with_logical_constraint(x, ("batch", "seq", "embed"))
+        with jax.named_scope(scopes.WTE):
+            wte_lookup = with_logical_constraint(wte, ("vocab", "embed_lookup"), explicit=True)
+            x = jnp.take(wte_lookup, input_ids, axis=0).astype(compute_dtype)
+            x = with_logical_constraint(x, ("batch", "seq", "embed"))
         if spec.poe_type == PositionTypes.ABSOLUTE.value:
             wpe = self.param(
                 "wpe",
@@ -1034,7 +1050,8 @@ class GPT2Module(nn.Module):
                 length=spec.n_layer,
                 metadata_params={nn.meta.PARTITION_NAME: "layers"},
             )(spec, self.deterministic, self.slot_spec, name="blocks")
-            (x, _, _), _ = scanned((x, slot, positions), None)
+            with jax.named_scope(scopes.LAYER_CARRY):  # the scan's own stacking and slicing; blocks name themselves
+                (x, _, _), _ = scanned((x, slot, positions), None)
         elif spec.scan_layers:
             scanned = nn.scan(
                 _BlockScanBody,
@@ -1082,7 +1099,8 @@ class GPT2Module(nn.Module):
                     dropout_rng=pp_dropout_rng,
                 )
             else:
-                x, _ = scanned(x, None)
+                with jax.named_scope(scopes.LAYER_CARRY):  # the scan's own stacking and slicing; blocks name themselves
+                    x, _ = scanned(x, None)
         else:
             for i in range(spec.n_layer):
                 block_cls = (
@@ -1099,7 +1117,8 @@ class GPT2Module(nn.Module):
         if self.output_hidden:
             return x
         if spec.use_weight_tying:
-            logits = jnp.einsum("bse,ve->bsv", x.astype(jnp.float32), wte.astype(jnp.float32))
+            with jax.named_scope(scopes.LM_HEAD):
+                logits = jnp.einsum("bse,ve->bsv", x.astype(jnp.float32), wte.astype(jnp.float32))
         elif spec.quant_weights != "none":
             logits = QuantDenseGeneral(
                 features=(spec.vocab_size,),
@@ -1489,7 +1508,8 @@ class GPT2LLM(NNModel):
 
         def embed(shared, tokens, rng):
             p = shared["params"]
-            x = jnp.take(p["wte"], tokens, axis=0).astype(compute_dtype)
+            with jax.named_scope(scopes.WTE):
+                x = jnp.take(p["wte"], tokens, axis=0).astype(compute_dtype)
             if spec.poe_type == PositionTypes.ABSOLUTE.value:
                 # tokens are a LOCAL seq chunk under cp: slice wpe at the global offset
                 offset = cp_shard_offset(cp_axis, tokens.shape[1])
@@ -1523,6 +1543,7 @@ class GPT2LLM(NNModel):
         # remat trade as the unpipelined fused chunked head+loss in train_step
         chunk_sum_count = jax.checkpoint(_norm_head_sum, prevent_cse=False)
 
+        @jax.named_scope(scopes.HEAD_LOSS)
         def head_loss(shared, x, targets):
             """Returns (mean loss over this microbatch, valid-token weight). The weight
             lets the executor reproduce the GLOBAL token mean exactly even when

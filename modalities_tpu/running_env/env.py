@@ -27,12 +27,21 @@ def configure_compilation_cache() -> str:
     """Where this process keeps JAX's persistent compilation cache — the one seam
     `run`, `warmstart`, `serve` and `chip_smoke.py` all pass. Where
     ``JAX_COMPILATION_CACHE_DIR`` is set, JAX's own setting stands and nothing is
-    set here; otherwise ``COMPILATION_CACHE_DIR``."""
+    set here; otherwise ``COMPILATION_CACHE_DIR``.
+
+    Wherever the cache is, an entry's key takes in the program's metadata. JAX leaves
+    it out by default, and two trees whose programs differ only in metadata (a scope
+    added or renamed: `telemetry/scopes.py`) then share one entry: the second gets the
+    first's executable back, with the first's `op_name`s, and a profile of it names
+    every operation by scopes its own source no longer has. The price is that a tree
+    whose traced lines moved compiles once more (the metadata holds file names and
+    line numbers): a cold compile where an entry of another tree would have been hit."""
+    import jax
+
+    jax.config.update("jax_compilation_cache_include_metadata_in_key", True)
     from_env = os.environ.get("JAX_COMPILATION_CACHE_DIR")
     if from_env:
         return from_env
-    import jax
-
     jax.config.update("jax_compilation_cache_dir", str(COMPILATION_CACHE_DIR))
     return str(COMPILATION_CACHE_DIR)
 

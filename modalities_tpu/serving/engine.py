@@ -177,6 +177,7 @@ class ServeResult:
     arrival_s: float = 0.0  # engine-clock arrival
     first_token_s: float = 0.0  # engine-clock time the first token was available
     finish_s: float = 0.0
+    queue_wait_s: float = 0.0  # enqueue (and every requeue) to slot admission, summed; set at finish
     token_times_s: list[float] = field(default_factory=list)
     # fleet-wide request tracing (PR 13): ONE trace_id spans router -> every
     # worker leg (a failover replay keeps the id, hop increments per leg)
@@ -649,6 +650,10 @@ class ServingEngine:
             reg.gauge(
                 "serve_paged_free_blocks", "Free blocks in the paged KV pool"
             ).set_fn(lambda: self._table_state.pool.free_count)
+            reg.gauge(
+                "serve_paged_blocks_in_use_peak",
+                "High-water mark of paged KV blocks in use since run() last started",
+            ).set_fn(lambda: self._table_state.pool.peak_used)
             reg.gauge("serve_paged_total_blocks", "Configured paged KV pool size").set(
                 self.num_blocks
             )
@@ -1243,6 +1248,7 @@ class ServingEngine:
         trace = self._traces.pop(result.rid, None)
         if trace is None:
             return
+        result.queue_wait_s = trace["queue_wait_s"]
         times = result.token_times_s
         tpot_mean = (
             (times[-1] - times[0]) / (len(times) - 1) if len(times) >= 2 else None
@@ -2583,6 +2589,8 @@ class ServingEngine:
         in-flight slots finish (graceful drain: no new admissions, queued
         requests are left unserved). Returns rid -> ServeResult."""
         t0 = self._now()
+        if self.kv_cache == "paged":
+            self._table_state.pool.reset_peak()  # the high-water mark is per run, like the clock
         try:
             while True:
                 stopping = self._stopping()
@@ -2665,6 +2673,7 @@ class ServingEngine:
                 block_size=self.block_size,
                 num_blocks=self.num_blocks,
                 free_blocks=self._table_state.pool.free_count,
+                blocks_in_use_peak=self._table_state.pool.peak_used,
                 prefix_sharing=self.prefix_sharing,
                 prefix_hit_requests=prefix_hit_requests,
                 prefix_hit_blocks=prefix_hit_blocks,
@@ -2764,6 +2773,15 @@ class ServingEngine:
         with self._rules_ctx():
             compiled = self._decode_lowered().compile()
         return perfscope_from_compiled(compiled, mesh_axis_sizes, hw)
+
+    def scope_table(self) -> dict[str, str]:
+        """{instruction name: op_name} of the compiled decode step
+        (telemetry/perfscope.py, the vocabulary in telemetry/scopes.py), for a
+        reader that names a device trace's events by scope."""
+        from modalities_tpu.telemetry.perfscope import scope_table
+
+        with self._rules_ctx():
+            return scope_table(self._decode_lowered().compile().as_text())
 
     def memscope_report(self) -> dict:
         """Compile the batched decode step and carve its memory_analysis() bytes

@@ -59,6 +59,9 @@ class BlockPool:
         # because tables, not block ids, carry position order)
         self._free: list[int] = list(range(self.num_blocks - 1, -1, -1))
         self._refcount: dict[int, int] = {}  # block id -> references >= 1
+        # high-water mark of distinct blocks in use since the last `reset_peak()`:
+        # what a pool has to hold for the traffic it saw, beside what was reserved
+        self.peak_used = 0
         # observers see block LIVENESS transitions (0 -> 1 ref on allocate,
         # last ref -> 0 on free; fork/partial-free are invisible) — the
         # quantized pool's scale mirror (quant/kv.py KVScaleMirror) rides these
@@ -91,6 +94,7 @@ class BlockPool:
             return None
         block = self._free.pop()
         self._refcount[block] = 1
+        self.peak_used = max(self.peak_used, len(self._refcount))
         for obs in self._observers:
             obs.on_allocate(block)
         return block
@@ -117,6 +121,10 @@ class BlockPool:
             obs.on_free(block)
         return True
 
+    def reset_peak(self) -> None:
+        """Start the high-water mark again from what is in use now."""
+        self.peak_used = len(self._refcount)
+
     def refcount(self, block: int) -> int:
         return self._refcount.get(block, 0)
 
@@ -137,6 +145,11 @@ class BlockPool:
         bad = {b: c for b, c in self._refcount.items() if c < 1}
         if bad:
             raise AssertionError(f"non-positive refcounts: {bad}")
+        if not len(self._refcount) <= self.peak_used <= self.num_blocks:
+            raise AssertionError(
+                f"high-water mark {self.peak_used} outside [in use {len(self._refcount)}, "
+                f"pool {self.num_blocks}]"
+            )
 
 
 @dataclass

@@ -81,6 +81,10 @@ class Telemetry:
         self.anomaly_window = int(anomaly_window)
         self._step_time_detector = None
         self._bucket_detectors: dict[str, object] = {}
+        # backend compiles, while this instance is the active one (compile_log.py);
+        # `_in_flight` is the step or scheduler round the watchdog calls announce
+        self._compile_log = None
+        self._in_flight: Optional[int] = None
         self._last_bucket_seconds: dict[str, float] = {}
         # optional SLO engine (PR 15): judged objectives over self.metrics;
         # None (the default) keeps every publish path on the pre-SLO behavior
@@ -179,6 +183,7 @@ class Telemetry:
         return self._watchdog
 
     def arm_watchdog(self, step_id: int, first_step: bool = False) -> None:
+        self._in_flight = step_id
         watchdog = self._ensure_watchdog()
         if watchdog is None:
             return
@@ -186,6 +191,7 @@ class Telemetry:
         watchdog.arm(step_id, deadline_s=deadline_s)
 
     def beat_watchdog(self, step_id: int) -> None:
+        self._in_flight = step_id + 1  # `step_id` is done: what compiles now belongs to the next
         if self._watchdog is not None:
             self._watchdog.beat(step_id)
 
@@ -204,6 +210,34 @@ class Telemetry:
     @property
     def watchdog_artifacts(self) -> list[Path]:
         return list(self._watchdog.fired_artifacts) if self._watchdog is not None else []
+
+    # --------------------------------------------------------------- compiles
+
+    def watch_compiles(self) -> None:
+        """Count this process's backend compiles from now on (`set_active_telemetry`
+        calls it for the instance it installs, and `unwatch_compiles` for the one it
+        replaces, so one instance listens at a time)."""
+        if self.enabled and self._compile_log is None:
+            from modalities_tpu.telemetry.compile_log import CompileLog
+
+            self._compile_log = CompileLog(on_compile=self._on_compile)
+
+    def unwatch_compiles(self) -> None:
+        if self._compile_log is not None:
+            self._compile_log.close()
+            self._compile_log = None
+
+    def _on_compile(self, function: str, seconds: float, cache_hit: bool) -> None:
+        hit = "true" if cache_hit else "false"
+        self.metrics.counter(
+            "compile_total", "Backend compiles of this process, by whether the persistent cache answered"
+        ).inc(cache_hit=hit)
+        self.metrics.counter(
+            "compile_seconds_total", "Seconds spent in backend compiles (a cache hit's are its load time)"
+        ).inc(seconds, cache_hit=hit)
+        if self._sink is not None:
+            self._sink.emit({"event": "compile", "function": function, "seconds": round(seconds, 6),
+                             "cache_hit": cache_hit, "step": self._in_flight})
 
     # ---------------------------------------------------------------- goodput
 
@@ -400,6 +434,7 @@ class Telemetry:
         safe on the exception path."""
         if self.slo_engine is not None:
             self.slo_engine.stop()
+        self.unwatch_compiles()
         if self._watchdog is not None:
             self._watchdog.stop()
         if self._sink is not None:
@@ -422,6 +457,9 @@ def set_active_telemetry(telemetry: Optional[Telemetry]) -> Telemetry:
     global _active
     previous = _active
     _active = telemetry if telemetry is not None else NOOP_TELEMETRY
+    if previous is not _active:
+        previous.unwatch_compiles()
+        _active.watch_compiles()
     return previous
 
 

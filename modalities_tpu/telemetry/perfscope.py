@@ -45,8 +45,9 @@ import statistics
 from collections import deque
 from dataclasses import dataclass
 from pathlib import Path
-from typing import Optional, Union
+from typing import Iterator, NamedTuple, Optional, Union
 
+from modalities_tpu.telemetry.scopes import scope_path
 from modalities_tpu.utils.logging import get_logger
 
 logger = get_logger(__name__)
@@ -67,7 +68,9 @@ _SHAPE_RE = re.compile(r"\b([a-z][a-z0-9]*)\[([0-9,]*)\](?:\{[^}]*\})?")
 _INSTR_RE = re.compile(r"^\s*(?:ROOT\s+)?%?([\w.\-]+)\s*=\s*(.*)$")
 # first bare identifier followed by '(' after the output shape(s) is the opcode
 _OPCODE_RE = re.compile(r"\b([a-z][a-z0-9\-]*)\(")
-_COMP_START_RE = re.compile(r"^\s*(?:ENTRY\s+)?%?([\w.\-]+)\s+(?:\([^)]*\)\s*->|\{)")
+# computation header: "name (params) -> result {" or "name {"; an instruction line has
+# "name = ..." instead. The parameter list may nest parentheses (tuple types).
+_COMP_START_RE = re.compile(r"^\s*(?:ENTRY\s+)?%?([\w.\-]+)\s+(?:\(.*\)\s*->.*\{|\{)\s*$")
 _CALLS_RE = re.compile(r"calls=%?([\w.\-]+)")
 _REPLICA_GROUPS_IOTA_RE = re.compile(r"replica_groups=\[(\d+),(\d+)\]")
 _REPLICA_GROUPS_LIT_RE = re.compile(r"replica_groups=\{\{([^}]*)\}")
@@ -82,6 +85,8 @@ _REPLICA_GROUPS_LIT_FULL_RE = re.compile(
 )
 _CONTRACT_RE = re.compile(r"lhs_contracting_dims=\{([0-9,]*)\}")
 _CUSTOM_TARGET_RE = re.compile(r'custom_call_target="([^"]*)"')
+_OP_NAME_RE = re.compile(r'op_name="((?:[^"\\]|\\.)*)"')
+_TO_APPLY_RE = re.compile(r"to_apply=%?([\w.\-]+)")
 
 # instruction opcodes that are pure bookkeeping: no data moved, no flops
 _SKIP_OPS = frozenset(
@@ -282,6 +287,80 @@ def _instruction_cost(opcode: str, line: str, rhs: str, opcode_pos: int) -> tupl
     return 0, nbytes
 
 
+class _Instruction(NamedTuple):
+    computation: Optional[str]
+    name: str
+    opcode: str
+    opcode_pos: int  # where the opcode starts in `rhs`: output shapes before it, operands after
+    rhs: str
+    line: str
+    is_root: bool
+
+
+def _instructions(hlo_text: str) -> Iterator[_Instruction]:
+    """Walk an HLO module's text, one `_Instruction` per instruction line."""
+    current_comp = None
+    for raw_line in hlo_text.splitlines():
+        comp_m = _COMP_START_RE.match(raw_line)
+        if comp_m:
+            current_comp = comp_m.group(1)
+            continue
+        instr = _INSTR_RE.match(raw_line)
+        if instr is None:
+            continue
+        rhs = instr.group(2)
+        op_m = _OPCODE_RE.search(rhs)
+        if op_m is None:
+            continue
+        yield _Instruction(current_comp, instr.group(1), op_m.group(1), op_m.start(), rhs, raw_line,
+                           raw_line.lstrip().startswith("ROOT "))
+
+
+def _op_name(line: str) -> Optional[str]:
+    m = _OP_NAME_RE.search(line)
+    return m.group(1).replace("\\'", "'").replace('\\"', '"') if m else None
+
+
+def scope_table(hlo_text: str) -> dict[str, str]:
+    """{instruction name: op_name} of an optimized HLO module: the scope path JAX
+    wrote while tracing (telemetry/scopes.py), for every instruction that can run as
+    an operation of its own and so show as one event of a device trace — those of the
+    entry computation, of loop bodies and conditions, of calls and branches. A fusion
+    whose own metadata is empty carries its root's (the last named instruction of its
+    fused computation where the root has none). Left out: bookkeeping instructions
+    (parameters, tuples, bitcasts: `_SKIP_OPS`), the insides of fusions, and the
+    bodies of reducers (`to_apply=` of anything but a `call`), none of which is ever
+    an event. An instruction without an `op_name` is left out too; a reader counts
+    what it cannot find as unattributed."""
+    rows = list(_instructions(hlo_text))
+    inside = set()  # computations whose instructions never run on their own
+    for row in rows:
+        if row.opcode == "fusion":
+            inside.update(_CALLS_RE.findall(row.line))
+        elif row.opcode != "call":
+            inside.update(_TO_APPLY_RE.findall(row.line))
+    root_name: dict[str, str] = {}  # fused computation -> its root's op_name, or its last named instruction's
+    rooted = set()
+    for row in rows:
+        if row.computation in inside and row.computation not in rooted:
+            name = _op_name(row.line)
+            if name is not None:
+                root_name[row.computation] = name
+                if row.is_root:
+                    rooted.add(row.computation)
+    table: dict[str, str] = {}
+    for row in rows:
+        if row.computation in inside or row.opcode in _SKIP_OPS:
+            continue
+        name = _op_name(row.line)
+        if name is None and row.opcode == "fusion":
+            called = _CALLS_RE.search(row.line)
+            name = root_name.get(called.group(1)) if called else None
+        if name is not None:
+            table[row.name] = name
+    return table
+
+
 def analyze_hlo_text(
     hlo_text: str,
     mesh_axis_sizes: Optional[dict[str, int]] = None,
@@ -306,6 +385,7 @@ def analyze_hlo_text(
         module_name = m.group(1)
 
     buckets: dict[str, dict] = {}
+    by_scope: dict[str, dict] = {}
 
     def _bucket(name: str) -> dict:
         b = buckets.get(name)
@@ -313,25 +393,12 @@ def analyze_hlo_text(
             b = buckets[name] = {"ops": 0, "flops": 0, "bytes": 0, "est_time_s": 0.0, "top_ops": []}
         return b
 
-    current_comp = None
-    for raw_line in hlo_text.splitlines():
-        comp_m = _COMP_START_RE.match(raw_line)
-        if comp_m and ("{" in raw_line or "->" in raw_line) and "=" not in raw_line.split("{")[0]:
-            current_comp = comp_m.group(1)
-            continue
-        instr = _INSTR_RE.match(raw_line)
-        if instr is None:
-            continue
-        rhs = instr.group(2)
-        op_m = _OPCODE_RE.search(rhs)
-        if op_m is None:
-            continue
-        opcode = op_m.group(1)
+    for current_comp, instr_name, opcode, opcode_pos, rhs, raw_line, _ in _instructions(hlo_text):
         if opcode in _SKIP_OPS:
             continue
         in_fusion = current_comp in fused_comps
 
-        flops, nbytes = _instruction_cost(opcode, raw_line, rhs, op_m.start())
+        flops, nbytes = _instruction_cost(opcode, raw_line, rhs, opcode_pos)
         if opcode == "fusion":
             flops = 0  # inner ops carry the flops
         elif in_fusion:
@@ -371,9 +438,18 @@ def analyze_hlo_text(
         b["bytes"] += nbytes
         b["est_time_s"] += est
         b["top_ops"].append(
-            {"op": f"{opcode} %{instr.group(1)}", "flops": flops, "bytes": nbytes,
+            {"op": f"{opcode} %{instr_name}", "flops": flops, "bytes": nbytes,
              "est_time_s": est}
         )
+        # the same instruction once more, by the scope its metadata names: the
+        # by-scope column closes on the module total exactly as the buckets do
+        row = by_scope.setdefault(
+            scope_path(_op_name(raw_line)), {"ops": 0, "flops": 0, "bytes": 0, "est_time_s": 0.0}
+        )
+        row["ops"] += 1
+        row["flops"] += flops
+        row["bytes"] += nbytes
+        row["est_time_s"] += est
 
     for b in buckets.values():
         b["top_ops"] = sorted(b["top_ops"], key=lambda o: -o["est_time_s"])[:top_ops]
@@ -394,6 +470,7 @@ def analyze_hlo_text(
         "mesh_axes": dict(mesh_axis_sizes or {}),
         "hw": hw.as_dict(),
         "buckets": {k: buckets[k] for k in sorted(buckets)},
+        "by_scope": {k: {**v, "est_time_s": round(v["est_time_s"], 12)} for k, v in sorted(by_scope.items())},
         "total": total,
     }
 
@@ -428,6 +505,9 @@ def write_report(report: dict, path: Union[str, Path]) -> Path:
     return path
 
 
+SCOPE_ROWS = 24  # scopes printed by est. time before the rest is summed into one row
+
+
 def format_perfscope_table(report: dict) -> str:
     """Aligned text table for one or many module reports ({"executables": ...}
     or a single analyze_hlo_text result)."""
@@ -454,6 +534,17 @@ def format_perfscope_table(report: dict) -> str:
                 f"  xla cost_analysis cross-check: {xla['flops'] / 1e9:.3f} GFLOP, "
                 f"{xla.get('bytes accessed', 0.0) / 1e6:.3f} MB"
             )
+        scoped = sorted((mod.get("by_scope") or {}).items(), key=lambda kv: -kv[1]["est_time_s"])
+        if scoped:
+            lines.append(f"  {'scope (telemetry/scopes.py)':<72} {'ops':>6} {'est ms':>10} {'share':>7}")
+            for scope, b in scoped[:SCOPE_ROWS]:
+                share = b["est_time_s"] / total["est_time_s"] if total["est_time_s"] else 0.0
+                lines.append(f"  {scope[-72:]:<72} {b['ops']:>6} {b['est_time_s'] * 1e3:>10.4f} {share:>6.1%}")
+            rest = scoped[SCOPE_ROWS:]
+            if rest:
+                est = sum(b["est_time_s"] for _, b in rest)
+                lines.append(f"  {f'({len(rest)} more scopes)':<72} {sum(b['ops'] for _, b in rest):>6} {est * 1e3:>10.4f} "
+                             f"{est / total['est_time_s'] if total['est_time_s'] else 0.0:>6.1%}")
         lines.append("")
     return "\n".join(lines).rstrip()
 

@@ -1,0 +1,86 @@
+"""The vocabulary of scope names on device programs, written down once.
+
+Every operation XLA emits carries, in its metadata, the `op_name` path JAX built while
+tracing: `jit(train_step)/while/body/closed_call/transpose(jvp(GPT2Module))/while/body/
+closed_call/blocks/block/mlp/W/dot_general`. A trace reader (`benchmark/xscope.py`,
+`data analyze_perfscope`) reads an operation's pass and component off that path, so
+the names on it are an interface. They are set with `jax.named_scope(<constant>)` at
+the place the work happens; call sites import the constants below and spell no name
+themselves. A scope is metadata: the optimized program is the same with and without it
+(`tests/telemetry/test_scopes.py` pins that).
+
+Pass is never set by hand. It is read from the transforms JAX writes itself:
+
+    forward    under `jvp(` and not under `transpose(`
+    backward   under `transpose(jvp(`; a `rematted_computation` below it counts as
+               backward, being work the backward pass causes
+    update     the train-step scopes of the first table
+
+Train step (`training/train_step.py`), what autodiff does not mark:
+
+    GRAD_ACCUMULATE   grad_accumulate   the zero accumulator, the sum and cast per microbatch, the division by acc_steps
+    GRAD_NORM         grad_norm         the global norm of the gradients as reported
+    OPTIMIZER         optimizer         `tx.update`: AdamW; a clip transform chained into `tx` shows as `optimizer/.../clip`
+    CLIP              clip              the name optax's own `jit(clip)` gives; no scope of ours
+    APPLY_UPDATES     apply_updates     `optax.apply_updates`, and ZeRO's all-gather of the new parameters
+    ANOMALY_SELECT    anomaly_select    the skip of a non-finite step (`anomaly_policy`)
+    STEP_METRICS      step_metrics      learning rate, flags and ballots of the metrics dict
+    HEAD_LOSS         head_loss         the head projection with its loss, chunked, fused or dense:
+                                        reads `jvp(head_loss)` / `transpose(jvp(head_loss))`
+
+Model (`models/gpt2/gpt2_model.py`), beside the names Flax gives its modules:
+
+    WTE               wte               the embedding lookup (and the tied head's use of the table)
+    ROPE              rope              the qkv transforms
+    ATTN_CORE         attn_core         the attention implementation's call: kernel, padding, reshapes, masks,
+                                        the KV cache's write, the paged gather and scatter
+    RESIDUAL          residual          the two adds of a block
+    LAYER_CARRY       layer_carry       what the layer scan stacks or slices outside a block; where no scope
+                                        reaches (the scan's own body), readers take the path
+                                        `GPT2Module)/while/body` without `blocks/` for the same bucket
+
+Module names Flax gives, part of the vocabulary as they are (`flax_profile` puts them
+on the stack): `GPT2Module`, `blocks/block` (`h_<i>` when the layers are not scanned),
+`attn/{q_attn,k_attn,v_attn,c_proj}`, `mlp/{W,V,W_2,c_fc,c_proj}`, `attention_norm`,
+`ffn_norm`, `lm_head_norm`, `lm_head`. Kernels keep the `name=` of their Pallas call:
+`flash_attention_{fwd,bwd_dq,bwd_dkv}`, `fused_ce_{fwd,bwd_dh,bwd_dw}`,
+`fused_rmsnorm_{fwd,bwd}`; the instruction of a call is named by it, and metrics select
+by that name.
+
+Host spans (`telemetry/spans.py`) are a second vocabulary, on the host's rows of the
+same trace: `data_wait`, `train_step`, `metrics_fetch`, `publish`, `serve/admission`,
+`serve/prefill`, `serve/decode`.
+"""
+
+GRAD_ACCUMULATE = "grad_accumulate"
+GRAD_NORM = "grad_norm"
+CLIP = "clip"
+OPTIMIZER = "optimizer"
+APPLY_UPDATES = "apply_updates"
+ANOMALY_SELECT = "anomaly_select"
+STEP_METRICS = "step_metrics"
+HEAD_LOSS = "head_loss"
+LM_HEAD = "lm_head"  # the name Flax gives the untied head's module; the tied head's einsum is set under it too
+
+WTE = "wte"
+ROPE = "rope"
+ATTN_CORE = "attn_core"
+RESIDUAL = "residual"
+LAYER_CARRY = "layer_carry"
+
+UPDATE_SCOPES = (GRAD_ACCUMULATE, GRAD_NORM, CLIP, OPTIMIZER, APPLY_UPDATES, ANOMALY_SELECT, STEP_METRICS)
+MODEL_SCOPES = (WTE, ROPE, ATTN_CORE, RESIDUAL, LAYER_CARRY)
+
+# what a path holds beside scopes: the jit wrapper, the plumbing of loops, calls and branches
+_PLUMBING = frozenset(("while", "body", "cond", "closed_call", "checkpoint"))
+
+
+def scope_path(op_name):
+    """An `op_name` cut down to its scopes, for a table a person reads: without the
+    primitive that ends it, the `jit(...)` that opens it and the plumbing of loops,
+    calls and branches between. `None` or a bare primitive gives `(no scope)`."""
+    if not op_name or "/" not in op_name:
+        return "(no scope)"
+    kept = [part for part in op_name.split("/")[:-1]
+            if part not in _PLUMBING and not part.startswith("branch_") and not (part.startswith("jit(") and part.endswith(")"))]
+    return "/".join(kept) or "(no scope)"

@@ -32,6 +32,7 @@ from modalities_tpu.parallel.sharding import (
     zero_params_shardings,
 )
 from modalities_tpu.running_env.device_mesh import DeviceMeshHandle
+from modalities_tpu.telemetry import scopes
 from modalities_tpu.utils.logging import get_logger
 
 logger = get_logger(__name__)
@@ -63,6 +64,12 @@ def _substitute_param_subtrees(node, param_treedef, param_shardings, replicated_
             for k, v in node.items()
         }
     return replicated_sharding
+
+
+def _under_scope(tx: optax.GradientTransformation, name: str) -> optax.GradientTransformation:
+    """`tx` with its update traced under the named scope `name`: metadata on the
+    operations it emits, the same program (telemetry/scopes.py)."""
+    return optax.GradientTransformation(tx.init, jax.named_scope(name)(tx.update))
 
 
 @dataclass
@@ -107,6 +114,20 @@ class StepFunctions:
         return perfscope_from_compiled(
             self.lower_train_step(batch_abstract).compile(), mesh_axis_sizes, hw
         )
+
+    def scope_table(self, batch_abstract) -> dict[str, str]:
+        """{instruction name: op_name} of the compiled step (telemetry/perfscope.py,
+        the vocabulary in telemetry/scopes.py): what a trace reader joins a device
+        event's instruction to, to read its pass and component. The compile is a
+        cache hit in a process that has run the step."""
+        if self.lower_train_step is None:
+            raise ValueError(
+                "scope_table needs the AOT lowering surface; this StepFunctions "
+                "was built without lower_train_step"
+            )
+        from modalities_tpu.telemetry.perfscope import scope_table
+
+        return scope_table(self.lower_train_step(batch_abstract).compile().as_text())
 
     def memscope_report(self, batch_abstract) -> dict:
         """Lower + compile the sharded step and carve its memory_analysis() bytes
@@ -348,9 +369,9 @@ class TrainStepBuilder:
             error_if_nonfinite = bool(getattr(self.grad_clipper, "error_if_nonfinite", False))
             clip_tx = self.grad_clipper.build_transform()
             if clip_tx is not None:
-                tx = optax.chain(clip_tx, tx)
+                tx = optax.chain(_under_scope(clip_tx, scopes.CLIP), tx)
         elif self.grad_clip_norm is not None:
-            tx = optax.chain(optax.clip_by_global_norm(self.grad_clip_norm), tx)
+            tx = optax.chain(_under_scope(optax.clip_by_global_norm(self.grad_clip_norm), scopes.CLIP), tx)
         lr_fn = schedule if schedule is not None else (lambda step: self.optimizer_spec.lr)
 
         init_routines = tuple(getattr(model.train_spec, "init_routines", ()))
@@ -454,6 +475,7 @@ class TrainStepBuilder:
                 prevent_cse=False,
             )
 
+            @jax.named_scope(scopes.HEAD_LOSS)
             def _chunked_ce(params, hidden, labels):
                 if fused_ce_tier_resolved is not None:
                     total, count = loss_fn.fused_sum_and_count(
@@ -504,7 +526,8 @@ class TrainStepBuilder:
                 predictions = model.apply(
                     params, samples, train=True, rngs={"dropout": dropout_rng} if dropout_rng is not None else None
                 )
-                return loss_fn(predictions, targets)
+                with jax.named_scope(scopes.HEAD_LOSS):
+                    return loss_fn(predictions, targets)
 
         # scheduled pipelining (1F1B): hand-rolled fwd/bwd with in-region loss replaces
         # value_and_grad through the in-module autodiff GPipe (the "gpipe" default)
@@ -595,38 +618,42 @@ class TrainStepBuilder:
                         g_acc = jax.lax.with_sharding_constraint(g_acc, zero_grad_shardings)
                     return (g_acc, l_acc + loss), None
 
-                if hierarchical_dcn:
-                    zero_grads = jax.tree.map(
-                        lambda p: jnp.zeros((dcn_degree, *p.shape), reduce_dtype), state.params
+                # the whole microbatch loop sits under one scope: what autodiff marks inside it
+                # (jvp, transpose) reads as a pass, the rest (the zero accumulator, the loop's own
+                # slicing, sum and cast, the division) as gradient accumulation
+                with jax.named_scope(scopes.GRAD_ACCUMULATE):
+                    if hierarchical_dcn:
+                        zero_grads = jax.tree.map(
+                            lambda p: jnp.zeros((dcn_degree, *p.shape), reduce_dtype), state.params
+                        )
+                        zero_grads = jax.lax.with_sharding_constraint(zero_grads, dcn_grad_shardings)
+                        loss_init = jax.lax.with_sharding_constraint(
+                            jnp.zeros((dcn_degree,), jnp.float32), dcn_loss_sharding
+                        )
+                    else:
+                        zero_grads = jax.tree.map(lambda p: jnp.zeros(p.shape, reduce_dtype), state.params)
+                        if zero_grad_shardings is not None:
+                            zero_grads = jax.lax.with_sharding_constraint(zero_grads, zero_grad_shardings)
+                        loss_init = 0.0
+                    (grads, loss_sum), _ = jax.lax.scan(
+                        micro, (zero_grads, loss_init), (jnp.arange(acc_steps), samples, targets)
                     )
-                    zero_grads = jax.lax.with_sharding_constraint(zero_grads, dcn_grad_shardings)
-                    loss_init = jax.lax.with_sharding_constraint(
-                        jnp.zeros((dcn_degree,), jnp.float32), dcn_loss_sharding
-                    )
-                else:
-                    zero_grads = jax.tree.map(lambda p: jnp.zeros(p.shape, reduce_dtype), state.params)
-                    if zero_grad_shardings is not None:
-                        zero_grads = jax.lax.with_sharding_constraint(zero_grads, zero_grad_shardings)
-                    loss_init = 0.0
-                (grads, loss_sum), _ = jax.lax.scan(
-                    micro, (zero_grads, loss_init), (jnp.arange(acc_steps), samples, targets)
-                )
-                if hierarchical_dcn:
-                    # THE hierarchical-reduction crossing point: the mean over the
-                    # dcn group dim reduces the fully-accumulated grads across
-                    # slices once per optimizer step, outside the scan body
-                    grads = jax.tree.map(
-                        lambda g, p: (g.mean(axis=0) / acc_steps).astype(p.dtype),
-                        grads,
-                        state.params,
-                    )
-                    grads = jax.lax.with_sharding_constraint(
-                        grads, zero_grad_shardings if zero_grad_shardings is not None else param_shardings
-                    )
-                    loss = loss_sum.mean() / acc_steps
-                else:
-                    grads = jax.tree.map(lambda g, p: (g / acc_steps).astype(p.dtype), grads, state.params)
-                    loss = loss_sum / acc_steps
+                    if hierarchical_dcn:
+                        # THE hierarchical-reduction crossing point: the mean over the
+                        # dcn group dim reduces the fully-accumulated grads across
+                        # slices once per optimizer step, outside the scan body
+                        grads = jax.tree.map(
+                            lambda g, p: (g.mean(axis=0) / acc_steps).astype(p.dtype),
+                            grads,
+                            state.params,
+                        )
+                        grads = jax.lax.with_sharding_constraint(
+                            grads, zero_grad_shardings if zero_grad_shardings is not None else param_shardings
+                        )
+                        loss = loss_sum.mean() / acc_steps
+                    else:
+                        grads = jax.tree.map(lambda g, p: (g / acc_steps).astype(p.dtype), grads, state.params)
+                        loss = loss_sum / acc_steps
 
                 if nan_grads_fault is not None:
                     poison = (
@@ -645,47 +672,52 @@ class TrainStepBuilder:
                     )
                     loss = loss + jnp.where(spike, float(loss_spike_fault.arg or 1e3), 0.0)
 
-                grad_norm = global_norm_by_mode(grads, norm_mode)
-                updates, new_opt_state = tx.update(grads, state.opt_state, state.params)
-                new_params = optax.apply_updates(state.params, updates)
-                if zero_grad_shardings is not None and param_shardings is not None:
-                    # re-materialize full (dp_replicate-replicated) params: the one
-                    # all-gather paired with the reduce-scatter above
-                    new_params = jax.lax.with_sharding_constraint(new_params, param_shardings)
+                with jax.named_scope(scopes.GRAD_NORM):
+                    grad_norm = global_norm_by_mode(grads, norm_mode)
+                with jax.named_scope(scopes.OPTIMIZER):
+                    updates, new_opt_state = tx.update(grads, state.opt_state, state.params)
+                with jax.named_scope(scopes.APPLY_UPDATES):
+                    new_params = optax.apply_updates(state.params, updates)
+                    if zero_grad_shardings is not None and param_shardings is not None:
+                        # re-materialize full (dp_replicate-replicated) params: the one
+                        # all-gather paired with the reduce-scatter above
+                        new_params = jax.lax.with_sharding_constraint(new_params, param_shardings)
                 if skip_on_anomaly:
                     # branch-free anomaly skip: a non-finite step keeps the old
                     # params/opt_state (jnp.where select, no lax.cond divergence
                     # across ranks) while the step counter still advances — so the
                     # data stream and sampler position stay aligned with a run that
                     # consumed the batch normally
-                    ok = jnp.isfinite(loss) & jnp.isfinite(grad_norm)
-                    new_params = jax.tree.map(
-                        lambda new, old: jnp.where(ok, new, old), new_params, state.params
-                    )
-                    new_opt_state = jax.tree.map(
-                        lambda new, old: jnp.where(ok, new, old), new_opt_state, state.opt_state
-                    )
-                new_state = AppState(params=new_params, opt_state=new_opt_state, step=state.step + 1)
-                metrics = {
-                    "loss": loss,
-                    "grad_norm": grad_norm,
-                    "lr": jnp.asarray(lr_fn(state.step), jnp.float32),
-                }
-                if skip_on_anomaly:
-                    metrics["skipped_step"] = (~ok).astype(jnp.int32)
-                if error_if_nonfinite:
-                    # consumed by Trainer at the next host sync (async equivalent of
-                    # torch clip_grad_norm_(error_if_nonfinite=True) raising inline)
-                    metrics["nonfinite_grads"] = (~jnp.isfinite(grad_norm)).astype(jnp.int32)
-                if with_grads:
-                    # debugging_enriched path: Trainer feeds these to DebugStatsLogger
-                    metrics["grads"] = grads
-                if stop_consensus:
-                    # the ONE consensus collective: max over every device's
-                    # locally-cast vote. The replicated scalar result is read
-                    # identically by all processes, so they exit the loop at the
-                    # same step boundary (resilience/coordination.py).
-                    metrics[BALLOT_KEY] = jnp.max(batch[BALLOT_KEY])
+                    with jax.named_scope(scopes.ANOMALY_SELECT):
+                        ok = jnp.isfinite(loss) & jnp.isfinite(grad_norm)
+                        new_params = jax.tree.map(
+                            lambda new, old: jnp.where(ok, new, old), new_params, state.params
+                        )
+                        new_opt_state = jax.tree.map(
+                            lambda new, old: jnp.where(ok, new, old), new_opt_state, state.opt_state
+                        )
+                with jax.named_scope(scopes.STEP_METRICS):
+                    new_state = AppState(params=new_params, opt_state=new_opt_state, step=state.step + 1)
+                    metrics = {
+                        "loss": loss,
+                        "grad_norm": grad_norm,
+                        "lr": jnp.asarray(lr_fn(state.step), jnp.float32),
+                    }
+                    if skip_on_anomaly:
+                        metrics["skipped_step"] = (~ok).astype(jnp.int32)
+                    if error_if_nonfinite:
+                        # consumed by Trainer at the next host sync (async equivalent of
+                        # torch clip_grad_norm_(error_if_nonfinite=True) raising inline)
+                        metrics["nonfinite_grads"] = (~jnp.isfinite(grad_norm)).astype(jnp.int32)
+                    if with_grads:
+                        # debugging_enriched path: Trainer feeds these to DebugStatsLogger
+                        metrics["grads"] = grads
+                    if stop_consensus:
+                        # the ONE consensus collective: max over every device's
+                        # locally-cast vote. The replicated scalar result is read
+                        # identically by all processes, so they exit the loop at the
+                        # same step boundary (resilience/coordination.py).
+                        metrics[BALLOT_KEY] = jnp.max(batch[BALLOT_KEY])
                 return new_state, metrics
 
             return train_step
@@ -702,7 +734,8 @@ class TrainStepBuilder:
 
             def eval_loss(params, samples, targets):
                 predictions = model.apply(params, samples, train=False)
-                return loss_fn(predictions, targets)
+                with jax.named_scope(scopes.HEAD_LOSS):
+                    return loss_fn(predictions, targets)
 
         if hierarchical_dcn:
             # same per-slice grouping as the train path: eval activations stay

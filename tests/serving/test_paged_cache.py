@@ -290,3 +290,25 @@ def test_randomized_allocator_fuzz_never_leaks():
     assert mirror.allocs == mirror.frees > 0
     assert ts.active_requests() == []
     assert ts.prefix_index_size == 0
+
+
+def test_pool_high_water_mark_counts_distinct_blocks_and_passes_the_audit():
+    """`peak_used` is the most distinct blocks in use at once since the last
+    `reset_peak()`: a fork adds a reference, not a block; the audit holds it
+    between what is in use and the pool's size."""
+    pool = BlockPool(6)
+    assert pool.peak_used == 0
+    a, b, c = pool.allocate(), pool.allocate(), pool.allocate()
+    pool.fork(a)
+    assert pool.peak_used == 3
+    pool.free(b)
+    pool.free(c)
+    assert pool.allocate() is not None
+    assert (pool.used_count, pool.peak_used) == (2, 3)
+    pool.check()
+    pool.reset_peak()
+    assert pool.peak_used == 2
+    pool.check()
+    pool.peak_used = 1  # below what is in use: the audit has to say so
+    with pytest.raises(AssertionError, match="high-water mark"):
+        pool.check()

@@ -566,3 +566,35 @@ def test_paged_mesh_decode_carries_named_shardings_and_matches(model, params, re
     assert results[rids[1]].tokens == ref([9, 8, 7, 6], 6, 0.8, 5)
     assert engine.stats()["decode_executables"] == 1
     assert "sharding" in engine.decode_lowered_text()
+
+
+def test_blocks_in_use_peak_restarts_with_run_and_queue_wait_is_on_the_result(model, params):
+    """What the first serving cell reads from the program (PERF.md section 7): the
+    high-water mark of KV blocks in use, per `run()` like the engine's clock, and each
+    request's queue wait on its `ServeResult`."""
+    engine = paged_engine(model, params, max_batch_slots=1, paged_block_size=4, paged_max_len=24)
+    first = engine.submit(PROMPT, 10, temperature=0.0, seed=0)  # the last token is never written: 7 + 9 positions, 4 blocks of 4
+    second = engine.submit([4, 2], 3, temperature=0.0, seed=1)  # waits for the one slot
+    results = engine.run()
+    stats = engine.stats()
+    assert stats["free_blocks"] == stats["num_blocks"] and stats["blocks_in_use_peak"] == 4
+    assert engine.metrics.gauge("serve_paged_blocks_in_use_peak", "").value() == 4
+    engine._table_state.check()  # the pool audit holds the mark between in-use and the pool's size
+    assert results[first].queue_wait_s < results[second].queue_wait_s
+    assert results[second].queue_wait_s == pytest.approx(results[first].finish_s - results[second].arrival_s, abs=0.05)
+    assert results[second].queue_wait_s <= results[second].ttft_s
+
+    third = engine.submit([9, 1, 1], 2, temperature=0.0, seed=2)  # 3 + 1 positions: 1 block
+    assert engine.run()[third].finish_reason == "budget"
+    assert engine.stats()["blocks_in_use_peak"] == 1, "a new run starts the mark again"
+    engine._table_state.check()
+
+
+def test_the_decode_program_names_its_operations_by_the_same_scopes(model, params):
+    """`ServingEngine.scope_table()`: the decode step runs the modules the train step
+    runs, so a trace of it reads by the same vocabulary (telemetry/scopes.py)."""
+    table = paged_engine(model, params, max_batch_slots=2).scope_table()
+    paths = set(table.values())
+    for scope in ("/attn_core/", "/rope/", "/residual/", "/wte/", "/blocks/block/mlp/", "/layer_carry/"):
+        assert any(scope in path for path in paths), scope
+    assert all(name and "/" not in name for name in table), "keys are instruction names"

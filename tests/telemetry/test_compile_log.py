@@ -1,0 +1,72 @@
+"""Compiles as the program's own counter (telemetry/compile_log.py): the active
+`Telemetry` counts every backend compile of the process, and each is one event on the
+sink with the function's name, whether the cache answered, and the step in flight."""
+
+import json
+
+import jax
+import jax.numpy as jnp
+import pytest
+
+from modalities_tpu.telemetry import Telemetry, get_active_telemetry, set_active_telemetry
+from modalities_tpu.telemetry.compile_log import BACKEND_COMPILE, CACHE_HIT, CompileLog
+
+
+def compile_something_new(salt: float):
+    return jax.jit(lambda x: jnp.sin(x) * salt + salt)(jnp.ones((3,), jnp.float32)).block_until_ready()
+
+
+def events_of(telemetry: Telemetry) -> list[dict]:
+    return [e for e in map(json.loads, telemetry.sink_path.read_text().splitlines()) if e.get("event") == "compile"]
+
+
+def test_log_records_function_seconds_and_cache_hit_and_stops_when_closed():
+    log = CompileLog()
+    try:
+        jax.monitoring.record_event(CACHE_HIT)
+        jax.monitoring.record_event_duration_secs(BACKEND_COMPILE, 0.25, fun_name="prefill")
+        jax.monitoring.record_event_duration_secs(BACKEND_COMPILE, 2.0, fun_name="decode_step")
+        jax.monitoring.record_event_duration_secs("/jax/something/else", 9.0)
+        assert log.compiles == [("prefill", 0.25, True), ("decode_step", 2.0, False)]
+        assert log.summary("prefill", "decode") == {
+            "prefill": {"count": 1, "cache_hits": 1, "first_s": 0.25, "total_s": 0.25},
+            "decode": {"count": 1, "cache_hits": 0, "first_s": 2.0, "total_s": 2.0},
+            "other": {"count": 0, "cache_hits": 0, "first_s": None, "total_s": 0.0},
+        }
+    finally:
+        log.close()
+    log.close()  # idempotent
+    jax.monitoring.record_event_duration_secs(BACKEND_COMPILE, 1.0, fun_name="late")
+    assert len(log.compiles) == 2
+
+
+def test_active_telemetry_counts_compiles_and_names_the_step_in_flight(tmp_path):
+    telemetry = Telemetry(output_folder_path=tmp_path, watchdog_deadline_s=0)
+    previous = set_active_telemetry(telemetry)
+    try:
+        telemetry.arm_watchdog(7)
+        compile_something_new(0.731)
+        telemetry.beat_watchdog(7)  # step 7 is done: the next compile belongs to step 8
+        jax.monitoring.record_event(CACHE_HIT)
+        jax.monitoring.record_event_duration_secs(BACKEND_COMPILE, 0.5, fun_name="train_step")
+    finally:
+        set_active_telemetry(previous)
+    jax.monitoring.record_event_duration_secs(BACKEND_COMPILE, 3.0, fun_name="after_deactivation")
+    compiles = telemetry.metrics.counter("compile_total", "")
+    seconds = telemetry.metrics.counter("compile_seconds_total", "")
+    assert compiles.value(cache_hit="true") == 1 and compiles.value(cache_hit="false") >= 1
+    assert seconds.value(cache_hit="true") == pytest.approx(0.5) and seconds.value(cache_hit="false") > 0
+    events = events_of(telemetry)
+    assert {e["step"] for e in events[:-1]} == {7} and any("lambda" in e["function"] for e in events[:-1])
+    assert events[-1] == {**events[-1], "function": "train_step", "cache_hit": True, "step": 8, "seconds": 0.5}
+    assert "after_deactivation" not in {e["function"] for e in events}
+    assert get_active_telemetry() is previous
+
+
+def test_a_disabled_telemetry_listens_to_nothing():
+    before = len(jax.monitoring.get_event_duration_listeners()) if hasattr(jax.monitoring, "get_event_duration_listeners") else None
+    quiet = Telemetry(enabled=False)
+    quiet.watch_compiles()
+    assert quiet._compile_log is None
+    if before is not None:
+        assert len(jax.monitoring.get_event_duration_listeners()) == before
