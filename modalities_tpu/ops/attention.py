@@ -21,20 +21,28 @@ _KV_AXES = ("batch", None, "kv_heads", None)
 def flash_attention_or_fallback(q, k, v, causal: bool = True, sm_scale: float | None = None):
     """q: [B,S,Hq,D], k/v: [B,S,Hkv,D] -> [B,S,Hq,D].
 
-    Block sizes are tunable via MODALITIES_TPU_FLASH_BLOCK_Q / _BLOCK_K. Default
-    1024 (stepped down automatically for shorter sequences): on a v5e, growing the
-    blocks 128 -> 1024 took a 1.3B GPT2 train step from 0.31 to 0.57 MFU — grid
-    overhead dominates the kernel at MXU-tile-sized blocks; 1024x1024 fp32 score
-    tiles still fit VMEM comfortably (4 MB).
+    Block sizes come from `env_flash_blocks`: MODALITIES_TPU_FLASH_BLOCK_Q / _BLOCK_K,
+    else the device's tuning table (1024 x 1024 on a v5e), stepped down automatically
+    for shorter sequences. What the driver's record holds for that choice is the
+    benchmark's cell `train-2p7b-4k` (S 4096, 32 q / 8 kv heads of 80; PERF.md, sections
+    5 and 6): the three kernels took 68.1 ms of a 340.3 ms step at 1024 x 1024 (ledger,
+    PR 24) and 50.7 of 322.0 once every score tile got only the work its place asks for
+    (PR 25, which also read 512 x 512 in the cell: 333.3 ms a step). 1024 x 1024 fp32
+    score tiles fit VMEM (4 MB each).
 
     Under a mesh the kernel runs per shard, split over batch and heads
     (parallel/sharding.per_shard)."""
     if not on_tpu():
         return jax.nn.dot_product_attention(q, k, v, is_causal=causal, scale=sm_scale)
-    from modalities_tpu.ops.pallas.flash_attention import env_flash_blocks, pallas_flash_attention
+    from modalities_tpu.ops.pallas.flash_attention import env_flash_blocks, pallas_flash_attention, tile_plan
     from modalities_tpu.parallel.sharding import per_shard
+    from modalities_tpu.telemetry import get_active_telemetry
 
     block_q, block_k = env_flash_blocks(q.shape[1], k.shape[1], dtype=q.dtype)
+    plan = {"seq_q": q.shape[1], "seq_k": k.shape[1], "block_q": block_q, "block_k": block_k, "causal": causal}
+    # runs while tracing: the operator sees once per shape how many score tiles a
+    # (batch, head) computes and which share takes the masked body; nothing per step
+    get_active_telemetry().emit_event_once("flash_tile_plan", {**plan, **tile_plan(**plan).counts()})
     kernel = functools.partial(
         pallas_flash_attention, causal=causal, sm_scale=sm_scale, block_q=block_q, block_k=block_k
     )

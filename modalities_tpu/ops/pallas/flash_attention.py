@@ -3,8 +3,7 @@
 gpt2_model.py:643-655).
 
 Design (FlashAttention-2 style, TPU-first):
-- forward: grid (B, Hq, Sq/BQ, Sk/BK) with the kv dimension innermost ("arbitrary"
-  semantics): k/v stream through VMEM one [BK, D] tile per step while fp32
+- forward: k/v stream through VMEM one [BK, D] tile per grid step while fp32
   accumulators (acc, m, l) persist in VMEM scratch — VMEM stays O(BQ*D + BK*D)
   regardless of sequence length; logsumexp is saved for the backward.
 - backward: two kernels with the same streaming structure — dq over q blocks
@@ -12,11 +11,22 @@ Design (FlashAttention-2 style, TPU-first):
   blockwise from the saved logsumexp (no S x S materialization anywhere). GQA folds
   the q-head group into the kv index map; dk/dv are accumulated per q-head and
   group-summed outside the kernel.
-- causal blocks above the diagonal are skipped via predicated bodies (@pl.when).
+- every score tile gets only the work its place asks for (PR 25). `tile_plan`
+  classifies the [BQ, BK] tiles at trace time from the static shapes and the three
+  kernels run on a grid (batch, head, pair) over the tiles that compute at all, looked
+  up in a scalar-prefetched table (`pltpu.PrefetchScalarGridSpec`; index maps read the
+  pair's q and kv tile from it, init and finish fire on a row's first and last pair):
+  a tile above the causal diagonal is no grid step and fetches nothing; a tile wholly
+  below it runs a body without iota, compare and select; a tile the diagonal crosses
+  is masked, and where it is square and on the diagonal (BQ == BK) only its lower
+  triangle is walked, column by column in sub-blocks of 256: 5/8 of its matmuls.
+  `causal=False` (ring attention's off-diagonal hops) is the same code over the whole
+  rectangle, every tile interior.
 - block sizes: this module's own defaults are 128 (the MXU tile), but the shipped
-  configuration is 1024x1024 via the ops/attention.py dispatch wrapper (1.8x faster
-  at 1.3B/seq-2048 on v5e — grid overhead dominates at tile-sized blocks), with
-  automatic step-down for short sequences; interpret mode keeps CPU tests exact.
+  configuration is 1024x1024 via the ops/attention.py dispatch wrapper
+  (`tuning_tables/v5e.json`), with automatic step-down for short sequences; interpret
+  mode keeps CPU tests exact. What the chip says of each choice is in PERF.md,
+  sections 5 and 6.
 - TPU layout: per-row statistics (lse, delta) carry a trailing singleton lane dim
   ([B, H, S, 1] arrays, [block_q, 1] in-kernel tiles) because Mosaic requires the
   last two block dims to tile (8, 128) or equal the array dims — a bare [S] row
@@ -28,98 +38,227 @@ from __future__ import annotations
 
 import functools
 import math
+from typing import NamedTuple
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
 NEG_INF = -1e30
 
+# ---------------------------------------------------------------- the tile plan
+
+# bits of a pair's flags, as the kernels read them from the prefetched table
+_FIRST, _LAST, _MASKED, _DIAGONAL = 1, 2, 4, 8
+# side of the squares an aligned diagonal tile is walked in (a module constant, not a
+# knob: tests shrink it to cross the diagonal at interpret-mode sizes)
+_DIAG_SUB_BLOCK = 256
+
+
+class TilePlan(NamedTuple):
+    """Which score tiles a call computes, in the order its kernels walk them.
+
+    `q_major` and `kv_major` are int32 [3, n] tables (q tile, kv tile, flags) over the
+    same n pairs: kv innermost for `fwd` and `bwd_dq`, q innermost for `bwd_dkv`.
+    Flags: _FIRST / _LAST pair of its row in that order (init / finish fire there),
+    _MASKED (the diagonal crosses the tile: whole-tile mask), _DIAGONAL (the tile is
+    square and sits on the diagonal: only its lower triangle is walked)."""
+
+    q_major: np.ndarray
+    kv_major: np.ndarray
+    computed: int  # pairs = grid steps of each kernel per (batch, head)
+    interior: int  # wholly at or below the diagonal: no mask
+    diagonal: int  # crossed by the diagonal: masked, or walked below it
+    skipped_steps: int  # grid steps that compute nothing: 0 (6 of 16 at S 4096 before PR 25)
+
+    def counts(self) -> dict[str, int]:
+        return {name: getattr(self, name) for name in ("computed", "interior", "diagonal", "skipped_steps")}
+
+
+@functools.lru_cache(maxsize=None)
+def tile_plan(seq_q: int, seq_k: int, block_q: int, block_k: int, causal: bool) -> TilePlan:
+    """Classify every [block_q, block_k] score tile from the static shapes. Causal
+    means q position i sees k positions <= i (both counted from 0). A tile above the
+    diagonal is no pair at all; the rest are interior or diagonal. A kv tile no q row
+    can see (seq_k > seq_q) keeps one fully masked pair so that its dk/dv are written."""
+    num_q, num_k = seq_q // block_q, seq_k // block_k
+    iq, jk = np.meshgrid(np.arange(num_q), np.arange(num_k), indexing="ij")
+    if causal:
+        needed = jk * block_k <= iq * block_q + block_q - 1
+        interior = iq * block_q >= jk * block_k + block_k - 1
+        needed[num_q - 1, ~needed.any(axis=0)] = True
+    else:
+        needed = interior = np.ones_like(iq, dtype=bool)
+    on_diagonal = needed & ~interior & (block_q == block_k) & (iq == jk)
+    kind = np.where(interior, 0, np.where(on_diagonal, _DIAGONAL, _MASKED))
+
+    def table(pairs, row):
+        """[3, n] for `pairs` ([n, 2], sorted with column `row` outermost): a row's
+        first and last pair are where that column changes."""
+        first = np.r_[True, pairs[1:, row] != pairs[:-1, row]]
+        flags = kind[pairs[:, 0], pairs[:, 1]] | first * _FIRST | np.r_[first[1:], True] * _LAST
+        out = np.stack([pairs[:, 0], pairs[:, 1], flags]).astype(np.int32)
+        out.setflags(write=False)  # the plan is cached: every caller gets these arrays
+        return out
+
+    pairs = np.argwhere(needed)  # sorted by q tile, then kv tile
+    computed, n_interior = len(pairs), int((needed & interior).sum())
+    return TilePlan(
+        table(pairs, 0), table(pairs[np.lexsort((pairs[:, 0], pairs[:, 1]))], 1),
+        computed, n_interior, computed - n_interior, 0,
+    )
+
+
+def _sub_block(block_q: int, block_k: int) -> int:
+    """Side of the squares an aligned diagonal tile is walked in."""
+    return _DIAG_SUB_BLOCK if block_q == block_k and block_q % _DIAG_SUB_BLOCK == 0 else block_q
+
+
+def _rectangles(cls: int, block_q: int, block_k: int):
+    """The static rectangles (row start, rows, col start, cols, masked) a tile of one
+    class is computed in. Interior and whole-tile masked: the tile itself. Diagonal:
+    column by column in sub-blocks, each given only the q rows from its own first row
+    down — the square on the diagonal, masked, and what lies below it, unmasked."""
+    sub = _sub_block(block_q, block_k)
+    if not cls & _DIAGONAL or sub == block_q:
+        return [(0, block_q, 0, block_k, cls != 0)]
+    out = []
+    for lo in range(0, block_q, sub):
+        out.append((lo, sub, lo, sub, True))
+        if lo + sub < block_q:
+            out.append((lo + sub, block_q - lo - sub, lo, sub, False))
+    return out
+
+
+def _by_class(classes, flags, offset, block_q, block_k, body):
+    """Run `body(rows, cols, mask_offset)` over the rectangles of this pair's class
+    (`mask_offset`: the rectangle's first q position less its first k position, None
+    where nothing is hidden). Only the classes the plan holds are traced at all."""
+    for cls in classes:
+        def run(cls=cls):
+            for r0, rows, c0, cols, masked in _rectangles(cls, block_q, block_k):
+                mask_offset = None if not masked else (0 if cls & _DIAGONAL else offset)
+                body(pl.ds(r0, rows), pl.ds(c0, cols), mask_offset)
+
+        if len(classes) == 1:
+            run()
+        else:
+            pl.when(flags & (_MASKED | _DIAGONAL) == cls)(run)
+
+
+def _pair(plan_ref, num_pairs, block_q, block_k):
+    """This grid step's flags, and how far its tile's first q position lies past its
+    first k position, from the prefetched plan (a [3, num_pairs] table laid out flat)."""
+    t = pl.program_id(2)
+    return plan_ref[2 * num_pairs + t], plan_ref[t] * block_q - plan_ref[num_pairs + t] * block_k
+
+
+def _keep(shape, offset):
+    """The causal mask of a rectangle whose first q position is `offset` past its first
+    k position (a static 0 on an aligned diagonal square, else a traced scalar)."""
+    row = jax.lax.broadcasted_iota(jnp.int32, shape, 0)
+    col = jax.lax.broadcasted_iota(jnp.int32, shape, 1)
+    return row >= col if isinstance(offset, int) and offset == 0 else row + offset >= col
+
+
+def _scores(q, k):
+    return jax.lax.dot_general(q, k, (((1,), (1,)), ((), ())), preferred_element_type=jnp.float32)
+
+
+def _masked_scores(q, k, mask_offset):
+    s = _scores(q, k)
+    return s if mask_offset is None else jnp.where(_keep(s.shape, mask_offset), s, NEG_INF)
+
 
 # --------------------------------------------------------------------------- fwd
 
 
-def _fwd_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref, acc_ref, m_ref, l_ref,
-                *, sm_scale, causal, block_q, block_k):
-    iq = pl.program_id(2)
-    jk = pl.program_id(3)
-    num_kv = pl.num_programs(3)
+def _stat_lanes(block_q: int, block_k: int) -> int:
+    """Lanes the forward's running max and sum are kept in: 128 (a vreg's) wherever
+    every rectangle's width is a multiple of it, which is every shape a TPU runs at
+    blocks of 128 and up; the widths' common divisor at interpret-mode sizes."""
+    return math.gcd(128, block_k, _sub_block(block_q, block_k))
 
-    @pl.when(jk == 0)
+
+def _across(x, width: int):
+    """A lane-replicated [R, W] statistic as [R, width]."""
+    lanes = x.shape[1]
+    if width <= lanes:
+        return x[:, :width]
+    return pltpu.repeat(x, width // lanes, 1) if width % lanes == 0 else jnp.broadcast_to(x[:, :1], (x.shape[0], width))
+
+
+def _fwd_kernel(plan_ref, q_ref, k_ref, v_ref, o_ref, lse_ref, acc_ref, m_ref, l_ref,
+                *, sm_scale, block_q, block_k, num_pairs, classes):
+    flags, offset = _pair(plan_ref, num_pairs, block_q, block_k)
+    lanes = m_ref.shape[1]
+
+    @pl.when(flags & _FIRST != 0)
     def _init():
         acc_ref[:] = jnp.zeros_like(acc_ref)
         m_ref[:] = jnp.full_like(m_ref, NEG_INF)
         l_ref[:] = jnp.zeros_like(l_ref)
 
-    # causal: blocks entirely above the diagonal contribute nothing
-    needed = jnp.logical_or(not causal, jk * block_k <= iq * block_q + block_q - 1)
-
-    @pl.when(needed)
-    def _compute():
-        q = q_ref[0, 0].astype(jnp.float32) * sm_scale  # [BQ, D]
-        k = k_ref[0, 0].astype(jnp.float32)  # [BK, D]
-        v = v_ref[0, 0].astype(jnp.float32)
-        s = jax.lax.dot_general(q, k, (((1,), (1,)), ((), ())), preferred_element_type=jnp.float32)
-        if causal:
-            q_pos = iq * block_q + jax.lax.broadcasted_iota(jnp.int32, (block_q, block_k), 0)
-            k_pos = jk * block_k + jax.lax.broadcasted_iota(jnp.int32, (block_q, block_k), 1)
-            s = jnp.where(q_pos >= k_pos, s, NEG_INF)
-        m_prev, l_prev = m_ref[:], l_ref[:]  # [BQ, 1] column stats
+    def rectangle(rows, cols, mask_offset):
+        q = q_ref[0, 0, rows, :].astype(jnp.float32) * sm_scale  # [R, D]
+        k = k_ref[0, 0, cols, :].astype(jnp.float32)  # [C, D]
+        v = v_ref[0, 0, cols, :].astype(jnp.float32)
+        s = _masked_scores(q, k, mask_offset)
+        # The running max is kept replicated over `lanes` lanes and the running sum as
+        # `lanes` partial sums, folded once at the end: [R, 1] columns cost a row of
+        # vregs an operation whatever the tile's width, which made a 1024 x 512 tile
+        # as dear as a 1024 x 1024 one and a walk in narrow rectangles a loss (PERF.md,
+        # section 6, PR 25).
+        m_prev = m_ref[rows, :]
         m_new = jnp.maximum(m_prev, s.max(axis=-1, keepdims=True))
         alpha = jnp.exp(m_prev - m_new)
-        p = jnp.exp(s - m_new)
-        l_ref[:] = l_prev * alpha + p.sum(axis=-1, keepdims=True)
-        acc_ref[:] = acc_ref[:] * alpha + jax.lax.dot_general(
+        p = jnp.exp(s - _across(m_new, s.shape[1]))
+        l_ref[rows, :] = l_ref[rows, :] * alpha + functools.reduce(
+            jnp.add, [p[:, c:c + lanes] for c in range(0, p.shape[1], lanes)]
+        )
+        acc_ref[rows, :] = acc_ref[rows, :] * _across(alpha, acc_ref.shape[1]) + jax.lax.dot_general(
             p, v, (((1,), (0,)), ((), ())), preferred_element_type=jnp.float32
         )
-        m_ref[:] = m_new
+        m_ref[rows, :] = m_new
 
-    @pl.when(jk == num_kv - 1)
+    _by_class(classes, flags, offset, block_q, block_k, rectangle)
+
+    @pl.when(flags & _LAST != 0)
     def _finish():
-        l_safe = jnp.maximum(l_ref[:], 1e-30)
+        l_safe = jnp.maximum(l_ref[:].sum(axis=-1, keepdims=True), 1e-30)
         o_ref[0, 0] = (acc_ref[:] / l_safe).astype(o_ref.dtype)
-        lse_ref[0, 0] = m_ref[:] + jnp.log(l_safe)
+        lse_ref[0, 0] = m_ref[:, :1] + jnp.log(l_safe)
 
 
 # ---------------------------------------------------------------------- bwd: dq
 
 
-def _bwd_dq_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref, dq_ref, dq_acc_ref,
-                   *, sm_scale, causal, block_q, block_k):
-    iq = pl.program_id(2)
-    jk = pl.program_id(3)
-    num_kv = pl.num_programs(3)
+def _bwd_dq_kernel(plan_ref, q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref, dq_ref, dq_acc_ref,
+                   *, sm_scale, block_q, block_k, num_pairs, classes):
+    flags, offset = _pair(plan_ref, num_pairs, block_q, block_k)
 
-    @pl.when(jk == 0)
+    @pl.when(flags & _FIRST != 0)
     def _init():
         dq_acc_ref[:] = jnp.zeros_like(dq_acc_ref)
 
-    needed = jnp.logical_or(not causal, jk * block_k <= iq * block_q + block_q - 1)
-
-    @pl.when(needed)
-    def _compute():
-        q = q_ref[0, 0].astype(jnp.float32)
-        do = do_ref[0, 0].astype(jnp.float32)
-        lse = lse_ref[0, 0]  # [BQ, 1]
-        delta = delta_ref[0, 0]  # [BQ, 1]
-        k = k_ref[0, 0].astype(jnp.float32)
-        v = v_ref[0, 0].astype(jnp.float32)
-        s = jax.lax.dot_general(
-            q * sm_scale, k, (((1,), (1,)), ((), ())), preferred_element_type=jnp.float32
-        )
-        if causal:
-            q_pos = iq * block_q + jax.lax.broadcasted_iota(jnp.int32, (block_q, block_k), 0)
-            k_pos = jk * block_k + jax.lax.broadcasted_iota(jnp.int32, (block_q, block_k), 1)
-            s = jnp.where(q_pos >= k_pos, s, NEG_INF)
-        p = jnp.exp(s - lse)
-        dp = jax.lax.dot_general(do, v, (((1,), (1,)), ((), ())), preferred_element_type=jnp.float32)
-        ds = p * (dp - delta) * sm_scale
-        dq_acc_ref[:] += jax.lax.dot_general(
+    def rectangle(rows, cols, mask_offset):
+        q = q_ref[0, 0, rows, :].astype(jnp.float32)
+        do = do_ref[0, 0, rows, :].astype(jnp.float32)
+        lse = lse_ref[0, 0, rows, :]  # [R, 1]
+        delta = delta_ref[0, 0, rows, :]  # [R, 1]
+        k = k_ref[0, 0, cols, :].astype(jnp.float32)
+        v = v_ref[0, 0, cols, :].astype(jnp.float32)
+        p = jnp.exp(_masked_scores(q * sm_scale, k, mask_offset) - lse)
+        ds = p * (_scores(do, v) - delta) * sm_scale
+        dq_acc_ref[rows, :] += jax.lax.dot_general(
             ds, k, (((1,), (0,)), ((), ())), preferred_element_type=jnp.float32
         )
 
-    @pl.when(jk == num_kv - 1)
+    _by_class(classes, flags, offset, block_q, block_k, rectangle)
+
+    @pl.when(flags & _LAST != 0)
     def _finish():
         dq_ref[0, 0] = dq_acc_ref[:].astype(dq_ref.dtype)
 
@@ -127,48 +266,69 @@ def _bwd_dq_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref, dq_ref, dq_a
 # -------------------------------------------------------------------- bwd: dkdv
 
 
-def _bwd_dkv_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref, dk_ref, dv_ref,
-                    dk_acc_ref, dv_acc_ref, *, sm_scale, causal, block_q, block_k):
-    jk = pl.program_id(2)
-    iq = pl.program_id(3)
-    num_q = pl.num_programs(3)
+def _bwd_dkv_kernel(plan_ref, q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref, dk_ref, dv_ref,
+                    dk_acc_ref, dv_acc_ref, *, sm_scale, block_q, block_k, num_pairs, classes):
+    flags, offset = _pair(plan_ref, num_pairs, block_q, block_k)
 
-    @pl.when(iq == 0)
+    @pl.when(flags & _FIRST != 0)
     def _init():
         dk_acc_ref[:] = jnp.zeros_like(dk_acc_ref)
         dv_acc_ref[:] = jnp.zeros_like(dv_acc_ref)
 
-    needed = jnp.logical_or(not causal, iq * block_q + block_q - 1 >= jk * block_k)
-
-    @pl.when(needed)
-    def _compute():
-        k = k_ref[0, 0].astype(jnp.float32)
-        v = v_ref[0, 0].astype(jnp.float32)
-        q = q_ref[0, 0].astype(jnp.float32)
-        do = do_ref[0, 0].astype(jnp.float32)
-        lse = lse_ref[0, 0]  # [BQ, 1]
-        delta = delta_ref[0, 0]  # [BQ, 1]
-        s = jax.lax.dot_general(
-            q * sm_scale, k, (((1,), (1,)), ((), ())), preferred_element_type=jnp.float32
-        )
-        if causal:
-            q_pos = iq * block_q + jax.lax.broadcasted_iota(jnp.int32, (block_q, block_k), 0)
-            k_pos = jk * block_k + jax.lax.broadcasted_iota(jnp.int32, (block_q, block_k), 1)
-            s = jnp.where(q_pos >= k_pos, s, NEG_INF)
-        p = jnp.exp(s - lse)
-        dv_acc_ref[:] += jax.lax.dot_general(
+    def rectangle(rows, cols, mask_offset):
+        k = k_ref[0, 0, cols, :].astype(jnp.float32)
+        v = v_ref[0, 0, cols, :].astype(jnp.float32)
+        q = q_ref[0, 0, rows, :].astype(jnp.float32)
+        do = do_ref[0, 0, rows, :].astype(jnp.float32)
+        lse = lse_ref[0, 0, rows, :]  # [R, 1]
+        delta = delta_ref[0, 0, rows, :]  # [R, 1]
+        p = jnp.exp(_masked_scores(q * sm_scale, k, mask_offset) - lse)
+        dv_acc_ref[cols, :] += jax.lax.dot_general(
             p, do, (((0,), (0,)), ((), ())), preferred_element_type=jnp.float32
         )
-        dp = jax.lax.dot_general(do, v, (((1,), (1,)), ((), ())), preferred_element_type=jnp.float32)
-        ds = p * (dp - delta) * sm_scale
-        dk_acc_ref[:] += jax.lax.dot_general(
+        ds = p * (_scores(do, v) - delta) * sm_scale
+        dk_acc_ref[cols, :] += jax.lax.dot_general(
             ds, q, (((0,), (0,)), ((), ())), preferred_element_type=jnp.float32
         )
 
-    @pl.when(iq == num_q - 1)
+    _by_class(classes, flags, offset, block_q, block_k, rectangle)
+
+    @pl.when(flags & _LAST != 0)
     def _finish():
         dk_ref[0, 0] = dk_acc_ref[:].astype(dk_ref.dtype)
         dv_ref[0, 0] = dv_acc_ref[:].astype(dv_ref.dtype)
+
+
+def _tiled_call(kernel, table, name, *, sm_scale, batch, num_heads, group, block_q, block_k, head_dim,
+                inputs, outputs, out_shape, scratch_shapes, interpret):
+    """One `pallas_call` of `kernel` over grid (batch, head, pair), each pair's tiles
+    looked up in `table` (a plan's int32 [3, n]: q tile, kv tile, flags), which goes in
+    flat as the one scalar-prefetched operand. `inputs` / `outputs` name each operand's
+    tiling: "q" (a [block_q, D] tile of q head h), "kv" (a [block_k, D] tile of kv head
+    h // group), "k_out" (a [block_k, D] tile per q head), "row" (a [block_q, 1] column)."""
+    n = table.shape[1]
+    specs = {
+        "q": pl.BlockSpec((1, 1, block_q, head_dim), lambda b, h, t, plan: (b, h, plan[t], 0)),
+        "row": pl.BlockSpec((1, 1, block_q, 1), lambda b, h, t, plan: (b, h, plan[t], 0)),
+        "kv": pl.BlockSpec((1, 1, block_k, head_dim), lambda b, h, t, plan: (b, h // group, plan[n + t], 0)),
+        "k_out": pl.BlockSpec((1, 1, block_k, head_dim), lambda b, h, t, plan: (b, h, plan[n + t], 0)),
+    }
+    classes = np.unique(table[2] & (_MASKED | _DIAGONAL)).tolist()
+    call = pl.pallas_call(
+        functools.partial(kernel, sm_scale=sm_scale, block_q=block_q, block_k=block_k, num_pairs=n, classes=classes),
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=1,
+            grid=(batch, num_heads, n),
+            in_specs=[specs[kind] for kind in inputs],
+            out_specs=[specs[kind] for kind in outputs],
+            scratch_shapes=scratch_shapes,
+        ),
+        out_shape=out_shape,
+        compiler_params=pltpu.CompilerParams(dimension_semantics=("parallel", "parallel", "arbitrary")),
+        interpret=interpret,
+        name=name,
+    )
+    return functools.partial(call, table.ravel())
 
 
 # ------------------------------------------------------------------- entry point
@@ -187,8 +347,8 @@ def env_flash_blocks(seq_q: int, seq_k: int, dtype="bfloat16") -> tuple[int, int
     """The (block_q, block_k) tuning knobs, shared by every kernel consumer
     (ops/attention.py dispatch, the ring tier). Precedence per knob:
     MODALITIES_TPU_FLASH_BLOCK_Q/_K env override > the per-device autotune table
-    (ops/pallas/autotune.py, consulted at trace time) > 1024 (see ops/attention.py
-    for the v5e tuning evidence) — then stepped down to divide the sequence. A
+    (ops/pallas/autotune.py, consulted at trace time) > 1024 (PERF.md section 6 has
+    the chip's readings) — then stepped down to divide the sequence. A
     malformed override raises (int()) — it must never silently demote the call to
     a fallback tier."""
     import os
@@ -227,32 +387,21 @@ def _flash_fwd(q, k, v, sm_scale, causal, block_q, block_k, interpret):
     num_kv_heads, seq_k = k.shape[1], k.shape[2]
     group = num_heads // num_kv_heads
 
-    kernel = functools.partial(
-        _fwd_kernel, sm_scale=sm_scale, causal=causal, block_q=block_q, block_k=block_k
-    )
-    out, lse = pl.pallas_call(
-        kernel,
-        grid=(batch, num_heads, seq_q // block_q, seq_k // block_k),
-        in_specs=[
-            pl.BlockSpec((1, 1, block_q, head_dim), lambda b, h, iq, jk: (b, h, iq, 0)),
-            pl.BlockSpec((1, 1, block_k, head_dim), lambda b, h, iq, jk: (b, h // group, jk, 0)),
-            pl.BlockSpec((1, 1, block_k, head_dim), lambda b, h, iq, jk: (b, h // group, jk, 0)),
-        ],
-        out_specs=[
-            pl.BlockSpec((1, 1, block_q, head_dim), lambda b, h, iq, jk: (b, h, iq, 0)),
-            pl.BlockSpec((1, 1, block_q, 1), lambda b, h, iq, jk: (b, h, iq, 0)),
-        ],
+    lanes = _stat_lanes(block_q, block_k)
+    out, lse = _tiled_call(
+        _fwd_kernel, tile_plan(seq_q, seq_k, block_q, block_k, causal).q_major, "flash_attention_fwd",
+        sm_scale=sm_scale, batch=batch, num_heads=num_heads, group=group, block_q=block_q, block_k=block_k, head_dim=head_dim,
+        inputs=("q", "kv", "kv"), outputs=("q", "row"),
         out_shape=[
             jax.ShapeDtypeStruct(q.shape, q.dtype),
             jax.ShapeDtypeStruct((batch, num_heads, seq_q, 1), jnp.float32),
         ],
         scratch_shapes=[
             pltpu.VMEM((block_q, head_dim), jnp.float32),
-            pltpu.VMEM((block_q, 1), jnp.float32),
-            pltpu.VMEM((block_q, 1), jnp.float32),
+            pltpu.VMEM((block_q, lanes), jnp.float32),
+            pltpu.VMEM((block_q, lanes), jnp.float32),
         ],
         interpret=interpret,
-        name="flash_attention_fwd",
     )(q, k, v)
     return out, (q, k, v, out, lse)
 
@@ -282,25 +431,16 @@ def flash_bwd_dq(q, k, v, do, lse, delta, *, causal, sm_scale, block_q, block_k,
     seq_k = k.shape[2]
     group = num_heads // k.shape[1]
 
-    return pl.pallas_call(
-        functools.partial(
-            _bwd_dq_kernel, sm_scale=sm_scale, causal=causal, block_q=block_q, block_k=block_k
-        ),
-        grid=(batch, num_heads, seq_q // block_q, seq_k // block_k),
-        in_specs=[
-            pl.BlockSpec((1, 1, block_q, head_dim), lambda b, h, iq, jk: (b, h, iq, 0)),
-            pl.BlockSpec((1, 1, block_k, head_dim), lambda b, h, iq, jk: (b, h // group, jk, 0)),
-            pl.BlockSpec((1, 1, block_k, head_dim), lambda b, h, iq, jk: (b, h // group, jk, 0)),
-            pl.BlockSpec((1, 1, block_q, head_dim), lambda b, h, iq, jk: (b, h, iq, 0)),
-            pl.BlockSpec((1, 1, block_q, 1), lambda b, h, iq, jk: (b, h, iq, 0)),
-            pl.BlockSpec((1, 1, block_q, 1), lambda b, h, iq, jk: (b, h, iq, 0)),
-        ],
-        out_specs=pl.BlockSpec((1, 1, block_q, head_dim), lambda b, h, iq, jk: (b, h, iq, 0)),
-        out_shape=jax.ShapeDtypeStruct(q.shape, q.dtype),
+    (dq,) = _tiled_call(
+        _bwd_dq_kernel, tile_plan(seq_q, seq_k, block_q, block_k, causal).q_major, "flash_attention_bwd_dq",
+        sm_scale=sm_scale, batch=batch, num_heads=num_heads, group=group, block_q=block_q, block_k=block_k, head_dim=head_dim,
+        inputs=("q", "kv", "kv", "q", "row", "row"), outputs=("q",),
+        out_shape=[jax.ShapeDtypeStruct(q.shape, q.dtype)],
         scratch_shapes=[pltpu.VMEM((block_q, head_dim), jnp.float32)],
         interpret=interpret,
-        name="flash_attention_bwd_dq",
     )(q, k, v, do, lse, delta)
+    return dq
+
 
 def flash_bwd_dkv(q, k, v, do, lse, delta, *, causal, sm_scale, block_q, block_k, interpret):
     """(dk, dv) for one (q, k, v) pairing given GLOBAL (lse, delta), GQA group-summed
@@ -311,23 +451,10 @@ def flash_bwd_dkv(q, k, v, do, lse, delta, *, causal, sm_scale, block_q, block_k
     group = num_heads // num_kv_heads
 
     # dk/dv per q-head (q blocks innermost), then summed over the GQA group
-    dk_h, dv_h = pl.pallas_call(
-        functools.partial(
-            _bwd_dkv_kernel, sm_scale=sm_scale, causal=causal, block_q=block_q, block_k=block_k
-        ),
-        grid=(batch, num_heads, seq_k // block_k, seq_q // block_q),
-        in_specs=[
-            pl.BlockSpec((1, 1, block_q, head_dim), lambda b, h, jk, iq: (b, h, iq, 0)),
-            pl.BlockSpec((1, 1, block_k, head_dim), lambda b, h, jk, iq: (b, h // group, jk, 0)),
-            pl.BlockSpec((1, 1, block_k, head_dim), lambda b, h, jk, iq: (b, h // group, jk, 0)),
-            pl.BlockSpec((1, 1, block_q, head_dim), lambda b, h, jk, iq: (b, h, iq, 0)),
-            pl.BlockSpec((1, 1, block_q, 1), lambda b, h, jk, iq: (b, h, iq, 0)),
-            pl.BlockSpec((1, 1, block_q, 1), lambda b, h, jk, iq: (b, h, iq, 0)),
-        ],
-        out_specs=[
-            pl.BlockSpec((1, 1, block_k, head_dim), lambda b, h, jk, iq: (b, h, jk, 0)),
-            pl.BlockSpec((1, 1, block_k, head_dim), lambda b, h, jk, iq: (b, h, jk, 0)),
-        ],
+    dk_h, dv_h = _tiled_call(
+        _bwd_dkv_kernel, tile_plan(seq_q, seq_k, block_q, block_k, causal).kv_major, "flash_attention_bwd_dkv",
+        sm_scale=sm_scale, batch=batch, num_heads=num_heads, group=group, block_q=block_q, block_k=block_k, head_dim=head_dim,
+        inputs=("q", "kv", "kv", "q", "row", "row"), outputs=("k_out", "k_out"),
         out_shape=[
             jax.ShapeDtypeStruct((batch, num_heads, seq_k, head_dim), q.dtype),
             jax.ShapeDtypeStruct((batch, num_heads, seq_k, head_dim), q.dtype),
@@ -337,7 +464,6 @@ def flash_bwd_dkv(q, k, v, do, lse, delta, *, causal, sm_scale, block_q, block_k
             pltpu.VMEM((block_k, head_dim), jnp.float32),
         ],
         interpret=interpret,
-        name="flash_attention_bwd_dkv",
     )(q, k, v, do, lse, delta)
 
     if group > 1:

@@ -85,6 +85,7 @@ class Telemetry:
         # `_in_flight` is the step or scheduler round the watchdog calls announce
         self._compile_log = None
         self._in_flight: Optional[int] = None
+        self._emitted_once: set[tuple] = set()
         self._last_bucket_seconds: dict[str, float] = {}
         # optional SLO engine (PR 15): judged objectives over self.metrics;
         # None (the default) keeps every publish path on the pre-SLO behavior
@@ -152,6 +153,16 @@ class Telemetry:
         if not self.enabled or self._sink is None:
             return
         self._sink.emit({"event": "resilience", "name": name, **(payload or {})})
+
+    def emit_event_once(self, name: str, payload: dict) -> None:
+        """`emit_event` for a fact that code finds while it is traced (a kernel's tile
+        plan for a shape): on this instance's sink once per distinct payload, however
+        often the shape is traced, and never per step."""
+        key = (name, tuple(sorted(payload.items())))
+        if self._sink is None or key in self._emitted_once:
+            return
+        self._emitted_once.add(key)
+        self.emit_event(name, payload)
 
     def emit_serve_trace(self, record: dict) -> None:
         """Write one per-request serving lifecycle record (`event:

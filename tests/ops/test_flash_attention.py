@@ -9,6 +9,7 @@ import numpy as np
 import pytest
 
 from modalities_tpu.models.gpt2.gpt2_model import manual_attention
+from modalities_tpu.ops.pallas import flash_attention as flash
 from modalities_tpu.ops.pallas.flash_attention import pallas_flash_attention
 
 
@@ -69,3 +70,102 @@ def test_non_causal():
     expected = jax.nn.dot_product_attention(q, k, v, is_causal=False)
     got = pallas_flash_attention(q, k, v, causal=False, block_q=8, block_k=8, interpret=True)
     np.testing.assert_allclose(np.asarray(got), np.asarray(expected), rtol=2e-5, atol=2e-5)
+
+
+# ------------------------------------------------------------------ the tile plan
+
+PLANS = {
+    # (seq_q, seq_k, block_q, block_k, causal): (computed, interior, diagonal)
+    "s4096_b1024": ((4096, 4096, 1024, 1024, True), (10, 6, 4)),
+    "s32768_b1024": ((32768, 32768, 1024, 1024, True), (528, 496, 32)),
+    "not_causal_is_the_rectangle": ((4096, 2048, 1024, 512, False), (16, 16, 0)),
+    "more_q_than_k": ((128, 64, 32, 32, True), (7, 5, 2)),
+    "more_k_than_q_keeps_one_masked_pair_a_kv_tile": ((64, 128, 32, 32, True), (5, 1, 4)),
+    "bq_over_bk_masks_whole_tiles": ((96, 96, 32, 16, True), (12, 6, 6)),
+    "bk_over_bq_masks_whole_tiles": ((96, 96, 16, 32, True), (12, 6, 6)),
+    "shorter_than_a_block": ((16, 16, 16, 16, True), (1, 0, 1)),
+}
+
+
+@pytest.mark.parametrize("case", sorted(PLANS))
+def test_tile_plan_classifies_every_tile_from_the_shapes(case):
+    (seq_q, seq_k, block_q, block_k, causal), (computed, interior, diagonal) = PLANS[case]
+    plan = flash.tile_plan(seq_q, seq_k, block_q, block_k, causal)
+    assert plan.counts() == {"computed": computed, "interior": interior, "diagonal": diagonal, "skipped_steps": 0}
+    q_pos, k_pos = np.arange(seq_q)[:, None], np.arange(seq_k)[None, :]
+    visible = (q_pos >= k_pos) if causal else np.ones((seq_q, seq_k), bool)
+    for table, row in ((plan.q_major, 0), (plan.kv_major, 1)):
+        assert table.dtype == np.int32 and table.shape == (3, computed)
+        covered = np.zeros_like(visible)
+        for t, (iq, jk, flags) in enumerate(table.T):
+            tile = visible[iq * block_q:(iq + 1) * block_q, jk * block_k:(jk + 1) * block_k]
+            masked = bool(flags & (flash._MASKED | flash._DIAGONAL))
+            assert masked == (not tile.all())  # the unmasked body only where nothing is hidden
+            if flags & flash._DIAGONAL:  # walked below the diagonal: square, and on it
+                assert block_q == block_k and iq == jk
+            covered[iq * block_q:(iq + 1) * block_q, jk * block_k:(jk + 1) * block_k] = True
+            # init and finish fire on the first and last pair of the accumulating kernel's row
+            first = t == 0 or table[row, t - 1] != table[row, t]
+            last = t == computed - 1 or table[row, t + 1] != table[row, t]
+            assert (bool(flags & flash._FIRST), bool(flags & flash._LAST)) == (first, last)
+        assert covered[visible].all()  # nothing visible is left out
+        assert len(set(table[row])) == (seq_k // block_k if row else seq_q // block_q)  # every output tile is written
+        assert (np.diff(table[row]) >= 0).all()  # and its pairs are contiguous
+    assert sorted(map(tuple, plan.q_major[:2].T)) == sorted(map(tuple, plan.kv_major[:2].T))
+
+
+def test_diagonal_tile_is_walked_in_five_eighths_of_its_squares():
+    rects = flash._rectangles(flash._DIAGONAL, 1024, 1024)
+    assert sum(rows * cols for _, rows, _, cols, _ in rects) == 1024 * 1024 * 5 // 8
+    assert sum(rows * cols for _, rows, _, cols, masked in rects if masked) == 4 * 256 * 256
+    visible = np.tril(np.ones((1024, 1024), bool))
+    for r0, rows, c0, cols, masked in rects:  # every visible score once; unmasked only where all are visible
+        assert masked or visible[r0:r0 + rows, c0:c0 + cols].all()
+        visible[r0:r0 + rows, c0:c0 + cols] = False
+    assert not visible.any()
+    assert flash._rectangles(0, 512, 256) == [(0, 512, 0, 256, False)]
+    assert flash._rectangles(flash._MASKED, 512, 256) == [(0, 512, 0, 256, True)]
+
+
+# ------------------------------------------- values and gradients, class by class
+
+
+def _oracle(q, k, v, causal):
+    group = q.shape[2] // k.shape[2]
+    k, v = jnp.repeat(k, group, axis=2), jnp.repeat(v, group, axis=2)
+    s = jnp.einsum("bqhd,bkhd->bhqk", q, k) / np.sqrt(q.shape[-1])
+    if causal:
+        s = jnp.where(jnp.arange(q.shape[1])[:, None] >= jnp.arange(k.shape[1])[None, :], s, -jnp.inf)
+    return jnp.einsum("bhqk,bkhd->bqhd", jax.nn.softmax(s, axis=-1), v)
+
+
+KERNEL_CASES = {
+    # (seq_q, seq_k, block_q, block_k, causal, head_dim): at least three tiles a side, GQA 4:1
+    "diagonal_walked_in_sub_blocks_d40": (96, 96, 32, 32, True, 40),
+    "diagonal_walked_in_sub_blocks_d80": (96, 96, 32, 32, True, 80),
+    "not_causal_d40": (96, 96, 32, 32, False, 40),
+    "whole_tile_mask_bq_over_bk_d40": (96, 96, 32, 16, True, 40),
+    "whole_tile_mask_bk_over_bq_d40": (96, 96, 16, 32, True, 40),
+    "more_k_than_q_d40": (96, 192, 32, 32, True, 40),
+    "more_q_than_k_not_causal_d80": (192, 96, 32, 32, False, 80),
+}
+
+
+@pytest.mark.parametrize("case", sorted(KERNEL_CASES))
+def test_values_and_gradients_match_oracle_in_every_tile_class(case, monkeypatch):
+    seq_q, seq_k, block_q, block_k, causal, head_dim = KERNEL_CASES[case]
+    monkeypatch.setattr(flash, "_DIAG_SUB_BLOCK", 8)  # four squares along a 32-wide diagonal tile
+    rng = jax.random.PRNGKey(11)
+    q = jax.random.normal(jax.random.fold_in(rng, 0), (2, seq_q, 4, head_dim))
+    k = jax.random.normal(jax.random.fold_in(rng, 1), (2, seq_k, 1, head_dim))
+    v = jax.random.normal(jax.random.fold_in(rng, 2), (2, seq_k, 1, head_dim))
+    w = jax.random.normal(jax.random.fold_in(rng, 3), q.shape)
+    kernel = functools.partial(
+        pallas_flash_attention, causal=causal, block_q=block_q, block_k=block_k, interpret=True
+    )
+    reference = manual_attention if causal and seq_q == seq_k else functools.partial(_oracle, causal=causal)
+    np.testing.assert_allclose(np.asarray(kernel(q, k, v)), np.asarray(reference(q, k, v)), rtol=2e-5, atol=2e-5)
+    got = jax.grad(lambda q, k, v: (kernel(q, k, v) * w).sum(), argnums=(0, 1, 2))(q, k, v)
+    want = jax.grad(lambda q, k, v: (reference(q, k, v) * w).sum(), argnums=(0, 1, 2))(q, k, v)
+    for g, e, name in zip(got, want, "qkv"):
+        np.testing.assert_allclose(np.asarray(g), np.asarray(e), rtol=5e-4, atol=5e-4, err_msg=f"d{name}")

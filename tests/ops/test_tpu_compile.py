@@ -16,7 +16,12 @@ import jax.numpy as jnp
 import pytest
 from jax.sharding import SingleDeviceSharding
 
-from modalities_tpu.ops.pallas.flash_attention import pallas_flash_attention
+from modalities_tpu.ops.pallas.flash_attention import (
+    flash_bwd_dkv,
+    flash_bwd_dq,
+    flash_fwd_out_lse,
+    pallas_flash_attention,
+)
 from modalities_tpu.ops.pallas.fused_ce import fused_ce_sum_and_count
 from modalities_tpu.ops.pallas.fused_rmsnorm import fused_rms_norm
 from modalities_tpu.ops.pallas.quant_matmul import quant_matmul
@@ -53,6 +58,20 @@ def _flash(heads_q, heads_kv, head_dim):
     return jax.grad(loss, argnums=(0, 1, 2)), (q, kv, kv), 3
 
 
+def _ring_hop_not_causal():
+    """An off-diagonal hop of ring attention (parallel/ring_attention.py) at
+    configs/config_7b_warmstart_32k.yaml's per-device shape: 32,768 over cp 4, 32 q and
+    8 kv heads of 128 over tp 8; the three kernels bare, on the whole rectangle."""
+    kw = dict(causal=False, sm_scale=128**-0.5, block_q=1024, block_k=1024, interpret=False)
+
+    def hop(q, k, v, do, delta):
+        out, lse = flash_fwd_out_lse(q, k, v, **kw)
+        return out, flash_bwd_dq(q, k, v, do, lse, delta, **kw), flash_bwd_dkv(q, k, v, do, lse, delta, **kw)
+
+    q, kv = ((1, 4, 8192, 128), BF16), ((1, 1, 8192, 128), BF16)
+    return hop, (q, kv, kv, q, ((1, 4, 8192, 1), F32)), 3
+
+
 def _fused_ce(n_embd, rows):
     def loss(hidden, head, labels):
         # the blocks tuning_tables/v5e.json ships; the kernel steps them down to VMEM
@@ -77,6 +96,7 @@ def _quant_matmul(m):
 CASES = {
     "flash_fwd_bwd_d128": _flash(16, 16, 128),
     "flash_fwd_bwd_d80_gqa_32_8": _flash(32, 8, 80),
+    "flash_ring_hop_not_causal_d128_gqa_4_1": _ring_hop_not_causal(),
     "fused_ce_fwd_bwd_e1536": _fused_ce(1536, SEQ),
     "fused_ce_fwd_bwd_e2560": _fused_ce(2560, 4 * SEQ),
     "fused_rmsnorm_fwd_bwd_e1536": _fused_rmsnorm(1536),

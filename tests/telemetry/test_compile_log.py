@@ -70,3 +70,37 @@ def test_a_disabled_telemetry_listens_to_nothing():
     assert quiet._compile_log is None
     if before is not None:
         assert len(jax.monitoring.get_event_duration_listeners()) == before
+
+
+def test_flash_tile_plan_is_one_event_per_traced_shape_and_none_per_step(tmp_path, monkeypatch):
+    """The attention dispatch says once, while tracing, which tiles its kernels will
+    compute (`flash_tile_plan`, beside `compile`); running the step says nothing more."""
+    import functools
+
+    import modalities_tpu.ops.attention as attention
+    import modalities_tpu.ops.pallas.flash_attention as flash
+
+    monkeypatch.setattr(attention, "on_tpu", lambda: True)
+    monkeypatch.setattr(
+        flash, "pallas_flash_attention", functools.partial(flash.pallas_flash_attention, interpret=True)
+    )
+    monkeypatch.setenv("MODALITIES_TPU_FLASH_BLOCK_Q", "16")
+    monkeypatch.setenv("MODALITIES_TPU_FLASH_BLOCK_K", "16")
+    telemetry = Telemetry(output_folder_path=tmp_path, watchdog_deadline_s=0)
+    previous = set_active_telemetry(telemetry)
+    try:
+        two_layers = jax.jit(lambda x: attention.flash_attention_or_fallback(
+            attention.flash_attention_or_fallback(x, x, x), x, x))  # one shape traced twice
+        for _ in range(3):  # three steps of one executable
+            two_layers(jnp.ones((1, 48, 2, 8), jnp.float32)).block_until_ready()
+        jax.jit(lambda x: attention.flash_attention_or_fallback(x, x, x, causal=False))(
+            jnp.ones((1, 32, 2, 8), jnp.float32)).block_until_ready()
+    finally:
+        set_active_telemetry(previous)
+    plans = [e for e in map(json.loads, telemetry.sink_path.read_text().splitlines()) if e.get("name") == "flash_tile_plan"]
+    assert [{k: v for k, v in e.items() if k not in ("event", "name", "rank")} for e in plans] == [
+        {"seq_q": 48, "seq_k": 48, "block_q": 16, "block_k": 16, "causal": True,
+         "computed": 6, "interior": 3, "diagonal": 3, "skipped_steps": 0},
+        {"seq_q": 32, "seq_k": 32, "block_q": 16, "block_k": 16, "causal": False,
+         "computed": 4, "interior": 4, "diagonal": 0, "skipped_steps": 0},
+    ]
