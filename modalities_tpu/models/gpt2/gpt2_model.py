@@ -36,6 +36,7 @@ from modalities_tpu.models.components.layer_norms import (
     NormSpec,
     build_norm,
 )
+from modalities_tpu.models.gpt2.ssm import MambaMixer, SSMConfig, SSMSpec, layer_kinds, layer_runs
 from modalities_tpu.models.model import NNModel
 from modalities_tpu.telemetry import scopes
 
@@ -126,6 +127,24 @@ class GPT2LLMConfig(BaseModel):
     # TPU only, "on" = always (interpret off-TPU), "off" = chunked-scan fallback.
     # MODALITIES_TPU_FUSED_CE overrides at trace time.
     lm_head_fused_ce: Literal["auto", "on", "off"] = "auto"
+    # A stack of two kinds of layer, by the two keys `model_type: jamba` publishes:
+    # layer i holds attention where i % attn_layer_period == attn_layer_offset and the
+    # state-space mixer of `ssm_config` (models/gpt2/ssm.py) elsewhere. Unset: attention
+    # in every layer.
+    attn_layer_period: Optional[Annotated[int, Field(strict=True, ge=1)]] = None
+    attn_layer_offset: Annotated[int, Field(strict=True, ge=0)] = 0
+    ssm_config: Optional[SSMConfig] = None
+
+    @model_validator(mode="after")
+    def check_layer_pattern(self) -> "GPT2LLMConfig":
+        if self.attn_layer_period is None:
+            if self.ssm_config is not None or self.attn_layer_offset:
+                raise ValueError("ssm_config and attn_layer_offset need attn_layer_period: without it every layer holds attention")
+        elif self.attn_layer_offset >= self.attn_layer_period:
+            raise ValueError("attn_layer_offset must be below attn_layer_period")
+        elif self.ssm_config is None:
+            raise ValueError("attn_layer_period puts the state-space mixer in the other layers: give ssm_config")
+        return self
 
     @model_validator(mode="after")
     def check_divisibility(self) -> "GPT2LLMConfig":
@@ -248,10 +267,27 @@ class GPT2ModelSpec:
     # quantized + float32 per-output-channel scale, dequant fused into the
     # matmul). Serving-only — the train step never sets this.
     quant_weights: str = "none"
+    # the mixer of every layer, "attn" or "ssm" (empty: attention everywhere), and the
+    # state-space mixer's sizes. Runs of equal kind are stacked and scanned one after
+    # another; one run is the dense decoder, with the tree and the program it always had
+    layer_kinds: tuple[str, ...] = ()
+    ssm: Optional[SSMSpec] = None
 
     @property
     def head_dim(self) -> int:
         return self.n_embd // self.n_head_q
+
+    @property
+    def kinds(self) -> tuple[str, ...]:
+        return self.layer_kinds or ("attn",) * self.n_layer
+
+    @property
+    def runs(self) -> tuple[tuple[str, int], ...]:
+        return layer_runs(self.kinds)
+
+    @property
+    def has_ssm(self) -> bool:
+        return "ssm" in self.layer_kinds
 
     def __hash__(self):
         # hash a subset of the fields __eq__ compares (never id()): value-equal specs
@@ -290,6 +326,8 @@ class GPT2ModelSpec:
                 self.compute_dtype,
                 self.debug_print_activations,
                 self.quant_weights,
+                self.layer_kinds,
+                self.ssm,
             )
         )
 
@@ -858,15 +896,20 @@ class GPT2Block(nn.Module):
     deterministic: bool = True
     decode: bool = False
     slot_spec: Optional[SlotDecodeSpec] = None
+    mixer: str = "attn"  # what sits in the mixer seat: "attn" or "ssm"
 
     @nn.compact
     def __call__(self, x, slot=None, positions=None):
         spec = self.spec
         x = with_logical_constraint(x, ("batch", "seq", "embed"), spec)
         h = build_norm(spec.attn_norm, "attention_norm", dtype=x.dtype)(x)
-        a = CausalSelfAttention(
-            spec, self.deterministic, self.decode, slot_spec=self.slot_spec, name="attn"
-        )(h, slot, positions)
+        if self.mixer == "ssm":
+            a = MambaMixer(spec, name=scopes.SSM)(h)
+            a = nn.Dropout(rate=spec.dropout)(a, deterministic=self.deterministic or spec.dropout == 0.0)
+        else:
+            a = CausalSelfAttention(
+                spec, self.deterministic, self.decode, slot_spec=self.slot_spec, name="attn"
+            )(h, slot, positions)
         with jax.named_scope(scopes.RESIDUAL):
             x = x + a
         h2 = build_norm(spec.ffn_norm, "ffn_norm", dtype=x.dtype)(x)
@@ -935,6 +978,7 @@ class _BlockScanBody(nn.Module):
     spec: GPT2ModelSpec
     deterministic: bool = True
     decode: bool = False
+    mixer: str = "attn"
 
     @nn.compact
     def __call__(self, carry, _):
@@ -950,8 +994,32 @@ class _BlockScanBody(nn.Module):
                     "use ac_freq > 1, or use ac_freq=1 / 'full'."
                 )
             block_cls = _remat_block_cls(spec)
-        x = block_cls(spec, self.deterministic, self.decode, name="block")(carry)
+        x = block_cls(spec, self.deterministic, self.decode, mixer=self.mixer, name="block")(carry)
         return x, None
+
+
+class _LayerRun(nn.Module):
+    """One run of `length` equal layers of a stack that holds more than one kind: the
+    same scan over stacked blocks as the dense decoder's, under the run's own name
+    (`run_<i>/blocks/block/...`)."""
+
+    spec: GPT2ModelSpec
+    deterministic: bool
+    mixer: str
+    length: int
+
+    @nn.compact
+    def __call__(self, x):
+        scanned = nn.scan(
+            _BlockScanBody,
+            variable_axes={"params": 0},
+            split_rngs={"params": True, "dropout": True},
+            length=self.length,
+            metadata_params={nn.meta.PARTITION_NAME: "layers"},
+        )(self.spec, self.deterministic, False, self.mixer, name="blocks")
+        with jax.named_scope(scopes.LAYER_CARRY):
+            x, _ = scanned(x, None)
+        return x
 
 
 class _SlotBlockScanBody(nn.Module):
@@ -971,6 +1039,13 @@ class _SlotBlockScanBody(nn.Module):
             self.spec, self.deterministic, False, slot_spec=self.slot_spec, name="block"
         )(x, slot, positions)
         return (x, slot, positions), None
+
+
+_NO_RECURRENT_STATE_CACHE = (
+    "this model has state-space layers (attn_layer_period), and serving them needs a recurrent-state cache "
+    "(the convolution's last taps and the scan's state [d_inner, d_state] for every sequence and layer, beside "
+    "the attention layers' KV cache), which serving/ does not have: it trains, it does not decode"
+)
 
 
 class GPT2Module(nn.Module):
@@ -1040,7 +1115,17 @@ class GPT2Module(nn.Module):
         x = nn.Dropout(rate=spec.dropout)(x, deterministic=self.deterministic or spec.dropout == 0.0)
         x = with_logical_constraint(x, ("batch", "seq", "embed"))
 
-        if spec.scan_layers and self.slot_spec is not None:
+        if spec.has_ssm and (self.decode or self.slot_spec is not None):
+            raise NotImplementedError(_NO_RECURRENT_STATE_CACHE)
+        if spec.scan_layers and len(spec.runs) > 1:
+            if spec.pipeline_axis is not None:
+                raise NotImplementedError(
+                    "pipeline parallelism splits ONE stack of equal layers over its stages; a model whose "
+                    "layers are of more than one kind (attn_layer_period) has several. Run it without a pp axis."
+                )
+            for i, (kind, length) in enumerate(spec.runs):
+                x = _LayerRun(spec, self.deterministic, kind, length, name=f"run_{i}")(x)
+        elif spec.scan_layers and self.slot_spec is not None:
             # serving slot-cache path: slot/positions are traced values and must ride
             # the scan carry; same "blocks"/"block" naming so trained params apply
             scanned = nn.scan(
@@ -1059,7 +1144,7 @@ class GPT2Module(nn.Module):
                 split_rngs={"params": True, "dropout": True},
                 length=spec.n_layer,
                 metadata_params={nn.meta.PARTITION_NAME: "layers"},
-            )(spec, self.deterministic, self.decode, name="blocks")
+            )(spec, self.deterministic, self.decode, spec.kinds[0], name="blocks")
             # decode never pipelines: generation is single-host and must go through
             # the scanned path so the per-layer KV caches are read/written
             if spec.pipeline_axis is not None and not self.is_initializing() and not self.decode:
@@ -1109,7 +1194,7 @@ class GPT2Module(nn.Module):
                     else GPT2Block
                 )
                 x = block_cls(
-                    spec, self.deterministic, self.decode, slot_spec=self.slot_spec, name=f"h_{i}"
+                    spec, self.deterministic, self.decode, slot_spec=self.slot_spec, mixer=spec.kinds[i], name=f"h_{i}"
                 )(x, slot, positions)
 
         x = build_norm(spec.lm_head_norm, "lm_head_norm")(x)
@@ -1169,6 +1254,9 @@ class GPT2LLM(NNModel):
         enforce_swiglu_hidden_dim_multiple_of: int = 256,
         lm_head_chunk_size: Optional[int] = None,
         lm_head_fused_ce: str = "auto",
+        attn_layer_period: Optional[int] = None,
+        attn_layer_offset: int = 0,
+        ssm_config: Optional[SSMConfig | dict] = None,
     ):
         super().__init__(
             sample_key=sample_key,
@@ -1180,6 +1268,8 @@ class GPT2LLM(NNModel):
                 "linear": [r".*(q_attn|k_attn|v_attn|c_proj|c_fc|W|V|W_2|lm_head).*kernel.*"],
                 "embedding": [r".*(wte|wpe).*"],
                 "layernorm": [r".*(norm).*"],
+                # what Mamba marks `_no_weight_decay` in the state-space mixer, and its biases
+                "ssm": [r".*/ssm/(A_log|D)$", r".*/ssm/.*bias$"],
             },
         )
         if n_head_q % n_head_kv != 0:
@@ -1231,6 +1321,8 @@ class GPT2LLM(NNModel):
             ),
             lm_head_chunk_size=lm_head_chunk_size,
             lm_head_fused_ce=lm_head_fused_ce,
+            layer_kinds=layer_kinds(n_layer, attn_layer_period, attn_layer_offset) if attn_layer_period else (),
+            ssm=SSMSpec.from_config(ssm_config, n_embd) if ssm_config is not None else None,
         )
         self.sequence_length = sequence_length
         self.vocab_size = vocab_size
@@ -1288,9 +1380,14 @@ class GPT2LLM(NNModel):
         return head["kernel"].T
 
     # ----------------------------------------------------------- KV-cache decoding
+    def _refuse_without_recurrent_state_cache(self) -> None:
+        if self.config_spec.has_ssm:
+            raise NotImplementedError(_NO_RECURRENT_STATE_CACHE)
+
     def init_decode_cache(self, params, batch_size: int):
         """Zeroed per-layer KV caches + position counters for `decode_step`. Shapes
         come from an abstract init (eval_shape) — no parameter materialization."""
+        self._refuse_without_recurrent_state_cache()
         module = GPT2Module(self.config_spec, deterministic=True, decode=True)
         dummy = jnp.zeros((batch_size, 1), dtype=jnp.int32)
         abstract = jax.eval_shape(lambda: module.init(jax.random.PRNGKey(0), dummy))
@@ -1327,6 +1424,7 @@ class GPT2LLM(NNModel):
     def init_slot_cache(self, params, max_batch_slots: int, cache_capacity: Optional[int] = None):
         """Zeroed [slots, capacity] ring KV cache for `prefill_slot`/`decode_slots`.
         Shapes via abstract init (eval_shape) — no materialization."""
+        self._refuse_without_recurrent_state_cache()
         cap = self.config_spec.sequence_length if cache_capacity is None else int(cache_capacity)
         if (
             cap > self.config_spec.sequence_length
@@ -1406,6 +1504,7 @@ class GPT2LLM(NNModel):
         leading layers axis added by the scan). Shapes via abstract init.
         kv_quant="int8" stores int8 pools plus float32 scale pools
         ([num_blocks, block_size, Hkv, 1]) alongside in the same tree."""
+        self._refuse_without_recurrent_state_cache()
         nb, bs = int(num_blocks), int(block_size)
         if nb < 1 or bs < 1:
             raise ValueError(f"paged cache needs num_blocks >= 1 and block_size >= 1, got {nb}/{bs}")

@@ -23,9 +23,11 @@ from modalities_tpu.nn.model_initialization.initialization_if import ModelInitia
 # regex groups per supported model type (reference parameter_name_filters.py)
 NAMED_PARAMETER_INIT_GROUPS = {
     "gpt2": {
-        "weighted_layers": [r".*(q_attn|k_attn|v_attn|c_proj|c_fc|W|V|W_2)/kernel.*", r".*wte.*", r".*wpe.*"],
+        # the state-space mixer's three large kernels among them; its convolution, dt_proj, A_log and D
+        # keep the initial values Mamba publishes (models/gpt2/ssm.py)
+        "weighted_layers": [r".*(q_attn|k_attn|v_attn|c_proj|c_fc|W|V|W_2|in_proj|x_proj|out_proj)/kernel.*", r".*wte.*", r".*wpe.*"],
         "embedding_layers": [r".*(wte|wpe).*"],
-        "projection_layers": [r".*(c_proj|W_2)/kernel.*"],
+        "projection_layers": [r".*(c_proj|W_2|out_proj)/kernel.*"],
         "norm_layers": [r".*(norm|scale).*"],
     },
     "coca": {

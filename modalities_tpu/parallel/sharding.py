@@ -97,6 +97,17 @@ def logical_to_mesh_spec(logical_axes, rules: LogicalRules) -> P:
     return P(*spec)
 
 
+def fit_spec_to_shape(spec: P, shape: tuple, mesh: Mesh) -> P:
+    """`spec` without the mesh axes that do not divide the dim they would split: that
+    dim is replicated instead. One key/value head under tp 2 (multi-query attention, 20
+    query heads on 1) cannot be split; the rules say where an axis may go, not that it fits."""
+    fitted = []
+    for dim, entry in zip(shape, tuple(spec) + (None,) * (len(shape) - len(spec))):
+        names = () if entry is None else entry if isinstance(entry, tuple) else (entry,)
+        fitted.append(entry if dim % int(np.prod([mesh.shape[n] for n in names], dtype=np.int64)) == 0 else None)
+    return P(*fitted)
+
+
 def params_shardings(abstract_params, rules: LogicalRules, mesh: Mesh):
     """NamedShardings for a pytree of flax Partitioned leaves (from module.init metadata)."""
     import flax

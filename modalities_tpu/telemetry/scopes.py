@@ -39,10 +39,18 @@ Model (`models/gpt2/gpt2_model.py`), beside the names Flax gives its modules:
                                         reaches (the scan's own body), readers take the path
                                         `GPT2Module)/while/body` without `blocks/` for the same bucket
 
+State-space mixer (`models/gpt2/ssm.py`), under the module name `ssm` that Flax gives it in a block's mixer seat:
+
+    SSM_CONV          conv              the causal depthwise convolution (the name of its module)
+    SSM_SCAN          scan              the recurrence (`ops/selective_scan.py`) and its backward pass, nothing else
+    SSM_GATE          gate              the skip `D * x` and the gate `silu(z)` on the scan's output
+
 Module names Flax gives, part of the vocabulary as they are (`flax_profile` puts them
 on the stack): `GPT2Module`, `blocks/block` (`h_<i>` when the layers are not scanned),
 `attn/{q_attn,k_attn,v_attn,c_proj}`, `mlp/{W,V,W_2,c_fc,c_proj}`, `attention_norm`,
-`ffn_norm`, `lm_head_norm`, `lm_head`. Kernels keep the `name=` of their Pallas call:
+`ffn_norm`, `lm_head_norm`, `lm_head`; a model whose layers are of more than one kind
+puts `run_<i>` before `blocks/block` (one scan a run of equal layers), and the state-space
+mixer's projections are `ssm/{in_proj,x_proj,dt_proj,out_proj}` with `ssm/{dt_norm,b_norm,c_norm}`. Kernels keep the `name=` of their Pallas call:
 `flash_attention_{fwd,bwd_dq,bwd_dkv}`, `fused_ce_{fwd,bwd_dh,bwd_dw}`,
 `fused_rmsnorm_{fwd,bwd}`; the instruction of a call is named by it, and metrics select
 by that name.
@@ -68,8 +76,14 @@ ATTN_CORE = "attn_core"
 RESIDUAL = "residual"
 LAYER_CARRY = "layer_carry"
 
+SSM = "ssm"  # the mixer's module name in the block's seat
+SSM_CONV = "conv"
+SSM_SCAN = "scan"
+SSM_GATE = "gate"
+
 UPDATE_SCOPES = (GRAD_ACCUMULATE, GRAD_NORM, CLIP, OPTIMIZER, APPLY_UPDATES, ANOMALY_SELECT, STEP_METRICS)
 MODEL_SCOPES = (WTE, ROPE, ATTN_CORE, RESIDUAL, LAYER_CARRY)
+SSM_SCOPES = (SSM_CONV, SSM_SCAN, SSM_GATE)  # on the step only where a layer holds the state-space mixer
 
 # what a path holds beside scopes: the jit wrapper, the plumbing of loops, calls and branches
 _PLUMBING = frozenset(("while", "body", "cond", "closed_call", "checkpoint"))

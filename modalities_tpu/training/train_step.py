@@ -27,6 +27,7 @@ from modalities_tpu.models.model import NNModel
 from modalities_tpu.parallel.sharding import (
     batch_sharding,
     default_logical_axis_rules,
+    fit_spec_to_shape,
     logical_to_mesh_spec,
     replicated,
     zero_params_shardings,
@@ -263,11 +264,11 @@ class TrainStepBuilder:
             mesh = mesh_handle.mesh
             from jax.sharding import NamedSharding, PartitionSpec as P
 
-            def to_sharding(spec):
-                return NamedSharding(mesh, logical_to_mesh_spec(tuple(spec), self.rules))
+            def to_sharding(spec, leaf):
+                return NamedSharding(mesh, fit_spec_to_shape(logical_to_mesh_spec(tuple(spec), self.rules), leaf.shape, mesh))
 
             param_shardings = jax.tree.map(
-                to_sharding, logical_specs, is_leaf=lambda x: isinstance(x, P)
+                to_sharding, logical_specs, _unbox(boxed_abstract), is_leaf=lambda x: isinstance(x, P)
             )
             replicated_sharding = replicated(mesh_handle)
             data_sharding = batch_sharding(mesh_handle)

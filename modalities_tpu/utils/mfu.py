@@ -48,7 +48,10 @@ class MFUCalculatorIF(ABC):
 
 
 class GPT2MFUCalculator(MFUCalculatorIF):
-    """MFU = tokens/s * (6N + 12*L*s*h) / (world * peak) (reference :150-197)."""
+    """MFU = tokens/s * (6N + 12*L*s*h) / (world * peak) (reference :150-197), with L the
+    layers that hold attention: all of them in the dense decoder; in a model whose layers
+    are of two kinds (`attn_layer_period`), read off the wrapped model's spec, those that
+    are not state-space layers (whose scan is elementwise work and adds no `s*h` term)."""
 
     def __init__(
         self,
@@ -70,10 +73,12 @@ class GPT2MFUCalculator(MFUCalculatorIF):
         if num_parameters is None and wrapped_model is not None:
             num_parameters = _count_params(wrapped_model)
         self.num_parameters = num_parameters or 0
+        kinds = getattr(getattr(model_parts if model_parts is not None else wrapped_model, "config_spec", None), "layer_kinds", ())
+        self.n_attention_layer = kinds.count("attn") if kinds else n_layer
         self._peak = get_peak_flops()
 
     def compute(self, tokens_per_second: float) -> float:
-        flops_per_token = 6 * self.num_parameters + 12 * self.n_layer * self.sequence_length * self.n_embd
+        flops_per_token = 6 * self.num_parameters + 12 * self.n_attention_layer * self.sequence_length * self.n_embd
         return tokens_per_second * flops_per_token / (self.world_size * self._peak)
 
 

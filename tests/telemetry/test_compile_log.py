@@ -104,3 +104,29 @@ def test_flash_tile_plan_is_one_event_per_traced_shape_and_none_per_step(tmp_pat
         {"seq_q": 32, "seq_k": 32, "block_q": 16, "block_k": 16, "causal": False,
          "computed": 4, "interior": 4, "diagonal": 0, "skipped_steps": 0},
     ]
+
+
+def test_ssm_scan_plan_is_one_event_per_traced_shape_and_none_per_step(tmp_path, monkeypatch):
+    """The state-space mixer says once, while tracing, how its scan walks the sequence
+    (`ssm_scan_plan`): the chunks, the state carried, what the backward pass holds of a
+    chunk; two layers of one shape and three steps of one executable say it once."""
+    from tests.models.test_hybrid_ssm import HYBRID
+
+    from modalities_tpu.models.gpt2.gpt2_model import GPT2LLM
+
+    monkeypatch.setattr("modalities_tpu.ops.selective_scan.CHUNK", 24)
+    model = GPT2LLM(**HYBRID)
+    telemetry = Telemetry(output_folder_path=tmp_path, watchdog_deadline_s=0)
+    previous = set_active_telemetry(telemetry)
+    try:
+        params = jax.jit(model.init_params)(jax.random.PRNGKey(0))  # the initializer's dummy of 8 tokens is a shape too
+        apply = jax.jit(lambda p, t: model.apply(p, {"input_ids": t})["logits"])
+        for _ in range(3):
+            apply(params, jnp.zeros((2, 64), jnp.int32)).block_until_ready()
+        apply(params, jnp.zeros((1, 40), jnp.int32)).block_until_ready()
+    finally:
+        set_active_telemetry(previous)
+    plans = [e for e in map(json.loads, telemetry.sink_path.read_text().splitlines()) if e.get("name") == "ssm_scan_plan"]
+    assert [(e["batch"], e["seq"], e["chunk"], e["chunks"]) for e in plans] == [(1, 8, 8, 1), (2, 64, 24, 3), (1, 40, 24, 2)]
+    assert plans[1] == {**plans[1], "d_inner": 256, "d_state": 8, "state_bytes_carried": 4 * 2 * 256 * 8,
+                        "boundary_state_bytes": 3 * 4 * 2 * 256 * 8, "backward_bytes_per_chunk": 24 * 4 * 2 * 256 * 8}

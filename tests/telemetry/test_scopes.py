@@ -116,6 +116,80 @@ def test_the_head_reads_as_a_pass_of_head_loss_and_the_blocks_as_their_modules(h
         assert any(f"transpose(jvp(GPT2Module))/layer_carry/while/body/closed_call/blocks/block/{module}/" in n for n in names), module
 
 
+# ------------------------------------------------------------------ (a') closure, for a stack of two kinds of layer
+
+
+def compile_toy_hybrid_train_step(tmp: Path) -> str:
+    """The benchmark's hybrid cell at toy size (tests/benchmark/toy_hybrid.py: state-space
+    layers round one attention layer, every block rematerialized), built as its mode
+    builds it: the optimized HLO text of its train step."""
+    from benchmark.manifest import load_cell
+    from benchmark.weights_hybrid import HybridShape
+    from tests.benchmark.toy_hybrid import CELL as HYBRID_CELL, make_toy_hybrid_root
+
+    root = make_toy_hybrid_root(tmp)
+    cell = load_cell(HYBRID_CELL, root)
+    mode = cell.module("modes", cell.mode)
+    raw = yaml.safe_load(cell.yaml_path.read_text())
+    shape = HybridShape.from_yaml(raw)
+    profile = raw["settings"]["step_profile"]
+    scratch = root / ".bench_scratch" / cell.name
+    (scratch / "data").mkdir(parents=True)
+    cell.module("traffic", cell.traffic["generator"]).generate(
+        cell.traffic, 1, scratch / "data" / "train.pbin", vocab_size=shape.vocab_size,
+        sequence_length=int(profile["sequence_length"]))
+    started_in = os.getcwd()
+    try:
+        _, fns = mode.build_program(cell, 1, scratch, shape)
+    finally:
+        os.chdir(started_in)
+    keys = raw["settings"]["referencing_keys"]
+    tokens = np.zeros((int(profile["local_train_micro_batch_size"]), int(profile["sequence_length"])), np.int32)
+    host = {"samples": {keys["sample_key"]: tokens[None]}, "targets": {keys["target_key"]: tokens[None]}}
+    return fns.lower_train_step(fns.put_batch(host, has_acc_dim=True)).compile().as_text()
+
+
+@pytest.fixture(scope="module")
+def hybrid_hlo(tmp_path_factory) -> str:
+    return compile_toy_hybrid_train_step(tmp_path_factory.mktemp("scoped_hybrid"))
+
+
+@pytest.fixture(scope="module")
+def hybrid_rules() -> dict:
+    raw = json.loads((REPO / "benchmark" / "scopes" / "train_hybrid.json").read_text())
+    return {name: [(re.compile(pattern), bucket) for pattern, bucket in raw[name]] for name in LISTS}
+
+
+@pytest.mark.parametrize("which", LISTS)
+def test_every_operation_of_the_toy_hybrid_step_falls_into_a_bucket(hybrid_hlo, hybrid_rules, which):
+    table = scope_table(hybrid_hlo)
+    assert len(table) > 100
+    paths = set(table.values()) | every_op_name(hybrid_hlo)
+    left = {path for path in paths if bucket_of(path, hybrid_rules[which]) == UNATTRIBUTED}
+    assert not left, f"no rule of the list {which!r} takes {sorted(left)[:5]}"
+    if which == "component":
+        found = {bucket_of(path, hybrid_rules[which]) for path in paths}
+        assert {"ssm_scan", "ssm", "attn", "mlp", "norms", "residual", "head_loss", "wte", "layer_carry"} <= found
+
+
+@pytest.mark.parametrize("scope", scopes.SSM_SCOPES)
+def test_each_scope_of_the_mixer_is_on_the_hybrid_step_under_ssm(hybrid_hlo, scope):
+    assert any(re.search(rf"/{scopes.SSM}/{scope}/", path) for path in every_op_name(hybrid_hlo)), scope
+
+
+def test_the_runs_name_themselves_and_the_scan_holds_the_recurrence_alone(hybrid_hlo, hybrid_rules):
+    names = every_op_name(hybrid_hlo)
+    for run in ("run_0", "run_1", "run_2"):
+        assert any(f"jvp(GPT2Module)/{run}/layer_carry/while/body/closed_call/blocks/block/" in n for n in names), run
+    assert any("/run_1/layer_carry/while/body/closed_call/blocks/block/attn/" in n for n in names)
+    # rematerialized: the backward pass reaches a block through `blocks/blocks/checkpoint/`, which the rules know
+    backward = [n for n in names if "transpose(jvp(GPT2Module))" in n and "/ssm/scan/" in n]
+    assert backward and all("blocks/blocks/checkpoint/" in n for n in backward)
+    assert {bucket_of(n, hybrid_rules["component"]) for n in backward} == {"ssm_scan"}
+    in_scan = {n.rsplit("/", 1)[-1] for n in names if "/ssm/scan/" in n}
+    assert not in_scan & {"dot_general", "softplus", "logistic", "conv_general_dilated"}, "projections, softplus and gate are outside ssm/scan"
+
+
 # ------------------------------------------------------------------ (b) only metadata
 
 
