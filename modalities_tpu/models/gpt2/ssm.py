@@ -159,7 +159,6 @@ class MambaMixer(nn.Module):
     def __call__(self, u):
         from modalities_tpu.models.gpt2.gpt2_model import with_logical_constraint
         from modalities_tpu.ops import selective_scan as scan_ops
-        from modalities_tpu.telemetry import get_active_telemetry
 
         spec, ssm = self.spec, self.spec.ssm
 
@@ -182,10 +181,8 @@ class MambaMixer(nn.Module):
         skip = self.param("D", nn.with_logical_partitioning(nn.initializers.ones, ("mlp",)), (ssm.d_inner,), jnp.float32)
         a = -jnp.exp(a_log)
 
-        # runs while tracing: once per shape on the sink, nothing per step
-        get_active_telemetry().emit_event_once(
-            "ssm_scan_plan", scan_ops.scan_plan(x.shape[0], x.shape[1], ssm.d_inner, ssm.d_state, scan_ops.CHUNK))
-        with jax.named_scope(scopes.SSM_SCAN):  # the recurrence and its backward, nothing else
+        # the recurrence and its backward, nothing else; says `ssm_scan_plan` once per shape while tracing
+        with jax.named_scope(scopes.SSM_SCAN):
             y, _ = scan_ops.selective_scan(x, dt, a, b, c, chunk=scan_ops.CHUNK)
         with jax.named_scope(scopes.SSM_GATE):
             y = ((y + skip * x.astype(jnp.float32)) * nn.silu(z.astype(jnp.float32))).astype(u.dtype)

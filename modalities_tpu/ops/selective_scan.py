@@ -16,9 +16,19 @@ autodiff makes of the loop keeps three tensors a step and ran 18 device operatio
 step where this runs a few. A sequence that `chunk` does not divide is padded with
 steps of `dt = 0`, which leave the state as it is.
 
-This is the plain form: `lax.scan` over time inside a chunk, a few steps unrolled. The
-Pallas kernels that keep the state in VMEM over a whole chunk are the next step
-(PERF.md section 7); they would be named `selective_scan_fwd` / `selective_scan_bwd`.
+Two forms of one algorithm, in the pattern of ops/attention.py. On a TPU the Pallas
+kernels `selective_scan_fwd` / `selective_scan_bwd` (ops/pallas/selective_scan.py) run:
+the time loop inside the kernel, the state and, in the backward, one chunk's states in
+VMEM. Whatever they raise is raised: there is no second tier behind them on the chip,
+and a shape their layout cannot hold (`d_inner` of a shard no multiple of 128, `d_state`
+no multiple of 8) is refused with the shape in the message. Off a TPU the plain form
+below runs: `lax.scan` over time inside a chunk, a few steps unrolled, every step a few
+XLA fusions (on the chip 18.1 ms of device time a layer at the hybrid cell's shape,
+forward and backward, where the kernels take 2.5: PERF.md section 6). It is what CPU runs and
+what the tests hold the kernels against (`interpret=True` runs the kernels' own code
+there). Under a mesh the kernels run per shard (parallel/sharding.per_shard): the
+recurrence is independent per sequence and per channel, so `batch` and `d_inner`
+(logical axis `mlp`) may both be split.
 
 Inside, the state is laid out `[batch, d_state, d_inner]`: `d_inner` is a multiple of
 128 and fills the lanes, `d_state` (16) the sublanes.
@@ -32,22 +42,41 @@ import jax
 import jax.numpy as jnp
 from jax import lax
 
+from modalities_tpu.ops.tiers import on_tpu
+
 # steps between the states the backward pass keeps. On the chip at the hybrid cell's shape (1 x 4096 x 5120 x 16, PR 26, forward and
 # backward of one layer): 64 steps 31.0 ms, 128 steps 26.6, 256 steps 29.5; the step's memory does not move with it (15.17-15.28 GiB)
 CHUNK = 128
 UNROLL = 16  # steps of the time loop traced into one loop body (chip, PR 26: 8 and 16 run the forward alike, 16 the backward 15% faster)
 
 
-def scan_plan(batch: int, seq: int, d_inner: int, d_state: int, chunk: int) -> dict:
-    """What `selective_scan` does for a shape: the facts of the sink event `ssm_scan_plan`."""
-    chunk = min(chunk, seq)
+def scan_plan(batch: int, seq: int, d_inner: int, d_state: int, chunk: int, kernel: bool = False) -> dict:
+    """What `selective_scan` does for a shape: the facts of the sink event `ssm_scan_plan`.
+    With `kernel`, what the Pallas kernels run with; `batch` and `d_inner` are then what one shard holds."""
+    chunk, block_d = min(chunk, seq), 0
+    if kernel:
+        from modalities_tpu.ops.pallas.selective_scan import plan_blocks
+
+        chunk, block_d = plan_blocks(seq, d_inner, d_state, chunk)
+    chunks = -(-seq // chunk)
     state_bytes = 4 * batch * d_inner * d_state
     return {
-        "batch": batch, "seq": seq, "chunk": chunk, "chunks": -(-seq // chunk), "d_inner": d_inner, "d_state": d_state,
+        "batch": batch, "seq": seq, "chunk": chunk, "chunks": chunks, "d_inner": d_inner, "d_state": d_state,
         "state_bytes_carried": state_bytes,
-        "boundary_state_bytes": state_bytes * -(-seq // chunk),  # kept from the forward pass for the backward
+        "boundary_state_bytes": state_bytes * chunks,  # kept from the forward pass for the backward
         "backward_bytes_per_chunk": chunk * state_bytes,  # the backward of one chunk holds the state before each of its steps
+        "kernel": kernel,  # the Pallas kernels were traced; the three below are 0 for the plain form
+        "block_d": block_d,
+        "grid_steps": batch * (d_inner // block_d) * chunks if kernel else 0,  # of each of the two kernels
+        "vmem_state_bytes": 4 * chunk * d_state * block_d,  # the backward's chunk of states: in VMEM, never in HBM
     }
+
+
+def _say_plan(x, a, chunk: int, kernel: bool) -> None:
+    """Runs while tracing: the operator sees once per shape how the scan walks it; nothing per step."""
+    from modalities_tpu.telemetry import get_active_telemetry
+
+    get_active_telemetry().emit_event_once("ssm_scan_plan", scan_plan(*x.shape, a.shape[1], chunk, kernel=kernel))
 
 
 def _chunk_loop(h, dt, x, b, c, a_t, keep_states: bool = False):
@@ -142,15 +171,37 @@ def _scan_bwd(chunk, kept, cotangents):
 _scan.defvjp(_scan_fwd, _scan_bwd)
 
 
-def selective_scan(x, dt, a, b, c, *, chunk: int = CHUNK, h0=None):
+_ROWS, _NARROW, _STATE = ("batch", None, "mlp"), ("batch", None, None), ("batch", "mlp", None)
+
+
+def uses_kernels(interpret: bool = False) -> bool:
+    """Whether `selective_scan` runs the Pallas kernels here: on a TPU always, elsewhere when asked to interpret them."""
+    return interpret or on_tpu()
+
+
+def selective_scan(x, dt, a, b, c, *, chunk: int = CHUNK, h0=None, interpret: bool = False):
     """x, dt `[B, S, D]`; a `[D, N]` (negative); b, c `[B, S, N]`; h0 `[B, D, N]` or None
     for zeros. Returns y `[B, S, D]` and the state after the last step `[B, D, N]`,
-    both float32. `chunk` is the number of steps between kept states (tests pass others than CHUNK)."""
+    both float32. `chunk` is the number of steps between kept states (tests pass others
+    than CHUNK). `interpret` runs the kernels' code off a TPU (tests)."""
     f32 = lambda v: v.astype(jnp.float32)  # noqa: E731
     batch, seq, d_inner = x.shape
     if h0 is None:
         h0 = jnp.zeros((batch, d_inner, a.shape[1]), jnp.float32)
-    return _scan(f32(x), f32(dt), f32(a), f32(b), f32(c), f32(h0), int(min(chunk, seq)))
+    operands = (f32(x), f32(dt), f32(a), f32(b), f32(c), f32(h0))
+    if not uses_kernels(interpret):
+        _say_plan(x, a, chunk, kernel=False)
+        return _scan(*operands, int(min(chunk, seq)))
+    from modalities_tpu.ops.pallas.selective_scan import pallas_selective_scan
+    from modalities_tpu.parallel.sharding import per_shard
+
+    def kernels(_axes, *local):
+        _say_plan(local[0], local[2], chunk, kernel=True)
+        return pallas_selective_scan(*local, chunk=chunk, interpret=not on_tpu())
+
+    # b, c and a are whole on the axes they do not name, and the shard_map's transpose adds
+    # their cotangents up over those: dB, dC over the axes d_inner was split on, dA over batch's
+    return per_shard(kernels, (_ROWS, _ROWS, ("mlp", None), _NARROW, _NARROW, _STATE), (_ROWS, _STATE))(*operands)
 
 
 def causal_depthwise_conv(x, kernel, bias=None):

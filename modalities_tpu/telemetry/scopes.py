@@ -42,7 +42,7 @@ Model (`models/gpt2/gpt2_model.py`), beside the names Flax gives its modules:
 State-space mixer (`models/gpt2/ssm.py`), under the module name `ssm` that Flax gives it in a block's mixer seat:
 
     SSM_CONV          conv              the causal depthwise convolution (the name of its module)
-    SSM_SCAN          scan              the recurrence (`ops/selective_scan.py`) and its backward pass, nothing else
+    SSM_SCAN          scan              the recurrence (`ops/selective_scan.py`: on a TPU its two Pallas kernels) and its backward pass, nothing else
     SSM_GATE          gate              the skip `D * x` and the gate `silu(z)` on the scan's output
 
 Module names Flax gives, part of the vocabulary as they are (`flax_profile` puts them
@@ -52,8 +52,8 @@ on the stack): `GPT2Module`, `blocks/block` (`h_<i>` when the layers are not sca
 puts `run_<i>` before `blocks/block` (one scan a run of equal layers), and the state-space
 mixer's projections are `ssm/{in_proj,x_proj,dt_proj,out_proj}` with `ssm/{dt_norm,b_norm,c_norm}`. Kernels keep the `name=` of their Pallas call:
 `flash_attention_{fwd,bwd_dq,bwd_dkv}`, `fused_ce_{fwd,bwd_dh,bwd_dw}`,
-`fused_rmsnorm_{fwd,bwd}`; the instruction of a call is named by it, and metrics select
-by that name.
+`fused_rmsnorm_{fwd,bwd}`, `selective_scan_{fwd,bwd}` (the recurrence on a TPU, under
+`ssm/scan`); the instruction of a call is named by it, and metrics select by that name.
 
 Host spans (`telemetry/spans.py`) are a second vocabulary, on the host's rows of the
 same trace: `data_wait`, `train_step`, `metrics_fetch`, `publish`, `serve/admission`,

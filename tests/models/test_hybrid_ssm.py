@@ -127,8 +127,11 @@ def test_bfloat16_program_against_the_reference(hybrid, tokens, reference_logits
     assert np.abs(logits_of(GPT2LLM(**HYBRID), hybrid[2], tokens) - reference_logits).max() < 0.02
 
 
-def test_loss_and_every_gradient_leaf_are_the_references(hybrid, tokens):
+@pytest.mark.parametrize("kernels", [False, True], ids=["plain_scan", "scan_kernels_interpreted"])
+def test_loss_and_every_gradient_leaf_are_the_references(hybrid, tokens, monkeypatch, kernels):
+    """With `kernels`, the scan as a TPU runs it: the Pallas kernels (interpreted), in every state-space layer."""
     model, shape, params = hybrid
+    monkeypatch.setattr("modalities_tpu.ops.selective_scan.uses_kernels", lambda interpret=False: kernels)
 
     def loss(params):
         with jax.default_matmul_precision("highest"):

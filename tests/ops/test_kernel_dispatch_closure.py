@@ -52,10 +52,13 @@ def test_dispatch_entry_points_expose_interpret():
     from modalities_tpu.ops.pallas.fused_ce import fused_ce_sum_and_count
     from modalities_tpu.ops.pallas.fused_rmsnorm import fused_rms_norm
     from modalities_tpu.ops.pallas.quant_matmul import quant_matmul
+    from modalities_tpu.ops.pallas.selective_scan import pallas_selective_scan
     from modalities_tpu.ops.quant_matmul import quant_matmul_or_fallback
     from modalities_tpu.ops.rmsnorm import rms_norm_or_fallback
+    from modalities_tpu.ops.selective_scan import selective_scan
 
-    for fn in (pallas_flash_attention, fused_ce_sum_and_count, fused_rms_norm, ce_dispatch, rms_norm_or_fallback, quant_matmul, quant_matmul_or_fallback):
+    for fn in (pallas_flash_attention, fused_ce_sum_and_count, fused_rms_norm, ce_dispatch, rms_norm_or_fallback, quant_matmul, quant_matmul_or_fallback,
+               pallas_selective_scan, selective_scan):
         params = inspect.signature(fn).parameters
         assert "interpret" in params, f"{fn.__module__}.{fn.__name__} lacks an interpret path"
         assert params["interpret"].default is False, fn.__name__
@@ -119,9 +122,19 @@ def _call_quant_matmul():
     )
 
 
+def _call_selective_scan():
+    import jax.numpy as jnp
+
+    from modalities_tpu.ops.selective_scan import selective_scan
+
+    rows, narrow = jnp.ones((1, 16, 128)), jnp.ones((1, 16, 8))
+    return selective_scan(rows, rows, -jnp.ones((128, 8)), narrow, narrow)
+
+
 @pytest.mark.parametrize(
     "module, call",
     [
+        ("selective_scan", _call_selective_scan),
         ("attention", _call_attention),
         ("cross_entropy", _call_fused_ce),
         ("rmsnorm", _call_rmsnorm),

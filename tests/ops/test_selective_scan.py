@@ -1,12 +1,16 @@
 """ops/selective_scan.py: the chunked scan with its hand-written backward pass against
-the recurrence as a plain loop over time that autodiff differentiates, and the causal
-depthwise convolution against a direct sum."""
+the recurrence as a plain loop over time that autodiff differentiates, in both of its
+forms (the plain `lax.scan` form that CPU runs, and the Pallas kernels of
+ops/pallas/selective_scan.py in interpret mode), and the causal depthwise convolution
+against a direct sum."""
 
 import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
 
+from modalities_tpu.ops import selective_scan as scan_ops
+from modalities_tpu.ops.pallas.selective_scan import plan_blocks
 from modalities_tpu.ops.selective_scan import causal_depthwise_conv, scan_plan, selective_scan
 
 B, S, D, N = 2, 37, 24, 4
@@ -52,6 +56,57 @@ def test_chunked_scan_is_the_loop_in_values_and_gradients(inputs, chunk, carried
         assert float(jnp.abs(g - w).max() / jnp.abs(w).max()) < 2e-6, name
 
 
+def kernel_inputs(batch, seq, d_inner=384, d_state=8):
+    """d_inner 384 is three blocks of 128 (512 and 256 do not divide it), so the partial dB, dC of the blocks are summed."""
+    rng = np.random.default_rng(seq)
+    f = lambda *shape: jnp.asarray(rng.normal(size=shape), jnp.float32)  # noqa: E731
+    return {"x": f(batch, seq, d_inner), "dt": jnp.asarray(rng.uniform(0.01, 0.5, size=(batch, seq, d_inner)), jnp.float32),
+            "a": -jnp.asarray(rng.uniform(0.5, 4, size=(d_inner, d_state)), jnp.float32), "b": f(batch, seq, d_state),
+            "c": f(batch, seq, d_state), "h0": f(batch, d_inner, d_state), "wy": f(batch, seq, d_inner), "wh": f(batch, d_inner, d_state)}
+
+
+# the kernels walk whole sublane tiles: 40 steps in chunks of 8 (divides) and 16 (pads to 48), 37 in chunks of 8 (pads
+# to 40) and 24 (pads to 48), and a chunk longer than the sequence (one chunk of 40)
+@pytest.mark.parametrize("seq, chunk", [(40, 8), (40, 16), (37, 8), (37, 24), (37, 64)])
+@pytest.mark.parametrize("batch, carried", [(1, False), (2, True), (2, False)], ids=["b1_from_zero", "b2_carried_state", "b2_from_zero"])
+def test_interpreted_kernels_are_the_loop_in_values_and_gradients(seq, chunk, batch, carried):
+    """Same tolerance as the plain form's test: what differs from the loop is float32
+    round-off of another summation order (dA over the steps, dB and dC over lanes and
+    blocks) and `exp2(v log2 e)` for `exp(v)`; a dropped term would be 1e-2 and more."""
+    v = kernel_inputs(batch, seq)
+    h0 = v["h0"] if carried else jnp.zeros_like(v["h0"])
+    args = (v["x"], v["dt"], v["a"], v["b"], v["c"], h0)
+    assert plan_blocks(seq, 384, 8, chunk)[1] == 128
+    kernels = lambda *a: selective_scan(*a[:5], chunk=chunk, h0=a[5], interpret=True)  # noqa: E731
+    y, h = selective_scan(*args[:5], chunk=chunk, h0=h0 if carried else None, interpret=True)
+    want_y, want_h = loop(*args)
+    np.testing.assert_allclose(y, want_y, atol=4e-6)
+    np.testing.assert_allclose(h, want_h, atol=2e-6)
+
+    weighed = lambda y, h: jnp.sum(y * v["wy"]) + jnp.sum(h * v["wh"])  # noqa: E731
+    got = jax.grad(lambda *a: weighed(*kernels(*a)), argnums=range(6))(*args)
+    want = jax.grad(lambda *a: weighed(*loop(*a)), argnums=range(6))(*args)
+    for name, g, w in zip(("x", "dt", "a", "b", "c", "h0"), got, want):
+        assert float(jnp.abs(g - w).max() / jnp.abs(w).max()) < 2e-6, name
+
+
+def test_the_plain_form_runs_off_a_tpu_and_the_kernels_refuse_what_their_layout_cannot_hold(inputs):
+    v = inputs
+    assert not scan_ops.uses_kernels() and scan_ops.uses_kernels(interpret=True)
+    plain = jax.make_jaxpr(lambda *a: selective_scan(*a, chunk=8))(v["x"], v["dt"], v["a"], v["b"], v["c"])
+    assert "pallas_call" not in str(plain) and "scan" in str(plain)
+    k = kernel_inputs(1, 16, d_inner=128)
+    kernels = jax.make_jaxpr(lambda *a: selective_scan(*a, chunk=8, interpret=True))(k["x"], k["dt"], k["a"], k["b"], k["c"])
+    assert str(kernels).count("pallas_call") == 1 and "selective_scan_fwd" in str(kernels)
+    # d_inner 24 is no multiple of 128, d_state 4 no multiple of 8: no second tier takes them, the shape is in the message
+    with pytest.raises(ValueError, match=r"d_inner 24 .* multiple of 128"):
+        selective_scan(v["x"], v["dt"], v["a"], v["b"], v["c"], chunk=8, interpret=True)
+    with pytest.raises(ValueError, match=r"d_state 4 .* multiple of 8"):
+        selective_scan(k["x"], k["dt"], k["a"][:, :4], k["b"][..., :4], k["c"][..., :4], chunk=8, interpret=True)
+    with pytest.raises(ValueError, match="no d_inner block"):
+        plan_blocks(4096, 5120, 16, 4096)  # a chunk of 4096 steps: its states do not fit the VMEM budget at any block
+
+
 def test_bfloat16_inputs_are_widened_and_the_state_stays_float32(inputs):
     v = inputs
     y, h = selective_scan(v["x"].astype(jnp.bfloat16), v["dt"], v["a"], v["b"], v["c"], chunk=16)
@@ -82,3 +137,13 @@ def test_scan_plan_counts_the_cells_shape():
     # the whole sequence's states, which are never held: 1.34 GB
     assert 4096 * 327_680 == 1_342_177_280
     assert scan_plan(1, 100, 8, 2, 48)["chunks"] == 3 and scan_plan(1, 8, 8, 2, 48)["chunk"] == 8
+    assert (plan["kernel"], plan["block_d"], plan["grid_steps"], plan["vmem_state_bytes"]) == (False, 0, 0, 0)
+    # what the Pallas kernels run with at that shape: ten blocks of 512 channels, 320 grid steps a kernel, and the
+    # backward's chunk of states (128 x 16 x 512 floats) in VMEM where the plain form held 42 MB in HBM
+    kernels = scan_plan(batch=1, seq=4096, d_inner=5120, d_state=16, chunk=128, kernel=True)
+    assert {**kernels, "kernel": False, "block_d": 0, "grid_steps": 0, "vmem_state_bytes": 0} == plan
+    assert (kernels["kernel"], kernels["block_d"], kernels["grid_steps"], kernels["vmem_state_bytes"]) == (True, 512, 320, 4 * 2**20)
+    # a shard of d_inner under tp 4 (1280 channels) takes blocks of 256; a short sequence one chunk of whole sublane tiles
+    assert scan_plan(2, 4096, 1280, 16, 128, kernel=True)["block_d"] == 256
+    short = scan_plan(2, 37, 256, 8, 128, kernel=True)
+    assert (short["chunk"], short["chunks"], short["grid_steps"]) == (40, 1, 2)

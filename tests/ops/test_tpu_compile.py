@@ -25,6 +25,7 @@ from modalities_tpu.ops.pallas.flash_attention import (
 from modalities_tpu.ops.pallas.fused_ce import fused_ce_sum_and_count
 from modalities_tpu.ops.pallas.fused_rmsnorm import fused_rms_norm
 from modalities_tpu.ops.pallas.quant_matmul import quant_matmul
+from modalities_tpu.ops.pallas.selective_scan import pallas_selective_scan
 
 BF16, F32, VOCAB, SEQ = jnp.bfloat16, jnp.float32, 50304, 4096
 
@@ -93,6 +94,17 @@ def _quant_matmul(m):
     return quant_matmul, (((m, k), BF16), ((k, n), jnp.int8), ((n,), F32)), 1
 
 
+def _selective_scan(d_inner, batch=1, d_state=16):
+    """The hybrid cell's recurrence (benchmark/configs/jamba2-3b-d14: 4096 x 5120 x 16), and
+    the quarter of `d_inner` a shard holds under tp 4, which takes blocks of 256."""
+    def loss(x, dt, a, b, c, h0):
+        y, h = pallas_selective_scan(x, dt, a, b, c, h0, chunk=128)
+        return y.sum() + h.sum()
+
+    rows, narrow = ((batch, SEQ, d_inner), F32), ((batch, SEQ, d_state), F32)
+    return jax.grad(loss, argnums=tuple(range(6))), (rows, rows, ((d_inner, d_state), F32), narrow, narrow, ((batch, d_inner, d_state), F32)), 2
+
+
 CASES = {
     "flash_fwd_bwd_d128": _flash(16, 16, 128),
     "flash_fwd_bwd_d80_gqa_32_8": _flash(32, 8, 80),
@@ -103,6 +115,8 @@ CASES = {
     "fused_rmsnorm_fwd_bwd_e2560": _fused_rmsnorm(2560),
     "quant_matmul_m8": _quant_matmul(8),
     "quant_matmul_m256": _quant_matmul(256),
+    "selective_scan_fwd_bwd_d5120": _selective_scan(5120),
+    "selective_scan_fwd_bwd_b2_d1280": _selective_scan(1280, batch=2),
 }
 
 

@@ -1,6 +1,6 @@
 """Pallas kernels under a mesh: GSPMD cannot partition a Mosaic custom call, so the
 dispatchers run their kernels per shard (parallel/sharding.per_shard). Here the
-three train-path kernels run in interpret mode on a dp_shard 2 x tp 2 mesh of
+train-path kernels (the fused norm, the fused loss, flash attention, the selective scan) run in interpret mode on a dp_shard 2 x tp 2 mesh of
 virtual CPU devices and are held, values and gradients, to their unsharded
 references. What only the chip's compiler can say — that the sharded step lowers
 at all — chip_smoke.py --chips 4 checks on four chips."""
@@ -82,12 +82,29 @@ def _flash_pair(monkeypatch):
     return kernel, reference, (q, k, v)
 
 
-@pytest.mark.parametrize("case", ["fused_rmsnorm", "fused_ce", "flash_attention"])
+def _selective_scan_pair():
+    """Batch over dp_shard, d_inner over tp: b, c are whole on tp and a on dp_shard, so dB, dC
+    are added up over tp and dA over dp_shard by the shard_map's transpose."""
+    from modalities_tpu.ops import selective_scan as scan_ops
+
+    keys = jax.random.split(jax.random.PRNGKey(0), 6)
+    x, b, c = (jax.random.normal(k, shape) for k, shape in zip(keys, ((4, 16, 256), (4, 16, 8), (4, 16, 8))))
+    dt = jax.random.uniform(keys[3], (4, 16, 256), minval=0.01, maxval=0.5)
+    a = -jax.random.uniform(keys[4], (256, 8), minval=0.5, maxval=4.0)
+    w = jax.random.normal(keys[5], (4, 256, 8))
+    weighed = lambda y, h: (y**2).sum() + (h * w).sum()  # noqa: E731
+    kernel = lambda *v: weighed(*scan_ops.selective_scan(*v, chunk=8, interpret=True))  # noqa: E731
+    reference = lambda *v: weighed(*scan_ops.selective_scan(*v, chunk=8))  # noqa: E731
+    return kernel, reference, (x, dt, a, b, c)
+
+
+@pytest.mark.parametrize("case", ["fused_rmsnorm", "fused_ce", "flash_attention", "selective_scan"])
 def test_kernel_per_shard_matches_unsharded_reference(case, mesh_rules, monkeypatch):
     kernel, reference, args = {
         "fused_rmsnorm": _rmsnorm_pair,
         "fused_ce": _fused_ce_pair,
         "flash_attention": functools.partial(_flash_pair, monkeypatch),
+        "selective_scan": _selective_scan_pair,
     }[case]()
     argnums = tuple(range(len(args)))
     got = _on_mesh(mesh_rules, jax.value_and_grad(kernel, argnums=argnums), *args)

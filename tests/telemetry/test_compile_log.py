@@ -106,15 +106,19 @@ def test_flash_tile_plan_is_one_event_per_traced_shape_and_none_per_step(tmp_pat
     ]
 
 
-def test_ssm_scan_plan_is_one_event_per_traced_shape_and_none_per_step(tmp_path, monkeypatch):
+@pytest.mark.parametrize("kernels", [False, True], ids=["plain_form", "kernels_interpreted"])
+def test_ssm_scan_plan_is_one_event_per_traced_shape_and_none_per_step(tmp_path, monkeypatch, kernels):
     """The state-space mixer says once, while tracing, how its scan walks the sequence
     (`ssm_scan_plan`): the chunks, the state carried, what the backward pass holds of a
-    chunk; two layers of one shape and three steps of one executable say it once."""
+    chunk, and whether the Pallas kernels were traced (as on a TPU) with their block,
+    grid and the VMEM their backward keeps a chunk's states in; two layers of one shape
+    and three steps of one executable say it once."""
     from tests.models.test_hybrid_ssm import HYBRID
 
     from modalities_tpu.models.gpt2.gpt2_model import GPT2LLM
 
     monkeypatch.setattr("modalities_tpu.ops.selective_scan.CHUNK", 24)
+    monkeypatch.setattr("modalities_tpu.ops.selective_scan.uses_kernels", lambda interpret=False: kernels)
     model = GPT2LLM(**HYBRID)
     telemetry = Telemetry(output_folder_path=tmp_path, watchdog_deadline_s=0)
     previous = set_active_telemetry(telemetry)
@@ -130,3 +134,8 @@ def test_ssm_scan_plan_is_one_event_per_traced_shape_and_none_per_step(tmp_path,
     assert [(e["batch"], e["seq"], e["chunk"], e["chunks"]) for e in plans] == [(1, 8, 8, 1), (2, 64, 24, 3), (1, 40, 24, 2)]
     assert plans[1] == {**plans[1], "d_inner": 256, "d_state": 8, "state_bytes_carried": 4 * 2 * 256 * 8,
                         "boundary_state_bytes": 3 * 4 * 2 * 256 * 8, "backward_bytes_per_chunk": 24 * 4 * 2 * 256 * 8}
+    of_kernels = [(e["kernel"], e["block_d"], e["grid_steps"], e["vmem_state_bytes"]) for e in plans]
+    if kernels:  # one block of 256 channels; a grid step a sequence and chunk
+        assert of_kernels == [(True, 256, 1, 4 * 8 * 8 * 256), (True, 256, 6, 4 * 24 * 8 * 256), (True, 256, 2, 4 * 24 * 8 * 256)]
+    else:
+        assert of_kernels == [(False, 0, 0, 0)] * 3

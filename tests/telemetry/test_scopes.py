@@ -190,6 +190,24 @@ def test_the_runs_name_themselves_and_the_scan_holds_the_recurrence_alone(hybrid
     assert not in_scan & {"dot_general", "softplus", "logistic", "conv_general_dilated"}, "projections, softplus and gate are outside ssm/scan"
 
 
+def test_the_scan_kernels_keep_their_names_under_ssm_scan(tmp_path, monkeypatch, hybrid_rules):
+    """What a TPU traces: the dispatcher takes the Pallas kernels (here interpreted, so that
+    their bodies are ordinary operations with a path). `selective_scan_fwd` runs in the
+    forward pass and again where a block is rematerialized, `selective_scan_bwd` in the
+    backward pass, both under `ssm/scan` and in no other scope, so the rules that read
+    the plain form's time read the kernels' without an edit."""
+    monkeypatch.setattr("modalities_tpu.ops.selective_scan.uses_kernels", lambda interpret=False: True)
+    names = every_op_name(compile_toy_hybrid_train_step(tmp_path))
+    of_kernels = [n for n in names if "selective_scan_" in n]
+    assert any("/jvp(GPT2Module)/" in n and "/ssm/scan/selective_scan_fwd/" in n for n in of_kernels)
+    assert any("transpose(jvp(GPT2Module))" in n and "rematted_computation" in n and "/ssm/scan/selective_scan_fwd/" in n for n in of_kernels)
+    assert any("transpose(jvp(GPT2Module))" in n and "/ssm/scan/selective_scan_bwd/" in n for n in of_kernels)
+    assert all("/ssm/scan/selective_scan_" in n for n in of_kernels)
+    assert {bucket_of(n, hybrid_rules["component"]) for n in of_kernels} == {"ssm_scan"}
+    assert {bucket_of(n, hybrid_rules["pass"]) for n in of_kernels} == {"forward", "backward"}
+    assert re.search(r"`selective_scan_\{fwd,bwd\}`", scopes.__doc__), "the vocabulary names the kernels beside the other three families"
+
+
 # ------------------------------------------------------------------ (b) only metadata
 
 
