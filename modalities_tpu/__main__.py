@@ -566,28 +566,6 @@ def entry_point_analyze_fleet(sink_paths: tuple[Path, ...], as_json: bool) -> No
         click.echo(format_fleet_trace_tree(traces))
 
 
-@data.command(name="analyze_bench")
-@click.option("--artifacts_dir", type=click.Path(exists=True, path_type=Path), default=Path("."),
-              show_default=True,
-              help="Folder holding the driver's BENCH_r*.json / MULTICHIP_r*.json rounds.")
-@click.option("--as_json", is_flag=True, default=False, help="Emit the summary dict as JSON.")
-@_exception_handling
-def entry_point_analyze_bench(artifacts_dir: Path, as_json: bool) -> None:
-    """Benchmark-trajectory trend table over the per-round hardware artifacts:
-    MFU/tokens-per-sec per round with vs_baseline, wedged rounds (rc=124,
-    nothing parsed) and completed-but-metricless rounds flagged explicitly."""
-    from modalities_tpu.utils.benchmarking.trajectory import (
-        format_trajectory_table,
-        summarize_trajectory,
-    )
-
-    summary = summarize_trajectory(artifacts_dir)
-    if as_json:
-        click.echo(json.dumps(summary))
-    else:
-        click.echo(format_trajectory_table(summary))
-
-
 @data.command(name="check_slo")
 @click.option("--slo_path", type=click.Path(exists=True, path_type=Path), required=True,
               help="YAML SLO spec (same grammar as the telemetry/serving `slo:` block).")
@@ -596,12 +574,6 @@ def entry_point_analyze_bench(artifacts_dir: Path, as_json: bool) -> None:
               help="Telemetry JSONL sink (file or folder); repeatable. serve_request "
                    "traces rebuild the serve_* histograms, mfu_waterfall records the "
                    "training_mfu_achieved gauge, spans the goodput ratio.")
-@click.option("--bench_path", "bench_paths", type=click.Path(exists=True, path_type=Path),
-              multiple=True,
-              help="bench_serve JSON-lines output; repeatable. The final result line's "
-                   "numeric fields become bench_<key> gauges.")
-@click.option("--trajectory_path", type=click.Path(exists=True, path_type=Path), default=None,
-              help="Folder of BENCH_r*/MULTICHIP_r* round artifacts (trajectory loader).")
 @click.option("--memscope_path", "memscope_paths", type=click.Path(exists=True, path_type=Path),
               multiple=True,
               help="memscope.json static report; repeatable. Buckets become "
@@ -610,32 +582,25 @@ def entry_point_analyze_bench(artifacts_dir: Path, as_json: bool) -> None:
 @click.option("--as_json", is_flag=True, default=False, help="Emit the verdict dict as JSON.")
 @_exception_handling
 def entry_point_check_slo(
-    slo_path: Path, sink_paths: tuple[Path, ...], bench_paths: tuple[Path, ...],
-    trajectory_path: Optional[Path], memscope_paths: tuple[Path, ...], as_json: bool,
+    slo_path: Path, sink_paths: tuple[Path, ...], memscope_paths: tuple[Path, ...],
+    as_json: bool,
 ) -> None:
     """Evaluate recorded runs against a declarative SLO spec: replay telemetry
-    sinks / bench_serve lines / benchmark-round artifacts into one metrics
-    registry, judge each objective point-in-time (no burn windows — the data is
-    historical), and exit nonzero when any objective breaches. The CI face of
-    the live SLO engine."""
+    sinks and memscope reports into one metrics registry, judge each objective
+    point-in-time (no burn windows — the data is historical), and exit nonzero
+    when any objective breaches. The CI face of the live SLO engine."""
     from modalities_tpu.telemetry.metrics import MetricsRegistry
     from modalities_tpu.telemetry.slo import (
         evaluate_recorded,
         load_slo_spec,
-        replay_bench_lines_into_registry,
         replay_memscope_into_registry,
         replay_sink_into_registry,
-        replay_trajectory_into_registry,
     )
 
     registry = MetricsRegistry()
     replayed = 0
     for path in sink_paths:
         replayed += replay_sink_into_registry(path, registry)
-    for path in bench_paths:
-        replayed += replay_bench_lines_into_registry(path, registry)
-    if trajectory_path is not None:
-        replayed += replay_trajectory_into_registry(trajectory_path, registry)
     for path in memscope_paths:
         replayed += replay_memscope_into_registry(path, registry)
     objectives, _ = load_slo_spec(slo_path)

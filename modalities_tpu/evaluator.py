@@ -57,7 +57,7 @@ class Evaluator:
                     feed.close()
                 # fetch BEFORE reading the clock: dispatch returns early, so an elapsed
                 # taken pre-sync times the host loop, not the device work — the same
-                # honest-clock rule the trainer and bench.py follow (hard_sync lesson)
+                # honest-clock rule the trainer follows (hard_sync lesson)
                 losses_np = np.asarray([np.asarray(loss) for loss in losses], dtype=np.float64)
                 elapsed = max(time.perf_counter() - start, 1e-9)
                 result = EvaluationResultBatch(

@@ -15,8 +15,8 @@ untuned guess. This module closes that gap with a *table*, not a heuristic:
 
       env var  >  MODALITIES_TPU_TUNE_DIR table  >  shipped table  >  default
 
-- ``tune_kernels()`` runs the timed sweep (``data tune_kernels`` CLI, or the
-  ``BENCH_TUNE_KERNELS=1`` bench.py hook) and persists what it measured. On a
+- ``tune_kernels()`` runs the timed sweep (the ``data tune_kernels`` CLI is
+  its only caller) and persists what it measured. On a
   non-TPU host the sweep runs in interpret mode: the table round-trips and the
   plumbing is exercised, but the timings are emulation smoke numbers — only a
   TPU-run table is worth shipping.

@@ -7,9 +7,8 @@ Three pillars, all behind the ops/tiers.py auto/on/off discipline:
 - `weights` — weight-only serving: params are quantized ONCE at load time through
               the shared `load_serving_params` seam, dequantized on the fly in the
               matmul path (Pallas fused dequant-matmul, ops/quant_matmul.py).
-- `kv`      — int8 paged KV pool helpers: byte accounting that sizes a quantized
-              pool against a byte budget, plus the host-side scale-allocation
-              mirror the pool fuzz audits.
+- `kv`      — int8 paged KV pool helpers: mode resolution and the host-side
+              scale-allocation mirror the pool fuzz audits.
 
 Quantized modes are excluded from the bitwise interactive-parity pins; `oracle`
 gates them instead (max-abs logit error + greedy token-match rate vs bf16).
@@ -32,8 +31,5 @@ from modalities_tpu.quant.weights import (  # noqa: F401
 from modalities_tpu.quant.oracle import OracleReport, run_oracle  # noqa: F401
 from modalities_tpu.quant.kv import (  # noqa: F401
     KVScaleMirror,
-    kv_block_bytes,
-    kv_blocks_for_budget,
-    kv_scale_bytes_per_block,
     resolve_quant_kv_mode,
 )

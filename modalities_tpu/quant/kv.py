@@ -1,17 +1,12 @@
-"""Quantized paged-KV helpers: mode resolution, byte accounting, and the
+"""Quantized paged-KV helpers: mode resolution and the
 host-side scale-allocation mirror the pool fuzz audits.
 
 The device-side work (int8 pools, per-(block, row, head) float32 scales,
 quantize-on-write / dequant-at-gather) lives in the model's
 `_paged_slot_attention`; this module owns the HOST-side contracts:
 
-- `kv_blocks_for_budget` sizes a pool against a byte budget. The budget is
-  defined over the K/V DATA arrays only — int8 data is exactly half of bf16,
-  so a half-budget int8 pool holds >= the full-budget bf16 block count (the
-  acceptance pin). The float32 scales are real memory but they're accounted
-  separately via `kv_scale_bytes_per_block` and reported in
-  `serve_kv_pool_bytes`, never folded into the sizing rule — folding them in
-  would make "half budget" quietly mean "fewer blocks" at small head counts.
+- `resolve_quant_kv_mode` reads the mode (env over config). The engine reports
+  what the pools hold, data and float32 scales together, as `serve_kv_pool_bytes`.
 - `KVScaleMirror` subscribes to `BlockPool`'s observer hooks and tracks which
   blocks' scale slots are live. The 500-step fuzz asserts the mirror never
   disagrees with the pool: scale allocation tracks block allocation exactly,
@@ -21,8 +16,6 @@ quantize-on-write / dequant-at-gather) lives in the model's
 from __future__ import annotations
 
 import os
-
-import jax.numpy as jnp
 
 KV_MODES = ("none", "int8")
 _ENV_VAR = "MODALITIES_TPU_QUANT_KV"
@@ -43,40 +36,6 @@ def resolve_quant_kv_mode(setting=None) -> str:
     if v in KV_MODES:
         return v
     raise ValueError(f"{source}: invalid KV quant mode {value!r} (expected none|int8)")
-
-
-def kv_block_bytes(
-    block_size: int,
-    n_head_kv: int,
-    head_dim: int,
-    mode: str = "none",
-    cache_dtype=jnp.bfloat16,
-) -> int:
-    """K+V data bytes of ONE pool block for one layer (scales excluded — see
-    module docstring for why the budget is data-only)."""
-    itemsize = 1 if mode == "int8" else jnp.dtype(cache_dtype).itemsize
-    return int(2 * block_size * n_head_kv * head_dim * itemsize)
-
-
-def kv_scale_bytes_per_block(block_size: int, n_head_kv: int) -> int:
-    """Float32 scale bytes of one block: one scale per (row, kv-head) for each
-    of K and V — rows land in a block at different decode steps, so the scale
-    granularity must be per written row, not per block."""
-    return int(2 * block_size * n_head_kv * 4)
-
-
-def kv_blocks_for_budget(
-    budget_bytes: int,
-    block_size: int,
-    n_head_kv: int,
-    head_dim: int,
-    mode: str = "none",
-    cache_dtype=jnp.bfloat16,
-) -> int:
-    """How many pool blocks (per layer) a byte budget buys. int8 doubles the
-    answer vs bf16 at the same budget."""
-    per_block = kv_block_bytes(block_size, n_head_kv, head_dim, mode, cache_dtype)
-    return max(1, int(budget_bytes) // per_block)
 
 
 class KVScaleMirror:

@@ -2,8 +2,8 @@
 rollback) is counted in-process AND emitted to the telemetry sink.
 
 The in-process counters exist so callers that need a *synchronous* answer to
-"did anything degrade this window?" — bench.py's measurement loop, the chaos
-tests — don't have to tail and parse the JSONL sink. Counters are keyed by the
+"did anything degrade this window?" — the chaos and elastic tests — don't
+have to tail and parse the JSONL sink. Counters are keyed by the
 event's first path segment (``anomaly/nonfinite`` counts under ``anomaly``),
 matching the goodput ledger's bucket convention.
 """

@@ -4,7 +4,7 @@ Transient storage errors (flaky NFS/GCS mounts on preemptible pods) should cost
 a retry, not the run. Every attempt after the first runs under a
 ``ckpt_retry/<what>`` telemetry span (goodput bucket: recovery) and emits a
 ``ckpt_retry/attempt`` event, so a run that survived on retries is visible in
-the sink and in bench.py's degraded-window flag.
+the sink and in the in-process event counts (`resilience/events.py`).
 
 Defaults are env-tunable so chaos tests stay fast without plumbing config
 through the checkpoint layers:

@@ -4,8 +4,8 @@
 [max_batch_slots, cache_capacity] shape, ONE compiled decode step advancing every
 active slot per dispatch, and a plain-Python scheduler that admits queued requests
 into freed slots at token boundaries. `serve.py` is the DI/CLI glue
-(`inference_component.serve`), bench_serve.py at the repo root is the load
-generator."""
+(`inference_component.serve`): it replays a requests file or serves HTTP; the
+repo ships no load generator."""
 
 from modalities_tpu.serving.engine import ServeRequest, ServeResult, ServingEngine
 

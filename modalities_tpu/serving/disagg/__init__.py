@@ -11,7 +11,7 @@ digest. The record changes WHERE work runs, never the tokens — greedy
 disaggregated output is bitwise equal to the combined paged path.
 
 - handoff.py   — HandoffRecord + digest + wire (JSON) serialization
-- pair.py      — in-process 1-prefill + 1-decode harness (bench + oracles)
+- pair.py      — in-process 1-prefill + 1-decode harness (the tests' oracles)
 - router.py    — DisaggRouter: two-leg dispatch (prefill leg -> handoff ->
                  decode leg) streaming ONE SSE answer, X-Trace-Id across
                  both legs, decode-leg failover via a fresh prefill

@@ -1,18 +1,13 @@
 """In-process prefill+decode pair: two ServingEngines, one scheduler loop.
 
 The pair is the disagg substrate everything in-process rides on — the bitwise
-parity oracle, the TPOT-isolation bench, the int8 handoff seam test. It drives
+parity oracle and the int8 handoff seam test of `tests/serving/test_disagg.py`. It drives
 both engines' `step()` off ONE clock and hands `HandoffRecord`s across by
 reference (serialization is the HTTP legs' concern, not a semantic one): a
 prefill-tier finish with reason "handoff" becomes an `import_handoff()` on the
 decode tier, `arrival_offset_s` stamped at the moment of handoff so the decode
 engine's `disagg_handoff_seconds` histogram measures handoff->seeded latency
 (pool-full starvation inflates exactly this tail).
-
-`step_hook(pair, dispatched)` fires after every round — the modeled-cost TPOT
-oracle advances its deterministic clock there from the engines' dispatch
-counters. `on_idle(wait_s)` replaces the arrival-wait sleep for modeled
-clocks.
 """
 
 from __future__ import annotations
@@ -61,8 +56,6 @@ class DisaggPair:
         decode,
         *,
         time_fn: Optional[Callable[[], float]] = None,
-        step_hook: Optional[Callable[["DisaggPair", bool], None]] = None,
-        on_idle: Optional[Callable[[float], None]] = None,
     ):
         if prefill.role != "prefill" or decode.role != "decode":
             raise ValueError(
@@ -72,8 +65,6 @@ class DisaggPair:
         self.prefill = prefill
         self.decode = decode
         self._now = time_fn if time_fn is not None else time.monotonic
-        self._step_hook = step_hook
-        self._on_idle = on_idle if on_idle is not None else lambda w: time.sleep(w)
         self._handled: set[int] = set()  # prefill rids already harvested
         self._imported: dict[int, int] = {}  # prefill rid -> decode rid
         self.handoff_failures: list[tuple[int, str]] = []  # (prefill rid, reason)
@@ -116,8 +107,6 @@ class DisaggPair:
             did = self.prefill.step(t0)
             self._harvest_handoffs(t0)
             did = self.decode.step(t0) or did
-            if self._step_hook is not None:
-                self._step_hook(self, did)
             if not self._pending():
                 break
             if not did:
@@ -132,7 +121,7 @@ class DisaggPair:
                     continue  # import in flight between the two steps
                 wait = min(heads) - (self._now() - t0)
                 if wait > 0:
-                    self._on_idle(min(wait, 0.05))
+                    time.sleep(min(wait, 0.05))
         return self.results()
 
     def results(self) -> dict[int, PairResult]:

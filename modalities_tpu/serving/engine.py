@@ -496,7 +496,7 @@ class ServingEngine:
 
         # fleet hot swap (PR 12): request_swap() queues new params from any
         # thread; step() installs them at the next token boundary. Generation
-        # tags every finished result/trace; swap_history feeds the bench report.
+        # tags every finished result/trace; swap_history keeps one record a swap.
         self.weights_generation = 0
         self.weight_swaps = 0
         self.request_errors = 0  # finishes with reason "error" (non-finite logits)

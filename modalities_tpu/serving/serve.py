@@ -5,7 +5,7 @@ Mirrors the generate_text wiring (inference/inference.py): the
 `inference_component.serve` variant is registered dynamically against the shared
 registry, params come from a sealed checkpoint (manifest-verified,
 resilience/manifest.py) or a fresh init, and the component either replays a JSONL
-request file (batch mode — the bench path) or runs an interactive loop."""
+request file (batch mode) or runs an interactive loop."""
 
 from __future__ import annotations
 
@@ -115,7 +115,7 @@ class ServingComponent:
         self.spec_decode = spec_decode
         self.quant = quant or {}
         # The config settings, not resolved modes: the engine resolves env >
-        # config itself so a bench override via env wins consistently.
+        # config itself so an override via env wins consistently.
         self.quant_weights_setting = self.quant.get("weights")
         self.quant_kv_setting = self.quant.get("kv")
         self.http_host = http_host

@@ -85,7 +85,7 @@ def bucket_of(span_name: str) -> str:
 
 class GoodputLedger:
     """Thread-safe accumulator from the span stream (or direct `add_seconds`,
-    for callers like bench.py that time segments without span machinery)."""
+    for callers that time segments without span machinery)."""
 
     def __init__(self):
         self._lock = threading.Lock()

@@ -81,8 +81,8 @@ DEFAULT_LEVERS = (
 )
 
 # Substrings that mark a device allocation failure across backends. XLA raises
-# RESOURCE_EXHAUSTED; some paths stringify to "Out of memory"; bench.py's
-# triage matches the same family.
+# RESOURCE_EXHAUSTED; some paths stringify to "Out of memory", with either
+# capital.
 OOM_MARKERS = ("RESOURCE_EXHAUSTED", "Out of memory", "out of memory")
 
 

@@ -23,11 +23,11 @@ Design constraints:
   #buckets) with no per-sample storage, so a week of serving traffic costs the
   same memory as one request. `quantile()` estimates percentiles by linear
   interpolation inside the winning bucket — the same estimate
-  `histogram_quantile()` would compute server-side, which is what
-  bench_serve.py compares against its exact client-side percentiles.
+  `histogram_quantile()` would compute server-side.
 - **Round-trip.** `parse_prometheus_text` parses what `render` emits (used by
-  bench_serve's end-of-run scrape and the exposition-validity tests); it is a
-  deliberately small parser for OUR exposition subset, not a general one.
+  the exposition-validity tests and every test that reads a `/metrics` page);
+  it is a deliberately small parser for OUR exposition subset, not a general
+  one.
 
 The closure test `tests/test_metric_doc_closure.py` statically asserts every
 metric name registered anywhere under `modalities_tpu/` appears in
@@ -58,8 +58,8 @@ def log_buckets(start: float, factor: float, count: int) -> tuple[float, ...]:
 
 
 # Default latency bounds: 0.5 ms .. ~8.4 s at factor 1.5. Factor-2 buckets make
-# quantile estimates too coarse to compare against exact client percentiles
-# (bench_serve's divergence check); 1.5 keeps the interpolation error moderate
+# quantile estimates too coarse to compare against exact client percentiles;
+# 1.5 keeps the interpolation error moderate
 # at 24 buckets of bookkeeping.
 LATENCY_BUCKETS = log_buckets(0.0005, 1.5, 24)
 
@@ -355,14 +355,6 @@ class MetricsRegistry:
         with self._lock:
             return sorted(self._metrics)
 
-    def reset(self) -> None:
-        """Zero every series, keeping registrations (bench_serve clears warmup
-        observations this way before the measured window)."""
-        with self._lock:
-            metrics = list(self._metrics.values())
-        for metric in metrics:
-            metric.reset()
-
     def render(self) -> str:
         """Prometheus text exposition format 0.0.4 (the `GET /metrics` body)."""
         lines = []
@@ -490,33 +482,3 @@ def parse_prometheus_text(text: str) -> dict[str, dict[tuple, float]]:
         )
         out.setdefault(m.group("name"), {})[tuple(sorted(labels))] = value
     return out
-
-
-def histogram_quantile_from_parsed(
-    parsed: dict[str, dict[tuple, float]], name: str, q: float
-) -> Optional[float]:
-    """`histogram_quantile(q, <name>_bucket)` over a parse_prometheus_text
-    result (label-free series) — bench_serve's server-side percentile scrape."""
-    buckets = parsed.get(f"{name}_bucket")
-    if not buckets:
-        return None
-    rows = []
-    for key, cum in buckets.items():
-        le = dict(key).get("le")
-        if le is None:
-            continue
-        rows.append((math.inf if le == "+Inf" else float(le), cum))
-    rows.sort()
-    total = rows[-1][1] if rows else 0.0
-    if total == 0:
-        return None
-    bounds, counts, prev = [], [], 0.0
-    for bound, cum in rows:
-        if bound == math.inf:
-            continue
-        bounds.append(bound)
-        counts.append(cum - prev)
-        prev = cum
-    if not bounds:
-        return None
-    return _quantile_from_bucket_counts(bounds, counts, total, q)

@@ -506,46 +506,6 @@ def replay_memscope_into_registry(
     return lifted
 
 
-def replay_bench_lines_into_registry(
-    path: Union[str, Path], registry: MetricsRegistry
-) -> int:
-    """Lift the LAST well-formed bench_serve JSON line's numeric fields into
-    ``bench_<key>`` gauges (the final line supersedes the provisional one)."""
-    last = None
-    for row in _iter_jsonl(Path(path)):
-        last = row
-    if last is None:
-        return 0
-    lifted = 0
-    for key, value in last.items():
-        if isinstance(value, (int, float)) and not isinstance(value, bool):
-            registry.gauge(f"bench_{key}", "").set(float(value))
-            lifted += 1
-    return lifted
-
-
-def replay_trajectory_into_registry(
-    folder: Union[str, Path], registry: MetricsRegistry
-) -> int:
-    """Summarize a BENCH_r*/MULTICHIP_r* trajectory folder (the PR-13 loader)
-    into gauges: best bench value + failed/wedged round counts per suite."""
-    from modalities_tpu.utils.benchmarking.trajectory import summarize_trajectory
-
-    summary = summarize_trajectory(folder)
-    lifted = 0
-    if summary.get("best_bench_value") is not None:
-        registry.gauge("bench_best_value", "").set(float(summary["best_bench_value"]))
-        lifted += 1
-    for suite in ("bench", "multichip"):
-        rows = summary.get(suite) or []
-        if not rows:
-            continue
-        bad = sum(1 for r in rows if r.get("status") in ("failed", "wedged", "no_metric", "oom"))
-        registry.gauge(f"{suite}_failed_rounds", "").set(float(bad))
-        lifted += 1
-    return lifted
-
-
 def evaluate_recorded(
     objectives: Sequence[Objective], registry: MetricsRegistry
 ) -> dict:

@@ -23,7 +23,7 @@ sides of the split:
 - "tokens/s" / "MFU": WALL-CLOCK numbers over the window (what a stopwatch sees —
   the honest scoreboard, includes every stall).
 - "tokens/s (device)" / "MFU (device)": the same tokens over the window minus the
-  measured stalls — the device-execution estimate, comparable to bench.py's
+  measured stalls — the device-execution estimate, comparable to a
   per-iteration device timing.
 - "host stall [s]": time the step loop spent blocked waiting for a device-ready
   batch (the feeder's queue wait; with `prefetch_to_device: 0`, the full inline
@@ -312,9 +312,9 @@ class Trainer:
                 # publish the PREVIOUS interval now, with this step already in
                 # flight: the publish's metrics fetch blocks until that interval's
                 # last step completed, but the device is not idle while it does —
-                # the same dispatch-ahead/fetch-behind structure bench.py times
-                # with, so in-app throughput stops paying a per-interval stall
-                # (VERDICT r4 #8). The fetch-return instant starts the next clock,
+                # dispatch ahead, fetch behind,
+                # so in-app throughput stops paying a per-interval stall.
+                # The fetch-return instant starts the next clock,
                 # and the stall accumulators are drained AT the publish, so every
                 # stalled second lands in exactly one window.
                 if deferred_publish is not None:
@@ -582,7 +582,7 @@ class Trainer:
         throughput = {
             "train steps/s": ResultItem(num_steps / wall_elapsed, 2),
             # wall-clock is the scoreboard number; the device split is what
-            # bench.py's per-iteration timing is comparable to (module docstring).
+            # a per-iteration device timing is comparable to (module docstring).
             # The bare "tokens/s"/"MFU" keys stay for dashboard compat; the
             # explicit "(wall)" aliases make the to-disc JSONL self-describing so
             # scoreboard numbers stay auditable offline without knowing that

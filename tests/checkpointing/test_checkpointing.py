@@ -67,9 +67,6 @@ def test_folder_name_roundtrips_through_number_conversion(tmp_path):
     assert NumberConversion.get_global_num_target_tokens_from_checkpoint_path(p) == 10000
 
 
-@pytest.mark.slow  # ~9 s; the save/load roundtrip stays pinned fast leaf-bitwise
-# by test_restore_preserves_optimizer_moments_bitwise below, and the info-file
-# pointer contract by test_async_save_defers_resume_pointer_until_commit
 def test_orbax_save_load_roundtrip_and_info_file(tmp_path):
     mesh = get_device_mesh(device_type="cpu", data_parallel_shard_degree=8, world_size=8)
     model = tiny_gpt2("pytorch_flash")
@@ -131,10 +128,6 @@ def test_double_load_guard(tmp_path):
         loader.load_app_state(fns.app_state_handle, folder)
 
 
-@pytest.mark.slow  # ~13 s twin train runs; cross-topology restore stays pinned fast
-# value-exact by tests/checkpointing/test_topology.py (reshard-at-load e2es), and
-# warmstart-then-train equivalence by tests/end2end_tests/test_acceptance_recipe_twins.py
-# (test_7b_tp_fsdp_twin_then_32k_warmstart_twin)
 def test_warmstart_topology_change_equivalence(tmp_path):
     """Train 6 steps on dp4 x tp2; resume from step 3's checkpoint on dp8; the last
     3 losses must match the uninterrupted run (reference warmstart oracle)."""
@@ -235,10 +228,6 @@ def test_restore_preserves_optimizer_moments_bitwise(tmp_path):
     assert int(loaded.step) == int(state.step) == 4
 
 
-@pytest.mark.slow  # ~9 s twin builds; value-exact reshard-at-load across mesh
-# topologies (incl. slice changes) is pinned fast by tests/checkpointing/
-# test_topology.py::test_reshard_at_load_restores_on_smaller_mesh and
-# test_two_slice_checkpoint_restores_on_single_slice_mesh
 def test_restore_reshards_leaves_bitwise_across_topologies(tmp_path):
     """Sharper than the loss-continuation oracle: save under dp4 x tp2, restore into
     dp8 abstract shardings, and compare every GLOBAL param + opt leaf bitwise —

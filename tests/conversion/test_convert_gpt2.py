@@ -13,9 +13,7 @@ from tests.models.test_gpt2_model import tiny_gpt2
 @pytest.mark.parametrize(
     "tying,kv",
     [
-        # ~10 s; the (False, 4) grid point below keeps the export-logit pin in
-        # tier-1 — same conversion path, only tying/GQA flavor differs
-        pytest.param(True, 2, marks=pytest.mark.slow),
+        (True, 2),
         (False, 4),
     ],
 )

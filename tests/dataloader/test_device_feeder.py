@@ -51,10 +51,6 @@ def _train_losses(prefetch, n_steps=4, acc=2):
     return losses
 
 
-@pytest.mark.slow  # ~13 s (two 4-step train runs); the feeder's relocate-only
-# contract stays pinned fast by test_feeder_stacks_acc_dim_and_counts_dropped_
-# tail (what it computes) + test_sync_mode_accounts_inline_transfer_as_stall and
-# test_trainer_publishes_wall_device_split_and_stalls (how it accounts)
 def test_feeder_async_bitwise_matches_sync():
     """N real optimizer steps through the background pipeline vs the inline path:
     same model seed, same data stream — the losses must be BIT-identical, because

@@ -27,8 +27,6 @@ def _load_tutorial_module():
     return mod
 
 
-@pytest.mark.slow  # ~11 s tutorial e2e; the custom-component registry path is exercised
-# by the main e2e and config tests
 def test_einsum_transformer_trains_via_custom_component(workdir):  # noqa: F811
     mod = _load_tutorial_module()
 

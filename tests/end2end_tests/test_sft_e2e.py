@@ -54,10 +54,6 @@ def sft_workdir(tmp_path, monkeypatch):
     return tmp_path
 
 
-@pytest.mark.slow  # ~16 s full config boot + train; the masked-collator
-# semantics stay pinned fast by tests/dataloader/test_loss_masking.py
-# (test_masks_outside_span et al.) and the e2e train chain by
-# test_main_end_to_end
 def test_sft_loss_masked_config_trains(sft_workdir):
     main = Main(
         CONFIG,

@@ -71,9 +71,6 @@ def tiny_coca():
     )
 
 
-@pytest.mark.slow  # ~7 s init; the ViT forward stays pinned fast by
-# test_vit_encoder_mode_shapes below (same tower, no head) and by
-# test_coca_forward_shapes (a ViT tower embedded in CoCa)
 def test_vit_classification_shapes():
     model = tiny_vit()
     params = model.init_params(jax.random.PRNGKey(0))

@@ -71,9 +71,6 @@ def test_attention_impl_equivalence():
     )
 
 
-@pytest.mark.slow  # ~9 s (two model inits); tier equivalence stays pinned fast
-# at op level by test_attention_impl_equivalence above, and the tiers' shared
-# dropout path by test_manual_and_sdpa_tiers_share_attn_dropout_path below
 def test_model_level_attention_tier_equivalence():
     m1 = tiny_gpt2("manual")
     m2 = tiny_gpt2("pytorch_flash")
@@ -185,7 +182,6 @@ def test_swiglu_hidden_dim():
     assert swiglu_hidden_dim(768, 256) == 512
 
 
-@pytest.mark.slow  # ~15 s remat-policy variant; scan-path remat is the production config
 def test_selective_layer_remat_honored_on_unrolled_blocks():
     """SELECTIVE_LAYER ac_freq > 1 (remat every freq-th block) needs per-layer remat
     decisions: honored on the unrolled-blocks model, numerics identical to no-remat;
@@ -324,7 +320,6 @@ def test_weight_tying_parameter_count_and_absence_of_head():
     assert not any("lm_head" in n and "norm" not in n for n in names)
 
 
-@pytest.mark.slow  # ~10 s; tying is pinned by the parameter-count test and every tied e2e run
 def test_weight_tying_gradient_flows_through_both_uses():
     """Reference test_weight_tying_behavior, functional form. The discriminating
     signal is an UNSEEN vocab row: a lookup-only (untied) embedding gets exactly

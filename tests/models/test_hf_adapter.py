@@ -9,8 +9,6 @@ from modalities_tpu.models.huggingface_adapters.hf_adapter import HFModelAdapter
 from tests.models.test_gpt2_model import tiny_gpt2
 
 
-@pytest.mark.slow  # ~11 s torch roundtrip; export logit equivalence is pinned in
-# tests/conversion/test_convert_gpt2.py which stays in tier-1
 def test_adapter_roundtrip(tmp_path):
     from flax.core import meta
 

@@ -31,9 +31,6 @@ def test_param_dtype_default_is_float32():
     assert all(dt == np.float32 for dt in dtypes.values()), dtypes
 
 
-@pytest.mark.slow  # ~11 s (10 train steps); the param-dtype plumbing through the
-# fsdp2 registry seam stays pinned fast by test_param_dtype_default_is_float32
-# above — this adds the bf16 policy split + no-silent-upcast train loop on top
 def test_bf16_param_dtype_is_honored_and_trains():
     mesh = get_device_mesh(device_type="cpu", data_parallel_shard_degree=8, world_size=8)
     model = tiny_gpt2("pytorch_flash")

@@ -1,6 +1,7 @@
 """Parity + dispatch pins for the fused dequant-matmul (ops/quant_matmul.py,
 ops/pallas/quant_matmul.py): interpret-mode kernel output is BITWISE equal to
-the pure-jnp reference (K is never split, so the contraction order matches),
+the pure-jnp reference where one tile holds N (K is never split, so the contraction
+order matches) and within a few roundings of it where N takes several tiles,
 and the tier/block resolution follows env > autotune > defaults."""
 
 import jax
@@ -42,7 +43,11 @@ def test_interpret_kernel_bitwise_matches_reference(m, k, n, bm, bn):
     got = quant_matmul(x, wq, scale, block_m=bm, block_n=bn, interpret=True)
     want = reference_quant_matmul(x, wq, scale)
     assert got.shape == (m, n) and got.dtype == want.dtype
-    np.testing.assert_array_equal(np.asarray(got), np.asarray(want))
+    # one tile of N is the reference's own dot, bit for bit; several tiles are dots
+    # of another width, which a CPU backend may sum in another order: 4 float32
+    # roundings of the largest output (docs/known_failures.md)
+    atol = 0.0 if n <= bn else 4 * np.finfo(np.float32).eps * float(np.abs(want).max())
+    np.testing.assert_allclose(np.asarray(got), np.asarray(want), rtol=0.0, atol=atol)
 
 
 def test_bf16_inputs_round_trip():

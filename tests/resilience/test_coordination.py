@@ -320,9 +320,6 @@ def _consensus_hlo(stop_consensus):
     return fns.lower_train_step(abstract).as_text()
 
 
-@pytest.mark.slow  # ~11 s (two full train-step lowerings); ballot/consensus
-# semantics stay pinned fast by the unit battery above (test_make_ballot_on_
-# mesh_reduces_with_max, test_resolve_consensus_modes, the agree_resume suite)
 def test_consensus_off_hlo_is_byte_identical_and_on_adds_at_most_one_all_reduce():
     baseline = _consensus_hlo(stop_consensus=False)
     off = _consensus_hlo(stop_consensus=False)

@@ -313,11 +313,6 @@ def test_prefix_sharing_and_spec_decode_on_imported_blocks(model, params, pair,
 # ------------------------------------------------------------- int8 handoff
 
 
-@pytest.mark.slow  # ~20 s (three extra int8 engines + oracle run); int8 KV
-# numerics + the teacher-forced logit oracle stay pinned fast by
-# tests/serving/test_quant_serving.py (test_logit_oracle_gates_the_fully_
-# quantized_mode), and handoff payload/digest verbatim-ship by
-# test_wire_roundtrip_preserves_payload_and_digest above
 def test_int8_handoff_ships_verbatim_at_half_bytes_and_passes_oracle(
     model, params, pair, pair_results
 ):

@@ -74,10 +74,6 @@ def ref(model, params):
 # ----------------------------------------------------------- batch invariance
 
 
-@pytest.mark.slow  # ~7 s (three sequential solo engine runs); the fast tier-1
-# pin for engine-vs-interactive bitwise equality is
-# test_mixed_concurrent_batch_matches_sequential_references (every request is
-# checked against its solo reference, including the 1-active-slot tail rounds)
 def test_single_slot_matches_interactive_path_bitwise(model, params, ref):
     """ISSUE acceptance: 1 active slot == _generate_cached, token for token,
     across greedy / sampled / temperature=None."""
@@ -93,10 +89,6 @@ def test_single_slot_matches_interactive_path_bitwise(model, params, ref):
     assert engine.stats()["decode_executables"] == 1
 
 
-@pytest.mark.slow  # ~13 s; concurrency-invisible-in-tokens stays pinned fast by
-# test_single_slot_matches_interactive_path_bitwise above and by the disagg
-# parity suite (tests/serving/test_disagg.py runs a 5-request mixed
-# temperature/budget trace through 2 slots on pair AND combined engines)
 def test_mixed_concurrent_batch_matches_sequential_references(model, params, ref):
     """Five requests with mixed temperatures/seeds/budgets through 2 slots:
     every completion must equal its solo interactive reference (concurrency is
@@ -244,10 +236,6 @@ def test_prefill_chunk_ladder_env_knob(monkeypatch):
 # ------------------------------------------------------------ mesh sharding
 
 
-@pytest.mark.slow  # ~4 s; the fast tier-1 pin for mesh-annotated decode
-# (NamedSharding-carrying cache leaves + bitwise tokens under dp_shard x tp) is
-# test_paged_engine.py::test_paged_mesh_decode_carries_named_shardings_and_matches
-# on the newer pool layout — the engine-side mesh plumbing is shared
 def test_mesh_sharded_decode_carries_named_shardings_and_matches(model, params, ref):
     """ISSUE acceptance: under a dp_shard x tp mesh the decode step's params and
     KV cache carry mesh NamedShardings (slots ride the batch/dp axis, kv heads

@@ -71,8 +71,6 @@ def paged_engine(model, params, **kwargs):
 # ----------------------------------------------------------- batch invariance
 
 
-@pytest.mark.slow  # ~9 s; bitwise parity + decode_executables==1 stay pinned by
-# the mixed-batch test below (same references, more slots, same one executable)
 def test_paged_single_slot_matches_interactive_path_bitwise(model, params, ref):
     """ISSUE acceptance: 1 paged slot == _generate_cached, token for token,
     across greedy / sampled / temperature=None."""
@@ -85,10 +83,6 @@ def test_paged_single_slot_matches_interactive_path_bitwise(model, params, ref):
     assert engine.stats()["decode_executables"] == 1
 
 
-@pytest.mark.slow  # ~7 s; the fast tier-1 pin for paged mixed-batch bitwise +
-# one-executable-each + pool-drained is now
-# test_prefix_sharing.py::test_prefix_sharing_forks_cow_and_stays_bitwise
-# (4 mixed greedy/sampled requests through 2 paged slots with the same asserts)
 def test_paged_mixed_batch_matches_references_one_executable_each(model, params, ref):
     """Mixed temperatures/seeds/budgets through 2 paged slots: bitwise equal to
     the solo references, ONE decode executable, ONE cross-request prefill
@@ -118,10 +112,6 @@ def test_paged_mixed_batch_matches_references_one_executable_each(model, params,
 # ------------------------------------------------------- length-ceiling lift
 
 
-@pytest.mark.slow  # ~5 s (runs a ring engine just for contrast); the fast
-# tier-1 pin for long paged decode never finishing "capacity" is
-# test_paged_budget_clamped_to_table_ceiling_never_capacity, and the
-# ring-vs-paged overflow contrast is the slow bench_serve paged-vs-ring oracle
 def test_paged_lifts_the_ring_length_ceiling(model, params, ref):
     """ISSUE acceptance: a (prompt, budget) that overflows the 32-token ring
     runs to its full budget under paged with a lifted max_len — finish reasons
@@ -155,8 +145,6 @@ def test_paged_budget_clamped_to_table_ceiling_never_capacity(model, params):
     assert engine.stats()["free_blocks"] == engine.stats()["num_blocks"]
 
 
-@pytest.mark.slow  # ~3 s; the truncated flag is pinned fast in test_engine.py
-# (ring) and the clamp formula by the budget-clamp test above
 def test_paged_overlong_prompt_truncated_and_clamped(model, params, ref):
     """Truncation semantics carry over to paged mode: prompt clipped to the
     last max_len-1 tokens, `truncated` flagged, budget clamped to the table
@@ -197,8 +185,6 @@ def test_pool_exhaustion_preempts_youngest_and_requeues(model, params, ref):
     engine._table_state.check()
 
 
-@pytest.mark.slow  # ~3 s; FIFO + no-leak gating legality stays pinned by the
-# tier-1 scheduler property cases below
 def test_admission_gates_on_free_blocks(model, params):
     """Admission gates on the PROMPT's block demand: while the first request
     holds the pool, a second whose prompt doesn't fit waits in the queue (no
@@ -246,8 +232,6 @@ def test_paged_construction_guards(model, params):
         ServingEngine(model, params, kv_cache="flat")
 
 
-@pytest.mark.slow  # ~3 s ABSOLUTE model build for one constructor ValueError;
-# the other construction guards stay tier-1 above
 def test_paged_max_len_rejected_for_absolute_poe(params):
     """The ceiling lift only exists for relative-position models: ABSOLUTE wpe
     has no rows past the trained sequence length."""
@@ -264,10 +248,8 @@ def test_paged_max_len_rejected_for_absolute_poe(params):
     "kv_cache,case_seed",
     [
         ("ring", 0),
-        # one seed per mode stays tier-1; the second seed of each mode (~3 s
-        # apiece) runs under -m slow only
-        pytest.param("ring", 1, marks=pytest.mark.slow),
-        pytest.param("paged", 0, marks=pytest.mark.slow),
+        ("ring", 1),
+        ("paged", 0),
         ("paged", 1),  # seed 1 shrinks the pool to 8 blocks -> forces preemption
         # seed 2 layers serving v3 onto the same invariants: half the prompts
         # share an 8-token prefix (2 full blocks -> refcount forking) and the

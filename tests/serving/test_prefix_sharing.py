@@ -114,8 +114,6 @@ def test_prefix_sharing_forks_cow_and_stays_bitwise(model, params, ref):
     engine._table_state.check()
 
 
-@pytest.mark.slow  # ~4 s duplicate engine; the knob's resolution is pinned
-# fast by test_prefix_sharing_env_knob and sharing-ON behavior by the test above
 def test_prefix_sharing_off_is_bitwise_identical_with_zero_hits(model, params, ref):
     """kwarg off-switch: same scenario, no forking — tokens unchanged (sharing
     is purely an admission-work optimization), hit counters stay zero."""
@@ -147,9 +145,6 @@ def test_prefix_sharing_env_knob(monkeypatch):
         _prefix_sharing_from_env()
 
 
-@pytest.mark.slow  # ~5 s squeeze run; donor-safety under preemption is also
-# fuzzed at pool level (test_paged_cache) and in the tier-1 scheduler property
-# shared-prefix case (test_paged_engine)
 def test_preempting_a_sharer_never_frees_donor_blocks(model, params, ref):
     """Pool squeeze with live sharing: the youngest slot (a sharer holding
     forked donor blocks) gets preempted — the donor keeps decoding unharmed

@@ -10,7 +10,6 @@ import jax.numpy as jnp
 import pytest
 from flax.core import meta
 
-from modalities_tpu.quant.kv import kv_blocks_for_budget
 from modalities_tpu.quant.weights import quantize_params
 from modalities_tpu.resilience.events import counts_since, snapshot_counts
 from modalities_tpu.serving.engine import ServingEngine
@@ -123,10 +122,6 @@ def test_engine_quantizes_identically_to_the_load_seam(model, params, quant_engi
 # ------------------------------------------------ preemption replay (quantized)
 
 
-@pytest.mark.slow  # ~12 s; preemption-replay determinism stays pinned fast on
-# the bf16 pool by tests/serving/test_paged_engine.py (pool-squeeze replay
-# family) and quantize-on-write numerics by
-# test_logit_oracle_gates_the_fully_quantized_mode
 def test_preemption_replay_deterministic_on_quantized_pool(model, params):
     """The seed-replay determinism contract survives quantization: a pool too
     small for both requests preempts the youngest, and re-admission reproduces
@@ -151,18 +146,6 @@ def test_preemption_replay_deterministic_on_quantized_pool(model, params):
         assert b.finish_reason == "budget"
     assert stats["free_blocks"] == stats["num_blocks"]
     tight_engine._table_state.check()
-
-
-# ------------------------------------------------------------- capacity math
-
-
-def test_half_budget_int8_pool_holds_full_budget_bf16_block_count():
-    """ISSUE acceptance: int8 K/V data is exactly half of bf16, so an int8 pool
-    sized from HALF the byte budget holds >= the bf16 block count."""
-    for budget in (1 << 16, 1 << 20, 123456):
-        bf16 = kv_blocks_for_budget(budget, 16, 2, 64, mode="none")
-        int8 = kv_blocks_for_budget(budget // 2, 16, 2, 64, mode="int8")
-        assert int8 >= bf16
 
 
 # -------------------------------------------------------- oracle gate (CPU)

@@ -33,10 +33,12 @@ from tests.serving.test_engine import _IdTok  # noqa: F401  (ref fixture dep)
 # periodic prompt: the drafter fires every step and the tiny model's greedy
 # trajectory locks onto the repeated token, so acceptance is near-total
 REPEAT = [1, 2, 3] * 6
-# this prompt's greedy trajectory emits thirteen 23s then a 122 — pointing
-# eod_token_id at 122 makes eod land MID-verify-run, after accepted drafts
-EOD_PROMPT = [3, 17, 42, 9, 77, 5, 23]
-EOD_ID = 122
+# this prompt's greedy trajectory emits thirteen 82s then a 109 — pointing
+# eod_token_id at 109 makes eod land MID-verify-run, after accepted drafts
+# (a fresh-init model's argmax: found again by search when a jaxlib moves it,
+# docs/known_failures.md)
+EOD_PROMPT = [72, 13, 46, 56, 41, 79, 82]
+EOD_ID = 109
 
 
 @pytest.fixture(scope="module")
@@ -123,10 +125,6 @@ def test_spec_requires_paged_cache(model, params):
 # ------------------------------------------------ greedy identity + pinning
 
 
-@pytest.mark.slow  # ~8 s extra engine; spec bitwise identity (greedy accept path
-# included) stays pinned fast by
-# test_spec_mixed_batch_bitwise_with_eod_and_sampled_rider below — this adds the
-# mid-draft budget clamp + executable-count accounting on top
 def test_spec_greedy_solo_bitwise_with_budget_clamp(model, params, ref):
     """ISSUE acceptance: greedy spec decode == interactive path token for
     token; a second request on the SAME engine whose budget cuts an accepted
@@ -164,7 +162,7 @@ def test_spec_mixed_batch_bitwise_with_eod_and_sampled_rider(model, params, ref)
     )
     reqs = [
         (REPEAT, 12, 0.0, 0),
-        (EOD_PROMPT, 20, 0.0, 0),  # greedy run hits 122 == eod before budget
+        (EOD_PROMPT, 20, 0.0, 0),  # greedy run hits 109 == eod before budget
         ([7, 7, 7], 6, 0.8, 1),  # sampled rider: proposal-exempt by design
     ]
     rids = [engine.submit(p, b, temperature=t, seed=s) for p, b, t, s in reqs]
@@ -182,9 +180,6 @@ def test_spec_mixed_batch_bitwise_with_eod_and_sampled_rider(model, params, ref)
     engine._table_state.check()
 
 
-@pytest.mark.slow  # ~4 s extra engine; the preemption mechanics stay pinned
-# fast by test_pool_exhaustion_preempts_youngest_and_requeues and spec identity
-# by the mixed-batch tier-1 test above
 def test_spec_preemption_replays_bitwise(model, params, ref):
     """Pool exhaustion preempts a speculating slot: on re-admission the pure
     drafter re-proposes from the identical context and the greedy trajectory

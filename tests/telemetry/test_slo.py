@@ -18,7 +18,6 @@ from modalities_tpu.telemetry.slo import (
     evaluate_recorded,
     load_slo_spec,
     parse_objective,
-    replay_bench_lines_into_registry,
     replay_sink_into_registry,
     tenant_objectives,
 )
@@ -327,17 +326,6 @@ def test_replay_sink_rebuilds_judgeable_series(tmp_path):
     assert reg.get("serve_ttft_seconds").count() == 20
     assert reg.get("training_mfu_achieved").value() == 0.4
     assert reg.get("training_goodput_ratio").value() == 1.0  # all-train_step sink
-
-
-def test_replay_bench_lines_takes_the_last_line(tmp_path):
-    path = tmp_path / "bench.jsonl"
-    path.write_text(
-        json.dumps({"provisional": True, "tokens_per_s": None}) + "\n"
-        + json.dumps({"provisional": False, "tokens_per_s": 123.0, "smoke": True}) + "\n"
-    )
-    reg = MetricsRegistry()
-    assert replay_bench_lines_into_registry(path, reg) == 1  # bools/None skipped
-    assert reg.get("bench_tokens_per_s").value() == 123.0
 
 
 def test_evaluate_recorded_splits_ok_breaching_skipped(tmp_path):

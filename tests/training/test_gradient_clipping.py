@@ -45,9 +45,7 @@ def test_clip_by_norm_mode_no_op_below_max_norm():
 @pytest.mark.parametrize(
     "norm_type",
     [
-        # ~18 s per variant; p1 norm math is pinned fast by the unit tests
-        # above — one non-p2 mode through the full train step is enough tier-1
-        pytest.param("p1_norm", marks=pytest.mark.slow),
+        "p1_norm",
         "max_norm",
     ],
 )
@@ -70,9 +68,6 @@ def test_train_step_with_non_p2_clipper(norm_type):
     assert float(metrics["grad_norm"]) > 0
 
 
-@pytest.mark.slow  # ~18 s full 8-dp train step for one metric key; the raise
-# path is pinned fast by test_trainer_raises_on_nonfinite_grads below and the
-# flag e2e by the chaos nan-grads raise test (-m slow)
 def test_error_if_nonfinite_flag_in_metrics():
     mesh = get_device_mesh(device_type="cpu", data_parallel_shard_degree=8, world_size=8)
     model = tiny_gpt2("pytorch_flash")

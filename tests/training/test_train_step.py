@@ -44,9 +44,6 @@ def _batch(rng, acc, mb, seq, vocab=128):
     }
 
 
-@pytest.mark.slow  # ~13 s (20 optimizer steps); loss-actually-decreases stays
-# pinned fast by tests/end2end_tests/test_main_e2e.py::test_main_end_to_end
-# (full CLI training loop asserting train loss falls)
 def test_loss_decreases_dp():
     mesh = get_device_mesh(device_type="cpu", data_parallel_shard_degree=8, world_size=8)
     model = tiny_gpt2("pytorch_flash")
@@ -135,8 +132,6 @@ def test_params_actually_sharded():
     assert big and any(not x.sharding.is_fully_replicated for x in big)
 
 
-@pytest.mark.slow  # ~15 s; one of the dp/pp/cp equivalence family —
-# loss_parallel and the pp combinations keep the mesh-equivalence net in tier-1
 def test_dp_hsdp_equivalence():
     """dp8 vs HSDP (dp_replicate2 x dp_shard4): the reference's HYBRID_SHARD
     headline layout (model_factory.py:205-211, BASELINE.md HYBRID rows) — params
@@ -932,7 +927,6 @@ def test_chunked_lm_head_ragged_tail_under_scheduled_pp():
     np.testing.assert_allclose(losses[None], losses[5], rtol=3e-4, atol=3e-4)
 
 
-@pytest.mark.slow  # ~16 s; kernel numerics pinned op-level in tests/ops/test_fused_rmsnorm.py
 def test_fused_rmsnorm_forced_matches_reference(monkeypatch):
     """MODALITIES_TPU_FUSED_RMSNORM=1 swaps every norm in the model for the
     Pallas kernel (interpret on CPU); training losses must match the reference
