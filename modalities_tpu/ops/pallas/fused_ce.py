@@ -3,16 +3,25 @@
 The LM head + CE is the single biggest HBM hog left in train_step: even the
 chunked scan materializes a `[B, chunk, V]` fp32 logits buffer per step and
 recomputes the whole chunk projection in the backward under `jax.checkpoint`.
-This kernel family never writes logits to HBM in either pass:
+This kernel family never writes logits to HBM in either pass, and computes them
+twice a step (8 N E V of matmul for the 6 required; without a saved `[N, V]`
+array that is the floor):
 
-- forward: stream the vocab dimension tile-by-tile, keeping the per-row running
-  max / exp-sum (flash-style online logsumexp) and the gathered correct-class
-  logit in `[block_rows, 1]` VMEM scratch; only `lse` and `corr` (two `[N, 1]`
-  vectors) ever reach HBM.
-- backward (custom_vjp): regenerate the softmax tile-wise from the saved `lse`
-  — `ds = g * mask * (exp(s - lse) - onehot(label))` — and contract it on the
-  fly into `d_hidden` (vocab-innermost accumulation) and `d_head_weight`
-  (rows-innermost accumulation). The `[*, V]` tensor never exists.
+- forward, no gradient wanted (`fused_ce_eval`): stream the vocab dimension
+  tile-by-tile, keeping the per-row running max / exp-sum (flash-style online
+  logsumexp) and the gathered correct-class logit in `[block_rows, 1]` VMEM
+  scratch; only `lse` and `corr` (two `[N, 1]` vectors) ever reach HBM.
+- forward under differentiation (`fused_ce_fwd`, the custom_vjp's forward rule):
+  the same pass also carries `sum_v softmax(s)[v] * W[v]`, the softmax's mean row
+  of W, in an fp32 `[block_rows, E]` accumulator that is rescaled with the
+  running max as flash attention's output is. The gradient of the hidden rows is
+  that mean minus `W[label]`, so the backward needs no kernel for it:
+  `d_hidden = g * mask * (mean - W[label])`, a gather and one elementwise pass.
+- backward (`fused_ce_bwd_dw`): regenerate the softmax tile-wise from the saved
+  `lse` — `ds = g * mask * (exp(s - lse) - onehot(label))` — and contract it on
+  the fly into `d_head_weight` (rows-innermost accumulation). It needs `lse` over
+  the whole vocabulary before any tile of it is right, so this recomputation
+  cannot ride the forward. The `[*, V]` tensor never exists.
 
 All tile math accumulates in fp32 regardless of input dtype (bf16 hidden is the
 production case). `interpret=True` runs the same kernels under the Pallas CPU
@@ -59,20 +68,29 @@ def _vocab_block(v: int, preferred: int) -> int:
 _VMEM_BUDGET_BYTES = 15 * 2**20
 
 
+def _forward_vmem_bytes(block_n: int, block_v: int, e: int, itemsize: int, dh_in_forward: bool = True) -> int:
+    """What the forward kernel holds: the hidden block and the streamed head block
+    double-buffered, one fp32 score tile, the three `[block_n, 1]` columns (a lane tile
+    wide each); carrying dh adds the fp32 accumulator and the fp32 output block,
+    double-buffered."""
+    lean = e * (block_n + block_v) * 2 * itemsize + 4 * block_n * block_v + 3 * 512 * block_n
+    return lean + (12 * block_n * e if dh_in_forward else 0)
+
+
+def _bwd_dw_vmem_bytes(block_n: int, block_v: int, e: int, itemsize: int) -> int:
+    """What `fused_ce_bwd_dw` holds: head block in and gradient block out, both
+    double-buffered, the fp32 accumulator, the streamed hidden block and one score tile."""
+    return e * (block_v * (4 * itemsize + 4) + block_n * 2 * itemsize) + 4 * block_n * block_v
+
+
 def _fit_blocks_to_vmem(block_n: int, block_v: int, e: int, itemsize: int) -> tuple[int, int]:
-    """Halve the larger block until both backward kernels fit scoped VMEM.
-
-    Each backward kernel holds, for the side it accumulates over (vocab tiles
-    for d_head_weight, row tiles for d_hidden), a double-buffered input block,
-    a double-buffered output block and an fp32 accumulator; for the side it
-    streams, a double-buffered input block; plus one fp32 score tile. At
-    256x512 that is 18 MiB for bf16 at E 2560 — the shipped blocks were sized
-    at E 1536, where it is 11."""
-
-    def need(acc_rows: int, stream_rows: int) -> int:
-        return e * (acc_rows * (4 * itemsize + 4) + stream_rows * 2 * itemsize) + 4 * block_n * block_v
-
-    while max(need(block_v, block_n), need(block_n, block_v)) > _VMEM_BUDGET_BYTES:
+    """Halve the larger block until the differentiated forward and the backward
+    kernel both fit scoped VMEM. At 256x512 the backward is 18 MiB for bf16 at
+    E 2560 (the shipped blocks were sized at E 1536, where it is 11); at 256x256
+    the forward is 13.1 MiB there and the backward 10.2."""
+    while max(
+        _forward_vmem_bytes(block_n, block_v, e, itemsize), _bwd_dw_vmem_bytes(block_n, block_v, e, itemsize)
+    ) > _VMEM_BUDGET_BYTES:
         if block_v >= block_n and block_v > 128:
             block_v //= 2
         elif block_n > 8:
@@ -82,12 +100,42 @@ def _fit_blocks_to_vmem(block_n: int, block_v: int, e: int, itemsize: int) -> tu
     return block_n, block_v
 
 
+def ce_plan(rows: int, vocab: int, vocab_padded: int, e: int, block_n: int, block_v: int, itemsize: int,
+            dh_in_forward: bool) -> dict:
+    """What one traced call does: the facts of the sink event `fused_ce_plan`. `rows` as the
+    kernels hold them (padded to the block; one shard's under a mesh). A differentiated call
+    computes the logits in `fused_ce_fwd` and again in `fused_ce_bwd_dw`; a call nobody
+    differentiates runs `fused_ce_eval` alone."""
+    steps = (rows // block_n) * (vocab_padded // block_v)
+    return {
+        "rows": rows, "vocab": vocab, "vocab_padded": vocab_padded, "n_embd": e,
+        "block_rows": block_n, "block_vocab": block_v,
+        "dh_in_forward": dh_in_forward,
+        "grid_steps_forward": steps, "grid_steps_bwd_dw": steps if dh_in_forward else 0,
+        # matmul operations in units of rows x n_embd x vocab: done (logits and mean row, logits
+        # and dW; or the logits alone) beside required (logits, dh, dW; or the logits)
+        "nev_done": 8 if dh_in_forward else 2, "nev_required": 6 if dh_in_forward else 2,
+        "forward_vmem_bytes": _forward_vmem_bytes(block_n, block_v, e, itemsize, dh_in_forward),
+    }
+
+
+def _say_plan(h, w, block_n: int, block_v: int, vocab: int, dh_in_forward: bool) -> None:
+    """Runs while tracing, from the custom_vjp's primal and from its forward rule (only they
+    know whether the call is differentiated): once per shape on the sink, nothing per step."""
+    from modalities_tpu.telemetry import get_active_telemetry
+
+    plan = ce_plan(h.shape[0], vocab, w.shape[0], h.shape[1], block_n, block_v, jnp.dtype(h.dtype).itemsize, dh_in_forward)
+    get_active_telemetry().emit_event_once("fused_ce_plan", plan)
+
+
 # ------------------------------------------------------------------ forward
 
 
-def _fwd_kernel(h_ref, w_ref, y_ref, lse_ref, corr_ref, m_ref, l_ref, c_ref, *, block_v, vocab):
+def _running_softmax_tile(h_ref, w_ref, y_ref, m_ref, l_ref, c_ref, *, block_v, vocab):
+    """One vocabulary tile of the online logsumexp: updates the running max, exp-sum and
+    gathered correct-class logit, and hands back the tile of `exp(s - m_new)`, the factor
+    `exp(m_prev - m_new)` that brings earlier sums to the new max, and the head block."""
     jv = pl.program_id(1)
-    nv = pl.num_programs(1)
 
     @pl.when(jv == 0)
     def _init():
@@ -110,42 +158,67 @@ def _fwd_kernel(h_ref, w_ref, y_ref, lse_ref, corr_ref, m_ref, l_ref, c_ref, *, 
 
     m_prev = m_ref[...]
     m_new = jnp.maximum(m_prev, s.max(axis=-1, keepdims=True))
-    l_ref[...] = l_ref[...] * jnp.exp(m_prev - m_new) + jnp.exp(s - m_new).sum(axis=-1, keepdims=True)
+    rescale = jnp.exp(m_prev - m_new)
+    p = jnp.exp(s - m_new)
+    l_ref[...] = l_ref[...] * rescale + p.sum(axis=-1, keepdims=True)
     m_ref[...] = m_new
+    return p, rescale, w
 
-    @pl.when(jv == nv - 1)
+
+def _write_stats(lse_ref, corr_ref, m_ref, l_ref, c_ref):
+    lse_ref[...] = m_ref[...] + jnp.log(jnp.maximum(l_ref[...], 1e-37))
+    corr_ref[...] = c_ref[...]
+
+
+def _eval_kernel(h_ref, w_ref, y_ref, lse_ref, corr_ref, m_ref, l_ref, c_ref, *, block_v, vocab):
+    _running_softmax_tile(h_ref, w_ref, y_ref, m_ref, l_ref, c_ref, block_v=block_v, vocab=vocab)
+
+    @pl.when(pl.program_id(1) == pl.num_programs(1) - 1)
     def _finish():
-        lse_ref[...] = m_ref[...] + jnp.log(jnp.maximum(l_ref[...], 1e-37))
-        corr_ref[...] = c_ref[...]
+        _write_stats(lse_ref, corr_ref, m_ref, l_ref, c_ref)
 
 
-def _ce_forward(h, w, labels2, block_n, block_v, vocab, interpret):
+def _fwd_kernel(h_ref, w_ref, y_ref, lse_ref, corr_ref, mean_ref, m_ref, l_ref, c_ref, acc_ref, *, block_v, vocab):
+    """`_eval_kernel` that also carries `sum_v exp(s[v] - m) W[v]` with the running max, as
+    flash attention carries its output; over the exp-sum at the last tile it is the
+    softmax's mean row of W, which is d_hidden but for `W[label]`, the mask and the scale."""
+    @pl.when(pl.program_id(1) == 0)
+    def _init():
+        acc_ref[...] = jnp.zeros_like(acc_ref)
+
+    p, rescale, w = _running_softmax_tile(h_ref, w_ref, y_ref, m_ref, l_ref, c_ref, block_v=block_v, vocab=vocab)
+    acc_ref[...] = acc_ref[...] * rescale + jax.lax.dot_general(
+        p, w, (((1,), (0,)), ((), ())), preferred_element_type=jnp.float32
+    )
+
+    @pl.when(pl.program_id(1) == pl.num_programs(1) - 1)
+    def _finish():
+        _write_stats(lse_ref, corr_ref, m_ref, l_ref, c_ref)
+        mean_ref[...] = acc_ref[...] / jnp.maximum(l_ref[...], 1e-37)
+
+
+def _ce_forward(h, w, labels2, block_n, block_v, vocab, interpret, dh_in_forward):
+    """`(lse, corr)`, and with `dh_in_forward` the fp32 `[N, E]` mean row of W as a third."""
     n, e = h.shape
-    v_padded = w.shape[0]
-    grid = (n // block_n, v_padded // block_v)
+    column = pl.BlockSpec((block_n, 1), lambda i, j: (i, 0))
+    rows = pl.BlockSpec((block_n, e), lambda i, j: (i, 0))
+    out_specs = [column, column]
+    out_shape = [jax.ShapeDtypeStruct((n, 1), jnp.float32)] * 2
+    scratch_shapes = [pltpu.VMEM((block_n, 1), jnp.float32)] * 3
+    if dh_in_forward:
+        out_specs.append(rows)
+        out_shape.append(jax.ShapeDtypeStruct((n, e), jnp.float32))
+        scratch_shapes.append(pltpu.VMEM((block_n, e), jnp.float32))
     return pl.pallas_call(
-        functools.partial(_fwd_kernel, block_v=block_v, vocab=vocab),
-        grid=grid,
-        in_specs=[
-            pl.BlockSpec((block_n, e), lambda i, j: (i, 0)),
-            pl.BlockSpec((block_v, e), lambda i, j: (j, 0)),
-            pl.BlockSpec((block_n, 1), lambda i, j: (i, 0)),
-        ],
-        out_specs=[
-            pl.BlockSpec((block_n, 1), lambda i, j: (i, 0)),
-            pl.BlockSpec((block_n, 1), lambda i, j: (i, 0)),
-        ],
-        out_shape=[
-            jax.ShapeDtypeStruct((n, 1), jnp.float32),
-            jax.ShapeDtypeStruct((n, 1), jnp.float32),
-        ],
-        scratch_shapes=[
-            pltpu.VMEM((block_n, 1), jnp.float32),
-            pltpu.VMEM((block_n, 1), jnp.float32),
-            pltpu.VMEM((block_n, 1), jnp.float32),
-        ],
+        functools.partial(_fwd_kernel if dh_in_forward else _eval_kernel, block_v=block_v, vocab=vocab),
+        grid=(n // block_n, w.shape[0] // block_v),  # vocab innermost: the running sums over tiles
+        in_specs=[rows, pl.BlockSpec((block_v, e), lambda i, j: (j, 0)), column],
+        out_specs=out_specs,
+        out_shape=out_shape,
+        scratch_shapes=scratch_shapes,
         interpret=interpret,
-        name="fused_ce_fwd",
+        # the differentiated step's forward keeps the name the train cells' traces are read by
+        name="fused_ce_fwd" if dh_in_forward else "fused_ce_eval",
     )(h, w, labels2)
 
 
@@ -168,23 +241,6 @@ def _softmax_delta(h_ref, w_ref, y_ref, lse_ref, gm_ref, jv, *, block_v, vocab):
     return gm * (p - jnp.where(col == labels, 1.0, 0.0))
 
 
-def _bwd_dh_kernel(h_ref, w_ref, y_ref, lse_ref, gm_ref, dh_ref, acc_ref, *, block_v, vocab):
-    jv = pl.program_id(1)
-    nv = pl.num_programs(1)
-
-    @pl.when(jv == 0)
-    def _init():
-        acc_ref[...] = jnp.zeros_like(acc_ref)
-
-    ds = _softmax_delta(h_ref, w_ref, y_ref, lse_ref, gm_ref, jv, block_v=block_v, vocab=vocab)
-    w = w_ref[...].astype(jnp.float32)
-    acc_ref[...] += jax.lax.dot_general(ds, w, (((1,), (0,)), ((), ())), preferred_element_type=jnp.float32)
-
-    @pl.when(jv == nv - 1)
-    def _finish():
-        dh_ref[...] = acc_ref[...].astype(dh_ref.dtype)
-
-
 def _bwd_dw_kernel(h_ref, w_ref, y_ref, lse_ref, gm_ref, dw_ref, acc_ref, *, block_v, vocab):
     jv = pl.program_id(0)
     ir = pl.program_id(1)
@@ -203,35 +259,19 @@ def _bwd_dw_kernel(h_ref, w_ref, y_ref, lse_ref, gm_ref, dw_ref, acc_ref, *, blo
         dw_ref[...] = acc_ref[...].astype(dw_ref.dtype)
 
 
-def _ce_backward(h, w, labels2, lse, gm, block_n, block_v, vocab, interpret):
+def _ce_backward_dw(h, w, labels2, lse, gm, block_n, block_v, vocab, interpret):
     n, e = h.shape
     v_padded = w.shape[0]
-    row_specs = dict(h=(block_n, e), y=(block_n, 1))
-    dh = pl.pallas_call(
-        functools.partial(_bwd_dh_kernel, block_v=block_v, vocab=vocab),
-        grid=(n // block_n, v_padded // block_v),  # vocab innermost: acc over tiles
-        in_specs=[
-            pl.BlockSpec(row_specs["h"], lambda i, j: (i, 0)),
-            pl.BlockSpec((block_v, e), lambda i, j: (j, 0)),
-            pl.BlockSpec(row_specs["y"], lambda i, j: (i, 0)),
-            pl.BlockSpec((block_n, 1), lambda i, j: (i, 0)),
-            pl.BlockSpec((block_n, 1), lambda i, j: (i, 0)),
-        ],
-        out_specs=pl.BlockSpec((block_n, e), lambda i, j: (i, 0)),
-        out_shape=jax.ShapeDtypeStruct((n, e), h.dtype),
-        scratch_shapes=[pltpu.VMEM((block_n, e), jnp.float32)],
-        interpret=interpret,
-        name="fused_ce_bwd_dh",
-    )(h, w, labels2, lse, gm)
-    dw = pl.pallas_call(
+    column = pl.BlockSpec((block_n, 1), lambda j, i: (i, 0))
+    return pl.pallas_call(
         functools.partial(_bwd_dw_kernel, block_v=block_v, vocab=vocab),
         grid=(v_padded // block_v, n // block_n),  # rows innermost: acc over tiles
         in_specs=[
-            pl.BlockSpec(row_specs["h"], lambda j, i: (i, 0)),
+            pl.BlockSpec((block_n, e), lambda j, i: (i, 0)),
             pl.BlockSpec((block_v, e), lambda j, i: (j, 0)),
-            pl.BlockSpec(row_specs["y"], lambda j, i: (i, 0)),
-            pl.BlockSpec((block_n, 1), lambda j, i: (i, 0)),
-            pl.BlockSpec((block_n, 1), lambda j, i: (i, 0)),
+            column,  # labels, lse, g * mask
+            column,
+            column,
         ],
         out_specs=pl.BlockSpec((block_v, e), lambda j, i: (j, 0)),
         out_shape=jax.ShapeDtypeStruct((v_padded, e), w.dtype),
@@ -239,31 +279,42 @@ def _ce_backward(h, w, labels2, lse, gm, block_n, block_v, vocab, interpret):
         interpret=interpret,
         name="fused_ce_bwd_dw",
     )(h, w, labels2, lse, gm)
-    return dh, dw
 
 
 # ---------------------------------------------------------------- custom_vjp
 
 
+def _sum_and_count(lse, corr, labels2, ignore_index):
+    mask = (labels2 != ignore_index).astype(jnp.float32)  # [N, 1]
+    return ((lse - corr) * mask).sum(), mask.sum(), mask
+
+
 @functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4, 5, 6, 7))
 def _fused_ce(h, w, labels2, ignore_index, block_n, block_v, vocab, interpret):
-    (total, count), _ = _fused_ce_fwd(h, w, labels2, ignore_index, block_n, block_v, vocab, interpret)
+    """Traced only where nobody differentiates the call (an evaluator, the first pass of a
+    head under `jax.checkpoint`): the lean kernel, which pays for no dh."""
+    _say_plan(h, w, block_n, block_v, vocab, dh_in_forward=False)
+    lse, corr = _ce_forward(h, w, labels2, block_n, block_v, vocab, interpret, dh_in_forward=False)
+    total, count, _ = _sum_and_count(lse, corr, labels2, ignore_index)
     return total, count
 
 
 def _fused_ce_fwd(h, w, labels2, ignore_index, block_n, block_v, vocab, interpret):
-    lse, corr = _ce_forward(h, w, labels2, block_n, block_v, vocab, interpret)
-    mask = (labels2 != ignore_index).astype(jnp.float32)  # [N, 1]
-    total = ((lse - corr) * mask).sum()
-    count = mask.sum()
-    return (total, count), (h, w, labels2, lse, mask)
+    _say_plan(h, w, block_n, block_v, vocab, dh_in_forward=True)
+    lse, corr, mean_w = _ce_forward(h, w, labels2, block_n, block_v, vocab, interpret, dh_in_forward=True)
+    total, count, mask = _sum_and_count(lse, corr, labels2, ignore_index)
+    return (total, count), (h, w, labels2, lse, mask, mean_w)
 
 
 def _fused_ce_bwd(ignore_index, block_n, block_v, vocab, interpret, residuals, cotangents):
-    h, w, labels2, lse, mask = residuals
+    h, w, labels2, lse, mask, mean_w = residuals
     g_total, _g_count = cotangents  # count is a function of the int labels only
     gm = (g_total * mask).astype(jnp.float32)  # [N, 1]
-    dh, dw = _ce_backward(h, w, labels2, lse, gm, block_n, block_v, vocab, interpret)
+    # the one-hot term is a row of the head per label (the embedding lookup's access pattern);
+    # an ignored row's label may be any number: it reads row 0 and the mask zeroes it
+    label_rows = w[jnp.where(mask > 0, labels2, 0)[:, 0]].astype(jnp.float32)
+    dh = (gm * (mean_w - label_rows)).astype(h.dtype)
+    dw = _ce_backward_dw(h, w, labels2, lse, gm, block_n, block_v, vocab, interpret)
     dlabels = np.zeros(labels2.shape, dtype=jax.dtypes.float0)
     return dh, dw, dlabels
 

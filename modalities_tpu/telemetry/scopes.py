@@ -51,7 +51,7 @@ on the stack): `GPT2Module`, `blocks/block` (`h_<i>` when the layers are not sca
 `ffn_norm`, `lm_head_norm`, `lm_head`; a model whose layers are of more than one kind
 puts `run_<i>` before `blocks/block` (one scan a run of equal layers), and the state-space
 mixer's projections are `ssm/{in_proj,x_proj,dt_proj,out_proj}` with `ssm/{dt_norm,b_norm,c_norm}`. Kernels keep the `name=` of their Pallas call:
-`flash_attention_{fwd,bwd_dq,bwd_dkv}`, `fused_ce_{fwd,bwd_dh,bwd_dw}`,
+`flash_attention_{fwd,bwd_dq,bwd_dkv}`, `fused_ce_{fwd,bwd_dw}` (`fused_ce_eval` where nobody differentiates the call),
 `fused_rmsnorm_{fwd,bwd}`, `selective_scan_{fwd,bwd}` (the recurrence on a TPU, under
 `ssm/scan`); the instruction of a call is named by it, and metrics select by that name.
 

@@ -1,6 +1,8 @@
 """Vocab-streaming fused cross-entropy vs the dense optax oracle (fwd + grads),
 in Pallas interpret mode on CPU — same pattern as test_flash_attention.py."""
 
+import functools
+
 import jax
 import jax.numpy as jnp
 import numpy as np
@@ -68,26 +70,103 @@ def test_all_rows_ignored_zero_count():
     assert float(got_count) == 0.0
 
 
-def test_gradients_match_oracle():
-    h, w, y = _inputs(3, 21, 200, 48)
-    y = y.at[2].set(-100)  # an ignored row must contribute zero grad
+def _argmax_in_last_tile(seed, rows, vocab, embd):
+    """Every row's largest logit sits in the last vocabulary tile: the running max moves at
+    the last step, so everything the forward has summed by then is rescaled at once."""
+    h, w, y = _inputs(seed, rows, vocab, embd)
+    w = w.at[vocab - rows:].set(3.0 * h)  # row i's own direction, three times over, in column vocab - rows + i
+    assert (jnp.argmax(h @ w.T, axis=-1) == vocab - rows + jnp.arange(rows)).all()
+    return h, w, y
 
-    def loss_fused(h, w):
-        total, count = fused_ce_sum_and_count(
-            h, w, y, block_rows=8, block_vocab=128, interpret=True
-        )
-        return total / jnp.maximum(count, 1.0)
 
-    def loss_oracle(h, w):
-        total, count = _oracle_sum_and_count(h, w, y)
-        return total / jnp.maximum(count, 1.0)
+GRADIENT_CASES = {
+    # what each case changes of the defaults in the test: blocks 8 x 128, float32, cotangent 1, no row ignored
+    "padded_rows_and_vocab_one_ignored": dict(rows=21, vocab=200, embd=48, ignored=[2]),
+    "vocab_50304_against_block_512": dict(rows=12, vocab=50304, embd=16, block_rows=8, block_vocab=512),
+    "rows_ignored": dict(rows=24, vocab=256, embd=32, ignored=range(7)),
+    "all_rows_ignored": dict(rows=16, vocab=256, embd=32, ignored=range(16)),
+    "bf16_hidden_and_head": dict(rows=32, vocab=256, embd=64, dtype=jnp.bfloat16, tolerance=2e-2),
+    "cotangent_not_one": dict(rows=16, vocab=384, embd=32, cotangent=-2.5),
+    "largest_logit_in_last_tile": dict(rows=16, vocab=384, embd=32, inputs=_argmax_in_last_tile),
+}
 
-    gh_f, gw_f = jax.grad(loss_fused, argnums=(0, 1))(h, w)
-    gh_o, gw_o = jax.grad(loss_oracle, argnums=(0, 1))(h, w)
-    np.testing.assert_allclose(np.asarray(gh_f), np.asarray(gh_o), rtol=1e-4, atol=1e-5)
-    np.testing.assert_allclose(np.asarray(gw_f), np.asarray(gw_o), rtol=1e-4, atol=1e-5)
-    # padded-row / padded-vocab pollution check: grads carry the primal shapes
-    assert gh_f.shape == h.shape and gw_f.shape == w.shape
+
+@pytest.mark.parametrize("case", sorted(GRADIENT_CASES))
+def test_gradients_match_oracle(case):
+    """d_hidden comes out of the forward kernel's running sums, d_head_weight out of the
+    backward kernel: both against the float32 oracle's, at the primal shapes and dtypes."""
+    spec = {"block_rows": 8, "block_vocab": 128, "dtype": jnp.float32, "tolerance": 1e-4, "cotangent": 1.0,
+            "ignored": [], "inputs": _inputs, **GRADIENT_CASES[case]}
+    h, w, y = spec["inputs"](3, spec["rows"], spec["vocab"], spec["embd"])
+    h, w = h.astype(spec["dtype"]), w.astype(spec["dtype"])
+    y = y.at[jnp.asarray(list(spec["ignored"]), jnp.int32)].set(-100)  # an ignored row must contribute zero grad
+
+    def mean_loss(sum_and_count, h, w):
+        total, count = sum_and_count(h, w, y)
+        return spec["cotangent"] * total / jnp.maximum(count, 1.0)
+
+    fused = functools.partial(
+        fused_ce_sum_and_count, block_rows=spec["block_rows"], block_vocab=spec["block_vocab"], interpret=True
+    )
+    gh_f, gw_f = jax.grad(functools.partial(mean_loss, fused), argnums=(0, 1))(h, w)
+    gh_o, gw_o = jax.grad(functools.partial(mean_loss, _oracle_sum_and_count), argnums=(0, 1))(
+        h.astype(jnp.float32), w.astype(jnp.float32)
+    )
+    # padded-row / padded-vocab pollution check: grads carry the primal shapes and dtypes
+    assert (gh_f.shape, gh_f.dtype, gw_f.shape, gw_f.dtype) == (h.shape, h.dtype, w.shape, w.dtype)
+    tolerance = spec["tolerance"]
+    for got, want in ((gh_f, gh_o), (gw_f, gw_o)):
+        scale = max(float(jnp.abs(want).max()), 1e-30)
+        np.testing.assert_allclose(np.asarray(got, np.float32) / scale, np.asarray(want) / scale, rtol=tolerance, atol=tolerance)
+    if len(list(spec["ignored"])) == spec["rows"]:
+        assert not np.asarray(gh_f).any() and not np.asarray(gw_f).any()
+
+
+def test_tied_table_gets_both_gradients():
+    """One `[V, E]` table looked up as the embedding and used as the head (the hybrid cell's
+    `wte`): its gradient is the lookup's scatter plus the head's, and the backward's own row
+    gather for the one-hot term reads the same array."""
+    table = jax.random.normal(jax.random.PRNGKey(0), (200, 32)) * 0.3
+    tokens = jax.random.randint(jax.random.PRNGKey(1), (24,), 0, 200)
+    y = jax.random.randint(jax.random.PRNGKey(2), (24,), 0, 200).at[5].set(-100)
+
+    def loss(sum_and_count, table):
+        total, count = sum_and_count(jnp.tanh(table[tokens]), table, y)
+        return total / count
+
+    fused = functools.partial(fused_ce_sum_and_count, block_rows=8, block_vocab=128, interpret=True)
+    got = jax.grad(functools.partial(loss, fused))(table)
+    want = jax.grad(functools.partial(loss, _oracle_sum_and_count))(table)
+    np.testing.assert_allclose(np.asarray(got), np.asarray(want), rtol=1e-4, atol=1e-6)
+
+
+def _pallas_calls(jaxpr):
+    """Every `pallas_call` equation of a jaxpr, those inside its sub-jaxprs too."""
+    found = []
+    for eqn in jaxpr.eqns:
+        if eqn.primitive.name == "pallas_call":
+            found.append(eqn)
+        for sub in jax.core.jaxprs_in_params(eqn.params):
+            found += _pallas_calls(sub)
+    return found
+
+
+def test_a_differentiated_call_runs_two_kernels_and_the_logits_twice():
+    h, w, y = _inputs(6, 32, 256, 64)
+    loss = lambda h, w: fused_ce_sum_and_count(h, w, y, block_rows=16, block_vocab=128, interpret=True)[0]  # noqa: E731
+    calls = _pallas_calls(jax.make_jaxpr(jax.grad(loss, argnums=(0, 1)))(h, w).jaxpr)
+    assert sorted(c.params["name"] for c in calls) == ["fused_ce_bwd_dw", "fused_ce_fwd"]
+    # d_hidden leaves the forward kernel as the softmax's mean row of the head, float32 [N, E]
+    forward = next(c for c in calls if c.params["name"] == "fused_ce_fwd")
+    assert [(v.aval.shape, v.aval.dtype) for v in forward.outvars] == [((32, 1), jnp.float32)] * 2 + [((32, 64), jnp.float32)]
+
+
+def test_a_call_nobody_differentiates_runs_the_lean_kernel():
+    h, w, y = _inputs(6, 32, 256, 64)
+    calls = _pallas_calls(jax.make_jaxpr(
+        lambda h, w: fused_ce_sum_and_count(h, w, y, block_rows=16, block_vocab=128, interpret=True))(h, w).jaxpr)
+    assert [c.params["name"] for c in calls] == ["fused_ce_eval"]
+    assert [v.aval.shape for v in calls[0].outvars] == [(32, 1), (32, 1)]  # lse and the label's logit: no [N, E] array
 
 
 def test_bf16_hidden_fp32_accumulation():

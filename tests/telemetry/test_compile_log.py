@@ -139,3 +139,33 @@ def test_ssm_scan_plan_is_one_event_per_traced_shape_and_none_per_step(tmp_path,
         assert of_kernels == [(True, 256, 1, 4 * 8 * 8 * 256), (True, 256, 6, 4 * 24 * 8 * 256), (True, 256, 2, 4 * 24 * 8 * 256)]
     else:
         assert of_kernels == [(False, 0, 0, 0)] * 3
+
+
+def test_fused_ce_plan_is_one_event_per_traced_shape_and_none_per_step(tmp_path):
+    """The fused cross entropy says once, while tracing, what a call does (`fused_ce_plan`):
+    the shape as the kernels hold it, the blocks, each kernel's grid steps, and whether the
+    forward carries d_hidden, which only a differentiated call does: it then computes the
+    logits twice (8 rows x n_embd x vocab of matmul for the 6 required) and the lean call
+    once; three steps of one executable say nothing more."""
+    from modalities_tpu.ops.cross_entropy import fused_ce_sum_and_count
+
+    hidden, head = jnp.ones((2, 20, 32), jnp.bfloat16), jnp.ones((300, 32), jnp.bfloat16)
+    labels = jnp.zeros((2, 20), jnp.int32)
+    loss = lambda hidden, head: fused_ce_sum_and_count(hidden, head, labels, interpret=True)[0]  # noqa: E731
+    telemetry = Telemetry(output_folder_path=tmp_path, watchdog_deadline_s=0)
+    previous = set_active_telemetry(telemetry)
+    try:
+        step = jax.jit(jax.grad(lambda hidden, head: loss(hidden, head) + loss(2 * hidden, head), argnums=(0, 1)))  # one shape traced twice
+        for _ in range(3):  # three steps of one executable
+            jax.block_until_ready(step(hidden, head))
+        jax.block_until_ready(jax.jit(loss)(hidden, head))  # the evaluator: nobody differentiates it
+    finally:
+        set_active_telemetry(previous)
+    plans = [e for e in map(json.loads, telemetry.sink_path.read_text().splitlines()) if e.get("name") == "fused_ce_plan"]
+    shape = {"rows": 64, "vocab": 300, "vocab_padded": 512, "n_embd": 32, "block_rows": 64, "block_vocab": 512, "grid_steps_forward": 1}
+    assert [{k: v for k, v in e.items() if k not in ("event", "name", "rank", "forward_vmem_bytes")} for e in plans] == [
+        {**shape, "dh_in_forward": True, "grid_steps_bwd_dw": 1, "nev_done": 8, "nev_required": 6},
+        {**shape, "dh_in_forward": False, "grid_steps_bwd_dw": 0, "nev_done": 2, "nev_required": 2},
+    ]
+    # carrying d_hidden costs the forward an fp32 accumulator and a double-buffered fp32 output block
+    assert plans[0]["forward_vmem_bytes"] - plans[1]["forward_vmem_bytes"] == 12 * 64 * 32
