@@ -22,6 +22,7 @@ TPU-first design choices (not translations):
 from __future__ import annotations
 
 import math
+import re
 from dataclasses import dataclass
 from enum import Enum
 from typing import Annotated, Literal, Optional
@@ -36,6 +37,8 @@ from modalities_tpu.models.components.layer_norms import (
     NormSpec,
     build_norm,
 )
+from modalities_tpu.models.gpt2.mla import LatentAttention, MLAConfig, MLASpec
+from modalities_tpu.models.gpt2.moe import BIAS_LEAF, COUNTERS, EXPERT_LOAD, MoE, MoEConfig, MoESpec, ffn_kinds, update_selection_bias
 from modalities_tpu.models.gpt2.ssm import MambaMixer, SSMConfig, SSMSpec, layer_kinds, layer_runs
 from modalities_tpu.models.model import NNModel
 from modalities_tpu.telemetry import scopes
@@ -134,6 +137,30 @@ class GPT2LLMConfig(BaseModel):
     attn_layer_period: Optional[Annotated[int, Field(strict=True, ge=1)]] = None
     attn_layer_offset: Annotated[int, Field(strict=True, ge=0)] = 0
     ssm_config: Optional[SSMConfig] = None
+    # `model_type: deepseek_v3`. `mla_config` puts latent attention (models/gpt2/mla.py) in every
+    # attention seat: heads of qk_nope_head_dim + qk_rope_head_dim for q and k and of v_head_dim
+    # for v, whatever n_embd / n_head_q is, with its own interleaved rotary (poe_type NOPE, no
+    # RotaryTransform). `moe_config` puts the routed-and-shared expert layer (models/gpt2/moe.py)
+    # in the feed-forward seat of every layer from `first_k_dense_replace` on; the layers before
+    # keep the dense one of `ffn_hidden`. `experts_held` / `expert_offset` say which of the
+    # router's experts this model holds (default: all).
+    mla_config: Optional[MLAConfig] = None
+    moe_config: Optional[MoEConfig] = None
+
+    @model_validator(mode="after")
+    def check_latent_attention(self) -> "GPT2LLMConfig":
+        if self.mla_config is None:
+            return self
+        if self.n_head_q != self.n_head_kv:
+            raise ValueError("mla_config: latent attention gives every head its own key and value; n_head_kv must equal n_head_q")
+        if self.attention_config.qk_norm_config is not None or any(
+            t.type_hint == QueryKeyValueTransformType.RotaryTransform for t in self.attention_config.qkv_transforms
+        ):
+            raise ValueError("mla_config: latent attention has its own rotary on part of a head and no QK norm; "
+                             "leave qkv_transforms to IdentityTransform and qk_norm_config unset")
+        if self.poe_type != PositionTypes.NOPE:
+            raise ValueError("mla_config: positions are latent attention's own rotary; poe_type must be NOPE")
+        return self
 
     @model_validator(mode="after")
     def check_layer_pattern(self) -> "GPT2LLMConfig":
@@ -272,6 +299,10 @@ class GPT2ModelSpec:
     # another; one run is the dense decoder, with the tree and the program it always had
     layer_kinds: tuple[str, ...] = ()
     ssm: Optional[SSMSpec] = None
+    # latent attention in the attention seats, and the expert layer in the feed-forward seat of
+    # the layers `moe.first_k_dense_replace` on: a layer's kind is (mixer, feed-forward)
+    mla: Optional[MLASpec] = None
+    moe: Optional[MoESpec] = None
 
     @property
     def head_dim(self) -> int:
@@ -282,12 +313,25 @@ class GPT2ModelSpec:
         return self.layer_kinds or ("attn",) * self.n_layer
 
     @property
+    def ffn_kinds(self) -> tuple[str, ...]:
+        return ffn_kinds(self.n_layer, self.moe)
+
+    @property
     def runs(self) -> tuple[tuple[str, int], ...]:
         return layer_runs(self.kinds)
 
     @property
+    def stack_runs(self) -> tuple[tuple[str, str, int], ...]:
+        """Runs of layers equal in mixer and feed-forward, in order: `(mixer, ffn, length)`."""
+        return tuple((mixer, ffn, length) for (mixer, ffn), length in layer_runs(tuple(zip(self.kinds, self.ffn_kinds))))
+
+    @property
     def has_ssm(self) -> bool:
         return "ssm" in self.layer_kinds
+
+    @property
+    def has_moe(self) -> bool:
+        return "moe" in self.ffn_kinds
 
     def __hash__(self):
         # hash a subset of the fields __eq__ compares (never id()): value-equal specs
@@ -328,6 +372,8 @@ class GPT2ModelSpec:
                 self.quant_weights,
                 self.layer_kinds,
                 self.ssm,
+                self.mla,
+                self.moe,
             )
         )
 
@@ -384,7 +430,7 @@ def masked_attention(q, k, v, mask, dropout_rate: float = 0.0, dropout_rng=None)
     """einsum + fp32 softmax attention with an explicit boolean mask — [Sq, Sk]
     shared across the batch, or [B, Sq, Sk] per-batch-row (slot decode: each slot
     attends up to its own cache length).
-    q: [B,Sq,Hq,D], k/v: [B,Sk,Hkv,D]; GQA convention: q head h uses kv head h // group.
+    q: [B,Sq,Hq,D], k: [B,Sk,Hkv,D], v: [B,Sk,Hkv,Dv]; GQA convention: q head h uses kv head h // group.
 
     `dropout_rate` > 0 applies inverted dropout to the attention *probabilities*
     (the reference semantic: manual_scaled_dot_product_attention / SDPA `dropout_p`,
@@ -407,7 +453,7 @@ def masked_attention(q, k, v, mask, dropout_rate: float = 0.0, dropout_rng=None)
         probs = jnp.where(keep, probs / (1.0 - dropout_rate), 0.0)
     probs = probs.astype(v.dtype)
     out = jnp.einsum("bhgst,bthd->bshgd", probs, v)
-    return out.reshape(b, sq, hq, d)
+    return out.reshape(b, sq, hq, v.shape[-1])
 
 
 def manual_attention(q, k, v, dropout_rate: float = 0.0, dropout_rng=None):
@@ -897,6 +943,7 @@ class GPT2Block(nn.Module):
     decode: bool = False
     slot_spec: Optional[SlotDecodeSpec] = None
     mixer: str = "attn"  # what sits in the mixer seat: "attn" or "ssm"
+    ffn: str = "mlp"  # what sits in the feed-forward seat: "mlp" or "moe"; with "moe" the block returns (x, what the layer counted)
 
     @nn.compact
     def __call__(self, x, slot=None, positions=None):
@@ -906,6 +953,9 @@ class GPT2Block(nn.Module):
         if self.mixer == "ssm":
             a = MambaMixer(spec, name=scopes.SSM)(h)
             a = nn.Dropout(rate=spec.dropout)(a, deterministic=self.deterministic or spec.dropout == 0.0)
+        elif spec.mla is not None:
+            a = LatentAttention(spec, self.deterministic, name="attn")(h)
+            a = nn.Dropout(rate=spec.dropout)(a, deterministic=self.deterministic or spec.dropout == 0.0)
         else:
             a = CausalSelfAttention(
                 spec, self.deterministic, self.decode, slot_spec=self.slot_spec, name="attn"
@@ -913,7 +963,11 @@ class GPT2Block(nn.Module):
         with jax.named_scope(scopes.RESIDUAL):
             x = x + a
         h2 = build_norm(spec.ffn_norm, "ffn_norm", dtype=x.dtype)(x)
-        m = MLP(spec, self.deterministic, name="mlp")(h2)
+        counters = None
+        if self.ffn == "moe":
+            m, counters = MoE(spec, self.deterministic, name=scopes.MOE)(h2)
+        else:
+            m = MLP(spec, self.deterministic, name="mlp")(h2)
         with jax.named_scope(scopes.RESIDUAL):
             x = x + m
         if spec.debug_print_activations == "shape":
@@ -928,7 +982,7 @@ class GPT2Block(nn.Module):
                 s=jnp.std(xf),
                 n=jnp.isnan(xf).sum(),
             )
-        return x
+        return x if counters is None else (x, counters)
 
 
 def _layer_remats(spec: "GPT2ModelSpec", layer_index: int) -> bool:
@@ -979,6 +1033,7 @@ class _BlockScanBody(nn.Module):
     deterministic: bool = True
     decode: bool = False
     mixer: str = "attn"
+    ffn: str = "mlp"
 
     @nn.compact
     def __call__(self, carry, _):
@@ -994,8 +1049,8 @@ class _BlockScanBody(nn.Module):
                     "use ac_freq > 1, or use ac_freq=1 / 'full'."
                 )
             block_cls = _remat_block_cls(spec)
-        x = block_cls(spec, self.deterministic, self.decode, mixer=self.mixer, name="block")(carry)
-        return x, None
+        out = block_cls(spec, self.deterministic, self.decode, mixer=self.mixer, ffn=self.ffn, name="block")(carry)
+        return out if self.ffn == "moe" else (out, None)  # what an expert layer counted is the scan's output, one row a layer
 
 
 class _LayerRun(nn.Module):
@@ -1007,6 +1062,7 @@ class _LayerRun(nn.Module):
     deterministic: bool
     mixer: str
     length: int
+    ffn: str = "mlp"
 
     @nn.compact
     def __call__(self, x):
@@ -1016,10 +1072,9 @@ class _LayerRun(nn.Module):
             split_rngs={"params": True, "dropout": True},
             length=self.length,
             metadata_params={nn.meta.PARTITION_NAME: "layers"},
-        )(self.spec, self.deterministic, False, self.mixer, name="blocks")
+        )(self.spec, self.deterministic, False, self.mixer, self.ffn, name="blocks")
         with jax.named_scope(scopes.LAYER_CARRY):
-            x, _ = scanned(x, None)
-        return x
+            return scanned(x, None)  # (x, what the expert layers counted [length, 3 + E] or None)
 
 
 class _SlotBlockScanBody(nn.Module):
@@ -1041,11 +1096,28 @@ class _SlotBlockScanBody(nn.Module):
         return (x, slot, positions), None
 
 
+_NO_LATENT_CACHE = (
+    "this model has latent attention (mla_config), and serving it needs a latent cache (the 512-wide latent and the shared "
+    "rotary key of every position, read through the absorbed form of the projections) in place of the per-head KV cache, "
+    "which serving/ does not have: it trains, it does not decode"
+)
+_NO_DECODE_THROUGH_DISPATCH = (
+    "this model has expert layers (moe_config), and serving them needs a decode path through the dispatch (a step of a "
+    "few tokens a slot sorted to the held experts, beside the prefill's), which serving/ does not have: it trains, it does not decode"
+)
 _NO_RECURRENT_STATE_CACHE = (
     "this model has state-space layers (attn_layer_period), and serving them needs a recurrent-state cache "
     "(the convolution's last taps and the scan's state [d_inner, d_state] for every sequence and layer, beside "
     "the attention layers' KV cache), which serving/ does not have: it trains, it does not decode"
 )
+
+
+def refuse_serving(spec: "GPT2ModelSpec") -> None:
+    """A cache, or a forward that reads one, is refused by the name of what serving lacks for this model."""
+    for missing, reason in ((spec.has_ssm, _NO_RECURRENT_STATE_CACHE), (spec.mla is not None, _NO_LATENT_CACHE),
+                            (spec.has_moe, _NO_DECODE_THROUGH_DISPATCH)):
+        if missing:
+            raise NotImplementedError(reason)
 
 
 class GPT2Module(nn.Module):
@@ -1115,16 +1187,20 @@ class GPT2Module(nn.Module):
         x = nn.Dropout(rate=spec.dropout)(x, deterministic=self.deterministic or spec.dropout == 0.0)
         x = with_logical_constraint(x, ("batch", "seq", "embed"))
 
-        if spec.has_ssm and (self.decode or self.slot_spec is not None):
-            raise NotImplementedError(_NO_RECURRENT_STATE_CACHE)
-        if spec.scan_layers and len(spec.runs) > 1:
-            if spec.pipeline_axis is not None:
-                raise NotImplementedError(
-                    "pipeline parallelism splits ONE stack of equal layers over its stages; a model whose "
-                    "layers are of more than one kind (attn_layer_period) has several. Run it without a pp axis."
-                )
-            for i, (kind, length) in enumerate(spec.runs):
-                x = _LayerRun(spec, self.deterministic, kind, length, name=f"run_{i}")(x)
+        if self.decode or self.slot_spec is not None:
+            refuse_serving(spec)
+        if spec.pipeline_axis is not None and (spec.has_moe or spec.mla is not None or len(spec.stack_runs) > 1):
+            raise NotImplementedError(
+                "pipeline parallelism splits ONE stack of equal dense-decoder layers over its stages; a model whose "
+                "layers are of more than one kind (attn_layer_period, moe_config) or hold latent attention is not "
+                "written for it. Run it without a pp axis."
+            )
+        layer_counters = []  # of the expert layers, a [layers, 3 + E] array a run
+        if spec.scan_layers and (len(spec.stack_runs) > 1 or spec.has_moe):
+            for i, (mixer, ffn, length) in enumerate(spec.stack_runs):
+                x, counters = _LayerRun(spec, self.deterministic, mixer, length, ffn, name=f"run_{i}")(x)
+                if counters is not None:
+                    layer_counters.append(counters)
         elif spec.scan_layers and self.slot_spec is not None:
             # serving slot-cache path: slot/positions are traced values and must ride
             # the scan carry; same "blocks"/"block" naming so trained params apply
@@ -1194,8 +1270,15 @@ class GPT2Module(nn.Module):
                     else GPT2Block
                 )
                 x = block_cls(
-                    spec, self.deterministic, self.decode, slot_spec=self.slot_spec, mixer=spec.kinds[i], name=f"h_{i}"
+                    spec, self.deterministic, self.decode, slot_spec=self.slot_spec, mixer=spec.kinds[i],
+                    ffn=spec.ffn_kinds[i], name=f"h_{i}"
                 )(x, slot, positions)
+                if spec.ffn_kinds[i] == "moe":
+                    x, counters = x
+                    layer_counters.append(counters[None])
+        if layer_counters and not self.is_initializing() and self.is_mutable_collection("counters"):
+            self.sow("counters", "moe", jnp.concatenate(layer_counters, axis=0), reduce_fn=lambda _, new: new,
+                     init_fn=lambda: jnp.zeros((0, len(COUNTERS) + spec.moe.n_routed_experts), jnp.float32))
 
         x = build_norm(spec.lm_head_norm, "lm_head_norm")(x)
         x = with_logical_constraint(x, ("batch", "seq", "embed"))
@@ -1257,6 +1340,8 @@ class GPT2LLM(NNModel):
         attn_layer_period: Optional[int] = None,
         attn_layer_offset: int = 0,
         ssm_config: Optional[SSMConfig | dict] = None,
+        mla_config: Optional[MLAConfig | dict] = None,
+        moe_config: Optional[MoEConfig | dict] = None,
     ):
         super().__init__(
             sample_key=sample_key,
@@ -1266,6 +1351,11 @@ class GPT2LLM(NNModel):
                 # group names match the reference (gpt2_model.py:871-875) so its
                 # YAMLs' weight_decay_groups_excluded lists resolve unchanged
                 "linear": [r".*(q_attn|k_attn|v_attn|c_proj|c_fc|W|V|W_2|lm_head).*kernel.*"],
+                # the matrices a deepseek_v3 model adds: latent attention's projections, the router, the experts' stacks
+                "latent_and_experts": [r".*/attn/(q_proj|kv_a_proj|kv_b_proj)/kernel$", r".*/moe/router/kernel$",
+                                       r".*/moe/experts/(W|V|W_2)$"],
+                # the router's selection bias: a buffer, which the optimizer must leave as it is
+                "router_bias": [r".*/moe/router/e_score_correction_bias$"],
                 "embedding": [r".*(wte|wpe).*"],
                 "layernorm": [r".*(norm).*"],
                 # what Mamba marks `_no_weight_decay` in the state-space mixer, and its biases
@@ -1323,6 +1413,8 @@ class GPT2LLM(NNModel):
             lm_head_fused_ce=lm_head_fused_ce,
             layer_kinds=layer_kinds(n_layer, attn_layer_period, attn_layer_offset) if attn_layer_period else (),
             ssm=SSMSpec.from_config(ssm_config, n_embd) if ssm_config is not None else None,
+            mla=MLASpec.from_config(mla_config) if mla_config is not None else None,
+            moe=MoESpec.from_config(moe_config) if moe_config is not None else None,
         )
         self.sequence_length = sequence_length
         self.vocab_size = vocab_size
@@ -1361,6 +1453,48 @@ class GPT2LLM(NNModel):
         )
         return module.apply(params, inputs[self.sample_key], rngs=rngs)
 
+    @property
+    def counted(self) -> dict[str, tuple[int, ...]]:
+        """What a training pass of the expert layers counts: the three of `moe.COUNTERS`, which a
+        step publishes (pairs held and mean load: the mean over the expert layers; the largest
+        load: over all of them), and every expert's load a layer, by which `after_update` moves
+        the selection bias."""
+        spec = self.config_spec
+        if not spec.has_moe:
+            return {}
+        return {**{name: () for name in COUNTERS}, EXPERT_LOAD: (spec.ffn_kinds.count("moe"), spec.moe.n_routed_experts)}
+
+    def apply_counted(self, params, inputs: dict, train: bool = False, rngs=None, hidden: bool = False):
+        if not self.config_spec.has_moe:
+            return super().apply_counted(params, inputs, train=train, rngs=rngs, hidden=hidden)
+        module = GPT2Module(self.config_spec, deterministic=not train, output_hidden=hidden)
+        out, state = module.apply(params, inputs[self.sample_key], rngs=rngs, mutable=["counters"])
+        rows = state["counters"]["moe"]  # [expert layers, 3 + E]
+        counted = {COUNTERS[0]: rows[:, 0].mean(), COUNTERS[1]: rows[:, 1].max(), COUNTERS[2]: rows[:, 2].mean(),
+                   EXPERT_LOAD: rows[:, len(COUNTERS):]}
+        return (out if hidden else {self.prediction_key: out}), counted
+
+    def after_update(self, params, counted: dict):
+        """The selection bias of every expert layer moved by its rule (`moe.update_selection_bias`)
+        from the step's loads; the tree as it is where `bias_update_speed` is 0."""
+        spec = self.config_spec
+        if not spec.has_moe or not spec.moe.bias_update_speed:
+            return params
+        expert_layers = [i for i, ffn in enumerate(spec.ffn_kinds) if ffn == "moe"]
+        first_of_run = [sum(length for _, _, length in spec.stack_runs[:r]) for r in range(len(spec.stack_runs))]
+
+        def moved(path, leaf):
+            name = "/".join(str(getattr(p, "key", getattr(p, "name", p))) for p in path)
+            if BIAS_LEAF not in name:
+                return leaf
+            where = re.search(r"(run|h)_(\d+)/", name)  # a run's stacked layers [length, E], or one unrolled layer [E]
+            first = first_of_run[int(where[2])] if where[1] == "run" else int(where[2])
+            row = expert_layers.index(first)
+            load = counted[EXPERT_LOAD][row: row + leaf.shape[0]] if leaf.ndim == 2 else counted[EXPERT_LOAD][row]
+            return update_selection_bias(leaf, load, spec.moe.bias_update_speed)
+
+        return jax.tree_util.tree_map_with_path(moved, params)
+
     def head_logits(self, params, hidden_chunk):
         """fp32 logits for a [B, C, E] hidden chunk (weight-tied or lm_head),
         vocab-constrained like the in-module head (loss parallel works)."""
@@ -1381,8 +1515,7 @@ class GPT2LLM(NNModel):
 
     # ----------------------------------------------------------- KV-cache decoding
     def _refuse_without_recurrent_state_cache(self) -> None:
-        if self.config_spec.has_ssm:
-            raise NotImplementedError(_NO_RECURRENT_STATE_CACHE)
+        refuse_serving(self.config_spec)
 
     def init_decode_cache(self, params, batch_size: int):
         """Zeroed per-layer KV caches + position counters for `decode_step`. Shapes

@@ -1,0 +1,240 @@
+"""The routed-and-shared expert layer as `model_type: deepseek_v3` computes it (Hugging
+Face's `modeling_deepseek_v3.py`): the second feed-forward a block's `MLP` seat can hold,
+under the module name `moe`.
+
+On `x [T, d]`, with `E = n_routed_experts`, `k = num_experts_per_tok`:
+
+    s        = sigmoid(x @ router)                 float32, router [d, E]
+    choice   = the k largest of s + b              b [E]: `e_score_correction_bias`, a buffer (no gradient, no decay)
+    w        = s[choice] / (sum of them + 1e-20) * routed_scaling_factor     (`norm_topk_prob`)
+    expert e = W_2_e(silu(W_e x) * (V_e x))        d -> moe_intermediate_size -> d
+    out      = sum over the chosen experts of w * expert(x) + shared(x)
+    shared   = one SwiGLU d -> n_shared_experts * moe_intermediate_size -> d, on every token
+
+`n_group` and `topk_group` other than 1 (group-limited routing) and a `scoring_func` other
+than `sigmoid` are not written and are refused. No token is dropped; there is no capacity.
+
+**The share.** The layer is told which experts it holds: `experts_held` of them from
+`expert_offset` (default: all). The router keeps its E outputs, the choice its k and the
+weights their normalisation over all k chosen; the sum runs over the chosen experts that
+are held (`ops/expert_dispatch.py`). What absent experts would have added is left out:
+under expert parallelism it is the other chips' to add (the exchange is not written:
+there is no `ep` mesh axis yet), on one chip it is simply absent, and that partial result
+goes on. Gradients follow: the router learns through the weights of held experts and
+through the normaliser.
+
+`b` sits in the parameter tree so that a checkpoint keeps it; only the choice's indices
+depend on it, so its gradient is exactly zero, and the `router_bias` weight-decay group
+keeps decay off it: AdamW's update of it is zero. How it moves between steps is not in a
+`config.json`; it is DeepSeek-V3's published rule (arXiv 2412.19437, section 2.1.2,
+"auxiliary-loss-free load balancing"; Wang et al., arXiv 2408.15664): after a step,
+
+    b_e = b_e + bias_update_speed * sign(mean load of the E experts - load of e)
+
+with the loads counted over the step's tokens and all E experts, held or not (the router
+sees every choice; under expert parallelism the counts of the group's chips would be
+summed). `bias_update_speed` 0, the default, leaves `b` as initialised or loaded.
+`update_selection_bias` is the rule; the train step calls it through
+`GPT2LLM.after_update` once the optimizer is done.
+
+Counted in a pass, from the choice: the pairs held, the largest and mean load of a held
+expert, and the load of each of the E experts. The block hands them up beside its output
+as one row (`COUNTERS`, then the E loads); `GPT2Module` puts them into the `counters`
+collection for the train step to publish (the three) and to move `b` by (the loads).
+"""
+
+from __future__ import annotations
+
+from dataclasses import dataclass
+from typing import Annotated, Optional
+
+import flax.linen as nn
+import jax
+import jax.numpy as jnp
+from pydantic import BaseModel, Field, model_validator
+
+from modalities_tpu.telemetry import scopes
+
+COUNTERS = ("moe_pairs_held", "moe_load_max", "moe_load_mean")  # a layer's, in this order
+EXPERT_LOAD = "moe_expert_load"  # [expert layers, E]: the pairs each of the router's experts got, held or not
+BIAS_LEAF = "moe/router/e_score_correction_bias"
+
+
+class MoEConfig(BaseModel):
+    """The `moe_config` block of a `model.gpt2` config; keys as `deepseek_v3` publishes
+    them, and the two that say which experts this layer holds."""
+
+    n_routed_experts: Annotated[int, Field(strict=True, ge=2)]
+    num_experts_per_tok: Annotated[int, Field(strict=True, ge=1)]
+    moe_intermediate_size: Annotated[int, Field(strict=True, ge=1)]
+    n_shared_experts: Annotated[int, Field(strict=True, ge=0)] = 0
+    first_k_dense_replace: Annotated[int, Field(strict=True, ge=0)] = 0  # the leading layers that keep the dense `mlp`
+    moe_layer_freq: Annotated[int, Field(strict=True, ge=1)] = 1
+    routed_scaling_factor: float = 1.0
+    norm_topk_prob: bool = True
+    scoring_func: str = "sigmoid"
+    topk_method: str = "noaux_tc"
+    n_group: int = 1
+    topk_group: int = 1
+    experts_held: Optional[Annotated[int, Field(strict=True, ge=1)]] = None  # default: all of them
+    expert_offset: Annotated[int, Field(strict=True, ge=0)] = 0
+    # a recipe's, not a config.json's: how far a step moves the selection bias of an expert whose load is off the mean (0: never)
+    bias_update_speed: Annotated[float, Field(ge=0.0)] = 0.0
+
+    @model_validator(mode="after")
+    def refuse_what_is_not_written(self) -> "MoEConfig":
+        if self.scoring_func != "sigmoid":
+            raise ValueError(f"moe_config.scoring_func {self.scoring_func!r}: only sigmoid scores are written")
+        if self.n_group != 1 or self.topk_group != 1:
+            raise ValueError("moe_config.n_group / topk_group: group-limited routing is not written; both must be 1")
+        if self.topk_method != "noaux_tc":
+            raise ValueError(f"moe_config.topk_method {self.topk_method!r}: only noaux_tc (scores plus a selection bias) is written")
+        if self.moe_layer_freq != 1:
+            raise ValueError("moe_config.moe_layer_freq: every layer after the leading dense ones is an expert layer; only 1 is written")
+        if self.num_experts_per_tok > self.n_routed_experts:
+            raise ValueError("moe_config.num_experts_per_tok exceeds n_routed_experts")
+        held = self.n_routed_experts if self.experts_held is None else self.experts_held
+        if self.expert_offset + held > self.n_routed_experts:
+            raise ValueError("moe_config: expert_offset + experts_held exceeds n_routed_experts")
+        return self
+
+
+@dataclass(frozen=True)
+class MoESpec:
+    n_routed_experts: int
+    num_experts_per_tok: int
+    moe_intermediate_size: int
+    shared_hidden: int  # n_shared_experts * moe_intermediate_size; 0: no shared expert
+    first_k_dense_replace: int
+    routed_scaling_factor: float
+    norm_topk_prob: bool
+    experts_held: int
+    expert_offset: int
+    bias_update_speed: float = 0.0
+
+    @classmethod
+    def from_config(cls, config: "MoEConfig | dict") -> "MoESpec":
+        if isinstance(config, dict):
+            config = MoEConfig(**config)
+        return cls(
+            n_routed_experts=config.n_routed_experts, num_experts_per_tok=config.num_experts_per_tok,
+            moe_intermediate_size=config.moe_intermediate_size,
+            shared_hidden=config.n_shared_experts * config.moe_intermediate_size,
+            first_k_dense_replace=config.first_k_dense_replace, routed_scaling_factor=float(config.routed_scaling_factor),
+            norm_topk_prob=config.norm_topk_prob,
+            experts_held=config.n_routed_experts if config.experts_held is None else config.experts_held,
+            expert_offset=config.expert_offset, bias_update_speed=float(config.bias_update_speed),
+        )
+
+
+def update_selection_bias(bias, load, speed: float):
+    """DeepSeek-V3's rule for `b` after a step. bias, load [..., E]: an expert that got more
+    than the mean of the E loses `speed`, one that got less gains it, one on the mean stays."""
+    return bias + speed * jnp.sign(jnp.mean(load, axis=-1, keepdims=True) - load).astype(bias.dtype)
+
+
+def ffn_kinds(n_layer: int, moe: Optional[MoESpec]) -> tuple[str, ...]:
+    """The feed-forward of every layer from the one published key: the first
+    `first_k_dense_replace` layers keep the dense `mlp`, every later one is `moe`."""
+    if moe is None:
+        return ("mlp",) * n_layer
+    return tuple("mlp" if i < moe.first_k_dense_replace else "moe" for i in range(n_layer))
+
+
+class _Router(nn.Module):
+    """Scores, choice and weights, in float32 whatever the compute dtype."""
+
+    moe: MoESpec
+
+    @nn.compact
+    def __call__(self, x):
+        moe = self.moe
+        kernel = self.param("kernel", nn.with_logical_partitioning(nn.initializers.normal(0.02), ("embed", "router")),
+                            (x.shape[-1], moe.n_routed_experts), jnp.float32)
+        bias = self.param("e_score_correction_bias", nn.with_logical_partitioning(nn.initializers.zeros, ("router",)),
+                          (moe.n_routed_experts,), jnp.float32)
+        scores = jax.nn.sigmoid(jnp.dot(x.astype(jnp.float32), kernel, precision=jax.lax.Precision.HIGHEST))
+        _, choice = jax.lax.top_k(scores + jax.lax.stop_gradient(bias), moe.num_experts_per_tok)
+        # the scores at the chosen experts, as a compare against all the experts and a sum: `take_along_axis` is a gather
+        # forward and a scatter backward, 65 ns an index on the chip (13 ms a step here), this a few elementwise passes
+        chosen = choice[..., None] == jnp.arange(moe.n_routed_experts, dtype=choice.dtype)
+        weights = jnp.sum(jnp.where(chosen, scores[:, None, :], 0.0), axis=-1)
+        load = jnp.sum(chosen, axis=(0, 1), dtype=jnp.float32)  # of every expert the router knows, held or not
+        if moe.norm_topk_prob:
+            weights = weights / (jnp.sum(weights, axis=-1, keepdims=True) + 1e-20)
+        return choice, weights * moe.routed_scaling_factor, load
+
+
+class _SharedExpert(nn.Module):
+    """The dense SwiGLU every token passes: the `MLP`'s arithmetic and leaf names at another hidden size."""
+
+    spec: object
+    hidden: int
+
+    @nn.compact
+    def __call__(self, x):
+        from modalities_tpu.models.gpt2.gpt2_model import _dense_general, with_logical_constraint
+
+        spec = self.spec
+        h = nn.silu(_dense_general(spec, self.hidden, "W", ("embed", "mlp"), x.dtype)(x)) * _dense_general(
+            spec, self.hidden, "V", ("embed", "mlp"), x.dtype)(x)
+        h = with_logical_constraint(h, ("batch", "seq", "mlp"), spec)
+        return _dense_general(spec, spec.n_embd, "W_2", ("mlp", "embed"), x.dtype)(h)
+
+
+class _Experts(nn.Module):
+    """The held experts' three stacks `[held, d, f]`, `[held, d, f]`, `[held, f, d]`."""
+
+    spec: object
+
+    @nn.compact
+    def __call__(self):
+        spec, moe = self.spec, self.spec.moe
+        dtype = jnp.dtype(spec.param_dtype)
+        init = nn.initializers.normal(0.02)
+        shape_in, shape_out = (moe.experts_held, spec.n_embd, moe.moe_intermediate_size), (moe.experts_held, moe.moe_intermediate_size, spec.n_embd)
+        w = self.param("W", nn.with_logical_partitioning(init, ("experts", "embed", "expert_mlp")), shape_in, dtype)
+        v = self.param("V", nn.with_logical_partitioning(init, ("experts", "embed", "expert_mlp")), shape_in, dtype)
+        w_2 = self.param("W_2", nn.with_logical_partitioning(init, ("experts", "expert_mlp", "embed")), shape_out, dtype)
+        return w, v, w_2
+
+
+class MoE(nn.Module):
+    """The expert layer; sits in a block's `MLP` seat under the name `moe`. Returns its
+    output and what the layer counted (float32 [3 + E]: `COUNTERS`, then the load of each of the router's experts)."""
+
+    spec: object  # GPT2ModelSpec (its `moe` is the MoESpec)
+    deterministic: bool = True
+
+    @nn.compact
+    def __call__(self, x):
+        from modalities_tpu.ops import expert_dispatch
+        from modalities_tpu.telemetry import get_active_telemetry
+
+        spec, moe = self.spec, self.spec.moe
+        batch, seq, width = x.shape
+        tokens = x.reshape(batch * seq, width)
+        pairs = batch * seq * moe.num_experts_per_tok
+        # runs while tracing: once per shape, nothing per step
+        get_active_telemetry().emit_event_once("moe_dispatch_plan", {
+            "tokens": batch * seq, "router_width": moe.n_routed_experts, "choices": moe.num_experts_per_tok,
+            "experts_held": moe.experts_held, "expert_offset": moe.expert_offset, "tile": expert_dispatch.TILE,
+            "rows": expert_dispatch.rows_for(pairs, moe.experts_held, expert_dispatch.TILE), "kernels": False,
+        })
+
+        with jax.named_scope(scopes.MOE_ROUTER):
+            choice, weights, load = _Router(moe, name="router")(tokens)
+        with jax.named_scope(scopes.MOE_DISPATCH):
+            plan = expert_dispatch.plan_dispatch(choice, moe.expert_offset, moe.experts_held)
+            held = jnp.sum(plan.group_sizes).astype(jnp.float32)
+            counters = jnp.concatenate([jnp.stack([held, jnp.max(plan.group_sizes).astype(jnp.float32), held / moe.experts_held]), load])
+        w, v, w_2 = _Experts(spec, name="experts")()
+        # one loop over the tiles in use; inside it the gather is `dispatch`, the products `experts`, the add back `combine`
+        routed = expert_dispatch.routed_experts(tokens, choice, weights, w, v, w_2, offset=moe.expert_offset, plan=plan)
+        out = routed.reshape(x.shape)
+        if moe.shared_hidden:
+            shared = _SharedExpert(spec, moe.shared_hidden, name=scopes.MOE_SHARED)(x)
+            with jax.named_scope(scopes.MOE_COMBINE):
+                out = out + shared
+        out = nn.Dropout(rate=spec.dropout)(out, deterministic=self.deterministic or spec.dropout == 0.0)
+        return out, jax.lax.stop_gradient(counters)

@@ -80,6 +80,24 @@ class NNModel:
     def apply(self, params, inputs: dict, train: bool = False, rngs=None) -> dict:  # pragma: no cover
         raise NotImplementedError
 
+    # --- what a training pass counts beside its loss (an expert layer's routing); nothing, for most models ---
+    @property
+    def counted(self) -> dict[str, tuple[int, ...]]:
+        """Name and shape (float32) of everything `apply_counted` counts. The train step sums each over
+        the microbatches (their mean; the largest where the name ends in `_max`), publishes the
+        scalars with the step's metrics and hands all of them to `after_update`."""
+        return {}
+
+    def apply_counted(self, params, inputs: dict, train: bool = False, rngs=None, hidden: bool = False):
+        """`apply` (`apply_hidden` with `hidden`), and what the pass counted: `{name: array}` as `counted` names them."""
+        forward = self.apply_hidden if hidden else self.apply
+        return forward(params, inputs, train=train, rngs=rngs), {}
+
+    def after_update(self, params, counted: dict):
+        """The parameters after the optimizer's update, with the buffers moved that a step moves by a
+        rule of their own from what it counted (an expert layer's selection bias)."""
+        return params
+
     def update_train_spec(self, **changes) -> "NNModel":
         self.train_spec = replace(self.train_spec, **changes)
         return self

@@ -25,9 +25,12 @@ NAMED_PARAMETER_INIT_GROUPS = {
     "gpt2": {
         # the state-space mixer's three large kernels among them; its convolution, dt_proj, A_log and D
         # keep the initial values Mamba publishes (models/gpt2/ssm.py)
-        "weighted_layers": [r".*(q_attn|k_attn|v_attn|c_proj|c_fc|W|V|W_2|in_proj|x_proj|out_proj)/kernel.*", r".*wte.*", r".*wpe.*"],
+        # latent attention's projections, the router and the experts' stacks (bare leaves, no `kernel` under them)
+        # among them; the router's selection bias keeps its zeros (models/gpt2/moe.py)
+        "weighted_layers": [r".*(q_attn|k_attn|v_attn|c_proj|c_fc|W|V|W_2|in_proj|x_proj|out_proj|q_proj|kv_a_proj|kv_b_proj|router)/kernel.*",
+                            r".*/experts/(W|V|W_2)(/[^/]*)?$", r".*wte.*", r".*wpe.*"],
         "embedding_layers": [r".*(wte|wpe).*"],
-        "projection_layers": [r".*(c_proj|W_2|out_proj)/kernel.*"],
+        "projection_layers": [r".*(c_proj|W_2|out_proj)/kernel.*", r".*/experts/W_2(/[^/]*)?$"],
         "norm_layers": [r".*(norm|scale).*"],
     },
     "coca": {

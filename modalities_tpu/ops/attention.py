@@ -11,6 +11,7 @@ from __future__ import annotations
 import functools
 
 import jax
+import jax.numpy as jnp
 
 from modalities_tpu.ops.tiers import on_tpu
 
@@ -18,8 +19,20 @@ _Q_AXES = ("batch", None, "heads", None)
 _KV_AXES = ("batch", None, "kv_heads", None)
 
 
+def _plain_attention(q, k, v, causal: bool, sm_scale: float | None):
+    """Off the TPU, where v is not as wide as q and k: the masked softmax written out, float32 scores."""
+    group = q.shape[2] // k.shape[2]
+    k, v = jnp.repeat(k, group, axis=2), jnp.repeat(v, group, axis=2)
+    scores = jnp.einsum("bshd,bthd->bhst", q, k).astype(jnp.float32) * (q.shape[-1] ** -0.5 if sm_scale is None else sm_scale)
+    if causal:
+        keep = jnp.arange(q.shape[1])[:, None] >= jnp.arange(k.shape[1])[None, :]
+        scores = jnp.where(keep, scores, jnp.finfo(jnp.float32).min)
+    return jnp.einsum("bhst,bthd->bshd", jax.nn.softmax(scores, axis=-1).astype(v.dtype), v)
+
+
 def flash_attention_or_fallback(q, k, v, causal: bool = True, sm_scale: float | None = None):
-    """q: [B,S,Hq,D], k/v: [B,S,Hkv,D] -> [B,S,Hq,D].
+    """q: [B,S,Hq,D], k: [B,S,Hkv,D], v: [B,S,Hkv,Dv] -> [B,S,Hq,Dv]. Dv is D everywhere but
+    in latent attention (192 and 128); the kernels read both widths off the arrays.
 
     Block sizes come from `env_flash_blocks`: MODALITIES_TPU_FLASH_BLOCK_Q / _BLOCK_K,
     else the device's tuning table (1024 x 1024 on a v5e), stepped down automatically
@@ -33,16 +46,19 @@ def flash_attention_or_fallback(q, k, v, causal: bool = True, sm_scale: float | 
     Under a mesh the kernel runs per shard, split over batch and heads
     (parallel/sharding.per_shard)."""
     if not on_tpu():
+        if v.shape[-1] != q.shape[-1]:
+            return _plain_attention(q, k, v, causal, sm_scale)  # SDPA takes one width for q, k and v
         return jax.nn.dot_product_attention(q, k, v, is_causal=causal, scale=sm_scale)
     from modalities_tpu.ops.pallas.flash_attention import env_flash_blocks, pallas_flash_attention, tile_plan
     from modalities_tpu.parallel.sharding import per_shard
     from modalities_tpu.telemetry import get_active_telemetry
 
-    block_q, block_k = env_flash_blocks(q.shape[1], k.shape[1], dtype=q.dtype)
+    block_q, block_k = env_flash_blocks(q.shape[1], k.shape[1], dtype=q.dtype, head_dim=q.shape[-1], head_dim_v=v.shape[-1])
     plan = {"seq_q": q.shape[1], "seq_k": k.shape[1], "block_q": block_q, "block_k": block_k, "causal": causal}
     # runs while tracing: the operator sees once per shape how many score tiles a
     # (batch, head) computes and which share takes the masked body; nothing per step
-    get_active_telemetry().emit_event_once("flash_tile_plan", {**plan, **tile_plan(**plan).counts()})
+    get_active_telemetry().emit_event_once(
+        "flash_tile_plan", {**plan, "head_dim": q.shape[-1], "head_dim_v": v.shape[-1], **tile_plan(**plan).counts()})
     kernel = functools.partial(
         pallas_flash_attention, causal=causal, sm_scale=sm_scale, block_q=block_q, block_k=block_k
     )

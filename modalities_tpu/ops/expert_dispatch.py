@@ -1,0 +1,239 @@
+"""Dropless dispatch of tokens to the experts a layer holds, under static shapes.
+
+An expert layer routes every token to `k` of `n_routed` experts and holds the experts
+`[offset, offset + held)` (all of them, or one chip's share under expert parallelism).
+Of the `k T` (token, choice) pairs only those of a held expert cost anything here:
+
+    plan      sort the pairs by expert, those of experts not held last; give every held
+              expert's group whole row tiles (a group is padded to a multiple of `tile`,
+              so a tile has one expert); per row its pair, per pair its row, per expert
+              its first tile and its tiles
+    experts   for every held expert, for every tile of its group (a loop whose length is
+              the group's: data dependent, `tile` rows a turn): gather the tile's tokens,
+              `W_2(silu(W x) * (V x))` with the expert's three matrices, the tile's rows
+              written where the plan put them
+    combine   for every token the sum over its k pairs of the pair's row times its routing
+              weight: k gathers of [T, d], a pair of an expert not held adding nothing
+
+No token is dropped at any load: the tables are sized for every pair landing on held
+experts (`rows`), but the loops run over the tiles in use, so the matrix products grow
+with the pairs held and not with `held x T`. All pairs on one expert is `k T / tile`
+turns of one loop; none on any expert is no turn at all.
+
+The backward pass is written by hand (`jax.custom_vjp`): a loop whose length is data
+dependent has no transpose, and the forward is cheap to compute again beside it. It
+walks the same tiles: the rows' cotangent is gathered by token, each expert's three
+gradients are summed over its tiles in float32 and written once, the tokens' gradient
+is the same sum by token over the rows' own, and the routing weights' gradient is the
+row's output (before weighing) against its cotangent.
+
+Nothing is added by token with a scatter: a first form did (`out.at[token].add(rows)` a
+tile, indices sorted and unique), and the chip ran it a row at a time, 0.9 us a row, a
+quarter of the cell's step (PERF.md section 6, PR 30). The sum by token costs `k T` row
+gathers whatever the load; the products, the tiles' gathers and everything in the loops
+grow with the pairs held.
+
+Products take the compute dtype's operands and accumulate in float32; `silu(a) * b` is
+rounded to the compute dtype before the third product and a row to it before the sum by
+token (which is in float32), as a dense SwiGLU's are.
+
+This is the plain form. Pallas kernels for the grouped products (`grouped_matmul_*`)
+are the next step where a trace asks for them; PERF.md has this form's readings.
+"""
+
+from __future__ import annotations
+
+import functools
+from typing import NamedTuple
+
+import jax
+import jax.numpy as jnp
+import numpy as np
+
+from modalities_tpu.telemetry import scopes
+
+TILE = 256  # rows a turn of an expert's loop takes: two MXU passes high, and at most 255 padding rows a held expert
+
+
+class DispatchPlan(NamedTuple):
+    """Where every (token, choice) pair of a held expert sits in the sorted, tile-aligned
+    order. `P = k T` pairs, `R` rows (static, `rows_for`)."""
+
+    row_pair: jax.Array  # int32 [R]: the pair of a row; P where the row is padding
+    pair_row: jax.Array  # int32 [P]: the row of a pair; R where the pair's expert is not held
+    first_tile: jax.Array  # int32 [held]: the first tile of an expert's group
+    tiles: jax.Array  # int32 [held]: the tiles of an expert's group
+    group_sizes: jax.Array  # int32 [held]: the pairs of an expert's group (its load)
+
+
+def rows_for(pairs: int, held: int, tile: int) -> int:
+    """Rows the tables are sized for: every pair on held experts, each group's last tile padded."""
+    return -(-(pairs + held * (tile - 1)) // tile) * tile
+
+
+def plan_dispatch(choice, offset: int, held: int, tile: int = TILE) -> DispatchPlan:
+    """`choice` int32 [T, k]: the experts every token chose, of all the router's."""
+    pairs = choice.size
+    rows = rows_for(pairs, held, tile)
+    local = choice.reshape(-1).astype(jnp.int32) - offset
+    key = jnp.where((local >= 0) & (local < held), local, held)  # experts not held sort last
+    order = jnp.argsort(key, stable=True).astype(jnp.int32)  # within a group by pair, hence by token
+    # Counts and look-ups in a table of `held` entries are compares against all of it and a sum: a scatter or a
+    # gather costs the chip some 65 ns an index whatever the table's size (7 ms for these k T), a compare nothing.
+    experts = jnp.arange(held, dtype=jnp.int32)
+    sizes = jnp.sum(key[:, None] == experts[None, :], axis=0, dtype=jnp.int32)
+    padded = -(-sizes // tile) * tile
+    start = jnp.cumsum(sizes) - sizes  # of a group, among the sorted pairs
+    row_start = jnp.cumsum(padded) - padded  # of a group, among the rows
+
+    def of_group(table, group):
+        return jnp.sum(jnp.where(group[:, None] == experts[None, :], table[None, :], 0), axis=1)
+
+    # row -> pair: the row's group from the padded boundaries, its rank in the group, the sorted pair there
+    row = jnp.arange(rows, dtype=jnp.int32)
+    group = jnp.minimum(jnp.sum(row[:, None] >= (row_start + padded)[None, :], axis=1, dtype=jnp.int32), held - 1)
+    rank = row - of_group(row_start, group)
+    live = (rank >= 0) & (rank < of_group(sizes, group))
+    row_pair = jnp.where(live, order[jnp.clip(of_group(start, group) + rank, 0, pairs - 1)], pairs)
+    # pair -> row: where the pair stands among the sorted pairs, less its group's start, on its group's rows
+    position = jnp.argsort(order).astype(jnp.int32)  # the inverse of a permutation is its argsort
+    pair_row = jnp.where(key < held, of_group(row_start - start, key) + position, rows)
+    return DispatchPlan(row_pair.astype(jnp.int32), pair_row.astype(jnp.int32), (row_start // tile).astype(jnp.int32),
+                        (padded // tile).astype(jnp.int32), sizes)
+
+
+@jax.named_scope(scopes.MOE_DISPATCH)
+def _tile_rows(plan_row_pair, weights_flat, x, i, tile: int, k: int):
+    """Tile `i`: its tokens (a padding row's lies past the last, which a gather fills with
+    zeros), their rows of `x`, their routing weights."""
+    pair = jax.lax.dynamic_slice(plan_row_pair, (i * tile,), (tile,))
+    token = jnp.where(pair < weights_flat.shape[0], pair // k, x.shape[0])
+    xs = jnp.take(x, token, axis=0, mode="fill", fill_value=0)
+    weight = jnp.take(weights_flat, pair, mode="fill", fill_value=0)
+    return token, xs, weight
+
+
+def _dot(a, b, contract):
+    return jax.lax.dot_general(a, b, ((contract[0], contract[1]), ((), ())), preferred_element_type=jnp.float32)
+
+
+@jax.named_scope(scopes.MOE_COMBINE)
+def _sum_by_token(rows, plan: DispatchPlan, tokens: int, k: int, weights=None):
+    """For every token the sum over its k pairs of the pair's row of `rows` [R, d] (times the
+    pair's weight, where given), in float32; a pair whose expert is not held has no row (its
+    index lies past the last) and adds nothing. k gathers of [T, d]: the sum needs no add by
+    token, which the chip runs a row at a time (PERF.md section 6, PR 30: 0.9 us a row against
+    20 to 40 ns a gathered row)."""
+    pair_row = plan.pair_row.reshape(tokens, k)
+    out = jnp.zeros((tokens, rows.shape[1]), jnp.float32)
+    for j in range(k):
+        picked = jnp.take(rows, pair_row[:, j], axis=0, mode="fill", fill_value=0).astype(jnp.float32)
+        out = out + (picked if weights is None else picked * weights[:, j, None])
+    return out
+
+
+def _forward(x, weights_flat, w_gate, w_up, w_down, plan: DispatchPlan, tile: int, k: int):
+    def one_expert(ys, per_expert):
+        gate, up, down, first, count = per_expert
+
+        def one_tile(j, ys):
+            _, xs, _ = _tile_rows(plan.row_pair, weights_flat, x, first + j, tile, k)
+            with jax.named_scope(scopes.MOE_EXPERTS):
+                h = (jax.nn.silu(_dot(xs, gate, ((1,), (0,)))) * _dot(xs, up, ((1,), (0,)))).astype(x.dtype)
+                y = _dot(h, down, ((1,), (0,))).astype(x.dtype)
+            return jax.lax.dynamic_update_slice(ys, y, ((first + j) * tile, 0))  # the tile's rows, where the plan put them
+
+        return jax.lax.fori_loop(0, count, one_tile, ys), None
+
+    # rows past the tiles in use are never read: every pair of a held expert has its row in a tile that was written
+    ys, _ = jax.lax.scan(one_expert, jnp.zeros((plan.row_pair.shape[0], x.shape[1]), x.dtype), (w_gate, w_up, w_down, plan.first_tile, plan.tiles))
+    return _sum_by_token(ys, plan, x.shape[0], k, weights_flat.reshape(x.shape[0], k)).astype(x.dtype)
+
+
+def _backward(x, weights_flat, w_gate, w_up, w_down, plan: DispatchPlan, dout, tile: int, k: int):
+    dtype = x.dtype
+
+    def one_expert(carry, per_expert):
+        gate, up, down, first, count = per_expert
+
+        def one_tile(j, carry):
+            dxs, d_row_weight, d_gate, d_up, d_down = carry
+            token, xs, weight = _tile_rows(plan.row_pair, weights_flat, x, first + j, tile, k)
+            with jax.named_scope(scopes.MOE_COMBINE):  # the transpose of the sum by token, and of the weighing
+                g = jnp.take(dout, token, axis=0, mode="fill", fill_value=0)
+                g_weighted = (g.astype(jnp.float32) * weight[:, None]).astype(dtype)
+            with jax.named_scope(scopes.MOE_EXPERTS):
+                a, b = _dot(xs, gate, ((1,), (0,))), _dot(xs, up, ((1,), (0,)))
+                sig = jax.nn.sigmoid(a)
+                silu = a * sig
+                h = (silu * b).astype(dtype)
+                dh_unweighted = _dot(g, down, ((1,), (1,)))  # [tile, f]
+                d_down = d_down + _dot(h, g_weighted, ((0,), (0,)))
+                dh = dh_unweighted * weight[:, None]
+                da = (dh * b * (sig + silu * (1.0 - sig))).astype(dtype)
+                db = (dh * silu).astype(dtype)
+                d_gate = d_gate + _dot(xs, da, ((0,), (0,)))
+                d_up = d_up + _dot(xs, db, ((0,), (0,)))
+                dx_rows = (_dot(da, gate, ((1,), (1,))) + _dot(db, up, ((1,), (1,)))).astype(dtype)
+            with jax.named_scope(scopes.MOE_COMBINE):
+                d_row_weight = jax.lax.dynamic_update_slice(
+                    d_row_weight, jnp.sum(h.astype(jnp.float32) * dh_unweighted, axis=-1), ((first + j) * tile,))
+            return jax.lax.dynamic_update_slice(dxs, dx_rows, ((first + j) * tile, 0)), d_row_weight, d_gate, d_up, d_down
+
+        dxs, d_row_weight = carry
+        zeros = lambda w: jnp.zeros(w.shape, jnp.float32)  # noqa: E731
+        dxs, d_row_weight, d_gate, d_up, d_down = jax.lax.fori_loop(
+            0, count, one_tile, (dxs, d_row_weight, zeros(gate), zeros(up), zeros(down)))
+        return (dxs, d_row_weight), (d_gate.astype(gate.dtype), d_up.astype(up.dtype), d_down.astype(down.dtype))
+
+    rows = plan.row_pair.shape[0]
+    init = (jnp.zeros((rows, x.shape[1]), dtype), jnp.zeros((rows,), jnp.float32))
+    (dxs, d_row_weight), (d_gate, d_up, d_down) = jax.lax.scan(
+        one_expert, init, (w_gate, w_up, w_down, plan.first_tile, plan.tiles))
+    dx = _sum_by_token(dxs, plan, x.shape[0], k)  # the transpose of the tiles' gathers of their tokens
+    with jax.named_scope(scopes.MOE_COMBINE):
+        d_weights = jnp.take(d_row_weight, plan.pair_row, mode="fill", fill_value=0).astype(weights_flat.dtype)
+    return dx.astype(dtype), d_weights, d_gate, d_up, d_down
+
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(6, 7))
+def _experts(x, weights_flat, w_gate, w_up, w_down, plan, tile, k):
+    return _forward(x, weights_flat, w_gate, w_up, w_down, plan, tile, k)
+
+
+def _experts_fwd(x, weights_flat, w_gate, w_up, w_down, plan, tile, k):
+    return _forward(x, weights_flat, w_gate, w_up, w_down, plan, tile, k), (x, weights_flat, w_gate, w_up, w_down, plan)
+
+
+def _experts_bwd(tile, k, residuals, dout):
+    x, weights_flat, w_gate, w_up, w_down, plan = residuals
+    grads = _backward(x, weights_flat, w_gate, w_up, w_down, plan, dout, tile, k)
+    return (*grads, jax.tree.map(lambda t: np.zeros(t.shape, jax.dtypes.float0), plan))
+
+
+_experts.defvjp(_experts_fwd, _experts_bwd)
+
+
+def routed_experts(x, choice, weights, w_gate, w_up, w_down, *, offset: int, plan: DispatchPlan | None = None, tile: int = TILE):
+    """The held experts' part of an expert layer's output.
+
+    x [T, d]; choice int32 [T, k] over all the router's experts; weights float32 [T, k]
+    (normalised over all k chosen, held or not); w_gate, w_up [held, d, f], w_down
+    [held, f, d]: the experts `offset .. offset + held - 1`. Returns [T, d] in x's dtype:
+    for every token the weighted sum over its chosen experts that are held, zero where it
+    chose none. Differentiable in x, weights and the three stacks."""
+    if plan is None:
+        plan = plan_dispatch(choice, offset, w_gate.shape[0], tile)
+    return _experts(x, weights.reshape(-1).astype(jnp.float32), w_gate, w_up, w_down, plan, tile, choice.shape[1])
+
+
+def dense_over_experts(x, choice, weights, w_gate, w_up, w_down, *, offset: int):
+    """The same sum the costly way, every held expert on every token with the weight zero
+    where not chosen: what the dispatch is held against in tests."""
+    held = w_gate.shape[0]
+    onehot = jax.nn.one_hot(choice - offset, held, dtype=jnp.float32)  # [T, k, held]; an expert not held gives no one
+    per_expert = jnp.einsum("tk,tke->te", weights.astype(jnp.float32), onehot)
+    h = (jax.nn.silu(jnp.einsum("td,edf->etf", x, w_gate, preferred_element_type=jnp.float32))
+         * jnp.einsum("td,edf->etf", x, w_up, preferred_element_type=jnp.float32)).astype(x.dtype)
+    y = jnp.einsum("etf,efd->etd", h, w_down, preferred_element_type=jnp.float32)
+    return jnp.einsum("etd,te->td", y, per_expert).astype(x.dtype)

@@ -5,7 +5,9 @@ gpt2_model.py:643-655).
 Design (FlashAttention-2 style, TPU-first):
 - forward: k/v stream through VMEM one [BK, D] tile per grid step while fp32
   accumulators (acc, m, l) persist in VMEM scratch — VMEM stays O(BQ*D + BK*D)
-  regardless of sequence length; logsumexp is saved for the backward.
+  regardless of sequence length; logsumexp is saved for the backward. v, the output,
+  its cotangent and dv take their width from v (`head_dim_v`), q, k, dq and dk from q:
+  latent attention has 192 and 128, and pads neither to the other.
 - backward: two kernels with the same streaming structure — dq over q blocks
   (kv innermost) and dk/dv over kv blocks (q innermost) — recomputing probabilities
   blockwise from the saved logsumexp (no S x S materialization anywhere). GQA folds
@@ -299,20 +301,27 @@ def _bwd_dkv_kernel(plan_ref, q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref, d
         dv_ref[0, 0] = dv_acc_ref[:].astype(dv_ref.dtype)
 
 
-def _tiled_call(kernel, table, name, *, sm_scale, batch, num_heads, group, block_q, block_k, head_dim,
+def _tiled_call(kernel, table, name, *, sm_scale, batch, num_heads, group, block_q, block_k, head_dim, head_dim_v,
                 inputs, outputs, out_shape, scratch_shapes, interpret):
     """One `pallas_call` of `kernel` over grid (batch, head, pair), each pair's tiles
     looked up in `table` (a plan's int32 [3, n]: q tile, kv tile, flags), which goes in
     flat as the one scalar-prefetched operand. `inputs` / `outputs` name each operand's
     tiling: "q" (a [block_q, D] tile of q head h), "kv" (a [block_k, D] tile of kv head
-    h // group), "k_out" (a [block_k, D] tile per q head), "row" (a [block_q, 1] column)."""
+    h // group), "k_out" (a [block_k, D] tile per q head), "row" (a [block_q, 1] column).
+    q and k are `head_dim` wide; what is as wide as v (`head_dim_v`: v, the output and its
+    cotangent, dv) is tiled alike under "qv", "v" and "v_out". Equal widths give the same
+    blocks under both names: the program is the one it was before there were two."""
     n = table.shape[1]
-    specs = {
-        "q": pl.BlockSpec((1, 1, block_q, head_dim), lambda b, h, t, plan: (b, h, plan[t], 0)),
-        "row": pl.BlockSpec((1, 1, block_q, 1), lambda b, h, t, plan: (b, h, plan[t], 0)),
-        "kv": pl.BlockSpec((1, 1, block_k, head_dim), lambda b, h, t, plan: (b, h // group, plan[n + t], 0)),
-        "k_out": pl.BlockSpec((1, 1, block_k, head_dim), lambda b, h, t, plan: (b, h, plan[n + t], 0)),
-    }
+
+    def tilings(width):
+        return (
+            pl.BlockSpec((1, 1, block_q, width), lambda b, h, t, plan: (b, h, plan[t], 0)),
+            pl.BlockSpec((1, 1, block_k, width), lambda b, h, t, plan: (b, h // group, plan[n + t], 0)),
+            pl.BlockSpec((1, 1, block_k, width), lambda b, h, t, plan: (b, h, plan[n + t], 0)),
+        )
+
+    specs = dict(zip(("q", "kv", "k_out"), tilings(head_dim)), **dict(zip(("qv", "v", "v_out"), tilings(head_dim_v))))
+    specs["row"] = pl.BlockSpec((1, 1, block_q, 1), lambda b, h, t, plan: (b, h, plan[t], 0))
     classes = np.unique(table[2] & (_MASKED | _DIAGONAL)).tolist()
     call = pl.pallas_call(
         functools.partial(kernel, sm_scale=sm_scale, block_q=block_q, block_k=block_k, num_pairs=n, classes=classes),
@@ -343,14 +352,20 @@ def _pick_block(seq: int, preferred: int) -> int:
     return seq
 
 
-def env_flash_blocks(seq_q: int, seq_k: int, dtype="bfloat16") -> tuple[int, int]:
+def env_flash_blocks(seq_q: int, seq_k: int, dtype="bfloat16", head_dim: int | None = None,
+                     head_dim_v: int | None = None) -> tuple[int, int]:
     """The (block_q, block_k) tuning knobs, shared by every kernel consumer
     (ops/attention.py dispatch, the ring tier). Precedence per knob:
     MODALITIES_TPU_FLASH_BLOCK_Q/_K env override > the per-device autotune table
     (ops/pallas/autotune.py, consulted at trace time) > 1024 (PERF.md section 6 has
     the chip's readings) — then stepped down to divide the sequence. A
     malformed override raises (int()) — it must never silently demote the call to
-    a fallback tier."""
+    a fallback tier.
+
+    The table's bucket is the two sequence lengths; where v is not as wide as q and k
+    (latent attention) it is the two widths instead (`d192_dv128`), whatever the sequence:
+    what fits VMEM depends on the blocks and the widths alone, and at 192/128 `bwd_dq`
+    asks 17.27 MiB of the 16 at 1024 x 1024, at 2 x 8192 and at 4 x 4096 alike."""
     import os
 
     env_q = os.environ.get("MODALITIES_TPU_FLASH_BLOCK_Q")
@@ -360,11 +375,10 @@ def env_flash_blocks(seq_q: int, seq_k: int, dtype="bfloat16") -> tuple[int, int
     if block_q is None or block_k is None:
         from modalities_tpu.ops.pallas import autotune
 
-        hit = autotune.lookup(
-            "flash_attention",
-            f"sq{autotune.shape_bucket(seq_q)}_sk{autotune.shape_bucket(seq_k)}",
-            jnp.dtype(dtype).name,
-        )
+        bucket = f"sq{autotune.shape_bucket(seq_q)}_sk{autotune.shape_bucket(seq_k)}"
+        if head_dim is not None and head_dim_v is not None and head_dim != head_dim_v:
+            bucket = f"d{head_dim}_dv{head_dim_v}"
+        hit = autotune.lookup("flash_attention", bucket, jnp.dtype(dtype).name)
         if hit:
             block_q = block_q if block_q is not None else int(hit.get("block_q", 1024))
             block_k = block_k if block_k is not None else int(hit.get("block_k", 1024))
@@ -384,20 +398,21 @@ def _flash_attention_bhsd(q, k, v, sm_scale, causal, block_q, block_k, interpret
 def _flash_fwd(q, k, v, sm_scale, causal, block_q, block_k, interpret):
     """q: [B, Hq, Sq, D]; k/v: [B, Hkv, Sk, D] -> (out, residuals)."""
     batch, num_heads, seq_q, head_dim = q.shape
-    num_kv_heads, seq_k = k.shape[1], k.shape[2]
+    num_kv_heads, seq_k, head_dim_v = k.shape[1], k.shape[2], v.shape[3]
     group = num_heads // num_kv_heads
 
     lanes = _stat_lanes(block_q, block_k)
     out, lse = _tiled_call(
         _fwd_kernel, tile_plan(seq_q, seq_k, block_q, block_k, causal).q_major, "flash_attention_fwd",
-        sm_scale=sm_scale, batch=batch, num_heads=num_heads, group=group, block_q=block_q, block_k=block_k, head_dim=head_dim,
-        inputs=("q", "kv", "kv"), outputs=("q", "row"),
+        sm_scale=sm_scale, batch=batch, num_heads=num_heads, group=group, block_q=block_q, block_k=block_k,
+        head_dim=head_dim, head_dim_v=head_dim_v,
+        inputs=("q", "kv", "v"), outputs=("qv", "row"),
         out_shape=[
-            jax.ShapeDtypeStruct(q.shape, q.dtype),
+            jax.ShapeDtypeStruct((batch, num_heads, seq_q, head_dim_v), q.dtype),
             jax.ShapeDtypeStruct((batch, num_heads, seq_q, 1), jnp.float32),
         ],
         scratch_shapes=[
-            pltpu.VMEM((block_q, head_dim), jnp.float32),
+            pltpu.VMEM((block_q, head_dim_v), jnp.float32),
             pltpu.VMEM((block_q, lanes), jnp.float32),
             pltpu.VMEM((block_q, lanes), jnp.float32),
         ],
@@ -433,8 +448,9 @@ def flash_bwd_dq(q, k, v, do, lse, delta, *, causal, sm_scale, block_q, block_k,
 
     (dq,) = _tiled_call(
         _bwd_dq_kernel, tile_plan(seq_q, seq_k, block_q, block_k, causal).q_major, "flash_attention_bwd_dq",
-        sm_scale=sm_scale, batch=batch, num_heads=num_heads, group=group, block_q=block_q, block_k=block_k, head_dim=head_dim,
-        inputs=("q", "kv", "kv", "q", "row", "row"), outputs=("q",),
+        sm_scale=sm_scale, batch=batch, num_heads=num_heads, group=group, block_q=block_q, block_k=block_k,
+        head_dim=head_dim, head_dim_v=v.shape[3],
+        inputs=("q", "kv", "v", "qv", "row", "row"), outputs=("q",),
         out_shape=[jax.ShapeDtypeStruct(q.shape, q.dtype)],
         scratch_shapes=[pltpu.VMEM((block_q, head_dim), jnp.float32)],
         interpret=interpret,
@@ -447,28 +463,29 @@ def flash_bwd_dkv(q, k, v, do, lse, delta, *, causal, sm_scale, block_q, block_k
     down to the kv heads ([B, Hkv, Sk, D]). Reusable by the ring backward, where the
     accumulators ride the k/v rotation."""
     batch, num_heads, seq_q, head_dim = q.shape
-    num_kv_heads, seq_k = k.shape[1], k.shape[2]
+    num_kv_heads, seq_k, head_dim_v = k.shape[1], k.shape[2], v.shape[3]
     group = num_heads // num_kv_heads
 
     # dk/dv per q-head (q blocks innermost), then summed over the GQA group
     dk_h, dv_h = _tiled_call(
         _bwd_dkv_kernel, tile_plan(seq_q, seq_k, block_q, block_k, causal).kv_major, "flash_attention_bwd_dkv",
-        sm_scale=sm_scale, batch=batch, num_heads=num_heads, group=group, block_q=block_q, block_k=block_k, head_dim=head_dim,
-        inputs=("q", "kv", "kv", "q", "row", "row"), outputs=("k_out", "k_out"),
+        sm_scale=sm_scale, batch=batch, num_heads=num_heads, group=group, block_q=block_q, block_k=block_k,
+        head_dim=head_dim, head_dim_v=head_dim_v,
+        inputs=("q", "kv", "v", "qv", "row", "row"), outputs=("k_out", "v_out"),
         out_shape=[
             jax.ShapeDtypeStruct((batch, num_heads, seq_k, head_dim), q.dtype),
-            jax.ShapeDtypeStruct((batch, num_heads, seq_k, head_dim), q.dtype),
+            jax.ShapeDtypeStruct((batch, num_heads, seq_k, head_dim_v), q.dtype),
         ],
         scratch_shapes=[
             pltpu.VMEM((block_k, head_dim), jnp.float32),
-            pltpu.VMEM((block_k, head_dim), jnp.float32),
+            pltpu.VMEM((block_k, head_dim_v), jnp.float32),
         ],
         interpret=interpret,
     )(q, k, v, do, lse, delta)
 
     if group > 1:
         dk = dk_h.reshape(batch, num_kv_heads, group, seq_k, head_dim).sum(axis=2)
-        dv = dv_h.reshape(batch, num_kv_heads, group, seq_k, head_dim).sum(axis=2)
+        dv = dv_h.reshape(batch, num_kv_heads, group, seq_k, head_dim_v).sum(axis=2)
     else:
         dk, dv = dk_h, dv_h
     return dk.astype(k.dtype), dv.astype(v.dtype)
@@ -491,7 +508,9 @@ def pallas_flash_attention(
     q, k, v, causal: bool = True, sm_scale: float | None = None,
     block_q: int = 128, block_k: int = 128, interpret: bool = False,
 ):
-    """Public entry. q: [B, S, Hq, D], k/v: [B, S, Hkv, D] (model layout) -> [B, S, Hq, D]."""
+    """Public entry. q: [B, S, Hq, D], k: [B, S, Hkv, D], v: [B, S, Hkv, Dv] (model layout)
+    -> [B, S, Hq, Dv]. Dv may differ from D (latent attention: 192 for q and k, 128 for v);
+    the default scale is that of D."""
     if sm_scale is None:
         sm_scale = 1.0 / math.sqrt(q.shape[-1])
     seq_q, seq_k = q.shape[1], k.shape[1]

@@ -59,6 +59,13 @@ def default_logical_axis_rules(mesh_handle: DeviceMeshHandle, sequence_parallel:
         ("kv_heads", tp),
         ("head_dim", None),
         ("mlp", tp),
+        # an expert layer's stacks [experts held, embed, expert_mlp]: the experts' axis waits for an `ep` mesh axis
+        # and its exchange, so every chip of a mesh holds the same experts; an expert's hidden dim splits over tp as
+        # the dense one does. The latent of latent attention and the router's outputs are small and replicated.
+        ("experts", None),
+        ("expert_mlp", tp),
+        ("latent", None),
+        ("router", None),
         ("vocab", tp),
         # LOGITS vocab dim: sharded over tp only when loss parallelism is enabled —
         # the CE logsumexp/gather then runs on vocab shards with XLA-inserted psums

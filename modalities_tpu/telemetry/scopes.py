@@ -45,12 +45,25 @@ State-space mixer (`models/gpt2/ssm.py`), under the module name `ssm` that Flax 
     SSM_SCAN          scan              the recurrence (`ops/selective_scan.py`: on a TPU its two Pallas kernels) and its backward pass, nothing else
     SSM_GATE          gate              the skip `D * x` and the gate `silu(z)` on the scan's output
 
+Expert layer (`models/gpt2/moe.py`, `ops/expert_dispatch.py`), under the module name `moe` that Flax gives it in a block's `MLP` seat:
+
+    MOE_ROUTER        router            scores, choice and weights, float32 (the name of its module)
+    MOE_DISPATCH      dispatch          the sort of the pairs by expert, the group sizes and tables, each tile's gather of its tokens
+    MOE_EXPERTS       experts           the grouped products of the held experts, both passes (and the module that holds their three stacks)
+    MOE_SHARED        shared            the shared expert, a dense SwiGLU on every token (the name of its module)
+    MOE_COMBINE       combine           the rows weighed and added back by token, the sum with the shared expert
+
+Latent attention (`models/gpt2/mla.py`) sits in the mixer seat under `attn` as the other
+attention does, with `attn/rope` and `attn/attn_core`; its projections keep Flax's names.
+
 Module names Flax gives, part of the vocabulary as they are (`flax_profile` puts them
 on the stack): `GPT2Module`, `blocks/block` (`h_<i>` when the layers are not scanned),
 `attn/{q_attn,k_attn,v_attn,c_proj}`, `mlp/{W,V,W_2,c_fc,c_proj}`, `attention_norm`,
 `ffn_norm`, `lm_head_norm`, `lm_head`; a model whose layers are of more than one kind
 puts `run_<i>` before `blocks/block` (one scan a run of equal layers), and the state-space
-mixer's projections are `ssm/{in_proj,x_proj,dt_proj,out_proj}` with `ssm/{dt_norm,b_norm,c_norm}`. Kernels keep the `name=` of their Pallas call:
+mixer's projections are `ssm/{in_proj,x_proj,dt_proj,out_proj}` with `ssm/{dt_norm,b_norm,c_norm}`;
+latent attention's are `attn/{q_proj,kv_a_proj,kv_a_norm,kv_b_proj,c_proj}`, the expert layer's
+`moe/router`, `moe/experts`, `moe/shared/{W,V,W_2}`. Kernels keep the `name=` of their Pallas call:
 `flash_attention_{fwd,bwd_dq,bwd_dkv}`, `fused_ce_{fwd,bwd_dw}` (`fused_ce_eval` where nobody differentiates the call),
 `fused_rmsnorm_{fwd,bwd}`, `selective_scan_{fwd,bwd}` (the recurrence on a TPU, under
 `ssm/scan`); the instruction of a call is named by it, and metrics select by that name.
@@ -81,9 +94,18 @@ SSM_CONV = "conv"
 SSM_SCAN = "scan"
 SSM_GATE = "gate"
 
+MOE = "moe"  # the expert layer's module name in the block's `MLP` seat
+MOE_ROUTER = "router"
+MOE_DISPATCH = "dispatch"
+MOE_EXPERTS = "experts"
+MOE_SHARED = "shared"
+MOE_COMBINE = "combine"
+
 UPDATE_SCOPES = (GRAD_ACCUMULATE, GRAD_NORM, CLIP, OPTIMIZER, APPLY_UPDATES, ANOMALY_SELECT, STEP_METRICS)
 MODEL_SCOPES = (WTE, ROPE, ATTN_CORE, RESIDUAL, LAYER_CARRY)
 SSM_SCOPES = (SSM_CONV, SSM_SCAN, SSM_GATE)  # on the step only where a layer holds the state-space mixer
+
+MOE_SCOPES = (MOE_ROUTER, MOE_DISPATCH, MOE_EXPERTS, MOE_SHARED, MOE_COMBINE)  # on the step only where a layer holds experts
 
 # what a path holds beside scopes: the jit wrapper, the plumbing of loops, calls and branches
 _PLUMBING = frozenset(("while", "body", "cond", "closed_call", "checkpoint"))

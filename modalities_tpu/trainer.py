@@ -76,7 +76,7 @@ from modalities_tpu.telemetry.memscope import (
     preflight_fits_check,
 )
 from modalities_tpu.telemetry.perfscope import ProfileWindow
-from modalities_tpu.training.train_step import StepFunctions
+from modalities_tpu.training.train_step import COUNTER_PREFIX, StepFunctions
 from modalities_tpu.training.training_progress import TrainingProgress
 from modalities_tpu.utils.logging import get_logger
 
@@ -569,6 +569,11 @@ class Trainer:
             losses = np.asarray([m["loss"] for m in pending_metrics], dtype=np.float64)
             grad_norms = np.asarray([m["grad_norm"] for m in pending_metrics], dtype=np.float64)
             lrs = np.asarray([m["lr"] for m in pending_metrics], dtype=np.float64)
+            # what the model counted beside its loss (train_step.COUNTER_PREFIX): fetched with the rest, no further sync
+            counters = {
+                key[len(COUNTER_PREFIX):]: np.asarray([m[key] for m in pending_metrics], dtype=np.float64)
+                for key in pending_metrics[0] if key.startswith(COUNTER_PREFIX)
+            }
         fetch_done = time.perf_counter()
         wall_elapsed = max(fetch_done - interval_start, 1e-9)
         host_stall_s = feed.take_stall_s() if feed is not None else 0.0
@@ -640,6 +645,7 @@ class Trainer:
                 "grad norm last": ResultItem(grad_norms[-1], 5),
                 "lr mean": ResultItem(lrs.mean(), 8),
                 "consumed tokens": ResultItem(tokens_total, 0),
+                **{name: ResultItem(values.max() if name.endswith("_max") else values.mean(), 2) for name, values in counters.items()},
             },
             throughput_metrics=throughput,
         )

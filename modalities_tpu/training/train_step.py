@@ -38,6 +38,10 @@ from modalities_tpu.utils.logging import get_logger
 
 logger = get_logger(__name__)
 
+# a step metric under this prefix is a scalar the model counted (`NNModel.counted`), not a loss:
+# the trainer publishes each under its name, the mean of an interval (the largest where the name ends in `_max`)
+COUNTER_PREFIX = "counter/"
+
 
 def _unbox(tree):
     return nn_meta.unbox(tree)
@@ -449,6 +453,13 @@ class TrainStepBuilder:
                 "chunk size or use a CLM-style loss"
             )
 
+        # what the model counts in a pass beside its loss (an expert layer's routing: models/gpt2/moe.py; nothing, for
+        # most models): an auxiliary output of the loss, summed over the microbatches on the device; the scalars are
+        # published with the step's metrics, and all of it goes to `model.after_update` once the optimizer is done
+        counted_shapes = dict(model.counted)
+        if counted_shapes and (mesh_handle is not None and mesh_handle.degrees.get("dcn", 1) > 1):
+            raise NotImplementedError("a model that counts in a step (expert layers) under a dcn mesh axis: the per-slice groups do not carry what it counts")
+
         if chunked_loss:
             # fused head + CE per sequence chunk: the [B,S,V] fp32 logits never
             # materialize (6.6 GB at 32k ctx x 50k vocab). Each chunk's projection
@@ -515,20 +526,17 @@ class TrainStepBuilder:
                 return total / jnp.maximum(count, 1.0)
 
             def compute_loss(params, samples, targets, dropout_rng):
-                hidden = model.apply_hidden(
-                    params, samples, train=True,
-                    rngs={"dropout": dropout_rng} if dropout_rng is not None else None,
-                )
-                return _chunked_ce(params, hidden, targets[target_key])
+                rngs = {"dropout": dropout_rng} if dropout_rng is not None else None
+                hidden, counted = model.apply_counted(params, samples, train=True, rngs=rngs, hidden=True)
+                return _chunked_ce(params, hidden, targets[target_key]), counted
 
         else:
 
             def compute_loss(params, samples, targets, dropout_rng):
-                predictions = model.apply(
-                    params, samples, train=True, rngs={"dropout": dropout_rng} if dropout_rng is not None else None
-                )
+                rngs = {"dropout": dropout_rng} if dropout_rng is not None else None
+                predictions, counted = model.apply_counted(params, samples, train=True, rngs=rngs)
                 with jax.named_scope(scopes.HEAD_LOSS):
-                    return loss_fn(predictions, targets)
+                    return loss_fn(predictions, targets), counted
 
         # scheduled pipelining (1F1B): hand-rolled fwd/bwd with in-region loss replaces
         # value_and_grad through the in-module autodiff GPipe (the "gpipe" default)
@@ -567,12 +575,12 @@ class TrainStepBuilder:
                     rng=dropout_rng if model_dropout > 0.0 else None,
                     seq_shard_axis=pp_seq_axis,
                 )
-                return loss, model.merge_pp_grads(g_stacked, g_shared)
+                return (loss, {}), model.merge_pp_grads(g_stacked, g_shared)  # the stage functions carry nothing counted
 
         else:
 
             def loss_and_grads(params, samples, targets, dropout_rng):
-                return jax.value_and_grad(compute_loss)(params, samples, targets, dropout_rng)
+                return jax.value_and_grad(compute_loss, has_aux=True)(params, samples, targets, dropout_rng)
 
         def make_train_step(with_grads: bool):
             def train_step(state: AppState, batch: dict) -> tuple[AppState, dict]:
@@ -584,7 +592,7 @@ class TrainStepBuilder:
                 def micro(acc, xs):
                     mb_index, s, t = xs
                     dropout_rng = jax.random.fold_in(step_rng, mb_index)
-                    g_acc, l_acc = acc
+                    g_acc, l_acc, c_acc = acc  # c_acc: what the model counted, an empty dict for a model that counts nothing
                     if hierarchical_dcn:
                         # per-slice groups: each slice computes grads over its own
                         # batch rows; all in-model collectives stay intra-slice
@@ -593,14 +601,17 @@ class TrainStepBuilder:
                         group_rngs = jax.vmap(
                             lambda i: jax.random.fold_in(dropout_rng, i)
                         )(jnp.arange(dcn_degree))
-                        loss, grads = jax.vmap(
+                        (loss, _), grads = jax.vmap(
                             loss_and_grads,
                             in_axes=(None, 0, 0, 0),
                             spmd_axis_name="dcn",
                         )(state.params, s, t, group_rngs)
                         loss = jax.lax.with_sharding_constraint(loss, dcn_loss_sharding)
                     else:
-                        loss, grads = loss_and_grads(state.params, s, t, dropout_rng)
+                        (loss, counted), grads = loss_and_grads(state.params, s, t, dropout_rng)
+                        # a largest value is the largest over the microbatches, everything else their mean
+                        c_acc = {name: jnp.maximum(c_acc[name], counted[name]) if name.endswith("_max")
+                                 else c_acc[name] + counted[name] / acc_steps for name in c_acc}
                     # accumulate in reduce_dtype (fp32 by default) even when grads are bf16
                     g_acc = jax.tree.map(lambda a, g: a + g.astype(reduce_dtype), g_acc, grads)
                     if hierarchical_dcn:
@@ -610,14 +621,14 @@ class TrainStepBuilder:
                         l_acc = jax.lax.with_sharding_constraint(
                             l_acc + loss, dcn_loss_sharding
                         )
-                        return (g_acc, l_acc), None
+                        return (g_acc, l_acc, c_acc), None
                     if zero_grad_shardings is not None:
                         # each microbatch's partial-sum grads reshard into the ZeRO
                         # layout here — this is the constraint GSPMD lowers to the
                         # reduce-scatter over dp_replicate (instead of the stage-0
                         # all-reduce that would replicate the full grads)
                         g_acc = jax.lax.with_sharding_constraint(g_acc, zero_grad_shardings)
-                    return (g_acc, l_acc + loss), None
+                    return (g_acc, l_acc + loss, c_acc), None
 
                 # the whole microbatch loop sits under one scope: what autodiff marks inside it
                 # (jvp, transpose) reads as a pass, the rest (the zero accumulator, the loop's own
@@ -636,8 +647,9 @@ class TrainStepBuilder:
                         if zero_grad_shardings is not None:
                             zero_grads = jax.lax.with_sharding_constraint(zero_grads, zero_grad_shardings)
                         loss_init = 0.0
-                    (grads, loss_sum), _ = jax.lax.scan(
-                        micro, (zero_grads, loss_init), (jnp.arange(acc_steps), samples, targets)
+                    zero_counters = {name: jnp.zeros(shape, jnp.float32) for name, shape in counted_shapes.items()}
+                    (grads, loss_sum, step_counted), _ = jax.lax.scan(
+                        micro, (zero_grads, loss_init, zero_counters), (jnp.arange(acc_steps), samples, targets)
                     )
                     if hierarchical_dcn:
                         # THE hierarchical-reduction crossing point: the mean over the
@@ -678,7 +690,7 @@ class TrainStepBuilder:
                 with jax.named_scope(scopes.OPTIMIZER):
                     updates, new_opt_state = tx.update(grads, state.opt_state, state.params)
                 with jax.named_scope(scopes.APPLY_UPDATES):
-                    new_params = optax.apply_updates(state.params, updates)
+                    new_params = model.after_update(optax.apply_updates(state.params, updates), step_counted)
                     if zero_grad_shardings is not None and param_shardings is not None:
                         # re-materialize full (dp_replicate-replicated) params: the one
                         # all-gather paired with the reduce-scatter above
@@ -704,6 +716,9 @@ class TrainStepBuilder:
                         "grad_norm": grad_norm,
                         "lr": jnp.asarray(lr_fn(state.step), jnp.float32),
                     }
+                    for name, shape in counted_shapes.items():
+                        if shape == ():
+                            metrics[COUNTER_PREFIX + name] = step_counted[name]
                     if skip_on_anomaly:
                         metrics["skipped_step"] = (~ok).astype(jnp.int32)
                     if error_if_nonfinite:
@@ -764,6 +779,9 @@ class TrainStepBuilder:
                 "grad_norm": replicated_sharding,
                 "lr": replicated_sharding,
             }
+            for name, shape in counted_shapes.items():
+                if shape == ():
+                    metrics_shardings[COUNTER_PREFIX + name] = replicated_sharding
             if skip_on_anomaly:
                 metrics_shardings["skipped_step"] = replicated_sharding
             if error_if_nonfinite:

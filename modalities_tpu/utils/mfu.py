@@ -51,7 +51,14 @@ class GPT2MFUCalculator(MFUCalculatorIF):
     """MFU = tokens/s * (6N + 12*L*s*h) / (world * peak) (reference :150-197), with L the
     layers that hold attention: all of them in the dense decoder; in a model whose layers
     are of two kinds (`attn_layer_period`), read off the wrapped model's spec, those that
-    are not state-space layers (whose scan is elementwise work and adds no `s*h` term)."""
+    are not state-space layers (whose scan is elementwise work and adds no `s*h` term).
+
+    A model with expert layers or latent attention (`moe_config`, `mla_config`) is counted
+    by what a token passes, never by the dense formula: N without the routed experts'
+    stacks plus `num_experts_per_tok` experts a token an expert layer (every chosen expert:
+    where only a share of the experts is held, `experts_held / n_routed_experts` of them on
+    average), and `6 * s * H * (qk_head_dim + v_head_dim)` a layer for the attention's two
+    products at their two head sizes (full, as the dense formula counts attention)."""
 
     def __init__(
         self,
@@ -73,12 +80,23 @@ class GPT2MFUCalculator(MFUCalculatorIF):
         if num_parameters is None and wrapped_model is not None:
             num_parameters = _count_params(wrapped_model)
         self.num_parameters = num_parameters or 0
-        kinds = getattr(getattr(model_parts if model_parts is not None else wrapped_model, "config_spec", None), "layer_kinds", ())
+        spec = getattr(model_parts if model_parts is not None else wrapped_model, "config_spec", None)
+        kinds = getattr(spec, "layer_kinds", ())
         self.n_attention_layer = kinds.count("attn") if kinds else n_layer
+        self.active_parameters = self.num_parameters
+        self.attention_width = 2 * n_embd  # q k^T and p v, each n_head * head_dim = n_embd wide
+        moe, mla = getattr(spec, "moe", None), getattr(spec, "mla", None)
+        if moe is not None:
+            expert = 3 * n_embd * moe.moe_intermediate_size
+            expert_layers = spec.ffn_kinds.count("moe")
+            chosen_here = moe.num_experts_per_tok * moe.experts_held / moe.n_routed_experts
+            self.active_parameters = self.num_parameters - expert_layers * (moe.experts_held - chosen_here) * expert
+        if mla is not None:
+            self.attention_width = spec.n_head_q * (mla.qk_head_dim + mla.v_head_dim)
         self._peak = get_peak_flops()
 
     def compute(self, tokens_per_second: float) -> float:
-        flops_per_token = 6 * self.num_parameters + 12 * self.n_attention_layer * self.sequence_length * self.n_embd
+        flops_per_token = 6 * self.active_parameters + 6 * self.n_attention_layer * self.sequence_length * self.attention_width
         return tokens_per_second * flops_per_token / (self.world_size * self._peak)
 
 

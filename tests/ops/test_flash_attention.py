@@ -169,3 +169,69 @@ def test_values_and_gradients_match_oracle_in_every_tile_class(case, monkeypatch
     want = jax.grad(lambda q, k, v: (reference(q, k, v) * w).sum(), argnums=(0, 1, 2))(q, k, v)
     for g, e, name in zip(got, want, "qkv"):
         np.testing.assert_allclose(np.asarray(g), np.asarray(e), rtol=5e-4, atol=5e-4, err_msg=f"d{name}")
+
+
+# ------------------------------------------- two head sizes: q and k wider than v (latent attention)
+
+
+TWO_WIDTH_CASES = {
+    # (seq, block_q, block_k, heads, head_dim, head_dim_v): at least three tiles a side
+    "d48_dv32_diagonal_walked": (96, 32, 32, 2, 48, 32),
+    "d48_dv32_whole_tile_mask": (96, 32, 16, 2, 48, 32),
+    "d192_dv128_diagonal_walked": (48, 16, 16, 1, 192, 128),
+    "d192_dv128_whole_tile_mask": (48, 16, 8, 1, 192, 128),
+}
+
+
+@pytest.mark.parametrize("case", sorted(TWO_WIDTH_CASES))
+def test_two_head_sizes_match_manual_attention(case, monkeypatch):
+    """v, the output, its cotangent and dv at one width, q, k, dq and dk at another; the scale is that of q's."""
+    seq, block_q, block_k, heads, d, dv = TWO_WIDTH_CASES[case]
+    monkeypatch.setattr(flash, "_DIAG_SUB_BLOCK", 8)
+    rng = jax.random.PRNGKey(13)
+    q = jax.random.normal(jax.random.fold_in(rng, 0), (2, seq, heads, d))
+    k = jax.random.normal(jax.random.fold_in(rng, 1), (2, seq, heads, d))
+    v = jax.random.normal(jax.random.fold_in(rng, 2), (2, seq, heads, dv))
+    w = jax.random.normal(jax.random.fold_in(rng, 3), (2, seq, heads, dv))
+    kernel = functools.partial(pallas_flash_attention, causal=True, block_q=block_q, block_k=block_k, interpret=True)
+    out = kernel(q, k, v)
+    assert out.shape == (2, seq, heads, dv)
+    np.testing.assert_allclose(np.asarray(out), np.asarray(manual_attention(q, k, v)), rtol=2e-5, atol=2e-5)
+    got = jax.grad(lambda q, k, v: (kernel(q, k, v) * w).sum(), argnums=(0, 1, 2))(q, k, v)
+    want = jax.grad(lambda q, k, v: (manual_attention(q, k, v) * w).sum(), argnums=(0, 1, 2))(q, k, v)
+    assert [g.shape[-1] for g in got] == [d, d, dv]
+    for g, e, name in zip(got, want, "qkv"):
+        np.testing.assert_allclose(np.asarray(g), np.asarray(e), rtol=5e-4, atol=5e-4, err_msg=f"d{name}")
+
+
+def test_equal_head_sizes_lower_to_the_program_they_always_did():
+    """The second width changes nothing where it equals the first: the same tilings under both names, so the
+    same jaxpr for the three kernels as a v of q's width always gave (blocks, scratch and out_shape by value)."""
+    q, k, v = _rand_qkv(5, 1, 64, 2, 1, 32)
+    loss = lambda q, k, v: pallas_flash_attention(q, k, v, causal=True, block_q=16, block_k=16, interpret=True).sum()  # noqa: E731
+    text = str(jax.make_jaxpr(jax.grad(loss, argnums=(0, 1, 2)))(q, k, v))
+    for name in ("flash_attention_fwd", "flash_attention_bwd_dq", "flash_attention_bwd_dkv"):
+        assert text.count(f"name={name}") == 1, name
+    # and the dispatcher's fallback off the TPU: SDPA where it can, the plain softmax where v is narrower
+    from modalities_tpu.ops.attention import flash_attention_or_fallback
+
+    narrow = v[..., :16]
+    np.testing.assert_allclose(flash_attention_or_fallback(q, k, narrow), manual_attention(q, k, narrow), rtol=2e-5, atol=2e-5)
+    np.testing.assert_allclose(flash_attention_or_fallback(q, k, v), manual_attention(q, k, v), rtol=2e-5, atol=2e-5)
+
+
+def test_blocks_are_looked_up_by_both_widths_only_where_they_differ(monkeypatch):
+    """The accepted cells read `flash_attention|*|*`; latent attention reads the bucket of its two widths, at any sequence."""
+    from modalities_tpu.ops.pallas import autotune
+
+    asked = []
+    monkeypatch.setattr(autotune, "lookup", lambda kernel, bucket, dtype: asked.append(bucket) or None)
+    flash.env_flash_blocks(4096, 4096, head_dim=80, head_dim_v=80)
+    flash.env_flash_blocks(4096, 4096)
+    flash.env_flash_blocks(8192, 8192, head_dim=192, head_dim_v=128)
+    flash.env_flash_blocks(4096, 4096, head_dim=192, head_dim_v=128)
+    assert asked == ["sq4096_sk4096", "sq4096_sk4096", "d192_dv128", "d192_dv128"]
+    monkeypatch.undo()
+    assert autotune.lookup("flash_attention", "sq4096_sk4096", "bfloat16", device_kind="TPU v5 lite") == {"block_q": 1024, "block_k": 1024}
+    hit = autotune.lookup("flash_attention", "d192_dv128", "bfloat16", device_kind="TPU v5 lite")
+    assert hit is not None and (hit["block_q"], hit["block_k"]) != (1024, 1024), "1024 x 1024 does not fit VMEM at 192/128 (bwd_dq)"

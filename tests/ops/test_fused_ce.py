@@ -211,3 +211,15 @@ def test_multidim_hidden_flattened():
         return total / count
 
     assert jax.grad(loss)(h).shape == h.shape
+
+
+def test_blocks_step_down_by_width_as_the_chip_compiles_them():
+    """The blocks the shipped 256 x 512 comes down to: unmoved at the widths the accepted cells run (E 2560: 256 x 256;
+    E 1536: as shipped), and at E 2048, where the forward inside a whole step asked 16.80 MiB of the 16 at 256 x 512
+    (PR 30: the kernel alone compiles there), 256 x 256, which also divides the 16,128 rows of that cell's head."""
+    from modalities_tpu.ops.pallas import fused_ce
+
+    fitted = {e: fused_ce._fit_blocks_to_vmem(256, 512, e, 2) for e in (1536, 2048, 2560, 4096)}
+    assert fitted == {1536: (256, 512), 2048: (256, 256), 2560: (256, 256), 4096: (128, 128)}
+    assert fused_ce._forward_in_step_vmem_bytes(256, 512, 2048, 2) > 16 * 2**20 > fused_ce._forward_in_step_vmem_bytes(256, 256, 2560, 2)
+    assert 16128 % 256 == 0

@@ -30,6 +30,14 @@ from modalities_tpu.ops.pallas.selective_scan import pallas_selective_scan
 BF16, F32, VOCAB, SEQ = jnp.bfloat16, jnp.float32, 50304, 4096
 
 
+def _table_blocks(head_dim: int, head_dim_v: int) -> tuple[int, int]:
+    """What tuning_tables/v5e.json gives the dispatcher at these widths, whatever the sequence."""
+    from modalities_tpu.ops.pallas import autotune
+
+    hit = autotune.lookup("flash_attention", f"d{head_dim}_dv{head_dim_v}", "bfloat16", device_kind="TPU v5 lite")
+    return hit["block_q"], hit["block_k"]
+
+
 @pytest.fixture(scope="module")
 def v5e():
     from jax.experimental import topologies
@@ -59,6 +67,16 @@ def _flash(heads_q, heads_kv, head_dim):
     return jax.grad(loss, argnums=(0, 1, 2)), (q, kv, kv), 3
 
 
+def _flash_two_widths(batch, seq, heads, head_dim, head_dim_v, block_q, block_k):
+    """Latent attention's kernels: q and k wider than v (benchmark/configs/kanana2-30b-a3b-d9: 2 x 8192 x 32 heads of
+    192 / 128), at the blocks the tuning table's own bucket gives them (1024 x 1024 asks 17.27 MiB for `bwd_dq`)."""
+    def loss(q, k, v):
+        return pallas_flash_attention(q, k, v, block_q=block_q, block_k=block_k).astype(F32).sum()
+
+    wide, narrow = ((batch, seq, heads, head_dim), BF16), ((batch, seq, heads, head_dim_v), BF16)
+    return jax.grad(loss, argnums=(0, 1, 2)), (wide, wide, narrow), 3
+
+
 def _ring_hop_not_causal():
     """An off-diagonal hop of ring attention (parallel/ring_attention.py) at
     configs/config_7b_warmstart_32k.yaml's per-device shape: 32,768 over cp 4, 32 q and
@@ -76,7 +94,7 @@ def _ring_hop_not_causal():
 def _fused_ce(n_embd, rows, vocab=VOCAB):
     """Forward with d_hidden carried in its running sums, and the head's backward: the dense
     cell's 8,192 rows (and 16,384, a microbatch of 4) against 50,304, the hybrid cell's 4,096
-    against the 32,768 rows of its tied table."""
+    against the 32,768 rows of its tied table, the expert cell's 16,384 at width 2048 against 16,128."""
     def loss(hidden, head, labels):
         # the blocks tuning_tables/v5e.json ships; the kernel steps them down to VMEM
         total, count = fused_ce_sum_and_count(hidden, head, labels, block_rows=256, block_vocab=512)
@@ -112,6 +130,10 @@ CASES = {
     "flash_fwd_bwd_d128": _flash(16, 16, 128),
     "flash_fwd_bwd_d80_gqa_32_8": _flash(32, 8, 80),
     "flash_ring_hop_not_causal_d128_gqa_4_1": _ring_hop_not_causal(),
+    "flash_fwd_bwd_d192_dv128_b2_s8192_h32": _flash_two_widths(2, 8192, 32, 192, 128, *_table_blocks(192, 128)),
+    # configs/config_kanana2_30b_a3b.yaml's own shape: sequence 4096, microbatch 4
+    "flash_fwd_bwd_d192_dv128_b4_s4096_h32": _flash_two_widths(4, 4096, 32, 192, 128, *_table_blocks(192, 128)),
+    "fused_ce_fwd_bwd_e2048_rows16384_v16128": _fused_ce(2048, 4 * SEQ, vocab=16128),
     "fused_ce_fwd_bwd_e1536": _fused_ce(1536, SEQ),
     "fused_ce_fwd_bwd_e2560": _fused_ce(2560, 4 * SEQ),
     "fused_ce_fwd_bwd_e2560_rows8192": _fused_ce(2560, 2 * SEQ),

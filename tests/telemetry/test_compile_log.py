@@ -99,11 +99,36 @@ def test_flash_tile_plan_is_one_event_per_traced_shape_and_none_per_step(tmp_pat
         set_active_telemetry(previous)
     plans = [e for e in map(json.loads, telemetry.sink_path.read_text().splitlines()) if e.get("name") == "flash_tile_plan"]
     assert [{k: v for k, v in e.items() if k not in ("event", "name", "rank")} for e in plans] == [
-        {"seq_q": 48, "seq_k": 48, "block_q": 16, "block_k": 16, "causal": True,
+        {"seq_q": 48, "seq_k": 48, "block_q": 16, "block_k": 16, "causal": True, "head_dim": 8, "head_dim_v": 8,
          "computed": 6, "interior": 3, "diagonal": 3, "skipped_steps": 0},
-        {"seq_q": 32, "seq_k": 32, "block_q": 16, "block_k": 16, "causal": False,
+        {"seq_q": 32, "seq_k": 32, "block_q": 16, "block_k": 16, "causal": False, "head_dim": 8, "head_dim_v": 8,
          "computed": 4, "interior": 4, "diagonal": 0, "skipped_steps": 0},
     ]
+
+
+def test_moe_dispatch_plan_is_one_event_per_traced_shape_and_none_per_step(tmp_path):
+    """The expert layer says once, while tracing, what its dispatch is sized for (`moe_dispatch_plan`): the tokens,
+    the router's width and a token's choices, the experts held and from where, the rows its tables hold (every pair
+    on held experts, each group's last tile padded), the tile, and that no kernels were traced (the plain form);
+    two expert layers of one shape and three steps of one executable say it once."""
+    from tests.models.test_moe_mla import build
+
+    from modalities_tpu.ops.expert_dispatch import TILE, rows_for
+
+    model = build()
+    telemetry = Telemetry(output_folder_path=tmp_path, watchdog_deadline_s=0)
+    previous = set_active_telemetry(telemetry)
+    try:
+        params = jax.jit(model.init_params)(jax.random.PRNGKey(0))  # the initializer's dummy of 8 tokens is a shape too
+        apply = jax.jit(lambda p, t: model.apply(p, {"input_ids": t})["logits"])
+        for _ in range(3):
+            apply(params, jnp.zeros((2, 64), jnp.int32)).block_until_ready()
+    finally:
+        set_active_telemetry(previous)
+    plans = [e for e in map(json.loads, telemetry.sink_path.read_text().splitlines()) if e.get("name") == "moe_dispatch_plan"]
+    assert [{k: v for k, v in e.items() if k not in ("event", "name", "rank")} for e in plans] == [
+        {"tokens": tokens, "router_width": 8, "choices": 3, "experts_held": 4, "expert_offset": 2, "tile": TILE,
+         "rows": rows_for(3 * tokens, 4, TILE), "kernels": False} for tokens in (8, 128)]
 
 
 @pytest.mark.parametrize("kernels", [False, True], ids=["plain_form", "kernels_interpreted"])

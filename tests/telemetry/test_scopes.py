@@ -208,6 +208,84 @@ def test_the_scan_kernels_keep_their_names_under_ssm_scan(tmp_path, monkeypatch,
     assert re.search(r"`selective_scan_\{fwd,bwd\}`", scopes.__doc__), "the vocabulary names the kernels beside the other three families"
 
 
+# ------------------------------------------------------------------ (a'') closure, for latent attention and expert layers
+
+
+def compile_toy_moe_train_step(tmp: Path) -> str:
+    """The benchmark's expert cell at toy size (tests/benchmark/toy_moe.py: a dense layer and two
+    expert layers, latent attention in all three, every block rematerialized), built as its
+    mode builds it: the optimized HLO text of its train step."""
+    from benchmark.manifest import load_cell
+    from benchmark.weights_moe import MoEMLAShape
+    from tests.benchmark.toy_moe import CELL as MOE_CELL, make_toy_moe_root
+
+    root = make_toy_moe_root(tmp)
+    cell = load_cell(MOE_CELL, root)
+    mode = cell.module("modes", cell.mode)
+    raw = yaml.safe_load(cell.yaml_path.read_text())
+    shape = MoEMLAShape.from_yaml(raw)
+    profile = raw["settings"]["step_profile"]
+    scratch = root / ".bench_scratch" / cell.name
+    (scratch / "data").mkdir(parents=True)
+    cell.module("traffic", cell.traffic["generator"]).generate(
+        cell.traffic, 1, scratch / "data" / "train.pbin", vocab_size=shape.vocab_size,
+        sequence_length=int(profile["sequence_length"]))
+    started_in = os.getcwd()
+    try:
+        _, fns = mode.build_program(cell, 1, scratch, shape)
+    finally:
+        os.chdir(started_in)
+    keys = raw["settings"]["referencing_keys"]
+    tokens = np.zeros((int(profile["local_train_micro_batch_size"]), int(profile["sequence_length"])), np.int32)
+    host = {"samples": {keys["sample_key"]: tokens[None]}, "targets": {keys["target_key"]: tokens[None]}}
+    return fns.lower_train_step(fns.put_batch(host, has_acc_dim=True)).compile().as_text()
+
+
+@pytest.fixture(scope="module")
+def moe_hlo(tmp_path_factory) -> str:
+    return compile_toy_moe_train_step(tmp_path_factory.mktemp("scoped_moe"))
+
+
+@pytest.fixture(scope="module")
+def moe_rules() -> dict:
+    raw = json.loads((REPO / "benchmark" / "scopes" / "train_moe.json").read_text())
+    return {name: [(re.compile(pattern), bucket) for pattern, bucket in raw[name]] for name in LISTS}
+
+
+@pytest.mark.parametrize("which", LISTS)
+def test_every_operation_of_the_toy_moe_step_falls_into_a_bucket(moe_hlo, moe_rules, which):
+    table = scope_table(moe_hlo)
+    assert len(table) > 100
+    paths = set(table.values()) | every_op_name(moe_hlo)
+    left = {path for path in paths if bucket_of(path, moe_rules[which]) == UNATTRIBUTED}
+    assert not left, f"no rule of the list {which!r} takes {sorted(left)[:5]}"
+    if which == "component":
+        found = {bucket_of(path, moe_rules[which]) for path in paths}
+        assert {"attn", "moe_router", "moe_dispatch", "moe_experts", "moe_shared", "moe_combine", "mlp", "norms", "residual",
+                "head_loss", "wte", "layer_carry"} <= found
+
+
+@pytest.mark.parametrize("scope", scopes.MOE_SCOPES)
+def test_each_scope_of_the_expert_layer_is_on_the_step_under_moe_in_both_passes(moe_hlo, scope):
+    names = [n for n in every_op_name(moe_hlo) if re.search(rf"/{scopes.MOE}/(.*/)?{scope}/", n)]
+    assert any("/jvp(GPT2Module)/" in n for n in names), scope
+    assert any("transpose(jvp(GPT2Module))" in n for n in names), scope
+
+
+def test_the_runs_are_by_feed_forward_and_latent_attention_keeps_the_names_under_attn(moe_hlo, moe_rules):
+    names = every_op_name(moe_hlo)
+    assert any("jvp(GPT2Module)/run_0/layer_carry/while/body/closed_call/blocks/block/mlp/" in n for n in names)
+    assert any("jvp(GPT2Module)/run_1/layer_carry/while/body/closed_call/blocks/block/moe/" in n for n in names)
+    assert not any("/run_0/" in n and "/moe/" in n for n in names) and not any("/run_1/" in n and "/block/mlp/" in n for n in names)
+    for module in ("q_proj", "kv_a_proj", "kv_a_norm", "kv_b_proj", "c_proj", "rope", "attn_core"):
+        assert any(f"/blocks/block/attn/{module}/" in n for n in names), module
+    # the grouped products are dots under moe/experts and nowhere else in the layer's loops; gathers and adds by token are not
+    in_experts = {n.rsplit("/", 1)[-1] for n in names if re.search(r"/moe/(.*/)?experts/", n)}
+    in_dispatch = {n.rsplit("/", 1)[-1] for n in names if re.search(r"/moe/(.*/)?(dispatch|combine)/", n)}
+    assert "dot_general" in in_experts and "dot_general" not in in_dispatch
+    assert re.search(r"MOE_DISPATCH\s+dispatch", scopes.__doc__) and re.search(r"MOE_COMBINE\s+combine", scopes.__doc__)
+
+
 # ------------------------------------------------------------------ (b) only metadata
 
 
