@@ -35,13 +35,21 @@ def flash_attention_or_fallback(q, k, v, causal: bool = True, sm_scale: float | 
     in latent attention (192 and 128); the kernels read both widths off the arrays.
 
     Block sizes come from `env_flash_blocks`: MODALITIES_TPU_FLASH_BLOCK_Q / _BLOCK_K,
-    else the device's tuning table (1024 x 1024 on a v5e), stepped down automatically
-    for shorter sequences. What the driver's record holds for that choice is the
-    benchmark's cell `train-2p7b-4k` (S 4096, 32 q / 8 kv heads of 80; PERF.md, sections
-    5 and 6): the three kernels took 68.1 ms of a 340.3 ms step at 1024 x 1024 (ledger,
-    PR 24) and 50.7 of 322.0 once every score tile got only the work its place asks for
-    (PR 25, which also read 512 x 512 in the cell: 333.3 ms a step). 1024 x 1024 fp32
-    score tiles fit VMEM (4 MB each).
+    else the device's tuning table (1024 x 1024 on a v5e; 1024 x 512 at 192/128), stepped
+    down automatically for shorter sequences. What the driver's record holds for that
+    choice is the benchmark's cells (PERF.md, sections 5 and 6): in `train-2p7b-4k` (S 4096,
+    32 q / 8 kv heads of 80) the three kernels took 68.1 ms of a 340.3 ms step at
+    1024 x 1024 (ledger, PR 24) and 50.7 of 322.0 once every score tile got only the work
+    its place asks for (PR 25, which also read 512 x 512 in the cell: 333.3 ms a step).
+
+    The backward of a differentiated call is one kernel since PR 31, `flash_attention_bwd`
+    (dq, dk and dv from one evaluation of p and ds a tile, a q head's dq row resident in
+    VMEM), wherever `backward_plan` counts it within its VMEM budget: every cell's shape
+    and every recipe's but rows of 32k, which keep `flash_attention_bwd_dq` + `_bwd_dkv`.
+    The `flash_tile_plan` event says which (`backward`, `dq_resident_bytes`,
+    `backward_vmem_bytes`); what each form costs alone is
+    `scripts/moe_mla_parts_bench.py --parts flash`, and PERF.md section 6 (PR 31) has the
+    chip's readings.
 
     Under a mesh the kernel runs per shard, split over batch and heads
     (parallel/sharding.per_shard)."""
@@ -49,18 +57,23 @@ def flash_attention_or_fallback(q, k, v, causal: bool = True, sm_scale: float | 
         if v.shape[-1] != q.shape[-1]:
             return _plain_attention(q, k, v, causal, sm_scale)  # SDPA takes one width for q, k and v
         return jax.nn.dot_product_attention(q, k, v, is_causal=causal, scale=sm_scale)
-    from modalities_tpu.ops.pallas.flash_attention import env_flash_blocks, pallas_flash_attention, tile_plan
+    from modalities_tpu.ops.pallas.flash_attention import backward_plan, env_flash_blocks, pallas_flash_attention, tile_plan
     from modalities_tpu.parallel.sharding import per_shard
     from modalities_tpu.telemetry import get_active_telemetry
 
-    block_q, block_k = env_flash_blocks(q.shape[1], k.shape[1], dtype=q.dtype, head_dim=q.shape[-1], head_dim_v=v.shape[-1])
+    shape = dict(dtype=q.dtype, head_dim=q.shape[-1], head_dim_v=v.shape[-1])
+    block_q, block_k = env_flash_blocks(q.shape[1], k.shape[1], **shape)
+    bwd_blocks = env_flash_blocks(q.shape[1], k.shape[1], backward=True, **shape)
     plan = {"seq_q": q.shape[1], "seq_k": k.shape[1], "block_q": block_q, "block_k": block_k, "causal": causal}
     # runs while tracing: the operator sees once per shape how many score tiles a
-    # (batch, head) computes and which share takes the masked body; nothing per step
+    # (batch, head) computes, which share takes the masked body, and which backward a
+    # differentiated call would run (by the shape alone: a mesh splits batch and heads,
+    # and the resident dq is one head's); nothing per step
     get_active_telemetry().emit_event_once(
-        "flash_tile_plan", {**plan, "head_dim": q.shape[-1], "head_dim_v": v.shape[-1], **tile_plan(**plan).counts()})
+        "flash_tile_plan", {**plan, "head_dim": q.shape[-1], "head_dim_v": v.shape[-1], **tile_plan(**plan).counts(),
+                            **backward_plan(q.shape[1], *bwd_blocks, q.shape[-1], v.shape[-1], q.dtype)})
     kernel = functools.partial(
-        pallas_flash_attention, causal=causal, sm_scale=sm_scale, block_q=block_q, block_k=block_k
+        pallas_flash_attention, causal=causal, sm_scale=sm_scale, block_q=block_q, block_k=block_k, bwd_blocks=bwd_blocks
     )
     return per_shard(
         lambda _axes, q, k, v: kernel(q, k, v), (_Q_AXES, _KV_AXES, _KV_AXES), _Q_AXES

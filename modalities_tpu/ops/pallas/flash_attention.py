@@ -8,14 +8,23 @@ Design (FlashAttention-2 style, TPU-first):
   regardless of sequence length; logsumexp is saved for the backward. v, the output,
   its cotangent and dv take their width from v (`head_dim_v`), q, k, dq and dk from q:
   latent attention has 192 and 128, and pads neither to the other.
-- backward: two kernels with the same streaming structure — dq over q blocks
-  (kv innermost) and dk/dv over kv blocks (q innermost) — recomputing probabilities
-  blockwise from the saved logsumexp (no S x S materialization anywhere). GQA folds
-  the q-head group into the kv index map; dk/dv are accumulated per q-head and
-  group-summed outside the kernel.
+- backward: one kernel, `flash_attention_bwd` (PR 31), walks the plan's kv-major table once
+  and recomputes a tile's probabilities p and ds = p (dp - delta) once from the saved
+  logsumexp (no S x S materialization anywhere): 5 products a tile. dk and dv accumulate
+  over a kv tile's q tiles in [BK, D] scratch; dq's terms for a q tile come from every kv
+  tile at or below it, so one (batch, q head)'s whole dq row stays in VMEM as float32
+  ([S/BQ, BQ, D], zeroed at the head's first pair, cast and written at its last) under a
+  raised `vmem_limit_bytes`. `backward_plan` picks it from the shapes wherever its counted
+  need (`fused_backward_vmem_bytes`) is within `FUSED_BWD_VMEM_BUDGET`. Rows too long for
+  that (32k x 128), and ring attention's backward (which calls them by name with merged
+  lse / delta), run the two kernels the fused one replaced, with the same streaming
+  structure and the same sums in the same order: `flash_bwd_dq` over q blocks (kv
+  innermost) and `flash_bwd_dkv` over kv blocks (q innermost), each recomputing p and ds
+  (7 products a tile between them). GQA folds the q-head group into the kv index map;
+  dk/dv are accumulated per q-head and group-summed outside the kernel.
 - every score tile gets only the work its place asks for (PR 25). `tile_plan`
-  classifies the [BQ, BK] tiles at trace time from the static shapes and the three
-  kernels run on a grid (batch, head, pair) over the tiles that compute at all, looked
+  classifies the [BQ, BK] tiles at trace time from the static shapes and every
+  kernel runs on a grid (batch, head, pair) over the tiles that compute at all, looked
   up in a scalar-prefetched table (`pltpu.PrefetchScalarGridSpec`; index maps read the
   pair's q and kv tile from it, init and finish fire on a row's first and last pair):
   a tile above the causal diagonal is no grid step and fetches nothing; a tile wholly
@@ -27,13 +36,15 @@ Design (FlashAttention-2 style, TPU-first):
 - block sizes: this module's own defaults are 128 (the MXU tile), but the shipped
   configuration is 1024x1024 via the ops/attention.py dispatch wrapper
   (`tuning_tables/v5e.json`), with automatic step-down for short sequences; interpret
-  mode keeps CPU tests exact. What the chip says of each choice is in PERF.md,
-  sections 5 and 6.
+  mode keeps CPU tests exact. What the chip says of each choice, and of the fused
+  backward beside the two kernels (`scripts/moe_mla_parts_bench.py --parts flash`), is in
+  PERF.md, sections 5 and 6.
 - TPU layout: per-row statistics (lse, delta) carry a trailing singleton lane dim
   ([B, H, S, 1] arrays, [block_q, 1] in-kernel tiles) because Mosaic requires the
   last two block dims to tile (8, 128) or equal the array dims — a bare [S] row
-  vector does not lower (the official jax kernel lane-broadcasts to 128 instead;
-  the singleton costs 128x less HBM for identical in-kernel code).
+  vector does not lower (the official jax kernel lane-broadcasts to 128 instead; the
+  singleton was meant to cost 128x less HBM, but the chip's tiled layout gives every
+  row a lane tile all the same: 256 MiB each at 2 x 32 x 8192, PERF.md section 7).
 """
 
 from __future__ import annotations
@@ -63,7 +74,7 @@ class TilePlan(NamedTuple):
     """Which score tiles a call computes, in the order its kernels walk them.
 
     `q_major` and `kv_major` are int32 [3, n] tables (q tile, kv tile, flags) over the
-    same n pairs: kv innermost for `fwd` and `bwd_dq`, q innermost for `bwd_dkv`.
+    same n pairs: kv innermost for `fwd` and `bwd_dq`, q innermost for `bwd` and `bwd_dkv`.
     Flags: _FIRST / _LAST pair of its row in that order (init / finish fire there),
     _MASKED (the diagonal crosses the tile: whole-tile mask), _DIAGONAL (the tile is
     square and sits on the diagonal: only its lower triangle is walked)."""
@@ -268,6 +279,26 @@ def _bwd_dq_kernel(plan_ref, q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref, dq
 # -------------------------------------------------------------------- bwd: dkdv
 
 
+def _add_dkv_terms(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref, dk_acc_ref, dv_acc_ref, rows, cols, mask_offset, sm_scale):
+    """A rectangle's p and ds from the saved logsumexp, its terms added into the dk and dv
+    accumulators; (ds, k) go back to the kernel that also forms ds k."""
+    k = k_ref[0, 0, cols, :].astype(jnp.float32)
+    v = v_ref[0, 0, cols, :].astype(jnp.float32)
+    q = q_ref[0, 0, rows, :].astype(jnp.float32)
+    do = do_ref[0, 0, rows, :].astype(jnp.float32)
+    lse = lse_ref[0, 0, rows, :]  # [R, 1]
+    delta = delta_ref[0, 0, rows, :]  # [R, 1]
+    p = jnp.exp(_masked_scores(q * sm_scale, k, mask_offset) - lse)
+    dv_acc_ref[cols, :] += jax.lax.dot_general(
+        p, do, (((0,), (0,)), ((), ())), preferred_element_type=jnp.float32
+    )
+    ds = p * (_scores(do, v) - delta) * sm_scale
+    dk_acc_ref[cols, :] += jax.lax.dot_general(
+        ds, q, (((0,), (0,)), ((), ())), preferred_element_type=jnp.float32
+    )
+    return ds, k
+
+
 def _bwd_dkv_kernel(plan_ref, q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref, dk_ref, dv_ref,
                     dk_acc_ref, dv_acc_ref, *, sm_scale, block_q, block_k, num_pairs, classes):
     flags, offset = _pair(plan_ref, num_pairs, block_q, block_k)
@@ -278,20 +309,7 @@ def _bwd_dkv_kernel(plan_ref, q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref, d
         dv_acc_ref[:] = jnp.zeros_like(dv_acc_ref)
 
     def rectangle(rows, cols, mask_offset):
-        k = k_ref[0, 0, cols, :].astype(jnp.float32)
-        v = v_ref[0, 0, cols, :].astype(jnp.float32)
-        q = q_ref[0, 0, rows, :].astype(jnp.float32)
-        do = do_ref[0, 0, rows, :].astype(jnp.float32)
-        lse = lse_ref[0, 0, rows, :]  # [R, 1]
-        delta = delta_ref[0, 0, rows, :]  # [R, 1]
-        p = jnp.exp(_masked_scores(q * sm_scale, k, mask_offset) - lse)
-        dv_acc_ref[cols, :] += jax.lax.dot_general(
-            p, do, (((0,), (0,)), ((), ())), preferred_element_type=jnp.float32
-        )
-        ds = p * (_scores(do, v) - delta) * sm_scale
-        dk_acc_ref[cols, :] += jax.lax.dot_general(
-            ds, q, (((0,), (0,)), ((), ())), preferred_element_type=jnp.float32
-        )
+        _add_dkv_terms(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref, dk_acc_ref, dv_acc_ref, rows, cols, mask_offset, sm_scale)
 
     _by_class(classes, flags, offset, block_q, block_k, rectangle)
 
@@ -301,16 +319,106 @@ def _bwd_dkv_kernel(plan_ref, q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref, d
         dv_ref[0, 0] = dv_acc_ref[:].astype(dv_ref.dtype)
 
 
+# ----------------------------------------- bwd: dq, dk and dv from one pass (PR 31)
+
+# What the fused backward may plan to hold in VMEM (a v5e core has 128 MiB; Mosaic's default
+# scope is 16). A row whose resident dq does not fit beside the tiles takes the two kernels.
+FUSED_BWD_VMEM_BUDGET = 48 * 2**20
+_LANES = 128
+
+
+def _lane_padded(width: int) -> int:
+    return -(-width // _LANES) * _LANES
+
+
+def dq_resident_bytes(seq_q: int, head_dim: int, itemsize: int) -> int:
+    """Bytes of one (batch, q head)'s dq that stay in VMEM while the fused backward walks its
+    pairs: the float32 accumulator and the output block in q's dtype, which the pipeline
+    holds twice. Lanes padded to 128: 192 takes the room of 256."""
+    return seq_q * _lane_padded(head_dim) * (4 + 2 * itemsize)
+
+
+def fused_backward_vmem_bytes(seq_q: int, block_q: int, block_k: int, head_dim: int, head_dim_v: int,
+                              itemsize: int) -> int:
+    """What `flash_attention_bwd` holds in VMEM for one grid step, in bytes, lanes padded: the
+    resident dq; the tiles of q, do, k, v, dk, dv and the two [block_q, 1] columns (a lane tile
+    a row), each twice (the pipeline's two buffers); the dk and dv accumulators; and a
+    rectangle's float32 temporaries, taken as four score tiles (s and p, dp and ds, the
+    transposes of p and ds) and one copy of each operand tile. Counted from the shapes, not
+    asked of the compiler, and on the high side of what Mosaic allots (PERF.md, section 6, PR 31)."""
+    wide, narrow = _lane_padded(head_dim), _lane_padded(head_dim_v)
+    q_side, kv_side = block_q * (wide + narrow), block_k * (wide + narrow)  # elements of q and do; of k and v, of dk and dv
+    pipelined = 2 * (itemsize * (q_side + 2 * kv_side) + 2 * 4 * block_q * _LANES)
+    accumulators = 4 * kv_side
+    temporaries = 4 * (4 * block_q * block_k + q_side + kv_side)
+    return dq_resident_bytes(seq_q, head_dim, itemsize) + pipelined + accumulators + temporaries
+
+
+def backward_plan(seq_q: int, block_q: int, block_k: int, head_dim: int, head_dim_v: int, dtype) -> dict:
+    """Which backward a differentiated call of these shapes runs, chosen from the shapes alone:
+    the fused kernel wherever its counted VMEM need is within `FUSED_BWD_VMEM_BUDGET`, else
+    `bwd_dq` and `bwd_dkv` as before PR 31 (at the forward's blocks). `block_q` x `block_k` are
+    the fused kernel's own (`env_flash_blocks(backward=True)`). The fields `flash_tile_plan` reports."""
+    itemsize = jnp.dtype(dtype).itemsize
+    need = fused_backward_vmem_bytes(seq_q, block_q, block_k, head_dim, head_dim_v, itemsize)
+    return {
+        "backward": "fused" if need <= FUSED_BWD_VMEM_BUDGET else "two_kernels",
+        "backward_block_q": block_q, "backward_block_k": block_k,
+        "dq_resident_bytes": dq_resident_bytes(seq_q, head_dim, itemsize),
+        "backward_vmem_bytes": need,
+    }
+
+
+def _bwd_kernel(plan_ref, q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref, dq_ref, dk_ref, dv_ref,
+                dq_acc_ref, dk_acc_ref, dv_acc_ref, *, sm_scale, block_q, block_k, num_pairs, classes):
+    """The plan's kv-major walk, once: p and ds of a rectangle feed dv, dk (accumulated over
+    the kv tile's q tiles, as `_bwd_dkv_kernel` does) and dq, whose float32 rows of the whole
+    (batch, q head) stay in `dq_acc_ref` [q tiles, block_q, D] from the head's first pair to
+    its last: a q tile's terms arrive in rising kv order, the order `_bwd_dq_kernel` adds them in."""
+    flags, offset = _pair(plan_ref, num_pairs, block_q, block_k)
+    t = pl.program_id(2)
+    q_tile = plan_ref[t]
+
+    @pl.when(t == 0)
+    def _init_dq():
+        dq_acc_ref[:] = jnp.zeros_like(dq_acc_ref)
+
+    @pl.when(flags & _FIRST != 0)
+    def _init():
+        dk_acc_ref[:] = jnp.zeros_like(dk_acc_ref)
+        dv_acc_ref[:] = jnp.zeros_like(dv_acc_ref)
+
+    def rectangle(rows, cols, mask_offset):
+        ds, k = _add_dkv_terms(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref, dk_acc_ref, dv_acc_ref, rows, cols, mask_offset, sm_scale)
+        dq_acc_ref[q_tile, rows, :] += jax.lax.dot_general(
+            ds, k, (((1,), (0,)), ((), ())), preferred_element_type=jnp.float32
+        )
+
+    _by_class(classes, flags, offset, block_q, block_k, rectangle)
+
+    @pl.when(flags & _LAST != 0)
+    def _finish():
+        dk_ref[0, 0] = dk_acc_ref[:].astype(dk_ref.dtype)
+        dv_ref[0, 0] = dv_acc_ref[:].astype(dv_ref.dtype)
+
+    @pl.when(t == num_pairs - 1)
+    def _finish_dq():
+        for i in range(dq_acc_ref.shape[0]):
+            dq_ref[0, 0, pl.ds(i * block_q, block_q), :] = dq_acc_ref[i].astype(dq_ref.dtype)
+
+
 def _tiled_call(kernel, table, name, *, sm_scale, batch, num_heads, group, block_q, block_k, head_dim, head_dim_v,
-                inputs, outputs, out_shape, scratch_shapes, interpret):
+                inputs, outputs, out_shape, scratch_shapes, interpret, vmem_limit_bytes=None, aliases=None):
     """One `pallas_call` of `kernel` over grid (batch, head, pair), each pair's tiles
     looked up in `table` (a plan's int32 [3, n]: q tile, kv tile, flags), which goes in
     flat as the one scalar-prefetched operand. `inputs` / `outputs` name each operand's
     tiling: "q" (a [block_q, D] tile of q head h), "kv" (a [block_k, D] tile of kv head
-    h // group), "k_out" (a [block_k, D] tile per q head), "row" (a [block_q, 1] column).
+    h // group), "k_out" (a [block_k, D] tile per q head), "row" (a [block_q, 1] column),
+    "q_rows" (all the plan's q tiles of q head h: a block that stays while the pairs go by).
     q and k are `head_dim` wide; what is as wide as v (`head_dim_v`: v, the output and its
     cotangent, dv) is tiled alike under "qv", "v" and "v_out". Equal widths give the same
-    blocks under both names: the program is the one it was before there were two."""
+    blocks under both names: the program is the one it was before there were two.
+    `aliases` {input: output} (positions in `inputs` / `outputs`) lets an output take its input's buffer."""
     n = table.shape[1]
 
     def tilings(width):
@@ -322,6 +430,7 @@ def _tiled_call(kernel, table, name, *, sm_scale, batch, num_heads, group, block
 
     specs = dict(zip(("q", "kv", "k_out"), tilings(head_dim)), **dict(zip(("qv", "v", "v_out"), tilings(head_dim_v))))
     specs["row"] = pl.BlockSpec((1, 1, block_q, 1), lambda b, h, t, plan: (b, h, plan[t], 0))
+    specs["q_rows"] = pl.BlockSpec((1, 1, (int(table[0].max()) + 1) * block_q, head_dim), lambda b, h, t, plan: (b, h, 0, 0))
     classes = np.unique(table[2] & (_MASKED | _DIAGONAL)).tolist()
     call = pl.pallas_call(
         functools.partial(kernel, sm_scale=sm_scale, block_q=block_q, block_k=block_k, num_pairs=n, classes=classes),
@@ -333,7 +442,9 @@ def _tiled_call(kernel, table, name, *, sm_scale, batch, num_heads, group, block
             scratch_shapes=scratch_shapes,
         ),
         out_shape=out_shape,
-        compiler_params=pltpu.CompilerParams(dimension_semantics=("parallel", "parallel", "arbitrary")),
+        input_output_aliases={1 + i: o for i, o in (aliases or {}).items()},  # the plan's table is operand 0
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("parallel", "parallel", "arbitrary"), vmem_limit_bytes=vmem_limit_bytes),
         interpret=interpret,
         name=name,
     )
@@ -353,7 +464,7 @@ def _pick_block(seq: int, preferred: int) -> int:
 
 
 def env_flash_blocks(seq_q: int, seq_k: int, dtype="bfloat16", head_dim: int | None = None,
-                     head_dim_v: int | None = None) -> tuple[int, int]:
+                     head_dim_v: int | None = None, backward: bool = False) -> tuple[int, int]:
     """The (block_q, block_k) tuning knobs, shared by every kernel consumer
     (ops/attention.py dispatch, the ring tier). Precedence per knob:
     MODALITIES_TPU_FLASH_BLOCK_Q/_K env override > the per-device autotune table
@@ -365,7 +476,12 @@ def env_flash_blocks(seq_q: int, seq_k: int, dtype="bfloat16", head_dim: int | N
     The table's bucket is the two sequence lengths; where v is not as wide as q and k
     (latent attention) it is the two widths instead (`d192_dv128`), whatever the sequence:
     what fits VMEM depends on the blocks and the widths alone, and at 192/128 `bwd_dq`
-    asks 17.27 MiB of the 16 at 1024 x 1024, at 2 x 8192 and at 4 x 4096 alike."""
+    asks 17.27 MiB of the 16 at 1024 x 1024, at 2 x 8192 and at 4 x 4096 alike.
+
+    `backward=True` asks for the fused backward's blocks: the table's `flash_attention_bwd`
+    entry of the same bucket where it has one (192/128 on a v5e: 1024 x 1024, which that
+    kernel's own VMEM limit holds and which read 25.90 ms a layer against 28.75 at the
+    forward's 1024 x 512; PERF.md section 6, PR 31), else the forward's blocks."""
     import os
 
     env_q = os.environ.get("MODALITIES_TPU_FLASH_BLOCK_Q")
@@ -378,7 +494,8 @@ def env_flash_blocks(seq_q: int, seq_k: int, dtype="bfloat16", head_dim: int | N
         bucket = f"sq{autotune.shape_bucket(seq_q)}_sk{autotune.shape_bucket(seq_k)}"
         if head_dim is not None and head_dim_v is not None and head_dim != head_dim_v:
             bucket = f"d{head_dim}_dv{head_dim_v}"
-        hit = autotune.lookup("flash_attention", bucket, jnp.dtype(dtype).name)
+        dtype_name = jnp.dtype(dtype).name
+        hit = (backward and autotune.lookup("flash_attention_bwd", bucket, dtype_name)) or autotune.lookup("flash_attention", bucket, dtype_name)
         if hit:
             block_q = block_q if block_q is not None else int(hit.get("block_q", 1024))
             block_k = block_k if block_k is not None else int(hit.get("block_k", 1024))
@@ -389,8 +506,8 @@ def env_flash_blocks(seq_q: int, seq_k: int, dtype="bfloat16", head_dim: int | N
     return _pick_block(seq_q, block_q), _pick_block(seq_k, block_k)
 
 
-@functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4, 5, 6, 7))
-def _flash_attention_bhsd(q, k, v, sm_scale, causal, block_q, block_k, interpret):
+@functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4, 5, 6, 7, 8))
+def _flash_attention_bhsd(q, k, v, sm_scale, causal, block_q, block_k, bwd_blocks, interpret):
     out, _ = _flash_fwd(q, k, v, sm_scale, causal, block_q, block_k, interpret)
     return out
 
@@ -432,7 +549,7 @@ def flash_fwd_out_lse(q, k, v, *, causal, sm_scale, block_q, block_k, interpret)
     return out, lse
 
 
-def _flash_fwd_vjp(q, k, v, sm_scale, causal, block_q, block_k, interpret):
+def _flash_fwd_vjp(q, k, v, sm_scale, causal, block_q, block_k, bwd_blocks, interpret):
     # custom_vjp fwd receives arguments in the primal order (nondiff included in place)
     out, res = _flash_fwd(q, k, v, sm_scale, causal, block_q, block_k, interpret)
     return out, res
@@ -483,21 +600,60 @@ def flash_bwd_dkv(q, k, v, do, lse, delta, *, causal, sm_scale, block_q, block_k
         interpret=interpret,
     )(q, k, v, do, lse, delta)
 
+    return _group_sum(dk_h, dv_h, k, v)
+
+
+def _group_sum(dk_h, dv_h, k, v):
+    """dk and dv as the kernels write them, per q head, summed over the GQA group down to the kv heads."""
+    batch, num_kv_heads, seq_k = k.shape[:3]
+    group = dk_h.shape[1] // num_kv_heads
     if group > 1:
-        dk = dk_h.reshape(batch, num_kv_heads, group, seq_k, head_dim).sum(axis=2)
-        dv = dv_h.reshape(batch, num_kv_heads, group, seq_k, head_dim_v).sum(axis=2)
-    else:
-        dk, dv = dk_h, dv_h
-    return dk.astype(k.dtype), dv.astype(v.dtype)
+        dk_h = dk_h.reshape(batch, num_kv_heads, group, seq_k, k.shape[3]).sum(axis=2)
+        dv_h = dv_h.reshape(batch, num_kv_heads, group, seq_k, v.shape[3]).sum(axis=2)
+    return dk_h.astype(k.dtype), dv_h.astype(v.dtype)
 
 
-def _flash_bwd_vjp(sm_scale, causal, block_q, block_k, interpret, res, do):
+def flash_bwd(q, k, v, do, lse, delta, *, causal, sm_scale, block_q, block_k, interpret):
+    """(dq, dk, dv) from one kernel, `flash_attention_bwd`: one walk of the plan's kv-major
+    table, p and ds evaluated once a tile (5 products for the 7 of `flash_bwd_dq` +
+    `flash_bwd_dkv`, whose sums it repeats in their order). The caller has checked
+    `backward_plan`: a (batch, q head)'s whole dq row lives in VMEM."""
+    batch, num_heads, seq_q, head_dim = q.shape
+    num_kv_heads, seq_k, head_dim_v = k.shape[1], k.shape[2], v.shape[3]
+
+    dq, dk_h, dv_h = _tiled_call(
+        _bwd_kernel, tile_plan(seq_q, seq_k, block_q, block_k, causal).kv_major, "flash_attention_bwd",
+        sm_scale=sm_scale, batch=batch, num_heads=num_heads, group=num_heads // num_kv_heads,
+        block_q=block_q, block_k=block_k, head_dim=head_dim, head_dim_v=head_dim_v,
+        inputs=("q", "kv", "v", "qv", "row", "row"), outputs=("q_rows", "k_out", "v_out"),
+        out_shape=[
+            jax.ShapeDtypeStruct(q.shape, q.dtype),
+            jax.ShapeDtypeStruct((batch, num_heads, seq_k, head_dim), q.dtype),
+            jax.ShapeDtypeStruct((batch, num_heads, seq_k, head_dim_v), q.dtype),
+        ],
+        scratch_shapes=[
+            pltpu.VMEM((seq_q // block_q, block_q, head_dim), jnp.float32),
+            pltpu.VMEM((block_k, head_dim), jnp.float32),
+            pltpu.VMEM((block_k, head_dim_v), jnp.float32),
+        ],
+        interpret=interpret, vmem_limit_bytes=FUSED_BWD_VMEM_BUDGET + 8 * 2**20,
+        # dq is written where q was read (a head's row at its last pair, when its every tile has been read): three
+        # results at once would else hold 192 MiB more of the expert cell's step than dq, then dk and dv, did
+        aliases={0: 0},
+    )(q, k, v, do, lse, delta)
+    return dq, *_group_sum(dk_h, dv_h, k, v)
+
+
+def _flash_bwd_vjp(sm_scale, causal, block_q, block_k, bwd_blocks, interpret, res, do):
     q, k, v, out, lse = res
     # [B, H, Sq, 1] — trailing singleton lane dim (see module docstring)
     delta = jnp.sum(do.astype(jnp.float32) * out.astype(jnp.float32), axis=-1, keepdims=True)
-    kw = dict(causal=causal, sm_scale=sm_scale, block_q=block_q, block_k=block_k, interpret=interpret)
-    dq = flash_bwd_dq(q, k, v, do, lse, delta, **kw)
-    dk, dv = flash_bwd_dkv(q, k, v, do, lse, delta, **kw)
+    kw = dict(causal=causal, sm_scale=sm_scale, interpret=interpret)
+    if backward_plan(q.shape[2], *bwd_blocks, q.shape[3], v.shape[3], q.dtype)["backward"] == "fused":
+        return flash_bwd(q, k, v, do, lse, delta, block_q=bwd_blocks[0], block_k=bwd_blocks[1], **kw)
+    # the two kernels at the forward's blocks: what such a row ran before PR 31
+    dq = flash_bwd_dq(q, k, v, do, lse, delta, block_q=block_q, block_k=block_k, **kw)
+    dk, dv = flash_bwd_dkv(q, k, v, do, lse, delta, block_q=block_q, block_k=block_k, **kw)
     return dq, dk, dv
 
 
@@ -507,17 +663,20 @@ _flash_attention_bhsd.defvjp(_flash_fwd_vjp, _flash_bwd_vjp)
 def pallas_flash_attention(
     q, k, v, causal: bool = True, sm_scale: float | None = None,
     block_q: int = 128, block_k: int = 128, interpret: bool = False,
+    bwd_blocks: tuple[int, int] | None = None,
 ):
     """Public entry. q: [B, S, Hq, D], k: [B, S, Hkv, D], v: [B, S, Hkv, Dv] (model layout)
     -> [B, S, Hq, Dv]. Dv may differ from D (latent attention: 192 for q and k, 128 for v);
-    the default scale is that of D."""
+    the default scale is that of D. `bwd_blocks`: (block_q, block_k) of the fused backward
+    where the tuning table gives it its own; the forward's otherwise."""
     if sm_scale is None:
         sm_scale = 1.0 / math.sqrt(q.shape[-1])
     seq_q, seq_k = q.shape[1], k.shape[1]
     block_q = _pick_block(seq_q, block_q)
     block_k = _pick_block(seq_k, block_k)
+    bwd_blocks = (block_q, block_k) if bwd_blocks is None else (_pick_block(seq_q, bwd_blocks[0]), _pick_block(seq_k, bwd_blocks[1]))
     qt = q.transpose(0, 2, 1, 3)
     kt = k.transpose(0, 2, 1, 3)
     vt = v.transpose(0, 2, 1, 3)
-    out = _flash_attention_bhsd(qt, kt, vt, sm_scale, causal, block_q, block_k, interpret)
+    out = _flash_attention_bhsd(qt, kt, vt, sm_scale, causal, block_q, block_k, bwd_blocks, interpret)
     return out.transpose(0, 2, 1, 3)

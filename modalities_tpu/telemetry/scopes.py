@@ -64,7 +64,8 @@ puts `run_<i>` before `blocks/block` (one scan a run of equal layers), and the s
 mixer's projections are `ssm/{in_proj,x_proj,dt_proj,out_proj}` with `ssm/{dt_norm,b_norm,c_norm}`;
 latent attention's are `attn/{q_proj,kv_a_proj,kv_a_norm,kv_b_proj,c_proj}`, the expert layer's
 `moe/router`, `moe/experts`, `moe/shared/{W,V,W_2}`. Kernels keep the `name=` of their Pallas call:
-`flash_attention_{fwd,bwd_dq,bwd_dkv}`, `fused_ce_{fwd,bwd_dw}` (`fused_ce_eval` where nobody differentiates the call),
+`flash_attention_{fwd,bwd}` (`flash_attention_bwd_{dq,dkv}` in ring attention's backward and where a row's dq does not
+fit VMEM: `ops/pallas/flash_attention.backward_plan`), `fused_ce_{fwd,bwd_dw}` (`fused_ce_eval` where nobody differentiates the call),
 `fused_rmsnorm_{fwd,bwd}`, `selective_scan_{fwd,bwd}` (the recurrence on a TPU, under
 `ssm/scan`); the instruction of a call is named by it, and metrics select by that name.
 

@@ -2,8 +2,11 @@
 the cell `train-kanana2-30b-8k` (`benchmark/configs/kanana2-30b-a3b-d9`): the builder's
 tool for the per-part prices PERF.md quotes, not a cell: nothing in `benchmark/` reads it.
 
-- `flash`: the three flash kernels, forward with backward, at 2 x 8192 x 32 heads of 192
-  (q, k) / 128 (v) for each `--blocks` pair, beside 128 / 128 at the table's 1024 x 1024.
+- `flash`: the flash kernels, forward with backward, at 2 x 8192 x 32 heads of 192
+  (q, k) / 128 (v) for each `--blocks` pair, beside 128 / 128 at the table's 1024 x 1024;
+  and for each, the backward alone in both its forms whatever the shape rule would pick
+  (`ops/pallas/flash_attention.backward_plan`): `flash_bwd_fused_*` is `flash_attention_bwd`
+  (PR 31), `flash_bwd_two_kernels_*` is `flash_attention_bwd_dq` + `_bwd_dkv`.
 - `moe`: one expert layer's routed part (`ops/expert_dispatch.py`: plan, gathers, grouped
   products, add back by token), forward with backward, at 16,384 tokens of width 2048
   routed 6 of 128 with the experts `0 .. held - 1` of 768 held; loads uniform, and for
@@ -20,6 +23,7 @@ CPU smoke:   JAX_PLATFORMS=cpu python scripts/moe_mla_parts_bench.py --smoke
 from __future__ import annotations
 
 import argparse
+import functools
 import json
 import os
 import sys
@@ -62,7 +66,11 @@ def flash_part(args, interpret: bool) -> None:
     import jax
     import jax.numpy as jnp
 
-    from modalities_tpu.ops.pallas.flash_attention import pallas_flash_attention
+    from modalities_tpu.ops.pallas.flash_attention import (
+        flash_bwd, flash_bwd_dkv, flash_bwd_dq, flash_fwd_out_lse, pallas_flash_attention)
+
+    def two_kernels(*values, **kw):
+        return flash_bwd_dq(*values, **kw), *flash_bwd_dkv(*values, **kw)
 
     batch, seq, heads = (1, 256, 2) if args.smoke else (2, 8192, 32)
     rng = np.random.default_rng(0)
@@ -76,11 +84,21 @@ def flash_part(args, interpret: bool) -> None:
             out = pallas_flash_attention(q, k, v, block_q=bq, block_k=bk, interpret=interpret)
             return jnp.sum(out.astype(jnp.float32) * w.astype(jnp.float32))
 
-        try:
-            timed(f"flash_d{d}_dv{dv}_{bq}x{bk}", jax.jit(jax.grad(loss, argnums=(0, 1, 2))), (q, k, v), args.iters, args.trace,
-                  head_dim=d, head_dim_v=dv, block_q=bq, block_k=bk)
-        except Exception as e:  # what Mosaic refuses (VMEM) is a reading too
-            print("[parts] " + json.dumps({"part": f"flash_d{d}_dv{dv}_{bq}x{bk}", "refused": str(e)[-300:]}), flush=True)
+        # the backward's operands as the custom_vjp hands them over: [B, H, S, D], lse from a forward at blocks that always fit
+        qt, kt, vt, wt = (x.transpose(0, 2, 1, 3) for x in (q, k, v, w))
+        kw = dict(causal=True, sm_scale=d**-0.5, interpret=interpret)
+        out, lse = jax.jit(functools.partial(flash_fwd_out_lse, block_q=min(bq, 512), block_k=min(bk, 512), **kw))(qt, kt, vt)
+        delta = jnp.sum(wt.astype(jnp.float32) * out.astype(jnp.float32), axis=-1, keepdims=True)
+        programs = {
+            f"flash_d{d}_dv{dv}_{bq}x{bk}": (jax.jit(jax.grad(loss, argnums=(0, 1, 2))), (q, k, v)),
+            **{f"flash_bwd_{form}_d{d}_dv{dv}_{bq}x{bk}": (jax.jit(functools.partial(fn, block_q=bq, block_k=bk, **kw)), (qt, kt, vt, wt, lse, delta))
+               for form, fn in (("fused", flash_bwd), ("two_kernels", two_kernels))},
+        }
+        for name, (fn, values) in programs.items():
+            try:
+                timed(name, fn, values, args.iters, args.trace, head_dim=d, head_dim_v=dv, block_q=bq, block_k=bk)
+            except Exception as e:  # what Mosaic refuses (VMEM) is a reading too
+                print("[parts] " + json.dumps({"part": name, "refused": str(e)[-300:]}), flush=True)
 
 
 def moe_part(args) -> None:
@@ -121,7 +139,7 @@ def moe_part(args) -> None:
 def main() -> None:
     p = argparse.ArgumentParser()
     p.add_argument("--parts", default="flash,moe")
-    p.add_argument("--blocks", default="512x1024,1024x512,512x512", help="block_q x block_k pairs tried at 192/128")
+    p.add_argument("--blocks", default="512x1024,1024x512,512x512,1024x1024", help="block_q x block_k pairs tried at 192/128")
     p.add_argument("--held", type=int, default=16)
     p.add_argument("--iters", type=int, default=5)
     p.add_argument("--smoke", action="store_true", help="tiny shapes, Pallas in interpret mode (CPU)")

@@ -140,35 +140,52 @@ def _oracle(q, k, v, causal):
 
 
 KERNEL_CASES = {
-    # (seq_q, seq_k, block_q, block_k, causal, head_dim): at least three tiles a side, GQA 4:1
-    "diagonal_walked_in_sub_blocks_d40": (96, 96, 32, 32, True, 40),
-    "diagonal_walked_in_sub_blocks_d80": (96, 96, 32, 32, True, 80),
-    "not_causal_d40": (96, 96, 32, 32, False, 40),
-    "whole_tile_mask_bq_over_bk_d40": (96, 96, 32, 16, True, 40),
-    "whole_tile_mask_bk_over_bq_d40": (96, 96, 16, 32, True, 40),
-    "more_k_than_q_d40": (96, 192, 32, 32, True, 40),
-    "more_q_than_k_not_causal_d80": (192, 96, 32, 32, False, 80),
+    # (seq_q, seq_k, block_q, block_k, causal, head_dim, kv heads of 4 q heads): at least three tiles a side
+    "diagonal_walked_in_sub_blocks_d40": (96, 96, 32, 32, True, 40, 1),
+    "diagonal_walked_in_sub_blocks_d80": (96, 96, 32, 32, True, 80, 1),
+    "diagonal_walked_in_sub_blocks_d40_group_2": (96, 96, 32, 32, True, 40, 2),
+    "not_causal_d40": (96, 96, 32, 32, False, 40, 1),
+    "whole_tile_mask_bq_over_bk_d40": (96, 96, 32, 16, True, 40, 1),
+    "whole_tile_mask_bk_over_bq_d40": (96, 96, 16, 32, True, 40, 1),
+    "whole_tile_mask_bq_over_bk_d40_group_1": (96, 96, 32, 16, True, 40, 4),
+    "more_k_than_q_d40": (96, 192, 32, 32, True, 40, 1),
+    "more_q_than_k_not_causal_d80": (192, 96, 32, 32, False, 80, 1),
 }
+BACKWARDS = ("fused", "two_kernels")  # PR 31: one kernel for dq, dk and dv; the two it replaced where its dq row does not fit
 
 
+def _grads_under(backward, monkeypatch, loss, q, k, v, block_q, block_k):
+    """The gradients of `loss` with the backward rule brought to choose `backward` for these shapes, as it would by size."""
+    with monkeypatch.context() as patched:
+        if backward == "two_kernels":
+            patched.setattr(flash, "FUSED_BWD_VMEM_BUDGET", 0)
+        assert flash.backward_plan(q.shape[1], block_q, block_k, q.shape[-1], v.shape[-1], q.dtype)["backward"] == backward
+        return jax.grad(loss, argnums=(0, 1, 2))(q, k, v)
+
+
+@pytest.mark.parametrize("backward", BACKWARDS)
 @pytest.mark.parametrize("case", sorted(KERNEL_CASES))
-def test_values_and_gradients_match_oracle_in_every_tile_class(case, monkeypatch):
-    seq_q, seq_k, block_q, block_k, causal, head_dim = KERNEL_CASES[case]
+def test_values_and_gradients_match_oracle_in_every_tile_class(case, backward, monkeypatch):
+    seq_q, seq_k, block_q, block_k, causal, head_dim, kv_heads = KERNEL_CASES[case]
     monkeypatch.setattr(flash, "_DIAG_SUB_BLOCK", 8)  # four squares along a 32-wide diagonal tile
     rng = jax.random.PRNGKey(11)
     q = jax.random.normal(jax.random.fold_in(rng, 0), (2, seq_q, 4, head_dim))
-    k = jax.random.normal(jax.random.fold_in(rng, 1), (2, seq_k, 1, head_dim))
-    v = jax.random.normal(jax.random.fold_in(rng, 2), (2, seq_k, 1, head_dim))
+    k = jax.random.normal(jax.random.fold_in(rng, 1), (2, seq_k, kv_heads, head_dim))
+    v = jax.random.normal(jax.random.fold_in(rng, 2), (2, seq_k, kv_heads, head_dim))
     w = jax.random.normal(jax.random.fold_in(rng, 3), q.shape)
     kernel = functools.partial(
         pallas_flash_attention, causal=causal, block_q=block_q, block_k=block_k, interpret=True
     )
     reference = manual_attention if causal and seq_q == seq_k else functools.partial(_oracle, causal=causal)
     np.testing.assert_allclose(np.asarray(kernel(q, k, v)), np.asarray(reference(q, k, v)), rtol=2e-5, atol=2e-5)
-    got = jax.grad(lambda q, k, v: (kernel(q, k, v) * w).sum(), argnums=(0, 1, 2))(q, k, v)
+    loss = lambda q, k, v: (kernel(q, k, v) * w).sum()  # noqa: E731
+    got = _grads_under(backward, monkeypatch, loss, q, k, v, block_q, block_k)
     want = jax.grad(lambda q, k, v: (reference(q, k, v) * w).sum(), argnums=(0, 1, 2))(q, k, v)
     for g, e, name in zip(got, want, "qkv"):
         np.testing.assert_allclose(np.asarray(g), np.asarray(e), rtol=5e-4, atol=5e-4, err_msg=f"d{name}")
+    if backward == "fused":  # the same sums in the same order as the two kernels: not close, equal
+        for g, e, name in zip(got, _grads_under("two_kernels", monkeypatch, loss, q, k, v, block_q, block_k), "qkv"):
+            np.testing.assert_array_equal(np.asarray(g), np.asarray(e), err_msg=f"d{name}")
 
 
 # ------------------------------------------- two head sizes: q and k wider than v (latent attention)
@@ -183,8 +200,9 @@ TWO_WIDTH_CASES = {
 }
 
 
+@pytest.mark.parametrize("backward", BACKWARDS)
 @pytest.mark.parametrize("case", sorted(TWO_WIDTH_CASES))
-def test_two_head_sizes_match_manual_attention(case, monkeypatch):
+def test_two_head_sizes_match_manual_attention(case, backward, monkeypatch):
     """v, the output, its cotangent and dv at one width, q, k, dq and dk at another; the scale is that of q's."""
     seq, block_q, block_k, heads, d, dv = TWO_WIDTH_CASES[case]
     monkeypatch.setattr(flash, "_DIAG_SUB_BLOCK", 8)
@@ -197,21 +215,95 @@ def test_two_head_sizes_match_manual_attention(case, monkeypatch):
     out = kernel(q, k, v)
     assert out.shape == (2, seq, heads, dv)
     np.testing.assert_allclose(np.asarray(out), np.asarray(manual_attention(q, k, v)), rtol=2e-5, atol=2e-5)
-    got = jax.grad(lambda q, k, v: (kernel(q, k, v) * w).sum(), argnums=(0, 1, 2))(q, k, v)
+    loss = lambda q, k, v: (kernel(q, k, v) * w).sum()  # noqa: E731
+    got = _grads_under(backward, monkeypatch, loss, q, k, v, block_q, block_k)
     want = jax.grad(lambda q, k, v: (manual_attention(q, k, v) * w).sum(), argnums=(0, 1, 2))(q, k, v)
     assert [g.shape[-1] for g in got] == [d, d, dv]
     for g, e, name in zip(got, want, "qkv"):
         np.testing.assert_allclose(np.asarray(g), np.asarray(e), rtol=5e-4, atol=5e-4, err_msg=f"d{name}")
+    if backward == "fused":
+        for g, e, name in zip(got, _grads_under("two_kernels", monkeypatch, loss, q, k, v, block_q, block_k), "qkv"):
+            np.testing.assert_array_equal(np.asarray(g), np.asarray(e), err_msg=f"d{name}")
+
+
+def _kernels(fn, *args):
+    """(`name=`, grid) of every Pallas call in the traced program, in order."""
+    found = []
+
+    def walk(jaxpr):
+        for eqn in jaxpr.eqns:
+            if eqn.primitive.name == "pallas_call":
+                found.append((eqn.params["name"], tuple(eqn.params["grid_mapping"].grid)))
+            for sub in jax.core.jaxprs_in_params(eqn.params):
+                walk(sub)
+
+    walk(jax.make_jaxpr(fn)(*args).jaxpr)
+    return found
+
+
+def _kernel_names(fn, *args):
+    return [name for name, _ in _kernels(fn, *args)]
+
+
+def test_a_differentiated_call_holds_the_fused_backward_and_neither_kernel_it_replaced():
+    """PR 31: the backward rule calls one kernel, `flash_attention_bwd`, wherever a (batch, q head)'s dq row fits VMEM;
+    `flash_attention_bwd_dq` and `_bwd_dkv` are in no program a model's step traces at such a shape."""
+    q, k, v = _rand_qkv(5, 1, 64, 2, 1, 32)
+    loss = lambda q, k, v: pallas_flash_attention(q, k, v, causal=True, block_q=16, block_k=16, interpret=True).sum()  # noqa: E731
+    assert _kernel_names(jax.grad(loss, argnums=(0, 1, 2)), q, k, v) == ["flash_attention_fwd", "flash_attention_bwd"]
+    assert _kernel_names(loss, q, k, v) == ["flash_attention_fwd"]
+
+
+def test_the_shape_rule_takes_the_two_kernels_where_the_dq_row_does_not_fit(monkeypatch):
+    """The choice is by the counted VMEM need against a module constant, nothing else: a row too long for the budget
+    (here the budget shrunk under a short row's need) runs `bwd_dq` and `bwd_dkv` as before PR 31, to the same gradients."""
+    q, k, v = _rand_qkv(6, 1, 96, 4, 2, 32)
+    w = jax.random.normal(jax.random.PRNGKey(7), q.shape)
+    loss = lambda q, k, v: (pallas_flash_attention(q, k, v, causal=True, block_q=32, block_k=16, interpret=True) * w).sum()  # noqa: E731
+    grad = jax.grad(loss, argnums=(0, 1, 2))
+    plan = flash.backward_plan(96, 32, 16, 32, 32, q.dtype)
+    assert plan == {"backward": "fused", "backward_block_q": 32, "backward_block_k": 16, "dq_resident_bytes": 96 * 128 * (4 + 2 * 4),
+                    "backward_vmem_bytes": flash.fused_backward_vmem_bytes(96, 32, 16, 32, 32, 4)}
+    assert plan["dq_resident_bytes"] < plan["backward_vmem_bytes"] <= flash.FUSED_BWD_VMEM_BUDGET
+    fused = grad(q, k, v)
+    monkeypatch.setattr(flash, "FUSED_BWD_VMEM_BUDGET", plan["backward_vmem_bytes"] - 1)
+    assert flash.backward_plan(96, 32, 16, 32, 32, q.dtype) == {**plan, "backward": "two_kernels"}
+    assert _kernel_names(grad, q, k, v) == ["flash_attention_fwd", "flash_attention_bwd_dq", "flash_attention_bwd_dkv"]
+    for g, e, name in zip(grad(q, k, v), fused, "qkv"):
+        np.testing.assert_array_equal(np.asarray(g), np.asarray(e), err_msg=f"d{name}")
+    # the rows the cells and recipes hold, at the table's blocks (bfloat16): 32k x 128 is the one that falls back
+    monkeypatch.undo()
+    rows = {(4096, 1024, 1024, 80, 80): "fused", (4096, 1024, 1024, 128, 128): "fused", (8192, 1024, 1024, 192, 128): "fused",
+            (32768, 1024, 1024, 128, 128): "two_kernels"}
+    for shape, backward in rows.items():
+        assert flash.backward_plan(*shape, jnp.bfloat16)["backward"] == backward, shape
+    assert flash.dq_resident_bytes(8192, 192, 2) == 16 * 2**20  # float32 8 MiB (192 lanes pad to 256) and the bf16 block twice
+
+
+def test_the_fused_backward_takes_its_own_blocks():
+    """`bwd_blocks` tiles the fused backward apart from the forward (the tuning table's `flash_attention_bwd` entry: at
+    192/128 the backward wants 1024 x 1024 where the forward and the two-kernel fallback cannot leave 1024 x 512): the
+    program holds the backward at those blocks, the gradients are the oracle's, and the fallback keeps the forward's."""
+    q, k, v = _rand_qkv(8, 1, 96, 2, 2, 32)
+    w = jax.random.normal(jax.random.PRNGKey(9), q.shape)
+    kernel = functools.partial(pallas_flash_attention, causal=True, block_q=32, block_k=16, bwd_blocks=(48, 48), interpret=True)
+    loss = lambda q, k, v: (kernel(q, k, v) * w).sum()  # noqa: E731
+    got = jax.grad(loss, argnums=(0, 1, 2))(q, k, v)
+    want = jax.grad(lambda q, k, v: (manual_attention(q, k, v) * w).sum(), argnums=(0, 1, 2))(q, k, v)
+    for g, e, name in zip(got, want, "qkv"):
+        np.testing.assert_allclose(np.asarray(g), np.asarray(e), rtol=5e-4, atol=5e-4, err_msg=f"d{name}")
+    # grids are (batch, heads, pairs of the plan at the kernel's blocks)
+    assert _kernels(jax.grad(loss, argnums=(0, 1, 2)), q, k, v) == [
+        ("flash_attention_fwd", (1, 2, flash.tile_plan(96, 96, 32, 16, True).computed)),
+        ("flash_attention_bwd", (1, 2, flash.tile_plan(96, 96, 48, 48, True).computed))]
 
 
 def test_equal_head_sizes_lower_to_the_program_they_always_did():
     """The second width changes nothing where it equals the first: the same tilings under both names, so the
-    same jaxpr for the three kernels as a v of q's width always gave (blocks, scratch and out_shape by value)."""
+    same jaxpr for the kernels as a v of q's width always gave (blocks, scratch and out_shape by value): one call each."""
     q, k, v = _rand_qkv(5, 1, 64, 2, 1, 32)
     loss = lambda q, k, v: pallas_flash_attention(q, k, v, causal=True, block_q=16, block_k=16, interpret=True).sum()  # noqa: E731
-    text = str(jax.make_jaxpr(jax.grad(loss, argnums=(0, 1, 2)))(q, k, v))
-    for name in ("flash_attention_fwd", "flash_attention_bwd_dq", "flash_attention_bwd_dkv"):
-        assert text.count(f"name={name}") == 1, name
+    assert sorted(_kernel_names(jax.grad(loss, argnums=(0, 1, 2)), q, k, v)) == ["flash_attention_bwd", "flash_attention_fwd"]
     # and the dispatcher's fallback off the TPU: SDPA where it can, the plain softmax where v is narrower
     from modalities_tpu.ops.attention import flash_attention_or_fallback
 
@@ -225,13 +317,18 @@ def test_blocks_are_looked_up_by_both_widths_only_where_they_differ(monkeypatch)
     from modalities_tpu.ops.pallas import autotune
 
     asked = []
-    monkeypatch.setattr(autotune, "lookup", lambda kernel, bucket, dtype: asked.append(bucket) or None)
+    monkeypatch.setattr(autotune, "lookup", lambda kernel, bucket, dtype: asked.append((kernel, bucket)) or None)
     flash.env_flash_blocks(4096, 4096, head_dim=80, head_dim_v=80)
     flash.env_flash_blocks(4096, 4096)
     flash.env_flash_blocks(8192, 8192, head_dim=192, head_dim_v=128)
     flash.env_flash_blocks(4096, 4096, head_dim=192, head_dim_v=128)
-    assert asked == ["sq4096_sk4096", "sq4096_sk4096", "d192_dv128", "d192_dv128"]
+    assert asked == [("flash_attention", bucket) for bucket in ("sq4096_sk4096", "sq4096_sk4096", "d192_dv128", "d192_dv128")]
+    del asked[:]  # the fused backward's blocks: its own entry of the same bucket first, the forward's where it has none
+    flash.env_flash_blocks(8192, 8192, head_dim=192, head_dim_v=128, backward=True)
+    assert asked == [("flash_attention_bwd", "d192_dv128"), ("flash_attention", "d192_dv128")]
     monkeypatch.undo()
+    assert autotune.lookup("flash_attention_bwd", "d192_dv128", "bfloat16", device_kind="TPU v5 lite") == {"block_q": 1024, "block_k": 1024}
+    assert autotune.lookup("flash_attention_bwd", "sq4096_sk4096", "bfloat16", device_kind="TPU v5 lite") is None  # the forward's there
     assert autotune.lookup("flash_attention", "sq4096_sk4096", "bfloat16", device_kind="TPU v5 lite") == {"block_q": 1024, "block_k": 1024}
     hit = autotune.lookup("flash_attention", "d192_dv128", "bfloat16", device_kind="TPU v5 lite")
     assert hit is not None and (hit["block_q"], hit["block_k"]) != (1024, 1024), "1024 x 1024 does not fit VMEM at 192/128 (bwd_dq)"

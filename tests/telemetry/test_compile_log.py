@@ -100,10 +100,55 @@ def test_flash_tile_plan_is_one_event_per_traced_shape_and_none_per_step(tmp_pat
     plans = [e for e in map(json.loads, telemetry.sink_path.read_text().splitlines()) if e.get("name") == "flash_tile_plan"]
     assert [{k: v for k, v in e.items() if k not in ("event", "name", "rank")} for e in plans] == [
         {"seq_q": 48, "seq_k": 48, "block_q": 16, "block_k": 16, "causal": True, "head_dim": 8, "head_dim_v": 8,
-         "computed": 6, "interior": 3, "diagonal": 3, "skipped_steps": 0},
+         "computed": 6, "interior": 3, "diagonal": 3, "skipped_steps": 0, **flash.backward_plan(48, 16, 16, 8, 8, jnp.float32)},
         {"seq_q": 32, "seq_k": 32, "block_q": 16, "block_k": 16, "causal": False, "head_dim": 8, "head_dim_v": 8,
-         "computed": 4, "interior": 4, "diagonal": 0, "skipped_steps": 0},
+         "computed": 4, "interior": 4, "diagonal": 0, "skipped_steps": 0, **flash.backward_plan(32, 16, 16, 8, 8, jnp.float32)},
     ]
+
+
+def test_flash_tile_plan_says_which_backward_a_differentiated_call_runs(tmp_path, monkeypatch):
+    """PR 31: the event names the backward the shape rule picks (`backward`: "fused", or "two_kernels" where a q
+    head's dq row does not fit the budget), the fused kernel's own blocks, the bytes of dq that stay in VMEM and the fused call's counted need; a
+    differentiated call of two layers of one shape, run three times, says it once, and the program holds that kernel."""
+    import functools
+
+    import modalities_tpu.ops.attention as attention
+    import modalities_tpu.ops.pallas.flash_attention as flash
+
+    monkeypatch.setattr(attention, "on_tpu", lambda: True)
+    monkeypatch.setattr(
+        flash, "pallas_flash_attention", functools.partial(flash.pallas_flash_attention, interpret=True)
+    )
+    monkeypatch.setenv("MODALITIES_TPU_FLASH_BLOCK_Q", "16")
+    monkeypatch.setenv("MODALITIES_TPU_FLASH_BLOCK_K", "16")
+
+    def loss(x):
+        return attention.flash_attention_or_fallback(attention.flash_attention_or_fallback(x, x, x), x, x).sum()
+
+    def plans_of_a_traced_step(folder):
+        telemetry = Telemetry(output_folder_path=folder, watchdog_deadline_s=0)
+        previous = set_active_telemetry(telemetry)
+        try:
+            step = jax.jit(jax.grad(loss))
+            for _ in range(3):
+                step(jnp.ones((1, 48, 2, 8), jnp.bfloat16)).block_until_ready()
+            kernels = str(jax.make_jaxpr(jax.grad(loss))(jnp.ones((1, 48, 2, 8), jnp.bfloat16)))
+        finally:
+            set_active_telemetry(previous)
+        events = [e for e in map(json.loads, telemetry.sink_path.read_text().splitlines()) if e.get("name") == "flash_tile_plan"]
+        return [{k: e[k] for k in ("backward", "backward_block_q", "backward_block_k", "dq_resident_bytes", "backward_vmem_bytes")}
+                for e in events], kernels
+
+    resident = 48 * 128 * (4 + 2 * 2)  # a float32 row of 8 lanes padded to 128 and its bfloat16 block twice
+    need = flash.fused_backward_vmem_bytes(48, 16, 16, 8, 8, 2)
+    plans, kernels = plans_of_a_traced_step(tmp_path / "fits")
+    sized = {"backward_block_q": 16, "backward_block_k": 16, "dq_resident_bytes": resident, "backward_vmem_bytes": need}
+    assert plans == [{"backward": "fused", **sized}]
+    assert kernels.count("name=flash_attention_bwd\n") == 2 and "flash_attention_bwd_d" not in kernels
+    monkeypatch.setattr(flash, "FUSED_BWD_VMEM_BUDGET", need - 1)
+    plans, kernels = plans_of_a_traced_step(tmp_path / "does_not_fit")
+    assert plans == [{"backward": "two_kernels", **sized}]
+    assert kernels.count("name=flash_attention_bwd_dq") == 2 == kernels.count("name=flash_attention_bwd_dkv")
 
 
 def test_moe_dispatch_plan_is_one_event_per_traced_shape_and_none_per_step(tmp_path):
