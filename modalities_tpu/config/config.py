@@ -337,6 +337,10 @@ class CLMCrossEntropyLossConfig(BaseModel):
     ignore_index: int = -100
 
 
+class LoopedExitLossConfig(CLMCrossEntropyLossConfig):
+    tag: str = "LoopedExitLoss"
+
+
 class NCELossConfig(BaseModel):
     prediction_key1: str
     prediction_key2: str

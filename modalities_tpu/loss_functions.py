@@ -38,16 +38,21 @@ class CLMCrossEntropyLoss(Loss):
         self.prediction_key = prediction_key
         self.ignore_index = ignore_index
 
-    def sum_and_count(self, logits, labels):
-        """(sum of per-token CE over non-ignored positions, their count) — the
-        accumulation form used by the chunked head+loss path and the pipeline
-        executor's token-weighted mean."""
+    def row_losses(self, logits, labels):
+        """(per-token CE in the shape of `labels`, 0 where ignored; the float32 mask of what counts)."""
         mask = (labels != self.ignore_index).astype(jnp.float32)
         safe_labels = jnp.where(labels == self.ignore_index, 0, labels)
         token_losses = optax.softmax_cross_entropy_with_integer_labels(
             logits.astype(jnp.float32), safe_labels
         )
-        return (token_losses * mask).sum(), mask.sum()
+        return token_losses * mask, mask
+
+    def sum_and_count(self, logits, labels):
+        """(sum of per-token CE over non-ignored positions, their count) — the
+        accumulation form used by the chunked head+loss path and the pipeline
+        executor's token-weighted mean."""
+        rows, mask = self.row_losses(logits, labels)
+        return rows.sum(), mask.sum()
 
     def fused_sum_and_count(self, hidden, head_weight, labels, interpret: bool = False):
         """`sum_and_count` without ever materializing logits: the Pallas
@@ -65,6 +70,53 @@ class CLMCrossEntropyLoss(Loss):
             predictions[self.prediction_key], targets[self.target_key]
         )
         return total / jnp.maximum(count, 1.0)
+
+
+def exit_counter_names(walks: int) -> tuple[str, ...]:
+    """What `LoopedExitLoss.exit_loss` counts for a model of `walks` exits, as the step publishes them."""
+    return tuple(f"loop_exit_ce_{t}" for t in range(1, walks + 1)) + ("loop_expected_exit", "loop_gate_entropy")
+
+
+class LoopedExitLoss(CLMCrossEntropyLoss):
+    """Training loss of a looped decoder with an exit gate (`loop_config`; Ouro, arXiv 2510.25741): per
+    token the cross entropy of every exit weighed by the gate's exit distribution, minus `beta` times
+    that distribution's entropy, then the mean over the tokens that count:
+
+        p_1 = g_1,  p_t = g_t prod_{j<t} (1 - g_j)  (t < T),  p_T = prod_{j<T} (1 - g_j)
+        loss = mean_i [ sum_t p_i(t) CE_i(t) - beta H(p_i) ],  H(p) = -sum_t p(t) log p(t)
+
+    with the gradient through both `p` and the cross entropies. The train step hands `exit_loss` every
+    exit's per-row cross entropy (one fused call over `T x B x S` rows, or the chunked scan) and the
+    gate logits; called on a predictions dict (an evaluation: the last exit's logits) it is the plain
+    mean cross entropy, which is what a model that runs every walk reports."""
+
+    def __init__(self, target_key: str, prediction_key: str, tag: str = "LoopedExitLoss", ignore_index: int = -100):
+        super().__init__(target_key, prediction_key, tag, ignore_index)
+
+    def fused_row_losses(self, hidden, head_weight, labels, interpret: bool = False):
+        """`row_losses(...)[0]` without the logits: the per-row entry of the fused-CE kernels (ops/cross_entropy.py)."""
+        from modalities_tpu.ops.cross_entropy import fused_ce_rows
+
+        return fused_ce_rows(hidden, head_weight, labels, ignore_index=self.ignore_index, interpret=interpret)
+
+    def exit_loss(self, row_ce, gate_logits, labels, beta: float):
+        """`row_ce` and `gate_logits` `[T, B, S]` (exit t's per-row cross entropy, 0 where ignored, and gate
+        logit), `labels` `[B, S]` -> (loss, what the step counts: `exit_counter_names(T)`). float32; the
+        distribution from log-sigmoids, so a saturated gate gives a small probability and not a NaN."""
+        mask = (labels != self.ignore_index).astype(jnp.float32)
+        count = jnp.maximum(mask.sum(), 1.0)
+        gate_logits = gate_logits.astype(jnp.float32)
+        walks = row_ce.shape[0]
+        log_stay = jax.nn.log_sigmoid(-gate_logits)  # log (1 - g_t)
+        log_reach = jnp.cumsum(log_stay, axis=0) - log_stay  # log prod_{j<t} (1 - g_j)
+        log_p = jnp.concatenate([log_reach[:-1] + jax.nn.log_sigmoid(gate_logits[:-1]), log_reach[-1:]], axis=0)
+        p = jnp.exp(log_p)
+        entropy = -(p * log_p).sum(axis=0)
+        per_token = (p * row_ce).sum(axis=0) - beta * entropy
+        mean = lambda rows: (rows * mask).sum() / count  # noqa: E731
+        steps = jnp.arange(1, walks + 1, dtype=jnp.float32)[:, None, None]
+        values = [mean(row_ce[t]) for t in range(walks)] + [mean((p * steps).sum(axis=0)), mean(entropy)]
+        return mean(per_token), dict(zip(exit_counter_names(walks), values))
 
 
 class NCELoss(Loss):

@@ -32,6 +32,7 @@ import jax
 import jax.numpy as jnp
 from pydantic import BaseModel, Field, model_validator
 
+from modalities_tpu.loss_functions import exit_counter_names
 from modalities_tpu.models.components.layer_norms import (
     LayerNormWrapperConfig,
     NormSpec,
@@ -41,7 +42,7 @@ from modalities_tpu.models.gpt2.mla import LatentAttention, MLAConfig, MLASpec
 from modalities_tpu.models.gpt2.moe import BIAS_LEAF, COUNTERS, EXPERT_LOAD, MoE, MoEConfig, MoESpec, ffn_kinds, update_selection_bias
 from modalities_tpu.models.gpt2.ssm import MambaMixer, SSMConfig, SSMSpec, layer_kinds, layer_runs
 from modalities_tpu.models.model import NNModel
-from modalities_tpu.telemetry import scopes
+from modalities_tpu.telemetry import get_active_telemetry, scopes
 
 
 def with_logical_constraint(x, axes, spec=None, explicit=False):
@@ -96,6 +97,39 @@ class AttentionConfig(BaseModel):
     qk_norm_config: Optional[LayerNormWrapperConfig] = None
 
 
+class LoopConfig(BaseModel):
+    """`model_type: ouro` (a looped decoder): the stack of layers is walked `total_ut_steps` times over
+    ONE set of weights, the final norm closes every walk and its output is both that walk's exit and
+    the next walk's input. With `exit_gate` a gate `sigmoid(w . h + b)` (float32) is read off every
+    exit and training takes the loss over all exits (`loss_functions.LoopedExitLoss`, entropy weight
+    `beta`); without it training sees the last exit alone. Evaluation and `apply` report the last exit,
+    which is what `early_exit_threshold: 1` runs: exit by the gate's cumulative distribution is not written."""
+
+    total_ut_steps: Annotated[int, Field(strict=True, ge=1)]
+    exit_gate: bool = True
+    beta: Annotated[float, Field(ge=0.0)] = 0.1
+    early_exit_threshold: float = 1.0
+
+    @model_validator(mode="after")
+    def check_no_early_exit(self) -> "LoopConfig":
+        if self.early_exit_threshold != 1.0:
+            raise ValueError("loop_config: early_exit_threshold below 1 asks for exit by the gate's cumulative distribution, "
+                             "which is not written: every walk runs; leave it at 1")
+        return self
+
+
+@dataclass(frozen=True)
+class LoopSpec:
+    total_ut_steps: int
+    exit_gate: bool = True
+    beta: float = 0.1
+
+    @classmethod
+    def from_config(cls, config: "LoopConfig | dict") -> "LoopSpec":
+        config = LoopConfig(**config) if isinstance(config, dict) else config
+        return cls(config.total_ut_steps, config.exit_gate, config.beta)
+
+
 class GPT2LLMConfig(BaseModel):
     """Config surface kept 1:1 with the reference (gpt2_model.py:320-408)."""
 
@@ -146,6 +180,19 @@ class GPT2LLMConfig(BaseModel):
     # router's experts this model holds (default: all).
     mla_config: Optional[MLAConfig] = None
     moe_config: Optional[MoEConfig] = None
+    # `model_type: ouro`. `loop_config` walks the stack several times over one set of weights
+    # (`LoopConfig`); the two norms below, set, make a block's norms a sandwich: one after each
+    # sub-layer, on what it adds to the residual stream, beside the one before it.
+    loop_config: Optional[LoopConfig] = None
+    post_attention_norm_config: Optional[LayerNormWrapperConfig] = None
+    post_ffn_norm_config: Optional[LayerNormWrapperConfig] = None
+
+    @model_validator(mode="after")
+    def check_loop(self) -> "GPT2LLMConfig":
+        if self.loop_config is not None and (self.attn_layer_period is not None or self.mla_config is not None or self.moe_config is not None):
+            raise ValueError("loop_config walks ONE run of equal dense-decoder layers; a stack of several kinds of layer "
+                             "(attn_layer_period, moe_config) or latent attention under it is not written")
+        return self
 
     @model_validator(mode="after")
     def check_latent_attention(self) -> "GPT2LLMConfig":
@@ -303,6 +350,11 @@ class GPT2ModelSpec:
     # the layers `moe.first_k_dense_replace` on: a layer's kind is (mixer, feed-forward)
     mla: Optional[MLASpec] = None
     moe: Optional[MoESpec] = None
+    # the stack walked several times over one parameter tree, and a block's two further norms
+    # (after each sub-layer); all unset: the tree and the program the decoder always had
+    loop: Optional[LoopSpec] = None
+    post_attn_norm: Optional[NormSpec] = None
+    post_ffn_norm: Optional[NormSpec] = None
 
     @property
     def head_dim(self) -> int:
@@ -374,6 +426,9 @@ class GPT2ModelSpec:
                 self.ssm,
                 self.mla,
                 self.moe,
+                self.loop,
+                self.post_attn_norm,
+                self.post_ffn_norm,
             )
         )
 
@@ -960,6 +1015,8 @@ class GPT2Block(nn.Module):
             a = CausalSelfAttention(
                 spec, self.deterministic, self.decode, slot_spec=self.slot_spec, name="attn"
             )(h, slot, positions)
+        if spec.post_attn_norm is not None:
+            a = build_norm(spec.post_attn_norm, scopes.POST_ATTENTION_NORM, dtype=x.dtype)(a)
         with jax.named_scope(scopes.RESIDUAL):
             x = x + a
         h2 = build_norm(spec.ffn_norm, "ffn_norm", dtype=x.dtype)(x)
@@ -968,6 +1025,8 @@ class GPT2Block(nn.Module):
             m, counters = MoE(spec, self.deterministic, name=scopes.MOE)(h2)
         else:
             m = MLP(spec, self.deterministic, name="mlp")(h2)
+        if spec.post_ffn_norm is not None:
+            m = build_norm(spec.post_ffn_norm, scopes.POST_FFN_NORM, dtype=x.dtype)(m)
         with jax.named_scope(scopes.RESIDUAL):
             x = x + m
         if spec.debug_print_activations == "shape":
@@ -1112,10 +1171,22 @@ _NO_RECURRENT_STATE_CACHE = (
 )
 
 
+_NO_CACHE_ENTRY_PER_WALK = (
+    "this model walks its layers several times (loop_config), and serving it needs a cache entry for every walk AND layer "
+    "(a position's key and value differ from walk to walk: total_ut_steps x n_layer entries), with an exit by the gate's "
+    "cumulative distribution beside it, which serving/ does not have: it trains, it does not decode"
+)
+_NO_STAGE_PLAN_THAT_CLOSES = (
+    "pipeline parallelism hands an activation from each stage to the next and stops at the last; a model that walks its "
+    "layers several times (loop_config) needs a stage plan that closes on itself (the last stage feeds the first again, "
+    "total_ut_steps times, the final norm between), which parallel/pipeline*.py does not have. Run it without a pp axis."
+)
+
+
 def refuse_serving(spec: "GPT2ModelSpec") -> None:
     """A cache, or a forward that reads one, is refused by the name of what serving lacks for this model."""
     for missing, reason in ((spec.has_ssm, _NO_RECURRENT_STATE_CACHE), (spec.mla is not None, _NO_LATENT_CACHE),
-                            (spec.has_moe, _NO_DECODE_THROUGH_DISPATCH)):
+                            (spec.has_moe, _NO_DECODE_THROUGH_DISPATCH), (spec.loop is not None, _NO_CACHE_ENTRY_PER_WALK)):
         if missing:
             raise NotImplementedError(reason)
 
@@ -1127,13 +1198,65 @@ class GPT2Module(nn.Module):
     per-layer k/v caches and the running position live in the ``cache`` collection.
     `output_hidden=True`: stop after lm_head_norm and return the [B,S,E] hidden
     state instead of logits (the chunked head+loss path computes the vocab
-    projection per sequence chunk outside the module)."""
+    projection per sequence chunk outside the module).
+    `output_exits=True` (a looped model with its exit gate, training): return
+    `{"exits": [T, B, S, E], "gate_logits": [T, B, S]}`, every walk's exit and the gate read off
+    it, for the loss over all exits (`loss_functions.LoopedExitLoss`)."""
 
     spec: GPT2ModelSpec
     deterministic: bool = True
     decode: bool = False
     output_hidden: bool = False
     slot_spec: Optional[SlotDecodeSpec] = None
+    output_exits: bool = False
+
+    def _walks(self, x):
+        """`loop.total_ut_steps` walks of the layer scan over ONE parameter tree, traced once: an
+        outer scan whose body is a walk (the layer scan, then the final norm, then the gate) with the
+        parameters broadcast to it, so that autodiff sums a weight's gradient over the walks in the
+        scan's own carry. Returns every walk's exit `[T, B, S, E]` and gate logits `[T, B, S]` (None
+        without a gate). The tree is the dense decoder's (`blocks/block/...`, `lm_head_norm`) with
+        `exit_gate` beside it, whatever T is."""
+        spec, loop = self.spec, self.spec.loop
+        carry_dtype = x.dtype
+
+        def walk(mdl, carry, _):
+            scanned = nn.scan(
+                _BlockScanBody,
+                variable_axes={"params": 0},
+                split_rngs={"params": True, "dropout": True},
+                length=spec.n_layer,
+                metadata_params={nn.meta.PARTITION_NAME: "layers"},
+            )(spec, mdl.deterministic, False, spec.kinds[0], name="blocks")
+            with jax.named_scope(scopes.LAYER_CARRY):
+                u, _ = scanned(carry, None)
+            h = build_norm(spec.lm_head_norm, "lm_head_norm")(u)
+            h = with_logical_constraint(h, ("batch", "seq", "embed"))
+            gate = None
+            if loop.exit_gate:
+                gate = nn.Dense(
+                    1, name=scopes.EXIT_GATE, dtype=jnp.float32, param_dtype=jnp.float32,
+                    # from zero: every token's gate at 1/2, and nothing reaches the stack through the gate before it has moved
+                    kernel_init=nn.with_logical_partitioning(nn.initializers.zeros, ("embed", None)),
+                    bias_init=nn.with_logical_partitioning(nn.initializers.zeros, (None,)),
+                )(h.astype(jnp.float32))[..., 0]
+            return h.astype(carry_dtype), (h, gate)
+
+        if self.is_initializing():  # one walk makes the tree: no walk has a parameter of its own
+            _, (h, gate) = walk(self, x, None)
+            return h[None], None if gate is None else gate[None]
+        layers, walks = spec.n_layer, loop.total_ut_steps
+        kept = walks * layers if spec.remat_variant is not None else None  # under remat a block keeps its input and nothing else
+        get_active_telemetry().emit_event_once("loop_plan", {
+            "walks": walks, "layers": layers, "applications": walks * layers, "exit_gate": loop.exit_gate,
+            "block_inputs_kept": kept, "block_input_bytes": None if kept is None else kept * x.size * x.dtype.itemsize,
+            "head_rows": (walks if loop.exit_gate and self.output_exits else 1) * x.shape[0] * x.shape[1],
+        })
+        with jax.named_scope(scopes.LOOP):  # the carry between walks, and in the backward the sum of a weight's gradient over them
+            _, exits = nn.scan(
+                walk, variable_broadcast="params", split_rngs={"params": False, "dropout": True}, length=walks,
+            )(self, x, None)
+        return exits
 
     @nn.compact
     def __call__(self, input_ids, slot=None, positions=None):
@@ -1195,8 +1318,17 @@ class GPT2Module(nn.Module):
                 "layers are of more than one kind (attn_layer_period, moe_config) or hold latent attention is not "
                 "written for it. Run it without a pp axis."
             )
+        if spec.loop is not None and spec.pipeline_axis is not None:
+            raise NotImplementedError(_NO_STAGE_PLAN_THAT_CLOSES)
         layer_counters = []  # of the expert layers, a [layers, 3 + E] array a run
-        if spec.scan_layers and (len(spec.stack_runs) > 1 or spec.has_moe):
+        if spec.loop is not None:
+            if not spec.scan_layers:
+                raise NotImplementedError("loop_config walks the layer scan: scan_layers must stay on")
+            exits, gate_logits = self._walks(x)
+            if self.output_exits:
+                return {"exits": exits, "gate_logits": gate_logits}
+            x = exits[-1]
+        elif spec.scan_layers and (len(spec.stack_runs) > 1 or spec.has_moe):
             for i, (mixer, ffn, length) in enumerate(spec.stack_runs):
                 x, counters = _LayerRun(spec, self.deterministic, mixer, length, ffn, name=f"run_{i}")(x)
                 if counters is not None:
@@ -1280,8 +1412,9 @@ class GPT2Module(nn.Module):
             self.sow("counters", "moe", jnp.concatenate(layer_counters, axis=0), reduce_fn=lambda _, new: new,
                      init_fn=lambda: jnp.zeros((0, len(COUNTERS) + spec.moe.n_routed_experts), jnp.float32))
 
-        x = build_norm(spec.lm_head_norm, "lm_head_norm")(x)
-        x = with_logical_constraint(x, ("batch", "seq", "embed"))
+        if spec.loop is None:  # a looped model's final norm closes every walk (`_walks`)
+            x = build_norm(spec.lm_head_norm, "lm_head_norm")(x)
+            x = with_logical_constraint(x, ("batch", "seq", "embed"))
         if self.output_hidden:
             return x
         if spec.use_weight_tying:
@@ -1342,6 +1475,9 @@ class GPT2LLM(NNModel):
         ssm_config: Optional[SSMConfig | dict] = None,
         mla_config: Optional[MLAConfig | dict] = None,
         moe_config: Optional[MoEConfig | dict] = None,
+        loop_config: Optional[LoopConfig | dict] = None,
+        post_attention_norm_config=None,
+        post_ffn_norm_config=None,
     ):
         super().__init__(
             sample_key=sample_key,
@@ -1358,6 +1494,8 @@ class GPT2LLM(NNModel):
                 "router_bias": [r".*/moe/router/e_score_correction_bias$"],
                 "embedding": [r".*(wte|wpe).*"],
                 "layernorm": [r".*(norm).*"],
+                # a looped model's exit gate: a vector and a scalar, float32
+                "exit_gate": [r".*/exit_gate/(kernel|bias)$"],
                 # what Mamba marks `_no_weight_decay` in the state-space mixer, and its biases
                 "ssm": [r".*/ssm/(A_log|D)$", r".*/ssm/.*bias$"],
             },
@@ -1415,6 +1553,9 @@ class GPT2LLM(NNModel):
             ssm=SSMSpec.from_config(ssm_config, n_embd) if ssm_config is not None else None,
             mla=MLASpec.from_config(mla_config) if mla_config is not None else None,
             moe=MoESpec.from_config(moe_config) if moe_config is not None else None,
+            loop=LoopSpec.from_config(loop_config) if loop_config is not None else None,
+            post_attn_norm=NormSpec.from_wrapper_config(post_attention_norm_config, n_embd) if post_attention_norm_config is not None else None,
+            post_ffn_norm=NormSpec.from_wrapper_config(post_ffn_norm_config, n_embd) if post_ffn_norm_config is not None else None,
         )
         self.sequence_length = sequence_length
         self.vocab_size = vocab_size
@@ -1460,11 +1601,22 @@ class GPT2LLM(NNModel):
         load: over all of them), and every expert's load a layer, by which `after_update` moves
         the selection bias."""
         spec = self.config_spec
+        if self.trains_on_exits:  # what the loss over the exits counts (`LoopedExitLoss`): the step publishes them with the model's own
+            return {name: () for name in exit_counter_names(spec.loop.total_ut_steps)}
         if not spec.has_moe:
             return {}
         return {**{name: () for name in COUNTERS}, EXPERT_LOAD: (spec.ffn_kinds.count("moe"), spec.moe.n_routed_experts)}
 
+    @property
+    def trains_on_exits(self) -> bool:
+        """A looped model with its exit gate: training takes every walk's exit and gate, not one hidden state."""
+        loop = self.config_spec.loop
+        return loop is not None and loop.exit_gate
+
     def apply_counted(self, params, inputs: dict, train: bool = False, rngs=None, hidden: bool = False):
+        if hidden and train and self.trains_on_exits:
+            module = GPT2Module(self.config_spec, deterministic=False, output_exits=True)
+            return module.apply(params, inputs[self.sample_key], rngs=rngs), {}
         if not self.config_spec.has_moe:
             return super().apply_counted(params, inputs, train=train, rngs=rngs, hidden=hidden)
         module = GPT2Module(self.config_spec, deterministic=not train, output_hidden=hidden)

@@ -299,9 +299,15 @@ def _ce_backward_dw(h, w, labels2, lse, gm, block_n, block_v, vocab, interpret):
 # ---------------------------------------------------------------- custom_vjp
 
 
-def _sum_and_count(lse, corr, labels2, ignore_index):
+def _row_losses(lse, corr, labels2, ignore_index):
+    """Every row's loss `[N, 1]` (0 where the label is ignored) and the float32 mask of what counts."""
     mask = (labels2 != ignore_index).astype(jnp.float32)  # [N, 1]
-    return ((lse - corr) * mask).sum(), mask.sum(), mask
+    return (lse - corr) * mask, mask
+
+
+def _sum_and_count(lse, corr, labels2, ignore_index):
+    rows, mask = _row_losses(lse, corr, labels2, ignore_index)
+    return rows.sum(), mask.sum(), mask
 
 
 @functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4, 5, 6, 7))
@@ -337,7 +343,53 @@ def _fused_ce_bwd(ignore_index, block_n, block_v, vocab, interpret, residuals, c
 _fused_ce.defvjp(_fused_ce_fwd, _fused_ce_bwd)
 
 
+@functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4, 5, 6, 7))
+def _fused_ce_rows(h, w, labels2, ignore_index, block_n, block_v, vocab, interpret):
+    """The per-row form: every row's loss `[N, 1]` (0 where the label is ignored) where `_fused_ce` gives
+    their sum, and in the backward a cotangent for every row where it takes one scalar. The same kernels
+    on the same operands: the sum moved outside (a loss that weighs each row before it sums)."""
+    _say_plan(h, w, block_n, block_v, vocab, dh_in_forward=False)
+    lse, corr = _ce_forward(h, w, labels2, block_n, block_v, vocab, interpret, dh_in_forward=False)
+    return _row_losses(lse, corr, labels2, ignore_index)[0]
+
+
+def _fused_ce_rows_fwd(h, w, labels2, ignore_index, block_n, block_v, vocab, interpret):
+    _say_plan(h, w, block_n, block_v, vocab, dh_in_forward=True)
+    lse, corr, mean_w = _ce_forward(h, w, labels2, block_n, block_v, vocab, interpret, dh_in_forward=True)
+    rows, mask = _row_losses(lse, corr, labels2, ignore_index)
+    return rows, (h, w, labels2, lse, mask, mean_w)
+
+
+def _fused_ce_rows_bwd(ignore_index, block_n, block_v, vocab, interpret, residuals, g_rows):
+    # a row's cotangent stands where `g_total` stood: the rest is `_fused_ce_bwd`
+    return _fused_ce_bwd(ignore_index, block_n, block_v, vocab, interpret, residuals, (g_rows, None))
+
+
+_fused_ce_rows.defvjp(_fused_ce_rows_fwd, _fused_ce_rows_bwd)
+
+
 # ------------------------------------------------------------- public entry
+
+
+def _flat_padded(hidden, head_weight, labels, ignore_index, block_rows, block_vocab):
+    """Rows flattened and, with the vocabulary, padded to the blocks that fit VMEM: `(h2, w, lab2, bn, bv, v, n)`."""
+    e = hidden.shape[-1]
+    v = head_weight.shape[0]
+    n = int(np.prod(hidden.shape[:-1])) if hidden.ndim > 1 else hidden.shape[0]
+
+    h2 = hidden.reshape(n, e)
+    lab2 = labels.reshape(n, 1).astype(jnp.int32)
+
+    bn, bv = _fit_blocks_to_vmem(
+        _row_block(n, block_rows), _vocab_block(v, block_vocab), e, jnp.dtype(hidden.dtype).itemsize
+    )
+    n_pad = -n % bn
+    v_pad = -v % bv
+    if n_pad:
+        h2 = jnp.pad(h2, ((0, n_pad), (0, 0)))
+        lab2 = jnp.pad(lab2, ((0, n_pad), (0, 0)), constant_values=ignore_index)
+    w = jnp.pad(head_weight, ((0, v_pad), (0, 0))) if v_pad else head_weight
+    return h2, w, lab2, bn, bv, v, n
 
 
 def fused_ce_sum_and_count(
@@ -358,21 +410,23 @@ def fused_ce_sum_and_count(
     labels: [...] int, `ignore_index` rows excluded from both sum and count.
     Differentiable wrt hidden and head_weight (fp32 accumulation throughout).
     """
-    e = hidden.shape[-1]
-    v = head_weight.shape[0]
-    n = int(np.prod(hidden.shape[:-1])) if hidden.ndim > 1 else hidden.shape[0]
-
-    h2 = hidden.reshape(n, e)
-    lab2 = labels.reshape(n, 1).astype(jnp.int32)
-
-    bn, bv = _fit_blocks_to_vmem(
-        _row_block(n, block_rows), _vocab_block(v, block_vocab), e, jnp.dtype(hidden.dtype).itemsize
-    )
-    n_pad = -n % bn
-    v_pad = -v % bv
-    if n_pad:
-        h2 = jnp.pad(h2, ((0, n_pad), (0, 0)))
-        lab2 = jnp.pad(lab2, ((0, n_pad), (0, 0)), constant_values=ignore_index)
-    w = jnp.pad(head_weight, ((0, v_pad), (0, 0))) if v_pad else head_weight
-
+    h2, w, lab2, bn, bv, v, _ = _flat_padded(hidden, head_weight, labels, ignore_index, block_rows, block_vocab)
     return _fused_ce(h2, w, lab2, ignore_index, bn, bv, v, interpret)
+
+
+def fused_ce_rows(
+    hidden,
+    head_weight,
+    labels,
+    *,
+    ignore_index: int = -100,
+    block_rows: int = 256,
+    block_vocab: int = 512,
+    interpret: bool = False,
+):
+    """`fused_ce_sum_and_count` before the sum: the cross entropy of every row, float32 in the
+    shape of `labels`, 0 where the label is `ignore_index`. Differentiable wrt hidden and
+    head_weight under any cotangent of the rows (a loss that weighs each row by something of
+    its own, as the loss over a looped model's exits does)."""
+    h2, w, lab2, bn, bv, v, n = _flat_padded(hidden, head_weight, labels, ignore_index, block_rows, block_vocab)
+    return _fused_ce_rows(h2, w, lab2, ignore_index, bn, bv, v, interpret)[:n, 0].reshape(labels.shape)

@@ -28,7 +28,7 @@ from modalities_tpu.dataloader.dataset import DummyDataset, DummyDatasetConfig
 from modalities_tpu.dataloader.dataset_factory import DatasetFactory
 from modalities_tpu.dataloader.sampler_factory import BatchSamplerFactory, SamplerFactory
 from modalities_tpu.dataloader.samplers import RandomSampler, SequentialSampler
-from modalities_tpu.loss_functions import CLMCrossEntropyLoss, NCELoss
+from modalities_tpu.loss_functions import CLMCrossEntropyLoss, LoopedExitLoss, NCELoss
 from modalities_tpu.logging_broker.subscriber_impl.progress_subscriber import (
     DummyProgressSubscriber,
     ProgressSubscriberFactory,
@@ -247,6 +247,7 @@ COMPONENTS: list[ComponentEntity] = [
     ),
     # losses
     ComponentEntity("loss", "clm_cross_entropy_loss", CLMCrossEntropyLoss, cfg.CLMCrossEntropyLossConfig),
+    ComponentEntity("loss", "looped_exit_loss", LoopedExitLoss, cfg.LoopedExitLossConfig),
     ComponentEntity("loss", "nce_loss", NCELoss, cfg.NCELossConfig),
     # optimizers
     ComponentEntity("optimizer", "adam", OptimizerFactory.get_adam, cfg.AdamOptimizerConfig),

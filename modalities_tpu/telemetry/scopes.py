@@ -53,6 +53,18 @@ Expert layer (`models/gpt2/moe.py`, `ops/expert_dispatch.py`), under the module 
     MOE_SHARED        shared            the shared expert, a dense SwiGLU on every token (the name of its module)
     MOE_COMBINE       combine           the rows weighed and added back by token, the sum with the shared expert
 
+Looped decoder (`loop_config`: the stack walked several times over one set of weights; `models/gpt2/gpt2_model.py`, `training/train_step.py`):
+
+    LOOP              loop              round the walks: the carry between walks, every walk's exit stacked, and in the backward
+                                        the sum of each shared weight's gradient over the walks; blocks, the layer scan
+                                        (`layer_carry`), the final norm and the gate name themselves inside it
+    EXIT_GATE         exit_gate         the gate read off every walk's exit, float32 (the name of its module)
+    EXIT_LOSS         exit_loss         inside `head_loss`: the exit distribution from the gates, the cross entropies weighed by
+                                        it, the entropy term, and what the step counts of them
+
+A block with sandwich norms (`post_attention_norm_config`, `post_ffn_norm_config`) holds the modules
+`post_attention_norm` and `post_ffn_norm` beside `attention_norm` and `ffn_norm`.
+
 Latent attention (`models/gpt2/mla.py`) sits in the mixer seat under `attn` as the other
 attention does, with `attn/rope` and `attn/attn_core`; its projections keep Flax's names.
 
@@ -95,6 +107,12 @@ SSM_CONV = "conv"
 SSM_SCAN = "scan"
 SSM_GATE = "gate"
 
+LOOP = "loop"  # a looped model's walks
+EXIT_GATE = "exit_gate"  # and the name of the gate's module
+EXIT_LOSS = "exit_loss"
+POST_ATTENTION_NORM = "post_attention_norm"  # the names of a sandwich block's two further norm modules
+POST_FFN_NORM = "post_ffn_norm"
+
 MOE = "moe"  # the expert layer's module name in the block's `MLP` seat
 MOE_ROUTER = "router"
 MOE_DISPATCH = "dispatch"
@@ -105,6 +123,8 @@ MOE_COMBINE = "combine"
 UPDATE_SCOPES = (GRAD_ACCUMULATE, GRAD_NORM, CLIP, OPTIMIZER, APPLY_UPDATES, ANOMALY_SELECT, STEP_METRICS)
 MODEL_SCOPES = (WTE, ROPE, ATTN_CORE, RESIDUAL, LAYER_CARRY)
 SSM_SCOPES = (SSM_CONV, SSM_SCAN, SSM_GATE)  # on the step only where a layer holds the state-space mixer
+
+LOOP_SCOPES = (LOOP, EXIT_GATE, EXIT_LOSS)  # on the step only where the stack is walked several times (`loop_config`)
 
 MOE_SCOPES = (MOE_ROUTER, MOE_DISPATCH, MOE_EXPERTS, MOE_SHARED, MOE_COMBINE)  # on the step only where a layer holds experts
 

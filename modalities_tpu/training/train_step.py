@@ -456,6 +456,15 @@ class TrainStepBuilder:
         # what the model counts in a pass beside its loss (an expert layer's routing: models/gpt2/moe.py; nothing, for
         # most models): an auxiliary output of the loss, summed over the microbatches on the device; the scalars are
         # published with the step's metrics, and all of it goes to `model.after_update` once the optimizer is done
+        # a looped model with its exit gate hands the loss every walk's exit and gate, and only the loss over the
+        # exits takes them: one without the other is a mistake of the config, said here and not in the first step
+        trains_on_exits = bool(getattr(model, "trains_on_exits", False))
+        if trains_on_exits != hasattr(loss_fn, "exit_loss") or (trains_on_exits and not chunked_loss):
+            raise ValueError(
+                "a model that walks its layers several times with an exit gate (loop_config.exit_gate) trains on the loss over "
+                "all exits (loss variant looped_exit_loss), which reads the exits' hidden states (set lm_head_chunk_size); "
+                f"got model.trains_on_exits={trains_on_exits}, loss {type(loss_fn).__name__}, lm_head_chunk_size={head_chunk}"
+            )
         counted_shapes = dict(model.counted)
         if counted_shapes and (mesh_handle is not None and mesh_handle.degrees.get("dcn", 1) > 1):
             raise NotImplementedError("a model that counts in a step (expert layers) under a dcn mesh axis: the per-slice groups do not carry what it counts")
@@ -525,9 +534,44 @@ class TrainStepBuilder:
                     total, count = loss_fn.sum_and_count(model.head_logits(params, hidden), labels)
                 return total / jnp.maximum(count, 1.0)
 
+            chunk_rows = jax.checkpoint(
+                lambda params, hc, lc: loss_fn.row_losses(model.head_logits(params, hc), lc)[0], prevent_cse=False
+            )
+
+            def _row_ce(params, exits, labels):
+                """Every exit's per-row cross entropy `[T, B, S]` from the exits `[T, B, S, E]` of a looped
+                model, by the tiers of `_chunked_ce`: `T x B x S` rows of ONE fused call against one head,
+                else the chunked scan over `T x B` rows of the sequence, else the whole logits."""
+                walks, batch, seq, width = exits.shape
+                tiled = jnp.broadcast_to(labels[None], (walks, batch, seq))
+                if fused_ce_tier_resolved is not None:
+                    return loss_fn.fused_row_losses(
+                        exits, model.head_weight(params), tiled, interpret=fused_ce_tier_resolved.interpret
+                    )
+                flat, flat_labels = exits.reshape(walks * batch, seq, width), tiled.reshape(walks * batch, seq)
+                if seq <= head_chunk:
+                    return chunk_rows(params, flat, flat_labels).reshape(walks, batch, seq)
+                num_chunks, tail = divmod(seq, head_chunk)
+                whole = num_chunks * head_chunk
+                chunks = lambda a: jnp.moveaxis(a[:, :whole].reshape(a.shape[0], num_chunks, head_chunk, *a.shape[2:]), 1, 0)  # noqa: E731
+                rows = jax.lax.map(lambda c: chunk_rows(params, *c), (chunks(flat), chunks(flat_labels)))
+                rows = jnp.moveaxis(rows, 0, 1).reshape(walks * batch, whole)
+                if tail:
+                    rows = jnp.concatenate([rows, chunk_rows(params, flat[:, whole:], flat_labels[:, whole:])], axis=1)
+                return rows.reshape(walks, batch, seq)
+
+            @jax.named_scope(scopes.HEAD_LOSS)
+            def _exit_ce(params, out, labels):
+                rows = _row_ce(params, out["exits"], labels)
+                with jax.named_scope(scopes.EXIT_LOSS):
+                    return loss_fn.exit_loss(rows, out["gate_logits"], labels, beta=model_spec.loop.beta)
+
             def compute_loss(params, samples, targets, dropout_rng):
                 rngs = {"dropout": dropout_rng} if dropout_rng is not None else None
                 hidden, counted = model.apply_counted(params, samples, train=True, rngs=rngs, hidden=True)
+                if trains_on_exits:
+                    loss, exit_counted = _exit_ce(params, hidden, targets[target_key])
+                    return loss, {**counted, **exit_counted}
                 return _chunked_ce(params, hidden, targets[target_key]), counted
 
         else:

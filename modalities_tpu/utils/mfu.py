@@ -58,7 +58,12 @@ class GPT2MFUCalculator(MFUCalculatorIF):
     stacks plus `num_experts_per_tok` experts a token an expert layer (every chosen expert:
     where only a share of the experts is held, `experts_held / n_routed_experts` of them on
     average), and `6 * s * H * (qk_head_dim + v_head_dim)` a layer for the attention's two
-    products at their two head sizes (full, as the dense formula counts attention)."""
+    products at their two head sizes (full, as the dense formula counts attention).
+
+    A looped model (`loop_config`) uses a parameter once for every walk, and `6N` would count it
+    once: its required operations are `6 x a layer's kernels x L x T` + `6 x T x L x s x h` (the
+    causal half of attention, a layer application) + `6 x T x E x V` (the head, once an exit) a
+    token, and the embedding, a gather, none."""
 
     def __init__(
         self,
@@ -93,10 +98,18 @@ class GPT2MFUCalculator(MFUCalculatorIF):
             self.active_parameters = self.num_parameters - expert_layers * (moe.experts_held - chosen_here) * expert
         if mla is not None:
             self.attention_width = spec.n_head_q * (mla.qk_head_dim + mla.v_head_dim)
+        self.looped_flops_per_token = None
+        loop = getattr(spec, "loop", None)
+        if loop is not None:
+            mlp = (3 * spec.swiglu_hidden if "swiglu" in spec.activation else 2 * spec.ffn_hidden) * n_embd
+            kernels = 2 * n_embd * spec.head_dim * (spec.n_head_q + spec.n_head_kv) + mlp
+            walks = loop.total_ut_steps
+            self.looped_flops_per_token = 6 * walks * (n_layer * (kernels + sequence_length * n_embd) + n_embd * spec.vocab_size)
         self._peak = get_peak_flops()
 
     def compute(self, tokens_per_second: float) -> float:
-        flops_per_token = 6 * self.active_parameters + 6 * self.n_attention_layer * self.sequence_length * self.attention_width
+        flops_per_token = self.looped_flops_per_token or (
+            6 * self.active_parameters + 6 * self.n_attention_layer * self.sequence_length * self.attention_width)
         return tokens_per_second * flops_per_token / (self.world_size * self._peak)
 
 

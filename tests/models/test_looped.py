@@ -1,0 +1,295 @@
+"""A looped decoder (`loop_config`: the stack walked several times over ONE set of weights, the final norm closing
+every walk, sandwich norms, an exit gate and the loss over all exits), held to the plain reference
+(benchmark/reference/looped_decoder_f32.py) on the benchmark's seeded weights at toy widths: d 128, 4 heads of 32,
+SwiGLU 256, 3 layers walked 4 times, vocabulary 512."""
+
+import dataclasses
+
+import jax
+import jax.numpy as jnp
+import numpy as np
+import pytest
+from flax.core import meta
+
+from benchmark.reference import looped_decoder_f32 as reference
+from benchmark.weights_looped import OUTER, LoopedShape, make_program_tree, reference_layout, seed_key
+from modalities_tpu.loss_functions import CLMCrossEntropyLoss, LoopedExitLoss, exit_counter_names
+from modalities_tpu.models.gpt2.gpt2_model import GPT2LLM, GPT2LLMConfig
+from modalities_tpu.optimizers.optimizer_factory import OptimizerFactory, build_weight_decay_mask
+from modalities_tpu.optimizers.scheduler_factory import DummyLRScheduler
+from modalities_tpu.training.train_step import TrainStepBuilder
+
+SEED = 2**31 + 11
+NORM = {"norm_type": "rms_norm", "config": {"ndim": 128, "bias": False, "epsilon": 1e-6}}
+ROTARY = {"qkv_transforms": [{"type_hint": "RotaryTransform", "config": {"n_embd": 128, "n_head": 4, "base_freq": 1000000}}]}
+DENSE = dict(
+    sample_key="input_ids", prediction_key="logits", poe_type="NOPE", sequence_length=64, vocab_size=512, n_layer=3,
+    n_head_q=4, n_head_kv=4, n_embd=128, ffn_hidden=384, dropout=0.0, bias=False, attention_config=ROTARY,
+    attention_implementation="manual", activation_type="swiglu", attention_norm_config=NORM, ffn_norm_config=NORM,
+    lm_head_norm_config=NORM, use_weight_tying=False, lm_head_chunk_size=32,
+)
+TOY = {**DENSE, "post_attention_norm_config": NORM, "post_ffn_norm_config": NORM, "loop_config": {"total_ut_steps": 4, "beta": 0.1}}
+HYPER = {"lr": [1e-3, 1e-3], "b1": 0.9, "b2": 0.95, "eps": 1e-8, "weight_decay": 0.1, "clip_norm": 1.0}
+
+
+def build(**changes) -> GPT2LLM:
+    return GPT2LLM(**GPT2LLMConfig(**{**TOY, **changes}).model_dump())
+
+
+def unboxed(model, abstract: bool = True):
+    init = lambda: meta.unbox(model.init_params(jax.random.PRNGKey(0)))  # noqa: E731
+    return jax.eval_shape(init) if abstract else init()
+
+
+@pytest.fixture(scope="module")
+def toy():
+    """The model computing in float32, its seeded weights (bfloat16 values, held in float32), and their shape."""
+    model = build().with_spec_updates(compute_dtype="float32")
+    shape = dataclasses.replace(LoopedShape.from_yaml({"model_raw": {"config": TOY}}), gate_std=0.02)  # the cells seed the gate at 0: here the path through it is held
+    params = jax.tree.map(lambda x: x.astype(jnp.float32), make_program_tree(shape, SEED, unboxed(model), match_dtypes=False))
+    return model, shape, params
+
+
+@pytest.fixture(scope="module")
+def tokens():
+    return np.random.default_rng(0).integers(0, 511, size=(2, 65)).astype(np.int32)
+
+
+def exits_of(model, params, ids):
+    with jax.default_matmul_precision("highest"):
+        out, counted = jax.jit(lambda p, t: model.apply_counted(p, {"input_ids": t}, train=True, hidden=True))(params, jnp.asarray(ids))
+    assert counted == {}
+    return out
+
+
+def program_loss(model, params, ids, targets, fused: bool = False):
+    """The loss over the exits as the train step computes it, from the program's own pieces."""
+    loss_fn = LoopedExitLoss(target_key="target_ids", prediction_key="logits")
+    out, _ = model.apply_counted(params, {"input_ids": ids}, train=True, hidden=True)
+    walks = out["exits"].shape[0]
+    tiled = jnp.broadcast_to(targets[None], (walks, *targets.shape))
+    if fused:
+        rows = loss_fn.fused_row_losses(out["exits"], model.head_weight(params), tiled, interpret=True)
+    else:
+        rows = loss_fn.row_losses(jax.vmap(lambda h: model.head_logits(params, h))(out["exits"]), tiled)[0]
+    return loss_fn.exit_loss(rows, out["gate_logits"], targets, beta=model.config_spec.loop.beta)
+
+
+# ------------------------------------------------------------------ config and tree
+
+
+def test_one_tree_whatever_the_walks(toy):
+    model, shape, params = toy
+    assert sorted(params["params"]) == ["blocks", "exit_gate", "lm_head", "lm_head_norm", "wte"]
+    assert sorted(params["params"]["blocks"]["block"]) == ["attention_norm", "attn", "ffn_norm", "mlp", "post_attention_norm", "post_ffn_norm"]
+    count = lambda tree: sum(int(np.prod(x.shape)) for x in jax.tree.leaves(tree))  # noqa: E731
+    counts = {t: count(unboxed(build(loop_config={"total_ut_steps": t}))) for t in (1, 2, 4, 7)}
+    assert set(counts.values()) == {shape.all_params()} == {count(params)}, counts
+    assert model.counted == {name: () for name in exit_counter_names(4)} and model.trains_on_exits
+    assert hash(model.config_spec) == hash(build().with_spec_updates(compute_dtype="float32").config_spec)
+    assert hash(model.config_spec) != hash(build(loop_config={"total_ut_steps": 3}).with_spec_updates(compute_dtype="float32").config_spec)
+
+
+def test_one_walk_without_sandwich_norms_and_gate_is_the_dense_decoder(tokens):
+    dense = GPT2LLM(**GPT2LLMConfig(**DENSE).model_dump())
+    looped = GPT2LLM(**GPT2LLMConfig(**{**DENSE, "loop_config": {"total_ut_steps": 1, "exit_gate": False}}).model_dump())
+    p_dense, p_looped = unboxed(dense, abstract=False), unboxed(looped, abstract=False)
+    assert jax.tree.structure(p_dense) == jax.tree.structure(p_looped)
+    assert all(bool((a == b).all()) for a, b in zip(jax.tree.leaves(p_dense), jax.tree.leaves(p_looped)))
+    assert not looped.trains_on_exits and looped.counted == {}
+    ids = {"input_ids": jnp.asarray(tokens[:, :-1])}
+    assert bool((dense.apply(p_dense, ids)["logits"] == looped.apply(p_looped, ids)["logits"]).all())
+    hidden = lambda m, p: m.apply_counted(p, ids, train=True, hidden=True)[0]  # noqa: E731
+    assert bool((hidden(dense, p_dense) == hidden(looped, p_looped)).all())
+
+
+@pytest.mark.parametrize("changes, match", [
+    ({"loop_config": {"total_ut_steps": 4, "early_exit_threshold": 0.9}}, "cumulative distribution"),
+    ({"attn_layer_period": 2, "ssm_config": {}}, "ONE run of equal dense-decoder layers"),
+])
+def test_what_is_not_written_is_refused_at_config_time(changes, match):
+    with pytest.raises(ValueError, match=match):
+        GPT2LLMConfig(**{**TOY, **changes})
+
+
+def test_serving_and_a_pipeline_axis_refuse_by_the_name_of_what_is_missing(toy, tokens):
+    model, _, params = toy
+    with pytest.raises(NotImplementedError, match="cache entry for every walk AND layer"):
+        model.init_decode_cache(params, 1)
+    with pytest.raises(NotImplementedError, match="cache entry for every walk AND layer"):
+        model.init_slot_cache(params, 2)
+    piped = build().with_spec_updates(pipeline_axis="pp")
+    with pytest.raises(NotImplementedError, match="stage plan that closes on itself"):
+        piped.apply(params, {"input_ids": jnp.asarray(tokens[:, :-1])})
+
+
+def test_the_exit_loss_needs_the_looped_model_and_the_looped_model_the_exit_loss(toy):
+    def builder(model, loss_fn):
+        opt = OptimizerFactory.get_adam_w(lr=1e-3, betas=(0.9, 0.95), eps=1e-8, weight_decay=0.1,
+                                          weight_decay_groups_excluded=["norm", "embedding"], wrapped_model=model)
+        return TrainStepBuilder(model=model, loss_fn=loss_fn, optimizer_spec=opt, scheduler_spec=DummyLRScheduler(name="dummy", optimizer=opt))
+
+    with pytest.raises(ValueError, match="looped_exit_loss"):
+        builder(build(), CLMCrossEntropyLoss(target_key="target_ids", prediction_key="logits")).build(seed=0, materialize=False)
+    with pytest.raises(ValueError, match="looped_exit_loss"):
+        builder(GPT2LLM(**GPT2LLMConfig(**DENSE).model_dump()), LoopedExitLoss(target_key="target_ids", prediction_key="logits")).build(seed=0, materialize=False)
+    with pytest.raises(ValueError, match="lm_head_chunk_size"):
+        builder(build(lm_head_chunk_size=None), LoopedExitLoss(target_key="target_ids", prediction_key="logits")).build(seed=0, materialize=False)
+
+
+# ------------------------------------------------------------------ against the reference
+
+
+def test_every_exit_is_the_references(toy, tokens):
+    """Every exit's hidden state, its logits and its gate, program in float32 against the reference: 1e-5 of the
+    largest value is float32 rounding through 12 layer applications (read: 2e-6)."""
+    model, shape, params = toy
+    out = exits_of(model, params, tokens[:, :-1])
+    hidden, gates, outer = reference.exits_of(shape, SEED, tokens[:, :-1])
+    assert out["exits"].shape == (4, 2, 64, 128) and out["gate_logits"].shape == (4, 2, 64)
+    assert float(jnp.abs(out["exits"] - hidden).max() / jnp.abs(hidden).max()) < 1e-5
+    assert float(jnp.abs(out["gate_logits"] - gates).max()) < 1e-5 and float(jnp.abs(gates).max()) > 0.05
+    with jax.default_matmul_precision("highest"):
+        logits = jax.vmap(lambda h: model.head_logits(params, h))(out["exits"])
+        want = jnp.einsum("tnse,ev->tnsv", hidden, outer["lm_head"])
+    assert float(want.std()) > 0.1 and float(jnp.abs(logits - want).max()) < 1e-5
+    # evaluation and `apply` report the last exit
+    last = model.apply(params, {"input_ids": jnp.asarray(tokens[:, :-1])})["logits"]
+    assert float(jnp.abs(last - want[-1]).max()) < 1e-4
+    assert not bool(jnp.allclose(hidden[0], hidden[3], atol=1e-2)), "the walks differ"
+
+
+def test_bfloat16_program_is_near_the_reference(toy, tokens):
+    """The program as it trains computes its blocks in bfloat16: about three digits a layer application. The exits are
+    normed (entries of size 1): read up to 0.06 after 12 applications (CPU, PR 32); a dropped post-norm moves them by 1."""
+    _, shape, params = toy
+    hidden, _, _ = reference.exits_of(shape, SEED, tokens[:, :-1])
+    assert float(jnp.abs(exits_of(build(), params, tokens[:, :-1])["exits"] - hidden).max()) < 0.15
+
+
+def reference_gradients(shape, tokens):
+    key = seed_key(SEED)  # jitted, as the program's tree is made
+    layer = jax.jit(lambda key, i: reference.reference_layer(shape, key, i))
+    layers = [layer(key, jnp.int32(i)) for i in range(shape.n_layer)]
+    return reference.loss_and_gradients(shape, layers, jax.jit(lambda key: reference.reference_outer(shape, key))(key), tokens[:, :-1], tokens[:, 1:])
+
+
+def test_loss_counters_and_every_gradient_leaf(toy, tokens):
+    """Loss, what the step counts and every leaf of the gradient against the reference's, which walks its backward
+    pass by hand: 2e-4 of a leaf's largest entry is float32 rounding through 12 applications and back."""
+    model, shape, params = toy
+    ids, targets = jnp.asarray(tokens[:, :-1]), jnp.asarray(tokens[:, 1:])
+    with jax.default_matmul_precision("highest"):
+        (got_loss, counted), got = jax.jit(jax.value_and_grad(lambda p: program_loss(model, p, ids, targets), has_aux=True))(params)
+    want_loss, (layer_grads, outer_grads), want_counted = reference_gradients(shape, tokens)
+    assert abs(float(got_loss) - want_loss) < 1e-5
+    assert np.allclose([float(counted[f"loop_exit_ce_{t}"]) for t in range(1, 5)], want_counted["exit_ce"], atol=1e-5)
+    assert abs(float(counted["loop_expected_exit"]) - want_counted["expected_exit"]) < 1e-5 and 1.5 < want_counted["expected_exit"] < 2.2
+    assert abs(float(counted["loop_gate_entropy"]) - want_counted["gate_entropy"]) < 1e-5
+    got = reference_layout(got)
+    leaves = [(f"layers.{name}[{i}]", got["layers"][name][i], layer[name]) for i, layer in enumerate(layer_grads) for name in layer]
+    leaves += [(name, got[name], outer_grads[name]) for name in OUTER]
+    assert len(leaves) == 3 * 11 + 5
+    for name, g, w in leaves:
+        assert float(jnp.abs(w).max()) > 0, name
+        assert float(jnp.abs(g - w).max() / jnp.abs(w).max()) < 2e-4, name
+
+
+def test_the_shared_gradient_is_the_sum_over_walks_of_an_untied_copys(toy, tokens):
+    """Four copies of the weights, one a walk, give four gradients; the shared weights' gradient is their sum."""
+    model, _, params = toy
+    ids, targets = jnp.asarray(tokens[:, :-1]), jnp.asarray(tokens[:, 1:])
+    spec, inner = model.config_spec, params["params"]
+    from modalities_tpu.models.components.layer_norms import build_norm
+    from modalities_tpu.models.gpt2.gpt2_model import GPT2Block
+
+    def untied_loss(copies):
+        """The model written out with a parameter tree a walk (`copies`: blocks and final norm, stacked on a leading axis)."""
+        x = jnp.take(inner["wte"], ids, axis=0)
+        exits, gates = [], []
+        for t in range(4):
+            for l in range(3):
+                layer = jax.tree.map(lambda v: v[t, l], copies["blocks"]["block"])
+                x = GPT2Block(spec).apply({"params": layer}, x)
+            x = build_norm(spec.lm_head_norm, "n").apply({"params": jax.tree.map(lambda v: v[t], copies["lm_head_norm"])}, x)
+            exits.append(x)
+            gates.append((x @ inner["exit_gate"]["kernel"])[..., 0] + inner["exit_gate"]["bias"][0])
+        loss_fn = LoopedExitLoss(target_key="target_ids", prediction_key="logits")
+        rows = loss_fn.row_losses(jnp.stack(exits) @ inner["lm_head"]["kernel"], jnp.broadcast_to(targets[None], (4, *targets.shape)))[0]
+        return loss_fn.exit_loss(rows, jnp.stack(gates), targets, beta=0.1)[0]
+
+    shared = {"blocks": inner["blocks"], "lm_head_norm": inner["lm_head_norm"]}
+    with jax.default_matmul_precision("highest"):
+        untied = jax.jit(jax.grad(untied_loss))(jax.tree.map(lambda v: jnp.broadcast_to(v[None], (4, *v.shape)), shared))
+        tied = jax.jit(jax.grad(lambda p: program_loss(model, p, ids, targets)[0]))(params)["params"]
+    for path, per_walk in jax.tree_util.tree_leaves_with_path(untied):
+        want, got = per_walk.sum(axis=0), tied
+        for part in path:
+            got = got[part.key]
+        assert float(jnp.abs(per_walk[0] - per_walk[3]).max()) > 0, "the walks' gradients differ"
+        assert float(jnp.abs(got - want).max() / jnp.abs(want).max()) < 1e-4, jax.tree_util.keystr(path)
+
+
+def test_two_adamw_steps_through_the_train_step(toy, tokens):
+    """The program's own train step (the loss over the exits through `apply_counted(hidden=True)`, AdamW with the
+    configuration's decay mask, clipping), chunked tier, beside the reference's two steps: losses to 1e-5, every leaf's
+    change to 2% of the reference's (Adam's first steps are lr * sign-like: a leaf's change is a norm of +-1e-3
+    entries, which float32 rounding of a tiny gradient flips for a few entries)."""
+    from modalities_tpu.models.model import MixedPrecisionSpec
+
+    _, shape, params = toy
+    model = build().update_train_spec(mixed_precision=MixedPrecisionSpec(compute_dtype="float32"))  # the builder writes it into the spec
+    rng = np.random.default_rng(5)
+    batches = [(s[:, :-1], s[:, 1:]) for s in (rng.integers(0, 511, size=(2, 65)).astype(np.int32) for _ in range(2))]
+    opt = OptimizerFactory.get_adam_w(lr=1e-3, betas=(0.9, 0.95), eps=1e-8, weight_decay=0.1,
+                                      weight_decay_groups_excluded=["embedding", "norm", "exit_gate"], wrapped_model=model)
+    mask = build_weight_decay_mask(params, model, ["embedding", "norm", "exit_gate"])
+    assert not mask["params"]["exit_gate"]["kernel"] and not mask["params"]["blocks"]["block"]["post_ffn_norm"]["scale"] and mask["params"]["lm_head"]["kernel"]
+    builder = TrainStepBuilder(model=model, loss_fn=LoopedExitLoss(target_key="target_ids", prediction_key="logits"), optimizer_spec=opt,
+                               scheduler_spec=DummyLRScheduler(name="dummy", optimizer=opt), grad_clip_norm=1.0)
+    fns = builder.build(seed=0)
+    state = fns.app_state_handle.state.replace(params=jax.tree.map(jnp.array, params))
+    losses, metrics = [], None
+    with jax.default_matmul_precision("highest"):
+        for ids, targets in batches:
+            batch = fns.put_batch({"samples": {"input_ids": ids[None]}, "targets": {"target_ids": targets[None]}})
+            state, metrics = fns.train_step(state, batch)
+            losses.append(float(metrics["loss"]))
+    want = reference.train_steps(shape, SEED, batches, HYPER)
+    assert np.allclose(losses, want["losses"], atol=1e-5), (losses, want["losses"])
+    assert np.allclose([float(metrics[f"counter/loop_exit_ce_{t}"]) for t in range(1, 5)], want["exit_ce"][1], atol=1e-5)
+    assert abs(float(metrics["counter/loop_expected_exit"]) - want["expected_exit"][1]) < 1e-5
+    start = make_program_tree(shape, SEED, unboxed(model), match_dtypes=False)
+    moved = jax.tree.map(lambda a, b: a - b.astype(jnp.float32), state.params, start)
+    got = jax.device_get(reference.leaf_norms(reference_layout(moved)))
+    for name, norms in want["delta_norms"].items():
+        assert np.all(norms > 0), name
+        assert np.allclose(got[name], norms, rtol=0.02), (name, got[name], norms)
+
+
+@pytest.mark.parametrize("tier, chunk", [("off", 32), ("off", 48), ("off", 64)])
+def test_the_chunked_and_whole_tiers_give_the_fused_tiers_numbers(toy, tokens, monkeypatch, tier, chunk):
+    """One step of the train step by each tier of `_row_ce` (the chunked scan over two chunks, over one and a tail, the
+    whole logits) against the fused tier's (`T x B x S` rows of one kernel call): loss, what the step counts and the
+    gradient's norm to float32 rounding."""
+    from modalities_tpu.models.model import MixedPrecisionSpec
+
+    _, _, params = toy
+    batch = {"samples": {"input_ids": tokens[None, :, :-1]}, "targets": {"target_ids": tokens[None, :, 1:]}}
+
+    def one_step(setting, head_chunk):
+        monkeypatch.setenv("MODALITIES_TPU_FUSED_CE", setting)
+        model = build(lm_head_chunk_size=head_chunk).update_train_spec(mixed_precision=MixedPrecisionSpec(compute_dtype="float32"))
+        opt = OptimizerFactory.get_adam_w(lr=1e-3, betas=(0.9, 0.95), eps=1e-8, weight_decay=0.1,
+                                          weight_decay_groups_excluded=["embedding", "norm", "exit_gate"], wrapped_model=model)
+        fns = TrainStepBuilder(model=model, loss_fn=LoopedExitLoss(target_key="target_ids", prediction_key="logits"), optimizer_spec=opt,
+                               scheduler_spec=DummyLRScheduler(name="dummy", optimizer=opt)).build(seed=0)
+        state = fns.app_state_handle.state.replace(params=jax.tree.map(jnp.array, params))
+        with jax.default_matmul_precision("highest"):
+            _, metrics = fns.train_step(state, fns.put_batch(batch))
+        return {name: float(value) for name, value in metrics.items()}
+
+    fused, other = one_step("on", 32), one_step(tier, chunk)
+    assert set(fused) == set(other) >= {"loss", "grad_norm", "counter/loop_exit_ce_1", "counter/loop_expected_exit"}
+    for name, value in fused.items():
+        assert abs(other[name] - value) <= 1e-5 * max(1.0, abs(value)), (name, value, other[name])

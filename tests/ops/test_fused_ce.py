@@ -223,3 +223,63 @@ def test_blocks_step_down_by_width_as_the_chip_compiles_them():
     assert fitted == {1536: (256, 512), 2048: (256, 256), 2560: (256, 256), 4096: (128, 128)}
     assert fused_ce._forward_in_step_vmem_bytes(256, 512, 2048, 2) > 16 * 2**20 > fused_ce._forward_in_step_vmem_bytes(256, 256, 2560, 2)
     assert 16128 % 256 == 0
+
+
+# ------------------------------------------------------------------ the per-row entry (a looped model's loss over its exits)
+
+
+@pytest.mark.parametrize("rows,vocab", [(32, 256), (21, 200)])
+def test_row_losses_and_both_gradients_under_random_row_cotangents(rows, vocab):
+    """`fused_ce_rows` hands out every row's loss and takes a cotangent for every row: the plain form's numbers for
+    the losses and for both gradients whatever weighs the rows (ragged rows and vocabulary, an ignored row)."""
+    from modalities_tpu.ops.pallas.fused_ce import fused_ce_rows
+
+    h, w, y = _inputs(7, rows, vocab, 64)
+    y = y.at[3].set(-100)
+    weights = jax.random.normal(jax.random.PRNGKey(8), (rows,))
+
+    def plain(h, w):
+        logits = jnp.einsum("ne,ve->nv", h, w)
+        per_row = optax.softmax_cross_entropy_with_integer_labels(logits, jnp.where(y == -100, 0, y))
+        return per_row * (y != -100)
+
+    fused = lambda h, w: fused_ce_rows(h, w, y, block_rows=16, block_vocab=128, interpret=True)  # noqa: E731
+    got, want = fused(h, w), plain(h, w)
+    assert got.shape == (rows,) and got.dtype == jnp.float32 and float(got[3]) == 0.0
+    np.testing.assert_allclose(np.asarray(got), np.asarray(want), rtol=1e-5, atol=1e-5)
+    got_grads = jax.grad(lambda h, w: (fused(h, w) * weights).sum(), argnums=(0, 1))(h, w)
+    want_grads = jax.grad(lambda h, w: (plain(h, w) * weights).sum(), argnums=(0, 1))(h, w)
+    for g, wnt in zip(got_grads, want_grads):
+        np.testing.assert_allclose(np.asarray(g), np.asarray(wnt), rtol=1e-4, atol=1e-5)
+    assert float(jnp.abs(got_grads[0][3]).max()) == 0.0, "an ignored row gets no gradient whatever its cotangent"
+
+
+def test_rows_keep_the_shape_of_the_labels_and_run_the_same_two_kernels():
+    """`[T, B, S, E]` exits are `T x B x S` rows of one call; differentiated it is `fused_ce_fwd` and `fused_ce_bwd_dw`
+    on the operands the sum form gives them, and undifferentiated the lean kernel."""
+    from modalities_tpu.ops.pallas.fused_ce import fused_ce_rows
+
+    h, w, y = _inputs(9, 48, 256, 64)
+    h4, y3 = h.reshape(3, 2, 8, 64), y.reshape(3, 2, 8)
+    rows = fused_ce_rows(h4, w, y3, block_rows=16, block_vocab=128, interpret=True)
+    assert rows.shape == (3, 2, 8)
+    total, _ = fused_ce_sum_and_count(h, w, y, block_rows=16, block_vocab=128, interpret=True)
+    np.testing.assert_allclose(float(rows.sum()), float(total), rtol=1e-6)
+
+    def kernels(fn):
+        return sorted((c.params["name"], tuple(v.aval.shape for v in c.invars)) for c in _pallas_calls(jax.make_jaxpr(fn)(h, w).jaxpr))
+
+    rows_loss = lambda h, w: fused_ce_rows(h, w, y, block_rows=16, block_vocab=128, interpret=True).sum()  # noqa: E731
+    sum_loss = lambda h, w: fused_ce_sum_and_count(h, w, y, block_rows=16, block_vocab=128, interpret=True)[0]  # noqa: E731
+    assert kernels(jax.grad(rows_loss, argnums=(0, 1))) == kernels(jax.grad(sum_loss, argnums=(0, 1)))
+    assert [name for name, _ in kernels(jax.grad(rows_loss, argnums=(0, 1)))] == ["fused_ce_bwd_dw", "fused_ce_fwd"]
+    assert [name for name, _ in kernels(rows_loss)] == ["fused_ce_eval"]
+
+
+def test_the_sum_form_lowers_as_it_did():
+    """The sum form is the accepted cells' path: its differentiated jaxpr holds the two kernels, one scalar cotangent
+    broadcast over the mask, and no per-row entry."""
+    h, w, y = _inputs(6, 32, 256, 64)
+    loss = lambda h, w: fused_ce_sum_and_count(h, w, y, block_rows=16, block_vocab=128, interpret=True)[0]  # noqa: E731
+    text = str(jax.make_jaxpr(jax.grad(loss, argnums=(0, 1)))(h, w))
+    assert "_fused_ce_rows" not in text and text.count("pallas_call") == 2

@@ -23,7 +23,7 @@ from modalities_tpu.ops.pallas.flash_attention import (
     flash_fwd_out_lse,
     pallas_flash_attention,
 )
-from modalities_tpu.ops.pallas.fused_ce import fused_ce_sum_and_count
+from modalities_tpu.ops.pallas.fused_ce import fused_ce_rows, fused_ce_sum_and_count
 from modalities_tpu.ops.pallas.fused_rmsnorm import fused_rms_norm
 from modalities_tpu.ops.pallas.quant_matmul import quant_matmul
 from modalities_tpu.ops.pallas.selective_scan import pallas_selective_scan
@@ -116,6 +116,16 @@ def _fused_ce(n_embd, rows, vocab=VOCAB):
     return jax.grad(loss, argnums=(0, 1)), (((rows, n_embd), BF16), ((vocab, n_embd), BF16), ((rows,), jnp.int32)), 2
 
 
+def _fused_ce_rows(n_embd, walks, rows, vocab):
+    """The per-row entry under a cotangent a row: the looped cell's four exits of 4,096 rows (16,384 rows of one call) at
+    width 2048 against the whole 49,152-row head, and the 32,768 rows of a microbatch of 2."""
+    def loss(hidden, head, labels, weights):
+        return (fused_ce_rows(hidden, head, labels, block_rows=256, block_vocab=512) * weights).sum()
+
+    exits = (walks, rows // SEQ, SEQ)
+    return jax.grad(loss, argnums=(0, 1)), (((*exits, n_embd), BF16), ((vocab, n_embd), BF16), (exits, jnp.int32), (exits, F32)), 2
+
+
 def _fused_rmsnorm(n_embd):
     def loss(x, scale):
         return fused_rms_norm(x, scale, None).astype(F32).sum()
@@ -149,6 +159,8 @@ CASES = {
     # configs/config_kanana2_30b_a3b.yaml's own shape: sequence 4096, microbatch 4
     "flash_fwd_bwd_d192_dv128_b4_s4096_h32": _flash_two_widths(4, 4096, 32, 192, 128),
     "fused_ce_fwd_bwd_e2048_rows16384_v16128": _fused_ce(2048, 4 * SEQ, vocab=16128),
+    "fused_ce_rows_fwd_bwd_e2048_exits4_rows4096_v49152": _fused_ce_rows(2048, 4, SEQ, 49152),
+    "fused_ce_rows_fwd_bwd_e2048_exits4_rows8192_v49152": _fused_ce_rows(2048, 4, 2 * SEQ, 49152),
     "fused_ce_fwd_bwd_e1536": _fused_ce(1536, SEQ),
     "fused_ce_fwd_bwd_e2560": _fused_ce(2560, 4 * SEQ),
     "fused_ce_fwd_bwd_e2560_rows8192": _fused_ce(2560, 2 * SEQ),

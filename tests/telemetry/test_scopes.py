@@ -286,6 +286,87 @@ def test_the_runs_are_by_feed_forward_and_latent_attention_keeps_the_names_under
     assert re.search(r"MOE_DISPATCH\s+dispatch", scopes.__doc__) and re.search(r"MOE_COMBINE\s+combine", scopes.__doc__)
 
 
+# ------------------------------------------------------------------ (a3) closure, for a stack walked several times
+
+
+def compile_toy_looped_train_step(tmp: Path) -> str:
+    """The benchmark's looped cell at toy size (tests/benchmark/toy_looped.py: 3 layers walked 4 times, sandwich
+    norms, the exit gate and the loss over the exits, every block rematerialized), built as its mode builds it:
+    the optimized HLO text of its train step."""
+    from benchmark.manifest import load_cell
+    from benchmark.weights_looped import LoopedShape
+    from tests.benchmark.toy_looped import CELL as LOOPED_CELL, make_toy_looped_root
+
+    root = make_toy_looped_root(tmp)
+    cell = load_cell(LOOPED_CELL, root)
+    mode = cell.module("modes", cell.mode)
+    raw = yaml.safe_load(cell.yaml_path.read_text())
+    shape = LoopedShape.from_yaml(raw)
+    profile = raw["settings"]["step_profile"]
+    scratch = root / ".bench_scratch" / cell.name
+    (scratch / "data").mkdir(parents=True)
+    cell.module("traffic", cell.traffic["generator"]).generate(
+        cell.traffic, 1, scratch / "data" / "train.pbin", vocab_size=shape.vocab_size,
+        sequence_length=int(profile["sequence_length"]))
+    started_in = os.getcwd()
+    try:
+        _, fns = mode.build_program(cell, 1, scratch, shape)
+    finally:
+        os.chdir(started_in)
+    keys = raw["settings"]["referencing_keys"]
+    tokens = np.zeros((int(profile["local_train_micro_batch_size"]), int(profile["sequence_length"])), np.int32)
+    host = {"samples": {keys["sample_key"]: tokens[None]}, "targets": {keys["target_key"]: tokens[None]}}
+    return fns.lower_train_step(fns.put_batch(host, has_acc_dim=True)).compile().as_text()
+
+
+@pytest.fixture(scope="module")
+def looped_hlo(tmp_path_factory) -> str:
+    return compile_toy_looped_train_step(tmp_path_factory.mktemp("scoped_looped"))
+
+
+@pytest.fixture(scope="module")
+def looped_rules() -> dict:
+    raw = json.loads((REPO / "benchmark" / "scopes" / "train_looped.json").read_text())
+    return {name: [(re.compile(pattern), bucket) for pattern, bucket in raw[name]] for name in LISTS}
+
+
+@pytest.mark.parametrize("which", LISTS)
+def test_every_operation_of_the_toy_looped_step_falls_into_a_bucket(looped_hlo, looped_rules, which):
+    table = scope_table(looped_hlo)
+    assert len(table) > 100
+    paths = set(table.values()) | every_op_name(looped_hlo)
+    left = {path for path in paths if bucket_of(path, looped_rules[which]) == UNATTRIBUTED}
+    assert not left, f"no rule of the list {which!r} takes {sorted(left)[:5]}"
+    if which == "component":
+        found = {bucket_of(path, looped_rules[which]) for path in paths}
+        assert {"attn", "mlp", "norms", "residual", "head_loss", "exit_gate", "wte", "layer_carry", "loop_carry"} <= found
+        assert "model_other" not in found, sorted(p for p in paths if bucket_of(p, looped_rules[which]) == "model_other")[:5]
+
+
+@pytest.mark.parametrize("scope", scopes.LOOP_SCOPES + (scopes.POST_ATTENTION_NORM, scopes.POST_FFN_NORM))
+def test_each_scope_of_the_loop_is_on_the_step_in_both_passes(looped_hlo, scope):
+    names = [n for n in every_op_name(looped_hlo) if re.search(rf"[/(]{scope}[/)]", n)]
+    assert any("jvp(" in n and "transpose(" not in n for n in names), scope
+    assert any("transpose(jvp(" in n for n in names), scope
+
+
+def test_the_walks_hold_the_layer_scan_the_final_norm_and_the_gate_and_the_exit_loss_sits_in_head_loss(looped_hlo, looped_rules):
+    names = every_op_name(looped_hlo)
+    walk = "GPT2Module._walks/loop/while/body/closed_call/GPT2Module.walk/"  # Flax names the method and the walk's function
+    inside, back = "/jvp(GPT2Module)/" + walk, "/transpose(jvp(GPT2Module))/" + walk
+    assert any(inside + "layer_carry/while/body/closed_call/blocks/block/mlp/W/" in n for n in names)
+    assert any(back + "layer_carry/while/body/closed_call/blocks/blocks/checkpoint/block/mlp/W/" in n for n in names)
+    assert any(inside + "lm_head_norm/" in n for n in names) and any(inside + "exit_gate/" in n for n in names)
+    assert any("/jvp(head_loss)/exit_loss/" in n for n in names) and any("transpose(jvp(head_loss))/exit_loss/" in n for n in names)
+    # what no walk changes (rotary tables, the causal mask) is hoisted out of the loop; a block's kernels are used inside it alone
+    assert not any(re.search(r"/blocks/.*/(attn/[qkv]_attn|mlp/(W|V|W_2))/", n) and "/loop/" not in n for n in names)
+    # the final norm inside the loop is a norm, not the head's; the gate is its own bucket
+    component = looped_rules["component"]
+    assert bucket_of(inside + "lm_head_norm/mul", component) == "norms" and bucket_of(inside + "exit_gate/dot_general", component) == "exit_gate"
+    assert bucket_of(inside + "layer_carry/while/body/closed_call/blocks/blocks/checkpoint/block/post_ffn_norm/mul", component) == "norms"
+    assert re.search(r"LOOP\s+loop", scopes.__doc__) and re.search(r"EXIT_LOSS\s+exit_loss", scopes.__doc__)
+
+
 # ------------------------------------------------------------------ (b) only metadata
 
 
