@@ -42,6 +42,7 @@ from modalities_tpu.models.gpt2.mla import LatentAttention, MLAConfig, MLASpec
 from modalities_tpu.models.gpt2.moe import BIAS_LEAF, COUNTERS, EXPERT_LOAD, MoE, MoEConfig, MoESpec, ffn_kinds, update_selection_bias
 from modalities_tpu.models.gpt2.ssm import MambaMixer, SSMConfig, SSMSpec, layer_kinds, layer_runs
 from modalities_tpu.models.model import NNModel
+from modalities_tpu.ops.embedding import embedding_lookup
 from modalities_tpu.telemetry import get_active_telemetry, scopes
 
 
@@ -1277,7 +1278,7 @@ class GPT2Module(nn.Module):
         # at scale that all-gathers [B,S,E] per step instead of the [V,E] table
         with jax.named_scope(scopes.WTE):
             wte_lookup = with_logical_constraint(wte, ("vocab", "embed_lookup"), explicit=True)
-            x = jnp.take(wte_lookup, input_ids, axis=0).astype(compute_dtype)
+            x = embedding_lookup(wte_lookup, input_ids).astype(compute_dtype)
             x = with_logical_constraint(x, ("batch", "seq", "embed"))
         if spec.poe_type == PositionTypes.ABSOLUTE.value:
             wpe = self.param(
@@ -1893,7 +1894,7 @@ class GPT2LLM(NNModel):
         def embed(shared, tokens, rng):
             p = shared["params"]
             with jax.named_scope(scopes.WTE):
-                x = jnp.take(p["wte"], tokens, axis=0).astype(compute_dtype)
+                x = embedding_lookup(p["wte"], tokens).astype(compute_dtype)
             if spec.poe_type == PositionTypes.ABSOLUTE.value:
                 # tokens are a LOCAL seq chunk under cp: slice wpe at the global offset
                 offset = cp_shard_offset(cp_axis, tokens.shape[1])

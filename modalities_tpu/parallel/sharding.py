@@ -186,6 +186,22 @@ def constrain_activation(x, logical_axes, explicit: bool = False):
         return x
 
 
+def shard_shape(shape: tuple, logical_axes: tuple) -> tuple[int, ...]:
+    """`shape` as one shard holds it under the rules and mesh the step installed (a mesh axis
+    that does not divide a dim leaves it whole, as `fit_spec_to_shape` has it); `shape` itself
+    with no rules installed or inside a manual region, where values are per shard already. For
+    code that plans by what a device will run, which under GSPMD it cannot see while tracing."""
+    from modalities_tpu.parallel.jax_compat import manual_axes
+
+    state = getattr(_ACTIVATION_RULES, "state", None)
+    if not state or manual_axes():
+        return tuple(shape)
+    rules, mesh = state
+    spec = fit_spec_to_shape(logical_to_mesh_spec(tuple(logical_axes), rules), shape, mesh)
+    shards = lambda entry: int(np.prod([mesh.shape[n] for n in (entry if isinstance(entry, tuple) else (entry,))]))  # noqa: E731
+    return tuple(dim if entry is None else dim // shards(entry) for dim, entry in zip(shape, spec))
+
+
 def per_shard(fn, in_logical, out_logical):
     """`fn(axes, *arrays)` run once per shard of the ambient mesh.
 

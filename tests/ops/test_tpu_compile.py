@@ -17,6 +17,7 @@ import jax.numpy as jnp
 import pytest
 from jax.sharding import SingleDeviceSharding
 
+from modalities_tpu.ops.embedding import embedding_lookup, grad_plan
 from modalities_tpu.ops.pallas.flash_attention import (
     flash_bwd_dkv,
     flash_bwd_dq,
@@ -27,6 +28,7 @@ from modalities_tpu.ops.pallas.fused_ce import fused_ce_rows, fused_ce_sum_and_c
 from modalities_tpu.ops.pallas.fused_rmsnorm import fused_rms_norm
 from modalities_tpu.ops.pallas.quant_matmul import quant_matmul
 from modalities_tpu.ops.pallas.selective_scan import pallas_selective_scan
+from tests.telemetry.test_scopes import without_metadata
 
 BF16, F32, VOCAB, SEQ = jnp.bfloat16, jnp.float32, 50304, 4096
 
@@ -185,6 +187,38 @@ def test_kernel_compiles_for_v5e(v5e, case):
         assert sorted(re.search(r"(\w+)\)*/pallas_call", line).group(1) for line in calls) == sorted(kernels)
     else:
         assert len(calls) == kernels
+
+
+# batch, sequence, n_embd, vocabulary of each cell's lookup (benchmark/configs/*/train.yaml)
+LOOKUPS = {
+    "train-2p7b-4k": (2, SEQ, 2560, VOCAB),
+    "train-jamba2-3b-4k": (1, SEQ, 2560, 32768),
+    "train-ouro-2p6b-4k": (1, SEQ, 2048, 49152),
+    "train-kanana2-30b-8k": (2, 8192, 2048, 16128),
+}
+
+
+@pytest.mark.parametrize("cell", sorted(LOOKUPS))
+def test_embedding_gradient_follows_the_compilers_switch(v5e, cell):
+    """The compiler sorts a scatter's indices when they number more than an eighth of the operand's rows, which in HBM
+    costs 2.4 us a row (ops/embedding.py). Where the rule cuts the lookup into pieces, here the dense cell, the compiled
+    gradient holds the plan's scatters and no sort; elsewhere it is the program `jax.grad` of `jnp.take` compiles to. A
+    libtpu that moves the switch fails here instead of silently costing the dense cell 17 ms a step."""
+    batch, seq, n_embd, vocab = LOOKUPS[cell]
+    chip = SingleDeviceSharding(v5e[0])
+    args = [jax.ShapeDtypeStruct(shape, dtype, sharding=chip)
+            for shape, dtype in (((vocab, n_embd), BF16), ((batch, seq), jnp.int32), ((batch, seq, n_embd), BF16))]
+    compiled = lambda lookup: jax.jit(jax.grad(  # noqa: E731
+        lambda table, ids, weights: (lookup(table, ids) * weights).astype(F32).sum())).lower(*args).compile().as_text()
+    text, plan = compiled(embedding_lookup), grad_plan((batch, seq), vocab, n_embd, 2)
+    if plan["form"] == "chunked":
+        assert cell == "train-2p7b-4k" and plan["rows_per_chunk"] <= vocab // 8
+        assert len(re.findall(r" scatter\(", text)) == plan["chunks"] and " sort(" not in text
+        assert " sort(" in compiled(lambda table, ids: jnp.take(table, ids, axis=0)), "the default the rule avoids"
+    else:
+        unnumbered = lambda text: re.sub(r"([A-Za-z_][\w\-]*)\.\d+", r"\1", without_metadata(text))  # noqa: E731  `%add.5` against `%add.3`
+        assert unnumbered(text) == unnumbered(compiled(lambda table, ids: jnp.take(table, ids, axis=0)))
+        assert (" sort(" in text) == (plan["rows"] > vocab // 8)
 
 
 def test_two_layer_model_forward_backward_compiles_for_v5e(v5e, monkeypatch):
