@@ -11,4 +11,10 @@ preserved as the user-facing API (reference: src/modalities/config/component_fac
 src/modalities/registry/components.py).
 """
 
+import time as _time
+
+# The origin of the process's own timeline (telemetry/spans.PROCESS_LOG): the first line
+# the package runs, on the clock every span, compile stamp and goodput ledger uses.
+IMPORTED_AT = _time.perf_counter()
+
 __version__ = "0.1.0"

@@ -26,7 +26,7 @@ from modalities_tpu.logging_broker.messages import MessageTypes
 from modalities_tpu.logging_broker.publisher import MessagePublisher
 from modalities_tpu.registry.components import COMPONENTS
 from modalities_tpu.registry.registry import ComponentEntity, Registry
-from modalities_tpu.telemetry import Telemetry, set_active_telemetry
+from modalities_tpu.telemetry import Telemetry, set_active_telemetry, span
 from modalities_tpu.trainer import Trainer
 from modalities_tpu.training.train_step import TrainStepBuilder
 from modalities_tpu.training.training_progress import TrainingProgress
@@ -65,7 +65,11 @@ class Main:
         )
 
     def build_components(self, components_model_type: Type = TrainingComponentsInstantiationModel):
-        return self.component_factory.build_components(self.config_dict, components_model_type)
+        # the whole config factory: model object, mesh, datasets, tokenizer. Spanned here,
+        # where the work is, so that every caller (the CLI, the benchmark's modes,
+        # `chip_smoke.py`) leaves it on the process's timeline
+        with span("build_components"):
+            return self.component_factory.build_components(self.config_dict, components_model_type)
 
     @staticmethod
     def build_step_functions(
@@ -73,23 +77,26 @@ class Main:
     ):
         """The one TrainStepBuilder assembled from the declarative components:
         what `run` trains with, what `validate_recipe` lowers with an abstract
-        state (`materialize=False`), and what `chip_smoke.py` times."""
+        state (`materialize=False`), and what `chip_smoke.py` times. The span `init`
+        is opened here and not round the call, so every caller gets it; the
+        builder's `state_init` is its child."""
         app_state_spec = components.app_state
         clipper = components.gradient_clipper
         resilience = getattr(components, "resilience", None)
-        return TrainStepBuilder(
-            model=app_state_spec.model,
-            loss_fn=components.loss_fn,
-            optimizer_spec=app_state_spec.optimizer,
-            scheduler_spec=app_state_spec.lr_scheduler,
-            mesh_handle=components.device_mesh,
-            gradient_acc_steps=components.settings.step_profile.gradient_accumulation_steps,
-            grad_clip_norm=getattr(clipper, "max_norm", None),
-            grad_clipper=clipper if hasattr(clipper, "build_transform") else None,
-            expose_grads=expose_grads,
-            anomaly_policy=resilience.anomaly_policy if resilience is not None else None,
-            stop_consensus=stop_consensus,
-        ).build(materialize=materialize)
+        with span("init"):
+            return TrainStepBuilder(
+                model=app_state_spec.model,
+                loss_fn=components.loss_fn,
+                optimizer_spec=app_state_spec.optimizer,
+                scheduler_spec=app_state_spec.lr_scheduler,
+                mesh_handle=components.device_mesh,
+                gradient_acc_steps=components.settings.step_profile.gradient_accumulation_steps,
+                grad_clip_norm=getattr(clipper, "max_norm", None),
+                grad_clipper=clipper if hasattr(clipper, "build_transform") else None,
+                expose_grads=expose_grads,
+                anomaly_policy=resilience.anomaly_policy if resilience is not None else None,
+                stop_consensus=stop_consensus,
+            ).build(materialize=materialize)
 
     def run(self, components: TrainingComponentsInstantiationModel) -> None:
         # telemetry is on by default: use the configured component when present,
@@ -205,25 +212,24 @@ class Main:
                     "and no experiments_root_path to derive one — debug stats are DISABLED"
                 )
 
-        with telemetry.span("init"):
-            step_functions = self.build_step_functions(
-                components,
-                expose_grads=debug_stats_logger is not None,
-                stop_consensus=consensus_enabled,
-            )
+        step_functions = self.build_step_functions(
+            components,
+            expose_grads=debug_stats_logger is not None,
+            stop_consensus=consensus_enabled,
+        )
 
-            if app_state_spec.checkpoint_dir_path is not None:
-                with telemetry.span("checkpoint_restore"):
-                    loader = app_state_spec.checkpoint_loading
-                    if loader is None:
-                        from modalities_tpu.checkpointing.orbax.orbax_checkpoint_loading import (
-                            OrbaxCheckpointLoading,
-                        )
-
-                        loader = OrbaxCheckpointLoading()
-                    loader.load_app_state(
-                        step_functions.app_state_handle, app_state_spec.checkpoint_dir_path
+        if app_state_spec.checkpoint_dir_path is not None:
+            with telemetry.span("checkpoint_restore"):
+                loader = app_state_spec.checkpoint_loading
+                if loader is None:
+                    from modalities_tpu.checkpointing.orbax.orbax_checkpoint_loading import (
+                        OrbaxCheckpointLoading,
                     )
+
+                    loader = OrbaxCheckpointLoading()
+                loader.load_app_state(
+                    step_functions.app_state_handle, app_state_spec.checkpoint_dir_path
+                )
 
         num_params = get_total_number_of_trainable_parameters(step_functions.app_state_handle.state)
         print_rank_0(f"experiment {self.experiment_id}: {num_params:,} trainable parameters")

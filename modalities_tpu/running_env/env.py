@@ -61,24 +61,30 @@ class TpuEnv:
     def __enter__(self) -> "TpuEnv":
         import jax
 
+        from modalities_tpu.telemetry import span
+
         configure_compilation_cache()
 
-        coordinator = os.environ.get("JAX_COORDINATOR_ADDRESS") or os.environ.get("COORDINATOR_ADDRESS")
-        num_processes = os.environ.get("JAX_NUM_PROCESSES") or os.environ.get("NNODES")
-        if coordinator and num_processes and int(num_processes) > 1:
-            jax.distributed.initialize(
-                coordinator_address=coordinator,
-                num_processes=int(num_processes),
-                process_id=int(os.environ.get("JAX_PROCESS_ID", os.environ.get("RANK", 0))),
-                initialization_timeout=self.timeout_s,
+        # the backend's own start (the coordinator's handshake, the first device list):
+        # no `Telemetry` is active yet, so the span goes to the process log and the
+        # run's instance takes it over when `Main.run` activates it
+        with span("backend_start"):
+            coordinator = os.environ.get("JAX_COORDINATOR_ADDRESS") or os.environ.get("COORDINATOR_ADDRESS")
+            num_processes = os.environ.get("JAX_NUM_PROCESSES") or os.environ.get("NNODES")
+            if coordinator and num_processes and int(num_processes) > 1:
+                jax.distributed.initialize(
+                    coordinator_address=coordinator,
+                    num_processes=int(num_processes),
+                    process_id=int(os.environ.get("JAX_PROCESS_ID", os.environ.get("RANK", 0))),
+                    initialization_timeout=self.timeout_s,
+                )
+                self._initialized_distributed = True
+            logger.info(
+                "TpuEnv: %d devices over %d processes (platform=%s)",
+                len(jax.devices()),
+                jax.process_count(),
+                jax.devices()[0].platform,
             )
-            self._initialized_distributed = True
-        logger.info(
-            "TpuEnv: %d devices over %d processes (platform=%s)",
-            len(jax.devices()),
-            jax.process_count(),
-            jax.devices()[0].platform,
-        )
         return self
 
     def __exit__(self, exc_type, exc_val, exc_tb) -> bool:

@@ -1,30 +1,47 @@
-"""Telemetry subsystem: step-aligned tracing, goodput ledger, hang watchdog, sink.
+"""Telemetry subsystem: the process's own timeline, goodput ledger, hang watchdog, sink.
 
-One `Telemetry` object per process composes the four parts:
+The process keeps one record whoever is listening: `spans.PROCESS_LOG` (every finished
+span, from the package's import on) and `compile_log.PROCESS_COMPILES` (every backend
+compile, from this package's import on), both bounded and both on
+`time.perf_counter()`. One `Telemetry` object per run composes the rest:
 
 - `spans.SpanRecorder` — host phases as spans doubling as profiler annotations
 - `goodput.GoodputLedger` — every wall second classified into a bucket
 - `watchdog.Watchdog` — per-step heartbeat; wedged step -> crash artifact
 - `sink.TelemetrySink` — per-rank always-flushed JSONL event stream
 
-Deep call sites (checkpointing, evaluator) use the module-level `span("name")`
-free function, which routes to the process-global active telemetry — no DI
-plumbing through every layer. `Main` constructs/activates the instance (it is a
-registry component, on by default); everything degrades to an allocation-free
-no-op when disabled, so library code never guards its telemetry calls.
+Deep call sites (the entry points' set-up, checkpointing, the evaluator, the serving
+engine) use the module-level `span("name")` free function, which routes to the
+process-global active telemetry, or, while none is active, to the process's own
+recorder: the span still lands in the log, and the next instance to become active
+takes it into its ledger and its sink (`set_active_telemetry`), with its wall clock
+set back to where the unaccounted stretch began. `Main` constructs/activates the
+instance (it is a registry component, on by default); an instance built with
+`enabled=False` is an allocation-free no-op, so library code never guards its
+telemetry calls.
 """
 
 from __future__ import annotations
 
 import os
+import statistics
 import tempfile
+import time
 from pathlib import Path
 from typing import Callable, Optional, Union
 
+from modalities_tpu.telemetry import compile_log
 from modalities_tpu.telemetry.goodput import BUCKETS, GoodputLedger
 from modalities_tpu.telemetry.metrics import MetricsRegistry
 from modalities_tpu.telemetry.sink import TelemetrySink
-from modalities_tpu.telemetry.spans import NULL_CONTEXT, SpanRecorder, step_trace_annotation
+from modalities_tpu.telemetry.spans import (
+    NULL_CONTEXT,
+    PROCESS_LOG,
+    PROCESS_RECORDER,
+    SpanRecord,
+    SpanRecorder,
+    step_trace_annotation,
+)
 from modalities_tpu.telemetry.watchdog import Watchdog
 from modalities_tpu.utils.logging import get_logger
 
@@ -81,10 +98,11 @@ class Telemetry:
         self.anomaly_window = int(anomaly_window)
         self._step_time_detector = None
         self._bucket_detectors: dict[str, object] = {}
-        # backend compiles, while this instance is the active one (compile_log.py);
-        # `_in_flight` is the step or scheduler round the watchdog calls announce
-        self._compile_log = None
+        # `_in_flight` is the step or scheduler round the watchdog calls announce: spans
+        # carry it, and so do the compiles forwarded while this instance is the active one
         self._in_flight: Optional[int] = None
+        # spans taken over from the process log (`_claim_process_log`) before the sink opened
+        self._claimed_before_sink: list[SpanRecord] = []
         self._emitted_once: set[tuple] = set()
         self._last_bucket_seconds: dict[str, float] = {}
         # optional SLO engine (PR 15): judged objectives over self.metrics;
@@ -131,6 +149,21 @@ class Telemetry:
         if self._sink is not None:
             self._sink.emit_span(record)
 
+    def _claim_process_log(self) -> None:
+        """This instance became the active one: its wall clock starts where the stretch
+        no instance accounted for began (the process log's origin for the first), and the
+        spans recorded since with nobody to hand them to (the entry point's
+        `backend_start` and `build_components`) count in its ledger and go to its sink,
+        now or when it opens."""
+        if not self.enabled:
+            return
+        since, records = PROCESS_LOG.claim()
+        self.ledger.start(at=min(self.ledger.origin, since))
+        for record in records:
+            self._on_record(record)
+            if self._sink is None:
+                self._claimed_before_sink.append(record)
+
     # ------------------------------------------------------------------- sink
 
     def set_output_folder(self, output_folder_path: Union[str, Path]) -> None:
@@ -140,6 +173,9 @@ class Telemetry:
             return
         self._folder = Path(output_folder_path)
         self._sink = TelemetrySink(self._folder, global_rank=self.global_rank)
+        for record in self._claimed_before_sink:
+            self._sink.emit_span(record)
+        self._claimed_before_sink.clear()
         if self._watchdog is not None:
             self._watchdog.artifact_dir = self._folder
 
@@ -193,8 +229,13 @@ class Telemetry:
             self._watchdog.start()
         return self._watchdog
 
-    def arm_watchdog(self, step_id: int, first_step: bool = False) -> None:
+    def _announce(self, step_id: int) -> None:
         self._in_flight = step_id
+        if self._recorder is not None:
+            self._recorder.step = step_id
+
+    def arm_watchdog(self, step_id: int, first_step: bool = False) -> None:
+        self._announce(step_id)
         watchdog = self._ensure_watchdog()
         if watchdog is None:
             return
@@ -202,7 +243,7 @@ class Telemetry:
         watchdog.arm(step_id, deadline_s=deadline_s)
 
     def beat_watchdog(self, step_id: int) -> None:
-        self._in_flight = step_id + 1  # `step_id` is done: what compiles now belongs to the next
+        self._announce(step_id + 1)  # `step_id` is done: what opens or compiles now belongs to the next
         if self._watchdog is not None:
             self._watchdog.beat(step_id)
 
@@ -224,21 +265,8 @@ class Telemetry:
 
     # --------------------------------------------------------------- compiles
 
-    def watch_compiles(self) -> None:
-        """Count this process's backend compiles from now on (`set_active_telemetry`
-        calls it for the instance it installs, and `unwatch_compiles` for the one it
-        replaces, so one instance listens at a time)."""
-        if self.enabled and self._compile_log is None:
-            from modalities_tpu.telemetry.compile_log import CompileLog
-
-            self._compile_log = CompileLog(on_compile=self._on_compile)
-
-    def unwatch_compiles(self) -> None:
-        if self._compile_log is not None:
-            self._compile_log.close()
-            self._compile_log = None
-
     def _on_compile(self, function: str, seconds: float, cache_hit: bool) -> None:
+        """One backend compile, as the process's record forwards it to the active instance."""
         hit = "true" if cache_hit else "false"
         self.metrics.counter(
             "compile_total", "Backend compiles of this process, by whether the persistent cache answered"
@@ -248,7 +276,8 @@ class Telemetry:
         ).inc(seconds, cache_hit=hit)
         if self._sink is not None:
             self._sink.emit({"event": "compile", "function": function, "seconds": round(seconds, 6),
-                             "cache_hit": cache_hit, "step": self._in_flight})
+                             "cache_hit": cache_hit, "step": self._in_flight,
+                             "end_s": round(time.perf_counter() - PROCESS_LOG.origin, 6)})
 
     # ---------------------------------------------------------------- goodput
 
@@ -331,11 +360,17 @@ class Telemetry:
             window=self.anomaly_window, zscore_threshold=self.anomaly_zscore
         )
 
-    def observe_step_time(self, seconds: float, step_id: Optional[int] = None) -> None:
+    def observe_step_time(
+        self, seconds: float, step_id: Optional[int] = None, window: Optional[tuple[float, float]] = None
+    ) -> None:
         """Feed one step's wall time through the rolling robust-z detector
         (PR 13). An anomalous step bumps `training_step_time_anomaly_total`,
         the live z/EWMA land on gauges, and the sink gets an `anomaly/step_time`
-        event the analyze CLI can line up against the goodput buckets."""
+        event the analyze CLI can line up against the goodput buckets. `window` is the
+        stretch of `time.perf_counter()` the time was taken over (one step, or an
+        interval of several): the event then says what the loop's thread was in while
+        it lasted, `split_s` by outermost span with `unspanned` for the rest
+        (`spans.PROCESS_LOG.split`), so that a step of seconds names its cause."""
         if not self.enabled:
             return
         if self._step_time_detector is None:
@@ -353,11 +388,21 @@ class Telemetry:
                 "training_step_time_anomaly_total",
                 "Steps whose wall time scored over the anomaly z-score threshold",
             ).inc()
-            self.emit_event(
-                "anomaly/step_time",
-                {"step_id": step_id, "seconds": round(seconds, 6),
-                 "zscore": round(z, 3), "ewma_s": round(verdict.ewma, 6)},
-            )
+            payload = {"step_id": step_id, "seconds": round(seconds, 6),
+                       "zscore": round(z, 3), "ewma_s": round(verdict.ewma, 6)}
+            if window is not None:
+                payload["window_s"] = round(window[1] - window[0], 6)
+                payload["split_s"] = {name: round(held, 6) for name, held in PROCESS_LOG.split(*window).items()}
+                # a device's steps are alike to a ten-thousandth, so z passes the threshold on a step a
+                # millisecond late: the log gets only the steps worth a look, the sink every one
+                usual = statistics.median(self._step_time_detector.window)
+                if seconds > 2.0 * usual:
+                    logger.warning(
+                        "step %s took %.3f s, %.1f times the usual %.3f s; where the loop's thread was meanwhile: %s",
+                        step_id, seconds, seconds / usual, usual,
+                        ", ".join(f"{name} {held:.3f} s" for name, held in sorted(payload["split_s"].items(), key=lambda kv: -kv[1])),
+                    )
+            self.emit_event("anomaly/step_time", payload)
 
     def _observe_bucket_deltas(self, bucket_seconds: dict) -> None:
         """Per-publish goodput-bucket deltas through per-bucket detectors: a
@@ -445,7 +490,6 @@ class Telemetry:
         safe on the exception path."""
         if self.slo_engine is not None:
             self.slo_engine.stop()
-        self.unwatch_compiles()
         if self._watchdog is not None:
             self._watchdog.stop()
         if self._sink is not None:
@@ -469,12 +513,18 @@ def set_active_telemetry(telemetry: Optional[Telemetry]) -> Telemetry:
     previous = _active
     _active = telemetry if telemetry is not None else NOOP_TELEMETRY
     if previous is not _active:
-        previous.unwatch_compiles()
-        _active.watch_compiles()
+        if previous.enabled:
+            PROCESS_LOG.release()
+        compile_log.forward_to(_active._on_compile if _active.enabled else None)
+        _active._claim_process_log()
     return previous
 
 
 def span(name: str):
     """`with span("checkpoint_save"): ...` against the active telemetry — the
-    zero-plumbing entry point for deep call sites."""
+    zero-plumbing entry point for deep call sites. While none is active the span is
+    recorded all the same, by the process's own recorder: it lands in
+    `spans.PROCESS_LOG`, and the next instance to become active takes it over."""
+    if _active is NOOP_TELEMETRY:
+        return PROCESS_RECORDER.span(name)
     return _active.span(name)

@@ -2,8 +2,9 @@
 
 Buckets (cf. the MPMD-pipeline paper's bubble/stall attribution in PAPERS.md):
 
-- ``init``               component build, state init, checkpoint restore
-- ``compile_first_step`` the first train step of the run (jit trace + compile)
+- ``init``               backend start, component build, state init, checkpoint restore
+- ``compile_first_step`` the preflight's AOT compile of the step and the first train
+                         step of the run (jit trace + compile or cache load)
 - ``train_step``         step dispatch + the device-execution wait when interval
                          metrics are fetched — the *goodput* numerator
 - ``data_stall``         the step loop blocked waiting for a host batch
@@ -22,7 +23,10 @@ remainder into ``other``, which makes "bucket seconds sum to wall time" hold by
 construction — the interesting signal is how small ``other`` is.
 
 ``goodput_pct`` = 100 * train_step / wall: the fraction of the run the devices
-spent advancing the model.
+spent advancing the model. The wall clock of the ledger of an ACTIVE `Telemetry` starts
+where the process's span log does (`spans.PROCESS_LOG`: the package's import), or where
+the instance active before it stepped down, and takes the timeline spans recorded since
+with no instance to hand them to: the set-up is part of the run it is charged to.
 """
 
 from __future__ import annotations
@@ -48,28 +52,25 @@ BUCKETS = (
     "other",
 )
 
-# span name (first path segment) -> bucket
+# span name (first path segment) -> bucket: a name is here only if a call site opens
+# a span of it (tests/telemetry/test_spans_goodput.py finds them in the source)
 _NAME_TO_BUCKET = {
-    "init": "init",
+    "backend_start": "init",
     "build_components": "init",
+    "init": "init",
     "state_init": "init",
     "checkpoint_restore": "init",
+    "preflight_memscope": "compile_first_step",
     "first_step": "compile_first_step",
     "train_step": "train_step",
     "metrics_fetch": "train_step",
     "data_wait": "data_stall",
     "eval": "eval",
-    "checkpoint": "checkpoint",
     "checkpoint_save": "checkpoint",
     "checkpoint_drain": "checkpoint",
     "publish": "publish",
     "preempt": "recovery",
     "ckpt_retry": "recovery",
-    "anomaly": "recovery",
-    "rollback": "recovery",
-    "recovery": "recovery",
-    "heartbeat": "recovery",
-    "consensus": "recovery",
     # serving engine (serving/engine.py): "serve/prefill", "serve/decode",
     # "serve/admission" all land in one bucket — decode-step seconds over total
     # serve seconds is the engine's goodput
@@ -92,9 +93,13 @@ class GoodputLedger:
         self._seconds = {bucket: 0.0 for bucket in BUCKETS}
         self._t0 = time.perf_counter()
 
-    def start(self) -> None:
-        """(Re)set the wall-clock origin used by `wall_s()`."""
-        self._t0 = time.perf_counter()
+    def start(self, at: Optional[float] = None) -> None:
+        """(Re)set the wall-clock origin used by `wall_s()`: now, or `at` on `time.perf_counter()`."""
+        self._t0 = time.perf_counter() if at is None else at
+
+    @property
+    def origin(self) -> float:
+        return self._t0
 
     def wall_s(self) -> float:
         return time.perf_counter() - self._t0

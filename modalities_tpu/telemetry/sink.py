@@ -15,7 +15,7 @@ import time
 from pathlib import Path
 from typing import Optional
 
-from modalities_tpu.telemetry.spans import SpanRecord
+from modalities_tpu.telemetry.spans import PROCESS_LOG, SpanRecord
 
 
 class TelemetrySink:
@@ -41,10 +41,15 @@ class TelemetrySink:
                 "event": "span",
                 "name": record.name,
                 "ts": round(record.ts, 6),
+                # the same start in seconds since the process log's origin: the clock of the
+                # `compile` events' `end_s` and of the run summary's `wall_s`
+                "start_s": round(record.t0 - PROCESS_LOG.origin, 6),
                 "dur_s": round(record.dur_s, 6),
                 "self_s": round(record.self_s, 6),
                 "thread": record.thread,
                 "timeline": record.timeline,
+                "parent": record.parent,
+                "step": record.step,
             }
         )
 

@@ -130,17 +130,23 @@ class Trainer:
         # process exits at the same step boundary (resilience/coordination.py)
         self.stop_consensus = stop_consensus
         self._boundary_stall_s = 0.0
+        self._first_interval = True
 
     def _telemetry(self) -> Telemetry:
         return self.telemetry if self.telemetry is not None else get_active_telemetry()
 
     @staticmethod
-    def _preflight_memscope(step_functions: StepFunctions, device_batch) -> Optional[dict]:
+    def _preflight_memscope(
+        step_functions: StepFunctions, device_batch, telemetry: Optional[Telemetry] = None
+    ) -> Optional[dict]:
         """Static memscope report + fits-check before the first dispatch. Only
         runs where it can act: a backend with a bytes_limit (TPU) and a check
         mode other than off — on CPU this is a no-op, so e2e tests pay nothing.
         A FitsCheckFailure propagates (fail-fast is the point); any other
-        failure degrades to 'no static report', never a dead run."""
+        failure degrades to 'no static report', never a dead run. Where it runs it
+        is the span `preflight_memscope`: `memscope_report` compiles the step ahead
+        of time (`lower_train_step(...).compile()`), before the first dispatch
+        compiles or loads the `jit` path's executable inside `first_step`."""
         from modalities_tpu.telemetry.memscope import FITS_CHECK_ENV
 
         mode = (os.environ.get(FITS_CHECK_ENV) or "fail").strip().lower()
@@ -150,13 +156,15 @@ class Trainer:
             or min_bytes_limit() is None
         ):
             return None
-        try:
-            report = step_functions.memscope_report(device_batch)
-        except Exception:
-            logger.exception("memscope: static report failed; fits-check skipped")
-            return None
-        preflight_fits_check(report)
-        return report
+        telemetry = telemetry if telemetry is not None else get_active_telemetry()
+        with telemetry.span("preflight_memscope"):
+            try:
+                report = step_functions.memscope_report(device_batch)
+            except Exception:
+                logger.exception("memscope: static report failed; fits-check skipped")
+                return None
+            preflight_fits_check(report)
+            return report
 
     def train(
         self,
@@ -185,6 +193,9 @@ class Trainer:
         step_id = self.num_seen_train_steps
         target_steps = training_progress.num_target_steps
         self._boundary_stall_s = 0.0
+        # the first published interval holds the run's first step (trace + compile):
+        # `_publish_interval` keeps it from the step-time detector
+        self._first_interval = True
         exhausted = False
 
         # --- stop-flag consensus state: each dispatch carries this process's
@@ -269,10 +280,9 @@ class Trainer:
                     # over-budget run fails here with levers named instead of
                     # dying inside XLA allocation. CPU (no limit): skipped.
                     fits_checked = True
-                    memscope_static = self._preflight_memscope(step_functions, device_batch)
+                    memscope_static = self._preflight_memscope(step_functions, device_batch, telemetry)
                     if memscope_static is not None:
                         telemetry.publish_memscope_report(memscope_static, executable="train_step")
-                step_t0 = time.perf_counter()
                 try:
                     fire_oom_if_armed(step_id + 1)  # chaos: oom@N
                     with telemetry.step_annotation(step_id + 1):
@@ -294,11 +304,6 @@ class Trainer:
                             metrics_snapshot=telemetry.metrics.snapshot(),
                         ) from e
                     raise
-                # host-side dispatch time: in steady state the dispatch queue's
-                # backpressure makes this track device step time — feed the rolling
-                # anomaly detector (compile-dominated first step excluded)
-                if step_id != first_step_id:
-                    telemetry.observe_step_time(time.perf_counter() - step_t0, step_id=step_id + 1)
                 debug_grads = metrics.pop("grads", None)  # exposed only when debugging
                 decided = VOTE_CONTINUE
                 if consensus:
@@ -576,6 +581,16 @@ class Trainer:
             }
         fetch_done = time.perf_counter()
         wall_elapsed = max(fetch_done - interval_start, 1e-9)
+        # the step-time detector is fed the step as the device paces it: the time from
+        # the return of the last interval's fetch to the return of this one's, over the
+        # interval's steps. (The dispatch alone returns in a millisecond, two steps are
+        # in flight at most, and the wait for the device lives in `metrics_fetch`.)
+        if self._first_interval:
+            self._first_interval = False
+        else:
+            telemetry.observe_step_time(
+                wall_elapsed / len(pending_metrics), step_id=step_id, window=(interval_start, fetch_done)
+            )
         host_stall_s = feed.take_stall_s() if feed is not None else 0.0
         boundary_stall_s, self._boundary_stall_s = self._boundary_stall_s, 0.0
         device_elapsed = max(wall_elapsed - host_stall_s - boundary_stall_s, 1e-9)

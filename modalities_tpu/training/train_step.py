@@ -33,7 +33,7 @@ from modalities_tpu.parallel.sharding import (
     zero_params_shardings,
 )
 from modalities_tpu.running_env.device_mesh import DeviceMeshHandle
-from modalities_tpu.telemetry import scopes
+from modalities_tpu.telemetry import scopes, span
 from modalities_tpu.utils.logging import get_logger
 
 logger = get_logger(__name__)
@@ -389,29 +389,32 @@ class TrainStepBuilder:
                 params = routine.initialize_in_place(params, jax.random.fold_in(r, 1000 + i))
             return AppState(params=params, opt_state=tx.init(params), step=jnp.zeros((), jnp.int32))
 
-        if mesh_handle is not None:
-            abstract_state = jax.eval_shape(init_state, rng)
-            param_treedef = jax.tree.structure(abstract_state.params)
-            opt_shardings = _substitute_param_subtrees(
-                abstract_state.opt_state,
-                param_treedef,
-                zero_grad_shardings if zero_active else param_shardings,
-                replicated_sharding,
-            )
-            state_shardings = AppState(
-                params=param_shardings, opt_state=opt_shardings, step=replicated_sharding
-            )
-            if materialize:
-                with mesh:
-                    state = jax.jit(init_state, out_shardings=state_shardings)(rng)
+        # the state's shapes and, where it is materialized, its jitted init: a span of
+        # its own inside the caller's `init` (the init program's compile falls in it)
+        with span("state_init"):
+            if mesh_handle is not None:
+                abstract_state = jax.eval_shape(init_state, rng)
+                param_treedef = jax.tree.structure(abstract_state.params)
+                opt_shardings = _substitute_param_subtrees(
+                    abstract_state.opt_state,
+                    param_treedef,
+                    zero_grad_shardings if zero_active else param_shardings,
+                    replicated_sharding,
+                )
+                state_shardings = AppState(
+                    params=param_shardings, opt_state=opt_shardings, step=replicated_sharding
+                )
+                if materialize:
+                    with mesh:
+                        state = jax.jit(init_state, out_shardings=state_shardings)(rng)
+                else:
+                    state = abstract_state
             else:
-                state = abstract_state
-        else:
-            state_shardings = None
-            if materialize:
-                state = jax.jit(init_state)(rng)
-            else:
-                state = jax.eval_shape(init_state, rng)
+                state_shardings = None
+                if materialize:
+                    state = jax.jit(init_state)(rng)
+                else:
+                    state = jax.eval_shape(init_state, rng)
 
         logger.info(
             "%s AppState: %d params",

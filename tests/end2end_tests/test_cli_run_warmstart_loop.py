@@ -59,6 +59,34 @@ def _train_lines(workdir, exclude=()):
     return dirs[0].name, [r for r in lines if r["dataloader_tag"] == "train"]
 
 
+def _timeline(workdir, experiment_id):
+    """The run's sink and goodput summary (PR 34: the timeline from process start)."""
+    folder = workdir / "data" / "experiments" / experiment_id / "telemetry"
+    events = [json.loads(line) for line in (folder / "telemetry_rank_0.jsonl").read_text().splitlines()]
+    return events, json.loads((folder / "goodput_summary.json").read_text())
+
+
+def _assert_timeline_starts_with_the_process(events, summary, restored: bool):
+    spans = [e for e in events if e["event"] == "span"]
+    # the set-up happened before `Main.run` made the run's Telemetry active: its spans came from the process log
+    assert [e["name"] for e in spans[:2]] == ["backend_start", "build_components"]
+    assert all(e["timeline"] and e["parent"] is None and e["step"] is None for e in spans[:2])
+    by_name = {e["name"]: e for e in reversed(spans)}  # the first of each name
+    assert by_name["state_init"]["parent"] == "init" and by_name["first_step"]["step"] >= 1
+    assert ("checkpoint_restore" in by_name) == restored
+    # `start_s` counts from the process log's origin, the package's import: the entry point's spans open within
+    # seconds of it and in order, and the ledger's wall clock covers them all
+    starts = [by_name[name]["start_s"] for name in ("backend_start", "build_components", "init", "first_step")]
+    assert 0 < starts[0] and starts == sorted(starts)
+    last_end = max(e["start_s"] + e["dur_s"] for e in spans)
+    assert last_end <= summary["wall_s"] <= last_end + 5.0, "wall_s starts at the process log's origin"
+    setup = sum(by_name[name]["self_s"] for name in ("backend_start", "build_components"))
+    assert summary["buckets"]["init"] >= setup + by_name["init"]["dur_s"] - 1e-3
+    assert sum(summary["buckets"].values()) == pytest.approx(summary["wall_s"], abs=1e-3)
+    compiles = [e for e in events if e["event"] == "compile"]
+    assert compiles and all(0 < e["end_s"] <= summary["wall_s"] for e in compiles)
+
+
 def test_cli_run_then_warmstart_subprocess_loop(workdir):
     _cli(
         ["run", "--config_file_path", str(RUN_CONFIG),
@@ -67,6 +95,7 @@ def test_cli_run_then_warmstart_subprocess_loop(workdir):
     )
     eid1, train = _train_lines(workdir)
     assert train[-1]["num_train_steps_done"] == 8
+    _assert_timeline_starts_with_the_process(*_timeline(workdir, eid1), restored=False)
     info_path = workdir / "data" / "checkpoints" / "last_checkpoint_info.json"
     info = json.loads(info_path.read_text())
     assert "seen_steps_8-" in info["checkpoint_folder_path"]
@@ -77,7 +106,8 @@ def test_cli_run_then_warmstart_subprocess_loop(workdir):
          "--experiments_root_path", str(workdir / "data" / "experiments")],
         cwd=workdir,
     )
-    _, train2 = _train_lines(workdir, exclude=(eid1,))
+    eid2, train2 = _train_lines(workdir, exclude=(eid1,))
+    _assert_timeline_starts_with_the_process(*_timeline(workdir, eid2), restored=True)
     assert train2[0]["num_train_steps_done"] > 8, "warmstart restarted instead of resuming"
     assert train2[-1]["num_train_steps_done"] == 12
     assert all(np.isfinite(r["losses"]["train loss avg"]) for r in train2)

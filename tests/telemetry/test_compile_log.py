@@ -63,13 +63,47 @@ def test_active_telemetry_counts_compiles_and_names_the_step_in_flight(tmp_path)
     assert get_active_telemetry() is previous
 
 
-def test_a_disabled_telemetry_listens_to_nothing():
-    before = len(jax.monitoring.get_event_duration_listeners()) if hasattr(jax.monitoring, "get_event_duration_listeners") else None
+def test_a_disabled_telemetry_listens_to_nothing_and_is_forwarded_nothing():
+    listeners = getattr(jax.monitoring, "get_event_duration_listeners", None)
+    before = len(listeners()) if listeners is not None else None
     quiet = Telemetry(enabled=False)
-    quiet.watch_compiles()
-    assert quiet._compile_log is None
-    if before is not None:
-        assert len(jax.monitoring.get_event_duration_listeners()) == before
+    previous = set_active_telemetry(quiet)
+    try:
+        jax.monitoring.record_event_duration_secs(BACKEND_COMPILE, 0.5, fun_name="unheard")
+    finally:
+        set_active_telemetry(previous)
+    assert quiet.metrics.counter("compile_total", "").value(cache_hit="false") == 0
+    if before is not None:  # the process's record listens once, whoever is active
+        assert len(listeners()) == before
+
+
+def test_the_process_record_holds_a_compile_made_before_any_instance(tmp_path):
+    """`PROCESS_COMPILES` listens from the package's import on: a compile made with no
+    `Telemetry` anywhere is there with its stamp on `time.perf_counter()`, and an instance
+    made later is forwarded the ones that follow, not that one."""
+    import time
+
+    from modalities_tpu.telemetry.compile_log import PROCESS_COMPILES
+    from modalities_tpu.telemetry.spans import PROCESS_LOG
+
+    assert get_active_telemetry().enabled is False
+    t0 = time.perf_counter()
+    compile_something_new(0.977)
+    jax.monitoring.record_event(CACHE_HIT)
+    jax.monitoring.record_event_duration_secs(BACKEND_COMPILE, 0.25, fun_name="early_step")
+    t1 = time.perf_counter()
+    early = [c for c in PROCESS_COMPILES if t0 <= c.at <= t1]
+    assert early[-1] == (early[-1].at, "early_step", 0.25, True) and early[-1].at >= PROCESS_LOG.origin
+    assert any("lambda" in c.function and not c.cache_hit and c.seconds > 0 for c in early[:-1])
+    telemetry = Telemetry(output_folder_path=tmp_path, watchdog_deadline_s=0)
+    previous = set_active_telemetry(telemetry)
+    try:
+        jax.monitoring.record_event_duration_secs(BACKEND_COMPILE, 1.5, fun_name="later_step")
+    finally:
+        set_active_telemetry(previous)
+    assert [e["function"] for e in events_of(telemetry)] == ["later_step"]
+    assert PROCESS_COMPILES[-1][1:] == ("later_step", 1.5, False)
+    assert 0 < events_of(telemetry)[0]["end_s"] == pytest.approx(PROCESS_COMPILES[-1].at - PROCESS_LOG.origin, abs=0.05)
 
 
 def test_flash_tile_plan_is_one_event_per_traced_shape_and_none_per_step(tmp_path, monkeypatch):

@@ -230,3 +230,95 @@ def test_watchdog_joins_on_training_exception(tmp_path):
     # the sink survived the crash path with its record sealed
     events = [json.loads(ln) for ln in telemetry.sink_path.read_text().splitlines()]
     assert events[-1]["event"] == "run_summary"
+
+
+# --------------------------------------------- PR 34: the step-time detector is fed the step
+
+
+class _SlowToFetch:
+    """A metric that stands for a device array not yet computed: reading it waits."""
+
+    def __init__(self, value: float, seconds: float):
+        self.value, self.seconds = value, seconds
+
+    def __float__(self) -> float:
+        time.sleep(self.seconds)
+        return self.value
+
+
+class _StallingLoader(_FakeTrainLoader):
+    """Hands out its batches, the one at `stall_at` only after `seconds`."""
+
+    def __init__(self, batches, stall_at: int, seconds: float):
+        super().__init__(batches)
+        self.stall_at, self.seconds = stall_at, seconds
+
+    def __iter__(self):
+        for i, batch in enumerate(self._batches):
+            if i == self.stall_at:
+                time.sleep(self.seconds)
+            yield batch
+
+
+@pytest.mark.parametrize("where", ["metrics_fetch", "data_wait"])
+def test_a_stalled_step_raises_the_anomaly_and_names_the_span_that_held_the_excess(tmp_path, where, caplog):
+    """`observe_step_time` gets the time between the returns of consecutive fetches, which
+    is the step as the device (here: a sleeping double) paces it, and not the dispatch,
+    which returns at once. A step that waits 0.4 s for its metrics, or for its batch, among
+    steps of 15 ms scores as `anomaly/step_time`, and the event's split says where the
+    loop's thread was."""
+    from modalities_tpu.dataloader.device_feeder import DeviceFeeder
+
+    n_steps, stalled_step, stall_s, step_s = 16, 13, 0.4, 0.015
+    telemetry = Telemetry(output_folder_path=tmp_path, watchdog_deadline_s=0, anomaly_window=32)
+    observed = []
+    feed_detector = telemetry.observe_step_time
+    telemetry.observe_step_time = lambda seconds, **kw: (observed.append((kw["step_id"], seconds)), feed_detector(seconds, **kw))[1]
+    calls = [0]
+
+    def fake_train_step(state, batch):
+        calls[0] += 1
+        slow = where == "metrics_fetch" and calls[0] == stalled_step
+        return state + 1, {"loss": _SlowToFetch(1.0, stall_s if slow else step_s), "grad_norm": 0.5, "lr": 1e-3}
+
+    fns = SimpleNamespace(app_state_handle=SimpleNamespace(state=0), train_step=fake_train_step,
+                          put_batch=lambda batch, has_acc_dim=True: batch, train_step_debug=None)
+    batches = list(_microbatches(n_steps))
+    loader = _StallingLoader(batches, stalled_step - 1, stall_s) if where == "data_wait" else _FakeTrainLoader(batches)
+    broker = MessageBroker()
+    pub = MessagePublisher(broker)
+    trainer = Trainer(progress_publisher=pub, evaluation_result_publisher=pub, gradient_acc_steps=1,
+                      global_num_tokens_per_train_step=128, training_log_interval_in_steps=1, gc_frequency=0,
+                      telemetry=telemetry, device_feeder=DeviceFeeder(prefetch_to_device=0))
+    progress = TrainingProgress(num_seen_steps_current_run=0, num_seen_tokens_current_run=0,
+                                num_target_steps=n_steps, num_target_tokens=128 * n_steps)
+    import logging
+
+    program_log = logging.getLogger("modalities_tpu")  # does not propagate to the root logger caplog listens on
+    program_log.addHandler(caplog.handler)
+    try:
+        trainer.train(fns, loader, progress, evaluation_callback=lambda step: None, checkpointing_callback=lambda p: None)
+    finally:
+        program_log.removeHandler(caplog.handler)
+    telemetry.close()
+    said = [r.getMessage() for r in caplog.records if "times the usual" in r.getMessage()]
+    assert len(said) == 1 and f"meanwhile: {where} 0.4" in said[0], said  # the log gets the step of seconds, and it alone
+
+    # the first interval holds the first step (trace + compile) and is kept from the detector
+    assert [step for step, _ in observed] == list(range(2, n_steps + 1))
+    usual = sorted(seconds for _, seconds in observed)[len(observed) // 2]
+    assert step_s <= usual < 3 * step_s, "the detector sees the step the double paces, not a dispatch of microseconds"
+    events = [json.loads(ln) for ln in telemetry.sink_path.read_text().splitlines()]
+    anomalies = [e for e in events if e.get("name") == "anomaly/step_time"]
+    # sleeps of one length leave the detector a yardstick of microseconds, so a loaded machine may add a milder one
+    assert anomalies and sum(e["seconds"] >= stall_s for e in anomalies) == 1, anomalies
+    event = max(anomalies, key=lambda e: e["seconds"])
+    # an interval runs from the return of one fetch to the return of the next and is named for the step whose metrics
+    # that fetch brought: step 13's are waited for in its own interval; step 13's BATCH is waited for before its dispatch,
+    # which comes before the fetch of step 12's metrics
+    assert event["step_id"] == (stalled_step if where == "metrics_fetch" else stalled_step - 1) and event["seconds"] >= stall_s
+    split = event["split_s"]
+    assert sum(split.values()) == pytest.approx(event["window_s"], abs=1e-4) and event["window_s"] == pytest.approx(event["seconds"], abs=1e-4)
+    assert split[where] >= stall_s and split[where] == max(split.values())
+    assert all(held < 0.1 for name, held in split.items() if name != where), split
+    assert telemetry.metrics.counter("training_step_time_anomaly_total").value() == len(anomalies)
