@@ -1192,6 +1192,111 @@ def refuse_serving(spec: "GPT2ModelSpec") -> None:
             raise NotImplementedError(reason)
 
 
+def _exit_gate() -> nn.Dense:
+    """The gate read off every walk's exit, float32. From zero: every token's gate at 1/2, and nothing reaches
+    the stack through the gate before it has moved."""
+    return nn.Dense(
+        1, name=scopes.EXIT_GATE, dtype=jnp.float32, param_dtype=jnp.float32,
+        kernel_init=nn.with_logical_partitioning(nn.initializers.zeros, ("embed", None)),
+        bias_init=nn.with_logical_partitioning(nn.initializers.zeros, (None,)),
+    )
+
+
+def _stack_bytes_a_shard(spec: "GPT2ModelSpec", stacked, x) -> int:
+    """Bytes of the layers' stacked gradient `[L, ...]` (the weights' shapes and dtypes) as one shard holds them
+    under the rules and mesh the step installed; the whole stack's where none are."""
+    from modalities_tpu.parallel.sharding import shard_shape
+
+    one = jax.eval_shape(GPT2Block(spec, mixer=spec.kinds[0]).init, jax.random.PRNGKey(0), jax.ShapeDtypeStruct(x.shape, x.dtype))
+    axes = nn.get_partition_spec(one)["params"]  # a layer's logical axes; the scan puts `layers` before them
+    return sum(
+        math.prod(shard_shape(leaf.shape, ("layers", *names))) * leaf.dtype.itemsize
+        for leaf, names in zip(jax.tree.leaves(stacked), jax.tree.leaves(axes, is_leaf=lambda a: isinstance(a, jax.sharding.PartitionSpec)))
+    )
+
+
+def _walks_in_place(spec: "GPT2ModelSpec", deterministic: bool, carry_dtype):
+    """The walks of a looped stack under full remat as a function of the layers' stacked tree `[L, ...]`, the
+    tree shared beside it (`lm_head_norm`, `exit_gate`), the embedded tokens and a dropout key an application
+    (`[T, L]` or None), with its backward written by hand: `GPT2Module._walks` says why. The forward is the
+    two loops autodiff's form runs, and keeps every application's input `[T, L, B, S, E]` and every walk's
+    last block output `[T, B, S, E]` (the final norm's input). Scope names are the step's vocabulary:
+    `layer_carry` round the layer loop, `blocks/block/...` on a block, `lm_head_norm`, `exit_gate`."""
+    layers, walks, gated = spec.n_layer, spec.loop.total_ut_steps, spec.loop.exit_gate
+
+    def block(layer, x, key):
+        with jax.named_scope("blocks"):
+            return GPT2Block(spec, deterministic, mixer=spec.kinds[0], name="block").apply(
+                {"params": layer}, x, rngs=None if key is None else {"dropout": key})
+
+    def close(shared, u):
+        h = build_norm(spec.lm_head_norm, "lm_head_norm").apply({"params": shared["lm_head_norm"]}, u)
+        h = with_logical_constraint(h, ("batch", "seq", "embed"))
+        gate = _exit_gate().apply({"params": shared[scopes.EXIT_GATE]}, h.astype(jnp.float32))[..., 0] if gated else None
+        return h.astype(carry_dtype), (h, gate)
+
+    def forward(stacked, shared, x, keys):
+        def walk(carry, walk_keys):
+            def layer(c, per_layer):
+                return block(per_layer[0], c, per_layer[1]), c
+
+            with jax.named_scope(scopes.LAYER_CARRY):
+                u, inputs = jax.lax.scan(layer, carry, (stacked, walk_keys), length=layers)
+            carry, exits = close(shared, u)
+            return carry, (exits, inputs, u)
+
+        _, (exits, inputs, pre_norm) = jax.lax.scan(walk, x, keys, length=walks)
+        return exits, (inputs, pre_norm)
+
+    @jax.custom_vjp
+    def stack(stacked, shared, x, keys):
+        return forward(stacked, shared, x, keys)[0]
+
+    def stack_fwd(stacked, shared, x, keys):
+        exits, kept = forward(stacked, shared, x, keys)
+        return exits, (stacked, shared, keys, *kept)
+
+    def recomputed(fn):
+        """`fn` for `jax.vjp`, which writes its transforms round the FIRST scope opened inside it
+        (`transpose(jvp(<scope>))/...`): that scope is the name JAX's own remat gives a recomputed forward,
+        so that the names readers select by (`blocks/block/...`, `lm_head_norm`) stand whole after it."""
+        def named(*args):
+            with jax.named_scope("rematted_computation"):
+                return fn(*args)
+        return named
+
+    def stack_bwd(residuals, d_exits):
+        stacked, shared, keys, inputs, pre_norm = residuals
+        zeros = lambda tree: jax.tree.map(jnp.zeros_like, tree)  # noqa: E731
+
+        def walk(carry, per_walk):
+            t, u, d_exit = per_walk
+            acc, acc_shared, d_carry = carry
+            d_shared, d_u = jax.vjp(recomputed(close), shared, u)[1]((d_carry, d_exit))
+            acc_shared = jax.tree.map(jnp.add, acc_shared, d_shared)
+
+            def layer(c, per_layer):
+                weights, l = per_layer
+                acc, d = c
+                x_in = jax.lax.dynamic_slice(inputs, (t, l, *(0,) * d.ndim), (1, 1, *d.shape)).reshape(d.shape)
+                d_weights, d = jax.vjp(recomputed(lambda w, a: block(w, a, None if keys is None else keys[t, l])), weights, x_in)[1](d)
+                # the accumulator's slice read, added, written where it stands: not `.at[l].add`, a scatter under a traced index
+                acc = jax.tree.map(lambda a, g: jax.lax.dynamic_update_index_in_dim(
+                    a, jax.lax.dynamic_index_in_dim(a, l, 0, keepdims=False) + g, l, 0), acc, d_weights)
+                return (acc, d), None
+
+            with jax.named_scope(scopes.LAYER_CARRY):
+                (acc, d_carry), _ = jax.lax.scan(layer, (acc, d_u), (stacked, jnp.arange(layers)), reverse=True)
+            return (acc, acc_shared, d_carry), None
+
+        start = (zeros(stacked), zeros(shared), jnp.zeros(inputs.shape[2:], carry_dtype))
+        (acc, acc_shared, d_x), _ = jax.lax.scan(walk, start, (jnp.arange(walks), pre_norm, d_exits), reverse=True)
+        return acc, acc_shared, d_x, None
+
+    stack.defvjp(stack_fwd, stack_bwd)
+    return stack
+
+
 class GPT2Module(nn.Module):
     """The linen module behind GPT2LLM: wte/wpe -> blocks -> lm_head_norm -> lm_head.
 
@@ -1212,12 +1317,30 @@ class GPT2Module(nn.Module):
     output_exits: bool = False
 
     def _walks(self, x):
-        """`loop.total_ut_steps` walks of the layer scan over ONE parameter tree, traced once: an
-        outer scan whose body is a walk (the layer scan, then the final norm, then the gate) with the
-        parameters broadcast to it, so that autodiff sums a weight's gradient over the walks in the
-        scan's own carry. Returns every walk's exit `[T, B, S, E]` and gate logits `[T, B, S]` (None
-        without a gate). The tree is the dense decoder's (`blocks/block/...`, `lm_head_norm`) with
-        `exit_gate` beside it, whatever T is."""
+        """`loop.total_ut_steps` walks of the layer scan over ONE parameter tree, traced once. Returns every
+        walk's exit `[T, B, S, E]` and gate logits `[T, B, S]` (None without a gate). The tree is the dense
+        decoder's (`blocks/block/...`, `lm_head_norm`) with `exit_gate` beside it, whatever T is.
+
+        A walk is the layer scan, then the final norm, then the gate; the walks are an outer loop over it.
+        What differs by remat variant is who writes the backward, because a shared weight's gradient is a
+        sum over the walks and the transpose decides what that sum costs:
+
+        - **full remat** (a block keeps its input and nothing else): `_walks_in_place`, whose backward is
+          written by hand (`jax.custom_vjp`). It walks the `T x L` applications last to first, recomputes each
+          block from its kept input (`jax.vjp`: what full remat means), and adds the block's weight gradient
+          into ONE accumulator `[L, ...]` at that layer's index, slice read, added, slice written
+          (`dynamic_update_index_in_dim` on the loop's carry, which XLA updates where it stands): one copy of
+          the layers' gradient, and no byte of it moved that the sum does not need.
+        - **no remat, `selective_op`** (residuals only autodiff knows): an outer `nn.scan` with the parameters
+          broadcast, its body the layer scan with the weights as scanned inputs. Autodiff transposes that to an
+          inner scan that emits a walk's gradient as a stack `[L, ...]` and an outer scan that carries the
+          accumulator and adds the whole stack once a walk (`add_any`): two copies of the layers' gradient,
+          each read and written once more a walk than the products need (28.8 ms of 941 and 1.53 GiB at
+          16 x 51.4 M parameters, four walks: PERF.md, PR 37).
+
+        Two shorter forms were not taken. The weights as a closed-over stack indexed by the layer inside one
+        scan: a dynamic index transposes to an add of the whole stack every LAYER. `acc.at[l].add(dW)` in the
+        hand-written rule: with a traced index that is a scatter, which the chip runs a row at a time."""
         spec, loop = self.spec, self.spec.loop
         carry_dtype = x.dtype
 
@@ -1233,27 +1356,30 @@ class GPT2Module(nn.Module):
                 u, _ = scanned(carry, None)
             h = build_norm(spec.lm_head_norm, "lm_head_norm")(u)
             h = with_logical_constraint(h, ("batch", "seq", "embed"))
-            gate = None
-            if loop.exit_gate:
-                gate = nn.Dense(
-                    1, name=scopes.EXIT_GATE, dtype=jnp.float32, param_dtype=jnp.float32,
-                    # from zero: every token's gate at 1/2, and nothing reaches the stack through the gate before it has moved
-                    kernel_init=nn.with_logical_partitioning(nn.initializers.zeros, ("embed", None)),
-                    bias_init=nn.with_logical_partitioning(nn.initializers.zeros, (None,)),
-                )(h.astype(jnp.float32))[..., 0]
+            gate = _exit_gate()(h.astype(jnp.float32))[..., 0] if loop.exit_gate else None
             return h.astype(carry_dtype), (h, gate)
 
         if self.is_initializing():  # one walk makes the tree: no walk has a parameter of its own
             _, (h, gate) = walk(self, x, None)
             return h[None], None if gate is None else gate[None]
         layers, walks = spec.n_layer, loop.total_ut_steps
+        in_place = spec.remat_variant == "full"
         kept = walks * layers if spec.remat_variant is not None else None  # under remat a block keeps its input and nothing else
+        params = nn.meta.unbox(self.variables["params"])
         get_active_telemetry().emit_event_once("loop_plan", {
             "walks": walks, "layers": layers, "applications": walks * layers, "exit_gate": loop.exit_gate,
             "block_inputs_kept": kept, "block_input_bytes": None if kept is None else kept * x.size * x.dtype.itemsize,
             "head_rows": (walks if loop.exit_gate and self.output_exits else 1) * x.shape[0] * x.shape[1],
+            "shared_gradient": "in_place" if in_place else "summed_by_walk", "shared_gradient_copies": 1 if in_place else 2,
+            "shared_gradient_bytes": _stack_bytes_a_shard(spec, params["blocks"]["block"], x),
         })
         with jax.named_scope(scopes.LOOP):  # the carry between walks, and in the backward the sum of a weight's gradient over them
+            if in_place:
+                keys = None
+                if spec.dropout > 0.0 and not self.deterministic:  # a key an application, the recomputed block's the forward's
+                    keys = jax.random.split(self.make_rng("dropout"), (walks, layers))
+                shared = {name: params[name] for name in ("lm_head_norm", scopes.EXIT_GATE) if name in params}
+                return _walks_in_place(spec, self.deterministic, carry_dtype)(params["blocks"]["block"], shared, x, keys)
             _, exits = nn.scan(
                 walk, variable_broadcast="params", split_rngs={"params": False, "dropout": True}, length=walks,
             )(self, x, None)

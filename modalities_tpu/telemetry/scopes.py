@@ -56,8 +56,10 @@ Expert layer (`models/gpt2/moe.py`, `ops/expert_dispatch.py`), under the module 
 Looped decoder (`loop_config`: the stack walked several times over one set of weights; `models/gpt2/gpt2_model.py`, `training/train_step.py`):
 
     LOOP              loop              round the walks: the carry between walks, every walk's exit stacked, and in the backward
-                                        the sum of each shared weight's gradient over the walks; blocks, the layer scan
-                                        (`layer_carry`), the final norm and the gate name themselves inside it
+                                        the sum of each shared weight's gradient over the walks (autodiff's form: a whole stack
+                                        added a walk; under full remat the hand-written rule adds a layer's slice where it
+                                        stands, inside `layer_carry`, and recomputes under `jvp(rematted_computation)/`);
+                                        blocks, the layer scan (`layer_carry`), the final norm and the gate name themselves inside it
     EXIT_GATE         exit_gate         the gate read off every walk's exit, float32 (the name of its module)
     EXIT_LOSS         exit_loss         inside `head_loss`: the exit distribution from the gates, the cross entropies weighed by
                                         it, the entropy term, and what the step counts of them

@@ -3,7 +3,9 @@ every walk, sandwich norms, an exit gate and the loss over all exits), held to t
 (benchmark/reference/looped_decoder_f32.py) on the benchmark's seeded weights at toy widths: d 128, 4 heads of 32,
 SwiGLU 256, 3 layers walked 4 times, vocabulary 512."""
 
+import contextlib
 import dataclasses
+import json
 
 import jax
 import jax.numpy as jnp
@@ -14,9 +16,12 @@ from flax.core import meta
 from benchmark.reference import looped_decoder_f32 as reference
 from benchmark.weights_looped import OUTER, LoopedShape, make_program_tree, reference_layout, seed_key
 from modalities_tpu.loss_functions import CLMCrossEntropyLoss, LoopedExitLoss, exit_counter_names
+from modalities_tpu.models.gpt2 import gpt2_model
 from modalities_tpu.models.gpt2.gpt2_model import GPT2LLM, GPT2LLMConfig
 from modalities_tpu.optimizers.optimizer_factory import OptimizerFactory, build_weight_decay_mask
 from modalities_tpu.optimizers.scheduler_factory import DummyLRScheduler
+from modalities_tpu.running_env.device_mesh import get_device_mesh
+from modalities_tpu.telemetry import Telemetry, set_active_telemetry
 from modalities_tpu.training.train_step import TrainStepBuilder
 
 SEED = 2**31 + 11
@@ -293,3 +298,146 @@ def test_the_chunked_and_whole_tiers_give_the_fused_tiers_numbers(toy, tokens, m
     assert set(fused) == set(other) >= {"loss", "grad_norm", "counter/loop_exit_ce_1", "counter/loop_expected_exit"}
     for name, value in fused.items():
         assert abs(other[name] - value) <= 1e-5 * max(1.0, abs(value)), (name, value, other[name])
+
+
+# ------------------------------------------------------------------ the backward written by hand (full remat)
+
+
+def toy_loss(model, params, ids, targets):
+    """What the model trains on: the loss over the exits with the gate, the last exit's cross entropy without."""
+    if model.trains_on_exits:
+        return program_loss(model, params, ids, targets)[0]
+    hidden, _ = model.apply_counted(params, {"input_ids": ids}, train=True, hidden=True)
+    logits = model.head_logits(params, hidden)
+    return -jnp.take_along_axis(jax.nn.log_softmax(logits), targets[..., None], axis=-1).mean()
+
+
+@contextlib.contextmanager
+def loop_plans(folder):
+    """A sink for what is traced inside; the list it yields holds the `loop_plan` events once the block is left."""
+    telemetry, plans = Telemetry(output_folder_path=folder, watchdog_deadline_s=0), []
+    previous = set_active_telemetry(telemetry)
+    try:
+        yield plans
+    finally:
+        set_active_telemetry(previous)
+    plans += [e for e in map(json.loads, telemetry.sink_path.read_text().splitlines()) if e.get("name") == "loop_plan"]
+
+
+def loss_and_gradient(params, tokens, variant, **changes):
+    """Loss and gradient of the toy under a remat variant."""
+    model = build(**changes).with_spec_updates(compute_dtype="float32", remat_variant=variant)
+    ids, targets = jnp.asarray(tokens[:, :-1]), jnp.asarray(tokens[:, 1:])
+    with jax.default_matmul_precision("highest"):
+        return jax.jit(jax.value_and_grad(lambda p: toy_loss(model, p, ids, targets)))(params)
+
+
+@pytest.mark.parametrize("gate", [True, False], ids=["gate", "no_gate"])
+@pytest.mark.parametrize("walks", [1, 2, 4])
+def test_the_backward_written_by_hand_is_autodiffs(toy, tokens, walks, gate):
+    """Under full remat the walks' backward is `_walks_in_place`'s rule (every application's weight gradient added into one
+    accumulator at its layer's index); without remat it is autodiff's transpose of the scan of scans. Same forward, so the
+    same loss; every leaf of the gradient, the final norm's and the gate's among them, to float32 rounding of another
+    order of summation (read: 2e-6 of a leaf's largest entry)."""
+    params = toy[2] if gate else {"params": {name: leaf for name, leaf in toy[2]["params"].items() if name != "exit_gate"}}
+    loop = {"total_ut_steps": walks, "exit_gate": gate, "beta": 0.1}
+    want_loss, want = loss_and_gradient(params, tokens, None, loop_config=loop)
+    got_loss, got = loss_and_gradient(params, tokens, "full", loop_config=loop)
+    assert float(got_loss) == pytest.approx(float(want_loss), rel=1e-6)
+    assert jax.tree.structure(got) == jax.tree.structure(want) == jax.tree.structure(params)
+    for (path, w), g in zip(jax.tree_util.tree_leaves_with_path(want), jax.tree.leaves(got)):
+        name = jax.tree_util.keystr(path)
+        if "exit_gate" in name and walks == 1:  # one exit takes the whole distribution whatever its gate says
+            assert float(jnp.abs(w).max()) == float(jnp.abs(g).max()) == 0, name
+            continue
+        assert float(jnp.abs(w).max()) > 0, name
+        assert float(jnp.abs(g - w).max() / jnp.abs(w).max()) < 1e-5, name
+
+
+@pytest.mark.parametrize("variant", [None, "selective_op"])
+def test_without_full_remat_the_transpose_stays_autodiffs(toy, tokens, tmp_path, monkeypatch, variant):
+    """No remat and `selective_op` save residuals only autodiff knows: the hand-written rule, which recomputes a block
+    from its input, is not built for them, and `loop_plan` says which form sums the shared gradient."""
+    monkeypatch.setattr(gpt2_model, "_walks_in_place", lambda *args: pytest.fail("the hand-written backward is full remat's"))
+    with loop_plans(tmp_path) as plans:
+        loss, grads = loss_and_gradient(toy[2], tokens, variant)
+    (plan,) = plans
+    assert np.isfinite(float(loss)) and all(float(jnp.abs(g).max()) > 0 for g in jax.tree.leaves(grads))
+    assert (plan["shared_gradient"], plan["shared_gradient_copies"]) == ("summed_by_walk", 2)
+    assert plan["block_inputs_kept"] == (None if variant is None else 12)
+
+
+def test_loop_plan_says_what_the_walks_keep_and_how_the_shared_gradient_is_summed(toy, tokens, tmp_path):
+    """One event a traced shape: the walks and what the remat keeps (PR 32's fields), and since PR 37 which form sums
+    the layers' gradient over the walks, how many copies of it live, and their bytes as one shard holds them."""
+    _, shape, params = toy
+    with loop_plans(tmp_path) as plans:
+        loss_and_gradient(params, tokens, "full")
+    stack = sum(leaf.size * leaf.dtype.itemsize for leaf in jax.tree.leaves(params["params"]["blocks"]))
+    assert stack == 3 * 4 * shape.layer_params()
+    assert [{k: v for k, v in e.items() if k not in ("event", "name", "rank", "ts")} for e in plans] == [{
+        "walks": 4, "layers": 3, "applications": 12, "exit_gate": True, "block_inputs_kept": 12,
+        "block_input_bytes": 12 * 2 * 64 * 128 * 4, "head_rows": 4 * 2 * 64,
+        "shared_gradient": "in_place", "shared_gradient_copies": 1, "shared_gradient_bytes": stack,
+    }]
+
+
+def test_under_dp_shard_2_the_step_agrees_with_one_device_and_the_plan_counts_a_shards_bytes(toy, tokens, tmp_path):
+    """The train step under full remat on a dp_shard 2 mesh of CPU devices beside one device: loss, the gradient's norm
+    and every leaf after the update; the accumulator is sharded as the weights are, so a shard holds half its bytes
+    (but for the norms' scales, which no axis splits)."""
+    from modalities_tpu.models.model import MixedPrecisionSpec
+
+    _, shape, params = toy
+    batch = {"samples": {"input_ids": tokens[None, :, :-1]}, "targets": {"target_ids": tokens[None, :, 1:]}}
+
+    def one_step(handle, folder):
+        model = build().with_spec_updates(remat_variant="full").update_train_spec(mixed_precision=MixedPrecisionSpec(compute_dtype="float32"))
+        opt = OptimizerFactory.get_adam_w(lr=1e-3, betas=(0.9, 0.95), eps=1e-8, weight_decay=0.1,
+                                          weight_decay_groups_excluded=["embedding", "norm", "exit_gate"], wrapped_model=model)
+        with loop_plans(folder) as plans:
+            fns = TrainStepBuilder(model=model, loss_fn=LoopedExitLoss(target_key="target_ids", prediction_key="logits"), optimizer_spec=opt,
+                                   scheduler_spec=DummyLRScheduler(name="dummy", optimizer=opt), mesh_handle=handle, grad_clip_norm=1.0).build(seed=0)
+            state = fns.app_state_handle.state
+            state = state.replace(params=jax.tree.map(lambda new, old: jax.device_put(jnp.array(new), old.sharding), params, state.params))
+            with jax.default_matmul_precision("highest"):
+                state, metrics = fns.train_step(state, fns.put_batch(batch))
+        (plan,) = plans
+        return jax.device_get(state.params), {name: float(value) for name, value in metrics.items()}, plan
+
+    one, one_metrics, one_plan = one_step(get_device_mesh(device_type="cpu", world_size=1, data_parallel_shard_degree=1), tmp_path / "one")
+    two, two_metrics, two_plan = one_step(get_device_mesh(device_type="cpu", world_size=2, data_parallel_shard_degree=2), tmp_path / "two")
+    for name in ("loss", "grad_norm", "counter/loop_expected_exit"):
+        assert two_metrics[name] == pytest.approx(one_metrics[name], rel=1e-5), name
+    for (path, a), b, start in zip(jax.tree_util.tree_leaves_with_path(one), jax.tree.leaves(two), jax.tree.leaves(params)):
+        # Adam's first step is lr * sign-like: rounding flips a few entries of a tiny gradient (as in the two-step test above)
+        assert np.linalg.norm(a - b) < 0.02 * np.linalg.norm(a - np.asarray(start)), jax.tree_util.keystr(path)
+    scales = 3 * 4 * 128 * 4  # a layer's four norms, float32
+    assert one_plan["shared_gradient_bytes"] == 3 * 4 * shape.layer_params()
+    assert two_plan["shared_gradient_bytes"] == (one_plan["shared_gradient_bytes"] - scales) // 2 + scales
+    assert (two_plan["shared_gradient"], two_plan["shared_gradient_copies"]) == ("in_place", 1)
+
+
+def test_dropout_keys_reach_the_recomputed_block_as_they_reached_the_forwards(toy):
+    """Were the rate not 0: a key an application, and the backward's recomputed block draws the mask the forward's drew.
+    The rule against autodiff of the same forward (`custom_vjp.fun`) under dropout 0.1, every cotangent; other keys, another answer."""
+    model = build(dropout=0.1).with_spec_updates(compute_dtype="float32", remat_variant="full")
+    inner = toy[2]["params"]
+    stacked, shared = inner["blocks"]["block"], {name: inner[name] for name in ("lm_head_norm", "exit_gate")}
+    x = jax.random.normal(jax.random.PRNGKey(2), (2, 64, 128))
+    walks = gpt2_model._walks_in_place(model.config_spec, False, jnp.float32)
+
+    def gradients(fn, keys):
+        def loss(stacked, shared, x):
+            exits, gates = fn(stacked, shared, x, keys)
+            return jnp.square(exits).mean() + (jnp.tanh(gates) * exits[..., 0]).mean()
+
+        with jax.default_matmul_precision("highest"):
+            return jax.jit(jax.grad(loss, argnums=(0, 1, 2)))(stacked, shared, x)
+
+    keys = jax.random.split(jax.random.PRNGKey(3), (4, 3))
+    got, want = gradients(walks, keys), gradients(walks.fun, keys)
+    for (path, w), g in zip(jax.tree_util.tree_leaves_with_path(want), jax.tree.leaves(got)):
+        assert float(jnp.abs(w).max()) > 0 and float(jnp.abs(g - w).max() / jnp.abs(w).max()) < 1e-5, jax.tree_util.keystr(path)
+    other = gradients(walks, jax.random.split(jax.random.PRNGKey(4), (4, 3)))
+    assert float(jnp.abs(other[2] - got[2]).max() / jnp.abs(got[2]).max()) > 1e-2

@@ -261,3 +261,53 @@ def test_two_layer_model_forward_backward_compiles_for_v5e(v5e, monkeypatch):
     for kernel in ("flash_attention_fwd", "flash_attention_bwd", "fused_rmsnorm_fwd", "fused_rmsnorm_bwd"):
         assert any(f"{kernel})" in line or f"{kernel}/" in line for line in kernel_lines), (kernel, len(kernel_lines))
     assert not any("flash_attention_bwd_d" in line for line in kernel_lines), "the two kernels the fused backward replaced"
+
+
+def test_the_looped_steps_backward_adds_a_layers_gradient_into_one_stack(v5e, monkeypatch):
+    """A looped stack (`loop_config`) at toy widths, 4 layers walked 4 times in bfloat16, gradient of a loss on every exit,
+    compiled for the chip in both forms of its backward. Autodiff's transpose of the scan of scans (reached here as
+    `selective_layer` at frequency 1: every block rematerialized, the hand-written rule not taken) adds each walk's
+    stacked gradient `[L, ...]` into the accumulator inside the outer loop (`add_any`) and holds both; under `full` the
+    rule of `gpt2_model._walks_in_place` adds a layer's slice where it stands: no `add_any` of a stack is left, and the
+    step's temporaries fall by at least the stack (PR 37: 28.8 ms and 3.5 GiB of the cell `train-ouro-2p6b-4k`)."""
+    from flax.core import meta
+
+    from modalities_tpu.models.gpt2.gpt2_model import GPT2LLM, GPT2LLMConfig, GPT2Module
+    from modalities_tpu.ops.pallas import autotune
+
+    monkeypatch.setattr(jax, "devices", lambda *args, **kwargs: list(v5e[:1]))
+    autotune.clear_cache()
+    n_embd, ffn, layers, walks = 1024, 2816, 4, 4
+    norm = {"norm_type": "rms_norm", "config": {"ndim": n_embd, "bias": False, "epsilon": 1e-6}}
+    config = GPT2LLMConfig(
+        sample_key="input_ids", prediction_key="logits", poe_type="NOPE", sequence_length=512, vocab_size=512, n_layer=layers,
+        n_head_q=8, n_head_kv=8, n_embd=n_embd, ffn_hidden=ffn, dropout=0.0, bias=False,
+        attention_config={"qkv_transforms": [{"type_hint": "RotaryTransform", "config": {"n_embd": n_embd, "n_head": 8, "base_freq": 1000000}}]},
+        attention_implementation="dao_flash", activation_type="swiglu", attention_norm_config=norm, ffn_norm_config=norm,
+        post_attention_norm_config=norm, post_ffn_norm_config=norm, lm_head_norm_config=norm, use_weight_tying=False,
+        lm_head_chunk_size=64, loop_config={"total_ut_steps": walks, "beta": 0.1},
+    )
+    chip = SingleDeviceSharding(v5e[0])
+    tokens = jax.ShapeDtypeStruct((2, 512), jnp.int32, sharding=chip)
+
+    def compiled(variant):
+        model = GPT2LLM(**config.model_dump()).with_spec_updates(remat_variant=variant, remat_freq=1, param_dtype="bfloat16")
+        params = jax.tree.map(lambda leaf: jax.ShapeDtypeStruct(leaf.shape, leaf.dtype, sharding=chip),
+                              meta.unbox(jax.eval_shape(model.init_params, jax.random.PRNGKey(0))))
+        module = GPT2Module(model.config_spec, deterministic=False, output_exits=True)
+
+        def loss(params, tokens):
+            out = module.apply(params, tokens)
+            return out["exits"].astype(F32).mean() + jnp.tanh(out["gate_logits"]).mean()
+
+        stack = sum(leaf.size * leaf.dtype.itemsize for leaf in jax.tree.leaves(params["params"]["blocks"]))
+        executable = jax.jit(jax.grad(loss)).lower(params, tokens).compile()
+        sums = [line for line in executable.as_text().splitlines()
+                if re.search(rf"= bf16\[{layers},\d+,\d+\]", line) and re.search(r'op_name="[^"]*/loop/while/body/[^"]*add_any"', line)]
+        return executable.memory_analysis().temp_size_in_bytes, sums, stack
+
+    by_walk, sums_by_walk, stack = compiled("selective_layer")
+    in_place, sums_in_place, _ = compiled("full")
+    assert stack > 2 * layers * 3 * n_embd * ffn
+    assert sums_by_walk and not sums_in_place, (len(sums_by_walk), sums_in_place[:2])
+    assert by_walk - in_place >= stack, (by_walk, in_place, stack)

@@ -352,10 +352,13 @@ def test_each_scope_of_the_loop_is_on_the_step_in_both_passes(looped_hlo, scope)
 
 def test_the_walks_hold_the_layer_scan_the_final_norm_and_the_gate_and_the_exit_loss_sits_in_head_loss(looped_hlo, looped_rules):
     names = every_op_name(looped_hlo)
-    walk = "GPT2Module._walks/loop/while/body/closed_call/GPT2Module.walk/"  # Flax names the method and the walk's function
+    walk = "GPT2Module._walks/loop/while/body/closed_call/"  # Flax names the method; under full remat the walks are `_walks_in_place`'s own loops
     inside, back = "/jvp(GPT2Module)/" + walk, "/transpose(jvp(GPT2Module))/" + walk
     assert any(inside + "layer_carry/while/body/closed_call/blocks/block/mlp/W/" in n for n in names)
-    assert any(back + "layer_carry/while/body/closed_call/blocks/blocks/checkpoint/block/mlp/W/" in n for n in names)
+    # the hand-written backward recomputes a block from its kept input and pulls the cotangent through it: both under the backward's walk
+    assert any(back + "layer_carry/while/body/closed_call/jvp(rematted_computation)/blocks/block/mlp/W/" in n for n in names)
+    assert any(back + "layer_carry/while/body/closed_call/transpose(jvp(rematted_computation))/blocks/block/mlp/W/" in n for n in names)
+    assert any(back + "transpose(jvp(rematted_computation))/lm_head_norm/" in n for n in names)
     assert any(inside + "lm_head_norm/" in n for n in names) and any(inside + "exit_gate/" in n for n in names)
     assert any("/jvp(head_loss)/exit_loss/" in n for n in names) and any("transpose(jvp(head_loss))/exit_loss/" in n for n in names)
     # what no walk changes (rotary tables, the causal mask) is hoisted out of the loop; a block's kernels are used inside it alone
@@ -363,7 +366,8 @@ def test_the_walks_hold_the_layer_scan_the_final_norm_and_the_gate_and_the_exit_
     # the final norm inside the loop is a norm, not the head's; the gate is its own bucket
     component = looped_rules["component"]
     assert bucket_of(inside + "lm_head_norm/mul", component) == "norms" and bucket_of(inside + "exit_gate/dot_general", component) == "exit_gate"
-    assert bucket_of(inside + "layer_carry/while/body/closed_call/blocks/blocks/checkpoint/block/post_ffn_norm/mul", component) == "norms"
+    assert bucket_of(back + "layer_carry/while/body/closed_call/transpose(jvp(rematted_computation))/blocks/block/post_ffn_norm/mul", component) == "norms"
+    assert bucket_of(inside + "layer_carry/while/body/closed_call/blocks/blocks/checkpoint/block/post_ffn_norm/mul", component) == "norms"  # autodiff's form: no remat, `selective_op`
     assert re.search(r"LOOP\s+loop", scopes.__doc__) and re.search(r"EXIT_LOSS\s+exit_loss", scopes.__doc__)
 
 
