@@ -21,6 +21,7 @@ TPU-first design choices (not translations):
 
 from __future__ import annotations
 
+import contextlib
 import math
 import re
 from dataclasses import dataclass
@@ -30,7 +31,8 @@ from typing import Annotated, Literal, Optional
 import flax.linen as nn
 import jax
 import jax.numpy as jnp
-from pydantic import BaseModel, Field, model_validator
+import numpy as np
+from pydantic import BaseModel, ConfigDict, Field, model_validator
 
 from modalities_tpu.loss_functions import exit_counter_names
 from modalities_tpu.models.components.layer_norms import (
@@ -39,7 +41,7 @@ from modalities_tpu.models.components.layer_norms import (
     build_norm,
 )
 from modalities_tpu.models.gpt2.mla import LatentAttention, MLAConfig, MLASpec
-from modalities_tpu.models.gpt2.moe import BIAS_LEAF, COUNTERS, EXPERT_LOAD, MoE, MoEConfig, MoESpec, ffn_kinds, update_selection_bias
+from modalities_tpu.models.gpt2.moe import AUX_LOSS, BIAS_LEAF, COUNTERS, EXPERT_LOAD, MoE, MoEConfig, MoESpec, ffn_kinds, update_selection_bias
 from modalities_tpu.models.gpt2.ssm import MambaMixer, SSMConfig, SSMSpec, layer_kinds, layer_runs
 from modalities_tpu.models.model import NNModel
 from modalities_tpu.ops.embedding import embedding_lookup
@@ -96,6 +98,73 @@ class AttentionConfig(BaseModel):
 
     qkv_transforms: list[QueryKeyValueTransformConfig] = []
     qk_norm_config: Optional[LayerNormWrapperConfig] = None
+
+
+SLIDING, FULL = "sliding_attention", "full_attention"  # a layer's kind of attention, as `layer_types` publishes it
+LayerType = Literal["sliding_attention", "full_attention"]
+
+
+class RopeParameters(BaseModel):
+    """The rotary of one kind of attention layer, keys as Hugging Face's `rope_parameters` publishes them.
+    `default`: `inv_freq_n = rope_theta^(-2n/D)`. `yarn` (Peng et al., arXiv 2309.00071, as
+    `transformers.modeling_rope_utils._compute_yarn_parameters` computes it): the frequencies a context of
+    `original_max_position_embeddings` turns fewer than `beta_slow` times are divided by `factor`, those it turns
+    more than `beta_fast` times are left, a linear ramp between the two bounds (rounded outwards), and cos
+    and sin are both multiplied by `attention_factor` (default `0.1 ln(factor) + 1`). A key that is not below
+    (`truncate`, `mscale`, ...) is refused: a rule nobody wrote is not run under the model's name."""
+
+    model_config = ConfigDict(extra="forbid")
+
+    rope_type: Literal["default", "yarn"] = "default"
+    rope_theta: Annotated[float, Field(gt=1.0)] = 10000.0
+    factor: Annotated[float, Field(ge=1.0)] = 1.0
+    original_max_position_embeddings: Optional[Annotated[int, Field(strict=True, ge=1)]] = None
+    beta_fast: Annotated[float, Field(gt=0.0)] = 32.0
+    beta_slow: Annotated[float, Field(gt=0.0)] = 1.0
+    attention_factor: Optional[Annotated[float, Field(gt=0.0)]] = None
+
+    @model_validator(mode="after")
+    def check_yarn(self) -> "RopeParameters":
+        if self.rope_type == "yarn" and self.original_max_position_embeddings is None:
+            raise ValueError("rope_parameters: rope_type yarn needs original_max_position_embeddings (the context the frequencies were trained at)")
+        return self
+
+
+@dataclass(frozen=True)
+class RopeSpec:
+    rope_type: str
+    theta: float
+    factor: float = 1.0
+    original: Optional[int] = None
+    beta_fast: float = 32.0
+    beta_slow: float = 1.0
+    attention_factor: float = 1.0
+
+    @classmethod
+    def from_config(cls, config: "RopeParameters | dict") -> "RopeSpec":
+        config = RopeParameters(**config) if isinstance(config, dict) else config
+        if config.rope_type == "default":
+            return cls("default", float(config.rope_theta))
+        scale = config.attention_factor if config.attention_factor is not None else 0.1 * math.log(config.factor) + 1.0
+        return cls("yarn", float(config.rope_theta), float(config.factor), config.original_max_position_embeddings,
+                   float(config.beta_fast), float(config.beta_slow), float(scale))
+
+
+def yarn_bounds(head_dim: int, rope: RopeSpec) -> tuple[int, int]:
+    """Between which two of the D/2 frequencies YaRN's ramp runs: the frequency the original context turns `beta`
+    times is number `D ln(original / (2 pi beta)) / (2 ln theta)`; `beta_fast` gives the lower bound, `beta_slow` the upper."""
+    turns = lambda beta: head_dim * math.log(rope.original / (2 * math.pi * beta)) / (2 * math.log(rope.theta))  # noqa: E731
+    return max(math.floor(turns(rope.beta_fast)), 0), min(math.ceil(turns(rope.beta_slow)), head_dim - 1)
+
+
+def rope_inv_freq(head_dim: int, rope: RopeSpec) -> np.ndarray:
+    """The D/2 rotary frequencies of `rope`, float32 (computed in float64 from the static numbers)."""
+    inv_freq = rope.theta ** -(np.arange(0, head_dim, 2, dtype=np.float64) / head_dim)
+    if rope.rope_type == "yarn":
+        low, high = yarn_bounds(head_dim, rope)
+        ramp = np.clip((np.arange(head_dim // 2, dtype=np.float64) - low) / max(high - low, 1e-3), 0.0, 1.0)
+        inv_freq = inv_freq * ((1.0 - ramp) + ramp / rope.factor)
+    return inv_freq.astype(np.float32)
 
 
 class LoopConfig(BaseModel):
@@ -187,12 +256,44 @@ class GPT2LLMConfig(BaseModel):
     loop_config: Optional[LoopConfig] = None
     post_attention_norm_config: Optional[LayerNormWrapperConfig] = None
     post_ffn_norm_config: Optional[LayerNormWrapperConfig] = None
+    # `model_type: mellum` and its like (PR 38), keys as the source names them. `head_dim`: a head's width where it is
+    # not n_embd / n_head_q (q is n_head_q * head_dim wide out of n_embd, c_proj brings it back). `layer_types`: the kind
+    # of attention of every layer; a `sliding_attention` layer sees itself and the `sliding_window - 1` positions before
+    # it, a `full_attention` layer all that came before. `rope_parameters`: the rotary by kind of layer (`RopeParameters`;
+    # needs a RotaryTransform in `qkv_transforms`, whose base_freq it replaces). All unset: the tree and the program of before.
+    head_dim: Optional[Annotated[int, Field(strict=True, ge=2)]] = None
+    layer_types: Optional[list[LayerType]] = None
+    sliding_window: Optional[Annotated[int, Field(strict=True, ge=1)]] = None
+    rope_parameters: Optional[dict[LayerType, RopeParameters]] = None
 
     @model_validator(mode="after")
     def check_loop(self) -> "GPT2LLMConfig":
-        if self.loop_config is not None and (self.attn_layer_period is not None or self.mla_config is not None or self.moe_config is not None):
+        if self.loop_config is not None and (self.attn_layer_period is not None or self.mla_config is not None
+                                             or self.moe_config is not None or self.layer_types is not None):
             raise ValueError("loop_config walks ONE run of equal dense-decoder layers; a stack of several kinds of layer "
-                             "(attn_layer_period, moe_config) or latent attention under it is not written")
+                             "(attn_layer_period, moe_config, layer_types) or latent attention under it is not written")
+        return self
+
+    @model_validator(mode="after")
+    def check_layer_types(self) -> "GPT2LLMConfig":
+        rotary = any(t.type_hint == QueryKeyValueTransformType.RotaryTransform for t in self.attention_config.qkv_transforms)
+        if self.mla_config is not None and (self.rope_parameters is not None or self.layer_types is not None or self.head_dim is not None):
+            raise ValueError("mla_config: latent attention has its own rotary (no YaRN: rope_parameters is the plain attention's), "
+                             "its own head widths and no window; leave rope_parameters, layer_types and head_dim unset")
+        if self.rope_parameters is not None and not rotary:
+            raise ValueError("rope_parameters gives the rotary its frequencies by kind of layer: put a RotaryTransform in attention_config.qkv_transforms")
+        if self.head_dim is not None and self.head_dim % 2:
+            raise ValueError("head_dim must be even: the rotary turns halves")
+        if self.layer_types is None:
+            if self.sliding_window is not None:
+                raise ValueError("sliding_window needs layer_types: without it every layer sees all that came before")
+            return self
+        if len(self.layer_types) != self.n_layer:
+            raise ValueError(f"layer_types names {len(self.layer_types)} layers, n_layer is {self.n_layer}")
+        if self.attn_layer_period is not None:
+            raise ValueError("layer_types beside attn_layer_period: window layers in a stack with state-space layers are not written")
+        if SLIDING in self.layer_types and self.sliding_window is None:
+            raise ValueError("layer_types holds sliding_attention layers: give sliding_window")
         return self
 
     @model_validator(mode="after")
@@ -342,7 +443,7 @@ class GPT2ModelSpec:
     # quantized + float32 per-output-channel scale, dequant fused into the
     # matmul). Serving-only — the train step never sets this.
     quant_weights: str = "none"
-    # the mixer of every layer, "attn" or "ssm" (empty: attention everywhere), and the
+    # the mixer of every layer, "attn", "ssm" or "swa" (attention under a window) (empty: attention everywhere), and the
     # state-space mixer's sizes. Runs of equal kind are stacked and scanned one after
     # another; one run is the dense decoder, with the tree and the program it always had
     layer_kinds: tuple[str, ...] = ()
@@ -356,10 +457,23 @@ class GPT2ModelSpec:
     loop: Optional[LoopSpec] = None
     post_attn_norm: Optional[NormSpec] = None
     post_ffn_norm: Optional[NormSpec] = None
+    # a head's width where the config gives it (`head_dim`); the window of the layers whose mixer is "swa" (`layer_kinds`
+    # then holds "swa" for a `sliding_attention` layer and "attn" for a `full_attention` one); the rotary by that kind
+    head_dim_key: Optional[int] = None
+    sliding_window: Optional[int] = None
+    rope_by_kind: tuple[tuple[str, RopeSpec], ...] = ()
 
     @property
     def head_dim(self) -> int:
-        return self.n_embd // self.n_head_q
+        return self.head_dim_key if self.head_dim_key is not None else self.n_embd // self.n_head_q
+
+    def rope_of(self, mixer: str) -> Optional[RopeSpec]:
+        """The rotary of the layers whose mixer is `mixer`; None: `rope_base_freq`, unscaled, as before there were kinds."""
+        return dict(self.rope_by_kind).get(mixer)
+
+    @property
+    def has_window(self) -> bool:
+        return "swa" in self.layer_kinds
 
     @property
     def kinds(self) -> tuple[str, ...]:
@@ -430,19 +544,30 @@ class GPT2ModelSpec:
                 self.loop,
                 self.post_attn_norm,
                 self.post_ffn_norm,
+                self.head_dim_key,
+                self.sliding_window,
+                self.rope_by_kind,
             )
         )
 
 
-def _rope_tables(head_dim: int, seq_len: int, base_freq: int, dtype=jnp.float32, offset=0):
+def _rope_tables(head_dim: int, seq_len: int, base_freq: int, dtype=jnp.float32, offset=0, rope: Optional[RopeSpec] = None):
     """cos/sin tables, rotate-half convention matching the reference RotaryTransform
     (gpt2_model.py:114-229). `offset` (int or traced scalar) shifts positions to
     `offset .. offset+seq_len-1` — required inside manual cp regions where the local
-    sequence chunk starts at a nonzero global position."""
-    inv_freq = 1.0 / (base_freq ** (jnp.arange(0, head_dim, 2, dtype=jnp.float32) / head_dim))
+    sequence chunk starts at a nonzero global position. `rope` (a kind of layer's own
+    rotary, `rope_parameters`) replaces `base_freq` by its theta and, for `yarn`, scales
+    the frequencies (`rope_inv_freq`) and multiplies both tables by its attention factor."""
+    if rope is not None and rope.rope_type != "default":
+        inv_freq, scale = jnp.asarray(rope_inv_freq(head_dim, rope)), rope.attention_factor
+    else:
+        base_freq = base_freq if rope is None else rope.theta
+        inv_freq, scale = 1.0 / (base_freq ** (jnp.arange(0, head_dim, 2, dtype=jnp.float32) / head_dim)), 1.0
     t = jnp.asarray(offset, jnp.float32) + jnp.arange(seq_len, dtype=jnp.float32)
     freqs = jnp.einsum("i,j->ij", t, inv_freq)
     emb = jnp.concatenate([freqs, freqs], axis=-1)
+    if scale != 1.0:
+        return (jnp.cos(emb) * scale).astype(dtype), (jnp.sin(emb) * scale).astype(dtype)
     return jnp.cos(emb).astype(dtype), jnp.sin(emb).astype(dtype)
 
 
@@ -512,13 +637,14 @@ def masked_attention(q, k, v, mask, dropout_rate: float = 0.0, dropout_rng=None)
     return out.reshape(b, sq, hq, v.shape[-1])
 
 
-def manual_attention(q, k, v, dropout_rate: float = 0.0, dropout_rng=None):
-    """Oracle attention: causal mask over a square sequence (reference :595-658)."""
+def manual_attention(q, k, v, dropout_rate: float = 0.0, dropout_rng=None, window: Optional[int] = None):
+    """Oracle attention: causal mask over a square sequence (reference :595-658); under `window`
+    a position sees itself and the `window - 1` before it."""
     s = q.shape[1]
-    return masked_attention(
-        q, k, v, jnp.tril(jnp.ones((s, s), dtype=bool)),
-        dropout_rate=dropout_rate, dropout_rng=dropout_rng,
-    )
+    mask = jnp.tril(jnp.ones((s, s), dtype=bool))
+    if window is not None:
+        mask = mask & ~jnp.tril(jnp.ones((s, s), dtype=bool), -window)
+    return masked_attention(q, k, v, mask, dropout_rate=dropout_rate, dropout_rng=dropout_rng)
 
 
 def sdpa_attention(q, k, v):
@@ -526,11 +652,11 @@ def sdpa_attention(q, k, v):
     return jax.nn.dot_product_attention(q, k, v, is_causal=True)
 
 
-def flash_attention(q, k, v):
-    """Pallas flash-attention tier; falls back to SDPA off-TPU."""
+def flash_attention(q, k, v, window: Optional[int] = None):
+    """Pallas flash-attention tier; falls back to SDPA off-TPU (under a window, to the masked softmax written out)."""
     from modalities_tpu.ops.attention import flash_attention_or_fallback
 
-    return flash_attention_or_fallback(q, k, v, causal=True)
+    return flash_attention_or_fallback(q, k, v, causal=True, window=window)
 
 
 class QuantDenseGeneral(nn.Module):
@@ -632,11 +758,13 @@ class CausalSelfAttention(nn.Module):
     deterministic: bool = True
     decode: bool = False
     slot_spec: Optional[SlotDecodeSpec] = None
+    kind: str = "attn"  # "swa": under the spec's window; each kind has its own rotary where `rope_parameters` gives one
 
     @nn.compact
     def __call__(self, x, slot=None, positions=None):
         spec = self.spec
         head_dim = spec.head_dim
+        window = spec.sliding_window if self.kind == "swa" else None
         q = _dense_general(spec, (spec.n_head_q, head_dim), "q_attn", ("embed", "heads", "head_dim"), x.dtype)(x)
         k = _dense_general(spec, (spec.n_head_kv, head_dim), "k_attn", ("embed", "kv_heads", "head_dim"), x.dtype)(x)
         v = _dense_general(spec, (spec.n_head_kv, head_dim), "v_attn", ("embed", "kv_heads", "head_dim"), x.dtype)(x)
@@ -659,7 +787,8 @@ class CausalSelfAttention(nn.Module):
             # positions in the ring come out shifted by cp_rank * S_local
             offset = cp_shard_offset(spec.context_parallel_axis, x.shape[1])
             with jax.named_scope(scopes.ROPE):
-                cos, sin = _rope_tables(head_dim, x.shape[1], spec.rope_base_freq, dtype=x.dtype, offset=offset)
+                cos, sin = _rope_tables(head_dim, x.shape[1], spec.rope_base_freq, dtype=x.dtype, offset=offset,
+                                        rope=spec.rope_of(self.kind))
                 q = apply_rope(q, cos, sin)
                 k = apply_rope(k, cos, sin)
 
@@ -700,12 +829,12 @@ class CausalSelfAttention(nn.Module):
                 # the fused XLA SDPA has no dropout hook, so both tiers drop to the exact
                 # unfused path — same math, probabilities dropped out as the reference does
                 y = manual_attention(
-                    q, k, v, dropout_rate=spec.dropout, dropout_rng=self.make_rng("dropout")
+                    q, k, v, dropout_rate=spec.dropout, dropout_rng=self.make_rng("dropout"), window=window
                 )
-            elif impl == AttentionImplementation.MANUAL.value:
-                y = manual_attention(q, k, v)
             elif impl == AttentionImplementation.DAO_FLASH.value:
-                y = flash_attention(q, k, v)
+                y = flash_attention(q, k, v, window)
+            elif impl == AttentionImplementation.MANUAL.value or window is not None:  # fused SDPA's mask is causal, no more
+                y = manual_attention(q, k, v, window=window)
             else:
                 y = sdpa_attention(q, k, v)
 
@@ -998,7 +1127,7 @@ class GPT2Block(nn.Module):
     deterministic: bool = True
     decode: bool = False
     slot_spec: Optional[SlotDecodeSpec] = None
-    mixer: str = "attn"  # what sits in the mixer seat: "attn" or "ssm"
+    mixer: str = "attn"  # what sits in the mixer seat: "attn", "ssm" or "swa" (attention under the spec's window)
     ffn: str = "mlp"  # what sits in the feed-forward seat: "mlp" or "moe"; with "moe" the block returns (x, what the layer counted)
 
     @nn.compact
@@ -1013,9 +1142,13 @@ class GPT2Block(nn.Module):
             a = LatentAttention(spec, self.deterministic, name="attn")(h)
             a = nn.Dropout(rate=spec.dropout)(a, deterministic=self.deterministic or spec.dropout == 0.0)
         else:
-            a = CausalSelfAttention(
-                spec, self.deterministic, self.decode, slot_spec=self.slot_spec, name="attn"
-            )(h, slot, positions)
+            # in a stack of both kinds of attention layer each goes under a name of its own: `block/window/attn/...`, `block/global/attn/...`
+            kind_scope = (jax.named_scope(scopes.ATTN_WINDOW if self.mixer == "swa" else scopes.ATTN_GLOBAL)
+                          if spec.has_window else contextlib.nullcontext())
+            with kind_scope:
+                a = CausalSelfAttention(
+                    spec, self.deterministic, self.decode, slot_spec=self.slot_spec, kind=self.mixer, name="attn"
+                )(h, slot, positions)
         if spec.post_attn_norm is not None:
             a = build_norm(spec.post_attn_norm, scopes.POST_ATTENTION_NORM, dtype=x.dtype)(a)
         with jax.named_scope(scopes.RESIDUAL):
@@ -1184,9 +1317,30 @@ _NO_STAGE_PLAN_THAT_CLOSES = (
 )
 
 
+_NO_CACHE_BY_LAYER_KIND = (
+    "this model has window layers (layer_types: sliding_attention, sliding_window), and serving them needs a cache allocator by "
+    "layer kind (a window layer keeps its last sliding_window positions and frees the blocks behind them, a full_attention layer "
+    "keeps all: serving/paged_cache.py gives every layer the same table) and the window in the decode and prefill masks, which "
+    "serving/ does not have: it trains, it does not decode"
+)
+_NO_WINDOW_IN_THE_RING = (
+    "this model has window layers (layer_types: sliding_attention), and context parallelism runs attention as a ring over the cp "
+    "axis (parallel/ring_attention.py), which carries no window: every hop's block would need the window's edge against the hop's "
+    "offset, and the hops wholly behind it skipped. Run it without a cp axis."
+)
+_NO_WINDOW_LAYERS_IN_STAGES = (
+    "this model has window layers (layer_types), a stack of two kinds of attention layer; pipeline parallelism splits ONE stack "
+    "of equal layers over its stages (parallel/pipeline*.py), and a stage plan that knows a layer's kind is not written. Run it without a pp axis."
+)
+
+
+_MIXER_OF = {SLIDING: "swa", FULL: "attn"}  # a published layer type as the block's mixer seat names it
+
+
 def refuse_serving(spec: "GPT2ModelSpec") -> None:
     """A cache, or a forward that reads one, is refused by the name of what serving lacks for this model."""
     for missing, reason in ((spec.has_ssm, _NO_RECURRENT_STATE_CACHE), (spec.mla is not None, _NO_LATENT_CACHE),
+                            (spec.has_window, _NO_CACHE_BY_LAYER_KIND),
                             (spec.has_moe, _NO_DECODE_THROUGH_DISPATCH), (spec.loop is not None, _NO_CACHE_ENTRY_PER_WALK)):
         if missing:
             raise NotImplementedError(reason)
@@ -1439,6 +1593,10 @@ class GPT2Module(nn.Module):
 
         if self.decode or self.slot_spec is not None:
             refuse_serving(spec)
+        if spec.has_window and spec.context_parallel_axis is not None:
+            raise NotImplementedError(_NO_WINDOW_IN_THE_RING)
+        if spec.has_window and spec.pipeline_axis is not None:
+            raise NotImplementedError(_NO_WINDOW_LAYERS_IN_STAGES)
         if spec.pipeline_axis is not None and (spec.has_moe or spec.mla is not None or len(spec.stack_runs) > 1):
             raise NotImplementedError(
                 "pipeline parallelism splits ONE stack of equal dense-decoder layers over its stages; a model whose "
@@ -1537,7 +1695,7 @@ class GPT2Module(nn.Module):
                     layer_counters.append(counters[None])
         if layer_counters and not self.is_initializing() and self.is_mutable_collection("counters"):
             self.sow("counters", "moe", jnp.concatenate(layer_counters, axis=0), reduce_fn=lambda _, new: new,
-                     init_fn=lambda: jnp.zeros((0, len(COUNTERS) + spec.moe.n_routed_experts), jnp.float32))
+                     init_fn=lambda: jnp.zeros((0, len(COUNTERS) + spec.moe.n_routed_experts + spec.moe.counts_aux_loss), jnp.float32))
 
         if spec.loop is None:  # a looped model's final norm closes every walk (`_walks`)
             x = build_norm(spec.lm_head_norm, "lm_head_norm")(x)
@@ -1605,6 +1763,10 @@ class GPT2LLM(NNModel):
         loop_config: Optional[LoopConfig | dict] = None,
         post_attention_norm_config=None,
         post_ffn_norm_config=None,
+        head_dim: Optional[int] = None,
+        layer_types: Optional[list[str]] = None,
+        sliding_window: Optional[int] = None,
+        rope_parameters: Optional[dict] = None,
     ):
         super().__init__(
             sample_key=sample_key,
@@ -1670,19 +1832,23 @@ class GPT2LLM(NNModel):
             ffn_norm=NormSpec.from_wrapper_config(ffn_norm_config, n_embd),
             lm_head_norm=NormSpec.from_wrapper_config(lm_head_norm_config, n_embd),
             qk_norm=(
-                NormSpec.from_wrapper_config(attention_config.qk_norm_config, n_embd // n_head_q)
+                NormSpec.from_wrapper_config(attention_config.qk_norm_config, head_dim if head_dim is not None else n_embd // n_head_q)
                 if attention_config.qk_norm_config is not None
                 else None
             ),
             lm_head_chunk_size=lm_head_chunk_size,
             lm_head_fused_ce=lm_head_fused_ce,
-            layer_kinds=layer_kinds(n_layer, attn_layer_period, attn_layer_offset) if attn_layer_period else (),
+            layer_kinds=(layer_kinds(n_layer, attn_layer_period, attn_layer_offset) if attn_layer_period
+                         else tuple(_MIXER_OF[kind] for kind in layer_types or ())),
             ssm=SSMSpec.from_config(ssm_config, n_embd) if ssm_config is not None else None,
             mla=MLASpec.from_config(mla_config) if mla_config is not None else None,
             moe=MoESpec.from_config(moe_config) if moe_config is not None else None,
             loop=LoopSpec.from_config(loop_config) if loop_config is not None else None,
             post_attn_norm=NormSpec.from_wrapper_config(post_attention_norm_config, n_embd) if post_attention_norm_config is not None else None,
             post_ffn_norm=NormSpec.from_wrapper_config(post_ffn_norm_config, n_embd) if post_ffn_norm_config is not None else None,
+            head_dim_key=head_dim,
+            sliding_window=sliding_window if layer_types and SLIDING in layer_types else None,
+            rope_by_kind=tuple(sorted((_MIXER_OF[kind], RopeSpec.from_config(rope)) for kind, rope in (rope_parameters or {}).items())),
         )
         self.sequence_length = sequence_length
         self.vocab_size = vocab_size
@@ -1732,7 +1898,8 @@ class GPT2LLM(NNModel):
             return {name: () for name in exit_counter_names(spec.loop.total_ut_steps)}
         if not spec.has_moe:
             return {}
-        return {**{name: () for name in COUNTERS}, EXPERT_LOAD: (spec.ffn_kinds.count("moe"), spec.moe.n_routed_experts)}
+        aux = {AUX_LOSS: ()} if spec.moe.counts_aux_loss else {}  # the balance term, the mean over the expert layers (a softmax router's)
+        return {**{name: () for name in COUNTERS}, EXPERT_LOAD: (spec.ffn_kinds.count("moe"), spec.moe.n_routed_experts), **aux}
 
     @property
     def trains_on_exits(self) -> bool:
@@ -1748,16 +1915,27 @@ class GPT2LLM(NNModel):
             return super().apply_counted(params, inputs, train=train, rngs=rngs, hidden=hidden)
         module = GPT2Module(self.config_spec, deterministic=not train, output_hidden=hidden)
         out, state = module.apply(params, inputs[self.sample_key], rngs=rngs, mutable=["counters"])
-        rows = state["counters"]["moe"]  # [expert layers, 3 + E]
+        rows = state["counters"]["moe"]  # [expert layers, 3 + E], and a last column for the balance term where the router has one
+        experts = self.config_spec.moe.n_routed_experts
         counted = {COUNTERS[0]: rows[:, 0].mean(), COUNTERS[1]: rows[:, 1].max(), COUNTERS[2]: rows[:, 2].mean(),
-                   EXPERT_LOAD: rows[:, len(COUNTERS):]}
+                   EXPERT_LOAD: rows[:, len(COUNTERS): len(COUNTERS) + experts]}
+        if self.config_spec.moe.counts_aux_loss:
+            counted[AUX_LOSS] = rows[:, -1].mean()  # the one thing counted that carries a gradient: `loss_from_layers`
         return (out if hidden else {self.prediction_key: out}), counted
+
+    def loss_from_layers(self, counted: dict):
+        """The loss term that comes from the layers and not from the logits: `router_aux_loss_coef` times the mean
+        over the expert layers of the balance term, where the config asks for one; None (nothing to add) elsewhere."""
+        moe = self.config_spec.moe
+        if moe is None or not moe.router_aux_loss_coef:
+            return None
+        return moe.router_aux_loss_coef * counted[AUX_LOSS]
 
     def after_update(self, params, counted: dict):
         """The selection bias of every expert layer moved by its rule (`moe.update_selection_bias`)
         from the step's loads; the tree as it is where `bias_update_speed` is 0."""
         spec = self.config_spec
-        if not spec.has_moe or not spec.moe.bias_update_speed:
+        if not spec.has_moe or not spec.moe.bias_update_speed or not spec.moe.selection_bias:
             return params
         expert_layers = [i for i, ffn in enumerate(spec.ffn_kinds) if ffn == "moe"]
         first_of_run = [sum(length for _, _, length in spec.stack_runs[:r]) for r in range(len(spec.stack_runs))]

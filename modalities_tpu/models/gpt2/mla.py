@@ -55,7 +55,8 @@ class MLAConfig(BaseModel):
         if self.q_lora_rank is not None:
             raise ValueError("mla_config.q_lora_rank: a query low-rank path (q_a_proj, its norm, q_b_proj) is not written; only null is")
         if self.rope_scaling is not None:
-            raise ValueError("mla_config.rope_scaling: no scaling of the rotary frequencies (yarn and the like) is written; only null is")
+            raise ValueError("mla_config.rope_scaling: no scaling of latent attention's rotary frequencies (yarn and the like) is written; "
+                             "only null is (YaRN is the plain attention's, by `rope_parameters`)")
         if not self.rope_interleave:
             raise ValueError("mla_config.rope_interleave: only the interleaved rotary (pairs 2i, 2i+1) is written")
         if self.qk_rope_head_dim % 2:

@@ -11,8 +11,26 @@ On `x [T, d]`, with `E = n_routed_experts`, `k = num_experts_per_tok`:
     out      = sum over the chosen experts of w * expert(x) + shared(x)
     shared   = one SwiGLU d -> n_shared_experts * moe_intermediate_size -> d, on every token
 
-`n_group` and `topk_group` other than 1 (group-limited routing) and a `scoring_func` other
-than `sigmoid` are not written and are refused. No token is dropped; there is no capacity.
+`n_group` and `topk_group` other than 1 (group-limited routing) are not written and are
+refused. No token is dropped; there is no capacity.
+
+**A softmax router** (PR 38: `scoring_func: softmax`, the `qwen3_moe` / `mixtral` / `mellum`
+families' way): `s = softmax(x @ router)` over all E, float32, the rest as above. With
+`topk_method: greedy` the choice is the k largest of `s` alone: the router holds no
+`e_score_correction_bias` leaf and `after_update` moves nothing. Such a model balances its
+experts' loads by a loss term, which the layer computes (Switch Transformer, arXiv
+2101.03961, eq. 4-6, for k choices):
+
+    aux = E * sum_e f_e P_e      f_e: the share of the layer's k T pairs that went to expert e (no gradient)
+                                 P_e: the mean of s_e over the layer's T tokens; both over all E, held or not
+
+1 at balance, E / k where k experts take everything. It is a layer's own (not pooled over
+layers, as Hugging Face's `load_balancing_loss_func` pools them: twelve collapsed layers that
+picked different experts would count as balanced), rides up with the counters as their last
+column, is published as `counter/moe_aux_loss` (the mean over the expert layers), and
+`router_aux_loss_coef` times that mean is added to the loss inside the differentiated
+function (`GPT2LLM.loss_from_layers`, `training/train_step.py`). Under expert parallelism
+the group's counts and means would be summed over the chips (not written: no `ep` axis).
 
 **The share.** The layer is told which experts it holds: `experts_held` of them from
 `expert_offset` (default: all). The router keeps its E outputs, the choice its k and the
@@ -56,6 +74,7 @@ from pydantic import BaseModel, Field, model_validator
 from modalities_tpu.telemetry import scopes
 
 COUNTERS = ("moe_pairs_held", "moe_load_max", "moe_load_mean")  # a layer's, in this order
+AUX_LOSS = "moe_aux_loss"  # a softmax router's balance term: the row's last column, after the E loads, and the one with a gradient
 EXPERT_LOAD = "moe_expert_load"  # [expert layers, E]: the pairs each of the router's experts got, held or not
 BIAS_LEAF = "moe/router/e_score_correction_bias"
 
@@ -80,15 +99,23 @@ class MoEConfig(BaseModel):
     expert_offset: Annotated[int, Field(strict=True, ge=0)] = 0
     # a recipe's, not a config.json's: how far a step moves the selection bias of an expert whose load is off the mean (0: never)
     bias_update_speed: Annotated[float, Field(ge=0.0)] = 0.0
+    # a recipe's too: the weight of the balance term in the loss (a softmax router's; 0: counted and published, not added)
+    router_aux_loss_coef: Annotated[float, Field(ge=0.0)] = 0.0
 
     @model_validator(mode="after")
     def refuse_what_is_not_written(self) -> "MoEConfig":
-        if self.scoring_func != "sigmoid":
-            raise ValueError(f"moe_config.scoring_func {self.scoring_func!r}: only sigmoid scores are written")
+        if self.scoring_func not in ("sigmoid", "softmax"):
+            raise ValueError(f"moe_config.scoring_func {self.scoring_func!r}: sigmoid and softmax scores are written")
         if self.n_group != 1 or self.topk_group != 1:
             raise ValueError("moe_config.n_group / topk_group: group-limited routing is not written; both must be 1")
-        if self.topk_method != "noaux_tc":
-            raise ValueError(f"moe_config.topk_method {self.topk_method!r}: only noaux_tc (scores plus a selection bias) is written")
+        if self.topk_method not in ("noaux_tc", "greedy"):
+            raise ValueError(f"moe_config.topk_method {self.topk_method!r}: noaux_tc (scores plus a selection bias) and greedy "
+                             "(the scores alone: no bias leaf) are written")
+        if self.topk_method == "greedy" and self.bias_update_speed:
+            raise ValueError("moe_config.bias_update_speed moves the selection bias, and topk_method greedy has none")
+        if self.router_aux_loss_coef and self.scoring_func != "softmax":
+            raise ValueError("moe_config.router_aux_loss_coef weighs the balance term of a softmax router (the mean probability "
+                             "of an expert against its share of the pairs); sigmoid scores balance by the selection bias")
         if self.moe_layer_freq != 1:
             raise ValueError("moe_config.moe_layer_freq: every layer after the leading dense ones is an expert layer; only 1 is written")
         if self.num_experts_per_tok > self.n_routed_experts:
@@ -111,6 +138,14 @@ class MoESpec:
     experts_held: int
     expert_offset: int
     bias_update_speed: float = 0.0
+    scoring_func: str = "sigmoid"
+    selection_bias: bool = True  # `topk_method: noaux_tc`; False (`greedy`): no bias leaf in the tree
+    router_aux_loss_coef: float = 0.0
+
+    @property
+    def counts_aux_loss(self) -> bool:
+        """A softmax router computes its balance term every pass (the row's last column), whatever its weight in the loss."""
+        return self.scoring_func == "softmax"
 
     @classmethod
     def from_config(cls, config: "MoEConfig | dict") -> "MoESpec":
@@ -124,6 +159,8 @@ class MoESpec:
             norm_topk_prob=config.norm_topk_prob,
             experts_held=config.n_routed_experts if config.experts_held is None else config.experts_held,
             expert_offset=config.expert_offset, bias_update_speed=float(config.bias_update_speed),
+            scoring_func=config.scoring_func, selection_bias=config.topk_method == "noaux_tc",
+            router_aux_loss_coef=float(config.router_aux_loss_coef),
         )
 
 
@@ -151,10 +188,14 @@ class _Router(nn.Module):
         moe = self.moe
         kernel = self.param("kernel", nn.with_logical_partitioning(nn.initializers.normal(0.02), ("embed", "router")),
                             (x.shape[-1], moe.n_routed_experts), jnp.float32)
-        bias = self.param("e_score_correction_bias", nn.with_logical_partitioning(nn.initializers.zeros, ("router",)),
-                          (moe.n_routed_experts,), jnp.float32)
-        scores = jax.nn.sigmoid(jnp.dot(x.astype(jnp.float32), kernel, precision=jax.lax.Precision.HIGHEST))
-        _, choice = jax.lax.top_k(scores + jax.lax.stop_gradient(bias), moe.num_experts_per_tok)
+        logits = jnp.dot(x.astype(jnp.float32), kernel, precision=jax.lax.Precision.HIGHEST)
+        scores = jax.nn.softmax(logits, axis=-1) if moe.scoring_func == "softmax" else jax.nn.sigmoid(logits)
+        selection = scores
+        if moe.selection_bias:
+            bias = self.param("e_score_correction_bias", nn.with_logical_partitioning(nn.initializers.zeros, ("router",)),
+                              (moe.n_routed_experts,), jnp.float32)
+            selection = scores + jax.lax.stop_gradient(bias)
+        _, choice = jax.lax.top_k(selection, moe.num_experts_per_tok)
         # the scores at the chosen experts, as a compare against all the experts and a sum: `take_along_axis` is a gather
         # forward and a scatter backward, 65 ns an index on the chip (13 ms a step here), this a few elementwise passes
         chosen = choice[..., None] == jnp.arange(moe.n_routed_experts, dtype=choice.dtype)
@@ -162,7 +203,11 @@ class _Router(nn.Module):
         load = jnp.sum(chosen, axis=(0, 1), dtype=jnp.float32)  # of every expert the router knows, held or not
         if moe.norm_topk_prob:
             weights = weights / (jnp.sum(weights, axis=-1, keepdims=True) + 1e-20)
-        return choice, weights * moe.routed_scaling_factor, load
+        aux = None
+        if moe.counts_aux_loss:  # E sum_e f_e P_e: the gradient reaches the router through P alone
+            share = jax.lax.stop_gradient(load) / (x.shape[0] * moe.num_experts_per_tok)
+            aux = moe.n_routed_experts * jnp.sum(share * jnp.mean(scores, axis=0))
+        return choice, weights * moe.routed_scaling_factor, load, aux
 
 
 class _SharedExpert(nn.Module):
@@ -201,7 +246,8 @@ class _Experts(nn.Module):
 
 class MoE(nn.Module):
     """The expert layer; sits in a block's `MLP` seat under the name `moe`. Returns its
-    output and what the layer counted (float32 [3 + E]: `COUNTERS`, then the load of each of the router's experts)."""
+    output and what the layer counted (float32 [3 + E]: `COUNTERS`, then the load of each of the router's experts; a
+    softmax router's balance term after them, [3 + E + 1], the one entry that carries a gradient)."""
 
     spec: object  # GPT2ModelSpec (its `moe` is the MoESpec)
     deterministic: bool = True
@@ -223,11 +269,14 @@ class MoE(nn.Module):
         })
 
         with jax.named_scope(scopes.MOE_ROUTER):
-            choice, weights, load = _Router(moe, name="router")(tokens)
+            choice, weights, load, aux = _Router(moe, name="router")(tokens)
         with jax.named_scope(scopes.MOE_DISPATCH):
             plan = expert_dispatch.plan_dispatch(choice, moe.expert_offset, moe.experts_held)
             held = jnp.sum(plan.group_sizes).astype(jnp.float32)
-            counters = jnp.concatenate([jnp.stack([held, jnp.max(plan.group_sizes).astype(jnp.float32), held / moe.experts_held]), load])
+            counters = jax.lax.stop_gradient(jnp.concatenate(
+                [jnp.stack([held, jnp.max(plan.group_sizes).astype(jnp.float32), held / moe.experts_held]), load]))
+            if aux is not None:
+                counters = jnp.concatenate([counters, aux[None]])
         w, v, w_2 = _Experts(spec, name="experts")()
         # one loop over the tiles in use; inside it the gather is `dispatch`, the products `experts`, the add back `combine`
         routed = expert_dispatch.routed_experts(tokens, choice, weights, w, v, w_2, offset=moe.expert_offset, plan=plan)
@@ -237,4 +286,4 @@ class MoE(nn.Module):
             with jax.named_scope(scopes.MOE_COMBINE):
                 out = out + shared
         out = nn.Dropout(rate=spec.dropout)(out, deterministic=self.deterministic or spec.dropout == 0.0)
-        return out, jax.lax.stop_gradient(counters)
+        return out, counters

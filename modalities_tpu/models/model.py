@@ -93,6 +93,11 @@ class NNModel:
         forward = self.apply_hidden if hidden else self.apply
         return forward(params, inputs, train=train, rngs=rngs), {}
 
+    def loss_from_layers(self, counted: dict):
+        """A loss term that comes from the layers and not from the logits, out of what the pass counted (an expert
+        layer's balance term); None where the model has none. The train step adds it before the gradient."""
+        return None
+
     def after_update(self, params, counted: dict):
         """The parameters after the optimizer's update, with the buffers moved that a step moves by a
         rule of their own from what it counted (an expert layer's selection bias)."""

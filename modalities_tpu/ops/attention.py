@@ -19,20 +19,31 @@ _Q_AXES = ("batch", None, "heads", None)
 _KV_AXES = ("batch", None, "kv_heads", None)
 
 
-def _plain_attention(q, k, v, causal: bool, sm_scale: float | None):
-    """Off the TPU, where v is not as wide as q and k: the masked softmax written out, float32 scores."""
+def _plain_attention(q, k, v, causal: bool, sm_scale: float | None, window: int | None = None):
+    """Off the TPU, where v is not as wide as q and k or a window hides what lies behind it: the masked softmax
+    written out, float32 scores."""
     group = q.shape[2] // k.shape[2]
     k, v = jnp.repeat(k, group, axis=2), jnp.repeat(v, group, axis=2)
     scores = jnp.einsum("bshd,bthd->bhst", q, k).astype(jnp.float32) * (q.shape[-1] ** -0.5 if sm_scale is None else sm_scale)
     if causal:
-        keep = jnp.arange(q.shape[1])[:, None] >= jnp.arange(k.shape[1])[None, :]
+        behind = jnp.arange(q.shape[1])[:, None] - jnp.arange(k.shape[1])[None, :]  # how far a key lies behind its query
+        keep = behind >= 0 if window is None else (behind >= 0) & (behind < window)
         scores = jnp.where(keep, scores, jnp.finfo(jnp.float32).min)
     return jnp.einsum("bhst,bthd->bshd", jax.nn.softmax(scores, axis=-1).astype(v.dtype), v)
 
 
-def flash_attention_or_fallback(q, k, v, causal: bool = True, sm_scale: float | None = None):
+def flash_attention_or_fallback(q, k, v, causal: bool = True, sm_scale: float | None = None, window: int | None = None):
     """q: [B,S,Hq,D], k: [B,S,Hkv,D], v: [B,S,Hkv,Dv] -> [B,S,Hq,Dv]. Dv is D everywhere but
     in latent attention (192 and 128); the kernels read both widths off the arrays.
+
+    `window` W (PR 38; causal calls only): position i sees itself and the W - 1 before it. The
+    same kernels run over a plan with a second edge (`tile_plan(..., window)`: a tile wholly
+    behind it is no grid step, one it crosses is masked from that side) under labels of their
+    own (`flash_attention_window_{fwd,bwd,bwd_dq,bwd_dkv}`), at the blocks an unwindowed call of
+    the same shape gets; off the TPU the written-out softmax masks the same
+    positions. Ring attention carries no window, and the serving paths' masks have none: both
+    refuse a model that asks for one (`gpt2_model.py`). A call without a window gets the plan,
+    the kernels and the event it always got.
 
     Block sizes come from `env_flash_blocks`: MODALITIES_TPU_FLASH_BLOCK_Q / _BLOCK_K,
     else the device's tuning table (1024 x 1024 on a v5e; 1024 x 512 at 192/128), stepped
@@ -53,18 +64,22 @@ def flash_attention_or_fallback(q, k, v, causal: bool = True, sm_scale: float | 
 
     Under a mesh the kernel runs per shard, split over batch and heads
     (parallel/sharding.per_shard)."""
+    if window is not None and not causal:
+        raise ValueError("flash attention: a window is written for causal calls only")
     if not on_tpu():
-        if v.shape[-1] != q.shape[-1]:
-            return _plain_attention(q, k, v, causal, sm_scale)  # SDPA takes one width for q, k and v
+        if v.shape[-1] != q.shape[-1] or window is not None:
+            return _plain_attention(q, k, v, causal, sm_scale, window)  # SDPA takes one width for q, k and v
         return jax.nn.dot_product_attention(q, k, v, is_causal=causal, scale=sm_scale)
     from modalities_tpu.ops.pallas.flash_attention import backward_plan, env_flash_blocks, pallas_flash_attention, tile_plan
     from modalities_tpu.parallel.sharding import per_shard
     from modalities_tpu.telemetry import get_active_telemetry
 
     shape = dict(dtype=q.dtype, head_dim=q.shape[-1], head_dim_v=v.shape[-1])
+    windowed = {} if window is None else {"window": window}  # a call without a window says and binds what it always did
     block_q, block_k = env_flash_blocks(q.shape[1], k.shape[1], **shape)
     bwd_blocks = env_flash_blocks(q.shape[1], k.shape[1], backward=True, **shape)
     plan = {"seq_q": q.shape[1], "seq_k": k.shape[1], "block_q": block_q, "block_k": block_k, "causal": causal}
+    plan.update(windowed)  # with it the counts hold `window_edge`, the tiles its edge crosses
     # runs while tracing: the operator sees once per shape how many score tiles a
     # (batch, head) computes, which share takes the masked body, and which backward a
     # differentiated call would run (by the shape alone: a mesh splits batch and heads,
@@ -73,7 +88,8 @@ def flash_attention_or_fallback(q, k, v, causal: bool = True, sm_scale: float | 
         "flash_tile_plan", {**plan, "head_dim": q.shape[-1], "head_dim_v": v.shape[-1], **tile_plan(**plan).counts(),
                             **backward_plan(q.shape[1], *bwd_blocks, q.shape[-1], v.shape[-1], q.dtype)})
     kernel = functools.partial(
-        pallas_flash_attention, causal=causal, sm_scale=sm_scale, block_q=block_q, block_k=block_k, bwd_blocks=bwd_blocks
+        pallas_flash_attention, causal=causal, sm_scale=sm_scale, block_q=block_q, block_k=block_k, bwd_blocks=bwd_blocks,
+        **windowed
     )
     return per_shard(
         lambda _axes, q, k, v: kernel(q, k, v), (_Q_AXES, _KV_AXES, _KV_AXES), _Q_AXES

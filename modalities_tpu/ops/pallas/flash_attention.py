@@ -33,6 +33,21 @@ Design (FlashAttention-2 style, TPU-first):
   triangle is walked, column by column in sub-blocks of 256: 5/8 of its matmuls.
   `causal=False` (ring attention's off-diagonal hops) is the same code over the whole
   rectangle, every tile interior.
+- a window (PR 38; `window=W` on a causal call over one sequence: position i sees itself
+  and the W - 1 before it) is a second edge of the same plan, `i - j = W`, and one more
+  term of the same mask, not a second set of kernels: a tile wholly behind the edge is no
+  grid step; a tile the edge crosses is masked from that side (`_WINDOW`, beside `_MASKED`
+  where the diagonal crosses it too: a window under a block); where the tile is square and
+  the edge lies on its own diagonal (W a multiple of the block: `_EDGE`) only what lies
+  above that diagonal is walked, in the diagonal tile's sub-blocks mirrored, so that at
+  1024 x 1024 and W 1024 a q tile computes 20 of 32 sub-squares for its diagonal and edge
+  tiles where two whole tiles would be 32. Forward, fused backward and the two kernels read
+  the same flags. Windowed calls carry their own `name=`
+  (`flash_attention_window_{fwd,bwd,bwd_dq,bwd_dkv}`): readers count by label and per call.
+  Still whole: the fused backward's resident dq row (a kv tile's window writes a band of it
+  only), and a tile both lines cross (masked whole, not walked). Not written: a window in
+  a non-causal call or over two sequences of different length (ring attention's hops), which
+  `tile_plan` refuses. `window=None` gives the tables and the kernels of before, array for array.
 - block sizes: this module's own defaults are 128 (the MXU tile), but the shipped
   configuration is 1024x1024 via the ops/attention.py dispatch wrapper
   (`tuning_tables/v5e.json`), with automatic step-down for short sequences; interpret
@@ -64,7 +79,8 @@ NEG_INF = -1e30
 # ---------------------------------------------------------------- the tile plan
 
 # bits of a pair's flags, as the kernels read them from the prefetched table
-_FIRST, _LAST, _MASKED, _DIAGONAL = 1, 2, 4, 8
+_FIRST, _LAST, _MASKED, _DIAGONAL, _WINDOW, _EDGE = 1, 2, 4, 8, 16, 32
+_KIND = _MASKED | _DIAGONAL | _WINDOW | _EDGE  # a pair's class: which body it runs
 # side of the squares an aligned diagonal tile is walked in (a module constant, not a
 # knob: tests shrink it to cross the diagonal at interpret-mode sizes)
 _DIAG_SUB_BLOCK = 256
@@ -77,35 +93,57 @@ class TilePlan(NamedTuple):
     same n pairs: kv innermost for `fwd` and `bwd_dq`, q innermost for `bwd` and `bwd_dkv`.
     Flags: _FIRST / _LAST pair of its row in that order (init / finish fire there),
     _MASKED (the diagonal crosses the tile: whole-tile mask), _DIAGONAL (the tile is
-    square and sits on the diagonal: only its lower triangle is walked)."""
+    square and sits on the diagonal: only its lower triangle is walked); with a window,
+    _WINDOW (the window's edge `i - j = W` crosses the tile: whole-tile mask from the other
+    side, beside _MASKED where the diagonal crosses it too) and _EDGE (the tile is square and
+    its own diagonal IS the window's edge: only what lies above that diagonal is walked)."""
 
     q_major: np.ndarray
     kv_major: np.ndarray
     computed: int  # pairs = grid steps of each kernel per (batch, head)
-    interior: int  # wholly at or below the diagonal: no mask
+    interior: int  # wholly at or below the diagonal (and inside the window): no mask
     diagonal: int  # crossed by the diagonal: masked, or walked below it
     skipped_steps: int  # grid steps that compute nothing: 0 (6 of 16 at S 4096 before PR 25)
+    window_edge: int = 0  # crossed by the window's edge (a tile both lines cross counts here and under `diagonal`)
 
     def counts(self) -> dict[str, int]:
-        return {name: getattr(self, name) for name in ("computed", "interior", "diagonal", "skipped_steps")}
+        names = ("computed", "interior", "diagonal", "skipped_steps")
+        return {name: getattr(self, name) for name in names + (("window_edge",) if self.window_edge else ())}
 
 
 @functools.lru_cache(maxsize=None)
-def tile_plan(seq_q: int, seq_k: int, block_q: int, block_k: int, causal: bool) -> TilePlan:
+def tile_plan(seq_q: int, seq_k: int, block_q: int, block_k: int, causal: bool, window: int | None = None) -> TilePlan:
     """Classify every [block_q, block_k] score tile from the static shapes. Causal
     means q position i sees k positions <= i (both counted from 0). A tile above the
     diagonal is no pair at all; the rest are interior or diagonal. A kv tile no q row
-    can see (seq_k > seq_q) keeps one fully masked pair so that its dk/dv are written."""
+    can see (seq_k > seq_q) keeps one fully masked pair so that its dk/dv are written.
+
+    `window` W (causal, seq_q == seq_k) hides also what lies W or more behind: i sees j with
+    `i - W < j <= i`, itself and the W - 1 before it. A tile wholly behind the window's edge is
+    no pair at all, as one above the diagonal is; one the edge crosses is masked from that side,
+    and where it is square with the edge on its own diagonal (W a multiple of the block) it is
+    walked in sub-blocks above that diagonal as the diagonal's tile is below its own.
+    `window=None` gives the tables it always gave, array for array."""
     num_q, num_k = seq_q // block_q, seq_k // block_k
     iq, jk = np.meshgrid(np.arange(num_q), np.arange(num_k), indexing="ij")
+    offset = iq * block_q - jk * block_k  # a tile's first q position less its first k position
     if causal:
-        needed = jk * block_k <= iq * block_q + block_q - 1
-        interior = iq * block_q >= jk * block_k + block_k - 1
+        needed = offset + block_q - 1 >= 0
+        interior = offset >= block_k - 1
         needed[num_q - 1, ~needed.any(axis=0)] = True
     else:
         needed = interior = np.ones_like(iq, dtype=bool)
     on_diagonal = needed & ~interior & (block_q == block_k) & (iq == jk)
     kind = np.where(interior, 0, np.where(on_diagonal, _DIAGONAL, _MASKED))
+    crossed = np.zeros_like(needed)
+    if window is not None:
+        if not causal or seq_q != seq_k or window < 1:
+            raise ValueError("flash attention: a window is causal, over one sequence (seq_q == seq_k), and at least 1 wide")
+        needed = needed & (offset - (block_k - 1) < window)  # the tile's nearest pair (first q row, last k column) is inside the window
+        crossed = needed & (offset + block_q - 1 >= window)  # its farthest pair (last q row, first k column) is not
+        on_edge = crossed & interior & (block_q == block_k) & (offset == window)
+        # a tile both lines cross (W under a block) is masked whole from both sides: no walk in sub-blocks there
+        kind = np.where(on_edge, _EDGE, np.where(crossed, np.where(interior, _WINDOW, _MASKED | _WINDOW), kind))
 
     def table(pairs, row):
         """[3, n] for `pairs` ([n, 2], sorted with column `row` outermost): a row's
@@ -117,10 +155,10 @@ def tile_plan(seq_q: int, seq_k: int, block_q: int, block_k: int, causal: bool) 
         return out
 
     pairs = np.argwhere(needed)  # sorted by q tile, then kv tile
-    computed, n_interior = len(pairs), int((needed & interior).sum())
+    computed, n_diagonal = len(pairs), int((needed & ~interior).sum())
     return TilePlan(
         table(pairs, 0), table(pairs[np.lexsort((pairs[:, 0], pairs[:, 1]))], 1),
-        computed, n_interior, computed - n_interior, 0,
+        computed, int((needed & interior & ~crossed).sum()), n_diagonal, 0, int(crossed.sum()),
     )
 
 
@@ -133,32 +171,43 @@ def _rectangles(cls: int, block_q: int, block_k: int):
     """The static rectangles (row start, rows, col start, cols, masked) a tile of one
     class is computed in. Interior and whole-tile masked: the tile itself. Diagonal:
     column by column in sub-blocks, each given only the q rows from its own first row
-    down — the square on the diagonal, masked, and what lies below it, unmasked."""
+    down — the square on the diagonal, masked, and what lies below it, unmasked. On the
+    window's edge the mirror image: each column of sub-blocks is given the q rows down to
+    its own last row — what lies above the square on the tile's diagonal, unmasked, and that square, masked."""
     sub = _sub_block(block_q, block_k)
-    if not cls & _DIAGONAL or sub == block_q:
+    if not cls & (_DIAGONAL | _EDGE) or sub == block_q:
         return [(0, block_q, 0, block_k, cls != 0)]
     out = []
     for lo in range(0, block_q, sub):
+        if cls & _EDGE and lo:
+            out.append((0, lo, lo, sub, False))
         out.append((lo, sub, lo, sub, True))
-        if lo + sub < block_q:
+        if cls & _DIAGONAL and lo + sub < block_q:
             out.append((lo + sub, block_q - lo - sub, lo, sub, False))
     return out
 
 
-def _by_class(classes, flags, offset, block_q, block_k, body):
-    """Run `body(rows, cols, mask_offset)` over the rectangles of this pair's class
-    (`mask_offset`: the rectangle's first q position less its first k position, None
-    where nothing is hidden). Only the classes the plan holds are traced at all."""
+def _by_class(classes, flags, offset, block_q, block_k, body, window=None):
+    """Run `body(rows, cols, mask)` over the rectangles of this pair's class. `mask` is None
+    where nothing is hidden, else `(causal, edge)`: how far the rectangle's first q position lies
+    past its first k position, for the diagonal's side of the mask, and that less the window, for
+    the window's side (each None where that side hides nothing here; a static 0 on an aligned
+    square, else a traced scalar). Only the classes the plan holds are traced at all."""
     for cls in classes:
         def run(cls=cls):
             for r0, rows, c0, cols, masked in _rectangles(cls, block_q, block_k):
-                mask_offset = None if not masked else (0 if cls & _DIAGONAL else offset)
-                body(pl.ds(r0, rows), pl.ds(c0, cols), mask_offset)
+                if not masked:
+                    mask = None
+                elif cls & (_DIAGONAL | _EDGE):
+                    mask = (0, None) if cls & _DIAGONAL else (None, 0)
+                else:
+                    mask = (offset if cls & _MASKED else None, offset - window if cls & _WINDOW else None)
+                body(pl.ds(r0, rows), pl.ds(c0, cols), mask)
 
         if len(classes) == 1:
             run()
         else:
-            pl.when(flags & (_MASKED | _DIAGONAL) == cls)(run)
+            pl.when(flags & _KIND == cls)(run)
 
 
 def _pair(plan_ref, num_pairs, block_q, block_k):
@@ -168,21 +217,29 @@ def _pair(plan_ref, num_pairs, block_q, block_k):
     return plan_ref[2 * num_pairs + t], plan_ref[t] * block_q - plan_ref[num_pairs + t] * block_k
 
 
-def _keep(shape, offset):
-    """The causal mask of a rectangle whose first q position is `offset` past its first
-    k position (a static 0 on an aligned diagonal square, else a traced scalar)."""
+def _keep(shape, causal, edge):
+    """The mask of a rectangle whose first q position is `causal` past its first k position
+    (the diagonal's side: row + causal >= col) and `edge` past it less the window (the window's
+    side: row + edge < col); a side that is None hides nothing, a static 0 is an aligned square."""
     row = jax.lax.broadcasted_iota(jnp.int32, shape, 0)
     col = jax.lax.broadcasted_iota(jnp.int32, shape, 1)
-    return row >= col if isinstance(offset, int) and offset == 0 else row + offset >= col
+    static_zero = lambda side: isinstance(side, int) and side == 0  # noqa: E731
+    keep = None
+    if causal is not None:
+        keep = row >= col if static_zero(causal) else row + causal >= col
+    if edge is not None:
+        inside = row < col if static_zero(edge) else row + edge < col
+        keep = inside if keep is None else keep & inside
+    return keep
 
 
 def _scores(q, k):
     return jax.lax.dot_general(q, k, (((1,), (1,)), ((), ())), preferred_element_type=jnp.float32)
 
 
-def _masked_scores(q, k, mask_offset):
+def _masked_scores(q, k, mask):
     s = _scores(q, k)
-    return s if mask_offset is None else jnp.where(_keep(s.shape, mask_offset), s, NEG_INF)
+    return s if mask is None else jnp.where(_keep(s.shape, *mask), s, NEG_INF)
 
 
 # --------------------------------------------------------------------------- fwd
@@ -204,7 +261,7 @@ def _across(x, width: int):
 
 
 def _fwd_kernel(plan_ref, q_ref, k_ref, v_ref, o_ref, lse_ref, acc_ref, m_ref, l_ref,
-                *, sm_scale, block_q, block_k, num_pairs, classes):
+                *, sm_scale, block_q, block_k, num_pairs, classes, window=None):
     flags, offset = _pair(plan_ref, num_pairs, block_q, block_k)
     lanes = m_ref.shape[1]
 
@@ -214,11 +271,11 @@ def _fwd_kernel(plan_ref, q_ref, k_ref, v_ref, o_ref, lse_ref, acc_ref, m_ref, l
         m_ref[:] = jnp.full_like(m_ref, NEG_INF)
         l_ref[:] = jnp.zeros_like(l_ref)
 
-    def rectangle(rows, cols, mask_offset):
+    def rectangle(rows, cols, mask):
         q = q_ref[0, 0, rows, :].astype(jnp.float32) * sm_scale  # [R, D]
         k = k_ref[0, 0, cols, :].astype(jnp.float32)  # [C, D]
         v = v_ref[0, 0, cols, :].astype(jnp.float32)
-        s = _masked_scores(q, k, mask_offset)
+        s = _masked_scores(q, k, mask)
         # The running max is kept replicated over `lanes` lanes and the running sum as
         # `lanes` partial sums, folded once at the end: [R, 1] columns cost a row of
         # vregs an operation whatever the tile's width, which made a 1024 x 512 tile
@@ -236,7 +293,7 @@ def _fwd_kernel(plan_ref, q_ref, k_ref, v_ref, o_ref, lse_ref, acc_ref, m_ref, l
         )
         m_ref[rows, :] = m_new
 
-    _by_class(classes, flags, offset, block_q, block_k, rectangle)
+    _by_class(classes, flags, offset, block_q, block_k, rectangle, window)
 
     @pl.when(flags & _LAST != 0)
     def _finish():
@@ -249,27 +306,27 @@ def _fwd_kernel(plan_ref, q_ref, k_ref, v_ref, o_ref, lse_ref, acc_ref, m_ref, l
 
 
 def _bwd_dq_kernel(plan_ref, q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref, dq_ref, dq_acc_ref,
-                   *, sm_scale, block_q, block_k, num_pairs, classes):
+                   *, sm_scale, block_q, block_k, num_pairs, classes, window=None):
     flags, offset = _pair(plan_ref, num_pairs, block_q, block_k)
 
     @pl.when(flags & _FIRST != 0)
     def _init():
         dq_acc_ref[:] = jnp.zeros_like(dq_acc_ref)
 
-    def rectangle(rows, cols, mask_offset):
+    def rectangle(rows, cols, mask):
         q = q_ref[0, 0, rows, :].astype(jnp.float32)
         do = do_ref[0, 0, rows, :].astype(jnp.float32)
         lse = lse_ref[0, 0, rows, :]  # [R, 1]
         delta = delta_ref[0, 0, rows, :]  # [R, 1]
         k = k_ref[0, 0, cols, :].astype(jnp.float32)
         v = v_ref[0, 0, cols, :].astype(jnp.float32)
-        p = jnp.exp(_masked_scores(q * sm_scale, k, mask_offset) - lse)
+        p = jnp.exp(_masked_scores(q * sm_scale, k, mask) - lse)
         ds = p * (_scores(do, v) - delta) * sm_scale
         dq_acc_ref[rows, :] += jax.lax.dot_general(
             ds, k, (((1,), (0,)), ((), ())), preferred_element_type=jnp.float32
         )
 
-    _by_class(classes, flags, offset, block_q, block_k, rectangle)
+    _by_class(classes, flags, offset, block_q, block_k, rectangle, window)
 
     @pl.when(flags & _LAST != 0)
     def _finish():
@@ -279,7 +336,7 @@ def _bwd_dq_kernel(plan_ref, q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref, dq
 # -------------------------------------------------------------------- bwd: dkdv
 
 
-def _add_dkv_terms(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref, dk_acc_ref, dv_acc_ref, rows, cols, mask_offset, sm_scale):
+def _add_dkv_terms(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref, dk_acc_ref, dv_acc_ref, rows, cols, mask, sm_scale):
     """A rectangle's p and ds from the saved logsumexp, its terms added into the dk and dv
     accumulators; (ds, k) go back to the kernel that also forms ds k."""
     k = k_ref[0, 0, cols, :].astype(jnp.float32)
@@ -288,7 +345,7 @@ def _add_dkv_terms(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref, dk_acc_ref, 
     do = do_ref[0, 0, rows, :].astype(jnp.float32)
     lse = lse_ref[0, 0, rows, :]  # [R, 1]
     delta = delta_ref[0, 0, rows, :]  # [R, 1]
-    p = jnp.exp(_masked_scores(q * sm_scale, k, mask_offset) - lse)
+    p = jnp.exp(_masked_scores(q * sm_scale, k, mask) - lse)
     dv_acc_ref[cols, :] += jax.lax.dot_general(
         p, do, (((0,), (0,)), ((), ())), preferred_element_type=jnp.float32
     )
@@ -300,7 +357,7 @@ def _add_dkv_terms(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref, dk_acc_ref, 
 
 
 def _bwd_dkv_kernel(plan_ref, q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref, dk_ref, dv_ref,
-                    dk_acc_ref, dv_acc_ref, *, sm_scale, block_q, block_k, num_pairs, classes):
+                    dk_acc_ref, dv_acc_ref, *, sm_scale, block_q, block_k, num_pairs, classes, window=None):
     flags, offset = _pair(plan_ref, num_pairs, block_q, block_k)
 
     @pl.when(flags & _FIRST != 0)
@@ -308,10 +365,10 @@ def _bwd_dkv_kernel(plan_ref, q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref, d
         dk_acc_ref[:] = jnp.zeros_like(dk_acc_ref)
         dv_acc_ref[:] = jnp.zeros_like(dv_acc_ref)
 
-    def rectangle(rows, cols, mask_offset):
-        _add_dkv_terms(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref, dk_acc_ref, dv_acc_ref, rows, cols, mask_offset, sm_scale)
+    def rectangle(rows, cols, mask):
+        _add_dkv_terms(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref, dk_acc_ref, dv_acc_ref, rows, cols, mask, sm_scale)
 
-    _by_class(classes, flags, offset, block_q, block_k, rectangle)
+    _by_class(classes, flags, offset, block_q, block_k, rectangle, window)
 
     @pl.when(flags & _LAST != 0)
     def _finish():
@@ -370,7 +427,7 @@ def backward_plan(seq_q: int, block_q: int, block_k: int, head_dim: int, head_di
 
 
 def _bwd_kernel(plan_ref, q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref, dq_ref, dk_ref, dv_ref,
-                dq_acc_ref, dk_acc_ref, dv_acc_ref, *, sm_scale, block_q, block_k, num_pairs, classes):
+                dq_acc_ref, dk_acc_ref, dv_acc_ref, *, sm_scale, block_q, block_k, num_pairs, classes, window=None):
     """The plan's kv-major walk, once: p and ds of a rectangle feed dv, dk (accumulated over
     the kv tile's q tiles, as `_bwd_dkv_kernel` does) and dq, whose float32 rows of the whole
     (batch, q head) stay in `dq_acc_ref` [q tiles, block_q, D] from the head's first pair to
@@ -388,13 +445,13 @@ def _bwd_kernel(plan_ref, q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref, dq_re
         dk_acc_ref[:] = jnp.zeros_like(dk_acc_ref)
         dv_acc_ref[:] = jnp.zeros_like(dv_acc_ref)
 
-    def rectangle(rows, cols, mask_offset):
-        ds, k = _add_dkv_terms(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref, dk_acc_ref, dv_acc_ref, rows, cols, mask_offset, sm_scale)
+    def rectangle(rows, cols, mask):
+        ds, k = _add_dkv_terms(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref, dk_acc_ref, dv_acc_ref, rows, cols, mask, sm_scale)
         dq_acc_ref[q_tile, rows, :] += jax.lax.dot_general(
             ds, k, (((1,), (0,)), ((), ())), preferred_element_type=jnp.float32
         )
 
-    _by_class(classes, flags, offset, block_q, block_k, rectangle)
+    _by_class(classes, flags, offset, block_q, block_k, rectangle, window)
 
     @pl.when(flags & _LAST != 0)
     def _finish():
@@ -408,7 +465,7 @@ def _bwd_kernel(plan_ref, q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref, dq_re
 
 
 def _tiled_call(kernel, table, name, *, sm_scale, batch, num_heads, group, block_q, block_k, head_dim, head_dim_v,
-                inputs, outputs, out_shape, scratch_shapes, interpret, vmem_limit_bytes=None, aliases=None):
+                inputs, outputs, out_shape, scratch_shapes, interpret, vmem_limit_bytes=None, aliases=None, window=None):
     """One `pallas_call` of `kernel` over grid (batch, head, pair), each pair's tiles
     looked up in `table` (a plan's int32 [3, n]: q tile, kv tile, flags), which goes in
     flat as the one scalar-prefetched operand. `inputs` / `outputs` name each operand's
@@ -431,9 +488,10 @@ def _tiled_call(kernel, table, name, *, sm_scale, batch, num_heads, group, block
     specs = dict(zip(("q", "kv", "k_out"), tilings(head_dim)), **dict(zip(("qv", "v", "v_out"), tilings(head_dim_v))))
     specs["row"] = pl.BlockSpec((1, 1, block_q, 1), lambda b, h, t, plan: (b, h, plan[t], 0))
     specs["q_rows"] = pl.BlockSpec((1, 1, (int(table[0].max()) + 1) * block_q, head_dim), lambda b, h, t, plan: (b, h, 0, 0))
-    classes = np.unique(table[2] & (_MASKED | _DIAGONAL)).tolist()
+    classes = np.unique(table[2] & _KIND).tolist()
+    windowed = {} if window is None else {"window": window}  # a call without a window binds what it always bound
     call = pl.pallas_call(
-        functools.partial(kernel, sm_scale=sm_scale, block_q=block_q, block_k=block_k, num_pairs=n, classes=classes),
+        functools.partial(kernel, sm_scale=sm_scale, block_q=block_q, block_k=block_k, num_pairs=n, classes=classes, **windowed),
         grid_spec=pltpu.PrefetchScalarGridSpec(
             num_scalar_prefetch=1,
             grid=(batch, num_heads, n),
@@ -481,7 +539,11 @@ def env_flash_blocks(seq_q: int, seq_k: int, dtype="bfloat16", head_dim: int | N
     `backward=True` asks for the fused backward's blocks: the table's `flash_attention_bwd`
     entry of the same bucket where it has one (192/128 on a v5e: 1024 x 1024, which that
     kernel's own VMEM limit holds and which read 25.90 ms a layer against 28.75 at the
-    forward's 1024 x 512; PERF.md section 6, PR 31), else the forward's blocks."""
+    forward's 1024 x 512; PERF.md section 6, PR 31), else the forward's blocks.
+
+    A windowed call reads the same entries: at window 1024 and head 128 the chip read the
+    default's 1024 x 1024 fastest of three pairs (PERF.md section 6, PR 38), so it has no
+    bucket of its own until a window or a width reads faster at other blocks."""
     import os
 
     env_q = os.environ.get("MODALITIES_TPU_FLASH_BLOCK_Q")
@@ -506,13 +568,19 @@ def env_flash_blocks(seq_q: int, seq_k: int, dtype="bfloat16", head_dim: int | N
     return _pick_block(seq_q, block_q), _pick_block(seq_k, block_k)
 
 
-@functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4, 5, 6, 7, 8))
-def _flash_attention_bhsd(q, k, v, sm_scale, causal, block_q, block_k, bwd_blocks, interpret):
-    out, _ = _flash_fwd(q, k, v, sm_scale, causal, block_q, block_k, interpret)
+def _name(kernel: str, window) -> str:
+    """A windowed call's kernels carry their own label: readers count by label and per call, and a window
+    layer's call does other work than a global layer's at the same shapes."""
+    return f"flash_attention_{kernel}" if window is None else f"flash_attention_window_{kernel}"
+
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4, 5, 6, 7, 8, 9))
+def _flash_attention_bhsd(q, k, v, sm_scale, causal, block_q, block_k, bwd_blocks, interpret, window=None):
+    out, _ = _flash_fwd(q, k, v, sm_scale, causal, block_q, block_k, interpret, window)
     return out
 
 
-def _flash_fwd(q, k, v, sm_scale, causal, block_q, block_k, interpret):
+def _flash_fwd(q, k, v, sm_scale, causal, block_q, block_k, interpret, window=None):
     """q: [B, Hq, Sq, D]; k/v: [B, Hkv, Sk, D] -> (out, residuals)."""
     batch, num_heads, seq_q, head_dim = q.shape
     num_kv_heads, seq_k, head_dim_v = k.shape[1], k.shape[2], v.shape[3]
@@ -520,7 +588,7 @@ def _flash_fwd(q, k, v, sm_scale, causal, block_q, block_k, interpret):
 
     lanes = _stat_lanes(block_q, block_k)
     out, lse = _tiled_call(
-        _fwd_kernel, tile_plan(seq_q, seq_k, block_q, block_k, causal).q_major, "flash_attention_fwd",
+        _fwd_kernel, tile_plan(seq_q, seq_k, block_q, block_k, causal, window).q_major, _name("fwd", window), window=window,
         sm_scale=sm_scale, batch=batch, num_heads=num_heads, group=group, block_q=block_q, block_k=block_k,
         head_dim=head_dim, head_dim_v=head_dim_v,
         inputs=("q", "kv", "v"), outputs=("qv", "row"),
@@ -549,13 +617,13 @@ def flash_fwd_out_lse(q, k, v, *, causal, sm_scale, block_q, block_k, interpret)
     return out, lse
 
 
-def _flash_fwd_vjp(q, k, v, sm_scale, causal, block_q, block_k, bwd_blocks, interpret):
+def _flash_fwd_vjp(q, k, v, sm_scale, causal, block_q, block_k, bwd_blocks, interpret, window=None):
     # custom_vjp fwd receives arguments in the primal order (nondiff included in place)
-    out, res = _flash_fwd(q, k, v, sm_scale, causal, block_q, block_k, interpret)
+    out, res = _flash_fwd(q, k, v, sm_scale, causal, block_q, block_k, interpret, window)
     return out, res
 
 
-def flash_bwd_dq(q, k, v, do, lse, delta, *, causal, sm_scale, block_q, block_k, interpret):
+def flash_bwd_dq(q, k, v, do, lse, delta, *, causal, sm_scale, block_q, block_k, interpret, window=None):
     """dq for one (q, k, v) pairing given GLOBAL (lse, delta) — reusable by the ring
     backward, where lse/delta come from the merged multi-hop softmax. All [B,H,S,D];
     lse/delta [B,H,Sq,1] fp32."""
@@ -564,7 +632,7 @@ def flash_bwd_dq(q, k, v, do, lse, delta, *, causal, sm_scale, block_q, block_k,
     group = num_heads // k.shape[1]
 
     (dq,) = _tiled_call(
-        _bwd_dq_kernel, tile_plan(seq_q, seq_k, block_q, block_k, causal).q_major, "flash_attention_bwd_dq",
+        _bwd_dq_kernel, tile_plan(seq_q, seq_k, block_q, block_k, causal, window).q_major, _name("bwd_dq", window), window=window,
         sm_scale=sm_scale, batch=batch, num_heads=num_heads, group=group, block_q=block_q, block_k=block_k,
         head_dim=head_dim, head_dim_v=v.shape[3],
         inputs=("q", "kv", "v", "qv", "row", "row"), outputs=("q",),
@@ -575,7 +643,7 @@ def flash_bwd_dq(q, k, v, do, lse, delta, *, causal, sm_scale, block_q, block_k,
     return dq
 
 
-def flash_bwd_dkv(q, k, v, do, lse, delta, *, causal, sm_scale, block_q, block_k, interpret):
+def flash_bwd_dkv(q, k, v, do, lse, delta, *, causal, sm_scale, block_q, block_k, interpret, window=None):
     """(dk, dv) for one (q, k, v) pairing given GLOBAL (lse, delta), GQA group-summed
     down to the kv heads ([B, Hkv, Sk, D]). Reusable by the ring backward, where the
     accumulators ride the k/v rotation."""
@@ -585,7 +653,7 @@ def flash_bwd_dkv(q, k, v, do, lse, delta, *, causal, sm_scale, block_q, block_k
 
     # dk/dv per q-head (q blocks innermost), then summed over the GQA group
     dk_h, dv_h = _tiled_call(
-        _bwd_dkv_kernel, tile_plan(seq_q, seq_k, block_q, block_k, causal).kv_major, "flash_attention_bwd_dkv",
+        _bwd_dkv_kernel, tile_plan(seq_q, seq_k, block_q, block_k, causal, window).kv_major, _name("bwd_dkv", window), window=window,
         sm_scale=sm_scale, batch=batch, num_heads=num_heads, group=group, block_q=block_q, block_k=block_k,
         head_dim=head_dim, head_dim_v=head_dim_v,
         inputs=("q", "kv", "v", "qv", "row", "row"), outputs=("k_out", "v_out"),
@@ -613,7 +681,7 @@ def _group_sum(dk_h, dv_h, k, v):
     return dk_h.astype(k.dtype), dv_h.astype(v.dtype)
 
 
-def flash_bwd(q, k, v, do, lse, delta, *, causal, sm_scale, block_q, block_k, interpret):
+def flash_bwd(q, k, v, do, lse, delta, *, causal, sm_scale, block_q, block_k, interpret, window=None):
     """(dq, dk, dv) from one kernel, `flash_attention_bwd`: one walk of the plan's kv-major
     table, p and ds evaluated once a tile (5 products for the 7 of `flash_bwd_dq` +
     `flash_bwd_dkv`, whose sums it repeats in their order). The caller has checked
@@ -622,7 +690,7 @@ def flash_bwd(q, k, v, do, lse, delta, *, causal, sm_scale, block_q, block_k, in
     num_kv_heads, seq_k, head_dim_v = k.shape[1], k.shape[2], v.shape[3]
 
     dq, dk_h, dv_h = _tiled_call(
-        _bwd_kernel, tile_plan(seq_q, seq_k, block_q, block_k, causal).kv_major, "flash_attention_bwd",
+        _bwd_kernel, tile_plan(seq_q, seq_k, block_q, block_k, causal, window).kv_major, _name("bwd", window), window=window,
         sm_scale=sm_scale, batch=batch, num_heads=num_heads, group=num_heads // num_kv_heads,
         block_q=block_q, block_k=block_k, head_dim=head_dim, head_dim_v=head_dim_v,
         inputs=("q", "kv", "v", "qv", "row", "row"), outputs=("q_rows", "k_out", "v_out"),
@@ -644,11 +712,11 @@ def flash_bwd(q, k, v, do, lse, delta, *, causal, sm_scale, block_q, block_k, in
     return dq, *_group_sum(dk_h, dv_h, k, v)
 
 
-def _flash_bwd_vjp(sm_scale, causal, block_q, block_k, bwd_blocks, interpret, res, do):
+def _flash_bwd_vjp(sm_scale, causal, block_q, block_k, bwd_blocks, interpret, window, res, do):
     q, k, v, out, lse = res
     # [B, H, Sq, 1] — trailing singleton lane dim (see module docstring)
     delta = jnp.sum(do.astype(jnp.float32) * out.astype(jnp.float32), axis=-1, keepdims=True)
-    kw = dict(causal=causal, sm_scale=sm_scale, interpret=interpret)
+    kw = dict(causal=causal, sm_scale=sm_scale, interpret=interpret, window=window)
     if backward_plan(q.shape[2], *bwd_blocks, q.shape[3], v.shape[3], q.dtype)["backward"] == "fused":
         return flash_bwd(q, k, v, do, lse, delta, block_q=bwd_blocks[0], block_k=bwd_blocks[1], **kw)
     # the two kernels at the forward's blocks: what such a row ran before PR 31
@@ -663,12 +731,13 @@ _flash_attention_bhsd.defvjp(_flash_fwd_vjp, _flash_bwd_vjp)
 def pallas_flash_attention(
     q, k, v, causal: bool = True, sm_scale: float | None = None,
     block_q: int = 128, block_k: int = 128, interpret: bool = False,
-    bwd_blocks: tuple[int, int] | None = None,
+    bwd_blocks: tuple[int, int] | None = None, window: int | None = None,
 ):
     """Public entry. q: [B, S, Hq, D], k: [B, S, Hkv, D], v: [B, S, Hkv, Dv] (model layout)
     -> [B, S, Hq, Dv]. Dv may differ from D (latent attention: 192 for q and k, 128 for v);
     the default scale is that of D. `bwd_blocks`: (block_q, block_k) of the fused backward
-    where the tuning table gives it its own; the forward's otherwise."""
+    where the tuning table gives it its own; the forward's otherwise. `window` W (causal only):
+    position i sees itself and the W - 1 before it (`tile_plan`); None sees all that came before."""
     if sm_scale is None:
         sm_scale = 1.0 / math.sqrt(q.shape[-1])
     seq_q, seq_k = q.shape[1], k.shape[1]
@@ -678,5 +747,5 @@ def pallas_flash_attention(
     qt = q.transpose(0, 2, 1, 3)
     kt = k.transpose(0, 2, 1, 3)
     vt = v.transpose(0, 2, 1, 3)
-    out = _flash_attention_bhsd(qt, kt, vt, sm_scale, causal, block_q, block_k, bwd_blocks, interpret)
+    out = _flash_attention_bhsd(qt, kt, vt, sm_scale, causal, block_q, block_k, bwd_blocks, interpret, window)
     return out.transpose(0, 2, 1, 3)

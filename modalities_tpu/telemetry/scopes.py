@@ -53,6 +53,14 @@ Expert layer (`models/gpt2/moe.py`, `ops/expert_dispatch.py`), under the module 
     MOE_SHARED        shared            the shared expert, a dense SwiGLU on every token (the name of its module)
     MOE_COMBINE       combine           the rows weighed and added back by token, the sum with the shared expert
 
+A stack of window and global attention layers (`layer_types`, PR 38; `models/gpt2/gpt2_model.py`), round a block's `attn` module:
+
+    ATTN_WINDOW       window            a `sliding_attention` layer's attention: `block/window/attn/{q_attn,...,rope,attn_core}`;
+                                        `attn/rope` holds this kind's tables, `attn/attn_core` the windowed kernels
+                                        (`flash_attention_window_{fwd,bwd}`)
+    ATTN_GLOBAL       global            a `full_attention` layer's: `block/global/attn/...` (YaRN's tables under `attn/rope` where
+                                        `rope_parameters` asks for them); a model without `layer_types` has neither name
+
 Looped decoder (`loop_config`: the stack walked several times over one set of weights; `models/gpt2/gpt2_model.py`, `training/train_step.py`):
 
     LOOP              loop              round the walks: the carry between walks, every walk's exit stacked, and in the backward
@@ -109,6 +117,9 @@ SSM_CONV = "conv"
 SSM_SCAN = "scan"
 SSM_GATE = "gate"
 
+ATTN_WINDOW = "window"  # round the attention of a window layer, and of a global layer beside one
+ATTN_GLOBAL = "global"
+
 LOOP = "loop"  # a looped model's walks
 EXIT_GATE = "exit_gate"  # and the name of the gate's module
 EXIT_LOSS = "exit_loss"
@@ -127,6 +138,8 @@ MODEL_SCOPES = (WTE, ROPE, ATTN_CORE, RESIDUAL, LAYER_CARRY)
 SSM_SCOPES = (SSM_CONV, SSM_SCAN, SSM_GATE)  # on the step only where a layer holds the state-space mixer
 
 LOOP_SCOPES = (LOOP, EXIT_GATE, EXIT_LOSS)  # on the step only where the stack is walked several times (`loop_config`)
+
+WINDOW_SCOPES = (ATTN_WINDOW, ATTN_GLOBAL)  # on the step only where the stack holds window layers (`layer_types`)
 
 MOE_SCOPES = (MOE_ROUTER, MOE_DISPATCH, MOE_EXPERTS, MOE_SHARED, MOE_COMBINE)  # on the step only where a layer holds experts
 

@@ -472,6 +472,12 @@ class TrainStepBuilder:
         if counted_shapes and (mesh_handle is not None and mesh_handle.degrees.get("dcn", 1) > 1):
             raise NotImplementedError("a model that counts in a step (expert layers) under a dcn mesh axis: the per-slice groups do not carry what it counts")
 
+        def _with_layers_term(loss, counted):
+            """The loss with the term the layers hand up beside their counters (an expert layer's balance term:
+            `NNModel.loss_from_layers`), added inside the differentiated function; the loss as it is where there is none."""
+            term = model.loss_from_layers(counted)
+            return loss if term is None else loss + term
+
         if chunked_loss:
             # fused head + CE per sequence chunk: the [B,S,V] fp32 logits never
             # materialize (6.6 GB at 32k ctx x 50k vocab). Each chunk's projection
@@ -575,7 +581,7 @@ class TrainStepBuilder:
                 if trains_on_exits:
                     loss, exit_counted = _exit_ce(params, hidden, targets[target_key])
                     return loss, {**counted, **exit_counted}
-                return _chunked_ce(params, hidden, targets[target_key]), counted
+                return _with_layers_term(_chunked_ce(params, hidden, targets[target_key]), counted), counted
 
         else:
 
@@ -583,7 +589,7 @@ class TrainStepBuilder:
                 rngs = {"dropout": dropout_rng} if dropout_rng is not None else None
                 predictions, counted = model.apply_counted(params, samples, train=True, rngs=rngs)
                 with jax.named_scope(scopes.HEAD_LOSS):
-                    return loss_fn(predictions, targets), counted
+                    return _with_layers_term(loss_fn(predictions, targets), counted), counted
 
         # scheduled pipelining (1F1B): hand-rolled fwd/bwd with in-region loss replaces
         # value_and_grad through the in-module autodiff GPipe (the "gpipe" default)

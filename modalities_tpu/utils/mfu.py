@@ -60,6 +60,9 @@ class GPT2MFUCalculator(MFUCalculatorIF):
     average), and `6 * s * H * (qk_head_dim + v_head_dim)` a layer for the attention's two
     products at their two head sizes (full, as the dense formula counts attention).
 
+    A layer under a window (`layer_types`: `sliding_attention`) sees `sliding_window` positions and not the
+    sequence: it adds `6 * min(2 W, s) * H * 2 * head_dim`, and `head_dim` is the config's own where it gives one.
+
     A looped model (`loop_config`) uses a parameter once for every walk, and `6N` would count it
     once: its required operations are `6 x a layer's kernels x L x T` + `6 x T x L x s x h` (the
     causal half of attention, a layer application) + `6 x T x E x V` (the head, once an exit) a
@@ -90,6 +93,12 @@ class GPT2MFUCalculator(MFUCalculatorIF):
         self.n_attention_layer = kinds.count("attn") if kinds else n_layer
         self.active_parameters = self.num_parameters
         self.attention_width = 2 * n_embd  # q k^T and p v, each n_head * head_dim = n_embd wide
+        if getattr(spec, "head_dim_key", None) is not None:
+            self.attention_width = 2 * spec.n_head_q * spec.head_dim
+        # a window layer's positions, in the formula's own convention (the `s` of `12 L s h` is twice what a causal row
+        # sees on average): twice the window, never more than the sequence
+        window = getattr(spec, "sliding_window", None)
+        self.window_positions = kinds.count("swa") * min(2 * window, sequence_length) if window else 0
         moe, mla = getattr(spec, "moe", None), getattr(spec, "mla", None)
         if moe is not None:
             expert = 3 * n_embd * moe.moe_intermediate_size
@@ -109,7 +118,8 @@ class GPT2MFUCalculator(MFUCalculatorIF):
 
     def compute(self, tokens_per_second: float) -> float:
         flops_per_token = self.looped_flops_per_token or (
-            6 * self.active_parameters + 6 * self.n_attention_layer * self.sequence_length * self.attention_width)
+            6 * self.active_parameters
+            + 6 * (self.n_attention_layer * self.sequence_length + self.window_positions) * self.attention_width)
         return tokens_per_second * flops_per_token / (self.world_size * self._peak)
 
 

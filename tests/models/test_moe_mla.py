@@ -93,7 +93,7 @@ def test_the_stack_has_runs_by_mixer_and_feed_forward(toy):
 @pytest.mark.parametrize("block, key, value, match", [
     ("mla_config", "q_lora_rank", 64, "q_lora_rank"), ("mla_config", "rope_scaling", {"type": "yarn"}, "rope_scaling"),
     ("moe_config", "n_group", 2, "n_group"), ("moe_config", "topk_group", 2, "topk_group"),
-    ("moe_config", "scoring_func", "softmax", "scoring_func"), ("moe_config", "expert_offset", 6, "exceeds n_routed_experts"),
+    ("moe_config", "scoring_func", "tanh", "scoring_func"), ("moe_config", "expert_offset", 6, "exceeds n_routed_experts"),
 ])
 def test_what_is_not_written_is_refused_at_config_time(block, key, value, match):
     with pytest.raises(ValueError, match=match):

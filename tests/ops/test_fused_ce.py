@@ -225,6 +225,19 @@ def test_blocks_step_down_by_width_as_the_chip_compiles_them():
     assert 16128 % 256 == 0
 
 
+def test_width_2304_takes_the_blocks_of_its_neighbours_and_the_plan_says_so():
+    """A fifth width through the fit (PR 38, the window-and-global cell: E 2304 against 12,288 rows): 256 x 256, as at 2048 and
+    2560, which divides the head's rows; `ce_plan` reports the choice as for every width. The whole step compiled for a described
+    v5e at these blocks (meta.json, memory_analysis) and tests/ops/test_tpu_compile.py compiles the kernels alone."""
+    from modalities_tpu.ops.pallas import fused_ce
+
+    assert fused_ce._fit_blocks_to_vmem(256, 512, 2304, 2) == (256, 256) and 12288 % 256 == 0
+    assert fused_ce._forward_in_step_vmem_bytes(256, 512, 2304, 2) > 16 * 2**20 > fused_ce._forward_in_step_vmem_bytes(256, 256, 2304, 2)
+    plan = fused_ce.ce_plan(16384, 12288, 12288, 2304, 256, 256, 2, dh_in_forward=True)
+    assert (plan["n_embd"], plan["block_rows"], plan["block_vocab"], plan["grid_steps_forward"]) == (2304, 256, 256, 64 * 48)
+    assert plan["forward_vmem_bytes"] == fused_ce._forward_vmem_bytes(256, 256, 2304, 2, True) < 16 * 2**20
+
+
 # ------------------------------------------------------------------ the per-row entry (a looped model's loss over its exits)
 
 

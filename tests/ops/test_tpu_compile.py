@@ -77,6 +77,20 @@ def _flash(heads_q, heads_kv, head_dim, seq=SEQ, kernels=FUSED):
     return jax.grad(loss, argnums=(0, 1, 2)), (q, kv, kv), kernels
 
 
+WINDOW_FUSED = ("flash_attention_window_fwd", "flash_attention_window_bwd")
+
+
+def _flash_window(block_q, block_k, window=1024, seq=4 * SEQ):
+    """The window layers' call of benchmark/configs/mellum2-12b-a2p5b-d12 (PR 38): one row of 16,384, 32 q on 4 kv heads of
+    128 under a window of 1024, forward and the fused backward (a head's dq row of 16 MiB float32 resident: 41.9 MiB counted
+    of the kernel's 48), under the windowed calls' own labels; at the three block pairs the builder read on the chip."""
+    def loss(q, k, v):
+        return pallas_flash_attention(q, k, v, block_q=block_q, block_k=block_k, window=window).astype(F32).sum()
+
+    q, kv = ((1, seq, 32, 128), BF16), ((1, seq, 4, 128), BF16)
+    return jax.grad(loss, argnums=(0, 1, 2)), (q, kv, kv), WINDOW_FUSED
+
+
 def _flash_two_widths(batch, seq, heads, head_dim, head_dim_v):
     """Latent attention's kernels: q and k wider than v (benchmark/configs/kanana2-30b-a3b-d9: 2 x 8192 x 32 heads of
     192 / 128), at the blocks the tuning table's own bucket gives them (1024 x 1024 asks 17.27 MiB for `bwd_dq`); the
@@ -160,6 +174,12 @@ CASES = {
     "flash_fwd_bwd_d192_dv128_b2_s8192_h32": _flash_two_widths(2, 8192, 32, 192, 128),
     # configs/config_kanana2_30b_a3b.yaml's own shape: sequence 4096, microbatch 4
     "flash_fwd_bwd_d192_dv128_b4_s4096_h32": _flash_two_widths(4, 4096, 32, 192, 128),
+    # the window-and-global cell (PR 38): its window layers' call at three block pairs, its global layers' at 16,384, its head at width 2304
+    "flash_window_fwd_bwd_w1024_s16384_gqa_32_4_b1024x1024": _flash_window(1024, 1024),
+    "flash_window_fwd_bwd_w1024_s16384_gqa_32_4_b1024x512": _flash_window(1024, 512),
+    "flash_window_fwd_bwd_w1024_s16384_gqa_32_4_b512x512": _flash_window(512, 512),
+    "flash_fwd_bwd_d128_gqa_32_4_s16384": _flash(32, 4, 128, seq=4 * SEQ),
+    "fused_ce_fwd_bwd_e2304_rows16384_v12288": _fused_ce(2304, 4 * SEQ, vocab=12288),
     "fused_ce_fwd_bwd_e2048_rows16384_v16128": _fused_ce(2048, 4 * SEQ, vocab=16128),
     "fused_ce_rows_fwd_bwd_e2048_exits4_rows4096_v49152": _fused_ce_rows(2048, 4, SEQ, 49152),
     "fused_ce_rows_fwd_bwd_e2048_exits4_rows8192_v49152": _fused_ce_rows(2048, 4, 2 * SEQ, 49152),
@@ -195,13 +215,14 @@ LOOKUPS = {
     "train-jamba2-3b-4k": (1, SEQ, 2560, 32768),
     "train-ouro-2p6b-4k": (1, SEQ, 2048, 49152),
     "train-kanana2-30b-8k": (2, 8192, 2048, 16128),
+    "train-mellum2-12b-16k": (1, 4 * SEQ, 2304, 12288),
 }
 
 
 @pytest.mark.parametrize("cell", sorted(LOOKUPS))
 def test_embedding_gradient_follows_the_compilers_switch(v5e, cell):
     """The compiler sorts a scatter's indices when they number more than an eighth of the operand's rows, which in HBM
-    costs 2.4 us a row (ops/embedding.py). Where the rule cuts the lookup into pieces, here the dense cell, the compiled
+    costs 2.4 us a row (ops/embedding.py). Where the rule cuts the lookup into pieces, here the dense cell and the window-and-global cell, the compiled
     gradient holds the plan's scatters and no sort; elsewhere it is the program `jax.grad` of `jnp.take` compiles to. A
     libtpu that moves the switch fails here instead of silently costing the dense cell 17 ms a step."""
     batch, seq, n_embd, vocab = LOOKUPS[cell]
@@ -212,7 +233,8 @@ def test_embedding_gradient_follows_the_compilers_switch(v5e, cell):
         lambda table, ids, weights: (lookup(table, ids) * weights).astype(F32).sum())).lower(*args).compile().as_text()
     text, plan = compiled(embedding_lookup), grad_plan((batch, seq), vocab, n_embd, 2)
     if plan["form"] == "chunked":
-        assert cell == "train-2p7b-4k" and plan["rows_per_chunk"] <= vocab // 8
+        # the dense cell, and since PR 38 the window-and-global cell: 16,384 ids against the 12,288 rows this chip holds
+        assert cell in ("train-2p7b-4k", "train-mellum2-12b-16k") and plan["rows_per_chunk"] <= vocab // 8
         assert len(re.findall(r" scatter\(", text)) == plan["chunks"] and " sort(" not in text
         assert " sort(" in compiled(lambda table, ids: jnp.take(table, ids, axis=0)), "the default the rule avoids"
     else:
