@@ -261,11 +261,15 @@ class MoE(nn.Module):
         batch, seq, width = x.shape
         tokens = x.reshape(batch * seq, width)
         pairs = batch * seq * moe.num_experts_per_tok
+        combine = expert_dispatch.combine_form(batch * seq, moe.num_experts_per_tok, moe.experts_held, width)
+        block = expert_dispatch.combine_block(batch * seq)
         # runs while tracing: once per shape, nothing per step
         get_active_telemetry().emit_event_once("moe_dispatch_plan", {
             "tokens": batch * seq, "router_width": moe.n_routed_experts, "choices": moe.num_experts_per_tok,
             "experts_held": moe.experts_held, "expert_offset": moe.expert_offset, "tile": expert_dispatch.TILE,
-            "rows": expert_dispatch.rows_for(pairs, moe.experts_held, expert_dispatch.TILE), "kernels": False,
+            "rows": expert_dispatch.rows_for(pairs, moe.experts_held, expert_dispatch.TILE),
+            "kernels": ("moe_combine",) if combine == "slabs" else (),  # the grouped products and the tiles' gathers are the plain form
+            "combine": combine, "combine_block": block, "combine_blocks_at_most": moe.experts_held * -(-batch * seq // block),
         })
 
         with jax.named_scope(scopes.MOE_ROUTER):
@@ -278,8 +282,8 @@ class MoE(nn.Module):
             if aux is not None:
                 counters = jnp.concatenate([counters, aux[None]])
         w, v, w_2 = _Experts(spec, name="experts")()
-        # one loop over the tiles in use; inside it the gather is `dispatch`, the products `experts`, the add back `combine`
-        routed = expert_dispatch.routed_experts(tokens, choice, weights, w, v, w_2, offset=moe.expert_offset, plan=plan)
+        # one loop over the tiles in use; inside it the gather is `dispatch`, the products `experts`; the sum by token after it `combine`
+        routed = expert_dispatch.routed_experts(tokens, choice, weights, w, v, w_2, offset=moe.expert_offset, plan=plan, combine=combine)
         out = routed.reshape(x.shape)
         if moe.shared_hidden:
             shared = _SharedExpert(spec, moe.shared_hidden, name=scopes.MOE_SHARED)(x)

@@ -362,3 +362,10 @@ def batch_sharding(mesh_handle: DeviceMeshHandle) -> NamedSharding:
 
 def replicated(mesh_handle: DeviceMeshHandle) -> NamedSharding:
     return NamedSharding(mesh_handle.mesh, P())
+
+
+def installed_mesh_size() -> int:
+    """Devices of the mesh the step installed with `activation_rules`; 1 with none installed. For code that
+    has a form for one device only and leaves the other to GSPMD (`ops/expert_dispatch.combine_form`)."""
+    state = getattr(_ACTIVATION_RULES, "state", None)
+    return int(state[1].size) if state else 1

@@ -48,10 +48,11 @@ State-space mixer (`models/gpt2/ssm.py`), under the module name `ssm` that Flax 
 Expert layer (`models/gpt2/moe.py`, `ops/expert_dispatch.py`), under the module name `moe` that Flax gives it in a block's `MLP` seat:
 
     MOE_ROUTER        router            scores, choice and weights, float32 (the name of its module)
-    MOE_DISPATCH      dispatch          the sort of the pairs by expert, the group sizes and tables, each tile's gather of its tokens
+    MOE_DISPATCH      dispatch          the sort of the pairs by expert, the group sizes and tables (the `slabs` form's by block and expert too), each tile's gather of its tokens
     MOE_EXPERTS       experts           the grouped products of the held experts, both passes (and the module that holds their three stacks)
     MOE_SHARED        shared            the shared expert, a dense SwiGLU on every token (the name of its module)
-    MOE_COMBINE       combine           the rows weighed and added back by token, the sum with the shared expert
+    MOE_COMBINE       combine           the rows weighed and summed by token (the kernel `moe_combine` over slabs of rows, or k gathers:
+                                        `ops/expert_dispatch.combine_plan`), its transpose in the tiles, the sum with the shared expert
 
 A stack of window and global attention layers (`layer_types`, PR 38; `models/gpt2/gpt2_model.py`), round a block's `attn` module:
 

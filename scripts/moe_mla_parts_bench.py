@@ -8,9 +8,11 @@ tool for the per-part prices PERF.md quotes, not a cell: nothing in `benchmark/`
   (`ops/pallas/flash_attention.backward_plan`): `flash_bwd_fused_*` is `flash_attention_bwd`
   (PR 31), `flash_bwd_two_kernels_*` is `flash_attention_bwd_dq` + `_bwd_dkv`.
 - `moe`: one expert layer's routed part (`ops/expert_dispatch.py`: plan, gathers, grouped
-  products, add back by token), forward with backward, at 16,384 tokens of width 2048
-  routed 6 of 128 with the experts `0 .. held - 1` of 768 held; loads uniform, and for
-  the extremes all pairs on one held expert and none on any.
+  products, sum by token), forward and forward with backward, and its sum by token alone, each
+  in both forms of the sum (`combine_plan`: `gathers`, k gathers of [T, d]; `slabs`, the kernel
+  `moe_combine`), at both expert cells' shapes (`MOE_SHAPES`) under three or four routings
+  (`moe_routings`), with the (block, expert) pairs the kernel has in use; then the sum alone
+  under uniform routing with `--held` experts held: the readings `combine_plan`'s line is set by.
 
 Each program runs `--iters` times between `block_until_ready`s (the host's clock, which holds
 about a millisecond of dispatch a call) and three times under the profiler: the line's
@@ -101,51 +103,100 @@ def flash_part(args, interpret: bool) -> None:
                 print("[parts] " + json.dumps({"part": name, "refused": str(e)[-300:]}), flush=True)
 
 
+# tokens, width, an expert's hidden size, the router's experts, a token's choices, the experts held: one layer as a cell's chip holds it
+MOE_SHAPES = {
+    "mellum2": (16384, 2304, 896, 64, 8, 8),  # benchmark/configs/mellum2-12b-a2p5b-d12
+    "kanana2": (16384, 2048, 768, 128, 6, 16),  # benchmark/configs/kanana2-30b-a3b-d9
+}
+
+
+def moe_routings(rng, tokens: int, routed: int, chosen: int, held: int) -> dict:
+    """Three routings of `tokens` tokens: `collapsed` (every token the same experts, one of them held: what both cells'
+    windows hold, an expert all tokens or none), `uniform` (every token its own draw: what a trained router sends, every
+    held expert a share of every block), `none_held`; and where a token can have all its choices held, `all_held`."""
+    import jax.numpy as jnp
+
+    same = lambda experts: jnp.broadcast_to(jnp.asarray(experts, jnp.int32), (tokens, chosen))  # noqa: E731
+    routings = {
+        "collapsed": same([0] + list(range(held, held + chosen - 1))),
+        "uniform": jnp.asarray(np.argsort(rng.random((tokens, routed)), axis=1)[:, :chosen], jnp.int32),  # k distinct experts a token
+        "none_held": same(range(held, held + chosen)),
+    }
+    if chosen <= held:
+        routings["all_held"] = same(range(chosen))
+    return routings
+
+
 def moe_part(args) -> None:
+    """One expert layer's routed part, and its sum by token alone, in both forms of the sum (`combine_plan`'s `gathers` and
+    `slabs`) at each of `--moe_shapes` under each routing; then the sum alone under `uniform` with more experts held, which
+    is where `combine_plan`'s line between the forms is read from."""
     import jax
     import jax.numpy as jnp
 
-    from modalities_tpu.ops import expert_dispatch
+    from modalities_tpu.ops import expert_dispatch as xd
 
-    tokens, width, hidden, routed, chosen, held = (256, 128, 64, 8, 3, 4) if args.smoke else (16384, 2048, 768, 128, 6, args.held)
-    tile = 8 if args.smoke else expert_dispatch.TILE
+    shapes = {"smoke": (256, 128, 64, 8, 3, 4)} if args.smoke else {name: MOE_SHAPES[name] for name in args.moe_shapes.split(",")}
+    tile = 8 if args.smoke else xd.TILE
     rng = np.random.default_rng(0)
     normal = lambda *shape, scale=1.0, dtype=jnp.bfloat16: jnp.asarray(rng.normal(size=shape) * scale, dtype)  # noqa: E731
-    x, w_out = normal(tokens, width), normal(tokens, width)
-    gate, up, down = normal(held, width, hidden, scale=0.02), normal(held, width, hidden, scale=0.02), normal(held, hidden, width, scale=0.02)
-    weights = jnp.asarray(rng.uniform(0.2, 0.6, size=(tokens, chosen)), jnp.float32)
-    uniform = jnp.asarray(np.stack([rng.choice(routed, size=chosen, replace=False) for _ in range(tokens)]), jnp.int32)
-    loads = {
-        "uniform": uniform,
-        "all_on_one": jnp.broadcast_to(jnp.asarray([0] + list(range(held, held + chosen - 1)), jnp.int32), (tokens, chosen)),
-        "none_held": jnp.broadcast_to(jnp.arange(held, held + chosen, dtype=jnp.int32), (tokens, chosen)),
-    }
 
-    def loss(x, weights, gate, up, down, choice):
-        out = expert_dispatch.routed_experts(x, choice, weights, gate, up, down, offset=0, tile=tile)
-        return jnp.sum(out.astype(jnp.float32) * w_out.astype(jnp.float32))
+    def sum_alone(name, tokens, width, chosen, held, choice, weights, **facts):
+        """The sum by token of a table of finite rows, both forms, the plan and the tables made beforehand; and the tables' own price."""
+        plan = jax.jit(lambda c: xd.plan_dispatch(c, 0, held, tile))(choice)
+        tables = jax.jit(lambda p: xd.slab_tables(p, tokens, chosen, tile))
+        slabs = tables(plan)
+        in_use = int(jnp.sum(slabs.count > 0))
+        facts = {**facts, "tokens": tokens, "width": width, "choices": chosen, "held": held, "pairs_held": int(jnp.sum(plan.group_sizes)),
+                 "blocks_in_use": in_use, "blocks_at_most": int(slabs.count.size), "plan": xd.combine_plan(tokens, chosen, held, width)}
+        rows = normal(xd._table_rows(plan, slabs), width)  # with the kernel's padding rows, which the gathers' table does not have
+        plain = rows[:plan.row_pair.shape[0]]
+        gathers = jax.jit(lambda r, p, w: xd._sum_by_token(r, p, tokens, chosen, w).astype(r.dtype))
+        kernel = jax.jit(lambda r, p, s, w: xd._sum_by_slabs(r, p, s, tokens, chosen, w))
+        gap = jnp.max(jnp.abs(gathers(plain, plan, weights).astype(jnp.float32) - kernel(rows, plan, slabs, weights).astype(jnp.float32)))
+        facts["slabs_against_gathers_max_gap"] = float(gap)  # of sums rounded to the rows' dtype: 0, or one unit in the last place
+        timed(f"sum_gathers_{name}", gathers, (plain, plan, weights), args.iters, args.trace, **facts)
+        timed(f"sum_slabs_{name}", kernel, (rows, plan, slabs, weights), args.iters, args.trace, **facts)
+        timed(f"sum_slab_tables_{name}", tables, (plan,), args.iters, args.trace, **facts)
 
-    both = jax.jit(jax.grad(loss, argnums=(0, 1, 2, 3, 4)))
-    forward = jax.jit(lambda x, weights, gate, up, down, choice: expert_dispatch.routed_experts(
-        x, choice, weights, gate, up, down, offset=0, tile=tile))
-    for name, choice in loads.items():
-        pairs = int(jnp.sum(choice < held))
-        facts = {"tokens": tokens, "held": held, "pairs_held": pairs, "tile": tile,
-                 "required_gflop_fwd": round(pairs * 6 * width * hidden / 1e9, 2)}
-        timed(f"moe_fwd_{name}", forward, (x, weights, gate, up, down, choice), args.iters, args.trace, **facts)
-        timed(f"moe_fwd_bwd_{name}", both, (x, weights, gate, up, down, choice), args.iters, args.trace, **facts)
+    for shape, (tokens, width, hidden, routed, chosen, held) in shapes.items():
+        x, w_out = normal(tokens, width), normal(tokens, width)
+        gate, up, down = normal(held, width, hidden, scale=0.02), normal(held, width, hidden, scale=0.02), normal(held, hidden, width, scale=0.02)
+        weights = jnp.asarray(rng.uniform(0.2, 0.6, size=(tokens, chosen)), jnp.float32)
+        for routing, choice in moe_routings(rng, tokens, routed, chosen, held).items():
+            sum_alone(f"{shape}_{routing}", tokens, width, chosen, held, choice, weights, shape=shape, routing=routing)
+            pairs = int(jnp.sum(choice < held))
+            facts = {"shape": shape, "routing": routing, "tokens": tokens, "held": held, "pairs_held": pairs, "tile": tile,
+                     "required_gflop_fwd": round(pairs * 6 * width * hidden / 1e9, 2)}
+            for form in ("gathers", "slabs"):
+                def layer(x, weights, gate, up, down, choice, form=form):
+                    return xd.routed_experts(x, choice, weights, gate, up, down, offset=0, tile=tile, combine=form)
+
+                def loss(*values):
+                    return jnp.sum(layer(*values).astype(jnp.float32) * w_out.astype(jnp.float32))
+
+                values = (x, weights, gate, up, down, choice)
+                timed(f"moe_fwd_{form}_{shape}_{routing}", jax.jit(layer), values, args.iters, args.trace, combine=form, **facts)
+                timed(f"moe_fwd_bwd_{form}_{shape}_{routing}", jax.jit(jax.grad(loss, argnums=(0, 1, 2, 3, 4))), values, args.iters, args.trace, combine=form, **facts)
+        # the line of `combine_plan`: uniform routing is the slabs' worst (every held expert in every block), more experts held their cost
+        for more in ([] if args.smoke else args.held):
+            if more > held and more <= routed:
+                choice = moe_routings(rng, tokens, routed, chosen, more)["uniform"]
+                sum_alone(f"{shape}_uniform_held{more}", tokens, width, chosen, more, choice, weights, shape=shape, routing="uniform")
 
 
 def main() -> None:
     p = argparse.ArgumentParser()
     p.add_argument("--parts", default="flash,moe")
     p.add_argument("--blocks", default="512x1024,1024x512,512x512,1024x1024", help="block_q x block_k pairs tried at 192/128")
-    p.add_argument("--held", type=int, default=16)
+    p.add_argument("--moe_shapes", default="mellum2,kanana2", help="of MOE_SHAPES")
+    p.add_argument("--held", default="16,24,32,48,64", help="experts held, beyond a shape's own, that the sum by token alone is also timed at")
     p.add_argument("--iters", type=int, default=5)
     p.add_argument("--smoke", action="store_true", help="tiny shapes, Pallas in interpret mode (CPU)")
     p.add_argument("--trace", default=None, help="directory for the profiler traces")
     args = p.parse_args()
     args.blocks = [tuple(int(n) for n in pair.split("x")) for pair in args.blocks.split(",")]
+    args.held = [int(n) for n in args.held.split(",")]
 
     import jax
 

@@ -148,7 +148,16 @@ def test_bfloat16_program_is_near_the_reference_forward(toy, tokens):
     assert np.abs(logits_of(build(), params, tokens) - want).max() < 0.03
 
 
-def test_loss_and_every_gradient_leaf(toy, tokens):
+@pytest.mark.parametrize("combine", ["gathers", "kernel_interpreted"])
+def test_loss_and_every_gradient_leaf(toy, tokens, combine, monkeypatch):
+    """Off the chip the sum by token is the k gathers; with the tier forced on it is the kernel `moe_combine`, interpreted,
+    over the 128 tokens as two blocks of 64: the same loss and gradients against the reference, under the same limits."""
+    from modalities_tpu.ops import expert_dispatch
+
+    if combine == "kernel_interpreted":
+        monkeypatch.setenv(expert_dispatch.COMBINE_TIER_ENV, "on")
+        monkeypatch.setattr(expert_dispatch, "COMBINE_BLOCK", 64)
+    assert expert_dispatch.combine_form(128, 3, 4, 128) == ("slabs" if combine == "kernel_interpreted" else "gathers")
     model, shape, params = toy
     ids, targets = jnp.asarray(tokens[:, :-1]), jnp.asarray(tokens[:, 1:])
 

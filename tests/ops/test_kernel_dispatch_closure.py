@@ -51,6 +51,7 @@ def test_dispatch_entry_points_expose_interpret():
     from modalities_tpu.ops.pallas.flash_attention import pallas_flash_attention
     from modalities_tpu.ops.pallas.fused_ce import fused_ce_sum_and_count
     from modalities_tpu.ops.pallas.fused_rmsnorm import fused_rms_norm
+    from modalities_tpu.ops.pallas.moe_combine import moe_combine
     from modalities_tpu.ops.pallas.quant_matmul import quant_matmul
     from modalities_tpu.ops.pallas.selective_scan import pallas_selective_scan
     from modalities_tpu.ops.quant_matmul import quant_matmul_or_fallback
@@ -58,7 +59,7 @@ def test_dispatch_entry_points_expose_interpret():
     from modalities_tpu.ops.selective_scan import selective_scan
 
     for fn in (pallas_flash_attention, fused_ce_sum_and_count, fused_rms_norm, ce_dispatch, rms_norm_or_fallback, quant_matmul, quant_matmul_or_fallback,
-               pallas_selective_scan, selective_scan):
+               pallas_selective_scan, selective_scan, moe_combine):
         params = inspect.signature(fn).parameters
         assert "interpret" in params, f"{fn.__module__}.{fn.__name__} lacks an interpret path"
         assert params["interpret"].default is False, fn.__name__
@@ -131,9 +132,19 @@ def _call_selective_scan():
     return selective_scan(rows, rows, -jnp.ones((128, 8)), narrow, narrow)
 
 
+def _call_moe_combine():
+    import jax.numpy as jnp
+
+    from modalities_tpu.ops.expert_dispatch import routed_experts
+
+    x, stack = jnp.ones((16, 128)), jnp.ones((2, 128, 128))
+    return routed_experts(x, jnp.zeros((16, 1), jnp.int32), jnp.ones((16, 1)), stack, stack, stack, offset=0, combine="slabs")
+
+
 @pytest.mark.parametrize(
     "module, call",
     [
+        ("expert_dispatch", _call_moe_combine),
         ("selective_scan", _call_selective_scan),
         ("attention", _call_attention),
         ("cross_entropy", _call_fused_ce),

@@ -26,6 +26,7 @@ from modalities_tpu.ops.pallas.flash_attention import (
 )
 from modalities_tpu.ops.pallas.fused_ce import fused_ce_rows, fused_ce_sum_and_count
 from modalities_tpu.ops.pallas.fused_rmsnorm import fused_rms_norm
+from modalities_tpu.ops.pallas.moe_combine import moe_combine, pad_rows, vmem_bytes
 from modalities_tpu.ops.pallas.quant_matmul import quant_matmul
 from modalities_tpu.ops.pallas.selective_scan import pallas_selective_scan
 from tests.telemetry.test_scopes import without_metadata
@@ -165,6 +166,22 @@ def _selective_scan(d_inner, batch=1, d_state=16):
     return jax.grad(loss, argnums=tuple(range(6))), (rows, rows, ((d_inner, d_state), F32), narrow, narrow, ((batch, d_inner, d_state), F32)), 2
 
 
+def _moe_combine(width, held, k, tokens=4 * SEQ, block=256):
+    """The expert layer's sum by token as both expert cells call it (PR 39): 16,384 tokens in blocks of 256, the forward's
+    weighted sum and the backward's unweighted one over a table sized for every pair on held experts, with the kernel's
+    padding rows; what a grid step holds in VMEM is counted under Mosaic's default scope, which the call leaves as it is."""
+    from modalities_tpu.ops.expert_dispatch import TILE, rows_for
+
+    assert vmem_bytes(block, width, held, 2) < 16 * 2**20
+
+    def both(rows, pos, start, count, weight):
+        return moe_combine(rows, pos, start, count, weight, block=block), moe_combine(rows, pos, start, count, block=block)
+
+    by_token, by_block = (tokens, held), (tokens // block, held)
+    rows = ((rows_for(k * tokens, held, TILE) + pad_rows(block), width), BF16)
+    return both, (rows, (by_token, jnp.int32), (by_block, jnp.int32), (by_block, jnp.int32), (by_token, F32)), ("moe_combine", "moe_combine")
+
+
 CASES = {
     "flash_fwd_bwd_d128": _flash(16, 16, 128),
     "flash_fwd_bwd_d80_gqa_32_8": _flash(32, 8, 80),
@@ -187,6 +204,8 @@ CASES = {
     "fused_ce_fwd_bwd_e2560": _fused_ce(2560, 4 * SEQ),
     "fused_ce_fwd_bwd_e2560_rows8192": _fused_ce(2560, 2 * SEQ),
     "fused_ce_fwd_bwd_e2560_rows4096_v32768": _fused_ce(2560, SEQ, vocab=32768),
+    "moe_combine_e2304_held8_k8_tokens16384": _moe_combine(2304, 8, 8),  # train-mellum2-12b-16k
+    "moe_combine_e2048_held16_k6_tokens16384": _moe_combine(2048, 16, 6),  # train-kanana2-30b-8k
     "fused_rmsnorm_fwd_bwd_e1536": _fused_rmsnorm(1536),
     "fused_rmsnorm_fwd_bwd_e2560": _fused_rmsnorm(2560),
     "quant_matmul_m8": _quant_matmul(8),

@@ -185,15 +185,19 @@ def test_flash_tile_plan_says_which_backward_a_differentiated_call_runs(tmp_path
     assert kernels.count("name=flash_attention_bwd_dq") == 2 == kernels.count("name=flash_attention_bwd_dkv")
 
 
-def test_moe_dispatch_plan_is_one_event_per_traced_shape_and_none_per_step(tmp_path):
+@pytest.mark.parametrize("tier", ["auto", "on"], ids=["gathers_off_the_chip", "kernel_interpreted"])
+def test_moe_dispatch_plan_is_one_event_per_traced_shape_and_none_per_step(tmp_path, monkeypatch, tier):
     """The expert layer says once, while tracing, what its dispatch is sized for (`moe_dispatch_plan`): the tokens,
     the router's width and a token's choices, the experts held and from where, the rows its tables hold (every pair
-    on held experts, each group's last tile padded), the tile, and that no kernels were traced (the plain form);
-    two expert layers of one shape and three steps of one executable say it once."""
+    on held experts, each group's last tile padded), the tile, and since PR 39 the form of the sum by token
+    (`combine`: `slabs` where `combine_plan` and the tier take the kernel, which `kernels` then names; `gathers` for
+    the plain form, as off the chip and for fewer tokens than a block), the kernel's block and the (block, expert)
+    pairs it can have in use at most; two expert layers of one shape and three steps of one executable say it once."""
     from tests.models.test_moe_mla import build
 
-    from modalities_tpu.ops.expert_dispatch import TILE, rows_for
+    from modalities_tpu.ops.expert_dispatch import COMBINE_TIER_ENV, TILE, rows_for
 
+    monkeypatch.setenv(COMBINE_TIER_ENV, tier)
     model = build()
     telemetry = Telemetry(output_folder_path=tmp_path, watchdog_deadline_s=0)
     previous = set_active_telemetry(telemetry)
@@ -201,13 +205,16 @@ def test_moe_dispatch_plan_is_one_event_per_traced_shape_and_none_per_step(tmp_p
         params = jax.jit(model.init_params)(jax.random.PRNGKey(0))  # the initializer's dummy of 8 tokens is a shape too
         apply = jax.jit(lambda p, t: model.apply(p, {"input_ids": t})["logits"])
         for _ in range(3):
-            apply(params, jnp.zeros((2, 64), jnp.int32)).block_until_ready()
+            apply(params, jnp.zeros((2, 256), jnp.int32)).block_until_ready()
     finally:
         set_active_telemetry(previous)
     plans = [e for e in map(json.loads, telemetry.sink_path.read_text().splitlines()) if e.get("name") == "moe_dispatch_plan"]
+    kernel = tier == "on"  # 512 tokens of width 128 are two blocks of whole lane tiles; the dummy's 8 tokens are under one block
     assert [{k: v for k, v in e.items() if k not in ("event", "name", "rank")} for e in plans] == [
         {"tokens": tokens, "router_width": 8, "choices": 3, "experts_held": 4, "expert_offset": 2, "tile": TILE,
-         "rows": rows_for(3 * tokens, 4, TILE), "kernels": False} for tokens in (8, 128)]
+         "rows": rows_for(3 * tokens, 4, TILE), "kernels": ["moe_combine"] if slabs else [], "combine": "slabs" if slabs else "gathers",
+         "combine_block": block, "combine_blocks_at_most": 4 * blocks}
+        for tokens, slabs, block, blocks in ((8, False, 16, 1), (512, kernel, 256, 2))]
 
 
 @pytest.mark.parametrize("kernels", [False, True], ids=["plain_form", "kernels_interpreted"])
