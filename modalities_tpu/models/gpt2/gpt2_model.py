@@ -40,8 +40,10 @@ from modalities_tpu.models.components.layer_norms import (
     NormSpec,
     build_norm,
 )
+from modalities_tpu.models.gpt2.cca import CCAConfig, CCASpec, CompressedConvAttention
 from modalities_tpu.models.gpt2.mla import LatentAttention, MLAConfig, MLASpec
-from modalities_tpu.models.gpt2.moe import AUX_LOSS, BIAS_LEAF, COUNTERS, EXPERT_LOAD, MoE, MoEConfig, MoESpec, ffn_kinds, update_selection_bias
+from modalities_tpu.models.gpt2.moe import (AUX_LOSS, BIAS_LEAF, COUNTERS, EXPERT_LOAD, SKIP_SHARE, MoE, MoEConfig, MoESpec, ffn_kinds,
+                                            update_selection_bias)
 from modalities_tpu.models.gpt2.ssm import MambaMixer, SSMConfig, SSMSpec, layer_kinds, layer_runs
 from modalities_tpu.models.model import NNModel
 from modalities_tpu.ops.embedding import embedding_lookup
@@ -101,7 +103,8 @@ class AttentionConfig(BaseModel):
 
 
 SLIDING, FULL = "sliding_attention", "full_attention"  # a layer's kind of attention, as `layer_types` publishes it
-LayerType = Literal["sliding_attention", "full_attention"]
+HYBRID = "hybrid"  # `model_type: zaya`'s one kind of layer: compressed convolutional attention (`cca_config`), then the expert layer
+LayerType = Literal["sliding_attention", "full_attention", "hybrid"]
 
 
 class RopeParameters(BaseModel):
@@ -110,7 +113,9 @@ class RopeParameters(BaseModel):
     `transformers.modeling_rope_utils._compute_yarn_parameters` computes it): the frequencies a context of
     `original_max_position_embeddings` turns fewer than `beta_slow` times are divided by `factor`, those it turns
     more than `beta_fast` times are left, a linear ramp between the two bounds (rounded outwards), and cos
-    and sin are both multiplied by `attention_factor` (default `0.1 ln(factor) + 1`). A key that is not below
+    and sin are both multiplied by `attention_factor` (default `0.1 ln(factor) + 1`). `partial_rotary_factor` below 1 (a
+    `hybrid` layer's; PR 40): the first `partial_rotary_factor * head_dim` channels of a head are turned, by the D' / 2
+    frequencies `rope_theta^(-2n/D')` of that width D', and the rest pass as they are. A key that is not below
     (`truncate`, `mscale`, ...) is refused: a rule nobody wrote is not run under the model's name."""
 
     model_config = ConfigDict(extra="forbid")
@@ -122,11 +127,15 @@ class RopeParameters(BaseModel):
     beta_fast: Annotated[float, Field(gt=0.0)] = 32.0
     beta_slow: Annotated[float, Field(gt=0.0)] = 1.0
     attention_factor: Optional[Annotated[float, Field(gt=0.0)]] = None
+    partial_rotary_factor: Annotated[float, Field(gt=0.0, le=1.0)] = 1.0
 
     @model_validator(mode="after")
     def check_yarn(self) -> "RopeParameters":
         if self.rope_type == "yarn" and self.original_max_position_embeddings is None:
             raise ValueError("rope_parameters: rope_type yarn needs original_max_position_embeddings (the context the frequencies were trained at)")
+        if self.rope_type == "yarn" and self.partial_rotary_factor != 1.0:
+            raise ValueError("rope_parameters: yarn on part of a head is not written (which of the fewer frequencies the ramp's bounds name); "
+                             "partial_rotary_factor goes with rope_type default")
         return self
 
 
@@ -139,15 +148,21 @@ class RopeSpec:
     beta_fast: float = 32.0
     beta_slow: float = 1.0
     attention_factor: float = 1.0
+    partial_rotary: float = 1.0  # the share of a head's channels the rotary turns, from the first
 
     @classmethod
     def from_config(cls, config: "RopeParameters | dict") -> "RopeSpec":
         config = RopeParameters(**config) if isinstance(config, dict) else config
         if config.rope_type == "default":
-            return cls("default", float(config.rope_theta))
+            return cls("default", float(config.rope_theta), partial_rotary=float(config.partial_rotary_factor))
         scale = config.attention_factor if config.attention_factor is not None else 0.1 * math.log(config.factor) + 1.0
         return cls("yarn", float(config.rope_theta), float(config.factor), config.original_max_position_embeddings,
                    float(config.beta_fast), float(config.beta_slow), float(scale))
+
+
+def rotary_dim(head_dim: int, rope: Optional[RopeSpec]) -> int:
+    """The channels of a head the rotary turns: all of them, or the first `partial_rotary_factor * head_dim`."""
+    return head_dim if rope is None else int(head_dim * rope.partial_rotary)
 
 
 def yarn_bounds(head_dim: int, rope: RopeSpec) -> tuple[int, int]:
@@ -265,6 +280,38 @@ class GPT2LLMConfig(BaseModel):
     layer_types: Optional[list[LayerType]] = None
     sliding_window: Optional[Annotated[int, Field(strict=True, ge=1)]] = None
     rope_parameters: Optional[dict[LayerType, RopeParameters]] = None
+    # `model_type: zaya` (PR 40). `layer_types` of `hybrid` puts compressed convolutional attention (models/gpt2/cca.py) in the
+    # mixer seat, its taps in `cca_config`; heads, `head_dim`, eps and the rotary (`rope_parameters.hybrid`, with its
+    # `partial_rotary_factor`) are the keys above. `scale_residual_merge`: a block's two merges are
+    # `(x + r_b) * r_s + (a + f_b) * f_s` with four learned `[n_embd]` leaves each (scales from 1, shifts from 0), and the
+    # first layer's first merge has none on the residual side (the embedding passes as it is). The router that is an MLP over
+    # a state handed from layer to layer is `moe_config`'s (`router: mlp`). All unset: the tree and the program of before.
+    cca_config: Optional[CCAConfig] = None
+    scale_residual_merge: bool = False
+
+    @model_validator(mode="after")
+    def check_hybrid_layers(self) -> "GPT2LLMConfig":
+        hybrid = self.layer_types is not None and HYBRID in self.layer_types
+        if hybrid != (self.cca_config is not None):
+            raise ValueError("cca_config gives a hybrid layer's two convolutions their taps: layer_types of hybrid and cca_config go together")
+        partial = [kind for kind, rope in (self.rope_parameters or {}).items() if rope.partial_rotary_factor != 1.0 and kind != HYBRID]
+        if partial:
+            raise ValueError(f"rope_parameters {partial}: partial_rotary_factor below 1 is written for hybrid layers (the cca mixer) only")
+        if not hybrid:
+            return self
+        if set(self.layer_types) != {HYBRID}:
+            raise ValueError("layer_types: hybrid layers beside sliding_attention or full_attention layers are not written; every layer is hybrid or none")
+        if self.mla_config is not None or self.loop_config is not None or self.sliding_window is not None or self.attn_layer_period is not None:
+            raise ValueError("cca_config beside mla_config, loop_config, sliding_window or attn_layer_period: compressed convolutional attention "
+                             "takes its scores in its own latent over all that came before, in a stack walked once; none of the four is written with it")
+        if self.n_head_kv % 2:
+            raise ValueError("cca_config: half the key/value heads read their values off the previous position; n_head_kv must be even")
+        if self.attention_config.qk_norm_config is not None:
+            raise ValueError("cca_config: q and k are L2-normalised with a learned key temperature; leave qk_norm_config unset")
+        head_dim = self.head_dim if self.head_dim is not None else self.n_embd // self.n_head_q
+        if int(head_dim * (self.rope_parameters or {}).get(HYBRID, RopeParameters()).partial_rotary_factor) % 2:
+            raise ValueError("rope_parameters.hybrid.partial_rotary_factor: the rotated part of a head must be even (the rotary turns halves)")
+        return self
 
     @model_validator(mode="after")
     def check_loop(self) -> "GPT2LLMConfig":
@@ -343,12 +390,14 @@ class GPT2LLMConfig(BaseModel):
 
     @model_validator(mode="after")
     def validate_sizes(self) -> "GPT2LLMConfig":
-        for param, name in zip(
-            [self.ffn_hidden, self.vocab_size, self.n_embd], ["ffn_hidden", "vocab_size", "n_embd"]
-        ):
+        for param, name in zip([self.ffn_hidden, self.n_embd], ["ffn_hidden", "n_embd"]):
             if param % 128 != 0:
                 # MXU tiles are 128-wide; unaligned dims waste systolic-array cycles
                 raise ValueError(f"{name} with value {param} should be divisible by 128 for efficient training.")
+        if self.vocab_size % 16 != 0:
+            # a chip's share of a published table is often no multiple of 128 (an eighth of 262,272 rows is 16 x 2049): the
+            # head's kernels pad the vocabulary to their blocks, the embedding's chunks take any count; whole sublane tiles stay
+            raise ValueError(f"vocab_size with value {self.vocab_size} should be divisible by 128 for efficient training, and must be by 16.")
         return self
 
 
@@ -462,6 +511,21 @@ class GPT2ModelSpec:
     head_dim_key: Optional[int] = None
     sliding_window: Optional[int] = None
     rope_by_kind: tuple[tuple[str, RopeSpec], ...] = ()
+    # compressed convolutional attention in the layers whose mixer is "cca" (`layer_types`: `hybrid`), and a block's merges in
+    # the scaled form; a router state handed from layer to layer is `moe.state_width`
+    cca: Optional[CCASpec] = None
+    scale_residual_merge: bool = False
+
+    @property
+    def router_state_width(self) -> int:
+        """The width of the state an expert layer's router takes from the layer before and hands on; 0: none."""
+        return self.moe.state_width if self.moe is not None else 0
+
+    @property
+    def counter_row_width(self) -> int:
+        """What an expert layer's block hands up a pass: `COUNTERS`, the router's columns' loads, a matrix softmax router's
+        balance term, and the mean key temperature where the block's mixer is `cca`."""
+        return len(COUNTERS) + self.moe.router_width + self.moe.counts_aux_loss + (self.cca is not None)
 
     @property
     def head_dim(self) -> int:
@@ -547,6 +611,8 @@ class GPT2ModelSpec:
                 self.head_dim_key,
                 self.sliding_window,
                 self.rope_by_kind,
+                self.cca,
+                self.scale_residual_merge,
             )
         )
 
@@ -597,7 +663,10 @@ def _rotate_half(x):
 
 def apply_rope(x, cos, sin):
     """x: [B, S, H, D]; cos/sin: [S, D] shared across the batch, or [B, S, D]
-    per-batch-row (slot decode: every slot sits at its own position)."""
+    per-batch-row (slot decode: every slot sits at its own position). Tables narrower than a
+    head (`partial_rotary_factor`) turn its first channels and pass the rest."""
+    if cos.shape[-1] < x.shape[-1]:
+        return jnp.concatenate([apply_rope(x[..., : cos.shape[-1]], cos, sin), x[..., cos.shape[-1]:]], axis=-1)
     if cos.ndim == 2:
         cos = cos[None, :, None, :]
         sin = sin[None, :, None, :]
@@ -1120,6 +1189,22 @@ class MLP(nn.Module):
         return nn.Dropout(rate=spec.dropout)(out, deterministic=self.deterministic or spec.dropout == 0.0)
 
 
+class _ResidualMerge(nn.Module):
+    """A block's merge in the scaled form (`scale_residual_merge`): `(x + r_b) * r_s + (a + f_b) * f_s`, four `[n_embd]`
+    float32 leaves (scales from 1, shifts from 0), computed in float32 and rounded once. `passes` (a flag, traced or
+    not) marks the merge whose residual side has no scale or shift: the first layer's first, where the embedding passes
+    as it is; its two leaves are in the tree (a scanned run's layers hold equal leaves) and get no gradient there."""
+
+    @nn.compact
+    def __call__(self, x, a, passes=None):
+        leaf = lambda name, init: self.param(name, nn.with_logical_partitioning(init, ("embed",)), (x.shape[-1],), jnp.float32)  # noqa: E731
+        r_s, r_b = leaf("residual_scale", nn.initializers.ones), leaf("residual_bias", nn.initializers.zeros)
+        f_s, f_b = leaf("out_scale", nn.initializers.ones), leaf("out_bias", nn.initializers.zeros)
+        if passes is not None:
+            r_s, r_b = jnp.where(passes, 1.0, r_s), jnp.where(passes, 0.0, r_b)
+        return ((x.astype(jnp.float32) + r_b) * r_s + (a.astype(jnp.float32) + f_b) * f_s).astype(x.dtype)
+
+
 class GPT2Block(nn.Module):
     """Pre-norm residual block (reference :801-813)."""
 
@@ -1127,15 +1212,22 @@ class GPT2Block(nn.Module):
     deterministic: bool = True
     decode: bool = False
     slot_spec: Optional[SlotDecodeSpec] = None
-    mixer: str = "attn"  # what sits in the mixer seat: "attn", "ssm" or "swa" (attention under the spec's window)
+    mixer: str = "attn"  # what sits in the mixer seat: "attn", "ssm", "swa" (attention under the spec's window) or "cca"
     ffn: str = "mlp"  # what sits in the feed-forward seat: "mlp" or "moe"; with "moe" the block returns (x, what the layer counted)
 
     @nn.compact
-    def __call__(self, x, slot=None, positions=None):
+    def __call__(self, x, slot=None, positions=None, router_state=None, layer_index=None):
+        """`router_state`: the previous layer's, where the expert layer's router is handed one (`moe.state_width`; the block
+        then returns `(x, counters, its own state)`). `layer_index` (a whole number or a traced one): which layer of the
+        stack this is, for the one thing a scanned block cannot know otherwise (`scale_residual_merge`: the first layer's
+        first merge)."""
         spec = self.spec
         x = with_logical_constraint(x, ("batch", "seq", "embed"), spec)
         h = build_norm(spec.attn_norm, "attention_norm", dtype=x.dtype)(x)
-        if self.mixer == "ssm":
+        key_temperature = None
+        if self.mixer == "cca":
+            a, key_temperature = CompressedConvAttention(spec, self.deterministic, name=scopes.CCA)(h)
+        elif self.mixer == "ssm":
             a = MambaMixer(spec, name=scopes.SSM)(h)
             a = nn.Dropout(rate=spec.dropout)(a, deterministic=self.deterministic or spec.dropout == 0.0)
         elif spec.mla is not None:
@@ -1152,17 +1244,24 @@ class GPT2Block(nn.Module):
         if spec.post_attn_norm is not None:
             a = build_norm(spec.post_attn_norm, scopes.POST_ATTENTION_NORM, dtype=x.dtype)(a)
         with jax.named_scope(scopes.RESIDUAL):
-            x = x + a
+            if spec.scale_residual_merge:
+                x = _ResidualMerge(name="attn_merge")(x, a, passes=None if layer_index is None else layer_index == 0)
+            else:
+                x = x + a
         h2 = build_norm(spec.ffn_norm, "ffn_norm", dtype=x.dtype)(x)
         counters = None
-        if self.ffn == "moe":
+        if self.ffn == "moe" and spec.router_state_width:
+            m, counters, router_state = MoE(spec, self.deterministic, name=scopes.MOE)(h2, router_state)
+        elif self.ffn == "moe":
             m, counters = MoE(spec, self.deterministic, name=scopes.MOE)(h2)
         else:
             m = MLP(spec, self.deterministic, name="mlp")(h2)
         if spec.post_ffn_norm is not None:
             m = build_norm(spec.post_ffn_norm, scopes.POST_FFN_NORM, dtype=x.dtype)(m)
         with jax.named_scope(scopes.RESIDUAL):
-            x = x + m
+            x = _ResidualMerge(name="ffn_merge")(x, m) if spec.scale_residual_merge else x + m
+        if counters is not None and key_temperature is not None:
+            counters = jnp.concatenate([counters, key_temperature[None]])
         if spec.debug_print_activations == "shape":
             jax.debug.print(
                 "block out shape=" + str(tuple(x.shape)) + " dtype=" + str(x.dtype)
@@ -1175,6 +1274,8 @@ class GPT2Block(nn.Module):
                 s=jnp.std(xf),
                 n=jnp.isnan(xf).sum(),
             )
+        if self.ffn == "moe" and spec.router_state_width:
+            return x, counters, router_state
         return x if counters is None else (x, counters)
 
 
@@ -1220,7 +1321,10 @@ def head_project(spec: "GPT2ModelSpec", inner_params, h):
 
 
 class _BlockScanBody(nn.Module):
-    """scan body: carry = activations; applies (optionally remat-wrapped) block."""
+    """scan body: carry = activations; applies (optionally remat-wrapped) block. Where the expert layer's router is
+    handed a state from the layer before (`moe.state_width`) the carry is `(activations, that state)`, and where the
+    block must know which layer it is (`scale_residual_merge`) the scan's input is the layer's index; every other
+    run carries the activations alone over no input, as it always did."""
 
     spec: GPT2ModelSpec
     deterministic: bool = True
@@ -1229,7 +1333,7 @@ class _BlockScanBody(nn.Module):
     ffn: str = "mlp"
 
     @nn.compact
-    def __call__(self, carry, _):
+    def __call__(self, carry, layer_index):
         spec = self.spec
         block_cls = GPT2Block
         if spec.remat_variant in ("full", "selective_layer", "selective_op") and not self.decode:
@@ -1242,7 +1346,11 @@ class _BlockScanBody(nn.Module):
                     "use ac_freq > 1, or use ac_freq=1 / 'full'."
                 )
             block_cls = _remat_block_cls(spec)
-        out = block_cls(spec, self.deterministic, self.decode, mixer=self.mixer, ffn=self.ffn, name="block")(carry)
+        block = block_cls(spec, self.deterministic, self.decode, mixer=self.mixer, ffn=self.ffn, name="block")
+        if self.ffn == "moe" and spec.router_state_width:
+            x, counters, state = block(carry[0], None, None, carry[1], layer_index)
+            return (x, state), counters
+        out = block(carry) if layer_index is None else block(carry, None, None, None, layer_index)
         return out if self.ffn == "moe" else (out, None)  # what an expert layer counted is the scan's output, one row a layer
 
 
@@ -1256,9 +1364,12 @@ class _LayerRun(nn.Module):
     mixer: str
     length: int
     ffn: str = "mlp"
+    first: int = 0  # the index of the run's first layer in the stack
 
     @nn.compact
-    def __call__(self, x):
+    def __call__(self, x, router_state=None):
+        """`router_state`: what the run before handed on (None before the first layer: zeros go in, which add nothing).
+        A run that carries one returns `(x, counters, the last layer's state)`."""
         scanned = nn.scan(
             _BlockScanBody,
             variable_axes={"params": 0},
@@ -1266,8 +1377,14 @@ class _LayerRun(nn.Module):
             length=self.length,
             metadata_params={nn.meta.PARTITION_NAME: "layers"},
         )(self.spec, self.deterministic, False, self.mixer, self.ffn, name="blocks")
+        layers = self.first + jnp.arange(self.length, dtype=jnp.int32) if self.spec.scale_residual_merge else None
         with jax.named_scope(scopes.LAYER_CARRY):
-            return scanned(x, None)  # (x, what the expert layers counted [length, 3 + E] or None)
+            if self.ffn == "moe" and self.spec.router_state_width:
+                if router_state is None:
+                    router_state = jnp.zeros((*x.shape[:2], self.spec.router_state_width), jnp.float32)
+                (x, router_state), counters = scanned((x, router_state), layers)
+                return x, counters, router_state
+            return scanned(x, layers)  # (x, what the expert layers counted [length, 3 + E] or None)
 
 
 class _SlotBlockScanBody(nn.Module):
@@ -1334,13 +1451,33 @@ _NO_WINDOW_LAYERS_IN_STAGES = (
 )
 
 
-_MIXER_OF = {SLIDING: "swa", FULL: "attn"}  # a published layer type as the block's mixer seat names it
+_NO_CONV_AND_SHIFT_STATE_CACHE = (
+    "this model has compressed convolutional attention (cca_config), and serving it needs a cache of convolution and shift state "
+    "beside keys and values (of every sequence and layer the previous position's normed input for the shifted value heads, and "
+    "the last cca_time0 - 1 and cca_time1 - 1 positions of the latent before each convolution), which serving/paged_cache.py and "
+    "serving/engine.py do not have: it trains, it does not decode"
+)
+_NO_CONV_ACROSS_A_SHARD_EDGE = (
+    "this model has compressed convolutional attention (cca_config), whose two convolutions over the sequence and whose value shift "
+    "read the positions before a token: under context parallelism a shard's first positions would need the last of the shard before "
+    "(a halo exchange beside parallel/ring_attention.py's ring), which is not written. Run it without a cp axis."
+)
+_NO_ROUTER_STATE_ACROSS_STAGES = (
+    "this model's expert layers hand their router's state from layer to layer (moe_config.use_eda); pipeline parallelism hands ONE "
+    "activation from each stage to the next (parallel/pipeline*.py), and a second array across the stage boundary is not written. "
+    "Run it without a pp axis."
+)
+
+
+KEY_TEMPERATURE = "cca_key_temperature"  # counted by a model whose mixer is `cca`: the mean of the learned key temperatures
+
+_MIXER_OF = {SLIDING: "swa", FULL: "attn", HYBRID: "cca"}  # a published layer type as the block's mixer seat names it
 
 
 def refuse_serving(spec: "GPT2ModelSpec") -> None:
     """A cache, or a forward that reads one, is refused by the name of what serving lacks for this model."""
     for missing, reason in ((spec.has_ssm, _NO_RECURRENT_STATE_CACHE), (spec.mla is not None, _NO_LATENT_CACHE),
-                            (spec.has_window, _NO_CACHE_BY_LAYER_KIND),
+                            (spec.has_window, _NO_CACHE_BY_LAYER_KIND), (spec.cca is not None, _NO_CONV_AND_SHIFT_STATE_CACHE),
                             (spec.has_moe, _NO_DECODE_THROUGH_DISPATCH), (spec.loop is not None, _NO_CACHE_ENTRY_PER_WALK)):
         if missing:
             raise NotImplementedError(reason)
@@ -1597,6 +1734,10 @@ class GPT2Module(nn.Module):
             raise NotImplementedError(_NO_WINDOW_IN_THE_RING)
         if spec.has_window and spec.pipeline_axis is not None:
             raise NotImplementedError(_NO_WINDOW_LAYERS_IN_STAGES)
+        if spec.cca is not None and spec.context_parallel_axis is not None:
+            raise NotImplementedError(_NO_CONV_ACROSS_A_SHARD_EDGE)
+        if spec.router_state_width and spec.pipeline_axis is not None:
+            raise NotImplementedError(_NO_ROUTER_STATE_ACROSS_STAGES)
         if spec.pipeline_axis is not None and (spec.has_moe or spec.mla is not None or len(spec.stack_runs) > 1):
             raise NotImplementedError(
                 "pipeline parallelism splits ONE stack of equal dense-decoder layers over its stages; a model whose "
@@ -1614,8 +1755,14 @@ class GPT2Module(nn.Module):
                 return {"exits": exits, "gate_logits": gate_logits}
             x = exits[-1]
         elif spec.scan_layers and (len(spec.stack_runs) > 1 or spec.has_moe):
+            router_state, first = None, 0  # the state starts as none before the first layer and is dropped after the last
             for i, (mixer, ffn, length) in enumerate(spec.stack_runs):
-                x, counters = _LayerRun(spec, self.deterministic, mixer, length, ffn, name=f"run_{i}")(x)
+                run = _LayerRun(spec, self.deterministic, mixer, length, ffn, first, name=f"run_{i}")
+                if ffn == "moe" and spec.router_state_width:
+                    x, counters, router_state = run(x, router_state)
+                else:
+                    x, counters = run(x)
+                first += length
                 if counters is not None:
                     layer_counters.append(counters)
         elif spec.scan_layers and self.slot_spec is not None:
@@ -1680,22 +1827,30 @@ class GPT2Module(nn.Module):
                 with jax.named_scope(scopes.LAYER_CARRY):  # the scan's own stacking and slicing; blocks name themselves
                     x, _ = scanned(x, None)
         else:
+            router_state = None
             for i in range(spec.n_layer):
                 block_cls = (
                     _remat_block_cls(spec)
                     if not self.decode and self.slot_spec is None and _layer_remats(spec, i)
                     else GPT2Block
                 )
-                x = block_cls(
+                block = block_cls(
                     spec, self.deterministic, self.decode, slot_spec=self.slot_spec, mixer=spec.kinds[i],
                     ffn=spec.ffn_kinds[i], name=f"h_{i}"
-                )(x, slot, positions)
-                if spec.ffn_kinds[i] == "moe":
+                )
+                if spec.scale_residual_merge or spec.router_state_width:  # the layer's index as an array: a remat-wrapped block traces its arguments
+                    x = block(x, slot, positions, router_state, jnp.int32(i))
+                else:
+                    x = block(x, slot, positions)
+                if spec.ffn_kinds[i] == "moe" and spec.router_state_width:
+                    x, counters, router_state = x
+                    layer_counters.append(counters[None])
+                elif spec.ffn_kinds[i] == "moe":
                     x, counters = x
                     layer_counters.append(counters[None])
         if layer_counters and not self.is_initializing() and self.is_mutable_collection("counters"):
             self.sow("counters", "moe", jnp.concatenate(layer_counters, axis=0), reduce_fn=lambda _, new: new,
-                     init_fn=lambda: jnp.zeros((0, len(COUNTERS) + spec.moe.n_routed_experts + spec.moe.counts_aux_loss), jnp.float32))
+                     init_fn=lambda: jnp.zeros((0, spec.counter_row_width), jnp.float32))
 
         if spec.loop is None:  # a looped model's final norm closes every walk (`_walks`)
             x = build_norm(spec.lm_head_norm, "lm_head_norm")(x)
@@ -1767,6 +1922,8 @@ class GPT2LLM(NNModel):
         layer_types: Optional[list[str]] = None,
         sliding_window: Optional[int] = None,
         rope_parameters: Optional[dict] = None,
+        cca_config: Optional[CCAConfig | dict] = None,
+        scale_residual_merge: bool = False,
     ):
         super().__init__(
             sample_key=sample_key,
@@ -1787,6 +1944,15 @@ class GPT2LLM(NNModel):
                 "exit_gate": [r".*/exit_gate/(kernel|bias)$"],
                 # what Mamba marks `_no_weight_decay` in the state-space mixer, and its biases
                 "ssm": [r".*/ssm/(A_log|D)$", r".*/ssm/.*bias$"],
+                # what a `zaya` model adds (PR 40). Matrices, which a recipe decays: the convolutions of compressed
+                # convolutional attention (its projections are `linear`'s by their names), the MLP router's kernels
+                "cca_convolutions": [r".*/cca/conv[01]_kernel$"],
+                "router_mlp": [r".*/moe/router/(down|fc1|fc2|out)/kernel$"],
+                # and vectors, which it does not: the key temperature and the convolutions' biases, a merge's scales and
+                # shifts, the router's biases, its gate on the state handed on and its norm's scale
+                "cca_vectors": [r".*/cca/(key_temperature|conv[01]_bias)$"],
+                "residual_merge": [r".*/(attn_merge|ffn_merge)/(residual|out)_(scale|bias)$"],
+                "router_vectors": [r".*/moe/router/(down|fc1|fc2)/bias$", r".*/moe/router/(eda_gate|norm_scale)$"],
             },
         )
         if n_head_q % n_head_kv != 0:
@@ -1849,6 +2015,8 @@ class GPT2LLM(NNModel):
             head_dim_key=head_dim,
             sliding_window=sliding_window if layer_types and SLIDING in layer_types else None,
             rope_by_kind=tuple(sorted((_MIXER_OF[kind], RopeSpec.from_config(rope)) for kind, rope in (rope_parameters or {}).items())),
+            cca=CCASpec.from_config(cca_config) if cca_config is not None else None,
+            scale_residual_merge=scale_residual_merge,
         )
         self.sequence_length = sequence_length
         self.vocab_size = vocab_size
@@ -1899,7 +2067,11 @@ class GPT2LLM(NNModel):
         if not spec.has_moe:
             return {}
         aux = {AUX_LOSS: ()} if spec.moe.counts_aux_loss else {}  # the balance term, the mean over the expert layers (a softmax router's)
-        return {**{name: () for name in COUNTERS}, EXPERT_LOAD: (spec.ffn_kinds.count("moe"), spec.moe.n_routed_experts), **aux}
+        if spec.moe.skip_column:  # the share of a layer's tokens that chose the column with no expert behind it, the mean over the layers
+            aux[SKIP_SHARE] = ()
+        if spec.cca is not None:  # the mean key temperature of compressed convolutional attention, over heads and layers
+            aux[KEY_TEMPERATURE] = ()
+        return {**{name: () for name in COUNTERS}, EXPERT_LOAD: (spec.ffn_kinds.count("moe"), spec.moe.router_width), **aux}
 
     @property
     def trains_on_exits(self) -> bool:
@@ -1915,12 +2087,17 @@ class GPT2LLM(NNModel):
             return super().apply_counted(params, inputs, train=train, rngs=rngs, hidden=hidden)
         module = GPT2Module(self.config_spec, deterministic=not train, output_hidden=hidden)
         out, state = module.apply(params, inputs[self.sample_key], rngs=rngs, mutable=["counters"])
-        rows = state["counters"]["moe"]  # [expert layers, 3 + E], and a last column for the balance term where the router has one
-        experts = self.config_spec.moe.n_routed_experts
-        counted = {COUNTERS[0]: rows[:, 0].mean(), COUNTERS[1]: rows[:, 1].max(), COUNTERS[2]: rows[:, 2].mean(),
-                   EXPERT_LOAD: rows[:, len(COUNTERS): len(COUNTERS) + experts]}
-        if self.config_spec.moe.counts_aux_loss:
-            counted[AUX_LOSS] = rows[:, -1].mean()  # the one thing counted that carries a gradient: `loss_from_layers`
+        rows = state["counters"]["moe"]  # [expert layers, `counter_row_width`]: 3, the router's columns, then what only some models count
+        moe = self.config_spec.moe
+        loads = rows[:, len(COUNTERS): len(COUNTERS) + moe.router_width]
+        counted = {COUNTERS[0]: rows[:, 0].mean(), COUNTERS[1]: rows[:, 1].max(), COUNTERS[2]: rows[:, 2].mean(), EXPERT_LOAD: loads}
+        if moe.counts_aux_loss:
+            # the one thing counted that carries a gradient (`loss_from_layers`): the row's last entry, but for a key temperature after it
+            counted[AUX_LOSS] = rows[:, -1 - (self.config_spec.cca is not None)].mean()
+        if moe.skip_column:
+            counted[SKIP_SHARE] = jnp.mean(loads[:, -1] / jnp.maximum(jnp.sum(loads, axis=1), 1.0))
+        if self.config_spec.cca is not None:
+            counted[KEY_TEMPERATURE] = rows[:, -1].mean()
         return (out if hidden else {self.prediction_key: out}), counted
 
     def loss_from_layers(self, counted: dict):

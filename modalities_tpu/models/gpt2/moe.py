@@ -55,6 +55,23 @@ summed). `bias_update_speed` 0, the default, leaves `b` as initialised or loaded
 `update_selection_bias` is the rule; the train step calls it through
 `GPT2LLM.after_update` once the optimizer is done.
 
+**A router of kind `mlp`** (PR 40: `model_type: zaya`, `router: mlp`; arXiv 2511.17127). The scores come from a small
+MLP over a state `router_hidden_size` wide that is handed from layer to layer, all of it float32:
+
+    s_l    = x W_d + b_d                        [T, R]
+    s_l    = s_l + g_l * s_{l-1}                `use_eda`: the previous layer's s (after its own sum, before its norm; zeros
+                                                before the first layer); g_l [R] learned, from 1
+    z      = RMSNorm_R(s_l)
+    logits = W_3 gelu(W_2 gelu(W_1 z + b_1) + b_2)      W_1, W_2 [R, R]; W_3 [R, E + 1] without bias where `use_mod` adds a
+                                                column with no expert behind it (a token that picks it skips the layer), else [R, E]
+    p      = softmax(logits) over the columns;  choice = the k largest of p + b;  w = p[choice]
+
+gelu is the exact one (erf). The skip column is a router column that no share holds: `plan_dispatch` leaves out a choice
+outside `[expert_offset, expert_offset + experts_held)`, so a token that picked it gets zero from the layer and passes by the
+residual; the loads, the selection bias and its rule run over all the columns. With one choice a token `norm_topk_prob`
+must be off (the normalised weight would be 1 and the router would get no gradient through it). The layer then takes the
+previous state beside its input and returns its own beside its output; `GPT2Block` and the layer scan carry it.
+
 Counted in a pass, from the choice: the pairs held, the largest and mean load of a held
 expert, and the load of each of the E experts. The block hands them up beside its output
 as one row (`COUNTERS`, then the E loads); `GPT2Module` puts them into the `counters`
@@ -64,7 +81,7 @@ collection for the train step to publish (the three) and to move `b` by (the loa
 from __future__ import annotations
 
 from dataclasses import dataclass
-from typing import Annotated, Optional
+from typing import Annotated, Literal, Optional
 
 import flax.linen as nn
 import jax
@@ -75,6 +92,7 @@ from modalities_tpu.telemetry import scopes
 
 COUNTERS = ("moe_pairs_held", "moe_load_max", "moe_load_mean")  # a layer's, in this order
 AUX_LOSS = "moe_aux_loss"  # a softmax router's balance term: the row's last column, after the E loads, and the one with a gradient
+SKIP_SHARE = "moe_skip_share"  # where the router has a skip column: the share of a layer's tokens that chose it, the mean over the expert layers
 EXPERT_LOAD = "moe_expert_load"  # [expert layers, E]: the pairs each of the router's experts got, held or not
 BIAS_LEAF = "moe/router/e_score_correction_bias"
 
@@ -101,6 +119,13 @@ class MoEConfig(BaseModel):
     bias_update_speed: Annotated[float, Field(ge=0.0)] = 0.0
     # a recipe's too: the weight of the balance term in the loss (a softmax router's; 0: counted and published, not added)
     router_aux_loss_coef: Annotated[float, Field(ge=0.0)] = 0.0
+    # `model_type: zaya` (PR 40). `router: mlp` scores by an MLP over a state `router_hidden_size` wide (the module docstring);
+    # `use_eda` (the source's `zaya_use_eda`) adds the previous layer's state under a learned gate, `use_mod` (`zaya_use_mod`)
+    # a column with no expert behind it. `matrix`: one `[d, E]` matrix, as before there were kinds.
+    router: Literal["matrix", "mlp"] = "matrix"
+    router_hidden_size: Optional[Annotated[int, Field(strict=True, ge=1)]] = None
+    use_eda: bool = False
+    use_mod: bool = False
 
     @model_validator(mode="after")
     def refuse_what_is_not_written(self) -> "MoEConfig":
@@ -121,8 +146,22 @@ class MoEConfig(BaseModel):
         if self.num_experts_per_tok > self.n_routed_experts:
             raise ValueError("moe_config.num_experts_per_tok exceeds n_routed_experts")
         held = self.n_routed_experts if self.experts_held is None else self.experts_held
-        if self.expert_offset + held > self.n_routed_experts:
+        if self.expert_offset + held > self.n_routed_experts:  # the skip column (`use_mod`) is a column, not an expert: nobody holds it
             raise ValueError("moe_config: expert_offset + experts_held exceeds n_routed_experts")
+        if self.router == "matrix" and (self.router_hidden_size is not None or self.use_eda or self.use_mod):
+            raise ValueError("moe_config.router_hidden_size, use_eda and use_mod belong to the router of kind mlp (router: mlp); "
+                             "the matrix router has no state to hand on and no skip column")
+        if self.router == "mlp":
+            if self.router_hidden_size is None:
+                raise ValueError("moe_config.router mlp needs router_hidden_size (the width of the state its MLP reads)")
+            if self.scoring_func != "softmax" or self.topk_method != "noaux_tc" or self.router_aux_loss_coef:
+                raise ValueError("moe_config.router mlp scores by softmax over its columns and balances by the selection bias: "
+                                 "scoring_func softmax, topk_method noaux_tc, no router_aux_loss_coef")
+            if self.num_experts_per_tok == 1 and self.norm_topk_prob:
+                raise ValueError("moe_config.norm_topk_prob with one choice a token makes every weight 1 and leaves the router "
+                                 "no gradient through it: set it false")
+            if self.n_shared_experts or self.first_k_dense_replace:
+                raise ValueError("moe_config.router mlp: a shared expert or leading dense layers beside the carried state are not written")
         return self
 
 
@@ -141,11 +180,26 @@ class MoESpec:
     scoring_func: str = "sigmoid"
     selection_bias: bool = True  # `topk_method: noaux_tc`; False (`greedy`): no bias leaf in the tree
     router_aux_loss_coef: float = 0.0
+    router: str = "matrix"  # "mlp": an MLP over a state `router_hidden` wide (PR 40)
+    router_hidden: int = 0
+    use_eda: bool = False  # the state is handed from layer to layer
+    skip_column: bool = False  # a last router column with no expert behind it
 
     @property
     def counts_aux_loss(self) -> bool:
-        """A softmax router computes its balance term every pass (the row's last column), whatever its weight in the loss."""
-        return self.scoring_func == "softmax"
+        """A softmax matrix router computes its balance term every pass (the row's last column), whatever its weight in the
+        loss; the MLP router balances by its selection bias and has none."""
+        return self.scoring_func == "softmax" and self.router == "matrix"
+
+    @property
+    def router_width(self) -> int:
+        """The router's columns: every expert, held or not, and the skip column where there is one."""
+        return self.n_routed_experts + self.skip_column
+
+    @property
+    def state_width(self) -> int:
+        """The width of the state a layer takes from the one before and hands on; 0: none."""
+        return self.router_hidden if self.use_eda else 0
 
     @classmethod
     def from_config(cls, config: "MoEConfig | dict") -> "MoESpec":
@@ -160,7 +214,8 @@ class MoESpec:
             experts_held=config.n_routed_experts if config.experts_held is None else config.experts_held,
             expert_offset=config.expert_offset, bias_update_speed=float(config.bias_update_speed),
             scoring_func=config.scoring_func, selection_bias=config.topk_method == "noaux_tc",
-            router_aux_loss_coef=float(config.router_aux_loss_coef),
+            router_aux_loss_coef=float(config.router_aux_loss_coef), router=config.router,
+            router_hidden=config.router_hidden_size or 0, use_eda=config.use_eda, skip_column=config.use_mod,
         )
 
 
@@ -190,24 +245,63 @@ class _Router(nn.Module):
                             (x.shape[-1], moe.n_routed_experts), jnp.float32)
         logits = jnp.dot(x.astype(jnp.float32), kernel, precision=jax.lax.Precision.HIGHEST)
         scores = jax.nn.softmax(logits, axis=-1) if moe.scoring_func == "softmax" else jax.nn.sigmoid(logits)
-        selection = scores
-        if moe.selection_bias:
-            bias = self.param("e_score_correction_bias", nn.with_logical_partitioning(nn.initializers.zeros, ("router",)),
-                              (moe.n_routed_experts,), jnp.float32)
-            selection = scores + jax.lax.stop_gradient(bias)
-        _, choice = jax.lax.top_k(selection, moe.num_experts_per_tok)
-        # the scores at the chosen experts, as a compare against all the experts and a sum: `take_along_axis` is a gather
-        # forward and a scatter backward, 65 ns an index on the chip (13 ms a step here), this a few elementwise passes
-        chosen = choice[..., None] == jnp.arange(moe.n_routed_experts, dtype=choice.dtype)
-        weights = jnp.sum(jnp.where(chosen, scores[:, None, :], 0.0), axis=-1)
-        load = jnp.sum(chosen, axis=(0, 1), dtype=jnp.float32)  # of every expert the router knows, held or not
-        if moe.norm_topk_prob:
-            weights = weights / (jnp.sum(weights, axis=-1, keepdims=True) + 1e-20)
-        aux = None
-        if moe.counts_aux_loss:  # E sum_e f_e P_e: the gradient reaches the router through P alone
-            share = jax.lax.stop_gradient(load) / (x.shape[0] * moe.num_experts_per_tok)
-            aux = moe.n_routed_experts * jnp.sum(share * jnp.mean(scores, axis=0))
-        return choice, weights * moe.routed_scaling_factor, load, aux
+        return _chosen(self, moe, scores, x.shape[0])
+
+
+def _chosen(module: nn.Module, moe: MoESpec, scores, tokens: int):
+    """Choice, weights, loads and the balance term from a router's scores `[T, columns]`: what both kinds of router share.
+    Holds the selection bias of the calling router module."""
+    columns = scores.shape[-1]
+    selection = scores
+    if moe.selection_bias:
+        bias = module.param("e_score_correction_bias", nn.with_logical_partitioning(nn.initializers.zeros, ("router",)),
+                            (columns,), jnp.float32)
+        selection = scores + jax.lax.stop_gradient(bias)
+    _, choice = jax.lax.top_k(selection, moe.num_experts_per_tok)
+    # the scores at the chosen experts, as a compare against all the experts and a sum: `take_along_axis` is a gather
+    # forward and a scatter backward, 65 ns an index on the chip (13 ms a step here), this a few elementwise passes
+    chosen = choice[..., None] == jnp.arange(columns, dtype=choice.dtype)
+    weights = jnp.sum(jnp.where(chosen, scores[:, None, :], 0.0), axis=-1)
+    load = jnp.sum(chosen, axis=(0, 1), dtype=jnp.float32)  # of every expert the router knows, held or not
+    if moe.norm_topk_prob:
+        weights = weights / (jnp.sum(weights, axis=-1, keepdims=True) + 1e-20)
+    aux = None
+    if moe.counts_aux_loss:  # E sum_e f_e P_e: the gradient reaches the router through P alone
+        share = jax.lax.stop_gradient(load) / (tokens * moe.num_experts_per_tok)
+        aux = moe.n_routed_experts * jnp.sum(share * jnp.mean(scores, axis=0))
+    return choice, weights * moe.routed_scaling_factor, load, aux
+
+
+class _MLPRouter(nn.Module):
+    """The router of kind `mlp` (the module docstring), float32 whatever the compute dtype. Takes the layer's tokens and
+    the previous layer's state (None: there is none to add), returns what `_Router` returns and the state it hands on."""
+
+    moe: MoESpec
+    eps: float
+
+    @nn.compact
+    def __call__(self, x, previous=None):
+        moe, width, f32 = self.moe, self.moe.router_hidden, jnp.float32
+        highest = jax.lax.Precision.HIGHEST
+
+        def dense(features, name, use_bias=True):
+            return nn.Dense(features, use_bias=use_bias, name=name, dtype=f32, param_dtype=f32, precision=highest,
+                            kernel_init=nn.with_logical_partitioning(nn.initializers.normal(0.02), (None, None)),
+                            bias_init=nn.with_logical_partitioning(nn.initializers.zeros, (None,)))
+
+        state = dense(width, scopes.ROUTER_DOWN)(x.astype(f32))
+        if moe.use_eda:
+            with jax.named_scope(scopes.ROUTER_EDA):
+                gate = self.param("eda_gate", nn.with_logical_partitioning(nn.initializers.ones, (None,)), (width,), f32)
+                if previous is not None:
+                    state = state + gate * previous.astype(f32)
+        with jax.named_scope(scopes.ROUTER_MLP):
+            scale = self.param("norm_scale", nn.with_logical_partitioning(nn.initializers.ones, (None,)), (width,), f32)
+            z = state * jax.lax.rsqrt(jnp.mean(state * state, axis=-1, keepdims=True) + self.eps) * scale
+            hidden = jax.nn.gelu(dense(width, "fc1")(z), approximate=False)
+            hidden = jax.nn.gelu(dense(width, "fc2")(hidden), approximate=False)
+            scores = jax.nn.softmax(dense(moe.router_width, "out", use_bias=False)(hidden), axis=-1)
+        return (*_chosen(self, moe, scores, x.shape[0]), state)
 
 
 class _SharedExpert(nn.Module):
@@ -246,14 +340,17 @@ class _Experts(nn.Module):
 
 class MoE(nn.Module):
     """The expert layer; sits in a block's `MLP` seat under the name `moe`. Returns its
-    output and what the layer counted (float32 [3 + E]: `COUNTERS`, then the load of each of the router's experts; a
-    softmax router's balance term after them, [3 + E + 1], the one entry that carries a gradient)."""
+    output and what the layer counted (float32 [3 + columns]: `COUNTERS`, then the load of each of the router's columns, its
+    experts and the skip column where it has one; a softmax matrix router's balance term after them, one entry more, the
+    one that carries a gradient)."""
 
     spec: object  # GPT2ModelSpec (its `moe` is the MoESpec)
     deterministic: bool = True
 
     @nn.compact
-    def __call__(self, x):
+    def __call__(self, x, router_state=None):
+        """`router_state` [B, S, R]: the previous layer's, for a router that is handed one (`use_eda`; None before the first
+        layer). Such a layer returns `(out, counters, its own state)`, every other `(out, counters)`."""
         from modalities_tpu.ops import expert_dispatch
         from modalities_tpu.telemetry import get_active_telemetry
 
@@ -270,10 +367,16 @@ class MoE(nn.Module):
             "rows": expert_dispatch.rows_for(pairs, moe.experts_held, expert_dispatch.TILE),
             "kernels": ("moe_combine",) if combine == "slabs" else (),  # the grouped products and the tiles' gathers are the plain form
             "combine": combine, "combine_block": block, "combine_blocks_at_most": moe.experts_held * -(-batch * seq // block),
+            **({"router": "mlp", "skip_column": moe.skip_column, "router_state": moe.state_width} if moe.router == "mlp" else {}),
         })
 
+        state = None
         with jax.named_scope(scopes.MOE_ROUTER):
-            choice, weights, load, aux = _Router(moe, name="router")(tokens)
+            if moe.router == "mlp":
+                previous = None if router_state is None else router_state.reshape(batch * seq, moe.router_hidden)
+                choice, weights, load, aux, state = _MLPRouter(moe, spec.ffn_norm.eps, name="router")(tokens, previous)
+            else:
+                choice, weights, load, aux = _Router(moe, name="router")(tokens)
         with jax.named_scope(scopes.MOE_DISPATCH):
             plan = expert_dispatch.plan_dispatch(choice, moe.expert_offset, moe.experts_held)
             held = jnp.sum(plan.group_sizes).astype(jnp.float32)
@@ -290,4 +393,6 @@ class MoE(nn.Module):
             with jax.named_scope(scopes.MOE_COMBINE):
                 out = out + shared
         out = nn.Dropout(rate=spec.dropout)(out, deterministic=self.deterministic or spec.dropout == 0.0)
+        if moe.state_width:
+            return out, counters, state.reshape(batch, seq, moe.router_hidden)
         return out, counters

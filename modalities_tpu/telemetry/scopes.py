@@ -62,6 +62,25 @@ A stack of window and global attention layers (`layer_types`, PR 38; `models/gpt
     ATTN_GLOBAL       global            a `full_attention` layer's: `block/global/attn/...` (YaRN's tables under `attn/rope` where
                                         `rope_parameters` asks for them); a model without `layer_types` has neither name
 
+Compressed convolutional attention (`cca_config`, a `hybrid` layer's mixer; `models/gpt2/cca.py`), under the module name `cca`
+in a block's mixer seat; `cca/rope` (the rotary on the rotated part of a head) and `cca/attn_core` (the flash kernels) are the names above:
+
+    CCA_LATENT        latent            the projections into the latent: q, k and the two halves of v (`cca/latent/{q_attn,k_attn,v_attn,v_attn_prev}`)
+    CCA_CONV          conv              both convolutions over the sequence on q and k together: the depthwise one's shifted multiplies,
+                                        the grouped one's head-batched products on the array and its shift
+    CCA_QK_MEAN       qk_mean           the mean of the two pre-convolution latents, a query head's and its key head's, added to q and k
+    CCA_QK_NORM       qk_norm           the L2 norm of every head of q and k, float32, and the learned key temperature
+    CCA_VALUE_SHIFT   value_shift       the half of the value heads that is read off the previous position
+    CCA_OUT           out               the output projection back to the residual's width (`cca/out/c_proj`)
+
+The router of kind `mlp` (`moe_config.router: mlp`; `models/gpt2/moe.py`), inside `moe/router`:
+
+    ROUTER_DOWN       down              the projection of the layer's input to the router's state (the name of its module)
+    ROUTER_EDA        eda               the previous layer's state added in, under the learned gate
+    ROUTER_MLP        mlp               the state's norm, the two hidden layers and the output columns (the skip column among them)
+
+A block with `scale_residual_merge` holds the modules `attn_merge` and `ffn_merge` (four `[n_embd]` leaves each); their arithmetic is under `residual`.
+
 Looped decoder (`loop_config`: the stack walked several times over one set of weights; `models/gpt2/gpt2_model.py`, `training/train_step.py`):
 
     LOOP              loop              round the walks: the carry between walks, every walk's exit stacked, and in the backward
@@ -134,6 +153,17 @@ MOE_EXPERTS = "experts"
 MOE_SHARED = "shared"
 MOE_COMBINE = "combine"
 
+CCA = "cca"  # the mixer's module name in the block's seat (a `hybrid` layer)
+CCA_LATENT = "latent"
+CCA_CONV = "conv"
+CCA_QK_MEAN = "qk_mean"
+CCA_QK_NORM = "qk_norm"
+CCA_VALUE_SHIFT = "value_shift"
+CCA_OUT = "out"
+ROUTER_DOWN = "down"  # inside `moe/router`, a router of kind `mlp`
+ROUTER_EDA = "eda"
+ROUTER_MLP = "mlp"
+
 UPDATE_SCOPES = (GRAD_ACCUMULATE, GRAD_NORM, CLIP, OPTIMIZER, APPLY_UPDATES, ANOMALY_SELECT, STEP_METRICS)
 MODEL_SCOPES = (WTE, ROPE, ATTN_CORE, RESIDUAL, LAYER_CARRY)
 SSM_SCOPES = (SSM_CONV, SSM_SCAN, SSM_GATE)  # on the step only where a layer holds the state-space mixer
@@ -143,6 +173,9 @@ LOOP_SCOPES = (LOOP, EXIT_GATE, EXIT_LOSS)  # on the step only where the stack i
 WINDOW_SCOPES = (ATTN_WINDOW, ATTN_GLOBAL)  # on the step only where the stack holds window layers (`layer_types`)
 
 MOE_SCOPES = (MOE_ROUTER, MOE_DISPATCH, MOE_EXPERTS, MOE_SHARED, MOE_COMBINE)  # on the step only where a layer holds experts
+
+CCA_SCOPES = (CCA_LATENT, CCA_CONV, CCA_QK_MEAN, CCA_QK_NORM, CCA_VALUE_SHIFT, CCA_OUT)  # on the step only where a layer's mixer is `cca`
+ROUTER_MLP_SCOPES = (ROUTER_DOWN, ROUTER_EDA, ROUTER_MLP)  # on the step only where the router is an MLP over a carried state
 
 # what a path holds beside scopes: the jit wrapper, the plumbing of loops, calls and branches
 _PLUMBING = frozenset(("while", "body", "cond", "closed_call", "checkpoint"))

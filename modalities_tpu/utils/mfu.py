@@ -63,6 +63,12 @@ class GPT2MFUCalculator(MFUCalculatorIF):
     A layer under a window (`layer_types`: `sliding_attention`) sees `sliding_window` positions and not the
     sequence: it adds `6 * min(2 W, s) * H * 2 * head_dim`, and `head_dim` is the config's own where it gives one.
 
+    A layer of compressed convolutional attention (`cca_config`) is counted as it is held: its projections into and out of
+    the latent and the grouped convolution's two products a head are parameters a token multiplies (in `6N`), the causal
+    scores at `n_head_q` heads of `head_dim` the `12 L s h` term at that width; the MLP router's matrices are in `6N` too, and
+    of the held experts a token passes `num_experts_per_tok * experts_held / columns`, the router's skip column among the
+    columns (a token that picks it passes none). The tied head is `6 E V`, the table's own count.
+
     A looped model (`loop_config`) uses a parameter once for every walk, and `6N` would count it
     once: its required operations are `6 x a layer's kernels x L x T` + `6 x T x L x s x h` (the
     causal half of attention, a layer application) + `6 x T x E x V` (the head, once an exit) a
@@ -90,7 +96,7 @@ class GPT2MFUCalculator(MFUCalculatorIF):
         self.num_parameters = num_parameters or 0
         spec = getattr(model_parts if model_parts is not None else wrapped_model, "config_spec", None)
         kinds = getattr(spec, "layer_kinds", ())
-        self.n_attention_layer = kinds.count("attn") if kinds else n_layer
+        self.n_attention_layer = kinds.count("attn") + kinds.count("cca") if kinds else n_layer
         self.active_parameters = self.num_parameters
         self.attention_width = 2 * n_embd  # q k^T and p v, each n_head * head_dim = n_embd wide
         if getattr(spec, "head_dim_key", None) is not None:
@@ -103,7 +109,7 @@ class GPT2MFUCalculator(MFUCalculatorIF):
         if moe is not None:
             expert = 3 * n_embd * moe.moe_intermediate_size
             expert_layers = spec.ffn_kinds.count("moe")
-            chosen_here = moe.num_experts_per_tok * moe.experts_held / moe.n_routed_experts
+            chosen_here = moe.num_experts_per_tok * moe.experts_held / moe.router_width
             self.active_parameters = self.num_parameters - expert_layers * (moe.experts_held - chosen_here) * expert
         if mla is not None:
             self.attention_width = spec.n_head_q * (mla.qk_head_dim + mla.v_head_dim)

@@ -235,6 +235,7 @@ LOOKUPS = {
     "train-ouro-2p6b-4k": (1, SEQ, 2048, 49152),
     "train-kanana2-30b-8k": (2, 8192, 2048, 16128),
     "train-mellum2-12b-16k": (1, 4 * SEQ, 2304, 12288),
+    "train-zaya1-8b-8k": (2, 8192, 2048, 32784),  # 16,384 ids against 16 x 2049 rows: over a quarter of them at a width the sorted form is fast at
 }
 
 
@@ -352,3 +353,30 @@ def test_the_looped_steps_backward_adds_a_layers_gradient_into_one_stack(v5e, mo
     assert stack > 2 * layers * 3 * n_embd * ffn
     assert sums_by_walk and not sums_in_place, (len(sums_by_walk), sums_in_place[:2])
     assert by_walk - in_place >= stack, (by_walk, in_place, stack)
+
+
+def test_the_compressed_convolutional_attention_cells_step_compiles_for_v5e(v5e, monkeypatch, tmp_path):
+    """The whole donated train step of `benchmark/configs/zaya1-8b-ep2/train.yaml` (PR 40: 10 hybrid layers of width 2048, 8 of 16
+    experts of 2048 held, 32,784 rows of the tied table, 2 rows of 8,192, every block rematerialized) through the recipe's own
+    components, compiled for a described v5e: the mixer's shifts, both convolutions and the router's carried state lower beside
+    the kernels the other cells share; no kernel of its own; the step fits the chip with the room `meta.json` states."""
+    from benchmark.traffic import packed_documents
+    from modalities_tpu.ops.pallas import autotune
+    from modalities_tpu.utils.recipe_validation import build_lowered_train_step
+
+    monkeypatch.setattr(jax, "devices", lambda *args, **kwargs: list(v5e[:1]))
+    autotune.clear_cache()
+    monkeypatch.chdir(tmp_path)  # the YAML's paths are relative; the corpus only has to exist and hold a step's rows
+    mix = {"sequences": 8, "size_seed": 1, "doc_len_median": 600, "doc_len_sigma": 1.0, "doc_len_min": 32, "doc_len_max": 8192}
+    packed_documents.generate(mix, 1, tmp_path / "data" / "train.pbin", vocab_size=32784, sequence_length=8192)
+    repo = os.path.dirname(os.path.dirname(os.path.dirname(os.path.abspath(__file__))))
+    built = build_lowered_train_step(os.path.join(repo, "benchmark", "configs", "zaya1-8b-ep2", "train.yaml"))
+    executable = built.lowered.compile()
+    text = executable.as_text()
+    kernels = sorted(set(re.findall(r"(\w+)\)*/pallas_call", text)))
+    assert kernels == ["flash_attention_bwd", "flash_attention_fwd", "fused_ce_bwd_dw", "fused_ce_fwd", "fused_rmsnorm_bwd", "fused_rmsnorm_fwd"], kernels
+    for scope in ("cca/conv", "cca/qk_norm", "cca/value_shift", "moe/router/router/eda", "moe/router/router/mlp", "residual/attn_merge"):
+        assert f"/{scope}/" in text, scope
+    memory = executable.memory_analysis()
+    peak = memory.argument_size_in_bytes + memory.output_size_in_bytes + memory.temp_size_in_bytes - memory.alias_size_in_bytes
+    assert 12.0 * 2**30 < peak < 14.7 * 2**30, peak / 2**30  # meta.json, memory_analysis: 12.76 GiB at 10 layers
