@@ -162,7 +162,7 @@ class CompressedConvAttention(nn.Module):
             if spec.dropout > 0.0 and not self.deterministic:  # on the probabilities, as the plain attention's: the written-out softmax
                 y = g.manual_attention(q, k, v, dropout_rate=spec.dropout, dropout_rng=self.make_rng("dropout"))
             elif spec.attention_impl == g.AttentionImplementation.DAO_FLASH.value:
-                y = g.flash_attention(q, k, v)
+                y = g.flash_attention(q, k, v, kept=True) if spec.remat_keep_flash else g.flash_attention(q, k, v)
             elif spec.attention_impl == g.AttentionImplementation.MANUAL.value:
                 y = g.manual_attention(q, k, v)
             else:

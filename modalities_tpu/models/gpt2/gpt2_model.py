@@ -469,6 +469,10 @@ class GPT2ModelSpec:
     remat_variant: Optional[str] = None
     remat_freq: int = 1
     remat_save_list: tuple[str, ...] = ()
+    # under `full`: a rematerialized block keeps the flash kernel's o and lse beside its input, so that its backward does
+    # not run the forward kernel again. Not a key of any config: `training/activation_checkpointing.attention_keep_plan`
+    # decides it from the shapes and the device's bytes when the train step is traced (`training/train_step.py`)
+    remat_keep_flash: bool = False
     # fuse lm-head + CE per sequence chunk of this size (train/eval step): the
     # [B,S,V] fp32 logits never materialize — at 32k ctx x 50k vocab that tensor
     # alone is 6.6 GB, more than a v5e can give it. None = whole-sequence logits.
@@ -590,6 +594,7 @@ class GPT2ModelSpec:
                 self.remat_variant,
                 self.remat_freq,
                 self.remat_save_list,
+                self.remat_keep_flash,
                 self.lm_head_chunk_size,
                 self.lm_head_fused_ce,
                 self.context_parallel_axis,
@@ -721,11 +726,12 @@ def sdpa_attention(q, k, v):
     return jax.nn.dot_product_attention(q, k, v, is_causal=True)
 
 
-def flash_attention(q, k, v, window: Optional[int] = None):
-    """Pallas flash-attention tier; falls back to SDPA off-TPU (under a window, to the masked softmax written out)."""
+def flash_attention(q, k, v, window: Optional[int] = None, kept: bool = False):
+    """Pallas flash-attention tier; falls back to SDPA off-TPU (under a window, to the masked softmax written out).
+    `kept`: the call sits in a rematerialized block that keeps the kernel's o and lse (`spec.remat_keep_flash`)."""
     from modalities_tpu.ops.attention import flash_attention_or_fallback
 
-    return flash_attention_or_fallback(q, k, v, causal=True, window=window)
+    return flash_attention_or_fallback(q, k, v, causal=True, window=window, kept=kept)
 
 
 class QuantDenseGeneral(nn.Module):
@@ -901,7 +907,8 @@ class CausalSelfAttention(nn.Module):
                     q, k, v, dropout_rate=spec.dropout, dropout_rng=self.make_rng("dropout"), window=window
                 )
             elif impl == AttentionImplementation.DAO_FLASH.value:
-                y = flash_attention(q, k, v, window)
+                # told only where the block keeps: a call that is not told is the call it always was (tests/benchmark wrap it)
+                y = flash_attention(q, k, v, window, kept=True) if spec.remat_keep_flash else flash_attention(q, k, v, window)
             elif impl == AttentionImplementation.MANUAL.value or window is not None:  # fused SDPA's mask is causal, no more
                 y = manual_attention(q, k, v, window=window)
             else:
@@ -909,8 +916,11 @@ class CausalSelfAttention(nn.Module):
 
             # named save point for selective-op remat (reference SAVE_DICT saves the SDPA
             # output, activation_checkpointing.py:67-83): save_list=("attn_out",) stores
-            # only this tensor and recomputes the rest of the block — the backward then
-            # skips re-running the attention kernel, the block's most expensive op
+            # this tensor and recomputes the rest of the block. That skips the XLA-fused
+            # tiers' attention; it does NOT skip the Pallas kernel, whose backward reads
+            # o and lse as the kernel wrote them (lse has no name here and y is a
+            # transposed copy of o). Under `full` a block keeps those two instead
+            # (`remat_keep_flash`, `flash_attention.KEPT_OUT` / `KEPT_LSE`)
             from jax.ad_checkpoint import checkpoint_name
 
             y = checkpoint_name(y, "attn_out")
@@ -1299,6 +1309,12 @@ def _remat_block_cls(spec: "GPT2ModelSpec"):
         from modalities_tpu.training.activation_checkpointing import save_list_policy
 
         policy = save_list_policy(spec.remat_save_list)
+    elif spec.remat_variant == "full" and spec.remat_keep_flash:
+        # the block's input and the flash kernel's o and lse; a block with no such call (state-space, the ffn) has no
+        # value under these names and keeps what `None` kept: its input
+        from modalities_tpu.ops.pallas.flash_attention import KEPT_LSE, KEPT_OUT
+
+        policy = jax.checkpoint_policies.save_only_these_names(KEPT_OUT, KEPT_LSE)
     return nn.remat(GPT2Block, prevent_cse=False, policy=policy)
 
 
@@ -2034,6 +2050,36 @@ class GPT2LLM(NNModel):
 
         self.config_spec = replace(self.config_spec, **changes)
         return self
+
+    def remat_flash_calls(self, rows: int, seq: int) -> Optional[dict]:
+        """The flash kernel calls that sit in blocks `_remat_block_cls` wraps under `full`, for a microbatch of `rows` rows of
+        `seq` as one shard sees it under the installed rules: `blocks` and the bytes of one block's input `[rows, seq, E]`
+        (what every rematerialized block keeps already), and for each kind of attention layer how many layers, the bytes
+        of the kernel's `o` `[rows, H, seq, Dv]` and of `lse` as numbers, `[rows, H, seq]` float32, and the bytes its
+        backward holds round the kernel (`backward_bytes`: q, k, v, o and its cotangent, dq, dk and dv a q head, lse and
+        delta as the kernel lays them out, a lane tile a row). It is what
+        `training/activation_checkpointing.attention_keep_plan` counts. None where no block is wrapped so: another variant
+        or none, a looped stack (`_walks_in_place` recomputes by hand and takes no policy), pipeline stages
+        (`jax.checkpoint` of their own), ring attention (no call of this kernel), a tier that is not the kernel, and off
+        the TPU, where `ops/attention.py` runs XLA's attention and there is no kernel to keep anything of."""
+        from modalities_tpu.ops.tiers import on_tpu
+        from modalities_tpu.parallel.sharding import shard_shape
+
+        spec = self.config_spec
+        if (spec.remat_variant != "full" or spec.loop is not None or spec.pipeline_axis is not None
+                or spec.context_parallel_axis is not None or spec.dropout > 0.0
+                or spec.attention_impl != AttentionImplementation.DAO_FLASH.value or not on_tpu()):
+            return None
+        itemsize = jnp.dtype(spec.compute_dtype).itemsize
+        # latent attention hands the kernel every head's own k and v, 192 and 128 wide; the others `n_head_kv` heads of `head_dim`
+        kv_heads, width, width_v = ((spec.n_head_q, spec.mla.qk_head_dim, spec.mla.v_head_dim) if spec.mla is not None
+                                    else (spec.n_head_kv, spec.head_dim, spec.head_dim))
+        b, s, h = shard_shape((rows, seq, spec.n_head_q), ("batch", None, "heads"))  # as `ops/attention.py` splits a call
+        h_kv = shard_shape((kv_heads,), ("kv_heads",))[0]
+        call = {"o_bytes": b * h * s * width_v * itemsize, "lse_bytes": b * h * s * 4,
+                "backward_bytes": b * s * itemsize * (3 * h * width + 3 * h * width_v + h_kv * (width + width_v)) + 2 * b * h * s * 128 * 4}
+        return {"blocks": spec.n_layer, "calls": [{"kind": kind, "layers": spec.kinds.count(kind), **call} for kind in ("attn", "swa", "cca") if kind in spec.kinds],
+                "block_input_bytes": math.prod(shard_shape((rows, seq, spec.n_embd), ("batch", "seq", "embed"))) * itemsize}
 
     def init_params(self, rng):
         dummy = jnp.zeros((1, min(8, self.sequence_length)), dtype=jnp.int32)

@@ -140,7 +140,8 @@ class LatentAttention(nn.Module):
             k = with_logical_constraint(k, ("batch", "seq", "heads", "head_dim"), spec)
             v = with_logical_constraint(v, ("batch", "seq", "heads", "head_dim"), spec)
             if spec.attention_impl == AttentionImplementation.DAO_FLASH.value:
-                y = flash_attention(q, k, v)  # scale 1 / sqrt(d_n + d_r): the kernels' default, off q's width
+                # scale 1 / sqrt(d_n + d_r): the kernels' default, off q's width
+                y = flash_attention(q, k, v, kept=True) if spec.remat_keep_flash else flash_attention(q, k, v)
             else:
                 y = manual_attention(q, k, v)  # SDPA takes one width for q, k and v: both other tiers are the plain softmax
             from jax.ad_checkpoint import checkpoint_name

@@ -32,7 +32,8 @@ def _plain_attention(q, k, v, causal: bool, sm_scale: float | None, window: int 
     return jnp.einsum("bhst,bthd->bshd", jax.nn.softmax(scores, axis=-1).astype(v.dtype), v)
 
 
-def flash_attention_or_fallback(q, k, v, causal: bool = True, sm_scale: float | None = None, window: int | None = None):
+def flash_attention_or_fallback(q, k, v, causal: bool = True, sm_scale: float | None = None, window: int | None = None,
+                                kept: bool = False):
     """q: [B,S,Hq,D], k: [B,S,Hkv,D], v: [B,S,Hkv,Dv] -> [B,S,Hq,Dv]. Dv is D everywhere but
     in latent attention (192 and 128); the kernels read both widths off the arrays.
 
@@ -44,6 +45,11 @@ def flash_attention_or_fallback(q, k, v, causal: bool = True, sm_scale: float | 
     positions. Ring attention carries no window, and the serving paths' masks have none: both
     refuse a model that asks for one (`gpt2_model.py`). A call without a window gets the plan,
     the kernels and the event it always got.
+
+    `kept` (PR 41): the call sits in a rematerialized block whose policy saves the kernel's o and lse
+    (`gpt2_model._remat_block_cls` under `spec.remat_keep_flash`); a differentiated call then hands its
+    backward those two under the names the policy reads (`flash_attention._flash_fwd_vjp`), lse as `[B, H, S]`.
+    Off the TPU there is no kernel and nothing to keep. A call without it binds what it always bound.
 
     Block sizes come from `env_flash_blocks`: MODALITIES_TPU_FLASH_BLOCK_Q / _BLOCK_K,
     else the device's tuning table (1024 x 1024 on a v5e; 1024 x 512 at 192/128), stepped
@@ -89,7 +95,7 @@ def flash_attention_or_fallback(q, k, v, causal: bool = True, sm_scale: float | 
                             **backward_plan(q.shape[1], *bwd_blocks, q.shape[-1], v.shape[-1], q.dtype)})
     kernel = functools.partial(
         pallas_flash_attention, causal=causal, sm_scale=sm_scale, block_q=block_q, block_k=block_k, bwd_blocks=bwd_blocks,
-        **windowed
+        kept=kept, **windowed
     )
     return per_shard(
         lambda _axes, q, k, v: kernel(q, k, v), (_Q_AXES, _KV_AXES, _KV_AXES), _Q_AXES

@@ -60,6 +60,10 @@ Design (FlashAttention-2 style, TPU-first):
   vector does not lower (the official jax kernel lane-broadcasts to 128 instead; the
   singleton was meant to cost 128x less HBM, but the chip's tiled layout gives every
   row a lane tile all the same: 256 MiB each at 2 x 32 x 8192, PERF.md section 7).
+  Those padded arrays are transients of one call's forward or backward. What a
+  rematerialized block KEEPS of a call from its forward to its backward (`kept=True`,
+  PR 41) is o and lse squeezed to [B, H, S] outside the kernel, dense: 2 MiB a layer at
+  2 x 32 x 8192; `_flash_bwd_vjp` spreads it to [B, H, S, 1] again before the kernels.
 """
 
 from __future__ import annotations
@@ -574,8 +578,8 @@ def _name(kernel: str, window) -> str:
     return f"flash_attention_{kernel}" if window is None else f"flash_attention_window_{kernel}"
 
 
-@functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4, 5, 6, 7, 8, 9))
-def _flash_attention_bhsd(q, k, v, sm_scale, causal, block_q, block_k, bwd_blocks, interpret, window=None):
+@functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4, 5, 6, 7, 8, 9, 10))
+def _flash_attention_bhsd(q, k, v, sm_scale, causal, block_q, block_k, bwd_blocks, interpret, window=None, kept=False):
     out, _ = _flash_fwd(q, k, v, sm_scale, causal, block_q, block_k, interpret, window)
     return out
 
@@ -617,9 +621,23 @@ def flash_fwd_out_lse(q, k, v, *, causal, sm_scale, block_q, block_k, interpret)
     return out, lse
 
 
-def _flash_fwd_vjp(q, k, v, sm_scale, causal, block_q, block_k, bwd_blocks, interpret, window=None):
+KEPT_OUT, KEPT_LSE = "flash_out", "flash_lse"  # what a rematerialized block may keep of a call (`kept`): o, and lse dense
+
+
+def _flash_fwd_vjp(q, k, v, sm_scale, causal, block_q, block_k, bwd_blocks, interpret, window=None, kept=False):
     # custom_vjp fwd receives arguments in the primal order (nondiff included in place)
     out, res = _flash_fwd(q, k, v, sm_scale, causal, block_q, block_k, interpret, window)
+    if kept:
+        # the call sits in a block whose remat policy saves these two names (`training/activation_checkpointing.py`):
+        # q, k and v are made again from the block's input, and with o and lse at hand nothing of the recomputed
+        # forward reads this kernel's results, so the compiler drops the second call. o goes on under its name as the
+        # primal too (what follows the kernel reads the saved array); lse is kept as numbers, [B, H, S], not as the
+        # kernel lays it out (a lane tile a row), and `_flash_bwd_vjp` spreads it again
+        from jax.ad_checkpoint import checkpoint_name
+
+        q, k, v, out, lse = res
+        out = checkpoint_name(out, KEPT_OUT)
+        res = (q, k, v, out, checkpoint_name(lse[..., 0], KEPT_LSE))
     return out, res
 
 
@@ -712,8 +730,10 @@ def flash_bwd(q, k, v, do, lse, delta, *, causal, sm_scale, block_q, block_k, in
     return dq, *_group_sum(dk_h, dv_h, k, v)
 
 
-def _flash_bwd_vjp(sm_scale, causal, block_q, block_k, bwd_blocks, interpret, window, res, do):
+def _flash_bwd_vjp(sm_scale, causal, block_q, block_k, bwd_blocks, interpret, window, kept, res, do):
     q, k, v, out, lse = res
+    if kept:
+        lse = lse[..., None]  # kept dense: the kernels read a [block_q, 1] column of it
     # [B, H, Sq, 1] — trailing singleton lane dim (see module docstring)
     delta = jnp.sum(do.astype(jnp.float32) * out.astype(jnp.float32), axis=-1, keepdims=True)
     kw = dict(causal=causal, sm_scale=sm_scale, interpret=interpret, window=window)
@@ -731,13 +751,15 @@ _flash_attention_bhsd.defvjp(_flash_fwd_vjp, _flash_bwd_vjp)
 def pallas_flash_attention(
     q, k, v, causal: bool = True, sm_scale: float | None = None,
     block_q: int = 128, block_k: int = 128, interpret: bool = False,
-    bwd_blocks: tuple[int, int] | None = None, window: int | None = None,
+    bwd_blocks: tuple[int, int] | None = None, window: int | None = None, kept: bool = False,
 ):
     """Public entry. q: [B, S, Hq, D], k: [B, S, Hkv, D], v: [B, S, Hkv, Dv] (model layout)
     -> [B, S, Hq, Dv]. Dv may differ from D (latent attention: 192 for q and k, 128 for v);
     the default scale is that of D. `bwd_blocks`: (block_q, block_k) of the fused backward
     where the tuning table gives it its own; the forward's otherwise. `window` W (causal only):
-    position i sees itself and the W - 1 before it (`tile_plan`); None sees all that came before."""
+    position i sees itself and the W - 1 before it (`tile_plan`); None sees all that came before.
+    `kept`: the call sits in a rematerialized block whose policy saves `KEPT_OUT` and `KEPT_LSE`, and a differentiated
+    call hands its backward o and lse under those names (`_flash_fwd_vjp`); False binds what it always bound."""
     if sm_scale is None:
         sm_scale = 1.0 / math.sqrt(q.shape[-1])
     seq_q, seq_k = q.shape[1], k.shape[1]
@@ -747,5 +769,5 @@ def pallas_flash_attention(
     qt = q.transpose(0, 2, 1, 3)
     kt = k.transpose(0, 2, 1, 3)
     vt = v.transpose(0, 2, 1, 3)
-    out = _flash_attention_bhsd(qt, kt, vt, sm_scale, causal, block_q, block_k, bwd_blocks, interpret, window)
+    out = _flash_attention_bhsd(qt, kt, vt, sm_scale, causal, block_q, block_k, bwd_blocks, interpret, window, kept)
     return out.transpose(0, 2, 1, 3)

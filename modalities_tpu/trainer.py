@@ -160,6 +160,16 @@ class Trainer:
         with telemetry.span("preflight_memscope"):
             try:
                 report = step_functions.memscope_report(device_batch)
+                kept, limit = getattr(step_functions, "kept_attention", None), min_bytes_limit()
+                if kept is not None and kept.plan and kept.plan["keep"] and report["predicted_peak_bytes"] > limit:
+                    # the count said the blocks' kept o and lse fit and the compiler says they do not: the step the model
+                    # had before is built in its place (one more trace and lowering), and only that one can fail the check
+                    logger.warning(
+                        "memscope: the step that keeps %d attention layers' o and lse (%d bytes) is predicted at %d bytes, over "
+                        "the device's %d: building the step without them", kept.plan["layers"], kept.plan["kept_bytes"],
+                        report["predicted_peak_bytes"], limit)
+                    kept.drop()
+                    report = step_functions.memscope_report(device_batch)
             except Exception:
                 logger.exception("memscope: static report failed; fits-check skipped")
                 return None

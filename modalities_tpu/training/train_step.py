@@ -77,6 +77,23 @@ def _under_scope(tx: optax.GradientTransformation, name: str) -> optax.GradientT
     return optax.GradientTransformation(tx.init, jax.named_scope(name)(tx.update))
 
 
+class KeptAttention:
+    """What a rematerialized block keeps of its attention in this build's step: the plan as the last trace of the step
+    made it (`training/activation_checkpointing.attention_keep_plan`; None before the first trace), and the way back
+    for a preflight that finds the keeping step over budget: `drop()` has the next trace plan without keeping and
+    forgets the step's traces, so that the next lowering or dispatch builds the step the model had before."""
+
+    def __init__(self):
+        self.plan: Optional[dict] = None
+        self.allowed = True
+        self.jitted: list = []  # the build's jitted steps: their caches hold the spec the model had when they were traced
+
+    def drop(self) -> None:
+        self.allowed = False
+        for step in self.jitted:
+            step.clear_cache()
+
+
 @dataclass
 class StepFunctions:
     """The compiled training surface handed to Trainer/Evaluator."""
@@ -99,6 +116,8 @@ class StepFunctions:
     # nothing if already on; accumulation halves the live microbatch only if raisable)
     zero_stage: int = 0
     gradient_acc_steps: int = 1
+    # what the step's rematerialized blocks keep of their attention, and the preflight's fall-back (trainer.py)
+    kept_attention: Optional[KeptAttention] = None
 
     def perfscope_report(self, batch_abstract, hw=None) -> dict:
         """Lower + compile the sharded step and bucket its optimized-HLO cost by
@@ -635,10 +654,48 @@ class TrainStepBuilder:
             def loss_and_grads(params, samples, targets, dropout_rng):
                 return jax.value_and_grad(compute_loss, has_aux=True)(params, samples, targets, dropout_rng)
 
+        kept_attention = KeptAttention()
+
+        def plan_kept_attention(state: AppState, microbatch_shape: tuple) -> None:
+            """Runs while the step is traced, before the model is: under `full` remat, whether a block keeps the flash
+            kernel's o and lse beside its input (`attention_keep_plan`: from the calls' shapes, this state's bytes a
+            device and the device's limit; no key of the config). The verdict goes onto the model's spec, which the
+            blocks read as they are traced, into three gauges and one log line."""
+            flash_calls = getattr(model, "remat_flash_calls", None)
+            if flash_calls is None or len(microbatch_shape) != 2:
+                return
+            from modalities_tpu.telemetry import get_active_telemetry
+            from modalities_tpu.telemetry.device_memory import min_bytes_limit
+            from modalities_tpu.training.activation_checkpointing import KEEP_VERDICTS, attention_keep_plan
+            from modalities_tpu.utils.recipe_validation import _tree_per_device_bytes as held
+
+            plan = attention_keep_plan(
+                flash_calls(*microbatch_shape),
+                state_bytes=sum(held(getattr(state, part), getattr(state_shardings, part, None)) for part in ("params", "opt_state")),
+                gradient_bytes=held(jax.tree.map(lambda p: jax.ShapeDtypeStruct(p.shape, reduce_dtype), state.params),
+                                    zero_grad_shardings if zero_active else getattr(state_shardings, "params", None)),
+                bytes_limit=min_bytes_limit(), allowed=kept_attention.allowed,
+            )
+            kept_attention.plan = plan
+            model.with_spec_updates(remat_keep_flash=plan["keep"])
+            telemetry = get_active_telemetry()
+            telemetry.metrics.gauge(
+                "train_remat_kept_attention_layers", "Attention layers whose rematerialized block keeps the flash kernel's o and lse"
+            ).set(plan["layers"] * plan["keep"])
+            telemetry.metrics.gauge(
+                "train_remat_kept_attention_bytes", "Bytes a device holds of kept o and lse over those layers"
+            ).set(plan["kept_bytes"] * plan["keep"])
+            verdict = telemetry.metrics.gauge("train_remat_keep_verdict", "1 under the attention keep plan's verdict, 0 under the others")
+            for name in KEEP_VERDICTS:
+                verdict.set(float(name == plan["verdict"]), verdict=name)
+            telemetry.emit_event_once("attention_keep_plan", plan)
+            logger.info("attention keep plan: %s", plan)
+
         def make_train_step(with_grads: bool):
             def train_step(state: AppState, batch: dict) -> tuple[AppState, dict]:
                 """batch: {"samples": {k: [acc, mb, ...]}, "targets": {k: [acc, mb, ...]}}"""
                 samples, targets = batch["samples"], batch["targets"]
+                plan_kept_attention(state, samples[sample_key].shape[1:])
                 # fresh dropout mask per step AND per microbatch, rooted at the build seed
                 step_rng = jax.random.fold_in(jax.random.PRNGKey(seed), state.step)
 
@@ -848,6 +905,7 @@ class TrainStepBuilder:
                 out_shardings=(state_shardings, metrics_shardings),
             )
             eval_step_j = jax.jit(eval_step, in_shardings=(state_shardings, None))
+            kept_attention.jitted.append(train_step_j)
 
             # execute (and trace) under the mesh context so in-model collectives
             # (ring attention shard_map) resolve the ambient mesh, and under the
@@ -879,6 +937,7 @@ class TrainStepBuilder:
                     in_shardings=(state_shardings, None),
                     out_shardings=(state_shardings, debug_metrics_shardings),
                 )
+                kept_attention.jitted.append(train_step_debug_j)
 
                 def train_step_debug_c(state, batch):
                     with mesh, activation_rules(rules, mesh):
@@ -891,6 +950,7 @@ class TrainStepBuilder:
                 jax.jit(make_train_step(True), donate_argnums=(0,)) if expose_grads else None
             )
             lower_train_step = lambda batch_abstract: train_step_c.lower(state, batch_abstract)  # noqa: E731
+            kept_attention.jitted.extend(step for step in (train_step_c, train_step_debug_c) if step is not None)
 
         put_batch = self._make_put_batch(data_sharding)
 
@@ -905,6 +965,7 @@ class TrainStepBuilder:
             lower_train_step=lower_train_step,
             zero_stage=self.zero_stage,
             gradient_acc_steps=self.gradient_acc_steps,
+            kept_attention=kept_attention,
         )
 
     # ------------------------------------------------------------------ data

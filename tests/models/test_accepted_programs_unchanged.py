@@ -4,7 +4,10 @@ forward-and-backward program of each, at toy size, are what the parent commit ga
 such a model carries the activations alone over no input; a block takes one argument; `apply_rope` takes its old branch.
 
 The digests were taken from `git archive 9565b4e` (the commit PR 40 started from) with this file's own `described`, and
-are the same on PR 40's tree. A later PR that changes one of these programs on purpose replaces its digest here, and says so."""
+are the same on PR 40's tree. A later PR that changes one of these programs on purpose replaces its digest here, and says so.
+
+PR 41 (a `full`-remat block keeps the flash kernel's o and lse) moved none of the five: what is kept is decided where the train
+step is traced (`spec.remat_keep_flash`, False on a model nobody planned for), and off the TPU no block holds a kernel call."""
 
 import hashlib
 import json
