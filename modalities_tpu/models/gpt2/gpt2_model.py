@@ -2057,7 +2057,7 @@ class GPT2LLM(NNModel):
         (what every rematerialized block keeps already), and for each kind of attention layer how many layers, the bytes
         of the kernel's `o` `[rows, H, seq, Dv]` and of `lse` as numbers, `[rows, H, seq]` float32, and the bytes its
         backward holds round the kernel (`backward_bytes`: q, k, v, o and its cotangent, dq, dk and dv a q head, lse and
-        delta as the kernel lays them out, a lane tile a row). It is what
+        delta as the kernel lays them out: `[rows, H, 1, seq]` rows of float32, dense since PR 42). It is what
         `training/activation_checkpointing.attention_keep_plan` counts. None where no block is wrapped so: another variant
         or none, a looped stack (`_walks_in_place` recomputes by hand and takes no policy), pipeline stages
         (`jax.checkpoint` of their own), ring attention (no call of this kernel), a tier that is not the kernel, and off
@@ -2076,8 +2076,9 @@ class GPT2LLM(NNModel):
                                     else (spec.n_head_kv, spec.head_dim, spec.head_dim))
         b, s, h = shard_shape((rows, seq, spec.n_head_q), ("batch", None, "heads"))  # as `ops/attention.py` splits a call
         h_kv = shard_shape((kv_heads,), ("kv_heads",))[0]
-        call = {"o_bytes": b * h * s * width_v * itemsize, "lse_bytes": b * h * s * 4,
-                "backward_bytes": b * s * itemsize * (3 * h * width + 3 * h * width_v + h_kv * (width + width_v)) + 2 * b * h * s * 128 * 4}
+        lse_bytes = b * h * s * 4  # delta's too
+        call = {"o_bytes": b * h * s * width_v * itemsize, "lse_bytes": lse_bytes,
+                "backward_bytes": b * s * itemsize * (3 * h * width + 3 * h * width_v + h_kv * (width + width_v)) + 2 * lse_bytes}
         return {"blocks": spec.n_layer, "calls": [{"kind": kind, "layers": spec.kinds.count(kind), **call} for kind in ("attn", "swa", "cca") if kind in spec.kinds],
                 "block_input_bytes": math.prod(shard_shape((rows, seq, spec.n_embd), ("batch", "seq", "embed"))) * itemsize}
 

@@ -48,7 +48,8 @@ def flash_attention_or_fallback(q, k, v, causal: bool = True, sm_scale: float | 
 
     `kept` (PR 41): the call sits in a rematerialized block whose policy saves the kernel's o and lse
     (`gpt2_model._remat_block_cls` under `spec.remat_keep_flash`); a differentiated call then hands its
-    backward those two under the names the policy reads (`flash_attention._flash_fwd_vjp`), lse as `[B, H, S]`.
+    backward those two under the names the policy reads (`flash_attention._flash_fwd_vjp`), lse as the kernel wrote it,
+    `[B, H, 1, S]` rows of numbers (PR 42).
     Off the TPU there is no kernel and nothing to keep. A call without it binds what it always bound.
 
     Block sizes come from `env_flash_blocks`: MODALITIES_TPU_FLASH_BLOCK_Q / _BLOCK_K,

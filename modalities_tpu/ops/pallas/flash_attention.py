@@ -10,7 +10,7 @@ Design (FlashAttention-2 style, TPU-first):
   latent attention has 192 and 128, and pads neither to the other.
 - backward: one kernel, `flash_attention_bwd` (PR 31), walks the plan's kv-major table once
   and recomputes a tile's probabilities p and ds = p (dp - delta) once from the saved
-  logsumexp (no S x S materialization anywhere): 5 products a tile. dk and dv accumulate
+  logsumexp (no S x S materialization anywhere): 5 products a tile, the scores transposed. dk and dv accumulate
   over a kv tile's q tiles in [BK, D] scratch; dq's terms for a q tile come from every kv
   tile at or below it, so one (batch, q head)'s whole dq row stays in VMEM as float32
   ([S/BQ, BQ, D], zeroed at the head's first pair, cast and written at its last) under a
@@ -54,16 +54,17 @@ Design (FlashAttention-2 style, TPU-first):
   mode keeps CPU tests exact. What the chip says of each choice, and of the fused
   backward beside the two kernels (`scripts/moe_mla_parts_bench.py --parts flash`), is in
   PERF.md, sections 5 and 6.
-- TPU layout: per-row statistics (lse, delta) carry a trailing singleton lane dim
-  ([B, H, S, 1] arrays, [block_q, 1] in-kernel tiles) because Mosaic requires the
-  last two block dims to tile (8, 128) or equal the array dims — a bare [S] row
-  vector does not lower (the official jax kernel lane-broadcasts to 128 instead; the
-  singleton was meant to cost 128x less HBM, but the chip's tiled layout gives every
-  row a lane tile all the same: 256 MiB each at 2 x 32 x 8192, PERF.md section 7).
-  Those padded arrays are transients of one call's forward or backward. What a
-  rematerialized block KEEPS of a call from its forward to its backward (`kept=True`,
-  PR 41) is o and lse squeezed to [B, H, S] outside the kernel, dense: 2 MiB a layer at
-  2 x 32 x 8192; `_flash_bwd_vjp` spreads it to [B, H, S, 1] again before the kernels.
+- TPU layout: the per-row statistics (lse out of the forward, lse and delta into the backward) cross HBM as rows of
+  numbers, [B, H, 1, S] float32 in (1, 1, 1, block_q) blocks (PR 42): the chip lays such an array out dense (`T(1,128)`:
+  2 MiB at 2 x 32 x 8192), and the last two block dims equal the array's or tile (.., 128), which Mosaic asks for: a
+  row of several tiles has blocks of a multiple of 128 and any other row is one tile (`_pick_block`; `init_params`' dummy
+  forward of 8). Before, they were [B, H, S, 1] columns, a lane tile of 512 bytes a number (256 MiB each at that
+  shape; PERF.md section 6, PR 42). The forward keeps its running max and sum as lane-replicated [block_q, 128] columns
+  and turns them into the row once a q tile (`_finish`: one transpose on the XLU). The backward kernels never need the
+  column: they take the scores transposed (`k q^T`, [block_k, block_q]: `_transposed_terms`), where a q row's statistic
+  is a sublane broadcast of the stored row, dv and dk are plain products and only dq contracts over a transposed operand.
+  What a rematerialized block KEEPS of a call from its forward to its backward (`kept=True`, PR 41) is o and that lse, as
+  the kernel wrote it and as the backward kernel reads it.
 """
 
 from __future__ import annotations
@@ -221,12 +222,13 @@ def _pair(plan_ref, num_pairs, block_q, block_k):
     return plan_ref[2 * num_pairs + t], plan_ref[t] * block_q - plan_ref[num_pairs + t] * block_k
 
 
-def _keep(shape, causal, edge):
+def _keep(shape, causal, edge, transposed=False):
     """The mask of a rectangle whose first q position is `causal` past its first k position
     (the diagonal's side: row + causal >= col) and `edge` past it less the window (the window's
-    side: row + edge < col); a side that is None hides nothing, a static 0 is an aligned square."""
-    row = jax.lax.broadcasted_iota(jnp.int32, shape, 0)
-    col = jax.lax.broadcasted_iota(jnp.int32, shape, 1)
+    side: row + edge < col); a side that is None hides nothing, a static 0 is an aligned square.
+    `transposed`: the scores are [cols, rows], k positions down and q positions across."""
+    row = jax.lax.broadcasted_iota(jnp.int32, shape, int(transposed))
+    col = jax.lax.broadcasted_iota(jnp.int32, shape, 1 - int(transposed))
     static_zero = lambda side: isinstance(side, int) and side == 0  # noqa: E731
     keep = None
     if causal is not None:
@@ -241,9 +243,9 @@ def _scores(q, k):
     return jax.lax.dot_general(q, k, (((1,), (1,)), ((), ())), preferred_element_type=jnp.float32)
 
 
-def _masked_scores(q, k, mask):
-    s = _scores(q, k)
-    return s if mask is None else jnp.where(_keep(s.shape, *mask), s, NEG_INF)
+def _masked_scores(q, k, mask, transposed=False):
+    s = _scores(k, q) if transposed else _scores(q, k)  # [C, R] or [R, C]
+    return s if mask is None else jnp.where(_keep(s.shape, *mask, transposed), s, NEG_INF)
 
 
 # --------------------------------------------------------------------------- fwd
@@ -303,7 +305,34 @@ def _fwd_kernel(plan_ref, q_ref, k_ref, v_ref, o_ref, lse_ref, acc_ref, m_ref, l
     def _finish():
         l_safe = jnp.maximum(l_ref[:].sum(axis=-1, keepdims=True), 1e-30)
         o_ref[0, 0] = (acc_ref[:] / l_safe).astype(o_ref.dtype)
-        lse_ref[0, 0] = m_ref[:, :1] + jnp.log(l_safe)
+        # lse leaves as the row it is read as, [1, block_q]: the lane-replicated column turned once a q tile (the XLU's
+        # work, 128 vregs at 1024 rows), one row of the result written
+        lse_ref[0, 0] = (m_ref[:] + jnp.log(l_safe)).T[:1]
+
+
+# ------------------------------------------- bwd: a rectangle's p and ds, transposed
+
+
+def _transposed_terms(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref, rows, cols, mask, sm_scale):
+    """A rectangle's p and ds from the saved logsumexp, both TRANSPOSED: scores as `k q^T`, [C, R], k positions down the
+    sublanes and q positions across the lanes, so that lse and delta are read as the [1, R] rows they are stored as (a
+    sublane broadcast, no column a row), and `p^T do` (dv), `ds^T q` (dk) are plain products; only dq = ds k contracts
+    over ds^T's first dimension (`_contract_rows`: the one transpose a tile; `p^T do` and `ds^T q` from [R, C] take two).
+    Returns (p^T, ds^T, q, k, do), the operands float32 as the products take them."""
+    k = k_ref[0, 0, cols, :].astype(jnp.float32)
+    v = v_ref[0, 0, cols, :].astype(jnp.float32)
+    q = q_ref[0, 0, rows, :].astype(jnp.float32)
+    do = do_ref[0, 0, rows, :].astype(jnp.float32)
+    lse = lse_ref[0, 0, :, rows]  # [1, R]
+    delta = delta_ref[0, 0, :, rows]  # [1, R]
+    p_t = jnp.exp(_masked_scores(q * sm_scale, k, mask, transposed=True) - lse)
+    ds_t = p_t * (_scores(v, do) - delta) * sm_scale
+    return p_t, ds_t, q, k, do
+
+
+def _contract_rows(a_t, b):
+    """a b for a given transposed: [C, R] x [C, D] -> [R, D]."""
+    return jax.lax.dot_general(a_t, b, (((0,), (0,)), ((), ())), preferred_element_type=jnp.float32)
 
 
 # ---------------------------------------------------------------------- bwd: dq
@@ -318,17 +347,8 @@ def _bwd_dq_kernel(plan_ref, q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref, dq
         dq_acc_ref[:] = jnp.zeros_like(dq_acc_ref)
 
     def rectangle(rows, cols, mask):
-        q = q_ref[0, 0, rows, :].astype(jnp.float32)
-        do = do_ref[0, 0, rows, :].astype(jnp.float32)
-        lse = lse_ref[0, 0, rows, :]  # [R, 1]
-        delta = delta_ref[0, 0, rows, :]  # [R, 1]
-        k = k_ref[0, 0, cols, :].astype(jnp.float32)
-        v = v_ref[0, 0, cols, :].astype(jnp.float32)
-        p = jnp.exp(_masked_scores(q * sm_scale, k, mask) - lse)
-        ds = p * (_scores(do, v) - delta) * sm_scale
-        dq_acc_ref[rows, :] += jax.lax.dot_general(
-            ds, k, (((1,), (0,)), ((), ())), preferred_element_type=jnp.float32
-        )
+        _, ds_t, _, k, _ = _transposed_terms(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref, rows, cols, mask, sm_scale)
+        dq_acc_ref[rows, :] += _contract_rows(ds_t, k)
 
     _by_class(classes, flags, offset, block_q, block_k, rectangle, window)
 
@@ -341,23 +361,11 @@ def _bwd_dq_kernel(plan_ref, q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref, dq
 
 
 def _add_dkv_terms(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref, dk_acc_ref, dv_acc_ref, rows, cols, mask, sm_scale):
-    """A rectangle's p and ds from the saved logsumexp, its terms added into the dk and dv
-    accumulators; (ds, k) go back to the kernel that also forms ds k."""
-    k = k_ref[0, 0, cols, :].astype(jnp.float32)
-    v = v_ref[0, 0, cols, :].astype(jnp.float32)
-    q = q_ref[0, 0, rows, :].astype(jnp.float32)
-    do = do_ref[0, 0, rows, :].astype(jnp.float32)
-    lse = lse_ref[0, 0, rows, :]  # [R, 1]
-    delta = delta_ref[0, 0, rows, :]  # [R, 1]
-    p = jnp.exp(_masked_scores(q * sm_scale, k, mask) - lse)
-    dv_acc_ref[cols, :] += jax.lax.dot_general(
-        p, do, (((0,), (0,)), ((), ())), preferred_element_type=jnp.float32
-    )
-    ds = p * (_scores(do, v) - delta) * sm_scale
-    dk_acc_ref[cols, :] += jax.lax.dot_general(
-        ds, q, (((0,), (0,)), ((), ())), preferred_element_type=jnp.float32
-    )
-    return ds, k
+    """A rectangle's terms added into the dk and dv accumulators; (ds^T, k) go back to the kernel that also forms ds k."""
+    p_t, ds_t, q, k, do = _transposed_terms(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref, rows, cols, mask, sm_scale)
+    dv_acc_ref[cols, :] += jax.lax.dot_general(p_t, do, (((1,), (0,)), ((), ())), preferred_element_type=jnp.float32)
+    dk_acc_ref[cols, :] += jax.lax.dot_general(ds_t, q, (((1,), (0,)), ((), ())), preferred_element_type=jnp.float32)
+    return ds_t, k
 
 
 def _bwd_dkv_kernel(plan_ref, q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref, dk_ref, dv_ref,
@@ -402,14 +410,14 @@ def dq_resident_bytes(seq_q: int, head_dim: int, itemsize: int) -> int:
 def fused_backward_vmem_bytes(seq_q: int, block_q: int, block_k: int, head_dim: int, head_dim_v: int,
                               itemsize: int) -> int:
     """What `flash_attention_bwd` holds in VMEM for one grid step, in bytes, lanes padded: the
-    resident dq; the tiles of q, do, k, v, dk, dv and the two [block_q, 1] columns (a lane tile
-    a row), each twice (the pipeline's two buffers); the dk and dv accumulators; and a
-    rectangle's float32 temporaries, taken as four score tiles (s and p, dp and ds, the
-    transposes of p and ds) and one copy of each operand tile. Counted from the shapes, not
+    resident dq; the tiles of q, do, k, v, dk, dv and the two [1, block_q] rows of statistics (a
+    sublane tile of 8 each), each twice (the pipeline's two buffers); the dk and dv accumulators;
+    and a rectangle's float32 temporaries, taken as four score tiles (s and p, dp and ds, the
+    transpose of ds, one to spare) and one copy of each operand tile. Counted from the shapes, not
     asked of the compiler, and on the high side of what Mosaic allots (PERF.md, section 6, PR 31)."""
     wide, narrow = _lane_padded(head_dim), _lane_padded(head_dim_v)
     q_side, kv_side = block_q * (wide + narrow), block_k * (wide + narrow)  # elements of q and do; of k and v, of dk and dv
-    pipelined = 2 * (itemsize * (q_side + 2 * kv_side) + 2 * 4 * block_q * _LANES)
+    pipelined = 2 * (itemsize * (q_side + 2 * kv_side) + 2 * 4 * 8 * block_q)
     accumulators = 4 * kv_side
     temporaries = 4 * (4 * block_q * block_k + q_side + kv_side)
     return dq_resident_bytes(seq_q, head_dim, itemsize) + pipelined + accumulators + temporaries
@@ -450,10 +458,8 @@ def _bwd_kernel(plan_ref, q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref, dq_re
         dv_acc_ref[:] = jnp.zeros_like(dv_acc_ref)
 
     def rectangle(rows, cols, mask):
-        ds, k = _add_dkv_terms(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref, dk_acc_ref, dv_acc_ref, rows, cols, mask, sm_scale)
-        dq_acc_ref[q_tile, rows, :] += jax.lax.dot_general(
-            ds, k, (((1,), (0,)), ((), ())), preferred_element_type=jnp.float32
-        )
+        ds_t, k = _add_dkv_terms(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref, dk_acc_ref, dv_acc_ref, rows, cols, mask, sm_scale)
+        dq_acc_ref[q_tile, rows, :] += _contract_rows(ds_t, k)
 
     _by_class(classes, flags, offset, block_q, block_k, rectangle, window)
 
@@ -474,7 +480,7 @@ def _tiled_call(kernel, table, name, *, sm_scale, batch, num_heads, group, block
     looked up in `table` (a plan's int32 [3, n]: q tile, kv tile, flags), which goes in
     flat as the one scalar-prefetched operand. `inputs` / `outputs` name each operand's
     tiling: "q" (a [block_q, D] tile of q head h), "kv" (a [block_k, D] tile of kv head
-    h // group), "k_out" (a [block_k, D] tile per q head), "row" (a [block_q, 1] column),
+    h // group), "k_out" (a [block_k, D] tile per q head), "row" (a q tile's [1, block_q] row of statistics),
     "q_rows" (all the plan's q tiles of q head h: a block that stays while the pairs go by).
     q and k are `head_dim` wide; what is as wide as v (`head_dim_v`: v, the output and its
     cotangent, dv) is tiled alike under "qv", "v" and "v_out". Equal widths give the same
@@ -490,7 +496,7 @@ def _tiled_call(kernel, table, name, *, sm_scale, batch, num_heads, group, block
         )
 
     specs = dict(zip(("q", "kv", "k_out"), tilings(head_dim)), **dict(zip(("qv", "v", "v_out"), tilings(head_dim_v))))
-    specs["row"] = pl.BlockSpec((1, 1, block_q, 1), lambda b, h, t, plan: (b, h, plan[t], 0))
+    specs["row"] = pl.BlockSpec((1, 1, 1, block_q), lambda b, h, t, plan: (b, h, 0, plan[t]))
     specs["q_rows"] = pl.BlockSpec((1, 1, (int(table[0].max()) + 1) * block_q, head_dim), lambda b, h, t, plan: (b, h, 0, 0))
     classes = np.unique(table[2] & _KIND).tolist()
     windowed = {} if window is None else {"window": window}  # a call without a window binds what it always bound
@@ -519,7 +525,7 @@ def _tiled_call(kernel, table, name, *, sm_scale, batch, num_heads, group, block
 def _pick_block(seq: int, preferred: int) -> int:
     if seq % preferred == 0:
         return preferred
-    for cand in (512, 256, 128, 64, 32, 16, 8):
+    for cand in (512, 256, 128):  # never under a lane tile: a row's [1, block_q] block of statistics tiles (.., 128) or spans the row
         if seq % cand == 0 and cand <= seq:
             return cand
     return seq
@@ -598,7 +604,7 @@ def _flash_fwd(q, k, v, sm_scale, causal, block_q, block_k, interpret, window=No
         inputs=("q", "kv", "v"), outputs=("qv", "row"),
         out_shape=[
             jax.ShapeDtypeStruct((batch, num_heads, seq_q, head_dim_v), q.dtype),
-            jax.ShapeDtypeStruct((batch, num_heads, seq_q, 1), jnp.float32),
+            jax.ShapeDtypeStruct((batch, num_heads, 1, seq_q), jnp.float32),
         ],
         scratch_shapes=[
             pltpu.VMEM((block_q, head_dim_v), jnp.float32),
@@ -612,7 +618,7 @@ def _flash_fwd(q, k, v, sm_scale, causal, block_q, block_k, interpret, window=No
 
 def flash_fwd_out_lse(q, k, v, *, causal, sm_scale, block_q, block_k, interpret):
     """Raw kernel forward WITH the log-sum-exp exposed: [B, H, S, D] ->
-    (out [B, H, S, D], lse [B, H, Sq, 1] fp32). (out, lse) is the information-
+    (out [B, H, S, D], lse [B, H, 1, Sq] fp32, rows). (out, lse) is the information-
     equivalent of unnormalized (o, m, l) block stats — o = out * exp(lse - m) * ...
     collapses to this pair — and it is exactly what an online-softmax merge needs:
     ring attention (parallel/ring_attention.py) merges per-hop (out, lse) pairs
@@ -621,7 +627,7 @@ def flash_fwd_out_lse(q, k, v, *, causal, sm_scale, block_q, block_k, interpret)
     return out, lse
 
 
-KEPT_OUT, KEPT_LSE = "flash_out", "flash_lse"  # what a rematerialized block may keep of a call (`kept`): o, and lse dense
+KEPT_OUT, KEPT_LSE = "flash_out", "flash_lse"  # what a rematerialized block may keep of a call (`kept`): o and lse, as the kernel wrote them
 
 
 def _flash_fwd_vjp(q, k, v, sm_scale, causal, block_q, block_k, bwd_blocks, interpret, window=None, kept=False):
@@ -631,20 +637,20 @@ def _flash_fwd_vjp(q, k, v, sm_scale, causal, block_q, block_k, bwd_blocks, inte
         # the call sits in a block whose remat policy saves these two names (`training/activation_checkpointing.py`):
         # q, k and v are made again from the block's input, and with o and lse at hand nothing of the recomputed
         # forward reads this kernel's results, so the compiler drops the second call. o goes on under its name as the
-        # primal too (what follows the kernel reads the saved array); lse is kept as numbers, [B, H, S], not as the
-        # kernel lays it out (a lane tile a row), and `_flash_bwd_vjp` spreads it again
+        # primal too (what follows the kernel reads the saved array); lse is kept as the kernel wrote it, [B, H, 1, S]
+        # rows of numbers, and is the backward kernel's operand as it stands
         from jax.ad_checkpoint import checkpoint_name
 
         q, k, v, out, lse = res
         out = checkpoint_name(out, KEPT_OUT)
-        res = (q, k, v, out, checkpoint_name(lse[..., 0], KEPT_LSE))
+        res = (q, k, v, out, checkpoint_name(lse, KEPT_LSE))
     return out, res
 
 
 def flash_bwd_dq(q, k, v, do, lse, delta, *, causal, sm_scale, block_q, block_k, interpret, window=None):
     """dq for one (q, k, v) pairing given GLOBAL (lse, delta) — reusable by the ring
     backward, where lse/delta come from the merged multi-hop softmax. All [B,H,S,D];
-    lse/delta [B,H,Sq,1] fp32."""
+    lse/delta [B,H,1,Sq] fp32."""
     batch, num_heads, seq_q, head_dim = q.shape
     seq_k = k.shape[2]
     group = num_heads // k.shape[1]
@@ -732,10 +738,8 @@ def flash_bwd(q, k, v, do, lse, delta, *, causal, sm_scale, block_q, block_k, in
 
 def _flash_bwd_vjp(sm_scale, causal, block_q, block_k, bwd_blocks, interpret, window, kept, res, do):
     q, k, v, out, lse = res
-    if kept:
-        lse = lse[..., None]  # kept dense: the kernels read a [block_q, 1] column of it
-    # [B, H, Sq, 1] — trailing singleton lane dim (see module docstring)
-    delta = jnp.sum(do.astype(jnp.float32) * out.astype(jnp.float32), axis=-1, keepdims=True)
+    # rows of numbers like lse, [B, H, 1, Sq]: a reduction's dense result, not a lane tile a row
+    delta = jnp.sum(do.astype(jnp.float32) * out.astype(jnp.float32), axis=-1)[:, :, None, :]
     kw = dict(causal=causal, sm_scale=sm_scale, interpret=interpret, window=window)
     if backward_plan(q.shape[2], *bwd_blocks, q.shape[3], v.shape[3], q.dtype)["backward"] == "fused":
         return flash_bwd(q, k, v, do, lse, delta, block_q=bwd_blocks[0], block_k=bwd_blocks[1], **kw)

@@ -173,7 +173,7 @@ def _hop_blocks(seq_q: int, seq_k: int):
 
 def _hop_fwd(q, k, v, idx, sm_scale, interpret):
     """One ring hop, all [B, H, S, D]: lax.switch over (full | diagonal | skip).
-    Returns (out fp32 [B,Hq,S,D], lse fp32 [B,Hq,S,1])."""
+    Returns (out fp32 [B,Hq,S,D], lse fp32 [B,Hq,S]: the kernel's [B,Hq,1,S] rows as the numbers the merge works on)."""
     from modalities_tpu.ops.pallas.flash_attention import flash_fwd_out_lse
 
     bq, bk = _hop_blocks(q.shape[2], k.shape[2])
@@ -184,7 +184,7 @@ def _hop_fwd(q, k, v, idx, sm_scale, interpret):
                 q, k_, v_, causal=causal, sm_scale=sm_scale,
                 block_q=bq, block_k=bk, interpret=interpret,
             )
-            return o.astype(jnp.float32), lse
+            return o.astype(jnp.float32), lse[:, :, 0]
 
         return hop
 
@@ -192,20 +192,20 @@ def _hop_fwd(q, k, v, idx, sm_scale, interpret):
         b, hq, sq, d = q.shape
         return (
             jnp.zeros((b, hq, sq, d), jnp.float32),
-            jnp.full((b, hq, sq, 1), NEG_INF, jnp.float32),
+            jnp.full((b, hq, sq), NEG_INF, jnp.float32),
         )
 
     return jax.lax.switch(idx, (make_hop(causal=False), make_hop(causal=True), skip), k, v)
 
 
 def _merge_out_lse(out_a, lse_a, out_b, lse_b):
-    """Flash-decoding merge of two normalized partials. NEG_INF sentinels (not real
-    -inf) keep the arithmetic NaN-free: exp(NEG_INF - finite) underflows to 0."""
+    """Flash-decoding merge of two normalized partials (out [B,H,S,D], lse [B,H,S]). NEG_INF
+    sentinels (not real -inf) keep the arithmetic NaN-free: exp(NEG_INF - finite) underflows to 0."""
     lse_m = jnp.maximum(lse_a, lse_b)
     lse_new = lse_m + jnp.log(jnp.exp(lse_a - lse_m) + jnp.exp(lse_b - lse_m))
     wa = jnp.exp(lse_a - lse_new)
     wb = jnp.exp(lse_b - lse_new)
-    return out_a * wa + out_b * wb, lse_new
+    return out_a * wa[..., None] + out_b * wb[..., None], lse_new
 
 
 def _branch_index(causal: bool, my_index, j_index):
@@ -226,7 +226,7 @@ def _ring_flash_fwd_res(q, k, v, axis_name, causal, sm_scale, interpret):
 
     b, hq, s, d = qt.shape
     out_run = jnp.zeros((b, hq, s, d), jnp.float32)
-    lse_run = jnp.full((b, hq, s, 1), NEG_INF, jnp.float32)
+    lse_run = jnp.full((b, hq, s), NEG_INF, jnp.float32)
 
     k_cur, v_cur = kt, vt
     for r in range(cp):
@@ -240,7 +240,7 @@ def _ring_flash_fwd_res(q, k, v, axis_name, causal, sm_scale, interpret):
             v_cur = jax.lax.ppermute(v_cur, axis_name, perm)
 
     out_t = out_run.astype(q.dtype)  # [B, Hq, S, D]
-    return out_t.transpose(0, 2, 1, 3), (qt, kt, vt, out_t, lse_run)
+    return out_t.transpose(0, 2, 1, 3), (qt, kt, vt, out_t, lse_run[:, :, None])  # lse as the backward kernels read it: [B,Hq,1,S] rows
 
 
 @functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4, 5, 6))
@@ -261,7 +261,7 @@ def _ring_flash_vjp_bwd(axis_name, causal, sm_scale, interpret, res, do):
     perm = [(i, (i + 1) % cp) for i in range(cp)]
 
     do_t = do.transpose(0, 2, 1, 3).astype(qt.dtype)  # [B, Hq, S, D]
-    delta = jnp.sum(do_t.astype(jnp.float32) * out_t.astype(jnp.float32), axis=-1, keepdims=True)
+    delta = jnp.sum(do_t.astype(jnp.float32) * out_t.astype(jnp.float32), axis=-1)[:, :, None]  # [B,Hq,1,S], as lse
     bq, bk = _hop_blocks(qt.shape[2], kt.shape[2])
 
     def make_bwd_hop(causal):  # one body, two causal flavors — keep the branches twins

@@ -3,8 +3,9 @@
 
 Reference variants -> TPU equivalents:
 - FULL: remat every transformer block (``nn.remat`` around the scanned block). A block keeps its
-  input, and since PR 41, where they fit, the flash kernel's ``o`` and ``lse`` (``lse`` as numbers,
-  ``[B, H, S]``), so that the recomputed forward does not run ``flash_attention_fwd`` again: the
+  input, and since PR 41, where they fit, the flash kernel's ``o`` and ``lse`` (``lse`` as the kernel
+  writes it since PR 42, ``[B, H, 1, S]`` rows of numbers), so that the recomputed forward does not run
+  ``flash_attention_fwd`` again: the
   backward kernels read q, k, v (projections, made again) and those two, which only the forward
   kernel makes. Who decides is the program, not a key: ``attention_keep_plan`` below, called while
   the train step is traced (``training/train_step.py``), and ``Trainer._preflight_memscope`` under
@@ -66,12 +67,14 @@ def save_list_policy(save_list: tuple[str, ...]):
 
 KEEP_VERDICTS = ("fits", "over_count", "fell_back_in_preflight", "no_remat")
 # what a block's backward holds beside what is kept, as the count has it: twice the widest flash call's own operands and
-# results (`backward_bytes`: the kernel's, and the model's layout of them round it) and 24 block inputs for everything
-# else of a block (norms, projections, the feed-forward or expert layer). Fitted on a v5e to `memory_analysis()` of the
-# keeping step of the two cells that bracket the limit (PERF.md section 6, PR 41: 13.63 GiB counted 13.72, 16.23 counted
-# 16.24) and checked on two more, where it counts high (14.26 counted 15.08, 14.84 counted 15.33): the compiler is the
-# judge (`Trainer._preflight_memscope`), the count only spares a step that cannot keep a second trace and lowering
-KEEP_FLASH_WORKING_SETS, KEEP_BLOCK_WORKING_INPUTS = 2, 24
+# results (`backward_bytes`: the kernel's, and the model's layout of them round it; lse and delta in it as the dense rows
+# they are since PR 42, no lane tile a number) and 26 block inputs for everything else of a block (norms, projections, the
+# feed-forward or expert layer). Fitted on a v5e to `memory_analysis()` of the keeping step of the two cells the count is
+# nearest to (`scripts/attention_keep_sizes.py`, PERF.md section 6, PR 42: 15.31 GiB counted 15.37, 13.50 counted 13.60)
+# and checked on two more, where it counts high (13.92 counted 14.23, 14.84 counted 15.22); under PR 41's layout the pair
+# was 2 and 24 (13.63 counted 13.72, 16.23 counted 16.24). The compiler is the judge (`Trainer._preflight_memscope`), the
+# count only spares a step that cannot keep a second trace and lowering
+KEEP_FLASH_WORKING_SETS, KEEP_BLOCK_WORKING_INPUTS = 2, 26
 
 
 def attention_keep_plan(flash_calls: Optional[dict], *, state_bytes: int, gradient_bytes: int, bytes_limit: Optional[int],

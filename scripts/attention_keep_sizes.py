@@ -4,7 +4,7 @@ what `training/activation_checkpointing.attention_keep_plan` counted and said at
 
     JAX_PLATFORMS=cpu python scripts/attention_keep_sizes.py --configs zaya1-8b-ep2,kanana2-30b-a3b-d9
 
-The readings `KEEP_FLASH_WORKING_SETS` and `KEEP_BLOCK_WORKING_INPUTS` were fitted to (PERF.md section 6, PR 41); run it
+The readings `KEEP_FLASH_WORKING_SETS` and `KEEP_BLOCK_WORKING_INPUTS` were fitted to (PERF.md section 6, PR 41 and PR 42); run it
 again when a cell, a kernel's residuals or the compiler changes. About 40 s a compile. A scratch script, not a test: it
 describes a topology as it runs and hands the program the described device by replacing `jax.devices` for this process
 (`benchmark/tools/size_x4.py` does the same for four chips). Nothing runs; a compile that passes is not a chip run.

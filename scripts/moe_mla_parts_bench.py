@@ -90,7 +90,7 @@ def flash_part(args, interpret: bool) -> None:
         qt, kt, vt, wt = (x.transpose(0, 2, 1, 3) for x in (q, k, v, w))
         kw = dict(causal=True, sm_scale=d**-0.5, interpret=interpret)
         out, lse = jax.jit(functools.partial(flash_fwd_out_lse, block_q=min(bq, 512), block_k=min(bk, 512), **kw))(qt, kt, vt)
-        delta = jnp.sum(wt.astype(jnp.float32) * out.astype(jnp.float32), axis=-1, keepdims=True)
+        delta = jnp.sum(wt.astype(jnp.float32) * out.astype(jnp.float32), axis=-1)[:, :, None]  # [B, H, 1, S] rows, as lse
         programs = {
             f"flash_d{d}_dv{dv}_{bq}x{bk}": (jax.jit(jax.grad(loss, argnums=(0, 1, 2))), (q, k, v)),
             **{f"flash_bwd_{form}_d{d}_dv{dv}_{bq}x{bk}": (jax.jit(functools.partial(fn, block_q=bq, block_k=bk, **kw)), (qt, kt, vt, wt, lse, delta))

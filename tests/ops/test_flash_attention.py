@@ -30,7 +30,7 @@ def test_forward_matches_oracle(hq, hkv):
 
 
 def test_forward_non_divisible_block_fallback():
-    q, k, v = _rand_qkv(1, 1, 48, 2, 2, 16)  # 48 not divisible by 128 -> picks 16
+    q, k, v = _rand_qkv(1, 1, 48, 2, 2, 16)  # 48 not divisible by 128 -> one tile of 48 (PR 42: no block under a lane tile but the whole row)
     expected = manual_attention(q, k, v)
     got = pallas_flash_attention(q, k, v, causal=True, interpret=True)
     np.testing.assert_allclose(np.asarray(got), np.asarray(expected), rtol=2e-5, atol=2e-5)
@@ -226,19 +226,24 @@ def test_two_head_sizes_match_manual_attention(case, backward, monkeypatch):
             np.testing.assert_array_equal(np.asarray(g), np.asarray(e), err_msg=f"d{name}")
 
 
-def _kernels(fn, *args):
-    """(`name=`, grid) of every Pallas call in the traced program, in order."""
+def _pallas_calls(fn, *args):
+    """Every `pallas_call` equation of the traced program, in order, through all nested programs."""
     found = []
 
     def walk(jaxpr):
         for eqn in jaxpr.eqns:
             if eqn.primitive.name == "pallas_call":
-                found.append((eqn.params["name"], tuple(eqn.params["grid_mapping"].grid)))
+                found.append(eqn)
             for sub in jax.core.jaxprs_in_params(eqn.params):
                 walk(sub)
 
     walk(jax.make_jaxpr(fn)(*args).jaxpr)
     return found
+
+
+def _kernels(fn, *args):
+    """(`name=`, grid) of every Pallas call in the traced program, in order."""
+    return [(eqn.params["name"], tuple(eqn.params["grid_mapping"].grid)) for eqn in _pallas_calls(fn, *args)]
 
 
 def _kernel_names(fn, *args):
@@ -300,7 +305,9 @@ def test_the_fused_backward_takes_its_own_blocks():
 
 def test_equal_head_sizes_lower_to_the_program_they_always_did():
     """The second width changes nothing where it equals the first: the same tilings under both names, so the
-    same jaxpr for the kernels as a v of q's width always gave (blocks, scratch and out_shape by value): one call each."""
+    same jaxpr for the kernels as a v of q's width always gave (blocks, scratch and out_shape by value): one call each.
+    Changed on purpose by PR 42, for every width alike: "the program they always did" now hands lse and delta between
+    the kernels as [B, H, 1, S] rows (`test_the_statistics_cross_between_the_kernels_as_rows_of_numbers` holds that)."""
     q, k, v = _rand_qkv(5, 1, 64, 2, 1, 32)
     loss = lambda q, k, v: pallas_flash_attention(q, k, v, causal=True, block_q=16, block_k=16, interpret=True).sum()  # noqa: E731
     assert sorted(_kernel_names(jax.grad(loss, argnums=(0, 1, 2)), q, k, v)) == ["flash_attention_bwd", "flash_attention_fwd"]
@@ -332,3 +339,58 @@ def test_blocks_are_looked_up_by_both_widths_only_where_they_differ(monkeypatch)
     assert autotune.lookup("flash_attention", "sq4096_sk4096", "bfloat16", device_kind="TPU v5 lite") == {"block_q": 1024, "block_k": 1024}
     hit = autotune.lookup("flash_attention", "d192_dv128", "bfloat16", device_kind="TPU v5 lite")
     assert hit is not None and (hit["block_q"], hit["block_k"]) != (1024, 1024), "1024 x 1024 does not fit VMEM at 192/128 (bwd_dq)"
+
+
+# batch, rows, q heads, kv heads, width of q and k, of v, forward blocks, backward blocks, window: each cell's call as
+# `ops/attention.py` makes it (benchmark/configs/*/train.yaml, tuning_tables/v5e.json), the 32k recipe's two-kernel
+# backward, and the 8 positions of `init_params`' dummy forward; traced, not run
+CALLS = {
+    "train-2p7b-4k": (2, 4096, 32, 8, 80, 80, (1024, 1024), None, None),
+    "train-jamba2-3b-4k": (1, 4096, 20, 1, 128, 128, (1024, 1024), None, None),
+    "train-ouro-2p6b-4k": (1, 4096, 16, 16, 128, 128, (1024, 1024), None, None),
+    "train-kanana2-30b-8k": (2, 8192, 32, 32, 192, 128, (1024, 512), (1024, 1024), None),
+    "train-mellum2-12b-16k-window": (1, 16384, 32, 4, 128, 128, (1024, 1024), None, 1024),
+    "train-mellum2-12b-16k-global": (1, 16384, 32, 4, 128, 128, (1024, 1024), None, None),
+    "train-zaya1-8b-8k": (2, 8192, 8, 2, 128, 128, (1024, 1024), None, None),
+    "rows-of-32k-two-kernels": (1, 32768, 12, 4, 128, 128, (1024, 1024), None, None),
+    "dummy-forward-of-8": (1, 8, 4, 4, 128, 128, (1024, 1024), None, None),
+}
+
+
+@pytest.mark.parametrize("call", sorted(CALLS))
+def test_the_statistics_cross_between_the_kernels_as_rows_of_numbers(call):
+    """PR 42: lse (forward to backward) and delta (into the backward) are [B, H, 1, S] float32, which the chip lays out
+    dense, where [B, H, S, 1] took a lane tile of 512 bytes a number (256 MiB for 2 MiB at 2 x 32 x 8192). Read off the
+    jaxpr of a differentiated call: no float32 operand or result of any kernel has a trailing dimension of 1, and the
+    backward kernels' lse IS the forward kernel's result: nothing squeezes or spreads it on the way."""
+    batch, seq, hq, hkv, d, dv, (block_q, block_k), bwd_blocks, window = CALLS[call]
+    shapes = [jax.ShapeDtypeStruct((batch, seq, heads, width), jnp.bfloat16) for heads, width in ((hq, d), (hkv, d), (hkv, dv))]
+    kernel = functools.partial(pallas_flash_attention, block_q=block_q, block_k=block_k, bwd_blocks=bwd_blocks, window=window, interpret=True)
+    calls = _pallas_calls(jax.grad(lambda q, k, v: kernel(q, k, v).astype(jnp.float32).sum(), argnums=(0, 1, 2)), *shapes)
+    forward, backward = calls[0], calls[1:]
+    assert len(backward) == (2 if seq == 32768 else 1)
+    rows = (batch, hq, 1, seq)
+    for eqn in calls:
+        for var in (*eqn.invars, *eqn.outvars):
+            if var.aval.dtype == jnp.float32:
+                assert var.aval.shape == rows, (eqn.params["name"], var.aval)
+    lse = forward.outvars[1]
+    assert lse.aval.shape == rows and lse.aval.dtype == jnp.float32
+    for eqn in backward:
+        statistics = [var for var in eqn.invars if var.aval.dtype == jnp.float32]
+        assert len(statistics) == 2 and statistics[0] is lse  # lse as the forward wrote it, then delta
+
+
+@pytest.mark.parametrize("backward", ["fused", "two_kernels"])
+def test_a_row_that_is_no_multiple_of_a_lane_tile(backward, monkeypatch):
+    """200 positions in tiles of 40: the rows of statistics are sliced at lanes no tile boundary falls on (interpreted:
+    on a TPU such a row is one tile, as the dummy forward of 8 is, or its blocks are multiples of 128)."""
+    q, k, v = _rand_qkv(11, 1, 200, 2, 1, 16)
+    w = jax.random.normal(jax.random.PRNGKey(12), q.shape)
+    kernel = functools.partial(pallas_flash_attention, causal=True, block_q=40, block_k=40, interpret=True)
+    np.testing.assert_allclose(np.asarray(kernel(q, k, v)), np.asarray(manual_attention(q, k, v)), rtol=2e-5, atol=2e-5)
+    loss = lambda q, k, v: (kernel(q, k, v) * w).sum()  # noqa: E731
+    got = _grads_under(backward, monkeypatch, loss, q, k, v, 40, 40)
+    want = jax.grad(lambda q, k, v: (manual_attention(q, k, v) * w).sum(), argnums=(0, 1, 2))(q, k, v)
+    for g, e, name in zip(got, want, "qkv"):
+        np.testing.assert_allclose(np.asarray(g), np.asarray(e), rtol=5e-4, atol=5e-4, err_msg=f"d{name}")

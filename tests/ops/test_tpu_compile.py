@@ -118,7 +118,7 @@ def _ring_hop_not_causal():
         return out, flash_bwd_dq(q, k, v, do, lse, delta, **kw), flash_bwd_dkv(q, k, v, do, lse, delta, **kw)
 
     q, kv = ((1, 4, 8192, 128), BF16), ((1, 1, 8192, 128), BF16)
-    return hop, (q, kv, kv, q, ((1, 4, 8192, 1), F32)), 3
+    return hop, (q, kv, kv, q, ((1, 4, 1, 8192), F32)), 3
 
 
 def _fused_ce(n_embd, rows, vocab=VOCAB):
@@ -196,6 +196,11 @@ CASES = {
     "flash_window_fwd_bwd_w1024_s16384_gqa_32_4_b1024x512": _flash_window(1024, 512),
     "flash_window_fwd_bwd_w1024_s16384_gqa_32_4_b512x512": _flash_window(512, 512),
     "flash_fwd_bwd_d128_gqa_32_4_s16384": _flash(32, 4, 128, seq=4 * SEQ),
+    # PR 42, the statistics as [B, H, 1, S] rows: the compressed-convolutional cell's 8 q on 2 kv heads at 8,192, and a row
+    # shorter than a lane tile, one tile of 8 (`init_params`' dummy forward on a TPU; the backward for the layout's sake) or of 24
+    "flash_fwd_bwd_d128_gqa_8_2_s8192": _flash(8, 2, 128, seq=2 * SEQ),
+    "flash_fwd_bwd_d128_s8_one_short_tile": _flash(4, 4, 128, seq=8),
+    "flash_fwd_bwd_d128_s24_one_tile_where_blocks_of_8_divide": _flash(4, 4, 128, seq=24),  # a [1, 8] block of [1, 24] does not lower
     "fused_ce_fwd_bwd_e2304_rows16384_v12288": _fused_ce(2304, 4 * SEQ, vocab=12288),
     "fused_ce_fwd_bwd_e2048_rows16384_v16128": _fused_ce(2048, 4 * SEQ, vocab=16128),
     "fused_ce_rows_fwd_bwd_e2048_exits4_rows4096_v49152": _fused_ce_rows(2048, 4, SEQ, 49152),

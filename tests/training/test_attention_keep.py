@@ -84,7 +84,7 @@ def test_a_full_remat_block_that_keeps_o_and_lse_runs_the_forward_kernel_once(ke
     assert {name: n for name, n in calls.items() if name != forward} == {name: n for name, n in kept_calls.items() if name != forward}
     assert flash.KEPT_LSE not in named and flash.KEPT_OUT not in named
     heads = model.config_spec.n_head_q
-    assert kept_named[flash.KEPT_LSE].shape == (ROWS, heads, SEQ) and kept_named[flash.KEPT_LSE].dtype == jnp.float32
+    assert kept_named[flash.KEPT_LSE].shape == (ROWS, heads, 1, SEQ) and kept_named[flash.KEPT_LSE].dtype == jnp.float32  # as the kernel wrote it
     assert kept_named[flash.KEPT_OUT].shape == (ROWS, heads, SEQ, 32) and kept_named[flash.KEPT_OUT].dtype == jnp.bfloat16
     for a, b in zip(jax.tree.leaves(plain), jax.tree.leaves(kept)):
         assert a.dtype == b.dtype and float(jnp.abs(a.astype(jnp.float32)).max()) > 0 and bool((a == b).all())  # the kept arrays ARE the recomputed ones
@@ -104,19 +104,20 @@ V5E = int(15.75 * GIB)  # a v5e's `bytes_limit`
 ATTENTION = {"o_bytes": 128 * MIB, "lse_bytes": 2 * MIB}  # 524,288 (row, head) pairs of 128 values: the third and the fifth cell's call
 # each cell's step as one chip sees it (`benchmark/configs/*/train.yaml`): what `GPT2LLM.remat_flash_calls` and `TrainStepBuilder`
 # hand the plan, the verdict at the v5e's limit, and what the compiler said of the keeping step (GiB, `memory_analysis()` for a
-# described v5e: PERF.md section 6, PR 41); None: nothing to keep, the step is the parent's
+# described v5e: `scripts/attention_keep_sizes.py`, PERF.md section 6, PR 42); None: nothing to keep, the step is the parent's.
+# `backward_bytes` holds lse and delta as the dense rows they are since PR 42 (2 x 2 MiB in the third cell, where it held 2 x 256)
 CELLS = {
     "train-2p7b-4k": (None, 4258928648, 2839152640, "no_remat", None),  # no remat at depth 6
     "train-ouro-2p6b-4k": (None, 6142083092, 4094181380, "no_remat", None),  # the looped stack recomputes by hand
     "train-jamba2-3b-4k": ({"blocks": 14, "block_input_bytes": 20 * MIB, "calls": [
-        {"kind": "attn", "layers": 1, "o_bytes": 20 * MIB, "lse_bytes": 327680, "backward_bytes": 211812352}]}, 9161563400, 6058680064, "fits", 14.84),
+        {"kind": "attn", "layers": 1, "o_bytes": 20 * MIB, "lse_bytes": 327680, "backward_bytes": 128581632}]}, 9161563400, 6058680064, "fits", 14.84),
     "train-kanana2-30b-8k": ({"blocks": 9, "block_input_bytes": 64 * MIB, "calls": [
-        {"kind": "attn", "layers": 9, **ATTENTION, "backward_bytes": 1879048192}]}, 6148073480, 4090148864, "over_count", 16.23),
+        {"kind": "attn", "layers": 9, **ATTENTION, "backward_bytes": 1346371584}]}, 6148073480, 4090148864, "fits", 15.31),
     "train-mellum2-12b-16k": ({"blocks": 12, "block_input_bytes": 72 * MIB, "calls": [
-        {"kind": "attn", "layers": 3, **ATTENTION, "backward_bytes": 1375731712},
-        {"kind": "swa", "layers": 9, **ATTENTION, "backward_bytes": 1375731712}]}, 5457742856, 3631186944, "fits", 14.26),
+        {"kind": "attn", "layers": 3, **ATTENTION, "backward_bytes": 843055104},
+        {"kind": "swa", "layers": 9, **ATTENTION, "backward_bytes": 843055104}]}, 5457742856, 3631186944, "fits", 13.92),
     "train-zaya1-8b-8k": ({"blocks": 10, "block_input_bytes": 64 * MIB, "calls": [
-        {"kind": "cca", "layers": 10, "o_bytes": 32 * MIB, "lse_bytes": MIB // 2, "backward_bytes": 352321536}]}, 6859299056, 4545393400, "fits", 13.63),
+        {"kind": "cca", "layers": 10, "o_bytes": 32 * MIB, "lse_bytes": MIB // 2, "backward_bytes": 219152384}]}, 6859299056, 4545393400, "fits", 13.50),
 }
 
 
@@ -140,6 +141,19 @@ def test_the_plan_over_the_six_cells(cell):
     assert not dropped["keep"] and dropped["verdict"] == "fell_back_in_preflight"
 
 
+def test_the_third_cell_keeps_because_its_statistics_are_numbers_now():
+    """PR 42: the count holds lse and delta at their 4 bytes a number (`backward_bytes` ends in twice `lse_bytes`, no `* 128`);
+    with the lane tile a number they took before (2 x 256 MiB here) the same count, doubled as a working set, is over the chip."""
+    calls, state_bytes, gradient_bytes, _, _ = CELLS["train-kanana2-30b-8k"]
+    (call,) = calls["calls"]
+    operands = 2 * 8192 * 2 * (3 * 32 * 192 + 3 * 32 * 128 + 32 * (192 + 128))  # q, k, v, o, do, dq, dk, dv: bfloat16
+    assert call["backward_bytes"] == operands + 2 * call["lse_bytes"]
+    padded = {**calls, "calls": [{**call, "backward_bytes": operands + 2 * 128 * call["lse_bytes"]}]}
+    sizes = dict(state_bytes=state_bytes, gradient_bytes=gradient_bytes, bytes_limit=V5E)
+    assert attention_keep_plan(calls, **sizes)["verdict"] == "fits" and attention_keep_plan(padded, **sizes)["verdict"] == "over_count"
+    assert not attention_keep_plan(padded, **sizes)["keep"]
+
+
 def test_a_stack_with_no_attention_layer_under_remat_has_nothing_to_keep():
     plan = attention_keep_plan({"blocks": 14, "block_input_bytes": MIB, "calls": []}, state_bytes=1, gradient_bytes=1, bytes_limit=V5E)
     assert not plan["keep"] and plan["verdict"] == "no_remat" and plan["kept_bytes"] == 0
@@ -151,7 +165,7 @@ def test_a_stack_with_no_attention_layer_under_remat_has_nothing_to_keep():
     ({"remat_variant": "full", "pipeline_axis": "pp"}, None), ({"remat_variant": "full", "context_parallel_axis": "cp"}, None),
     ({"remat_variant": "full", "attention_impl": "manual"}, None),
     ({"remat_variant": "full"}, [{"kind": "attn", "layers": 2, "o_bytes": 2 * 4 * 64 * 32 * 2, "lse_bytes": 2 * 4 * 64 * 4,
-                                  "backward_bytes": 2 * 64 * 2 * (6 * 4 * 32 + 2 * 2 * 32) + 2 * 2 * 4 * 64 * 512}]),
+                                  "backward_bytes": 2 * 64 * 2 * (6 * 4 * 32 + 2 * 2 * 32) + 2 * 2 * 4 * 64 * 4}]),  # lse and delta: 4 bytes a number
 ])
 def test_the_model_names_the_calls_its_rematerialized_blocks_hold(monkeypatch, keys, calls):
     model = tiny_gpt2("dao_flash", sequence_length=SEQ).with_spec_updates(**keys)
