@@ -83,29 +83,20 @@ def build_norm(spec: NormSpec, name: str, dtype=None):
     `dtype` is the *output/compute* dtype (internals always reduce in fp32); pass the
     block compute dtype (bf16) to keep residual streams stable under lax.scan.
 
-    RMS-family norms dispatch through the fused Pallas kernel tier
-    (MODALITIES_TPU_FUSED_RMSNORM, same pattern as ops/attention.py): "auto"
-    keeps the reference modules off-TPU, so CPU tier-1 numerics are untouched;
+    RMS-family norms take the fused Pallas kernel where kernels run (`ops/tiers.py`:
+    on a TPU) and the reference modules elsewhere, so CPU tier-1 numerics are untouched;
     the fused module uses the same param names ("scale"/"bias"), so checkpoints
-    are interchangeable across tiers."""
+    are interchangeable between the two."""
     import flax.linen as nn
 
     if spec.kind == LayerNorms.layer_norm:
         return nn.LayerNorm(
             epsilon=spec.eps, use_bias=spec.use_bias, use_scale=spec.use_scale, name=name, dtype=dtype
         )
-    from modalities_tpu.ops.rmsnorm import fused_rmsnorm_tier
+    from modalities_tpu.ops import tiers
 
-    tier = fused_rmsnorm_tier()
-    if tier.enabled:
-        return FusedRMSNorm(
-            epsilon=spec.eps,
-            use_bias=spec.use_bias,
-            use_scale=spec.use_scale,
-            dtype=dtype,
-            interpret=tier.interpret,
-            name=name,
-        )
+    if tiers.kernels_run():
+        return FusedRMSNorm(epsilon=spec.eps, use_bias=spec.use_bias, use_scale=spec.use_scale, dtype=dtype, name=name)
     if spec.use_bias:
         return RMSNormWithBias(epsilon=spec.eps, name=name)
     return nn.RMSNorm(epsilon=spec.eps, use_scale=spec.use_scale, name=name, dtype=dtype)
@@ -133,13 +124,12 @@ try:  # define lazily-importable module class at module scope
     class FusedRMSNorm(_nn.Module):
         """RMS norm through the fused Pallas kernel (ops/pallas/fused_rmsnorm.py):
         one HBM round-trip per row block instead of ~6. Parameter names match the
-        reference modules ("scale"/"bias") so tiers share checkpoints."""
+        reference modules ("scale"/"bias") so both forms share checkpoints."""
 
         epsilon: float = 1e-6
         use_bias: bool = False
         use_scale: bool = True
         dtype: Optional[object] = None
-        interpret: bool = False
 
         @_nn.compact
         def __call__(self, x):
@@ -151,7 +141,7 @@ try:  # define lazily-importable module class at module scope
             bias = (
                 self.param("bias", _nn.initializers.zeros, (x.shape[-1],)) if self.use_bias else None
             )
-            y = rms_norm_or_fallback(x, scale, bias, eps=self.epsilon, interpret=self.interpret)
+            y = rms_norm_or_fallback(x, scale, bias, eps=self.epsilon)
             return y.astype(self.dtype) if self.dtype is not None else y
 
 except ImportError:  # pragma: no cover

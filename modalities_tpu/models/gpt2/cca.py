@@ -19,7 +19,7 @@ head's channels. On the block's normed input `h [S, E]`, `G = n_head_q / n_head_
     q = sqrt(d) q / ||q||           k = tau[j] sqrt(d) k / ||k||      per head and position, float32; tau [Hkv] learned, from 1
     q, k = rope(q), rope(k)         on the first `partial_rotary_factor * d` channels of a head (`rope_parameters`), the rest passed
     v[t, :Hkv/2] = h[t] W_v         v[t, Hkv/2:] = h[t - 1] W_v'      (h[-1] = 0): half the value heads are the previous position's
-    o = softmax(q k^T / sqrt(d), causal) v,  grouped Hq : Hkv         `ops/attention.flash_attention_or_fallback`
+    o = softmax(q k^T / sqrt(d), causal) v,  grouped Hq : Hkv         `ops/attention.causal_attention`
     a = o W_o
 
 The norm divides by `max(||x||, 1e-12)` (a zero vector stays zero). The previous position's
@@ -44,6 +44,8 @@ import jax
 import jax.numpy as jnp
 from pydantic import BaseModel, ConfigDict, Field
 
+from modalities_tpu.ops import tiers
+from modalities_tpu.ops.attention import causal_attention
 from modalities_tpu.telemetry import scopes
 
 
@@ -159,14 +161,9 @@ class CompressedConvAttention(nn.Module):
         with jax.named_scope(scopes.ATTN_CORE):
             q = g.with_logical_constraint(q, ("batch", "seq", "heads", "head_dim"), spec)
             k = g.with_logical_constraint(k, ("batch", "seq", "kv_heads", "head_dim"), spec)
-            if spec.dropout > 0.0 and not self.deterministic:  # on the probabilities, as the plain attention's: the written-out softmax
-                y = g.manual_attention(q, k, v, dropout_rate=spec.dropout, dropout_rng=self.make_rng("dropout"))
-            elif spec.attention_impl == g.AttentionImplementation.DAO_FLASH.value:
-                y = g.flash_attention(q, k, v, kept=True) if spec.remat_keep_flash else g.flash_attention(q, k, v)
-            elif spec.attention_impl == g.AttentionImplementation.MANUAL.value:
-                y = g.manual_attention(q, k, v)
-            else:
-                y = g.sdpa_attention(q, k, v)
+            dropping = spec.dropout > 0.0 and not self.deterministic  # on the probabilities, as the plain attention's
+            y = causal_attention(q, k, v, impl=spec.attention_impl, kept=spec.remat_keep_flash,
+                                 dropout_rate=spec.dropout if dropping else 0.0, dropout_rng=self.make_rng("dropout") if dropping else None)
             y = checkpoint_name(y, "attn_out")
         with jax.named_scope(scopes.CCA_OUT):
             out = nn.DenseGeneral(
@@ -181,10 +178,8 @@ class CompressedConvAttention(nn.Module):
 
 def _flash_blocks(seq: int, head_dim: int, dtype):
     """The flash kernels' forward blocks at this shape where they run (a TPU); None elsewhere."""
-    from modalities_tpu.ops.tiers import on_tpu
-
-    if not on_tpu():
+    if not tiers.kernels_run():
         return None
-    from modalities_tpu.ops.pallas.flash_attention import env_flash_blocks
+    from modalities_tpu.ops.pallas.flash_attention import flash_blocks
 
-    return tuple(env_flash_blocks(seq, seq, dtype=dtype, head_dim=head_dim, head_dim_v=head_dim))
+    return tuple(flash_blocks(seq, seq, dtype=dtype, head_dim=head_dim, head_dim_v=head_dim))

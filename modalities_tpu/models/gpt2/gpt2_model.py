@@ -46,6 +46,9 @@ from modalities_tpu.models.gpt2.moe import (AUX_LOSS, BIAS_LEAF, COUNTERS, EXPER
                                             update_selection_bias)
 from modalities_tpu.models.gpt2.ssm import MambaMixer, SSMConfig, SSMSpec, layer_kinds, layer_runs
 from modalities_tpu.models.model import NNModel
+from modalities_tpu.ops import tiers
+# `flash_attention` is this module's name for the ladder's kernel rung: tests/benchmark/ replaces it here to drop a window
+from modalities_tpu.ops.attention import AttentionImplementation, causal_attention, flash_attention, masked_attention, takes_kernel
 from modalities_tpu.ops.embedding import embedding_lookup
 from modalities_tpu.telemetry import get_active_telemetry, scopes
 
@@ -71,12 +74,6 @@ class ActivationType(str, Enum):
     GELU = "gelu"
     SWIGLU = "swiglu"
     FUSED_SWIGLU = "fused_swiglu"  # config-compat: XLA fuses SwiGLU on TPU anyway
-
-
-class AttentionImplementation(str, Enum):
-    MANUAL = "manual"
-    PYTORCH_FLASH = "pytorch_flash"  # config-compat alias for the XLA-fused SDPA tier
-    DAO_FLASH = "dao_flash"  # Pallas flash-attention kernel tier
 
 
 class QueryKeyValueTransformType(Enum):
@@ -245,10 +242,6 @@ class GPT2LLMConfig(BaseModel):
     # chunk is fine: the scan covers the divisible prefix and the remainder runs
     # as one short chunk (odd eval lengths need no config change).
     lm_head_chunk_size: Optional[Annotated[int, Field(strict=True, ge=1)]] = None
-    # Pallas vocab-streaming fused CE tier (ops/cross_entropy.py): "auto" = on
-    # TPU only, "on" = always (interpret off-TPU), "off" = chunked-scan fallback.
-    # MODALITIES_TPU_FUSED_CE overrides at trace time.
-    lm_head_fused_ce: Literal["auto", "on", "off"] = "auto"
     # A stack of two kinds of layer, by the two keys `model_type: jamba` publishes:
     # layer i holds attention where i % attn_layer_period == attn_layer_offset and the
     # state-space mixer of `ssm_config` (models/gpt2/ssm.py) elsewhere. Unset: attention
@@ -477,9 +470,6 @@ class GPT2ModelSpec:
     # [B,S,V] fp32 logits never materialize — at 32k ctx x 50k vocab that tensor
     # alone is 6.6 GB, more than a v5e can give it. None = whole-sequence logits.
     lm_head_chunk_size: Optional[int] = None
-    # Pallas vocab-streaming fused-CE tier: "auto" | "on" | "off" (the chunked
-    # scan above stays the fallback tier; MODALITIES_TPU_FUSED_CE overrides)
-    lm_head_fused_ce: str = "auto"
     context_parallel_axis: Optional[str] = None  # set when the mesh has cp > 1
     pipeline_axis: Optional[str] = None  # set when the mesh has pp > 1
     pp_num_microbatches: Optional[int] = None  # GPipe microbatches (default: pp degree)
@@ -596,7 +586,6 @@ class GPT2ModelSpec:
                 self.remat_save_list,
                 self.remat_keep_flash,
                 self.lm_head_chunk_size,
-                self.lm_head_fused_ce,
                 self.context_parallel_axis,
                 self.pipeline_axis,
                 self.pp_num_microbatches,
@@ -679,59 +668,6 @@ def apply_rope(x, cos, sin):
         cos = cos[:, :, None, :]
         sin = sin[:, :, None, :]
     return x * cos + _rotate_half(x) * sin
-
-
-def masked_attention(q, k, v, mask, dropout_rate: float = 0.0, dropout_rng=None):
-    """einsum + fp32 softmax attention with an explicit boolean mask — [Sq, Sk]
-    shared across the batch, or [B, Sq, Sk] per-batch-row (slot decode: each slot
-    attends up to its own cache length).
-    q: [B,Sq,Hq,D], k: [B,Sk,Hkv,D], v: [B,Sk,Hkv,Dv]; GQA convention: q head h uses kv head h // group.
-
-    `dropout_rate` > 0 applies inverted dropout to the attention *probabilities*
-    (the reference semantic: manual_scaled_dot_product_attention / SDPA `dropout_p`,
-    reference gpt2_model.py:595-658) — NOT to the attention output."""
-    b, sq, hq, d = q.shape
-    hkv = k.shape[2]
-    group = hq // hkv
-    qg = q.reshape(b, sq, hkv, group, d)
-    logits = jnp.einsum("bshgd,bthd->bhgst", qg, k).astype(jnp.float32) / math.sqrt(d)
-    mask_b = mask[None, None, None, :, :] if mask.ndim == 2 else mask[:, None, None, :, :]
-    logits = jnp.where(mask_b, logits, jnp.finfo(jnp.float32).min)
-    probs = jax.nn.softmax(logits, axis=-1)
-    if dropout_rate > 0.0:
-        if dropout_rng is None:
-            raise ValueError(
-                "masked_attention: dropout_rate > 0 requires dropout_rng — refusing "
-                "to silently skip attention-probability dropout"
-            )
-        keep = jax.random.bernoulli(dropout_rng, 1.0 - dropout_rate, probs.shape)
-        probs = jnp.where(keep, probs / (1.0 - dropout_rate), 0.0)
-    probs = probs.astype(v.dtype)
-    out = jnp.einsum("bhgst,bthd->bshgd", probs, v)
-    return out.reshape(b, sq, hq, v.shape[-1])
-
-
-def manual_attention(q, k, v, dropout_rate: float = 0.0, dropout_rng=None, window: Optional[int] = None):
-    """Oracle attention: causal mask over a square sequence (reference :595-658); under `window`
-    a position sees itself and the `window - 1` before it."""
-    s = q.shape[1]
-    mask = jnp.tril(jnp.ones((s, s), dtype=bool))
-    if window is not None:
-        mask = mask & ~jnp.tril(jnp.ones((s, s), dtype=bool), -window)
-    return masked_attention(q, k, v, mask, dropout_rate=dropout_rate, dropout_rng=dropout_rng)
-
-
-def sdpa_attention(q, k, v):
-    """XLA-fused scaled dot product attention with native GQA support."""
-    return jax.nn.dot_product_attention(q, k, v, is_causal=True)
-
-
-def flash_attention(q, k, v, window: Optional[int] = None, kept: bool = False):
-    """Pallas flash-attention tier; falls back to SDPA off-TPU (under a window, to the masked softmax written out).
-    `kept`: the call sits in a rematerialized block that keeps the kernel's o and lse (`spec.remat_keep_flash`)."""
-    from modalities_tpu.ops.attention import flash_attention_or_fallback
-
-    return flash_attention_or_fallback(q, k, v, causal=True, window=window, kept=kept)
 
 
 class QuantDenseGeneral(nn.Module):
@@ -871,48 +807,14 @@ class CausalSelfAttention(nn.Module):
             q = with_logical_constraint(q, ("batch", "seq", "heads", "head_dim"), spec)
             k = with_logical_constraint(k, ("batch", "seq", "kv_heads", "head_dim"), spec)
 
-            impl = spec.attention_impl
-            # attention-probability dropout (reference gpt2_model.py:595-658: every tier
-            # passes `dropout` into the attention itself — manual attn_dropout(att) /
-            # SDPA+flash dropout_p). The unfused path implements it exactly; the Pallas
-            # flash kernel and the ring do not sample inside the kernel, so they refuse
-            # rather than silently training a different model (docs/components.md §2.4).
-            attn_dropout_active = spec.dropout > 0.0 and not self.deterministic
-            if spec.context_parallel_axis is not None:
-                if attn_dropout_active:
-                    raise NotImplementedError(
-                        "attention-probability dropout (dropout > 0) is not implemented for "
-                        "ring attention (context parallelism): the ring merges per-chunk "
-                        "softmax statistics that dropout would invalidate. Set dropout: 0.0 "
-                        "or run without a cp mesh axis."
-                    )
-                # real context parallelism: ring attention over the cp axis (the slot the
-                # reference leaves unfilled, SURVEY.md §5.7)
-                from modalities_tpu.parallel.ring_attention import ring_attention
-                from modalities_tpu.running_env.device_mesh import current_mesh
-
-                y = ring_attention(q, k, v, current_mesh(), axis_name=spec.context_parallel_axis)
-            elif attn_dropout_active:
-                if impl == AttentionImplementation.DAO_FLASH.value:
-                    raise NotImplementedError(
-                        "attention-probability dropout (dropout > 0) is not implemented in "
-                        "the dao_flash Pallas kernel. Use attention_implementation: manual "
-                        "or pytorch_flash (both apply the reference's attention-weight "
-                        "dropout semantics), or set dropout: 0.0."
-                    )
-                # manual AND pytorch_flash: the reference applies dropout_p inside SDPA;
-                # the fused XLA SDPA has no dropout hook, so both tiers drop to the exact
-                # unfused path — same math, probabilities dropped out as the reference does
-                y = manual_attention(
-                    q, k, v, dropout_rate=spec.dropout, dropout_rng=self.make_rng("dropout"), window=window
-                )
-            elif impl == AttentionImplementation.DAO_FLASH.value:
-                # told only where the block keeps: a call that is not told is the call it always was (tests/benchmark wrap it)
-                y = flash_attention(q, k, v, window, kept=True) if spec.remat_keep_flash else flash_attention(q, k, v, window)
-            elif impl == AttentionImplementation.MANUAL.value or window is not None:  # fused SDPA's mask is causal, no more
-                y = manual_attention(q, k, v, window=window)
-            else:
-                y = sdpa_attention(q, k, v)
+            # attention-probability dropout is in force where the module is not deterministic; the ladder
+            # (`ops/attention.causal_attention`) picks the function, this module's name for the kernel's rung handed in
+            dropping = spec.dropout > 0.0 and not self.deterministic
+            y = causal_attention(
+                q, k, v, impl=spec.attention_impl, window=window, kept=spec.remat_keep_flash, cp_axis=spec.context_parallel_axis,
+                dropout_rate=spec.dropout if dropping else 0.0, dropout_rng=self.make_rng("dropout") if dropping else None,
+                flash=flash_attention,
+            )
 
             # named save point for selective-op remat (reference SAVE_DICT saves the SDPA
             # output, activation_checkpointing.py:67-83): save_list=("attn_out",) stores
@@ -1925,7 +1827,6 @@ class GPT2LLM(NNModel):
         seed: Optional[int] = None,
         enforce_swiglu_hidden_dim_multiple_of: int = 256,
         lm_head_chunk_size: Optional[int] = None,
-        lm_head_fused_ce: str = "auto",
         attn_layer_period: Optional[int] = None,
         attn_layer_offset: int = 0,
         ssm_config: Optional[SSMConfig | dict] = None,
@@ -2019,7 +1920,6 @@ class GPT2LLM(NNModel):
                 else None
             ),
             lm_head_chunk_size=lm_head_chunk_size,
-            lm_head_fused_ce=lm_head_fused_ce,
             layer_kinds=(layer_kinds(n_layer, attn_layer_period, attn_layer_offset) if attn_layer_period
                          else tuple(_MIXER_OF[kind] for kind in layer_types or ())),
             ssm=SSMSpec.from_config(ssm_config, n_embd) if ssm_config is not None else None,
@@ -2062,13 +1962,11 @@ class GPT2LLM(NNModel):
         or none, a looped stack (`_walks_in_place` recomputes by hand and takes no policy), pipeline stages
         (`jax.checkpoint` of their own), ring attention (no call of this kernel), a tier that is not the kernel, and off
         the TPU, where `ops/attention.py` runs XLA's attention and there is no kernel to keep anything of."""
-        from modalities_tpu.ops.tiers import on_tpu
         from modalities_tpu.parallel.sharding import shard_shape
 
         spec = self.config_spec
-        if (spec.remat_variant != "full" or spec.loop is not None or spec.pipeline_axis is not None
-                or spec.context_parallel_axis is not None or spec.dropout > 0.0
-                or spec.attention_impl != AttentionImplementation.DAO_FLASH.value or not on_tpu()):
+        if (spec.remat_variant != "full" or spec.loop is not None or spec.pipeline_axis is not None or not tiers.kernels_run()
+                or not takes_kernel(spec.attention_impl, spec.dropout, spec.context_parallel_axis)):
             return None
         itemsize = jnp.dtype(spec.compute_dtype).itemsize
         # latent attention hands the kernel every head's own k and v, 192 and 128 wide; the others `n_head_kv` heads of `head_dim`

@@ -34,6 +34,7 @@ import jax
 import jax.numpy as jnp
 from pydantic import BaseModel, Field, model_validator
 
+from modalities_tpu.ops.attention import causal_attention
 from modalities_tpu.telemetry import scopes
 
 
@@ -107,8 +108,7 @@ class LatentAttention(nn.Module):
 
     @nn.compact
     def __call__(self, x):
-        from modalities_tpu.models.gpt2.gpt2_model import (AttentionImplementation, flash_attention, manual_attention,
-                                                           with_logical_constraint)
+        from modalities_tpu.models.gpt2.gpt2_model import with_logical_constraint
         from modalities_tpu.models.gpt2.ssm import _ScaleNorm  # an RMS norm with a learned float32 scale, computed in float32
 
         spec, mla = self.spec, self.spec.mla
@@ -139,11 +139,9 @@ class LatentAttention(nn.Module):
             q = with_logical_constraint(q, ("batch", "seq", "heads", "head_dim"), spec)
             k = with_logical_constraint(k, ("batch", "seq", "heads", "head_dim"), spec)
             v = with_logical_constraint(v, ("batch", "seq", "heads", "head_dim"), spec)
-            if spec.attention_impl == AttentionImplementation.DAO_FLASH.value:
-                # scale 1 / sqrt(d_n + d_r): the kernels' default, off q's width
-                y = flash_attention(q, k, v, kept=True) if spec.remat_keep_flash else flash_attention(q, k, v)
-            else:
-                y = manual_attention(q, k, v)  # SDPA takes one width for q, k and v: both other tiers are the plain softmax
+            # scale 1 / sqrt(d_n + d_r), off q's width; SDPA takes one width for q, k and v, so at d_v < d_n + d_r
+            # whatever is not the kernel is the written-out softmax
+            y = causal_attention(q, k, v, impl=spec.attention_impl, kept=spec.remat_keep_flash)
             from jax.ad_checkpoint import checkpoint_name
 
             y = checkpoint_name(y, "attn_out")

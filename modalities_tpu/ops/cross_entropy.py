@@ -1,54 +1,25 @@
-"""Fused cross-entropy dispatch — same tier pattern as ops/attention.py.
+"""Fused cross-entropy dispatch: the Pallas vocab-streaming kernel (ops/pallas/fused_ce.py)
+per shard of the rows.
 
-Tier resolution (`MODALITIES_TPU_FUSED_CE`, falling back to the model spec's
-`lm_head_fused_ce` knob): "auto" runs the Pallas vocab-streaming kernel on TPU
-only; "on" forces it everywhere (interpret mode off-TPU, which is how CPU tests
-and the no-[B,S,V]-HLO assertion exercise the real kernel); "off" keeps the
-chunked-scan fallback tier. Malformed values raise — never silently demote.
+Whether a train step takes it is `ops/tiers.py`'s one rule, asked where the step is built
+(`training/train_step.py`: on a TPU, for a model with `lm_head_chunk_size` set; elsewhere
+the chunked scan). A call that gets here runs the kernel, interpreted off a TPU.
 
-Block sizes: env override > autotune table (ops/pallas/autotune.py, consulted
-at trace time) > module default.
+Block sizes: the tuning table (`ops/pallas/autotune.blocks`), else the defaults below.
 """
 
 from __future__ import annotations
 
-import os
-from typing import Optional, Tuple
-
-import jax.numpy as jnp
-
-from modalities_tpu.ops.tiers import KernelTier, on_tpu, resolve_tier
+from modalities_tpu.ops import tiers
+from modalities_tpu.ops.pallas import autotune
 
 DEFAULT_BLOCK_ROWS = 256
 DEFAULT_BLOCK_VOCAB = 512
 
 
-def fused_ce_tier(spec_setting: Optional[str] = None) -> KernelTier:
-    return resolve_tier("MODALITIES_TPU_FUSED_CE", spec_setting)
-
-
-def resolve_ce_blocks(rows: int, vocab: int, n_embd: int, dtype) -> Tuple[int, int]:
-    """env var > autotune table > default — parsed outside any fallback guard so
-    a malformed override raises instead of demoting the kernel tier."""
-    env_rows = os.environ.get("MODALITIES_TPU_CE_BLOCK_ROWS")
-    env_vocab = os.environ.get("MODALITIES_TPU_CE_BLOCK_VOCAB")
-    block_rows = int(env_rows) if env_rows is not None else None
-    block_vocab = int(env_vocab) if env_vocab is not None else None
-    if block_rows is None or block_vocab is None:
-        from modalities_tpu.ops.pallas import autotune
-
-        hit = autotune.lookup(
-            "fused_ce",
-            f"n{autotune.shape_bucket(rows)}_v{autotune.shape_bucket(vocab)}_e{autotune.shape_bucket(n_embd)}",
-            jnp.dtype(dtype).name,
-        )
-        if hit:
-            block_rows = block_rows if block_rows is not None else int(hit.get("block_rows", DEFAULT_BLOCK_ROWS))
-            block_vocab = block_vocab if block_vocab is not None else int(hit.get("block_vocab", DEFAULT_BLOCK_VOCAB))
-    return (
-        block_rows if block_rows is not None else DEFAULT_BLOCK_ROWS,
-        block_vocab if block_vocab is not None else DEFAULT_BLOCK_VOCAB,
-    )
+def resolve_ce_blocks(rows: int, vocab: int, n_embd: int, dtype) -> tuple[int, int]:
+    bucket = f"n{autotune.shape_bucket(rows)}_v{autotune.shape_bucket(vocab)}_e{autotune.shape_bucket(n_embd)}"
+    return autotune.blocks("fused_ce", bucket, dtype, block_rows=DEFAULT_BLOCK_ROWS, block_vocab=DEFAULT_BLOCK_VOCAB)
 
 
 def _row_layout(labels):
@@ -67,7 +38,7 @@ def _dispatch(hidden, head_weight, labels, ignore_index: int, interpret: bool, r
 
     rows = int(np.prod(hidden.shape[:-1])) if hidden.ndim > 1 else hidden.shape[0]
     block_rows, block_vocab = resolve_ce_blocks(rows, head_weight.shape[0], hidden.shape[-1], hidden.dtype)
-    interpret = interpret or not on_tpu()
+    interpret = tiers.interpret(interpret)
     pallas_entry = pallas_rows if rows_out else pallas_sum_and_count
 
     def kernel(axes, hidden, head_weight, labels):

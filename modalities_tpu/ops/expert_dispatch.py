@@ -81,12 +81,11 @@ import jax.numpy as jnp
 import numpy as np
 
 from modalities_tpu.ops.pallas.moe_combine import ALIGN, moe_combine, pad_rows
-from modalities_tpu.ops.tiers import on_tpu, resolve_tier
+from modalities_tpu.ops import tiers
 from modalities_tpu.telemetry import scopes
 
 TILE = 256  # rows a turn of an expert's loop takes: two MXU passes high, and at most 255 padding rows a held expert
 COMBINE_BLOCK = 256  # tokens a grid step of the sum by token's kernel takes: a slab of 272 rows and a 256 x 272 product an expert in use
-COMBINE_TIER_ENV = "MODALITIES_TPU_MOE_COMBINE"  # auto (the kernel on a TPU, the gathers elsewhere) | on (interpreted off a TPU) | off
 SLABS_UP_TO_HELD_PER_CHOICE = 4  # `combine_plan`: slabs where a layer holds at most this many experts to a choice
 
 
@@ -177,13 +176,12 @@ def combine_plan(tokens: int, k: int, held: int, width: int) -> str:
 
 
 def combine_form(tokens: int, k: int, held: int, width: int) -> str:
-    """`combine_plan` where the kernel may run: by the tier (`MODALITIES_TPU_MOE_COMBINE`: `auto` the
-    kernel on a TPU and the gathers elsewhere, `on` the kernel anywhere, interpreted off a TPU, `off` the
-    gathers), and the gathers under a mesh (GSPMD partitions them with the rest of the layer; the kernel
-    would want a plan of its own a shard: not written)."""
+    """`combine_plan` where the kernel may run (`ops/tiers.py`'s one rule: on a TPU); the gathers elsewhere,
+    and under a mesh (GSPMD partitions them with the rest of the layer; the kernel would want a plan of its
+    own a shard: not written)."""
     from modalities_tpu.parallel.sharding import installed_mesh_size
 
-    if not resolve_tier(COMBINE_TIER_ENV).enabled or installed_mesh_size() > 1:
+    if not tiers.kernels_run() or installed_mesh_size() > 1:
         return "gathers"
     return combine_plan(tokens, k, held, width)
 
@@ -229,7 +227,7 @@ def _sum_by_slabs(rows, plan: DispatchPlan, slabs: SlabTables, tokens: int, k: i
     """The same sum by the kernel, in `rows`' dtype: every token's rows read out of the slab its block
     fetched for that expert, times the float32 weight, added in float32 by held expert (the gathers add
     by choice: the one difference), rounded once. `rows` carries the kernel's padding rows. Off a TPU the
-    kernel is interpreted (tests, `MODALITIES_TPU_MOE_COMBINE=on`)."""
+    kernel is interpreted (tests)."""
     by_expert = None
     if weights is not None:
         with jax.named_scope(scopes.MOE_DISPATCH):  # the weights laid out by held expert: a pair's weight where its row is the token's on that expert
@@ -237,7 +235,7 @@ def _sum_by_slabs(rows, plan: DispatchPlan, slabs: SlabTables, tokens: int, k: i
             by_expert = jnp.sum(jnp.where(on_expert, weights[:, :, None], 0.0), axis=1)
             by_expert = jnp.pad(by_expert, ((0, slabs.pos.shape[0] - tokens), (0, 0)))
     with jax.named_scope(scopes.MOE_COMBINE):
-        out = moe_combine(rows, slabs.pos, slabs.start, slabs.count, by_expert, block=slabs.block, interpret=not on_tpu())
+        out = moe_combine(rows, slabs.pos, slabs.start, slabs.count, by_expert, block=slabs.block, interpret=tiers.interpret())
     return out[:tokens]
 
 

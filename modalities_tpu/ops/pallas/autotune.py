@@ -10,11 +10,13 @@ untuned guess. This module closes that gap with a *table*, not a heuristic:
 - One file per device kind (``v5e.json``, ``v5p.json``, ...). Shipped defaults
   live in ``modalities_tpu/ops/pallas/tuning_tables/``; an operator-run sweep
   writes to ``MODALITIES_TPU_TUNE_DIR``, which takes precedence.
-- ``lookup()`` is consulted at trace time by the dispatch wrappers, after env
-  overrides and before hardcoded defaults:
+- ``blocks()`` is what the dispatch wrappers ask at trace time, and the one reader
+  of the tables (through ``lookup()``):
 
-      env var  >  MODALITIES_TPU_TUNE_DIR table  >  shipped table  >  default
+      MODALITIES_TPU_TUNE_DIR table  >  shipped table  >  the caller's default
 
+  Nothing stands in front of the tables: to give a kernel other blocks, an operator
+  (or a test) puts a table under ``MODALITIES_TPU_TUNE_DIR``.
 - ``tune_kernels()`` runs the timed sweep (the ``data tune_kernels`` CLI is
   its only caller) and persists what it measured. On a
   non-TPU host the sweep runs in interpret mode: the table round-trips and the
@@ -138,6 +140,19 @@ def lookup(
             if isinstance(hit, dict):
                 return dict(hit)
     return None
+
+
+def blocks(kernel, bucket: str, dtype, **defaults: int) -> tuple:
+    """A kernel call's block sizes, in the order of `defaults`: the entry of the first of `kernel`'s
+    names a table answers for (one name, or several in order: the fused flash backward asks for its
+    own entry before the forward's), each size the entry lacks at the caller's default. A size a
+    table holds that is no integer raises: it must never quietly become another block."""
+    import jax.numpy as jnp
+
+    dtype_name = jnp.dtype(dtype).name
+    names = (kernel,) if isinstance(kernel, str) else kernel
+    hit = next(filter(None, (lookup(name, bucket, dtype_name) for name in names)), {})
+    return tuple(int(hit.get(size, default)) for size, default in defaults.items())
 
 
 def save_table(out_dir: Path, slug: str, entries: Dict[str, Dict[str, Any]]) -> Path:

@@ -427,7 +427,7 @@ def backward_plan(seq_q: int, block_q: int, block_k: int, head_dim: int, head_di
     """Which backward a differentiated call of these shapes runs, chosen from the shapes alone:
     the fused kernel wherever its counted VMEM need is within `FUSED_BWD_VMEM_BUDGET`, else
     `bwd_dq` and `bwd_dkv` as before PR 31 (at the forward's blocks). `block_q` x `block_k` are
-    the fused kernel's own (`env_flash_blocks(backward=True)`). The fields `flash_tile_plan` reports."""
+    the fused kernel's own (`flash_blocks(backward=True)`). The fields `flash_tile_plan` reports."""
     itemsize = jnp.dtype(dtype).itemsize
     need = fused_backward_vmem_bytes(seq_q, block_q, block_k, head_dim, head_dim_v, itemsize)
     return {
@@ -531,15 +531,12 @@ def _pick_block(seq: int, preferred: int) -> int:
     return seq
 
 
-def env_flash_blocks(seq_q: int, seq_k: int, dtype="bfloat16", head_dim: int | None = None,
-                     head_dim_v: int | None = None, backward: bool = False) -> tuple[int, int]:
-    """The (block_q, block_k) tuning knobs, shared by every kernel consumer
-    (ops/attention.py dispatch, the ring tier). Precedence per knob:
-    MODALITIES_TPU_FLASH_BLOCK_Q/_K env override > the per-device autotune table
-    (ops/pallas/autotune.py, consulted at trace time) > 1024 (PERF.md section 6 has
-    the chip's readings) — then stepped down to divide the sequence. A
-    malformed override raises (int()) — it must never silently demote the call to
-    a fallback tier.
+def flash_blocks(seq_q: int, seq_k: int, dtype="bfloat16", head_dim: int | None = None,
+                 head_dim_v: int | None = None, backward: bool = False) -> tuple[int, int]:
+    """The (block_q, block_k) of a call, shared by every kernel consumer (ops/attention.py
+    dispatch, the ring's hops): the per-device tuning table (`ops/pallas/autotune.blocks`,
+    consulted at trace time), else 1024 (PERF.md section 6 has the chip's readings), then
+    stepped down to divide the sequence.
 
     The table's bucket is the two sequence lengths; where v is not as wide as q and k
     (latent attention) it is the two widths instead (`d192_dv128`), whatever the sequence:
@@ -554,27 +551,13 @@ def env_flash_blocks(seq_q: int, seq_k: int, dtype="bfloat16", head_dim: int | N
     A windowed call reads the same entries: at window 1024 and head 128 the chip read the
     default's 1024 x 1024 fastest of three pairs (PERF.md section 6, PR 38), so it has no
     bucket of its own until a window or a width reads faster at other blocks."""
-    import os
+    from modalities_tpu.ops.pallas import autotune
 
-    env_q = os.environ.get("MODALITIES_TPU_FLASH_BLOCK_Q")
-    env_k = os.environ.get("MODALITIES_TPU_FLASH_BLOCK_K")
-    block_q = int(env_q) if env_q is not None else None
-    block_k = int(env_k) if env_k is not None else None
-    if block_q is None or block_k is None:
-        from modalities_tpu.ops.pallas import autotune
-
-        bucket = f"sq{autotune.shape_bucket(seq_q)}_sk{autotune.shape_bucket(seq_k)}"
-        if head_dim is not None and head_dim_v is not None and head_dim != head_dim_v:
-            bucket = f"d{head_dim}_dv{head_dim_v}"
-        dtype_name = jnp.dtype(dtype).name
-        hit = (backward and autotune.lookup("flash_attention_bwd", bucket, dtype_name)) or autotune.lookup("flash_attention", bucket, dtype_name)
-        if hit:
-            block_q = block_q if block_q is not None else int(hit.get("block_q", 1024))
-            block_k = block_k if block_k is not None else int(hit.get("block_k", 1024))
-    if block_q is None:
-        block_q = 1024
-    if block_k is None:
-        block_k = 1024
+    bucket = f"sq{autotune.shape_bucket(seq_q)}_sk{autotune.shape_bucket(seq_k)}"
+    if head_dim is not None and head_dim_v is not None and head_dim != head_dim_v:
+        bucket = f"d{head_dim}_dv{head_dim_v}"
+    kernels = ("flash_attention_bwd", "flash_attention") if backward else "flash_attention"
+    block_q, block_k = autotune.blocks(kernels, bucket, dtype, block_q=1024, block_k=1024)
     return _pick_block(seq_q, block_q), _pick_block(seq_k, block_k)
 
 

@@ -1,40 +1,27 @@
-"""Fused RMSNorm dispatch — same tier pattern as ops/attention.py.
+"""Fused RMSNorm dispatch: the Pallas kernel (ops/pallas/fused_rmsnorm.py) per shard of the rows.
 
-Tier resolution via `MODALITIES_TPU_FUSED_RMSNORM`: "auto" (default) uses the
-Pallas kernel on TPU and the exact reference everywhere else, so CPU tier-1
-numerics are byte-identical to the seed; "on" forces the kernel (interpret mode
-off-TPU); "off" pins the reference. Malformed values raise.
+Whether a model's RMS norms take it is `ops/tiers.py`'s one rule, asked where the norm is built
+(`models/components/layer_norms.build_norm`: on a TPU; elsewhere the reference linen norms, so
+CPU tier-1 numerics are the seed's). A call that gets here runs the kernel, interpreted off a TPU.
 
-Block size: `MODALITIES_TPU_RMSNORM_BLOCK_ROWS` > autotune table > 256.
+Block size: the tuning table (`ops/pallas/autotune.blocks`), else 256 rows.
 """
 
 from __future__ import annotations
 
 import functools
-import os
 
 import jax
 import jax.numpy as jnp
 
-from modalities_tpu.ops.tiers import KernelTier, on_tpu, resolve_tier
+from modalities_tpu.ops import tiers
+from modalities_tpu.ops.pallas import autotune
 
 DEFAULT_BLOCK_ROWS = 256
 
 
-def fused_rmsnorm_tier() -> KernelTier:
-    return resolve_tier("MODALITIES_TPU_FUSED_RMSNORM")
-
-
 def resolve_rmsnorm_block_rows(n_embd: int, dtype) -> int:
-    env = os.environ.get("MODALITIES_TPU_RMSNORM_BLOCK_ROWS")
-    if env is not None:
-        return int(env)  # malformed must raise, never demote
-    from modalities_tpu.ops.pallas import autotune
-
-    hit = autotune.lookup("fused_rmsnorm", f"e{autotune.shape_bucket(n_embd)}", jnp.dtype(dtype).name)
-    if hit:
-        return int(hit.get("block_rows", DEFAULT_BLOCK_ROWS))
-    return DEFAULT_BLOCK_ROWS
+    return autotune.blocks("fused_rmsnorm", f"e{autotune.shape_bucket(n_embd)}", dtype, block_rows=DEFAULT_BLOCK_ROWS)[0]
 
 
 # how the rows of a norm's input lie on the mesh, by rank: the residual stream
@@ -54,7 +41,7 @@ def rms_norm_or_fallback(x, scale=None, bias=None, *, eps: float = 1e-6, interpr
         fused_rms_norm,
         eps=eps,
         block_rows=resolve_rmsnorm_block_rows(x.shape[-1], x.dtype),
-        interpret=interpret or not on_tpu(),
+        interpret=tiers.interpret(interpret),
     )
     # the identity params the kernel would make for itself, made here so that
     # every shard is handed the same three operands
@@ -67,8 +54,7 @@ def rms_norm_or_fallback(x, scale=None, bias=None, *, eps: float = 1e-6, interpr
 
 
 def reference_rms_norm(x, scale=None, bias=None, *, eps: float = 1e-6):
-    """Same math as layer_norms.RMSNormWithBias, kept here as the fallback tier
-    and the parity-test oracle."""
+    """Same math as layer_norms.RMSNormWithBias: the parity tests' oracle."""
     x32 = x.astype(jnp.float32)
     y = x32 * jax.lax.rsqrt((x32 * x32).mean(axis=-1, keepdims=True) + eps)
     if scale is not None:

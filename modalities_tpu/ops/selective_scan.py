@@ -42,7 +42,7 @@ import jax
 import jax.numpy as jnp
 from jax import lax
 
-from modalities_tpu.ops.tiers import on_tpu
+from modalities_tpu.ops import tiers
 
 # steps between the states the backward pass keeps. On the chip at the hybrid cell's shape (1 x 4096 x 5120 x 16, PR 26, forward and
 # backward of one layer): 64 steps 31.0 ms, 128 steps 26.6, 256 steps 29.5; the step's memory does not move with it (15.17-15.28 GiB)
@@ -175,8 +175,8 @@ _ROWS, _NARROW, _STATE = ("batch", None, "mlp"), ("batch", None, None), ("batch"
 
 
 def uses_kernels(interpret: bool = False) -> bool:
-    """Whether `selective_scan` runs the Pallas kernels here: on a TPU always, elsewhere when asked to interpret them."""
-    return interpret or on_tpu()
+    """Whether `selective_scan` runs the Pallas kernels here: where kernels run (`ops/tiers.py`: on a TPU), elsewhere when asked to interpret them."""
+    return interpret or tiers.kernels_run()
 
 
 def selective_scan(x, dt, a, b, c, *, chunk: int = CHUNK, h0=None, interpret: bool = False):
@@ -197,7 +197,7 @@ def selective_scan(x, dt, a, b, c, *, chunk: int = CHUNK, h0=None, interpret: bo
 
     def kernels(_axes, *local):
         _say_plan(local[0], local[2], chunk, kernel=True)
-        return pallas_selective_scan(*local, chunk=chunk, interpret=not on_tpu())
+        return pallas_selective_scan(*local, chunk=chunk, interpret=tiers.interpret(interpret))
 
     # b, c and a are whole on the axes they do not name, and the shard_map's transpose adds
     # their cotangents up over those: dB, dC over the axes d_inner was split on, dA over batch's

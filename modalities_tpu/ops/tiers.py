@@ -1,56 +1,51 @@
-"""Kernel-tier resolution shared by the dispatch wrappers (attention, fused CE,
-fused RMSNorm).
+"""Which form of an operation runs: one rule, asked here by every dispatch wrapper.
 
-A tier setting is "auto" | "on" | "off":
-- "auto": the Pallas kernel runs on TPU, the exact fallback everywhere else
-  (CPU tests see reference numerics, mirroring ops/attention.py).
-- "on": the kernel runs unconditionally — off-TPU it runs in interpret mode so
-  numerics stay exact (this is how CPU tests exercise the kernel path and how
-  the no-[B,S,V]-buffer HLO assertion is made on a CPU-only CI box).
-- "off": the fallback tier runs everywhere.
+A Pallas kernel runs where the platform is a TPU and the planner that reads the call's
+shapes says so (`combine_plan`, `backward_plan`, `grad_plan`, `attention_keep_plan`);
+off a TPU the reference form runs, so CPU tests see reference numerics. No environment
+variable, config key or CLI flag overrides this: two forms are compared as two commits
+on the chip.
 
-Precedence: env var > config/spec knob > "auto". A malformed value raises — it
-must never silently demote a training run to the fallback tier.
+Tests reach a kernel off the chip through `interpreted_kernels()` (or a wrapper's own
+`interpret=` keyword). Wrappers ask through the module (`tiers.kernels_run()`), so a
+test that pretends the platform is a TPU, to lower for a described topology, patches
+the one name `tiers.on_tpu`.
 """
 
 from __future__ import annotations
 
-import os
-from dataclasses import dataclass
-from typing import Optional
+import contextlib
 
-_ON = ("1", "on", "true", "yes", "force")
-_OFF = ("0", "off", "false", "no")
+_interpreted = False  # inside `interpreted_kernels()`
 
 
 def on_tpu() -> bool:
     """A backend that fails to initialise raises here: answering "not a TPU" would
-    turn every `auto` kernel into its reference and every forced one into
-    interpret mode without a word."""
+    turn every kernel into its reference without a word."""
     import jax
 
     return jax.devices()[0].platform == "tpu"
 
 
-@dataclass(frozen=True)
-class KernelTier:
-    enabled: bool
-    # run the Pallas kernel in interpret mode (forced-on off-TPU: exact CPU
-    # emulation, same kernel code path as the hardware lowering)
-    interpret: bool
+def kernels_run() -> bool:
+    """Whether a wrapper takes its kernel: on a TPU, and off one inside `interpreted_kernels()`."""
+    return _interpreted or on_tpu()
 
 
-def resolve_tier(env_name: str, spec_setting: Optional[str] = None) -> KernelTier:
-    env = os.environ.get(env_name)
-    raw = (env if env is not None else (spec_setting or "auto")).strip().lower()
-    if raw in _OFF:
-        return KernelTier(enabled=False, interpret=False)
-    if raw in _ON:
-        return KernelTier(enabled=True, interpret=not on_tpu())
-    if raw == "auto":
-        return KernelTier(enabled=on_tpu(), interpret=False)
-    source = env_name if env is not None else "config"
-    raise ValueError(
-        f"{source}={raw!r}: expected one of auto/on/off (a malformed tier setting "
-        "must raise, never silently demote the kernel to a fallback tier)"
-    )
+def interpret(asked: bool = False) -> bool:
+    """The `interpret` a kernel call gets: compiled on a TPU unless the caller asked, interpreted
+    (exact CPU emulation of the same kernel code) wherever else a call got this far."""
+    return asked or not on_tpu()
+
+
+@contextlib.contextmanager
+def interpreted_kernels():
+    """For tests, reachable from Python only: what is traced inside takes every kernel the rule
+    would take on a TPU, interpreted. Read while tracing, as the platform is: a function jitted
+    outside keeps the forms it was traced with."""
+    global _interpreted
+    before, _interpreted = _interpreted, True
+    try:
+        yield
+    finally:
+        _interpreted = before

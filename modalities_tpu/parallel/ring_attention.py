@@ -8,7 +8,7 @@ commented-out CP context). This module fills that slot TPU-first:
 - k/v chunks rotate around the ring via `lax.ppermute` (ICI neighbor hops) while each
   device accumulates attention for its q chunk with an online-softmax merge — peak
   memory O(S_local * block) per device instead of O(S^2), communication overlappable
-- two inner-loop tiers: on TPU each hop runs the in-repo Pallas flash kernel
+- two forms of a hop: on TPU each hop runs the in-repo Pallas flash kernel
   (ops/pallas/flash_attention.py) and hops merge their normalized (out, lse) pairs
   with the flash-decoding rule; off-TPU a dense/k-blocked einsum path keeps tests
   exact. Chunk-level causality is decided OUTSIDE the kernel (full/diagonal/skip
@@ -28,6 +28,8 @@ import math
 
 import jax
 import jax.numpy as jnp
+
+from modalities_tpu.ops import tiers
 
 NEG_INF = -1e30
 
@@ -166,9 +168,9 @@ def _ring_dense_local(q, k, v, *, axis_name: str, causal: bool, sm_scale: float)
 
 
 def _hop_blocks(seq_q: int, seq_k: int):
-    from modalities_tpu.ops.pallas.flash_attention import env_flash_blocks
+    from modalities_tpu.ops.pallas.flash_attention import flash_blocks
 
-    return env_flash_blocks(seq_q, seq_k)
+    return flash_blocks(seq_q, seq_k)
 
 
 def _hop_fwd(q, k, v, idx, sm_scale, interpret):
@@ -316,47 +318,14 @@ def _ring_flash_vjp_bwd(axis_name, causal, sm_scale, interpret, res, do):
 _ring_flash_local.defvjp(_ring_flash_vjp_fwd, _ring_flash_vjp_bwd)
 
 
-# Platform probe cached once per process: jax.devices() can trigger backend
-# initialization, which must never happen inside a shard_map body mid-trace.
-# Only the PROBE is cached — the MODALITIES_TPU_RING_IMPL override is re-read on
-# every ring_attention() call because the graft entrypoint mutates it at runtime
-# (e.g. forcing flash_interpret for CPU equivalence tests).
-_platform_is_tpu: bool | None = None
-
-
-def _probe_tpu_platform() -> bool:
-    global _platform_is_tpu
-    if _platform_is_tpu is None:
-        _platform_is_tpu = jax.devices()[0].platform == "tpu"
-    return _platform_is_tpu
-
-
-def _ring_impl() -> str:
-    """'flash' (Pallas hops) on TPU, 'dense' elsewhere; MODALITIES_TPU_RING_IMPL
-    overrides (dense | flash | flash_interpret — the latter for CPU equivalence
-    tests of the kernel path)."""
-    import os
-
-    override = os.environ.get("MODALITIES_TPU_RING_IMPL", "").strip()
-    if override:
-        if override not in ("dense", "flash", "flash_interpret"):
-            raise ValueError(
-                f"MODALITIES_TPU_RING_IMPL={override!r}: expected dense | flash | "
-                "flash_interpret — refusing to silently fall back to a default tier"
-            )
-        return override
-    return "flash" if _probe_tpu_platform() else "dense"
-
-
-def _ring_attention_local(q, k, v, *, axis_name: str, causal: bool, sm_scale: float, impl: str):
+def _ring_attention_local(q, k, v, *, axis_name: str, causal: bool, sm_scale: float, flash: bool, interpret: bool):
     """Runs on each cp shard inside shard_map. q/k/v: [B, S_local, H(, kv), D].
-    `impl` is resolved by the caller BEFORE entering the shard_map body — the
-    tier is baked into the traced program, so changing MODALITIES_TPU_RING_IMPL
-    after a step has compiled has no effect until a retrace."""
-    if impl in ("flash", "flash_interpret"):
-        return _ring_flash_local(
-            q, k, v, axis_name, causal, sm_scale, impl == "flash_interpret"
-        )
+    `flash` (Pallas hops, `interpret`ed off a TPU) or dense hops is resolved by the
+    caller BEFORE entering the shard_map body (asking the platform can start the
+    backend, which must never happen mid-trace inside the body) and is baked into
+    the traced program."""
+    if flash:
+        return _ring_flash_local(q, k, v, axis_name, causal, sm_scale, interpret)
     return _ring_dense_local(q, k, v, axis_name=axis_name, causal=causal, sm_scale=sm_scale)
 
 
@@ -366,8 +335,8 @@ def ring_attention(
     """Context-parallel attention. q: [B, S, Hq, D], k/v: [B, S, Hkv, D], with S
     sharded over `axis_name`; all other axes left to GSPMD (shard_map auto mode).
 
-    The kernel tier (dense | flash | flash_interpret) is resolved HERE, at trace
-    time, outside the shard_map body — it is baked into the compiled program.
+    Flash hops where kernels run (`ops/tiers.py`: on a TPU), dense hops elsewhere:
+    resolved HERE, at trace time, outside the shard_map body.
     """
     from jax.sharding import PartitionSpec as P
 
@@ -378,7 +347,7 @@ def ring_attention(
     if mesh is None or axis_name not in mesh.axis_names or mesh.shape[axis_name] == 1:
         return jax.nn.dot_product_attention(q, k, v, is_causal=causal, scale=sm_scale)
 
-    impl = _ring_impl()
+    hops = dict(flash=tiers.kernels_run(), interpret=tiers.interpret())
 
     # Already inside a manual region over cp (e.g. the pp pipeline's shard_map binds
     # {pp, cp})? Then q/k/v are per-shard local and collectives over cp are legal
@@ -387,14 +356,14 @@ def ring_attention(
 
     if axis_name in manual_axes():
         return _ring_attention_local(
-            q, k, v, axis_name=axis_name, causal=causal, sm_scale=sm_scale, impl=impl
+            q, k, v, axis_name=axis_name, causal=causal, sm_scale=sm_scale, **hops
         )
 
     spec = P(None, axis_name, None, None)
     # only `cp` is manual; dp/tp stay auto so GSPMD keeps partitioning batch/heads
     fn = shard_map(
         functools.partial(
-            _ring_attention_local, axis_name=axis_name, causal=causal, sm_scale=sm_scale, impl=impl
+            _ring_attention_local, axis_name=axis_name, causal=causal, sm_scale=sm_scale, **hops
         ),
         mesh=mesh,
         in_specs=(spec, spec, spec),

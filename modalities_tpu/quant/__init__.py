@@ -1,6 +1,6 @@
 """Quantized inference subsystem (ISSUE 14, ROADMAP item 5b).
 
-Three pillars, all behind the ops/tiers.py auto/on/off discipline:
+Three pillars; the kernel among them runs by ops/tiers.py's one rule (on a TPU):
 
 - `core`    — symmetric per-channel / per-block int8 and fp8 quantize/dequantize
               primitives with explicit scale layouts (the numerics ground truth).

@@ -505,19 +505,13 @@ class TrainStepBuilder:
             # pipeline executor's, so ignore_index semantics are exact.
             target_key = loss_fn.target_key
 
-            # Pallas fused-CE tier (ops/cross_entropy.py): when the loss and model
-            # expose the fused path and the tier resolves enabled, the vocab
-            # dimension streams through VMEM and not even the [B,chunk,V] buffer
-            # exists; the chunked scan below stays the fallback tier. Resolved
-            # ONCE at build so the tier is baked at trace time, and resolution
-            # errors (malformed env) surface here, not mid-run.
-            fused_ce_tier_resolved = None
-            if hasattr(loss_fn, "fused_sum_and_count") and hasattr(model, "head_weight"):
-                from modalities_tpu.ops.cross_entropy import fused_ce_tier
+            # Pallas fused CE (ops/cross_entropy.py): where kernels run (ops/tiers.py: on a TPU) and the
+            # loss and model expose the fused path, the vocab dimension streams through VMEM and not
+            # even the [B,chunk,V] buffer exists, whatever `head_chunk`'s value; elsewhere the chunked
+            # scan below. Asked ONCE at build so the form is baked at trace time.
+            from modalities_tpu.ops import tiers
 
-                tier = fused_ce_tier(getattr(model_spec, "lm_head_fused_ce", None))
-                if tier.enabled:
-                    fused_ce_tier_resolved = tier
+            fused_ce = hasattr(loss_fn, "fused_sum_and_count") and hasattr(model, "head_weight") and tiers.kernels_run()
 
             chunk_sum_count = jax.checkpoint(
                 lambda params, hc, lc: loss_fn.sum_and_count(model.head_logits(params, hc), lc),
@@ -526,13 +520,8 @@ class TrainStepBuilder:
 
             @jax.named_scope(scopes.HEAD_LOSS)
             def _chunked_ce(params, hidden, labels):
-                if fused_ce_tier_resolved is not None:
-                    total, count = loss_fn.fused_sum_and_count(
-                        hidden,
-                        model.head_weight(params),
-                        labels,
-                        interpret=fused_ce_tier_resolved.interpret,
-                    )
+                if fused_ce:
+                    total, count = loss_fn.fused_sum_and_count(hidden, model.head_weight(params), labels)
                     return total / jnp.maximum(count, 1.0)
                 seq = hidden.shape[1]
                 if seq > head_chunk:
@@ -568,14 +557,12 @@ class TrainStepBuilder:
 
             def _row_ce(params, exits, labels):
                 """Every exit's per-row cross entropy `[T, B, S]` from the exits `[T, B, S, E]` of a looped
-                model, by the tiers of `_chunked_ce`: `T x B x S` rows of ONE fused call against one head,
+                model, by the forms of `_chunked_ce`: `T x B x S` rows of ONE fused call against one head,
                 else the chunked scan over `T x B` rows of the sequence, else the whole logits."""
                 walks, batch, seq, width = exits.shape
                 tiled = jnp.broadcast_to(labels[None], (walks, batch, seq))
-                if fused_ce_tier_resolved is not None:
-                    return loss_fn.fused_row_losses(
-                        exits, model.head_weight(params), tiled, interpret=fused_ce_tier_resolved.interpret
-                    )
+                if fused_ce:
+                    return loss_fn.fused_row_losses(exits, model.head_weight(params), tiled)
                 flat, flat_labels = exits.reshape(walks * batch, seq, width), tiled.reshape(walks * batch, seq)
                 if seq <= head_chunk:
                     return chunk_rows(params, flat, flat_labels).reshape(walks, batch, seq)

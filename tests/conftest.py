@@ -47,6 +47,30 @@ def pytest_sessionfinish(session, exitstatus):
 
 
 @pytest.fixture
+def kernels_interpreted():
+    """What this test traces takes every kernel a TPU would take, interpreted (`ops/tiers.interpreted_kernels`)."""
+    from modalities_tpu.ops import tiers
+
+    with tiers.interpreted_kernels():
+        yield
+
+
+@pytest.fixture
+def tune_table(tmp_path, monkeypatch):
+    """`tune_table({"flash_attention|*|*": {"block_q": 64, "block_k": 32}})`: this test's kernels take their blocks from
+    a table under MODALITIES_TPU_TUNE_DIR, which is how an operator gives a kernel other blocks than the shipped ones."""
+    from modalities_tpu.ops.pallas import autotune
+
+    def plant(entries):
+        monkeypatch.setenv(autotune.TUNE_DIR_ENV, str(tmp_path / "tune"))
+        autotune.save_table(tmp_path / "tune", autotune.device_kind_slug(), entries)
+        autotune.clear_cache()
+
+    yield plant
+    autotune.clear_cache()
+
+
+@pytest.fixture
 def tmp_experiment_dir(tmp_path):
     d = tmp_path / "experiments"
     d.mkdir()

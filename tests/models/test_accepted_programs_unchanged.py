@@ -1,10 +1,14 @@
-"""The five configurations the benchmark had before PR 40 trace the program they traced: with `cca_config`,
+"""The five configurations the benchmark had before PR 40 (and, since PR 43, the sixth, which PR 40 added) trace the program they traced: with `cca_config`,
 `scale_residual_merge`, `partial_rotary_factor` and the router of kind `mlp` all unset, the parameter tree and the lowered
 forward-and-backward program of each, at toy size, are what the parent commit gave, array for array. The layer scan of
 such a model carries the activations alone over no input; a block takes one argument; `apply_rope` takes its old branch.
 
 The digests were taken from `git archive 9565b4e` (the commit PR 40 started from) with this file's own `described`, and
 are the same on PR 40's tree. A later PR that changes one of these programs on purpose replaces its digest here, and says so.
+
+PR 43 (one attention ladder in `ops/attention.py`, one rule in `ops/tiers.py`) added the sixth digest, `zaya1-8b-ep2`, taken from
+`git archive 1d85786` (the commit it started from) before the three mixers' ladders moved, so that all three are pinned; the five
+stood as they were.
 
 PR 41 (a `full`-remat block keeps the flash kernel's o and lse) moved none of the five: what is kept is decided where the train
 step is traced (`spec.remat_keep_flash`, False on a model nobody planned for), and off the TPU no block holds a kernel call."""
@@ -23,6 +27,7 @@ from flax.core import meta
 from benchmark.weights_hybrid import resolved
 from modalities_tpu.models.gpt2.gpt2_model import GPT2LLM, GPT2LLMConfig
 from tests.benchmark.toy import TOY_SEQ, make_toy_root
+from tests.benchmark.toy_cca_moe import make_toy_cca_moe_root
 from tests.benchmark.toy_hybrid import make_toy_hybrid_root
 from tests.benchmark.toy_looped import make_toy_looped_root
 from tests.benchmark.toy_moe import make_toy_moe_root
@@ -30,7 +35,7 @@ from tests.benchmark.toy_swa_moe import make_toy_swa_moe_root
 
 # every configuration with the maker of its own toy root (the plain one cuts the dense decoder alone to a model that builds)
 ROOTS = {"modalities-2p7b-d6": make_toy_root, "jamba2-3b-d14": make_toy_hybrid_root, "kanana2-30b-a3b-d9": make_toy_moe_root,
-         "ouro-2p6b-t4": make_toy_looped_root, "mellum2-12b-a2p5b-d12": make_toy_swa_moe_root}
+         "ouro-2p6b-t4": make_toy_looped_root, "mellum2-12b-a2p5b-d12": make_toy_swa_moe_root, "zaya1-8b-ep2": make_toy_cca_moe_root}
 
 DIGESTS = {
     "jamba2-3b-d14": {"tree": "9b8b851d3d8af70d", "leaves": 45, "operations": 5196, "program": "6353c2870cff0a05"},
@@ -38,6 +43,7 @@ DIGESTS = {
     "mellum2-12b-a2p5b-d12": {"tree": "015f795054da947e", "leaves": 23, "operations": 3510, "program": "cb006d4edde61b15"},
     "modalities-2p7b-d6": {"tree": "993770db743a2e84", "leaves": 12, "operations": 816, "program": "6bae6b8f26550a9c"},
     "ouro-2p6b-t4": {"tree": "5ea0cce5d8a1517b", "leaves": 16, "operations": 1290, "program": "41b802438489e1d9"},
+    "zaya1-8b-ep2": {"tree": "2691cd61a4ab909f", "leaves": 35, "operations": 3171, "program": "89eec99a83418209"},
 }
 
 

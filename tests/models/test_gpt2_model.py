@@ -12,9 +12,8 @@ from modalities_tpu.models.gpt2.gpt2_model import (
     GPT2LLM,
     apply_rope,
     _rope_tables,
-    manual_attention,
-    sdpa_attention,
 )
+from modalities_tpu.ops.attention import manual_attention, sdpa_attention
 
 
 def tiny_gpt2(attn_impl="manual", **overrides):
@@ -223,7 +222,7 @@ def test_masked_attention_dropout_is_on_probabilities():
     With v = identity basis the attention output IS the probability row, so we can
     observe the dropped entries directly: each is either 0 or probs/(1-p), and the
     empirical drop fraction matches p."""
-    from modalities_tpu.models.gpt2.gpt2_model import masked_attention
+    from modalities_tpu.ops.attention import masked_attention
 
     b, s, h = 2, 16, 2
     d = s  # v one-hot basis: out[b,i,h,:] == dropped-out probs row i

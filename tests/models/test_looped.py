@@ -272,18 +272,24 @@ def test_two_adamw_steps_through_the_train_step(toy, tokens):
         assert np.allclose(got[name], norms, rtol=0.02), (name, got[name], norms)
 
 
-@pytest.mark.parametrize("tier, chunk", [("off", 32), ("off", 48), ("off", 64)])
-def test_the_chunked_and_whole_tiers_give_the_fused_tiers_numbers(toy, tokens, monkeypatch, tier, chunk):
-    """One step of the train step by each tier of `_row_ce` (the chunked scan over two chunks, over one and a tail, the
-    whole logits) against the fused tier's (`T x B x S` rows of one kernel call): loss, what the step counts and the
-    gradient's norm to float32 rounding."""
+@pytest.mark.parametrize("chunk", [32, 48, 64])
+def test_the_chunked_and_whole_tiers_give_the_fused_tiers_numbers(toy, tokens, chunk):
+    """One step of the train step by each form of `_row_ce` off the chip (the chunked scan over two chunks, over one and a
+    tail, the whole logits) against the step a TPU traces, its kernels interpreted (`T x B x S` rows of one fused call):
+    loss, what the step counts and the gradient's norm to float32 rounding."""
+    import contextlib
+
     from modalities_tpu.models.model import MixedPrecisionSpec
+    from modalities_tpu.ops import tiers
 
     _, _, params = toy
     batch = {"samples": {"input_ids": tokens[None, :, :-1]}, "targets": {"target_ids": tokens[None, :, 1:]}}
 
-    def one_step(setting, head_chunk):
-        monkeypatch.setenv("MODALITIES_TPU_FUSED_CE", setting)
+    def one_step(fused, head_chunk):
+        with tiers.interpreted_kernels() if fused else contextlib.nullcontext():
+            return stepped(head_chunk)
+
+    def stepped(head_chunk):
         model = build(lm_head_chunk_size=head_chunk).update_train_spec(mixed_precision=MixedPrecisionSpec(compute_dtype="float32"))
         opt = OptimizerFactory.get_adam_w(lr=1e-3, betas=(0.9, 0.95), eps=1e-8, weight_decay=0.1,
                                           weight_decay_groups_excluded=["embedding", "norm", "exit_gate"], wrapped_model=model)
@@ -294,7 +300,7 @@ def test_the_chunked_and_whole_tiers_give_the_fused_tiers_numbers(toy, tokens, m
             _, metrics = fns.train_step(state, fns.put_batch(batch))
         return {name: float(value) for name, value in metrics.items()}
 
-    fused, other = one_step("on", 32), one_step(tier, chunk)
+    fused, other = one_step(True, 32), one_step(False, chunk)
     assert set(fused) == set(other) >= {"loss", "grad_norm", "counter/loop_exit_ce_1", "counter/loop_expected_exit"}
     for name, value in fused.items():
         assert abs(other[name] - value) <= 1e-5 * max(1.0, abs(value)), (name, value, other[name])

@@ -149,13 +149,14 @@ def test_bfloat16_program_is_near_the_reference_forward(toy, tokens):
 
 
 @pytest.mark.parametrize("combine", ["gathers", "kernel_interpreted"])
-def test_loss_and_every_gradient_leaf(toy, tokens, combine, monkeypatch):
-    """Off the chip the sum by token is the k gathers; with the tier forced on it is the kernel `moe_combine`, interpreted,
-    over the 128 tokens as two blocks of 64: the same loss and gradients against the reference, under the same limits."""
+def test_loss_and_every_gradient_leaf(toy, tokens, combine, monkeypatch, request):
+    """Off the chip the sum by token is the k gathers; inside the tests' seam the model takes what a TPU takes, interpreted:
+    the kernel `moe_combine` over the 128 tokens as two blocks of 64 (and with it the flash kernels and the fused norms): the
+    same loss and gradients against the reference, under the same limits."""
     from modalities_tpu.ops import expert_dispatch
 
     if combine == "kernel_interpreted":
-        monkeypatch.setenv(expert_dispatch.COMBINE_TIER_ENV, "on")
+        request.getfixturevalue("kernels_interpreted")
         monkeypatch.setattr(expert_dispatch, "COMBINE_BLOCK", 64)
     assert expert_dispatch.combine_form(128, 3, 4, 128) == ("slabs" if combine == "kernel_interpreted" else "gathers")
     model, shape, params = toy

@@ -116,44 +116,55 @@ def _fake_cpu_table(tmp_path, entries):
     autotune.save_table(tmp_path, slug, entries)
 
 
-def test_table_blocks_observable_in_ce_dispatch(tmp_path, monkeypatch):
-    from modalities_tpu.ops.cross_entropy import resolve_ce_blocks
+@pytest.fixture
+def tables(tmp_path, monkeypatch, tune_table):
+    """The two places a table can lie, both empty: `plant("shipped" | "operator", entries)` puts one there
+    (the shipped directory is the package's own; the operator's is `MODALITIES_TPU_TUNE_DIR`: conftest's `tune_table`)."""
+    monkeypatch.setattr(autotune, "SHIPPED_TABLE_DIR", tmp_path / "shipped")
+    tune_table({})
 
-    monkeypatch.setenv(autotune.TUNE_DIR_ENV, str(tmp_path))
-    monkeypatch.delenv("MODALITIES_TPU_CE_BLOCK_ROWS", raising=False)
-    monkeypatch.delenv("MODALITIES_TPU_CE_BLOCK_VOCAB", raising=False)
-    _fake_cpu_table(tmp_path, {"fused_ce|*|*": {"block_rows": 64, "block_vocab": 1024}})
+    def plant(where, entries):
+        if where == "operator":
+            return tune_table(entries)
+        _fake_cpu_table(tmp_path / "shipped", entries)
+        autotune.clear_cache()
+
+    return plant
+
+
+def test_ce_blocks_operator_table_beats_shipped_beats_default(tables):
+    from modalities_tpu.ops.cross_entropy import DEFAULT_BLOCK_ROWS, DEFAULT_BLOCK_VOCAB, resolve_ce_blocks
+
+    assert resolve_ce_blocks(4096, 16384, 1024, "bfloat16") == (DEFAULT_BLOCK_ROWS, DEFAULT_BLOCK_VOCAB)
+    tables("shipped", {"fused_ce|*|*": {"block_rows": 64, "block_vocab": 1024}})
     assert resolve_ce_blocks(4096, 16384, 1024, "bfloat16") == (64, 1024)
-    # env override beats the table, per knob
-    monkeypatch.setenv("MODALITIES_TPU_CE_BLOCK_ROWS", "32")
-    assert resolve_ce_blocks(4096, 16384, 1024, "bfloat16") == (32, 1024)
+    # a size the winning entry lacks is the caller's default, not the next table's
+    tables("operator", {"fused_ce|*|*": {"block_rows": 32}})
+    assert resolve_ce_blocks(4096, 16384, 1024, "bfloat16") == (32, DEFAULT_BLOCK_VOCAB)
 
 
-def test_table_blocks_observable_in_flash_dispatch(tmp_path, monkeypatch):
-    from modalities_tpu.ops.pallas.flash_attention import env_flash_blocks
+def test_flash_blocks_operator_table_beats_shipped_beats_default(tables):
+    from modalities_tpu.ops.pallas.flash_attention import flash_blocks
 
-    monkeypatch.setenv(autotune.TUNE_DIR_ENV, str(tmp_path))
-    monkeypatch.delenv("MODALITIES_TPU_FLASH_BLOCK_Q", raising=False)
-    monkeypatch.delenv("MODALITIES_TPU_FLASH_BLOCK_K", raising=False)
-    _fake_cpu_table(tmp_path, {"flash_attention|*|*": {"block_q": 512, "block_k": 256}})
-    assert env_flash_blocks(2048, 2048, "bfloat16") == (512, 256)
-    # env override beats the table
-    monkeypatch.setenv("MODALITIES_TPU_FLASH_BLOCK_Q", "128")
-    assert env_flash_blocks(2048, 2048, "bfloat16") == (128, 256)
+    assert flash_blocks(2048, 2048, "bfloat16") == (1024, 1024)
+    tables("shipped", {"flash_attention|*|*": {"block_q": 512, "block_k": 256}})
+    assert flash_blocks(2048, 2048, "bfloat16") == (512, 256)
+    assert flash_blocks(2048, 2048, "bfloat16", backward=True) == (512, 256)  # no entry of the backward's own: the forward's
+    tables("operator", {"flash_attention|*|*": {"block_q": 128, "block_k": 256}, "flash_attention_bwd|*|*": {"block_q": 256, "block_k": 128}})
+    assert flash_blocks(2048, 2048, "bfloat16") == (128, 256)
+    assert flash_blocks(2048, 2048, "bfloat16", backward=True) == (256, 128)
     # blocks still step down to divide short sequences
-    monkeypatch.delenv("MODALITIES_TPU_FLASH_BLOCK_Q", raising=False)
-    bq, bk = env_flash_blocks(48, 48, "float32")
+    bq, bk = flash_blocks(48, 48, "float32")
     assert 48 % bq == 0 and 48 % bk == 0
 
 
-def test_table_blocks_observable_in_rmsnorm_dispatch(tmp_path, monkeypatch):
-    from modalities_tpu.ops.rmsnorm import resolve_rmsnorm_block_rows
+def test_rmsnorm_block_operator_table_beats_shipped_beats_default(tables):
+    from modalities_tpu.ops.rmsnorm import DEFAULT_BLOCK_ROWS, resolve_rmsnorm_block_rows
 
-    monkeypatch.setenv(autotune.TUNE_DIR_ENV, str(tmp_path))
-    monkeypatch.delenv("MODALITIES_TPU_RMSNORM_BLOCK_ROWS", raising=False)
-    _fake_cpu_table(tmp_path, {"fused_rmsnorm|*|*": {"block_rows": 128}})
+    assert resolve_rmsnorm_block_rows(1024, "bfloat16") == DEFAULT_BLOCK_ROWS
+    tables("shipped", {"fused_rmsnorm|*|*": {"block_rows": 128}})
     assert resolve_rmsnorm_block_rows(1024, "bfloat16") == 128
-    monkeypatch.setenv("MODALITIES_TPU_RMSNORM_BLOCK_ROWS", "16")
+    tables("operator", {"fused_rmsnorm|*|*": {"block_rows": 16}})
     assert resolve_rmsnorm_block_rows(1024, "bfloat16") == 16
 
 

@@ -194,19 +194,19 @@ def test_combine_plan_by_shapes(case):
     assert xd.combine_plan(*shape) == form
 
 
-@pytest.mark.parametrize("tier, mesh, form", [("auto", False, "gathers"), ("on", False, "slabs"), ("off", False, "gathers"), ("on", True, "gathers")],
-                         ids=["auto_off_the_chip", "forced_on", "forced_off", "forced_on_under_a_mesh"])
-def test_combine_form_by_tier_and_mesh(tier, mesh, form, monkeypatch):
-    """Off the chip `auto` keeps the parent's gathers; `on` takes the kernel (interpreted); under a mesh of several devices
-    the gathers stay whatever the tier (GSPMD partitions them; the kernel has no per-shard plan)."""
+@pytest.mark.parametrize("kernels, mesh, form", [(False, False, "gathers"), (True, False, "slabs"), (True, True, "gathers")],
+                         ids=["off_the_chip", "kernels_interpreted", "kernels_interpreted_under_a_mesh"])
+def test_combine_form_by_tier_and_mesh(kernels, mesh, form):
+    """Off the chip the parent's gathers; where kernels run (here interpreted, as the tests' seam has them) the kernel; under
+    a mesh of several devices the gathers stay (GSPMD partitions them; the kernel has no per-shard plan)."""
+    import contextlib
+
     from jax.sharding import Mesh
 
+    from modalities_tpu.ops import tiers
     from modalities_tpu.parallel.sharding import activation_rules
 
-    monkeypatch.setenv(xd.COMBINE_TIER_ENV, tier)
     shape = PLANS["train-mellum2-12b-16k: 8 of 64 held, 8 choices"][0]
-    if mesh:
-        with activation_rules((), Mesh(np.array(jax.devices()[:2]), ("dp_shard",))):
+    with tiers.interpreted_kernels() if kernels else contextlib.nullcontext():
+        with activation_rules((), Mesh(np.array(jax.devices()[:2]), ("dp_shard",))) if mesh else contextlib.nullcontext():
             assert xd.combine_form(*shape) == form
-    else:
-        assert xd.combine_form(*shape) == form

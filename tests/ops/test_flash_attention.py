@@ -8,7 +8,7 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
-from modalities_tpu.models.gpt2.gpt2_model import manual_attention
+from modalities_tpu.ops.attention import manual_attention
 from modalities_tpu.ops.pallas import flash_attention as flash
 from modalities_tpu.ops.pallas.flash_attention import pallas_flash_attention
 
@@ -325,13 +325,13 @@ def test_blocks_are_looked_up_by_both_widths_only_where_they_differ(monkeypatch)
 
     asked = []
     monkeypatch.setattr(autotune, "lookup", lambda kernel, bucket, dtype: asked.append((kernel, bucket)) or None)
-    flash.env_flash_blocks(4096, 4096, head_dim=80, head_dim_v=80)
-    flash.env_flash_blocks(4096, 4096)
-    flash.env_flash_blocks(8192, 8192, head_dim=192, head_dim_v=128)
-    flash.env_flash_blocks(4096, 4096, head_dim=192, head_dim_v=128)
+    flash.flash_blocks(4096, 4096, head_dim=80, head_dim_v=80)
+    flash.flash_blocks(4096, 4096)
+    flash.flash_blocks(8192, 8192, head_dim=192, head_dim_v=128)
+    flash.flash_blocks(4096, 4096, head_dim=192, head_dim_v=128)
     assert asked == [("flash_attention", bucket) for bucket in ("sq4096_sk4096", "sq4096_sk4096", "d192_dv128", "d192_dv128")]
     del asked[:]  # the fused backward's blocks: its own entry of the same bucket first, the forward's where it has none
-    flash.env_flash_blocks(8192, 8192, head_dim=192, head_dim_v=128, backward=True)
+    flash.flash_blocks(8192, 8192, head_dim=192, head_dim_v=128, backward=True)
     assert asked == [("flash_attention_bwd", "d192_dv128"), ("flash_attention", "d192_dv128")]
     monkeypatch.undo()
     assert autotune.lookup("flash_attention_bwd", "d192_dv128", "bfloat16", device_kind="TPU v5 lite") == {"block_q": 1024, "block_k": 1024}

@@ -13,7 +13,7 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
-from modalities_tpu.models.gpt2.gpt2_model import masked_attention
+from modalities_tpu.ops.attention import masked_attention
 from modalities_tpu.ops.pallas import flash_attention as flash
 from modalities_tpu.ops.pallas.flash_attention import pallas_flash_attention
 
@@ -168,19 +168,18 @@ def test_the_aligned_edge_tile_is_walked_in_sub_blocks():
     assert masked == [(lo, 256, lo, 256) for lo in range(0, 1024, 256)]
 
 
-def test_a_windowed_call_takes_the_blocks_an_unwindowed_call_of_its_shape_takes(monkeypatch):
+def test_a_windowed_call_takes_the_blocks_an_unwindowed_call_of_its_shape_takes(monkeypatch, tune_table):
     """No bucket of the tuning table is a window's own: the chip read the default's 1024 x 1024 fastest at window 1024 and head
-    128 (PERF.md section 6, PR 38). So the dispatcher asks for a windowed call's blocks as for any call's, an override of the
-    environment reaches both, and the plan it says holds those blocks beside the window."""
-    from modalities_tpu.ops import attention
+    128 (PERF.md section 6, PR 38). So the dispatcher asks for a windowed call's blocks as for any call's, an operator's
+    table reaches both, and the plan it says holds those blocks beside the window."""
+    from modalities_tpu.ops import attention, tiers
     from modalities_tpu.ops.pallas import autotune
 
     assert autotune.lookup("flash_attention_window", "w1024", "bfloat16", device_kind="TPU v5 lite") is None
     said = []
-    monkeypatch.setattr(attention, "on_tpu", lambda: True)
+    monkeypatch.setattr(tiers, "on_tpu", lambda: True)
     monkeypatch.setattr(flash, "pallas_flash_attention", lambda q, k, v, **kw: said.append(kw) or q)
-    monkeypatch.setenv("MODALITIES_TPU_FLASH_BLOCK_Q", "64")
-    monkeypatch.setenv("MODALITIES_TPU_FLASH_BLOCK_K", "32")
+    tune_table({"flash_attention|*|*": {"block_q": 64, "block_k": 32}})
     q = jnp.zeros((1, 128, 2, 16), jnp.bfloat16)
     for window in (None, 48):
         attention.flash_attention_or_fallback(q, q, q, causal=True, window=window)

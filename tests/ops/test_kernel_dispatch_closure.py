@@ -115,12 +115,8 @@ def _call_quant_matmul():
     import jax.numpy as jnp
 
     from modalities_tpu.ops.quant_matmul import quant_matmul_or_fallback
-    from modalities_tpu.ops.tiers import KernelTier
 
-    return quant_matmul_or_fallback(
-        jnp.ones((8, 16)), jnp.ones((16, 8), jnp.int8), jnp.ones((8,)),
-        tier=KernelTier(enabled=True, interpret=False),
-    )
+    return quant_matmul_or_fallback(jnp.ones((8, 16)), jnp.ones((16, 8), jnp.int8), jnp.ones((8,)))
 
 
 def _call_selective_scan():
@@ -142,24 +138,15 @@ def _call_moe_combine():
 
 
 @pytest.mark.parametrize(
-    "module, call",
-    [
-        ("expert_dispatch", _call_moe_combine),
-        ("selective_scan", _call_selective_scan),
-        ("attention", _call_attention),
-        ("cross_entropy", _call_fused_ce),
-        ("rmsnorm", _call_rmsnorm),
-        ("quant_matmul", _call_quant_matmul),
-    ],
+    "call",
+    [_call_moe_combine, _call_selective_scan, _call_attention, _call_fused_ce, _call_rmsnorm, _call_quant_matmul],
 )
-def test_dispatcher_raises_what_the_kernel_raises_on_a_tpu(module, call, monkeypatch, caplog):
-    """With the platform probe answering "TPU" on this CPU, the real kernel is
-    asked for a Mosaic lowering and refuses. The dispatcher hands that on: it used
-    to warn once and run the reference, which would hide a kernel the chip's
-    compiler rejects."""
-    import importlib
-
-    monkeypatch.setattr(importlib.import_module(f"modalities_tpu.ops.{module}"), "on_tpu", lambda: True)
+def test_dispatcher_raises_what_the_kernel_raises_on_a_tpu(call, monkeypatch, caplog):
+    """With the platform probe answering "TPU" on this CPU (ONE name: every dispatcher
+    asks `tiers.on_tpu` through the module), the real kernel is asked for a Mosaic
+    lowering and refuses. The dispatcher hands that on: it used to warn once and run
+    the reference, which would hide a kernel the chip's compiler rejects."""
+    monkeypatch.setattr("modalities_tpu.ops.tiers.on_tpu", lambda: True)
     with pytest.raises(ValueError, match="Only interpret mode is supported on CPU backend"):
         call()
     assert not [r for r in caplog.records if "unavailable" in r.getMessage()]
@@ -169,7 +156,7 @@ def test_dispatcher_raises_what_the_kernel_raises_on_a_tpu(module, call, monkeyp
     "probe",
     [
         "modalities_tpu.ops.tiers:on_tpu",
-        "modalities_tpu.parallel.ring_attention:_probe_tpu_platform",
+        "modalities_tpu.ops.tiers:kernels_run",
         "modalities_tpu.ops.pallas.autotune:device_kind_slug",
         "modalities_tpu.utils.mfu:get_peak_flops",
     ],
@@ -186,7 +173,5 @@ def test_platform_probe_raises_when_the_backend_does(probe, monkeypatch):
     module_name, function = probe.split(":")
     module = importlib.import_module(module_name)
     monkeypatch.setattr(jax, "devices", no_backend)
-    if hasattr(module, "_platform_is_tpu"):
-        monkeypatch.setattr(module, "_platform_is_tpu", None)  # the probe's own memo
     with pytest.raises(RuntimeError, match="Unable to initialize backend"):
         getattr(module, function)()

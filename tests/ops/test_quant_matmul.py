@@ -2,7 +2,7 @@
 ops/pallas/quant_matmul.py): interpret-mode kernel output is BITWISE equal to
 the pure-jnp reference where one tile holds N (K is never split, so the contraction
 order matches) and within a few roundings of it where N takes several tiles,
-and the tier/block resolution follows env > autotune > defaults."""
+the kernel runs where `ops/tiers.py` says, and its blocks come from the tuning table or the defaults."""
 
 import jax
 import jax.numpy as jnp
@@ -14,11 +14,8 @@ from modalities_tpu.ops.pallas.quant_matmul import (
     quant_matmul,
     reference_quant_matmul,
 )
-from modalities_tpu.ops.quant_matmul import (
-    quant_matmul_or_fallback,
-    quant_matmul_tier,
-    resolve_quant_matmul_blocks,
-)
+from modalities_tpu.ops import tiers
+from modalities_tpu.ops.quant_matmul import quant_matmul_or_fallback, resolve_quant_matmul_blocks
 from modalities_tpu.quant.core import quantize_per_channel
 
 
@@ -68,33 +65,34 @@ def test_reference_dequant_is_exactly_scaled_int_matmul():
     )
 
 
-def test_tier_resolution_and_fallback(monkeypatch):
-    monkeypatch.delenv("MODALITIES_TPU_QUANT_MATMUL", raising=False)
-    assert not quant_matmul_tier().enabled  # auto off-TPU = fallback tier
-    monkeypatch.setenv("MODALITIES_TPU_QUANT_MATMUL", "on")
-    assert quant_matmul_tier().enabled
-    monkeypatch.setenv("MODALITIES_TPU_QUANT_MATMUL", "off")
-    tier = quant_matmul_tier()
-    assert not tier.enabled
+def test_the_kernel_runs_where_the_rule_says_and_the_reference_elsewhere(monkeypatch):
+    """Off a TPU the pure-jnp expression; the kernel, interpreted, by the wrapper's keyword or inside the tests' seam:
+    counted by the calls that reach `ops/pallas/quant_matmul.quant_matmul` through the dispatcher."""
+    import modalities_tpu.ops.quant_matmul as dispatch
+
+    reached = []
+    monkeypatch.setattr(dispatch, "quant_matmul", lambda *a, **kw: reached.append(kw["interpret"]) or quant_matmul(*a, **kw))
     x, wq, scale = _case(4, 8, 6)
-    # off tier returns the pure-jnp fallback; interpret still drives the kernel
-    off = quant_matmul_or_fallback(x, wq, scale, tier=tier)
-    np.testing.assert_array_equal(np.asarray(off), np.asarray(reference_quant_matmul(x, wq, scale)))
-    kern = quant_matmul_or_fallback(x, wq, scale, tier=tier, interpret=True)
-    np.testing.assert_array_equal(np.asarray(kern), np.asarray(off))
-    monkeypatch.setenv("MODALITIES_TPU_QUANT_MATMUL", "sideways")
-    with pytest.raises(ValueError, match="MODALITIES_TPU_QUANT_MATMUL"):
-        quant_matmul_tier()
+    want = np.asarray(reference_quant_matmul(x, wq, scale))
+    np.testing.assert_array_equal(np.asarray(quant_matmul_or_fallback(x, wq, scale)), want)
+    assert not tiers.kernels_run() and reached == []
+    np.testing.assert_array_equal(np.asarray(quant_matmul_or_fallback(x, wq, scale, interpret=True)), want)
+    with tiers.interpreted_kernels():
+        assert tiers.kernels_run()
+        np.testing.assert_array_equal(np.asarray(quant_matmul_or_fallback(x, wq, scale)), want)
+    assert reached == [True, True] and not tiers.kernels_run()  # the seam closes behind itself
 
 
-def test_block_env_overrides_beat_autotune(monkeypatch):
-    monkeypatch.setenv("MODALITIES_TPU_QUANT_MM_BLOCK_M", "32")
-    monkeypatch.setenv("MODALITIES_TPU_QUANT_MM_BLOCK_N", "64")
+def test_blocks_come_from_the_tuning_table_or_the_defaults(tune_table):
+    from modalities_tpu.ops.pallas.quant_matmul import DEFAULT_BLOCK_M, DEFAULT_BLOCK_N
+
+    tune_table({})  # a table that answers for nothing
+    assert resolve_quant_matmul_blocks(4096, jnp.bfloat16) == (DEFAULT_BLOCK_M, DEFAULT_BLOCK_N)
+    tune_table({"quant_matmul|m4096|*": {"block_m": 32, "block_n": 64}, "quant_matmul|*|*": {"block_m": 64}})
     assert resolve_quant_matmul_blocks(4096, jnp.bfloat16) == (32, 64)
-    monkeypatch.delenv("MODALITIES_TPU_QUANT_MM_BLOCK_N")
-    assert resolve_quant_matmul_blocks(4096, jnp.bfloat16)[0] == 32
-    monkeypatch.setenv("MODALITIES_TPU_QUANT_MM_BLOCK_M", "notanint")
-    with pytest.raises(ValueError):
+    assert resolve_quant_matmul_blocks(512, jnp.bfloat16) == (64, DEFAULT_BLOCK_N)  # the wildcard entry, its missing size at the default
+    tune_table({"quant_matmul|m4096|*": {"block_m": "notanint"}})
+    with pytest.raises(ValueError):  # a malformed size raises: it never quietly becomes another block
         resolve_quant_matmul_blocks(4096, jnp.bfloat16)
 
 

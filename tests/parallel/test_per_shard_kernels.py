@@ -5,7 +5,6 @@ virtual CPU devices and are held, values and gradients, to their unsharded
 references. What only the chip's compiler can say — that the sharded step lowers
 at all — chip_smoke.py --chips 4 checks on four chips."""
 
-import functools
 
 import jax
 import jax.numpy as jnp
@@ -63,21 +62,21 @@ def _fused_ce_pair():
     return kernel, reference, (hidden, head)
 
 
-def _flash_pair(monkeypatch):
-    """The attention dispatcher has no interpret switch of its own: steer its
-    probe and hand it the kernel in interpret mode."""
+def _flash_pair():
+    """The attention dispatcher has no interpret switch of its own: traced inside the
+    tests' seam it takes the kernel a TPU takes, interpreted."""
     import modalities_tpu.ops.attention as attention
-    import modalities_tpu.ops.pallas.flash_attention as flash
+    from modalities_tpu.ops import tiers
 
-    monkeypatch.setattr(attention, "on_tpu", lambda: True)
-    monkeypatch.setattr(
-        flash, "pallas_flash_attention", functools.partial(flash.pallas_flash_attention, interpret=True)
-    )
     keys = jax.random.split(jax.random.PRNGKey(0), 3)
     q = jax.random.normal(keys[0], (4, 32, 4, 16))
     k = jax.random.normal(keys[1], (4, 32, 2, 16))  # GQA: q and kv heads split over tp together
     v = jax.random.normal(keys[2], (4, 32, 2, 16))
-    kernel = lambda q, k, v: (attention.flash_attention_or_fallback(q, k, v) ** 2).sum()  # noqa: E731
+
+    def kernel(q, k, v):
+        with tiers.interpreted_kernels():
+            return (attention.flash_attention_or_fallback(q, k, v) ** 2).sum()
+
     reference = lambda q, k, v: (jax.nn.dot_product_attention(q, k, v, is_causal=True) ** 2).sum()  # noqa: E731
     return kernel, reference, (q, k, v)
 
@@ -99,11 +98,11 @@ def _selective_scan_pair():
 
 
 @pytest.mark.parametrize("case", ["fused_rmsnorm", "fused_ce", "flash_attention", "selective_scan"])
-def test_kernel_per_shard_matches_unsharded_reference(case, mesh_rules, monkeypatch):
+def test_kernel_per_shard_matches_unsharded_reference(case, mesh_rules):
     kernel, reference, args = {
         "fused_rmsnorm": _rmsnorm_pair,
         "fused_ce": _fused_ce_pair,
-        "flash_attention": functools.partial(_flash_pair, monkeypatch),
+        "flash_attention": _flash_pair,
         "selective_scan": _selective_scan_pair,
     }[case]()
     argnums = tuple(range(len(args)))

@@ -106,20 +106,13 @@ def test_the_process_record_holds_a_compile_made_before_any_instance(tmp_path):
     assert 0 < events_of(telemetry)[0]["end_s"] == pytest.approx(PROCESS_COMPILES[-1].at - PROCESS_LOG.origin, abs=0.05)
 
 
-def test_flash_tile_plan_is_one_event_per_traced_shape_and_none_per_step(tmp_path, monkeypatch):
+def test_flash_tile_plan_is_one_event_per_traced_shape_and_none_per_step(tmp_path, kernels_interpreted, tune_table):
     """The attention dispatch says once, while tracing, which tiles its kernels will
     compute (`flash_tile_plan`, beside `compile`); running the step says nothing more."""
-    import functools
-
     import modalities_tpu.ops.attention as attention
     import modalities_tpu.ops.pallas.flash_attention as flash
 
-    monkeypatch.setattr(attention, "on_tpu", lambda: True)
-    monkeypatch.setattr(
-        flash, "pallas_flash_attention", functools.partial(flash.pallas_flash_attention, interpret=True)
-    )
-    monkeypatch.setenv("MODALITIES_TPU_FLASH_BLOCK_Q", "16")
-    monkeypatch.setenv("MODALITIES_TPU_FLASH_BLOCK_K", "16")
+    tune_table({"flash_attention|*|*": {"block_q": 16, "block_k": 16}})
     telemetry = Telemetry(output_folder_path=tmp_path, watchdog_deadline_s=0)
     previous = set_active_telemetry(telemetry)
     try:
@@ -140,21 +133,14 @@ def test_flash_tile_plan_is_one_event_per_traced_shape_and_none_per_step(tmp_pat
     ]
 
 
-def test_flash_tile_plan_says_which_backward_a_differentiated_call_runs(tmp_path, monkeypatch):
+def test_flash_tile_plan_says_which_backward_a_differentiated_call_runs(tmp_path, monkeypatch, kernels_interpreted, tune_table):
     """PR 31: the event names the backward the shape rule picks (`backward`: "fused", or "two_kernels" where a q
     head's dq row does not fit the budget), the fused kernel's own blocks, the bytes of dq that stay in VMEM and the fused call's counted need; a
     differentiated call of two layers of one shape, run three times, says it once, and the program holds that kernel."""
-    import functools
-
     import modalities_tpu.ops.attention as attention
     import modalities_tpu.ops.pallas.flash_attention as flash
 
-    monkeypatch.setattr(attention, "on_tpu", lambda: True)
-    monkeypatch.setattr(
-        flash, "pallas_flash_attention", functools.partial(flash.pallas_flash_attention, interpret=True)
-    )
-    monkeypatch.setenv("MODALITIES_TPU_FLASH_BLOCK_Q", "16")
-    monkeypatch.setenv("MODALITIES_TPU_FLASH_BLOCK_K", "16")
+    tune_table({"flash_attention|*|*": {"block_q": 16, "block_k": 16}})
 
     def loss(x):
         return attention.flash_attention_or_fallback(attention.flash_attention_or_fallback(x, x, x), x, x).sum()
@@ -185,31 +171,34 @@ def test_flash_tile_plan_says_which_backward_a_differentiated_call_runs(tmp_path
     assert kernels.count("name=flash_attention_bwd_dq") == 2 == kernels.count("name=flash_attention_bwd_dkv")
 
 
-@pytest.mark.parametrize("tier", ["auto", "on"], ids=["gathers_off_the_chip", "kernel_interpreted"])
-def test_moe_dispatch_plan_is_one_event_per_traced_shape_and_none_per_step(tmp_path, monkeypatch, tier):
+@pytest.mark.parametrize("kernel", [False, True], ids=["gathers_off_the_chip", "kernel_interpreted"])
+def test_moe_dispatch_plan_is_one_event_per_traced_shape_and_none_per_step(tmp_path, kernel):
     """The expert layer says once, while tracing, what its dispatch is sized for (`moe_dispatch_plan`): the tokens,
     the router's width and a token's choices, the experts held and from where, the rows its tables hold (every pair
     on held experts, each group's last tile padded), the tile, and since PR 39 the form of the sum by token
-    (`combine`: `slabs` where `combine_plan` and the tier take the kernel, which `kernels` then names; `gathers` for
+    (`combine`: `slabs` where `combine_plan` takes the kernel and kernels run, which `kernels` then names; `gathers` for
     the plain form, as off the chip and for fewer tokens than a block), the kernel's block and the (block, expert)
     pairs it can have in use at most; two expert layers of one shape and three steps of one executable say it once."""
     from tests.models.test_moe_mla import build
 
-    from modalities_tpu.ops.expert_dispatch import COMBINE_TIER_ENV, TILE, rows_for
+    import contextlib
 
-    monkeypatch.setenv(COMBINE_TIER_ENV, tier)
+    from modalities_tpu.ops import tiers
+    from modalities_tpu.ops.expert_dispatch import TILE, rows_for
+
     model = build()
     telemetry = Telemetry(output_folder_path=tmp_path, watchdog_deadline_s=0)
     previous = set_active_telemetry(telemetry)
     try:
-        params = jax.jit(model.init_params)(jax.random.PRNGKey(0))  # the initializer's dummy of 8 tokens is a shape too
-        apply = jax.jit(lambda p, t: model.apply(p, {"input_ids": t})["logits"])
-        for _ in range(3):
-            apply(params, jnp.zeros((2, 256), jnp.int32)).block_until_ready()
+        with tiers.interpreted_kernels() if kernel else contextlib.nullcontext():
+            params = jax.jit(model.init_params)(jax.random.PRNGKey(0))  # the initializer's dummy of 8 tokens is a shape too
+            apply = jax.jit(lambda p, t: model.apply(p, {"input_ids": t})["logits"])
+            for _ in range(3):
+                apply(params, jnp.zeros((2, 256), jnp.int32)).block_until_ready()
     finally:
         set_active_telemetry(previous)
     plans = [e for e in map(json.loads, telemetry.sink_path.read_text().splitlines()) if e.get("name") == "moe_dispatch_plan"]
-    kernel = tier == "on"  # 512 tokens of width 128 are two blocks of whole lane tiles; the dummy's 8 tokens are under one block
+    # with the kernel: 512 tokens of width 128 are two blocks of whole lane tiles; the dummy's 8 tokens are under one block
     assert [{k: v for k, v in e.items() if k not in ("event", "name", "rank")} for e in plans] == [
         {"tokens": tokens, "router_width": 8, "choices": 3, "experts_held": 4, "expert_offset": 2, "tile": TILE,
          "rows": rows_for(3 * tokens, 4, TILE), "kernels": ["moe_combine"] if slabs else [], "combine": "slabs" if slabs else "gathers",

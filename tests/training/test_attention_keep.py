@@ -1,10 +1,10 @@
 """Under `full` remat a block keeps the flash kernel's o and lse beside its input (PR 41): the recomputed forward has no
 use for `flash_attention*_fwd` and the backward holds ONE call of it where it held two. On a CPU the kernels run
-interpreted, through the dispatcher the model calls (`ops/attention.flash_attention_or_fallback`), at toy size; what is
+interpreted (the fixture `kernels_interpreted`: `ops/tiers.interpreted_kernels`), through the dispatcher the model calls
+(`ops/attention.flash_attention_or_fallback`), at toy size; what is
 kept is decided by `training/activation_checkpointing.attention_keep_plan`, and `Trainer._preflight_memscope` is the net
 under it."""
 
-import functools
 
 import jax
 import jax.numpy as jnp
@@ -28,13 +28,6 @@ KINDS = {
                                                          "v_head_dim": 32, "rope_theta": 1e6}}),
     "compressed_heads": ("cca", {"layer_types": ["hybrid", "hybrid"], "cca_config": {"cca_time0": 2, "cca_time1": 2}, "head_dim": 32}),
 }
-
-
-@pytest.fixture
-def kernels_interpreted(monkeypatch):
-    """The dispatcher takes the TPU's path, and the kernels it calls run interpreted."""
-    monkeypatch.setattr("modalities_tpu.ops.attention.on_tpu", lambda: True)
-    monkeypatch.setattr(flash, "pallas_flash_attention", functools.partial(flash.pallas_flash_attention, interpret=True))
 
 
 def walked(jaxpr, calls: dict, named: dict):
@@ -220,10 +213,9 @@ def test_dropping_has_the_next_trace_plan_without_keeping():
     assert traced == [True, False]
 
 
-def test_the_step_plans_while_it_is_traced_and_plans_again_once_dropped(kernels_interpreted, monkeypatch):
+def test_the_step_plans_while_it_is_traced_and_plans_again_once_dropped(kernels_interpreted):
     """Lowered, not compiled: the plan lands on the model's spec before the blocks are traced, in the build's seat and
     in the gauges; after `drop()` the same lowering traces a step that keeps nothing."""
-    monkeypatch.setattr("modalities_tpu.ops.tiers.on_tpu", lambda: True)
     import numpy as np
 
     from modalities_tpu.telemetry import Telemetry, set_active_telemetry

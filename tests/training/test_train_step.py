@@ -2,12 +2,15 @@
 sharding works across dp/tp layouts, grad accumulation invariance
 (mirrors the reference's fsdp2_parallelization equivalence suite, SURVEY.md §4)."""
 
+import contextlib
+
 import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
 
 from modalities_tpu.loss_functions import CLMCrossEntropyLoss
+from modalities_tpu.ops import tiers
 from modalities_tpu.optimizers.optimizer_factory import OptimizerFactory
 from modalities_tpu.optimizers.scheduler_factory import DummyLRScheduler
 from modalities_tpu.running_env.device_mesh import get_device_mesh
@@ -825,8 +828,8 @@ def test_tp_placement_colwise_rowwise_and_vocab():
 @pytest.mark.slow  # ~23 s; chunked-CE family — test_chunked_lm_head_loss_equivalence
 # keeps the chunked-vs-dense loss pin in tier-1; the fused kernel's interpret
 # bitwise pin rides the kernel-dispatch closure
-def test_fused_ce_matches_chunked_and_elides_logits_hlo(monkeypatch):
-    """MODALITIES_TPU_FUSED_CE=1 (interpret mode on CPU) must reproduce the
+def test_fused_ce_matches_chunked_and_elides_logits_hlo():
+    """The step a TPU traces (its kernels interpreted on CPU) must reproduce the
     chunked-scan losses AND lower to a train-step HLO without any vocab-shaped
     buffer. vocab=384 collides with no model dim (n_embd 128, swiglu 2*ffn=256,
     fused qkv 256) so a bare substring check on the stablehlo text is sound."""
@@ -839,24 +842,24 @@ def test_fused_ce_matches_chunked_and_elides_logits_hlo(monkeypatch):
     abstract = jax.tree.map(lambda x: jax.ShapeDtypeStruct(x.shape, jnp.int32), raw)
 
     losses, evals, hlos = {}, {}, {}
-    for setting in ("off", "1"):
-        monkeypatch.setenv("MODALITIES_TPU_FUSED_CE", setting)
-        model_run = tiny_gpt2("pytorch_flash", vocab_size=384)
-        model_run.with_spec_updates(lm_head_chunk_size=8)
-        fns = _builder(model_run, mesh, clip=1.0).build(seed=0)
-        state = fns.app_state_handle.state
-        ev_batch = fns.put_batch(
-            {"samples": {k: v[0] for k, v in raw["samples"].items()},
-             "targets": {k: v[0] for k, v in raw["targets"].items()}},
-            has_acc_dim=False,
-        )
-        evals[setting] = float(fns.eval_step(state, ev_batch)["loss"])
-        ls = []
-        for _ in range(3):
-            state, metrics = fns.train_step(state, fns.put_batch(raw))
-            ls.append(float(metrics["loss"]))
-        losses[setting] = ls
-        hlos[setting] = fns.lower_train_step(abstract).as_text()
+    for setting, kernels in (("off", contextlib.nullcontext()), ("1", tiers.interpreted_kernels())):
+        with kernels:
+            model_run = tiny_gpt2("pytorch_flash", vocab_size=384)
+            model_run.with_spec_updates(lm_head_chunk_size=8)
+            fns = _builder(model_run, mesh, clip=1.0).build(seed=0)
+            state = fns.app_state_handle.state
+            ev_batch = fns.put_batch(
+                {"samples": {k: v[0] for k, v in raw["samples"].items()},
+                 "targets": {k: v[0] for k, v in raw["targets"].items()}},
+                has_acc_dim=False,
+            )
+            evals[setting] = float(fns.eval_step(state, ev_batch)["loss"])
+            ls = []
+            for _ in range(3):
+                state, metrics = fns.train_step(state, fns.put_batch(raw))
+                ls.append(float(metrics["loss"]))
+            losses[setting] = ls
+            hlos[setting] = fns.lower_train_step(abstract).as_text()
 
     np.testing.assert_allclose(losses["off"], losses["1"], rtol=2e-4, atol=2e-4)
     np.testing.assert_allclose(evals["off"], evals["1"], rtol=2e-4, atol=2e-4)
@@ -927,25 +930,25 @@ def test_chunked_lm_head_ragged_tail_under_scheduled_pp():
     np.testing.assert_allclose(losses[None], losses[5], rtol=3e-4, atol=3e-4)
 
 
-def test_fused_rmsnorm_forced_matches_reference(monkeypatch):
-    """MODALITIES_TPU_FUSED_RMSNORM=1 swaps every norm in the model for the
-    Pallas kernel (interpret on CPU); training losses must match the reference
-    modules — same params, same numerics."""
+def test_fused_rmsnorm_forced_matches_reference():
+    """Where kernels run every norm in the model is the Pallas kernel (here
+    interpreted on CPU, by the tests' seam); training losses must match the
+    reference modules — same params, same numerics."""
     mesh = get_device_mesh(device_type="cpu", data_parallel_shard_degree=8, world_size=8)
     rng = np.random.default_rng(45)
     raw = _batch(rng, 1, 8, 32)
 
     losses = {}
-    for setting in ("off", "1"):
-        monkeypatch.setenv("MODALITIES_TPU_FUSED_RMSNORM", setting)
-        model_run = tiny_gpt2("pytorch_flash")
-        fns = _builder(model_run, mesh, clip=1.0).build(seed=0)
-        state = fns.app_state_handle.state
-        ls = []
-        for _ in range(3):
-            state, metrics = fns.train_step(state, fns.put_batch(raw))
-            ls.append(float(metrics["loss"]))
-        losses[setting] = ls
+    for setting, kernels in (("off", contextlib.nullcontext()), ("1", tiers.interpreted_kernels())):
+        with kernels:
+            model_run = tiny_gpt2("pytorch_flash")
+            fns = _builder(model_run, mesh, clip=1.0).build(seed=0)
+            state = fns.app_state_handle.state
+            ls = []
+            for _ in range(3):
+                state, metrics = fns.train_step(state, fns.put_batch(raw))
+                ls.append(float(metrics["loss"]))
+            losses[setting] = ls
     # the kernel's analytic dx differs from autodiff-of-reference at the 1e-5
     # level; three optimizer steps amplify that to ~1e-4
     np.testing.assert_allclose(losses["off"], losses["1"], rtol=5e-4, atol=5e-4)
