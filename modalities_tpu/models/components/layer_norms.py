@@ -30,6 +30,8 @@ class RMSLayerNormConfig(BaseModel):
     ndim: Annotated[int, Field(strict=True, ge=1)]
     epsilon: Annotated[float, Field(gt=0)] = 1e-6
     bias: bool = True
+    # the leaf `scale` is `w` of `y = norm(x) * (1 + w)` and starts at 0 (`Qwen3NextRMSNorm`, Gemma's): no bias goes with it
+    zero_centered: bool = False
 
 
 class PytorchRMSLayerNormConfig(BaseModel):
@@ -53,6 +55,7 @@ class NormSpec(BaseModel):
     eps: float
     use_bias: bool
     use_scale: bool = True
+    zero_centered: bool = False  # the scale leaf is applied as `1 + w` and starts at 0
 
     @staticmethod
     def from_wrapper_config(wrapper: Optional[LayerNormWrapperConfig | dict], default_dim: int) -> "NormSpec":
@@ -72,7 +75,10 @@ class NormSpec(BaseModel):
             )
         if wrapper.norm_type == LayerNorms.rms_norm:
             parsed = RMSLayerNormConfig(**cfg)
-            return NormSpec(kind=wrapper.norm_type, dim=parsed.ndim, eps=parsed.epsilon, use_bias=parsed.bias)
+            if parsed.zero_centered and parsed.bias:
+                raise ValueError("rms_norm: zero_centered (the scale applied as 1 + w) with a bias is not written; set bias false")
+            return NormSpec(kind=wrapper.norm_type, dim=parsed.ndim, eps=parsed.epsilon, use_bias=parsed.bias,
+                            zero_centered=parsed.zero_centered)
         parsed = PytorchRMSLayerNormConfig(**cfg)
         return NormSpec(kind=wrapper.norm_type, dim=parsed.normalized_shape, eps=parsed.eps, use_bias=False)
 
@@ -96,7 +102,10 @@ def build_norm(spec: NormSpec, name: str, dtype=None):
     from modalities_tpu.ops import tiers
 
     if tiers.kernels_run():
-        return FusedRMSNorm(epsilon=spec.eps, use_bias=spec.use_bias, use_scale=spec.use_scale, dtype=dtype, name=name)
+        return FusedRMSNorm(epsilon=spec.eps, use_bias=spec.use_bias, use_scale=spec.use_scale, dtype=dtype, name=name,
+                            zero_centered=spec.zero_centered)
+    if spec.zero_centered:
+        return ZeroCentredRMSNorm(epsilon=spec.eps, dtype=dtype, name=name)
     if spec.use_bias:
         return RMSNormWithBias(epsilon=spec.eps, name=name)
     return nn.RMSNorm(epsilon=spec.eps, use_scale=spec.use_scale, name=name, dtype=dtype)
@@ -121,6 +130,20 @@ try:  # define lazily-importable module class at module scope
             y = x32 * _lax.rsqrt((x32 * x32).mean(-1, keepdims=True) + self.epsilon)
             return (y * scale + bias).astype(dtype)
 
+    class ZeroCentredRMSNorm(_nn.Module):
+        """`y = x / sqrt(mean(x^2) + eps) * (1 + w)`, float32 inside; the leaf `scale` is `w`, from 0."""
+
+        epsilon: float = 1e-6
+        dtype: Optional[object] = None
+
+        @_nn.compact
+        def __call__(self, x):
+            from modalities_tpu.ops.rmsnorm import reference_rms_norm
+
+            scale = self.param("scale", _nn.initializers.zeros, (x.shape[-1],))
+            y = reference_rms_norm(x, scale, eps=self.epsilon, zero_centered=True)
+            return y.astype(self.dtype) if self.dtype is not None else y
+
     class FusedRMSNorm(_nn.Module):
         """RMS norm through the fused Pallas kernel (ops/pallas/fused_rmsnorm.py):
         one HBM round-trip per row block instead of ~6. Parameter names match the
@@ -130,22 +153,23 @@ try:  # define lazily-importable module class at module scope
         use_bias: bool = False
         use_scale: bool = True
         dtype: Optional[object] = None
+        zero_centered: bool = False  # the leaf is `w` of `1 + w`, from 0: one add where the wrapper reads it
 
         @_nn.compact
         def __call__(self, x):
             from modalities_tpu.ops.rmsnorm import rms_norm_or_fallback
 
-            scale = (
-                self.param("scale", _nn.initializers.ones, (x.shape[-1],)) if self.use_scale else None
-            )
+            init = _nn.initializers.zeros if self.zero_centered else _nn.initializers.ones
+            scale = self.param("scale", init, (x.shape[-1],)) if self.use_scale else None
             bias = (
                 self.param("bias", _nn.initializers.zeros, (x.shape[-1],)) if self.use_bias else None
             )
-            y = rms_norm_or_fallback(x, scale, bias, eps=self.epsilon)
+            y = rms_norm_or_fallback(x, scale, bias, eps=self.epsilon, zero_centered=self.zero_centered)
             return y.astype(self.dtype) if self.dtype is not None else y
 
 except ImportError:  # pragma: no cover
     RMSNormWithBias = None
+    ZeroCentredRMSNorm = None
     FusedRMSNorm = None
 
 

@@ -41,6 +41,7 @@ from modalities_tpu.models.components.layer_norms import (
     build_norm,
 )
 from modalities_tpu.models.gpt2.cca import CCAConfig, CCASpec, CompressedConvAttention
+from modalities_tpu.models.gpt2.gdn import COUNTERS as GDN_COUNTERS, GatedDeltaNet, GDNConfig, GDNSpec
 from modalities_tpu.models.gpt2.mla import LatentAttention, MLAConfig, MLASpec
 from modalities_tpu.models.gpt2.moe import (AUX_LOSS, BIAS_LEAF, COUNTERS, EXPERT_LOAD, SKIP_SHARE, MoE, MoEConfig, MoESpec, ffn_kinds,
                                             update_selection_bias)
@@ -101,7 +102,8 @@ class AttentionConfig(BaseModel):
 
 SLIDING, FULL = "sliding_attention", "full_attention"  # a layer's kind of attention, as `layer_types` publishes it
 HYBRID = "hybrid"  # `model_type: zaya`'s one kind of layer: compressed convolutional attention (`cca_config`), then the expert layer
-LayerType = Literal["sliding_attention", "full_attention", "hybrid"]
+LINEAR = "linear_attention"  # `model_type: qwen3_next`'s layers between the `full_attention` ones: the gated delta rule's mixer (`gdn_config`)
+LayerType = Literal["sliding_attention", "full_attention", "hybrid", "linear_attention"]
 
 
 class RopeParameters(BaseModel):
@@ -111,7 +113,7 @@ class RopeParameters(BaseModel):
     `original_max_position_embeddings` turns fewer than `beta_slow` times are divided by `factor`, those it turns
     more than `beta_fast` times are left, a linear ramp between the two bounds (rounded outwards), and cos
     and sin are both multiplied by `attention_factor` (default `0.1 ln(factor) + 1`). `partial_rotary_factor` below 1 (a
-    `hybrid` layer's; PR 40): the first `partial_rotary_factor * head_dim` channels of a head are turned, by the D' / 2
+    `hybrid` layer's, PR 40; a `full_attention` layer's, PR 44): the first `partial_rotary_factor * head_dim` channels of a head are turned, by the D' / 2
     frequencies `rope_theta^(-2n/D')` of that width D', and the rest pass as they are. A key that is not below
     (`truncate`, `mscale`, ...) is refused: a rule nobody wrote is not run under the model's name."""
 
@@ -281,15 +283,47 @@ class GPT2LLMConfig(BaseModel):
     # a state handed from layer to layer is `moe_config`'s (`router: mlp`). All unset: the tree and the program of before.
     cca_config: Optional[CCAConfig] = None
     scale_residual_merge: bool = False
+    # `model_type: qwen3_next` (PR 44). `layer_types` of `linear_attention` puts the gated delta rule's mixer (models/gpt2/gdn.py) in
+    # the mixer seat, its heads and taps in `gdn_config`; the `full_attention` layers beside them are the plain attention's
+    # (`full_attention_interval` is read into `layer_types` where the YAML is written, not here). `attn_output_gate`: `q_attn` is
+    # twice as wide, a head's query and a head's gate, and the attention's output is multiplied by `sigmoid(gate)` before `c_proj`.
+    # `rope_parameters.full_attention.partial_rotary_factor` turns the first part of a head. Zero-centred norms are a norm's own key
+    # (`zero_centered`), the gated shared expert `moe_config`'s. All unset: the tree and the program of before.
+    gdn_config: Optional[GDNConfig] = None
+    attn_output_gate: bool = False
+
+    @model_validator(mode="after")
+    def check_linear_layers(self) -> "GPT2LLMConfig":
+        linear = self.layer_types is not None and LINEAR in self.layer_types
+        if linear != (self.gdn_config is not None):
+            raise ValueError("gdn_config gives a linear_attention layer its heads and taps: layer_types of linear_attention and gdn_config go together")
+        if not linear:
+            return self
+        if set(self.layer_types) - {LINEAR, FULL}:
+            raise ValueError("layer_types: linear_attention layers stand beside full_attention layers or alone; beside sliding_attention "
+                             "or hybrid layers they are not written")
+        beside = [name for name, value in (("mla_config", self.mla_config), ("loop_config", self.loop_config), ("cca_config", self.cca_config),
+                                           ("ssm_config", self.ssm_config), ("sliding_window", self.sliding_window)) if value is not None]
+        if beside:
+            raise ValueError(f"gdn_config beside {', '.join(beside)}: the gated delta rule's mixer shares a stack with plain attention over all "
+                             "that came before, in a stack walked once; with latent or compressed attention, state-space layers, a loop or a "
+                             "window it is not written")
+        return self
 
     @model_validator(mode="after")
     def check_hybrid_layers(self) -> "GPT2LLMConfig":
         hybrid = self.layer_types is not None and HYBRID in self.layer_types
         if hybrid != (self.cca_config is not None):
             raise ValueError("cca_config gives a hybrid layer's two convolutions their taps: layer_types of hybrid and cca_config go together")
-        partial = [kind for kind, rope in (self.rope_parameters or {}).items() if rope.partial_rotary_factor != 1.0 and kind != HYBRID]
+        partial = [kind for kind, rope in (self.rope_parameters or {}).items() if rope.partial_rotary_factor != 1.0 and kind not in (HYBRID, FULL)]
         if partial:
-            raise ValueError(f"rope_parameters {partial}: partial_rotary_factor below 1 is written for hybrid layers (the cca mixer) only")
+            raise ValueError(f"rope_parameters {partial}: partial_rotary_factor below 1 is written for hybrid layers (the cca mixer) and "
+                             "full_attention layers; a sliding_attention layer's rotary turns the whole head")
+        full = (self.rope_parameters or {}).get(FULL)
+        if full is not None and full.partial_rotary_factor != 1.0:
+            head_dim = self.head_dim if self.head_dim is not None else self.n_embd // self.n_head_q
+            if int(head_dim * full.partial_rotary_factor) % 2:
+                raise ValueError("rope_parameters.full_attention.partial_rotary_factor: the rotated part of a head must be even (the rotary turns halves)")
         if not hybrid:
             return self
         if set(self.layer_types) != {HYBRID}:
@@ -509,6 +543,10 @@ class GPT2ModelSpec:
     # the scaled form; a router state handed from layer to layer is `moe.state_width`
     cca: Optional[CCASpec] = None
     scale_residual_merge: bool = False
+    # the gated delta rule's mixer in the layers whose mixer is "gdn" (`layer_types`: `linear_attention`), and the plain
+    # attention's output under a gate read off a `q_attn` twice as wide
+    gdn: Optional[GDNSpec] = None
+    attn_output_gate: bool = False
 
     @property
     def router_state_width(self) -> int:
@@ -518,8 +556,14 @@ class GPT2ModelSpec:
     @property
     def counter_row_width(self) -> int:
         """What an expert layer's block hands up a pass: `COUNTERS`, the router's columns' loads, a matrix softmax router's
-        balance term, and the mean key temperature where the block's mixer is `cca`."""
-        return len(COUNTERS) + self.moe.router_width + self.moe.counts_aux_loss + (self.cca is not None)
+        balance term, and after them the mean key temperature where the block's mixer is `cca`, or the two of `gdn.COUNTERS`
+        where the stack holds the gated delta rule's mixer (zeros in the row of a layer whose mixer is another)."""
+        return len(COUNTERS) + self.moe.router_width + self.moe.counts_aux_loss + (self.cca is not None) + self.mixer_counters
+
+    @property
+    def mixer_counters(self) -> int:
+        """The entries a row holds after the expert layer's own for the stack's `gdn` layers."""
+        return len(GDN_COUNTERS) if self.gdn is not None else 0
 
     @property
     def head_dim(self) -> int:
@@ -607,6 +651,8 @@ class GPT2ModelSpec:
                 self.rope_by_kind,
                 self.cca,
                 self.scale_residual_merge,
+                self.gdn,
+                self.attn_output_gate,
             )
         )
 
@@ -776,7 +822,11 @@ class CausalSelfAttention(nn.Module):
         spec = self.spec
         head_dim = spec.head_dim
         window = spec.sliding_window if self.kind == "swa" else None
-        q = _dense_general(spec, (spec.n_head_q, head_dim), "q_attn", ("embed", "heads", "head_dim"), x.dtype)(x)
+        q = _dense_general(spec, (spec.n_head_q, 2 * head_dim if spec.attn_output_gate else head_dim), "q_attn",
+                           ("embed", "heads", "head_dim"), x.dtype)(x)
+        gate = None
+        if spec.attn_output_gate:  # a head's `2 * head_dim` are its query, then its gate
+            q, gate = q[..., :head_dim], q[..., head_dim:]
         k = _dense_general(spec, (spec.n_head_kv, head_dim), "k_attn", ("embed", "kv_heads", "head_dim"), x.dtype)(x)
         v = _dense_general(spec, (spec.n_head_kv, head_dim), "v_attn", ("embed", "kv_heads", "head_dim"), x.dtype)(x)
 
@@ -798,8 +848,8 @@ class CausalSelfAttention(nn.Module):
             # positions in the ring come out shifted by cp_rank * S_local
             offset = cp_shard_offset(spec.context_parallel_axis, x.shape[1])
             with jax.named_scope(scopes.ROPE):
-                cos, sin = _rope_tables(head_dim, x.shape[1], spec.rope_base_freq, dtype=x.dtype, offset=offset,
-                                        rope=spec.rope_of(self.kind))
+                rope = spec.rope_of(self.kind)
+                cos, sin = _rope_tables(rotary_dim(head_dim, rope), x.shape[1], spec.rope_base_freq, dtype=x.dtype, offset=offset, rope=rope)
                 q = apply_rope(q, cos, sin)
                 k = apply_rope(k, cos, sin)
 
@@ -826,6 +876,9 @@ class CausalSelfAttention(nn.Module):
             from jax.ad_checkpoint import checkpoint_name
 
             y = checkpoint_name(y, "attn_out")
+        if gate is not None:
+            with jax.named_scope(scopes.ATTN_GATE):
+                y = (y.astype(jnp.float32) * jax.nn.sigmoid(gate.astype(jnp.float32))).astype(y.dtype)
         return self._project_out(x, y)
 
     def _decode_attention(self, x, q, k, v):
@@ -1124,7 +1177,7 @@ class GPT2Block(nn.Module):
     deterministic: bool = True
     decode: bool = False
     slot_spec: Optional[SlotDecodeSpec] = None
-    mixer: str = "attn"  # what sits in the mixer seat: "attn", "ssm", "swa" (attention under the spec's window) or "cca"
+    mixer: str = "attn"  # what sits in the mixer seat: "attn", "ssm", "swa" (attention under the spec's window), "cca" or "gdn"
     ffn: str = "mlp"  # what sits in the feed-forward seat: "mlp" or "moe"; with "moe" the block returns (x, what the layer counted)
 
     @nn.compact
@@ -1136,9 +1189,11 @@ class GPT2Block(nn.Module):
         spec = self.spec
         x = with_logical_constraint(x, ("batch", "seq", "embed"), spec)
         h = build_norm(spec.attn_norm, "attention_norm", dtype=x.dtype)(x)
-        key_temperature = None
+        key_temperature = mixer_counted = None
         if self.mixer == "cca":
             a, key_temperature = CompressedConvAttention(spec, self.deterministic, name=scopes.CCA)(h)
+        elif self.mixer == "gdn":
+            a, mixer_counted = GatedDeltaNet(spec, self.deterministic, name=scopes.GDN)(h)
         elif self.mixer == "ssm":
             a = MambaMixer(spec, name=scopes.SSM)(h)
             a = nn.Dropout(rate=spec.dropout)(a, deterministic=self.deterministic or spec.dropout == 0.0)
@@ -1174,6 +1229,8 @@ class GPT2Block(nn.Module):
             x = _ResidualMerge(name="ffn_merge")(x, m) if spec.scale_residual_merge else x + m
         if counters is not None and key_temperature is not None:
             counters = jnp.concatenate([counters, key_temperature[None]])
+        if counters is not None and spec.mixer_counters:  # a `gdn` layer's two; zeros from a layer of the same stack whose mixer is another
+            counters = jnp.concatenate([counters, mixer_counted if mixer_counted is not None else jnp.zeros((spec.mixer_counters,), jnp.float32)])
         if spec.debug_print_activations == "shape":
             jax.debug.print(
                 "block out shape=" + str(tuple(x.shape)) + " dtype=" + str(x.dtype)
@@ -1387,15 +1444,46 @@ _NO_ROUTER_STATE_ACROSS_STAGES = (
 )
 
 
+_NO_MATRIX_STATE_CACHE = (
+    "this model has layers of the gated delta rule (gdn_config, layer_types: linear_attention), and serving them needs a cache of the "
+    "convolution's last linear_conv_kernel_dim - 1 inputs and of the [value heads, key_head_dim, value_head_dim] state of every sequence "
+    "and layer beside the attention layers' keys and values, which serving/paged_cache.py and serving/engine.py do not have: it trains, "
+    "it does not decode"
+)
+_NO_STATE_ACROSS_A_SHARD_EDGE = (
+    "this model has layers of the gated delta rule (gdn_config), whose state and whose convolution over the sequence run from the row's "
+    "first position to its last: under context parallelism a shard would need the state and the last taps of the shard before (a hand-off "
+    "along the cp axis beside parallel/ring_attention.py's ring), which is not written. Run it without a cp axis."
+)
+_NO_GATE_IN_THE_CACHED_FORWARD = (
+    "this model gates its attention's output (attn_output_gate), and the cached forwards of serving (the decode, slot and paged paths "
+    "of CausalSelfAttention) do not carry the gate to the output projection: it trains, it does not decode"
+)
+
+
+def refuse_uneven_heads(spec: "GPT2ModelSpec") -> None:
+    """A tp axis that does not divide the gated delta rule's key heads or the attention's key/value heads is refused by name: the
+    rules would leave such a dim whole on every chip (`parallel/sharding.fit_spec_to_shape`) and split its neighbours."""
+    from modalities_tpu.parallel.sharding import installed_axis_size
+
+    tp = installed_axis_size("tp")
+    if spec.gdn is not None and tp > 1 and (spec.gdn.key_heads % tp or spec.n_head_kv % tp):
+        raise NotImplementedError(
+            f"a tp axis of {tp} does not divide the gated delta rule's {spec.gdn.key_heads} key heads and the attention's {spec.n_head_kv} "
+            "key/value heads: a value head's state stays with its key head, and a split that leaves one of the two whole is not written. "
+            "Run it with a tp axis that divides both, or without one.")
+
+
 KEY_TEMPERATURE = "cca_key_temperature"  # counted by a model whose mixer is `cca`: the mean of the learned key temperatures
 
-_MIXER_OF = {SLIDING: "swa", FULL: "attn", HYBRID: "cca"}  # a published layer type as the block's mixer seat names it
+_MIXER_OF = {SLIDING: "swa", FULL: "attn", HYBRID: "cca", LINEAR: "gdn"}  # a published layer type as the block's mixer seat names it
 
 
 def refuse_serving(spec: "GPT2ModelSpec") -> None:
     """A cache, or a forward that reads one, is refused by the name of what serving lacks for this model."""
     for missing, reason in ((spec.has_ssm, _NO_RECURRENT_STATE_CACHE), (spec.mla is not None, _NO_LATENT_CACHE),
                             (spec.has_window, _NO_CACHE_BY_LAYER_KIND), (spec.cca is not None, _NO_CONV_AND_SHIFT_STATE_CACHE),
+                            (spec.gdn is not None, _NO_MATRIX_STATE_CACHE), (spec.attn_output_gate, _NO_GATE_IN_THE_CACHED_FORWARD),
                             (spec.has_moe, _NO_DECODE_THROUGH_DISPATCH), (spec.loop is not None, _NO_CACHE_ENTRY_PER_WALK)):
         if missing:
             raise NotImplementedError(reason)
@@ -1656,6 +1744,10 @@ class GPT2Module(nn.Module):
             raise NotImplementedError(_NO_CONV_ACROSS_A_SHARD_EDGE)
         if spec.router_state_width and spec.pipeline_axis is not None:
             raise NotImplementedError(_NO_ROUTER_STATE_ACROSS_STAGES)
+        if spec.gdn is not None:
+            if spec.context_parallel_axis is not None:
+                raise NotImplementedError(_NO_STATE_ACROSS_A_SHARD_EDGE)
+            refuse_uneven_heads(spec)
         if spec.pipeline_axis is not None and (spec.has_moe or spec.mla is not None or len(spec.stack_runs) > 1):
             raise NotImplementedError(
                 "pipeline parallelism splits ONE stack of equal dense-decoder layers over its stages; a model whose "
@@ -1841,6 +1933,8 @@ class GPT2LLM(NNModel):
         rope_parameters: Optional[dict] = None,
         cca_config: Optional[CCAConfig | dict] = None,
         scale_residual_merge: bool = False,
+        gdn_config: Optional[GDNConfig | dict] = None,
+        attn_output_gate: bool = False,
     ):
         super().__init__(
             sample_key=sample_key,
@@ -1870,6 +1964,12 @@ class GPT2LLM(NNModel):
                 "cca_vectors": [r".*/cca/(key_temperature|conv[01]_bias)$"],
                 "residual_merge": [r".*/(attn_merge|ffn_merge)/(residual|out)_(scale|bias)$"],
                 "router_vectors": [r".*/moe/router/(down|fc1|fc2)/bias$", r".*/moe/router/(eda_gate|norm_scale)$"],
+                # what a `qwen3_next` model adds (PR 44). Matrices, which a recipe decays: the gated delta rule's three projections
+                "gdn_projections": [r".*/gdn/(qkvz|ba|out_proj)/kernel$"],
+                # and what it does not: the decay's `A_log` and `dt_bias`, the convolution's taps, the norm a head after the rule,
+                # and the shared expert's gate `w_g [d, 1]`
+                "gdn_vectors": [r".*/gdn/(A_log|dt_bias|conv_kernel|out_norm_scale)$"],
+                "shared_expert_gate": [r".*/moe/shared_gate$"],
             },
         )
         if n_head_q % n_head_kv != 0:
@@ -1933,6 +2033,8 @@ class GPT2LLM(NNModel):
             rope_by_kind=tuple(sorted((_MIXER_OF[kind], RopeSpec.from_config(rope)) for kind, rope in (rope_parameters or {}).items())),
             cca=CCASpec.from_config(cca_config) if cca_config is not None else None,
             scale_residual_merge=scale_residual_merge,
+            gdn=GDNSpec.from_config(gdn_config) if gdn_config is not None else None,
+            attn_output_gate=attn_output_gate,
         )
         self.sequence_length = sequence_length
         self.vocab_size = vocab_size
@@ -2016,6 +2118,8 @@ class GPT2LLM(NNModel):
             aux[SKIP_SHARE] = ()
         if spec.cca is not None:  # the mean key temperature of compressed convolutional attention, over heads and layers
             aux[KEY_TEMPERATURE] = ()
+        if spec.mixer_counters:  # the gated delta rule's mean decay and mean beta, over tokens, heads and its layers
+            aux.update({name: () for name in GDN_COUNTERS})
         return {**{name: () for name in COUNTERS}, EXPERT_LOAD: (spec.ffn_kinds.count("moe"), spec.moe.router_width), **aux}
 
     @property
@@ -2037,12 +2141,18 @@ class GPT2LLM(NNModel):
         loads = rows[:, len(COUNTERS): len(COUNTERS) + moe.router_width]
         counted = {COUNTERS[0]: rows[:, 0].mean(), COUNTERS[1]: rows[:, 1].max(), COUNTERS[2]: rows[:, 2].mean(), EXPERT_LOAD: loads}
         if moe.counts_aux_loss:
-            # the one thing counted that carries a gradient (`loss_from_layers`): the row's last entry, but for a key temperature after it
-            counted[AUX_LOSS] = rows[:, -1 - (self.config_spec.cca is not None)].mean()
+            # the one thing counted that carries a gradient (`loss_from_layers`): the row's last entry, but for a key temperature or
+            # a `gdn` layer's two after it
+            counted[AUX_LOSS] = rows[:, -1 - (self.config_spec.cca is not None) - self.config_spec.mixer_counters].mean()
         if moe.skip_column:
             counted[SKIP_SHARE] = jnp.mean(loads[:, -1] / jnp.maximum(jnp.sum(loads, axis=1), 1.0))
         if self.config_spec.cca is not None:
             counted[KEY_TEMPERATURE] = rows[:, -1].mean()
+        if self.config_spec.mixer_counters:  # the mean over the expert layers whose mixer is `gdn` (a row a layer, in the stack's order)
+            spec = self.config_spec
+            of_gdn = [row for row, layer in enumerate(i for i, ffn in enumerate(spec.ffn_kinds) if ffn == "moe") if spec.kinds[layer] == "gdn"]
+            for column, name in enumerate(GDN_COUNTERS, start=-len(GDN_COUNTERS)):
+                counted[name] = rows[jnp.asarray(of_gdn), column].mean() if of_gdn else jnp.zeros((), jnp.float32)
         return (out if hidden else {self.prediction_key: out}), counted
 
     def loss_from_layers(self, counted: dict):

@@ -9,7 +9,8 @@ On `x [T, d]`, with `E = n_routed_experts`, `k = num_experts_per_tok`:
     w        = s[choice] / (sum of them + 1e-20) * routed_scaling_factor     (`norm_topk_prob`)
     expert e = W_2_e(silu(W_e x) * (V_e x))        d -> moe_intermediate_size -> d
     out      = sum over the chosen experts of w * expert(x) + shared(x)
-    shared   = one SwiGLU d -> n_shared_experts * moe_intermediate_size -> d, on every token
+    shared   = one SwiGLU d -> n_shared_experts * moe_intermediate_size -> d, on every token (`shared_expert_intermediate_size`
+               gives the width where the source names it so); with `shared_expert_gate` times `sigmoid(x w_g)`, `w_g [d, 1]`
 
 `n_group` and `topk_group` other than 1 (group-limited routing) are not written and are
 refused. No token is dropped; there is no capacity.
@@ -126,6 +127,11 @@ class MoEConfig(BaseModel):
     router_hidden_size: Optional[Annotated[int, Field(strict=True, ge=1)]] = None
     use_eda: bool = False
     use_mod: bool = False
+    # `model_type: qwen3_next` / `qwen2_moe` (PR 44). `shared_expert_intermediate_size`: the shared expert's width where the source
+    # names it so (in place of `n_shared_experts`, which counts widths of `moe_intermediate_size`). `shared_expert_gate`: the shared
+    # expert's output times `sigmoid(x w_g)`, `w_g [d, 1]` without bias, a token's own scalar.
+    shared_expert_intermediate_size: Optional[Annotated[int, Field(strict=True, ge=1)]] = None
+    shared_expert_gate: bool = False
 
     @model_validator(mode="after")
     def refuse_what_is_not_written(self) -> "MoEConfig":
@@ -148,6 +154,10 @@ class MoEConfig(BaseModel):
         held = self.n_routed_experts if self.experts_held is None else self.experts_held
         if self.expert_offset + held > self.n_routed_experts:  # the skip column (`use_mod`) is a column, not an expert: nobody holds it
             raise ValueError("moe_config: expert_offset + experts_held exceeds n_routed_experts")
+        if self.shared_expert_intermediate_size is not None and self.n_shared_experts:
+            raise ValueError("moe_config: shared_expert_intermediate_size and n_shared_experts both give the shared expert its width; set one")
+        if self.shared_expert_gate and not (self.shared_expert_intermediate_size or self.n_shared_experts):
+            raise ValueError("moe_config.shared_expert_gate gates a shared expert: give shared_expert_intermediate_size (or n_shared_experts)")
         if self.router == "matrix" and (self.router_hidden_size is not None or self.use_eda or self.use_mod):
             raise ValueError("moe_config.router_hidden_size, use_eda and use_mod belong to the router of kind mlp (router: mlp); "
                              "the matrix router has no state to hand on and no skip column")
@@ -160,7 +170,7 @@ class MoEConfig(BaseModel):
             if self.num_experts_per_tok == 1 and self.norm_topk_prob:
                 raise ValueError("moe_config.norm_topk_prob with one choice a token makes every weight 1 and leaves the router "
                                  "no gradient through it: set it false")
-            if self.n_shared_experts or self.first_k_dense_replace:
+            if self.n_shared_experts or self.shared_expert_intermediate_size or self.first_k_dense_replace:
                 raise ValueError("moe_config.router mlp: a shared expert or leading dense layers beside the carried state are not written")
         return self
 
@@ -184,6 +194,7 @@ class MoESpec:
     router_hidden: int = 0
     use_eda: bool = False  # the state is handed from layer to layer
     skip_column: bool = False  # a last router column with no expert behind it
+    shared_gate: bool = False  # the shared expert's output times `sigmoid(x w_g)`
 
     @property
     def counts_aux_loss(self) -> bool:
@@ -208,7 +219,7 @@ class MoESpec:
         return cls(
             n_routed_experts=config.n_routed_experts, num_experts_per_tok=config.num_experts_per_tok,
             moe_intermediate_size=config.moe_intermediate_size,
-            shared_hidden=config.n_shared_experts * config.moe_intermediate_size,
+            shared_hidden=config.shared_expert_intermediate_size or config.n_shared_experts * config.moe_intermediate_size,
             first_k_dense_replace=config.first_k_dense_replace, routed_scaling_factor=float(config.routed_scaling_factor),
             norm_topk_prob=config.norm_topk_prob,
             experts_held=config.n_routed_experts if config.experts_held is None else config.experts_held,
@@ -216,6 +227,7 @@ class MoESpec:
             scoring_func=config.scoring_func, selection_bias=config.topk_method == "noaux_tc",
             router_aux_loss_coef=float(config.router_aux_loss_coef), router=config.router,
             router_hidden=config.router_hidden_size or 0, use_eda=config.use_eda, skip_column=config.use_mod,
+            shared_gate=config.shared_expert_gate,
         )
 
 
@@ -390,6 +402,12 @@ class MoE(nn.Module):
         out = routed.reshape(x.shape)
         if moe.shared_hidden:
             shared = _SharedExpert(spec, moe.shared_hidden, name=scopes.MOE_SHARED)(x)
+            if moe.shared_gate:
+                with jax.named_scope(scopes.MOE_SHARED_GATE):
+                    w_g = self.param("shared_gate", nn.with_logical_partitioning(nn.initializers.normal(0.02), ("embed", None)),
+                                     (width, 1), jnp.dtype(spec.param_dtype))
+                    gate = jnp.einsum("bsd,do->bso", x, w_g.astype(x.dtype), preferred_element_type=jnp.float32)
+                    shared = (shared.astype(jnp.float32) * jax.nn.sigmoid(gate)).astype(x.dtype)
             with jax.named_scope(scopes.MOE_COMBINE):
                 out = out + shared
         out = nn.Dropout(rate=spec.dropout)(out, deterministic=self.deterministic or spec.dropout == 0.0)

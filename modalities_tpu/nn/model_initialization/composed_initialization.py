@@ -28,7 +28,10 @@ NAMED_PARAMETER_INIT_GROUPS = {
         # latent attention's projections, the router and the experts' stacks (bare leaves, no `kernel` under them)
         # among them; the router's selection bias keeps its zeros (models/gpt2/moe.py)
         "weighted_layers": [r".*(q_attn|k_attn|v_attn|c_proj|c_fc|W|V|W_2|in_proj|x_proj|out_proj|q_proj|kv_a_proj|kv_b_proj|router)/kernel.*",
-                            r".*/experts/(W|V|W_2)(/[^/]*)?$", r".*wte.*", r".*wpe.*"],
+                            r".*/experts/(W|V|W_2)(/[^/]*)?$", r".*wte.*", r".*wpe.*",
+                            # the gated delta rule's two projections in (its `out_proj` is above) and the shared expert's gate; its
+                            # convolution, A_log and dt_bias keep the initial values the source publishes (models/gpt2/gdn.py)
+                            r".*/gdn/(qkvz|ba)/kernel.*", r".*/moe/shared_gate$"],
         "embedding_layers": [r".*(wte|wpe).*"],
         "projection_layers": [r".*(c_proj|W_2|out_proj)/kernel.*", r".*/experts/W_2(/[^/]*)?$"],
         "norm_layers": [r".*(norm|scale).*"],

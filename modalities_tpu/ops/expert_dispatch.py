@@ -59,6 +59,15 @@ A (block, expert) pair in use costs 2.4-2.9 us (its slab's DMA, a 256 x 272 x d 
 weighted add), 5.5 where it is a block's only one (nothing hides its DMA), a block with none 1.8
 (its zeros written); a gathered row 45-50 ns, real or not. Under uniform routing the forms meet
 at 4.5 experts held to a choice, so the line is `held <= 4 k`; lumpier routing only helps the slabs.
+
+PR 44 read the line again where a layer holds 64 of 512 experts to 10 choices of width 2048 (6.4 held to a choice; the same
+script, `--moe_shapes qwen3next`, host ms a call, TPU v5 lite), and it stands. The sum alone under uniform routing: 8.42 by
+gathers against 10.27 by slabs, as the table above says it would be. The layer's forward and backward together meet there
+(23.07 against 22.92), and the slabs win it under lumpier routing (all tokens the same ten experts with one held 16.37 against
+10.63, none held 13.21 against 7.34, all ten held 43.60 against 39.32), but a rematerialized block runs the layer's forward
+once more (14.24 against 17.03 under uniform routing), and **in the cell** (`train-qwen3next-80b-16k`, whose routing stays near
+uniform for most of a window: 1.25 pairs a token, the largest load 3 to 5 times the mean) the step read 668.7 ms with the
+gathers against 692.0 with the slabs on traced runs (`moe/combine` 56.9 against 84.4 ms), 690-693 over eighteen untraced ones.
 The products, the tiles' gathers and everything in the loops grow with the pairs held.
 
 Products take the compute dtype's operands and accumulate in float32; `silu(a) * b` is

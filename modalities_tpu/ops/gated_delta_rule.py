@@ -1,0 +1,188 @@
+"""The gated delta rule (Yang, Kautz, Hatamizadeh, arXiv 2412.06464) over a row, in plain `jax.numpy`.
+
+A value head `j` keeps a state `S [d_k, d_v]`, a matrix, decayed by a scalar a token and corrected by the
+delta rule; it reads key head `j // r` (`r = value heads / key heads`). For `t = 0 .. S-1`, from `S = 0`:
+
+    S      = exp(g_t) S                 g_t <= 0: the log of the decay
+    delta  = beta_t (v_t - S^T k_t)
+    S      = S + k_t delta^T
+    o_t    = S^T q_t
+
+`gated_delta_rule_recurrent` is that, a `lax.scan` over positions in float32: the form tests hold the other
+against. `gated_delta_rule` is the chunked form the program runs (Yang et al., arXiv 2406.06484;
+`transformers`' `torch_chunk_gated_delta_rule`), the same function of its inputs. In a chunk of `C` positions,
+`G_i = sum_{m <= i} g_m` and `D_ij = exp(G_i - G_j)` for `i >= j`, else 0:
+
+    L   = strictly_lower((beta k) k^T * D)                   [C, C]
+    T   = (I + L)^-1                                         L is nilpotent: (I - L)(I + L^2)(I + L^4) ... (I + L^(C/2))
+    U   = T (beta v)          W = T (beta k * exp(G))        [C, d_v], [C, d_k]
+    then chunk by chunk, carrying S [d_k, d_v]:
+    V'  = U - W S
+    o   = (q * exp(G)) S + lower_with_diagonal(q k^T * D) V'
+    S   = exp(G_C) S + (k * exp(G_C - G))^T V'
+
+Everything a chunk needs but the state (`L`, `T`, `U`, `W`, the lower products) is computed for all chunks at
+once, batched products on the MXU, under the scope `intra`; the pass over the chunks is one `lax.scan` under
+`state`. The row is padded to whole chunks with positions that change nothing (`k = 0`, `beta = 0`, `g = 0`) and cut
+again. The state is carried through the whole row and starts at zero with it.
+
+Precision. `g`, `G`, `D`, `L`, `T` and the carried `S` are float32, and `T` is built at precision `highest`
+(a product of matrices whose entries grow with the chunk: one bfloat16 pass would cost it three digits). The
+other products (`k k^T`, `q k^T`, `T` times its two right sides, and the three a chunk step takes with the state)
+take operands in the inputs' dtype (bfloat16 in training, `S`, `T`, `U`, `W` rounded to it where they are an
+operand) and accumulate in float32; with float32 inputs every product is float32 at `highest`.
+
+Backward. Autodiff, told what to keep (decided from `memory_analysis()` of the cell's step, PR 44: with the whole
+row's `intra` arrays kept for the backward, the float32 `[C, C]` matrices of the series among them, the step compiled
+to 18.2 GiB against the chip's 15.75). The row is walked in GROUPS of `GROUP_CHUNKS` chunks, an outer `lax.scan` that
+carries the state; a group is what is described above (its chunks' `intra` batched, then the scan over them) and is
+rematerialized (`jax.checkpoint`): the backward keeps one float32 state a group and a head (`state_bytes`) beside the
+rule's inputs, and computes a group's matrices again, one group's working set at a time. Inside a group the chunk
+step is rematerialized too (the state a chunk, for one group), the arrays the chunk scan reads are rounded to the
+operands' dtype once, and `T = (I + L)^-1` has its own rule (`dL = -T^T dT T^T`: `T` is all it keeps of the series).
+`gdn_plan` says so (`backward`).
+"""
+
+from __future__ import annotations
+
+import jax
+import jax.numpy as jnp
+
+from modalities_tpu.telemetry import scopes
+
+CHUNK = 64
+GROUP_CHUNKS = 32  # chunks a rematerialized group holds: 2,048 positions, 1,024 `[C, C]` systems at 32 heads
+HOW_T = "nilpotent_product"  # how `(I + L)^-1` is computed, for `gdn_plan`
+BACKWARD = "autodiff over rematerialized groups of chunks: a state a group kept, a group's matrices computed again"
+
+
+def state_bytes(tokens: int, value_heads: int, key_dim: int, value_dim: int, chunk: int = CHUNK, group: int = GROUP_CHUNKS) -> int:
+    """Bytes of the float32 states the backward keeps of one layer: one `[d_k, d_v]` a group of chunks and a value head."""
+    return -(-tokens // (chunk * group)) * value_heads * key_dim * value_dim * 4
+
+
+def _dot(spec: str, a, b, dtype):
+    """A product with operands in `dtype` and a float32 result; float32 operands multiply exactly."""
+    precision = jax.lax.Precision.HIGHEST if jnp.dtype(dtype) == jnp.float32 else None
+    return jnp.einsum(spec, a.astype(dtype), b.astype(dtype), precision=precision, preferred_element_type=jnp.float32)
+
+
+def _mm(a, b):
+    return jnp.matmul(a, b, precision=jax.lax.Precision.HIGHEST)
+
+
+@jax.custom_vjp
+def _unit_lower_inverse(lower):
+    """`(I + L)^-1` of a strictly lower triangular `L [..., C, C]` (float32): `L^C = 0`, so the inverse is the
+    finite product `(I - L)(I + L^2)(I + L^4) ...`, log2(C) squarings and as many products, all on the MXU.
+    Its backward is the inverse's own, `dL = -T^T dT T^T`, and keeps `T` alone of the series."""
+    size = lower.shape[-1]
+    eye = jnp.eye(size, dtype=lower.dtype)
+    inverse, power, reach = eye - lower, lower, 2  # `inverse` holds the series up to L^(reach - 1)
+    while reach < size:
+        power = _mm(power, power)
+        inverse = _mm(inverse, eye + power)
+        reach *= 2
+    return inverse
+
+
+def _unit_lower_inverse_fwd(lower):
+    inverse = _unit_lower_inverse(lower)
+    return inverse, inverse
+
+
+def _unit_lower_inverse_bwd(inverse, d_inverse):
+    transposed = jnp.swapaxes(inverse, -1, -2)
+    return (-_mm(_mm(transposed, d_inverse), transposed),)
+
+
+_unit_lower_inverse.defvjp(_unit_lower_inverse_fwd, _unit_lower_inverse_bwd)
+
+
+def _group(state, q, k, v, g, beta, chunk: int):
+    """`n` chunks from the state that came in: q, k `[B, n C, Hk, d_k]`, v `[B, n C, Hv, d_v]`, g, beta `[B, n C, Hv]`, state
+    `[B, Hk, r, d_k, d_v]` float32. Returns the state that goes on and o `[B, n C, Hv, d_v]`."""
+    b, length, hk, dk = q.shape
+    hv, dv = v.shape[2], v.shape[3]
+    r, n, dtype, f32 = hv // hk, length // chunk, v.dtype, jnp.float32
+    with jax.named_scope(scopes.GDN_INTRA):
+        # chunks lead (the scan walks them), then batch, key head, the value heads that read it
+        qc = q.reshape(b, n, chunk, hk, dk).transpose(1, 0, 3, 2, 4)  # [N, B, Hk, C, d_k]
+        kc = k.reshape(b, n, chunk, hk, dk).transpose(1, 0, 3, 2, 4)
+        vc = v.reshape(b, n, chunk, hk, r, dv).transpose(1, 0, 3, 4, 2, 5)  # [N, B, Hk, r, C, d_v]
+        gc = g.astype(f32).reshape(b, n, chunk, hk, r).transpose(1, 0, 3, 4, 2)  # [N, B, Hk, r, C]
+        bc = beta.astype(f32).reshape(b, n, chunk, hk, r).transpose(1, 0, 3, 4, 2)
+        cum = jnp.cumsum(gc, axis=-1)
+        row, col = jnp.arange(chunk)[:, None], jnp.arange(chunk)[None, :]
+        # exp of a difference that is never positive: above the diagonal it would be, and could overflow
+        decay = jnp.where(row >= col, jnp.exp(jnp.where(row >= col, cum[..., :, None] - cum[..., None, :], 0.0)), 0.0)
+        kk = _dot("nbhid,nbhjd->nbhij", kc, kc, dtype)[:, :, :, None]  # one product a key head, read by its r value heads
+        qk = _dot("nbhid,nbhjd->nbhij", qc, kc, dtype)[:, :, :, None]
+        lower = jnp.where(row > col, bc[..., :, None] * kk * decay, 0.0)
+        solve = _unit_lower_inverse(lower)  # T [N, B, Hk, r, C, C]
+        # what the chunk scan reads, rounded to the operands' dtype once
+        u = _dot("nbhrij,nbhrjd->nbhrid", solve, vc.astype(f32) * bc[..., None], dtype).astype(dtype)
+        w = _dot("nbhrij,nbhrjd->nbhrid", solve, kc.astype(f32)[:, :, :, None] * (bc * jnp.exp(cum))[..., None], dtype).astype(dtype)
+        within = (qk * decay).astype(dtype)  # lower with its diagonal: what a chunk's own keys give its queries
+        q_in = (qc.astype(f32)[:, :, :, None] * jnp.exp(cum)[..., None]).astype(dtype)  # against the state that came in
+        k_out = (kc.astype(f32)[:, :, :, None] * jnp.exp(cum[..., -1:] - cum)[..., None]).astype(dtype)  # into the state that goes on
+        carry_decay = jnp.exp(cum[..., -1])  # [N, B, Hk, r]
+
+    @jax.checkpoint
+    def step(state, per_chunk):
+        u_c, w_c, within_c, q_c, k_c, decay_c = per_chunk
+        v_new = u_c - _dot("bhrid,bhrde->bhrie", w_c, state, dtype)
+        out = _dot("bhrid,bhrde->bhrie", q_c, state, dtype) + _dot("bhrij,bhrje->bhrie", within_c, v_new, dtype)
+        state = decay_c[..., None, None] * state + _dot("bhrid,bhrie->bhrde", k_c, v_new, dtype)
+        return state, out.astype(dtype)
+
+    with jax.named_scope(scopes.GDN_STATE):
+        state, out = jax.lax.scan(step, state, (u, w, within, q_in, k_out, carry_decay))
+        out = out.transpose(1, 0, 4, 2, 3, 5).reshape(b, length, hv, dv)  # [N, B, Hk, r, C, d_v] -> [B, n C, Hv, d_v]
+    return state, out
+
+
+def gated_delta_rule(q, k, v, g, beta, *, chunk: int = CHUNK, group_chunks: int = GROUP_CHUNKS):
+    """q, k `[B, S, Hk, d_k]` (already normalised and scaled by the caller), v `[B, S, Hv, d_v]`, g and beta
+    `[B, S, Hv]` float32 (`g <= 0`). Returns o `[B, S, Hv, d_v]` in v's dtype. The chunked form (module docstring)."""
+    b, s, hk, dk = q.shape
+    hv, dv = v.shape[2], v.shape[3]
+    if hv % hk:
+        raise ValueError(f"gated_delta_rule: {hv} value heads do not share {hk} key heads evenly")
+    chunks = -(-s // chunk)
+    groups = -(-chunks // group_chunks)
+    per_group = -(-chunks // groups) * chunk  # positions a group holds: whole chunks, the groups alike
+    pad = groups * per_group - s
+    if pad:  # positions that change nothing: no key, no correction, no decay
+        q, k, v = (jnp.pad(a, ((0, 0), (0, pad), (0, 0), (0, 0))) for a in (q, k, v))
+        g, beta = (jnp.pad(a, ((0, 0), (0, pad), (0, 0))) for a in (g, beta))
+    state = jnp.zeros((b, hk, hv // hk, dk, dv), jnp.float32)
+    if groups == 1:
+        out = _group(state, q, k, v, g, beta, chunk)[1]
+    else:
+        by_group = lambda a: jnp.moveaxis(a.reshape(b, groups, per_group, *a.shape[2:]), 1, 0)  # noqa: E731
+        one = jax.checkpoint(lambda state, xs: _group(state, *xs, chunk))
+        with jax.named_scope(scopes.GDN_STATE):  # the carry from group to group; a group names its own two scopes inside
+            _, out = jax.lax.scan(one, state, tuple(by_group(a) for a in (q, k, v, g, beta)))
+        out = jnp.moveaxis(out, 0, 1).reshape(b, groups * per_group, hv, dv)
+    return out[:, :s] if pad else out
+
+
+def gated_delta_rule_recurrent(q, k, v, g, beta):
+    """The recurrence of the module docstring position by position, float32: what the chunked form is held against."""
+    b, s, hk, dk = q.shape
+    hv, dv = v.shape[2], v.shape[3]
+    r, f32 = hv // hk, jnp.float32
+    q, k = (jnp.repeat(a.astype(f32), r, axis=2) for a in (q, k))  # value head j reads key head j // r
+    highest = jax.lax.Precision.HIGHEST
+
+    def step(state, at):
+        q_t, k_t, v_t, g_t, beta_t = at  # [B, Hv, d], [B, Hv]
+        state = state * jnp.exp(g_t)[..., None, None]
+        delta = beta_t[..., None] * (v_t - jnp.einsum("bhkv,bhk->bhv", state, k_t, precision=highest))
+        state = state + k_t[..., :, None] * delta[..., None, :]
+        return state, jnp.einsum("bhkv,bhk->bhv", state, q_t, precision=highest)
+
+    by_position = lambda a: jnp.moveaxis(a.astype(f32), 1, 0)  # noqa: E731
+    _, out = jax.lax.scan(step, jnp.zeros((b, hv, dk, dv), f32), tuple(by_position(a) for a in (q, k, v, g, beta)))
+    return jnp.moveaxis(out, 0, 1).astype(v.dtype)

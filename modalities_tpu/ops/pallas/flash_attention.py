@@ -539,9 +539,11 @@ def flash_blocks(seq_q: int, seq_k: int, dtype="bfloat16", head_dim: int | None 
     stepped down to divide the sequence.
 
     The table's bucket is the two sequence lengths; where v is not as wide as q and k
-    (latent attention) it is the two widths instead (`d192_dv128`), whatever the sequence:
-    what fits VMEM depends on the blocks and the widths alone, and at 192/128 `bwd_dq`
-    asks 17.27 MiB of the 16 at 1024 x 1024, at 2 x 8192 and at 4 x 4096 alike.
+    (latent attention) or a head is wider than a lane tile of 128 (PR 44: heads of 256) it is
+    the two widths instead (`d192_dv128`, `d256_dv256`), whatever the sequence: what fits VMEM
+    depends on the blocks and the widths alone, and at 192/128 `bwd_dq` asks 17.27 MiB of the
+    16 at 1024 x 1024, at 2 x 8192 and at 4 x 4096 alike. Heads of 128 and narrower keep the
+    bucket of their sequence lengths.
 
     `backward=True` asks for the fused backward's blocks: the table's `flash_attention_bwd`
     entry of the same bucket where it has one (192/128 on a v5e: 1024 x 1024, which that
@@ -554,7 +556,7 @@ def flash_blocks(seq_q: int, seq_k: int, dtype="bfloat16", head_dim: int | None 
     from modalities_tpu.ops.pallas import autotune
 
     bucket = f"sq{autotune.shape_bucket(seq_q)}_sk{autotune.shape_bucket(seq_k)}"
-    if head_dim is not None and head_dim_v is not None and head_dim != head_dim_v:
+    if head_dim is not None and head_dim_v is not None and (head_dim != head_dim_v or head_dim > _LANES):
         bucket = f"d{head_dim}_dv{head_dim_v}"
     kernels = ("flash_attention_bwd", "flash_attention") if backward else "flash_attention"
     block_q, block_k = autotune.blocks(kernels, bucket, dtype, block_q=1024, block_k=1024)

@@ -30,10 +30,12 @@ def resolve_rmsnorm_block_rows(n_embd: int, dtype) -> int:
 _ROW_AXES = {3: ("batch", "seq_sp", None), 4: ("batch", "seq", "heads", None)}
 
 
-def rms_norm_or_fallback(x, scale=None, bias=None, *, eps: float = 1e-6, interpret: bool = False):
+def rms_norm_or_fallback(x, scale=None, bias=None, *, eps: float = 1e-6, interpret: bool = False, zero_centered: bool = False):
     """Single-HBM-round-trip RMSNorm. Whatever the kernel raises is raised, on a
     TPU as in interpret mode (tests): there is no reference tier behind it. Under
-    a mesh the kernel runs per shard of the rows (parallel/sharding.per_shard)."""
+    a mesh the kernel runs per shard of the rows (parallel/sharding.per_shard).
+    `zero_centered`: `scale` is `w` of `y = norm(x) * (1 + w)`; the one is added here, where the
+    scale is read, and the kernel (whose `dscale` is `dw`) is the same."""
     from modalities_tpu.ops.pallas.fused_rmsnorm import fused_rms_norm
     from modalities_tpu.parallel.sharding import per_shard
 
@@ -45,7 +47,7 @@ def rms_norm_or_fallback(x, scale=None, bias=None, *, eps: float = 1e-6, interpr
     )
     # the identity params the kernel would make for itself, made here so that
     # every shard is handed the same three operands
-    scale = jnp.ones((x.shape[-1],), jnp.float32) if scale is None else scale
+    scale = jnp.ones((x.shape[-1],), jnp.float32) if scale is None else scale + 1.0 if zero_centered else scale
     bias = jnp.zeros((x.shape[-1],), jnp.float32) if bias is None else bias
     x_axes = _ROW_AXES.get(x.ndim, ("batch",) + (None,) * (x.ndim - 1))
     return per_shard(
@@ -53,12 +55,12 @@ def rms_norm_or_fallback(x, scale=None, bias=None, *, eps: float = 1e-6, interpr
     )(x, scale, bias)
 
 
-def reference_rms_norm(x, scale=None, bias=None, *, eps: float = 1e-6):
-    """Same math as layer_norms.RMSNormWithBias: the parity tests' oracle."""
+def reference_rms_norm(x, scale=None, bias=None, *, eps: float = 1e-6, zero_centered: bool = False):
+    """Same math as layer_norms.RMSNormWithBias: the parity tests' oracle. `zero_centered`: times `1 + scale`."""
     x32 = x.astype(jnp.float32)
     y = x32 * jax.lax.rsqrt((x32 * x32).mean(axis=-1, keepdims=True) + eps)
     if scale is not None:
-        y = y * scale
+        y = y * (scale + 1.0 if zero_centered else scale)
     if bias is not None:
         y = y + bias
     return y.astype(x.dtype)

@@ -369,3 +369,9 @@ def installed_mesh_size() -> int:
     has a form for one device only and leaves the other to GSPMD (`ops/expert_dispatch.combine_form`)."""
     state = getattr(_ACTIVATION_RULES, "state", None)
     return int(state[1].size) if state else 1
+
+
+def installed_axis_size(name: str) -> int:
+    """The size of mesh axis `name` in the mesh the step installed with `activation_rules`; 1 with none installed or no such axis."""
+    state = getattr(_ACTIVATION_RULES, "state", None)
+    return int(state[1].shape.get(name, 1)) if state else 1

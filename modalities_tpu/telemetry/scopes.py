@@ -73,6 +73,22 @@ in a block's mixer seat; `cca/rope` (the rotary on the rotated part of a head) a
     CCA_VALUE_SHIFT   value_shift       the half of the value heads that is read off the previous position
     CCA_OUT           out               the output projection back to the residual's width (`cca/out/c_proj`)
 
+The gated delta rule's mixer (`gdn_config`, a `linear_attention` layer's; `models/gpt2/gdn.py`, `ops/gated_delta_rule.py`), under the
+module name `gdn` in a block's mixer seat:
+
+    GDN_IN_PROJ       in_proj           the two projections of the block's normed input: q, k, v and z together (`gdn/in_proj/qkvz`), b and a (`gdn/in_proj/ba`)
+    GDN_CONV          conv              the causal depthwise convolution over q, k and v together, and its SiLU
+    GDN_GATES         gates             `beta = sigmoid(b)` and the log of the decay `g = -exp(A_log) softplus(a + dt_bias)`, float32
+    GDN_QK_NORM       qk_norm           the L2 norm of every head of q and k, float32, and q's `1 / sqrt(d_k)`
+    GDN_RULE          rule              the chunked rule; under it:
+    GDN_INTRA         intra             what a chunk needs but the state, for all chunks at once: `L`, `T = (I + L)^-1`, `U`, `W`, the lower products
+    GDN_STATE         state             the scan over the chunks that carries the `[d_k, d_v]` state a head
+    GDN_OUT_NORM      out_norm          the RMS norm a head of the rule's output, times `silu(z)` (the name of the norm's module)
+    GDN_OUT           out               the output projection back to the residual's width (`gdn/out/out_proj`)
+
+Attention with an output gate (`attn_output_gate`): `attn/gate` holds the sigmoid of the gate half of `q_attn` times the attention's output.
+An expert layer whose shared expert is gated (`shared_expert_gate`): `moe/shared_gate` holds the `[d, 1]` product, its sigmoid and the multiply.
+
 The router of kind `mlp` (`moe_config.router: mlp`; `models/gpt2/moe.py`), inside `moe/router`:
 
     ROUTER_DOWN       down              the projection of the layer's input to the router's state (the name of its module)
@@ -164,6 +180,19 @@ ROUTER_DOWN = "down"  # inside `moe/router`, a router of kind `mlp`
 ROUTER_EDA = "eda"
 ROUTER_MLP = "mlp"
 
+GDN = "gdn"  # the mixer's module name in the block's seat (a `linear_attention` layer)
+GDN_IN_PROJ = "in_proj"
+GDN_CONV = "conv"
+GDN_GATES = "gates"
+GDN_QK_NORM = "qk_norm"
+GDN_RULE = "rule"
+GDN_INTRA = "intra"
+GDN_STATE = "state"
+GDN_OUT_NORM = "out_norm"
+GDN_OUT = "out"
+ATTN_GATE = "gate"  # inside `attn`, where the attention's output is gated (`attn_output_gate`)
+MOE_SHARED_GATE = "shared_gate"  # inside `moe`, where the shared expert is gated (`shared_expert_gate`)
+
 UPDATE_SCOPES = (GRAD_ACCUMULATE, GRAD_NORM, CLIP, OPTIMIZER, APPLY_UPDATES, ANOMALY_SELECT, STEP_METRICS)
 MODEL_SCOPES = (WTE, ROPE, ATTN_CORE, RESIDUAL, LAYER_CARRY)
 SSM_SCOPES = (SSM_CONV, SSM_SCAN, SSM_GATE)  # on the step only where a layer holds the state-space mixer
@@ -176,6 +205,7 @@ MOE_SCOPES = (MOE_ROUTER, MOE_DISPATCH, MOE_EXPERTS, MOE_SHARED, MOE_COMBINE)  #
 
 CCA_SCOPES = (CCA_LATENT, CCA_CONV, CCA_QK_MEAN, CCA_QK_NORM, CCA_VALUE_SHIFT, CCA_OUT)  # on the step only where a layer's mixer is `cca`
 ROUTER_MLP_SCOPES = (ROUTER_DOWN, ROUTER_EDA, ROUTER_MLP)  # on the step only where the router is an MLP over a carried state
+GDN_SCOPES = (GDN_IN_PROJ, GDN_CONV, GDN_GATES, GDN_QK_NORM, GDN_RULE, GDN_INTRA, GDN_STATE, GDN_OUT_NORM, GDN_OUT)  # on the step only where a layer's mixer is `gdn`
 
 # what a path holds beside scopes: the jit wrapper, the plumbing of loops, calls and branches
 _PLUMBING = frozenset(("while", "body", "cond", "closed_call", "checkpoint"))

@@ -69,6 +69,14 @@ class GPT2MFUCalculator(MFUCalculatorIF):
     of the held experts a token passes `num_experts_per_tok * experts_held / columns`, the router's skip column among the
     columns (a token that picks it passes none). The tied head is `6 E V`, the table's own count.
 
+    A layer of the gated delta rule (`gdn_config`) is counted as it is held: its three projections are parameters a token
+    multiplies (in `6N`), its convolution's taps too (a multiply-add a tap and a channel), and the chunked rule's products a
+    chunk and a value head (`k k^T`, `q k^T`, `T` against its two right sides, the three products with the state and the lower
+    product; the nilpotent series that builds `T` beside them) come to `gdn_rule_flops_per_token` forward, times 3 for a step.
+    Attention whose output is gated holds the gate's half of `q_attn` in `6N`; its scores are the `12 L s h` term at
+    `n_head_q * head_dim`. The shared expert, its gate and the router are in `6N` whole; of the held experts a token passes
+    `num_experts_per_tok * experts_held / columns`.
+
     A looped model (`loop_config`) uses a parameter once for every walk, and `6N` would count it
     once: its required operations are `6 x a layer's kernels x L x T` + `6 x T x L x s x h` (the
     causal half of attention, a layer application) + `6 x T x E x V` (the head, once an exit) a
@@ -113,6 +121,8 @@ class GPT2MFUCalculator(MFUCalculatorIF):
             self.active_parameters = self.num_parameters - expert_layers * (moe.experts_held - chosen_here) * expert
         if mla is not None:
             self.attention_width = spec.n_head_q * (mla.qk_head_dim + mla.v_head_dim)
+        gdn = getattr(spec, "gdn", None)
+        self.rule_flops_per_token = kinds.count("gdn") * gdn_rule_flops_per_token(gdn) if gdn is not None else 0.0
         self.looped_flops_per_token = None
         loop = getattr(spec, "loop", None)
         if loop is not None:
@@ -124,9 +134,23 @@ class GPT2MFUCalculator(MFUCalculatorIF):
 
     def compute(self, tokens_per_second: float) -> float:
         flops_per_token = self.looped_flops_per_token or (
-            6 * self.active_parameters
+            6 * self.active_parameters + 3 * self.rule_flops_per_token
             + 6 * (self.n_attention_layer * self.sequence_length + self.window_positions) * self.attention_width)
         return tokens_per_second * flops_per_token / (self.world_size * self._peak)
+
+
+def gdn_rule_flops_per_token(gdn, chunk: int = 64) -> float:
+    """Forward operations a token of one layer's chunked gated delta rule (`ops/gated_delta_rule.py`), beside its projections
+    and taps (which are parameters): a chunk of `C` positions and a value head take `k k^T` and `q k^T` (`2 C^2 d_k` each, once a
+    key head: divided by the heads that share it), the nilpotent series for `T` (`2 log2(C) - 1` products of `[C, C]`), `T`
+    against `[beta v | beta k exp(G)]` (`2 C^2 (d_k + d_v)`), `W S`, `q S` and `k^T V'` (`2 C d_k d_v` each) and the lower
+    product (`2 C^2 d_v`); divided by `C` and summed over the value heads."""
+    import math
+
+    c, dk, dv, share = chunk, gdn.key_dim, gdn.value_dim, gdn.value_heads // gdn.key_heads
+    a_head = (2 * 2 * c * c * dk / share + (2 * int(math.log2(c)) - 1) * 2 * c ** 3 + 2 * c * c * (dk + dv)
+              + 3 * 2 * c * dk * dv + 2 * c * c * dv)
+    return gdn.value_heads * a_head / c
 
 
 def _count_params(model) -> Optional[int]:

@@ -107,6 +107,7 @@ def flash_part(args, interpret: bool) -> None:
 MOE_SHAPES = {
     "mellum2": (16384, 2304, 896, 64, 8, 8),  # benchmark/configs/mellum2-12b-a2p5b-d12
     "kanana2": (16384, 2048, 768, 128, 6, 16),  # benchmark/configs/kanana2-30b-a3b-d9
+    "qwen3next": (16384, 2048, 512, 512, 10, 64),  # benchmark/configs/qwen3-next-80b-a3b-d4 (PR 44): 6.4 experts held to a choice, over `combine_plan`'s line
 }
 
 

@@ -146,9 +146,11 @@ def test_what_is_not_written_is_refused_at_config_time(changes, match):
 
 
 def test_a_partial_rotary_is_the_hybrid_layers_alone():
-    plain = {**{k: v for k, v in TOY.items() if k not in ("cca_config", "scale_residual_merge", "moe_config")}, "layer_types": ["full_attention"] * LAYERS}
+    """Since PR 44 a `full_attention` layer's too (the quarter-head rotary of `qwen3_next`); a window layer's stays refused."""
+    plain = {k: v for k, v in TOY.items() if k not in ("cca_config", "scale_residual_merge", "moe_config")}
     with pytest.raises(ValueError, match="written for hybrid layers"):
-        GPT2LLMConfig(**{**plain, "rope_parameters": {"full_attention": ROPE["hybrid"]}})
+        GPT2LLMConfig(**{**plain, "layer_types": ["sliding_attention"] * LAYERS, "sliding_window": 16, "rope_parameters": {"sliding_attention": ROPE["hybrid"]}})
+    GPT2LLMConfig(**{**plain, "layer_types": ["full_attention"] * LAYERS, "rope_parameters": {"full_attention": ROPE["hybrid"]}})
 
 
 # ------------------------------------------------------------------ refused by name where it is not written
@@ -252,13 +254,13 @@ def test_the_whole_mixer_and_its_gradients_are_the_references(toy):
     h = jnp.asarray(np.random.default_rng(1).normal(size=(2, 64, 128)), jnp.float32)
     apply, leaves = mixer_of(model, params, 1)
     w = layer_of(params, 1)
-    with HIGHEST:
-        got = apply(leaves, h)
-        want = jax.vmap(lambda row: reference.attention(row, w, shape))(h)
+    with HIGHEST:  # each side one jitted program (PR 44: op by op, these four calls took 66 s of the suite's clock)
+        got = jax.jit(apply)(leaves, h)
+        want = jax.jit(jax.vmap(lambda row, w: reference.attention(row, w, shape), in_axes=(0, None)))(h, w)
         assert float(jnp.abs(want).max()) > 0.05 and float(jnp.abs(got - want).max()) < 1e-5
         probe = jnp.asarray(np.random.default_rng(2).normal(size=got.shape), jnp.float32)
-        got_dw, got_dh = jax.grad(lambda l, h: jnp.sum(apply(l, h) * probe), argnums=(0, 1))(leaves, h)
-        want_dw, want_dh = jax.grad(lambda w, h: jnp.sum(jax.vmap(lambda row: reference.attention(row, w, shape))(h) * probe), argnums=(0, 1))(w, h)
+        got_dw, got_dh = jax.jit(jax.grad(lambda l, h: jnp.sum(apply(l, h) * probe), argnums=(0, 1)))(leaves, h)
+        want_dw, want_dh = jax.jit(jax.grad(lambda w, h: jnp.sum(jax.vmap(lambda row: reference.attention(row, w, shape))(h) * probe), argnums=(0, 1)))(w, h)
     assert float(jnp.abs(got_dh - want_dh).max()) < 1e-4 * float(jnp.abs(want_dh).max())
     named = {**{name: got_dw[name]["kernel"] for name in ("q_attn", "k_attn", "v_attn", "v_attn_prev", "c_proj")},
              **{name: got_dw[name] for name in ("conv0_kernel", "conv0_bias", "conv1_kernel", "conv1_bias", "key_temperature")}}
@@ -349,9 +351,9 @@ def test_scanned_rematerialized_and_unrolled_the_stack_computes_the_same(toy, to
         got_params = {"params": {**{k: v for k, v in params["params"].items() if k != "run_0"},
                                  **{f"h_{i}": jax.tree.map(lambda v, i=i: v[i], block) for i in range(LAYERS)}}}
     ref = reference_layout(params)
-    with HIGHEST:
-        want = reference.batch_loss(ref, jnp.asarray(tokens[:, :-1]), jnp.asarray(tokens[:, 1:]), shape)
-        got, grads = jax.value_and_grad(lambda p: program_loss(other, p, tokens))(got_params)
+    with HIGHEST:  # each side one jitted program (PR 44: op by op, the three cases took 70 s of the suite's clock)
+        want = jax.jit(lambda ref: reference.batch_loss(ref, jnp.asarray(tokens[:, :-1]), jnp.asarray(tokens[:, 1:]), shape))(ref)
+        got, grads = jax.jit(jax.value_and_grad(lambda p: program_loss(other, p, tokens)))(got_params)
     assert abs(float(got) - float(want)) < 2e-5 * float(want)
     assert all(np.all(np.isfinite(np.asarray(g))) for g in jax.tree.leaves(grads))
 
@@ -400,7 +402,8 @@ def test_the_layer_by_layer_gradient_is_the_whole_models(toy, tokens):
     inputs, targets = tokens[:, :-1], tokens[:, 1:]
     layers = [layer_of(params, i) for i in range(LAYERS)]
     loss, (grads, outer_grads), loads = reference.loss_and_gradients(shape, layers, {name: ref_params[name] for name in reference.OUTER}, inputs, targets)
-    want, want_grads = jax.value_and_grad(lambda p: reference.batch_loss(p, jnp.asarray(inputs), jnp.asarray(targets), shape))(ref_params)
+    # one jitted program (PR 44: op by op this call took most of the test's 27 s)
+    want, want_grads = jax.jit(jax.value_and_grad(lambda p: reference.batch_loss(p, jnp.asarray(inputs), jnp.asarray(targets), shape)))(ref_params)
     assert abs(loss - float(want)) < 1e-5 and loads.shape == (LAYERS, 9)
     named = reference.by_run(shape, grads, outer_grads)
     for name, leaf in want_grads["runs"][0].items():
@@ -424,11 +427,16 @@ def test_two_steps_of_adamw_and_the_bias_rule_follow_the_reference(tokens):
     assert decayed == set(reference.DECAYED) and not reference_layout(mask)["wte"] and not reference_layout(mask)["final_norm"]
     tx = optax.chain(optax.clip_by_global_norm(1.0), optax.adamw(1.6e-4, b1=0.9, b2=0.95, eps=1e-8, weight_decay=0.1, mask=mask))
     params, opt_state, losses = seeded, tx.init(seeded), []
+
+    @jax.jit  # one compiled step for both batches (PR 44: op by op, the two steps took 70 s of the suite's clock)
+    def step(params, opt_state, batch):
+        (loss, counted), grads = jax.value_and_grad(lambda p: program_loss(model, p, batch, True), has_aux=True)(params)
+        updates, opt_state = tx.update(grads, opt_state, params)
+        return model.after_update(optax.apply_updates(params, updates), counted), opt_state, loss
+
     with HIGHEST:
         for batch in batches:
-            (loss, counted), grads = jax.value_and_grad(lambda p: program_loss(model, p, batch, True), has_aux=True)(params)
-            updates, opt_state = tx.update(grads, opt_state, params)
-            params = model.after_update(optax.apply_updates(params, updates), counted)
+            params, opt_state, loss = step(params, opt_state, batch)
             losses.append(float(loss))
         want = reference.train_steps(shape, SEED, [(b[:, :-1], b[:, 1:]) for b in batches], hyper)
     assert losses == pytest.approx(want["losses"], rel=3e-5)
@@ -454,13 +462,13 @@ def test_the_shares_parts_add_up_to_the_uncut_layer(toy):
     lifted = router["e_score_correction_bias"].at[8].add(float(jnp.quantile(lacking, 0.25)))
     router, w = {**router, "e_score_correction_bias": lifted}, {**w, reference.BIAS: lifted}
     with HIGHEST:
-        want, load, want_state = jax.vmap(lambda row, state: reference.expert_layer(row, w, state, whole))(x, previous)
+        want, load, want_state = jax.jit(jax.vmap(lambda row, state: reference.expert_layer(row, w, state, whole)))(x, previous)  # jitted, as each share's below (PR 44: the suite's clock)
         load = load.sum(axis=0)
         total, held = jnp.zeros_like(want), []
         for offset in (0, 4):
             part = build(moe_config={**MOE, "expert_offset": offset}).with_spec_updates(compute_dtype="float32")
             leaves = {"router": router, "experts": {n: w[f"experts_{n}"][offset: offset + 4] for n in ("W", "V", "W_2")}}
-            out, counters, state = MoE(part.config_spec).apply({"params": leaves}, x, previous)
+            out, counters, state = jax.jit(lambda leaves, x, previous, part=part: MoE(part.config_spec).apply({"params": leaves}, x, previous))(leaves, x, previous)
             total, held = total + out, held + [float(counters[0])]
             assert np.asarray(counters[3:12]).tolist() == np.asarray(load).tolist(), "every share counts all 9 columns' loads"
             np.testing.assert_allclose(np.asarray(state), np.asarray(want_state), atol=1e-5)

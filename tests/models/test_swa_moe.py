@@ -175,18 +175,25 @@ def logits_of(model, params, tokens):
         return np.asarray(jax.jit(lambda p, t: model.apply(p, {"input_ids": t})["logits"])(params, jnp.asarray(tokens[:, :-1])), np.float32)
 
 
-def test_float32_program_is_the_reference_forward(toy, tokens):
+@pytest.fixture(scope="module")
+def reference_logits(toy, tokens):
+    """The reference's logits on the seeded weights, once for the three tests that hold a program against them (PR 44: each
+    computed them again, 19 s of the suite's clock a time)."""
+    return np.asarray(reference.logits_layer_by_layer(toy[1], SEED, tokens[:, :-1]))
+
+
+def test_float32_program_is_the_reference_forward(toy, tokens, reference_logits):
     model, shape, params = toy
-    want = np.asarray(reference.logits_layer_by_layer(shape, SEED, tokens[:, :-1]))
+    want = reference_logits
     assert want.std() > 0.1 and np.abs(logits_of(model, params, tokens) - want).max() < 1e-5
 
 
 @pytest.mark.parametrize("broken, changes", [("the window dropped", {"sliding_window": 64}),
                                              ("plain rotary on the global layers", {"rope_parameters": {"full_attention": {"rope_type": "default", "rope_theta": 500000}}})])
-def test_a_program_without_the_window_or_without_yarn_is_another_model(toy, tokens, broken, changes):
+def test_a_program_without_the_window_or_without_yarn_is_another_model(toy, tokens, reference_logits, broken, changes):
     _, shape, params = toy
     other = build(**changes).with_spec_updates(compute_dtype="float32")
-    want = np.asarray(reference.logits_layer_by_layer(shape, SEED, tokens[:, :-1]))
+    want = reference_logits
     assert np.abs(logits_of(other, params, tokens) - want).max() > 1e-3, broken
 
 
@@ -233,7 +240,8 @@ def test_the_layer_by_layer_gradient_is_the_whole_models(tokens):
     params = reference.reference_params(shape, key)
     outer = {name: params[name] for name in reference.OUTER}
     loss, (grads, outer_grads), (ce, aux, loads) = reference.loss_and_gradients(shape, layers, outer, inputs, targets)
-    want, want_grads = jax.value_and_grad(lambda p: reference.batch_loss(p, jnp.asarray(inputs), jnp.asarray(targets), shape))(params)
+    # one jitted program (PR 44: op by op this call took most of the test's 38 s)
+    want, want_grads = jax.jit(jax.value_and_grad(lambda p: reference.batch_loss(p, jnp.asarray(inputs), jnp.asarray(targets), shape)))(params)
     assert abs(loss - float(want)) < 1e-5 and loss == pytest.approx(ce + aux, abs=1e-6) and loads.shape == (8, 16) and loads.sum() == 8 * 2 * 64 * 4
     named = reference.by_run(shape, grads, outer_grads)
     for r, run in enumerate(want_grads["runs"]):
@@ -249,12 +257,12 @@ def test_the_shares_parts_add_up_to_the_uncut_layer(toy):
     w = {k: v.astype(jnp.float32) for k, v in layer_weights(whole, seed_key(SEED), 1).items()}
     x = jnp.asarray(np.random.default_rng(2).normal(size=(2, 64, 128)), jnp.float32)
     with jax.default_matmul_precision("highest"):
-        want, load, _ = jax.vmap(lambda row: reference.expert_layer(row, w, whole))(x)
+        want, load, _ = jax.jit(jax.vmap(lambda row: reference.expert_layer(row, w, whole)))(x)  # jitted, as each share's below (PR 44: the suite's clock)
         total, held = jnp.zeros_like(want), []
         for offset in (0, 4, 8, 12):
             part = build(moe_config={**MOE, "expert_offset": offset}).with_spec_updates(compute_dtype="float32")
             leaves = {"router": {"kernel": w["router"]}, "experts": {n: w[f"experts_{n}"][offset: offset + 4] for n in ("W", "V", "W_2")}}
-            out, counters = MoE(part.config_spec).apply({"params": leaves}, x)
+            out, counters = jax.jit(lambda leaves, x, part=part: MoE(part.config_spec).apply({"params": leaves}, x))(leaves, x)
             total, held = total + out, held + [float(counters[0])]
             assert np.asarray(counters[3:19]).tolist() == np.asarray(load.sum(axis=0)).tolist(), "every share counts all 16 experts' loads"
     assert float(jnp.abs(total - want).max()) < 1e-5 * float(jnp.abs(want).max())

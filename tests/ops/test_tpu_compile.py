@@ -78,6 +78,24 @@ def _flash(heads_q, heads_kv, head_dim, seq=SEQ, kernels=FUSED):
     return jax.grad(loss, argnums=(0, 1, 2)), (q, kv, kv), kernels
 
 
+def _flash_at_the_tables_blocks(heads_q, heads_kv, head_dim, seq):
+    """Forward and backward at the blocks `tuning_tables/v5e.json` gives a head of this width (PR 44: heads of 256 have a bucket of
+    their own, `d256_dv256`): at one row of 16,384 a q head's resident dq is 32 MiB, the fused backward fits its VMEM budget at the
+    backward entry's 512 x 512 (counted 42 MiB of 48) and would not at the forward's 1024 x 1024 (60), where the two kernels' `bwd_dkv`
+    asks 18.65 MiB of Mosaic's 16."""
+    from modalities_tpu.ops.pallas.flash_attention import backward_plan
+
+    forward, backward = _table_blocks(head_dim, head_dim), _table_blocks(head_dim, head_dim, "flash_attention_bwd")
+    assert backward_plan(seq, *backward, head_dim, head_dim, BF16)["backward"] == "fused"
+    assert backward_plan(seq, *forward, head_dim, head_dim, BF16)["backward"] == "two_kernels"
+
+    def loss(q, k, v):
+        return pallas_flash_attention(q, k, v, block_q=forward[0], block_k=forward[1], bwd_blocks=backward).astype(F32).sum()
+
+    q, kv = ((1, seq, heads_q, head_dim), BF16), ((1, seq, heads_kv, head_dim), BF16)
+    return jax.grad(loss, argnums=(0, 1, 2)), (q, kv, kv), FUSED
+
+
 WINDOW_FUSED = ("flash_attention_window_fwd", "flash_attention_window_bwd")
 
 
@@ -201,6 +219,9 @@ CASES = {
     "flash_fwd_bwd_d128_gqa_8_2_s8192": _flash(8, 2, 128, seq=2 * SEQ),
     "flash_fwd_bwd_d128_s8_one_short_tile": _flash(4, 4, 128, seq=8),
     "flash_fwd_bwd_d128_s24_one_tile_where_blocks_of_8_divide": _flash(4, 4, 128, seq=24),  # a [1, 8] block of [1, 24] does not lower
+    # the gated-delta-rule cell's one attention layer (PR 44): 16 q on 2 kv heads of 256 at 16,384, and its head at 18,992 rows
+    "flash_fwd_bwd_d256_gqa_16_2_s16384_at_the_tables_blocks": _flash_at_the_tables_blocks(16, 2, 256, 4 * SEQ),
+    "fused_ce_fwd_bwd_e2048_rows16384_v18992": _fused_ce(2048, 4 * SEQ, vocab=18992),
     "fused_ce_fwd_bwd_e2304_rows16384_v12288": _fused_ce(2304, 4 * SEQ, vocab=12288),
     "fused_ce_fwd_bwd_e2048_rows16384_v16128": _fused_ce(2048, 4 * SEQ, vocab=16128),
     "fused_ce_rows_fwd_bwd_e2048_exits4_rows4096_v49152": _fused_ce_rows(2048, 4, SEQ, 49152),
@@ -360,11 +381,9 @@ def test_the_looped_steps_backward_adds_a_layers_gradient_into_one_stack(v5e, mo
     assert by_walk - in_place >= stack, (by_walk, in_place, stack)
 
 
-def test_the_compressed_convolutional_attention_cells_step_compiles_for_v5e(v5e, monkeypatch, tmp_path):
-    """The whole donated train step of `benchmark/configs/zaya1-8b-ep2/train.yaml` (PR 40: 10 hybrid layers of width 2048, 8 of 16
-    experts of 2048 held, 32,784 rows of the tied table, 2 rows of 8,192, every block rematerialized) through the recipe's own
-    components, compiled for a described v5e: the mixer's shifts, both convolutions and the router's carried state lower beside
-    the kernels the other cells share; no kernel of its own; the step fits the chip with the room `meta.json` states."""
+def _compiled_cell_step(v5e, monkeypatch, tmp_path, config: str, vocab_size: int, sequence_length: int):
+    """The whole donated train step of `benchmark/configs/<config>/train.yaml` through the recipe's own components, compiled for
+    the described v5e: its text, and the compiler's peak (arguments + outputs + temporaries - aliases)."""
     from benchmark.traffic import packed_documents
     from modalities_tpu.ops.pallas import autotune
     from modalities_tpu.utils.recipe_validation import build_lowered_train_step
@@ -373,15 +392,21 @@ def test_the_compressed_convolutional_attention_cells_step_compiles_for_v5e(v5e,
     autotune.clear_cache()
     monkeypatch.chdir(tmp_path)  # the YAML's paths are relative; the corpus only has to exist and hold a step's rows
     mix = {"sequences": 8, "size_seed": 1, "doc_len_median": 600, "doc_len_sigma": 1.0, "doc_len_min": 32, "doc_len_max": 8192}
-    packed_documents.generate(mix, 1, tmp_path / "data" / "train.pbin", vocab_size=32784, sequence_length=8192)
+    packed_documents.generate(mix, 1, tmp_path / "data" / "train.pbin", vocab_size=vocab_size, sequence_length=sequence_length)
     repo = os.path.dirname(os.path.dirname(os.path.dirname(os.path.abspath(__file__))))
-    built = build_lowered_train_step(os.path.join(repo, "benchmark", "configs", "zaya1-8b-ep2", "train.yaml"))
-    executable = built.lowered.compile()
-    text = executable.as_text()
+    executable = build_lowered_train_step(os.path.join(repo, "benchmark", "configs", config, "train.yaml")).lowered.compile()
+    memory = executable.memory_analysis()
+    return executable.as_text(), memory.argument_size_in_bytes + memory.output_size_in_bytes + memory.temp_size_in_bytes - memory.alias_size_in_bytes
+
+
+def test_the_compressed_convolutional_attention_cells_step_compiles_for_v5e(v5e, monkeypatch, tmp_path):
+    """The whole donated train step of `benchmark/configs/zaya1-8b-ep2/train.yaml` (PR 40: 10 hybrid layers of width 2048, 8 of 16
+    experts of 2048 held, 32,784 rows of the tied table, 2 rows of 8,192, every block rematerialized) through the recipe's own
+    components, compiled for a described v5e: the mixer's shifts, both convolutions and the router's carried state lower beside
+    the kernels the other cells share; no kernel of its own; the step fits the chip with the room `meta.json` states."""
+    text, peak = _compiled_cell_step(v5e, monkeypatch, tmp_path, "zaya1-8b-ep2", 32784, 8192)
     kernels = sorted(set(re.findall(r"(\w+)\)*/pallas_call", text)))
     assert kernels == ["flash_attention_bwd", "flash_attention_fwd", "fused_ce_bwd_dw", "fused_ce_fwd", "fused_rmsnorm_bwd", "fused_rmsnorm_fwd"], kernels
     for scope in ("cca/conv", "cca/qk_norm", "cca/value_shift", "moe/router/router/eda", "moe/router/router/mlp", "residual/attn_merge"):
         assert f"/{scope}/" in text, scope
-    memory = executable.memory_analysis()
-    peak = memory.argument_size_in_bytes + memory.output_size_in_bytes + memory.temp_size_in_bytes - memory.alias_size_in_bytes
     assert 12.0 * 2**30 < peak < 14.7 * 2**30, peak / 2**30  # meta.json, memory_analysis: 12.76 GiB at 10 layers
