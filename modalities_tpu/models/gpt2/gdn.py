@@ -107,10 +107,11 @@ class GatedDeltaNet(nn.Module):
         nk, nv, dk, dv = gdn.key_heads, gdn.value_heads, gdn.key_dim, gdn.value_dim
         r, f32 = nv // nk, jnp.float32
         b, s, _ = x.shape
+        kernels = rule.walk_kernels(r, rule.groups_of(s)[1], rule.CHUNK, dk, dv, x.dtype)  # of the walk over a group's chunks: the rest of the rule is the plain form
         get_active_telemetry().emit_event_once("gdn_plan", {  # runs while tracing: once per shape, nothing per step
             "tokens": b * s, "sequence": s, "chunk": rule.CHUNK, "chunks": -(-s // rule.CHUNK), "key_heads": nk, "value_heads": nv,
             "key_dim": dk, "value_dim": dv, "conv_taps": gdn.taps, "conv_width": gdn.conv_width,
-            "state_bytes_a_layer": b * rule.state_bytes(s, nv, dk, dv), "inverse": rule.HOW_T, "backward": rule.BACKWARD, "kernels": (),
+            "state_bytes_a_layer": b * rule.state_bytes(s, nv, dk, dv), "inverse": rule.HOW_T, "backward": rule.BACKWARD[bool(kernels)], "kernels": kernels,
         })
         param_dtype = jnp.dtype(spec.param_dtype)
 

@@ -22,9 +22,12 @@ against. `gated_delta_rule` is the chunked form the program runs (Yang et al., a
     S   = exp(G_C) S + (k * exp(G_C - G))^T V'
 
 Everything a chunk needs but the state (`L`, `T`, `U`, `W`, the lower products) is computed for all chunks at
-once, batched products on the MXU, under the scope `intra`; the pass over the chunks is one `lax.scan` under
-`state`. The row is padded to whole chunks with positions that change nothing (`k = 0`, `beta = 0`, `g = 0`) and cut
-again. The state is carried through the whole row and starts at zero with it.
+once, batched products on the MXU, under the scope `intra`; the pass over the chunks (`_walk`, under `state`) is a
+pair of Pallas kernels that keep the state in VMEM (`ops/pallas/gated_delta_state.py`) where kernels run
+(`ops/tiers.py`: on a TPU) and `walk_kernels` says they serve the shapes (head sizes that fill whole lane tiles, a
+chunk of whole sublane tiles, a group's states within the kernels' VMEM budget), per shard of batch and heads under a
+mesh; else, and off a TPU, one `lax.scan`. The row is padded to whole chunks with positions that change nothing
+(`k = 0`, `beta = 0`, `g = 0`) and cut again. The state is carried through the whole row and starts at zero with it.
 
 Precision. `g`, `G`, `D`, `L`, `T` and the carried `S` are float32, and `T` is built at precision `highest`
 (a product of matrices whose entries grow with the chunk: one bfloat16 pass would cost it three digits). The
@@ -37,10 +40,13 @@ row's `intra` arrays kept for the backward, the float32 `[C, C]` matrices of the
 to 18.2 GiB against the chip's 15.75). The row is walked in GROUPS of `GROUP_CHUNKS` chunks, an outer `lax.scan` that
 carries the state; a group is what is described above (its chunks' `intra` batched, then the scan over them) and is
 rematerialized (`jax.checkpoint`): the backward keeps one float32 state a group and a head (`state_bytes`) beside the
-rule's inputs, and computes a group's matrices again, one group's working set at a time. Inside a group the chunk
-step is rematerialized too (the state a chunk, for one group), the arrays the chunk scan reads are rounded to the
-operands' dtype once, and `T = (I + L)^-1` has its own rule (`dL = -T^T dT T^T`: `T` is all it keeps of the series).
-`gdn_plan` says so (`backward`).
+rule's inputs, and computes a group's matrices again, one group's working set at a time. Inside a group the arrays
+the walk reads are rounded to the operands' dtype once, and `T = (I + L)^-1` has its own rule (`dL = -T^T dT T^T`: `T`
+is all it keeps of the series). The walk's backward needs the state that came into each chunk, for one group (64 MiB
+at 32 chunks and 32 heads of 128 x 128). The kernels' walk has its own rule (`custom_vjp`) that keeps the walk's
+operands alone: its backward kernel sweeps the group's chunks forward once more with those states kept in VMEM, then
+from the last chunk with `dS` resident. The plain scan's is autodiff over a rematerialized chunk step, which keeps
+the states as its carry, in HBM. `gdn_plan` says which (`kernels`, `backward`).
 """
 
 from __future__ import annotations
@@ -48,17 +54,29 @@ from __future__ import annotations
 import jax
 import jax.numpy as jnp
 
+from modalities_tpu.ops import tiers
 from modalities_tpu.telemetry import scopes
 
 CHUNK = 64
 GROUP_CHUNKS = 32  # chunks a rematerialized group holds: 2,048 positions, 1,024 `[C, C]` systems at 32 heads
 HOW_T = "nilpotent_product"  # how `(I + L)^-1` is computed, for `gdn_plan`
-BACKWARD = "autodiff over rematerialized groups of chunks: a state a group kept, a group's matrices computed again"
+WALK_KERNELS = ("gated_delta_state_fwd", "gated_delta_state_bwd")
+_GROUPS = "rematerialized groups of chunks: a state a group kept, a group's matrices computed again; "
+# what the backward is, for `gdn_plan`, by whether the walk's kernels run
+BACKWARD = {False: _GROUPS + "autodiff over a rematerialized chunk step",
+            True: _GROUPS + "the backward kernel sweeps a group's chunks forward, the states a chunk kept in VMEM, then backward"}
+
+
+def groups_of(tokens: int, chunk: int = CHUNK, group: int = GROUP_CHUNKS) -> tuple[int, int]:
+    """(groups, chunks a group) a row of `tokens` positions is walked in: whole chunks, the groups alike, at most `group` chunks each."""
+    chunks = -(-tokens // chunk)
+    groups = -(-chunks // group)
+    return groups, -(-chunks // groups)
 
 
 def state_bytes(tokens: int, value_heads: int, key_dim: int, value_dim: int, chunk: int = CHUNK, group: int = GROUP_CHUNKS) -> int:
     """Bytes of the float32 states the backward keeps of one layer: one `[d_k, d_v]` a group of chunks and a value head."""
-    return -(-tokens // (chunk * group)) * value_heads * key_dim * value_dim * 4
+    return groups_of(tokens, chunk, group)[0] * value_heads * key_dim * value_dim * 4
 
 
 def _dot(spec: str, a, b, dtype):
@@ -99,6 +117,50 @@ def _unit_lower_inverse_bwd(inverse, d_inverse):
 _unit_lower_inverse.defvjp(_unit_lower_inverse_fwd, _unit_lower_inverse_bwd)
 
 
+def walk_kernels(per_key_head: int, chunks: int, chunk: int, key_dim: int, value_dim: int, dtype) -> tuple[str, ...]:
+    """The kernels the walk over a group of `chunks` chunks takes for these shapes, `()` for the plain scan: where kernels
+    run and their planner serves the shapes (one key head a grid step is the least it may take, whatever a shard holds)."""
+    if not tiers.kernels_run():
+        return ()
+    from modalities_tpu.ops.pallas.gated_delta_state import plan_heads
+
+    return WALK_KERNELS if plan_heads(1, per_key_head, chunks, chunk, key_dim, value_dim, dtype) else ()
+
+
+_PER_CHUNK, _STATE = (None, "batch", "heads", None, None, None), ("batch", "heads", None, None, None)
+
+
+def _plain_walk(state, u, w, within, q_in, k_out, carry_decay):
+    """`_walk` as one `lax.scan` over the chunks, the step rematerialized: its backward keeps a state a chunk."""
+    dtype = u.dtype
+
+    @jax.checkpoint
+    def step(state, per_chunk):
+        u_c, w_c, within_c, q_c, k_c, decay_c = per_chunk
+        v_new = u_c - _dot("bhrid,bhrde->bhrie", w_c, state, dtype)
+        out = _dot("bhrid,bhrde->bhrie", q_c, state, dtype) + _dot("bhrij,bhrje->bhrie", within_c, v_new, dtype)
+        state = decay_c[..., None, None] * state + _dot("bhrid,bhrie->bhrde", k_c, v_new, dtype)
+        return state, out.astype(dtype)
+
+    return jax.lax.scan(step, state, (u, w, within, q_in, k_out, carry_decay))
+
+
+def _walk(state, u, w, within, q_in, k_out, carry_decay):
+    """The chunks of a group one after the other from the state that came in `[B, Hk, r, d_k, d_v]` float32: u, w,
+    within, q_in, k_out `[N, B, Hk, r, C, d]` in the operands' dtype, carry_decay `[N, B, Hk, r]`. Returns the state
+    that goes on and o `[N, B, Hk, r, C, d_v]`."""
+    if not walk_kernels(u.shape[3], u.shape[0], u.shape[4], w.shape[-1], u.shape[-1], u.dtype):
+        return _plain_walk(state, u, w, within, q_in, k_out, carry_decay)
+    from modalities_tpu.ops.pallas.gated_delta_state import plan_heads, walk
+    from modalities_tpu.parallel.sharding import per_shard
+
+    def kernels(_axes, state, u, *rest):
+        heads = plan_heads(u.shape[2], u.shape[3], u.shape[0], u.shape[4], state.shape[-2], u.shape[-1], u.dtype)  # of the key heads this shard holds
+        return walk(state, u, *rest, heads=heads, interpret=tiers.interpret())
+
+    return per_shard(kernels, (_STATE, *[_PER_CHUNK] * 5, _PER_CHUNK[:4]), (_STATE, _PER_CHUNK))(state, u, w, within, q_in, k_out, carry_decay)
+
+
 def _group(state, q, k, v, g, beta, chunk: int):
     """`n` chunks from the state that came in: q, k `[B, n C, Hk, d_k]`, v `[B, n C, Hv, d_v]`, g, beta `[B, n C, Hv]`, state
     `[B, Hk, r, d_k, d_v]` float32. Returns the state that goes on and o `[B, n C, Hv, d_v]`."""
@@ -128,16 +190,8 @@ def _group(state, q, k, v, g, beta, chunk: int):
         k_out = (kc.astype(f32)[:, :, :, None] * jnp.exp(cum[..., -1:] - cum)[..., None]).astype(dtype)  # into the state that goes on
         carry_decay = jnp.exp(cum[..., -1])  # [N, B, Hk, r]
 
-    @jax.checkpoint
-    def step(state, per_chunk):
-        u_c, w_c, within_c, q_c, k_c, decay_c = per_chunk
-        v_new = u_c - _dot("bhrid,bhrde->bhrie", w_c, state, dtype)
-        out = _dot("bhrid,bhrde->bhrie", q_c, state, dtype) + _dot("bhrij,bhrje->bhrie", within_c, v_new, dtype)
-        state = decay_c[..., None, None] * state + _dot("bhrid,bhrie->bhrde", k_c, v_new, dtype)
-        return state, out.astype(dtype)
-
     with jax.named_scope(scopes.GDN_STATE):
-        state, out = jax.lax.scan(step, state, (u, w, within, q_in, k_out, carry_decay))
+        state, out = _walk(state, u, w, within, q_in, k_out, carry_decay)
         out = out.transpose(1, 0, 4, 2, 3, 5).reshape(b, length, hv, dv)  # [N, B, Hk, r, C, d_v] -> [B, n C, Hv, d_v]
     return state, out
 
@@ -149,9 +203,8 @@ def gated_delta_rule(q, k, v, g, beta, *, chunk: int = CHUNK, group_chunks: int 
     hv, dv = v.shape[2], v.shape[3]
     if hv % hk:
         raise ValueError(f"gated_delta_rule: {hv} value heads do not share {hk} key heads evenly")
-    chunks = -(-s // chunk)
-    groups = -(-chunks // group_chunks)
-    per_group = -(-chunks // groups) * chunk  # positions a group holds: whole chunks, the groups alike
+    groups, chunks_a_group = groups_of(s, chunk, group_chunks)
+    per_group = chunks_a_group * chunk  # positions a group holds
     pad = groups * per_group - s
     if pad:  # positions that change nothing: no key, no correction, no decay
         q, k, v = (jnp.pad(a, ((0, 0), (0, pad), (0, 0), (0, 0))) for a in (q, k, v))

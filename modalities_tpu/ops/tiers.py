@@ -1,7 +1,8 @@
 """Which form of an operation runs: one rule, asked here by every dispatch wrapper.
 
 A Pallas kernel runs where the platform is a TPU and the planner that reads the call's
-shapes says so (`combine_plan`, `backward_plan`, `grad_plan`, `attention_keep_plan`);
+shapes says so (`combine_plan`, `backward_plan`, `grad_plan`, `attention_keep_plan`,
+`walk_kernels`);
 off a TPU the reference form runs, so CPU tests see reference numerics. No environment
 variable, config key or CLI flag overrides this: two forms are compared as two commits
 on the chip.

@@ -82,7 +82,7 @@ module name `gdn` in a block's mixer seat:
     GDN_QK_NORM       qk_norm           the L2 norm of every head of q and k, float32, and q's `1 / sqrt(d_k)`
     GDN_RULE          rule              the chunked rule; under it:
     GDN_INTRA         intra             what a chunk needs but the state, for all chunks at once: `L`, `T = (I + L)^-1`, `U`, `W`, the lower products
-    GDN_STATE         state             the scan over the chunks that carries the `[d_k, d_v]` state a head
+    GDN_STATE         state             the walk over the chunks that carries the `[d_k, d_v]` state a head (on a TPU the kernels `gated_delta_state_fwd` / `_bwd`, else a scan), and the carry from group to group
     GDN_OUT_NORM      out_norm          the RMS norm a head of the rule's output, times `silu(z)` (the name of the norm's module)
     GDN_OUT           out               the output projection back to the residual's width (`gdn/out/out_proj`)
 

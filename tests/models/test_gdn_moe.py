@@ -381,7 +381,34 @@ def test_the_new_scopes_and_the_plan_are_on_the_step(toy, tokens):
         assert path in text, path
     plan = next(e for e in events if e.get("name") == "gdn_plan" and e["tokens"] == 2 * SEQ)
     assert plan["chunk"] == 64 and plan["chunks"] == 2 and (plan["key_heads"], plan["value_heads"], plan["key_dim"], plan["value_dim"]) == (2, 4, 16, 16)
-    assert plan["state_bytes_a_layer"] == 2 * 4 * 16 * 16 * 4 and plan["inverse"] == "nilpotent_product" and "rematerialized groups" in plan["backward"]
+    assert plan["state_bytes_a_layer"] == 2 * 4 * 16 * 16 * 4 and plan["inverse"] == "nilpotent_product" and "rematerialized groups" in plan["backward"] and plan["kernels"] == []  # heads of 16: the plain walk
+
+
+@pytest.mark.parametrize("interpreted", [False, True], ids=["plain_walk", "kernels_interpreted"])
+def test_the_plan_names_the_walks_kernels_and_the_backward_they_make(interpreted):
+    """`gdn_plan` off a trace of the mixer at heads of 128 x 128 (nothing compiles): no kernel off a TPU, the walk's two where kernels run."""
+    import contextlib
+    import json
+    import tempfile
+    from pathlib import Path
+
+    from modalities_tpu.ops import tiers
+    from modalities_tpu.telemetry import Telemetry, set_active_telemetry
+
+    spec = build(gdn_config={**GDN, "linear_key_head_dim": 128, "linear_value_head_dim": 128}).config_spec
+    with tempfile.TemporaryDirectory() as folder, (tiers.interpreted_kernels() if interpreted else contextlib.nullcontext()):
+        telemetry = Telemetry(output_folder_path=Path(folder))
+        previous = set_active_telemetry(telemetry)
+        try:
+            jax.eval_shape(lambda h: gdn.GatedDeltaNet(spec).init_with_output(jax.random.PRNGKey(0), h)[0], jax.ShapeDtypeStruct((1, SEQ, 128), jnp.bfloat16))
+        finally:
+            set_active_telemetry(previous)
+        plan = next(json.loads(line) for line in Path(telemetry.sink_path).read_text().splitlines() if '"gdn_plan"' in line)
+    assert (plan["key_dim"], plan["value_dim"]) == (128, 128) and "a state a group kept, a group's matrices computed again" in plan["backward"]
+    if interpreted:
+        assert plan["kernels"] == ["gated_delta_state_fwd", "gated_delta_state_bwd"] and "the states a chunk kept in VMEM" in plan["backward"]
+    else:
+        assert plan["kernels"] == [] and "autodiff over a rematerialized chunk step" in plan["backward"]
 
 
 def test_the_required_operations_count_what_a_token_passes():

@@ -26,6 +26,7 @@ from modalities_tpu.ops.pallas.flash_attention import (
 )
 from modalities_tpu.ops.pallas.fused_ce import fused_ce_rows, fused_ce_sum_and_count
 from modalities_tpu.ops.pallas.fused_rmsnorm import fused_rms_norm
+from modalities_tpu.ops.pallas.gated_delta_state import plan_heads, walk
 from modalities_tpu.ops.pallas.moe_combine import moe_combine, pad_rows, vmem_bytes
 from modalities_tpu.ops.pallas.quant_matmul import quant_matmul
 from modalities_tpu.ops.pallas.selective_scan import pallas_selective_scan
@@ -184,6 +185,27 @@ def _selective_scan(d_inner, batch=1, d_state=16):
     return jax.grad(loss, argnums=tuple(range(6))), (rows, rows, ((d_inner, d_state), F32), narrow, narrow, ((batch, d_inner, d_state), F32)), 2
 
 
+def _gated_delta_state(backward, chunks=32, key_heads=16, r=2, chunk=64, dim=128):
+    """The walk over a group's chunks at the gated-delta-rule cell's shapes (PR 45: 32 chunks of 64, 16 key heads with 2 value heads each,
+    heads of 128 x 128, bfloat16), `plan_heads`' key heads a grid step: the forward alone, and a differentiated call, whose backward is the
+    one kernel that sweeps the chunks forward, the state that came into each kept in VMEM (16 MiB here), and then backward."""
+    heads = plan_heads(key_heads, r, chunks, chunk, dim, dim, BF16)
+    assert heads * r == 8
+
+    def forward(*operands):
+        return walk(*operands, heads=heads)
+
+    def loss(*operands):
+        state, out = forward(*operands)
+        return state.sum() + out.astype(F32).sum()
+
+    per_chunk = lambda *last: ((chunks, 1, key_heads, r, chunk, *last), BF16)  # noqa: E731
+    shapes = (((1, key_heads, r, dim, dim), F32), per_chunk(dim), per_chunk(dim), per_chunk(chunk), per_chunk(dim), per_chunk(dim), ((chunks, 1, key_heads, r), F32))
+    if backward:
+        return jax.grad(loss, argnums=tuple(range(7))), shapes, ("gated_delta_state_bwd",)  # the forward's outputs are not read: dead code
+    return forward, shapes, ("gated_delta_state_fwd",)
+
+
 def _moe_combine(width, held, k, tokens=4 * SEQ, block=256):
     """The expert layer's sum by token as both expert cells call it (PR 39): 16,384 tokens in blocks of 256, the forward's
     weighted sum and the backward's unweighted one over a table sized for every pair on held experts, with the kernel's
@@ -238,6 +260,8 @@ CASES = {
     "quant_matmul_m256": _quant_matmul(256),
     "selective_scan_fwd_bwd_d5120": _selective_scan(5120),
     "selective_scan_fwd_bwd_b2_d1280": _selective_scan(1280, batch=2),
+    "gated_delta_state_fwd_chunks32_heads32_d128": _gated_delta_state(backward=False),  # train-qwen3next-80b-16k
+    "gated_delta_state_bwd_chunks32_heads32_d128": _gated_delta_state(backward=True),
 }
 
 
