@@ -100,6 +100,7 @@ class GatedDeltaNet(nn.Module):
     def __call__(self, x):
         from modalities_tpu.models.gpt2.ssm import _uniform
         from modalities_tpu.ops import gated_delta_rule as rule
+        from modalities_tpu.ops import head_norm
         from modalities_tpu.ops.selective_scan import causal_depthwise_conv
         from modalities_tpu.telemetry import get_active_telemetry
 
@@ -108,10 +109,13 @@ class GatedDeltaNet(nn.Module):
         r, f32 = nv // nk, jnp.float32
         b, s, _ = x.shape
         kernels = rule.walk_kernels(r, rule.groups_of(s)[1], rule.CHUNK, dk, dv, x.dtype)  # of the walk over a group's chunks: the rest of the rule is the plain form
+        # of the two norms over a head's channels (`ops/head_norm.py`): none off a TPU and at heads that are no whole lane tiles, where the plain forms below run
+        qk_norm, out_norm = head_norm.kernels("l2", (b, s, nk, dk), x.dtype), head_norm.kernels("gated", (b, s, nv, dv), x.dtype)
         get_active_telemetry().emit_event_once("gdn_plan", {  # runs while tracing: once per shape, nothing per step
             "tokens": b * s, "sequence": s, "chunk": rule.CHUNK, "chunks": -(-s // rule.CHUNK), "key_heads": nk, "value_heads": nv,
             "key_dim": dk, "value_dim": dv, "conv_taps": gdn.taps, "conv_width": gdn.conv_width,
             "state_bytes_a_layer": b * rule.state_bytes(s, nv, dk, dv), "inverse": rule.HOW_T, "backward": rule.BACKWARD[bool(kernels)], "kernels": kernels,
+            "norm_kernels": qk_norm + out_norm,
         })
         param_dtype = jnp.dtype(spec.param_dtype)
 
@@ -142,14 +146,20 @@ class GatedDeltaNet(nn.Module):
             g = -jnp.exp(a_log) * jax.nn.softplus(ba[..., r:].astype(f32).reshape(b, s, nv) + dt_bias)
             counters = jax.lax.stop_gradient(jnp.stack([jnp.mean(jnp.exp(g)), jnp.mean(beta)]))
         with jax.named_scope(scopes.GDN_QK_NORM):
-            q, k = l2_normalised(q, dk ** -0.5).astype(x.dtype), l2_normalised(k).astype(x.dtype)
+            if qk_norm:
+                q, k = head_norm.head_l2_norm(q, dk ** -0.5), head_norm.head_l2_norm(k)
+            else:
+                q, k = l2_normalised(q, dk ** -0.5).astype(x.dtype), l2_normalised(k).astype(x.dtype)
         with jax.named_scope(scopes.GDN_RULE):
             o = rule.gated_delta_rule(q, k, v, g, beta)
         with jax.named_scope(scopes.GDN_OUT_NORM):
             w_n = self.param("out_norm_scale", nn.with_logical_partitioning(nn.initializers.ones, (None,)), (dv,), f32)
-            o = o.astype(f32)
-            y = o * jax.lax.rsqrt(jnp.mean(o * o, axis=-1, keepdims=True) + spec.attn_norm.eps) * w_n * nn.silu(z.astype(f32))
-            y = y.astype(x.dtype)
+            if out_norm:
+                y = head_norm.gated_head_rms_norm(o, z, w_n, eps=spec.attn_norm.eps)
+            else:
+                o = o.astype(f32)
+                y = o * jax.lax.rsqrt(jnp.mean(o * o, axis=-1, keepdims=True) + spec.attn_norm.eps) * w_n * nn.silu(z.astype(f32))
+                y = y.astype(x.dtype)
         with jax.named_scope(scopes.GDN_OUT):
             out = nn.DenseGeneral(
                 features=spec.n_embd, axis=(-2, -1), use_bias=False, name="out_proj", dtype=x.dtype, param_dtype=param_dtype,

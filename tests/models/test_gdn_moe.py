@@ -381,12 +381,13 @@ def test_the_new_scopes_and_the_plan_are_on_the_step(toy, tokens):
         assert path in text, path
     plan = next(e for e in events if e.get("name") == "gdn_plan" and e["tokens"] == 2 * SEQ)
     assert plan["chunk"] == 64 and plan["chunks"] == 2 and (plan["key_heads"], plan["value_heads"], plan["key_dim"], plan["value_dim"]) == (2, 4, 16, 16)
-    assert plan["state_bytes_a_layer"] == 2 * 4 * 16 * 16 * 4 and plan["inverse"] == "nilpotent_product" and "rematerialized groups" in plan["backward"] and plan["kernels"] == []  # heads of 16: the plain walk
+    assert plan["state_bytes_a_layer"] == 2 * 4 * 16 * 16 * 4 and plan["inverse"] == "nilpotent_product" and "rematerialized groups" in plan["backward"] and plan["kernels"] == [] and plan["norm_kernels"] == []  # heads of 16: the plain walk and the plain norms
 
 
 @pytest.mark.parametrize("interpreted", [False, True], ids=["plain_walk", "kernels_interpreted"])
 def test_the_plan_names_the_walks_kernels_and_the_backward_they_make(interpreted):
-    """`gdn_plan` off a trace of the mixer at heads of 128 x 128 (nothing compiles): no kernel off a TPU, the walk's two where kernels run."""
+    """`gdn_plan` off a trace of the mixer at heads of 128 x 128 (nothing compiles): no kernel off a TPU; where kernels run the walk's two
+    under `kernels` and the two norms' four under `norm_kernels` (PR 46: `gdn/qk_norm` forward and backward, then `gdn/out_norm`'s)."""
     import contextlib
     import json
     import tempfile
@@ -407,8 +408,9 @@ def test_the_plan_names_the_walks_kernels_and_the_backward_they_make(interpreted
     assert (plan["key_dim"], plan["value_dim"]) == (128, 128) and "a state a group kept, a group's matrices computed again" in plan["backward"]
     if interpreted:
         assert plan["kernels"] == ["gated_delta_state_fwd", "gated_delta_state_bwd"] and "the states a chunk kept in VMEM" in plan["backward"]
+        assert plan["norm_kernels"] == ["head_l2_norm_fwd", "head_l2_norm_bwd", "gated_head_rms_norm_fwd", "gated_head_rms_norm_bwd"]
     else:
-        assert plan["kernels"] == [] and "autodiff over a rematerialized chunk step" in plan["backward"]
+        assert plan["kernels"] == [] and plan["norm_kernels"] == [] and "autodiff over a rematerialized chunk step" in plan["backward"]
 
 
 def test_the_required_operations_count_what_a_token_passes():
@@ -422,3 +424,24 @@ def test_the_required_operations_count_what_a_token_passes():
     assert calc.rule_flops_per_token == 3 * gdn_rule_flops_per_token(spec.gdn) and calc.n_attention_layer == 1 and calc.attention_width == 2 * 4 * 32
     c, dk, dv = 64, 16, 16
     assert gdn_rule_flops_per_token(spec.gdn) == 4 * (2 * 2 * c * c * dk / 2 + 11 * 2 * c ** 3 + 2 * c * c * (dk + dv) + 6 * c * dk * dv + 2 * c * c * dv) / c
+
+
+def test_under_a_mesh_the_mixers_norms_and_walk_run_per_shard_and_the_mixer_is_the_plain_one():
+    """dp_shard 2 x tp 2 on the CPU's virtual devices, heads of 128 x 128 (2 key and 4 value heads: one key head a shard), kernels interpreted:
+    the two norms go through `per_shard` as the walk does (four `shard_map`s of kernels in the forward's trace: q's and k's norm, the walk,
+    the gated norm), `out_norm_scale` whole on every shard; the output is the same mixer's traced plain on one device."""
+    from modalities_tpu.ops import tiers
+    from modalities_tpu.parallel.sharding import activation_rules, default_logical_axis_rules
+    from modalities_tpu.running_env.device_mesh import get_device_mesh
+
+    spec = build(gdn_config={**GDN, "linear_key_head_dim": 128, "linear_value_head_dim": 128}).config_spec
+    module = gdn.GatedDeltaNet(spec)
+    h = jax.random.normal(jax.random.PRNGKey(3), (2, SEQ, 128), jnp.float32)
+    params = stirred(meta.unbox(module.init(jax.random.PRNGKey(0), h)))
+    want = jax.jit(lambda p, h: module.apply(p, h)[0])(params, h)
+    handle = get_device_mesh(device_type="cpu", world_size=4, data_parallel_shard_degree=2, tensor_parallel_degree=2)
+    with handle.mesh, activation_rules(default_logical_axis_rules(handle), handle.mesh), tiers.interpreted_kernels():
+        text = str(jax.make_jaxpr(lambda p, h: module.apply(p, h)[0])(params, h))
+        assert text.count("shard_map") >= 4 and all(name in text for name in ("head_l2_norm_fwd", "gated_head_rms_norm_fwd", "gated_delta_state_fwd"))
+        got = jax.jit(lambda p, h: module.apply(p, h)[0])(params, h)
+    np.testing.assert_allclose(np.asarray(got), np.asarray(want), rtol=2e-4, atol=2e-5)

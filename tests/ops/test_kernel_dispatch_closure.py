@@ -47,10 +47,12 @@ def test_every_pallas_call_wires_interpret():
 def test_dispatch_entry_points_expose_interpret():
     """The manifest of kernel entry points reachable from dispatch wrappers.
     A new kernel added to a wrapper without an interpret path must fail here."""
+    from modalities_tpu.ops import head_norm as head_norm_dispatch
     from modalities_tpu.ops.cross_entropy import fused_ce_sum_and_count as ce_dispatch
     from modalities_tpu.ops.pallas.flash_attention import pallas_flash_attention
     from modalities_tpu.ops.pallas.fused_ce import fused_ce_sum_and_count
     from modalities_tpu.ops.pallas.fused_rmsnorm import fused_rms_norm
+    from modalities_tpu.ops.pallas.head_norm import gated_head_rms_norm, head_l2_norm
     from modalities_tpu.ops.pallas.moe_combine import moe_combine
     from modalities_tpu.ops.pallas.quant_matmul import quant_matmul
     from modalities_tpu.ops.pallas.selective_scan import pallas_selective_scan
@@ -59,7 +61,8 @@ def test_dispatch_entry_points_expose_interpret():
     from modalities_tpu.ops.selective_scan import selective_scan
 
     for fn in (pallas_flash_attention, fused_ce_sum_and_count, fused_rms_norm, ce_dispatch, rms_norm_or_fallback, quant_matmul, quant_matmul_or_fallback,
-               pallas_selective_scan, selective_scan, moe_combine):
+               pallas_selective_scan, selective_scan, moe_combine, head_l2_norm, gated_head_rms_norm, head_norm_dispatch.head_l2_norm,
+               head_norm_dispatch.gated_head_rms_norm):
         params = inspect.signature(fn).parameters
         assert "interpret" in params, f"{fn.__module__}.{fn.__name__} lacks an interpret path"
         assert params["interpret"].default is False, fn.__name__
@@ -137,9 +140,27 @@ def _call_moe_combine():
     return routed_experts(x, jnp.zeros((16, 1), jnp.int32), jnp.ones((16, 1)), stack, stack, stack, offset=0, combine="slabs")
 
 
+def _call_head_l2_norm():
+    import jax.numpy as jnp
+
+    from modalities_tpu.ops.head_norm import head_l2_norm
+
+    return head_l2_norm(jnp.ones((1, 16, 2, 128)))
+
+
+def _call_gated_head_rms_norm():
+    import jax.numpy as jnp
+
+    from modalities_tpu.ops.head_norm import gated_head_rms_norm
+
+    rows = jnp.ones((1, 16, 2, 128))
+    return gated_head_rms_norm(rows, rows, jnp.ones((128,)), eps=1e-6)
+
+
 @pytest.mark.parametrize(
     "call",
-    [_call_moe_combine, _call_selective_scan, _call_attention, _call_fused_ce, _call_rmsnorm, _call_quant_matmul],
+    [_call_moe_combine, _call_selective_scan, _call_attention, _call_fused_ce, _call_rmsnorm, _call_quant_matmul, _call_head_l2_norm,
+     _call_gated_head_rms_norm],
 )
 def test_dispatcher_raises_what_the_kernel_raises_on_a_tpu(call, monkeypatch, caplog):
     """With the platform probe answering "TPU" on this CPU (ONE name: every dispatcher

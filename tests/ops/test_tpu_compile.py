@@ -27,6 +27,7 @@ from modalities_tpu.ops.pallas.flash_attention import (
 from modalities_tpu.ops.pallas.fused_ce import fused_ce_rows, fused_ce_sum_and_count
 from modalities_tpu.ops.pallas.fused_rmsnorm import fused_rms_norm
 from modalities_tpu.ops.pallas.gated_delta_state import plan_heads, walk
+from modalities_tpu.ops.pallas.head_norm import KERNELS as HEAD_NORM_KERNELS, gated_head_rms_norm, head_l2_norm
 from modalities_tpu.ops.pallas.moe_combine import moe_combine, pad_rows, vmem_bytes
 from modalities_tpu.ops.pallas.quant_matmul import quant_matmul
 from modalities_tpu.ops.pallas.selective_scan import pallas_selective_scan
@@ -206,6 +207,17 @@ def _gated_delta_state(backward, chunks=32, key_heads=16, r=2, chunk=64, dim=128
     return forward, shapes, ("gated_delta_state_fwd",)
 
 
+def _head_norm(norm, heads, backward, tokens=4 * SEQ, dim=128):
+    """The rule mixer's two norms over a head's channels at the gated-delta-rule cell's shapes (PR 46: one row of 16,384, q and k at 16
+    heads of 128, o and z at 32, bfloat16, `out_norm_scale` float32), at the kernels' own block: the forward alone, and a differentiated
+    call, which holds the forward too (its output is read by the loss) beside the backward kernel."""
+    rows = ((1, tokens, heads, dim), BF16)
+    forward, shapes = (head_l2_norm, (rows,)) if norm == "l2" else (lambda o, z, w: gated_head_rms_norm(o, z, w, eps=1e-6), (rows, rows, ((dim,), F32)))
+    if backward:
+        return jax.grad(lambda *xs: (forward(*xs).astype(F32) ** 2).sum(), argnums=tuple(range(len(shapes)))), shapes, HEAD_NORM_KERNELS[norm]
+    return forward, shapes, HEAD_NORM_KERNELS[norm][:1]
+
+
 def _moe_combine(width, held, k, tokens=4 * SEQ, block=256):
     """The expert layer's sum by token as both expert cells call it (PR 39): 16,384 tokens in blocks of 256, the forward's
     weighted sum and the backward's unweighted one over a table sized for every pair on held experts, with the kernel's
@@ -262,6 +274,10 @@ CASES = {
     "selective_scan_fwd_bwd_b2_d1280": _selective_scan(1280, batch=2),
     "gated_delta_state_fwd_chunks32_heads32_d128": _gated_delta_state(backward=False),  # train-qwen3next-80b-16k
     "gated_delta_state_bwd_chunks32_heads32_d128": _gated_delta_state(backward=True),
+    "head_l2_norm_fwd_rows16384x16_d128": _head_norm("l2", 16, backward=False),  # train-qwen3next-80b-16k: gdn/qk_norm
+    "head_l2_norm_fwd_bwd_rows16384x16_d128": _head_norm("l2", 16, backward=True),
+    "gated_head_rms_norm_fwd_rows16384x32_d128": _head_norm("gated", 32, backward=False),  # gdn/out_norm
+    "gated_head_rms_norm_fwd_bwd_rows16384x32_d128": _head_norm("gated", 32, backward=True),
 }
 
 
