@@ -16,6 +16,7 @@ import numpy as np
 import pytest
 
 from modalities_tpu.dataloader.packed_data import write_pbin_file
+from tests.conftest import xla_flags
 
 REPO = Path(__file__).parent.parent.parent
 # phase 1 is the pp2 x dp2 x tp2 pretrain — the warmstart config's training target
@@ -39,7 +40,7 @@ def workdir(tmp_path):
 def _cli(args, cwd):
     env = dict(os.environ)
     env["JAX_PLATFORMS"] = "cpu"
-    env["XLA_FLAGS"] = "--xla_force_host_platform_device_count=8"
+    env["XLA_FLAGS"] = xla_flags(8)
     env["PYTHONPATH"] = str(REPO)
     proc = subprocess.run(
         [sys.executable, "-m", "modalities_tpu", *args],

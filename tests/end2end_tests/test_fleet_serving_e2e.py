@@ -134,7 +134,7 @@ def test_fleet_train_to_serve_loop_with_canary_rollback(tmp_path):
         # ---- 1. fleet boots from the sealed ring checkpoint
         deadline = time.monotonic() + 300
         while True:
-            assert proc.poll() is None, proc.communicate()[1][-4000:]
+            assert proc.poll() is None, proc.communicate(timeout=30)[1][-4000:]
             try:
                 status, health = _get_json(port, "/healthz", timeout=5)
                 if status == 200 and health["workers_healthy"] == 2:
@@ -200,7 +200,7 @@ def test_fleet_train_to_serve_loop_with_canary_rollback(tmp_path):
     finally:
         if proc.poll() is None:
             proc.kill()
-            proc.wait()
+            proc.wait(timeout=30)
 
 
 def _raw_metrics(port, timeout=10.0):

@@ -154,20 +154,39 @@ KERNEL_CASES = {
 BACKWARDS = ("fused", "two_kernels")  # PR 31: one kernel for dq, dk and dv; the two it replaced where its dq row does not fit
 
 
-def _grads_under(backward, monkeypatch, loss, q, k, v, block_q, block_k):
+def _grads_under(backward, loss, q, k, v, block_q, block_k):
     """The gradients of `loss` with the backward rule brought to choose `backward` for these shapes, as it would by size."""
-    with monkeypatch.context() as patched:
+    with pytest.MonkeyPatch.context() as patched:
         if backward == "two_kernels":
             patched.setattr(flash, "FUSED_BWD_VMEM_BUDGET", 0)
         assert flash.backward_plan(q.shape[1], block_q, block_k, q.shape[-1], v.shape[-1], q.dtype)["backward"] == backward
         return jax.grad(loss, argnums=(0, 1, 2))(q, k, v)
 
 
-@pytest.mark.parametrize("backward", BACKWARDS)
-@pytest.mark.parametrize("case", sorted(KERNEL_CASES))
-def test_values_and_gradients_match_oracle_in_every_tile_class(case, backward, monkeypatch):
+def _values_and_gradients(kernel, reference, q, k, v, w, block_q, block_k, diag_sub_block=8):
+    """What the cases of one shape share, computed once (a case a form of the backward reads its part): the kernel's values and the
+    reference's, and the gradients of the sum weighed by `w` under each form of the backward and through the reference."""
+    with pytest.MonkeyPatch.context() as patched:
+        patched.setattr(flash, "_DIAG_SUB_BLOCK", diag_sub_block)  # 8: four squares along a 32-wide diagonal tile
+        loss = lambda q, k, v: (kernel(q, k, v) * w).sum()  # noqa: E731
+        found = {"out": kernel(q, k, v), "reference": reference(q, k, v)}
+        found.update({backward: _grads_under(backward, loss, q, k, v, block_q, block_k) for backward in BACKWARDS})
+        found["oracle"] = jax.grad(lambda q, k, v: (reference(q, k, v) * w).sum(), argnums=(0, 1, 2))(q, k, v)
+    return jax.tree.map(np.asarray, found)
+
+
+def _held_to_the_oracle(found, backward):
+    np.testing.assert_allclose(found["out"], found["reference"], rtol=2e-5, atol=2e-5)
+    for g, e, name in zip(found[backward], found["oracle"], "qkv"):
+        np.testing.assert_allclose(g, e, rtol=5e-4, atol=5e-4, err_msg=f"d{name}")
+    if backward == "fused":  # the same sums in the same order as the two kernels: not close, equal
+        for g, e, name in zip(found["fused"], found["two_kernels"], "qkv"):
+            np.testing.assert_array_equal(g, e, err_msg=f"d{name}")
+
+
+@functools.lru_cache(maxsize=None)
+def _tile_class(case):
     seq_q, seq_k, block_q, block_k, causal, head_dim, kv_heads = KERNEL_CASES[case]
-    monkeypatch.setattr(flash, "_DIAG_SUB_BLOCK", 8)  # four squares along a 32-wide diagonal tile
     rng = jax.random.PRNGKey(11)
     q = jax.random.normal(jax.random.fold_in(rng, 0), (2, seq_q, 4, head_dim))
     k = jax.random.normal(jax.random.fold_in(rng, 1), (2, seq_k, kv_heads, head_dim))
@@ -177,15 +196,13 @@ def test_values_and_gradients_match_oracle_in_every_tile_class(case, backward, m
         pallas_flash_attention, causal=causal, block_q=block_q, block_k=block_k, interpret=True
     )
     reference = manual_attention if causal and seq_q == seq_k else functools.partial(_oracle, causal=causal)
-    np.testing.assert_allclose(np.asarray(kernel(q, k, v)), np.asarray(reference(q, k, v)), rtol=2e-5, atol=2e-5)
-    loss = lambda q, k, v: (kernel(q, k, v) * w).sum()  # noqa: E731
-    got = _grads_under(backward, monkeypatch, loss, q, k, v, block_q, block_k)
-    want = jax.grad(lambda q, k, v: (reference(q, k, v) * w).sum(), argnums=(0, 1, 2))(q, k, v)
-    for g, e, name in zip(got, want, "qkv"):
-        np.testing.assert_allclose(np.asarray(g), np.asarray(e), rtol=5e-4, atol=5e-4, err_msg=f"d{name}")
-    if backward == "fused":  # the same sums in the same order as the two kernels: not close, equal
-        for g, e, name in zip(got, _grads_under("two_kernels", monkeypatch, loss, q, k, v, block_q, block_k), "qkv"):
-            np.testing.assert_array_equal(np.asarray(g), np.asarray(e), err_msg=f"d{name}")
+    return _values_and_gradients(kernel, reference, q, k, v, w, block_q, block_k)
+
+
+@pytest.mark.parametrize("backward", BACKWARDS)
+@pytest.mark.parametrize("case", sorted(KERNEL_CASES))
+def test_values_and_gradients_match_oracle_in_every_tile_class(case, backward):
+    _held_to_the_oracle(_tile_class(case), backward)
 
 
 # ------------------------------------------- two head sizes: q and k wider than v (latent attention)
@@ -200,30 +217,27 @@ TWO_WIDTH_CASES = {
 }
 
 
-@pytest.mark.parametrize("backward", BACKWARDS)
-@pytest.mark.parametrize("case", sorted(TWO_WIDTH_CASES))
-def test_two_head_sizes_match_manual_attention(case, backward, monkeypatch):
-    """v, the output, its cotangent and dv at one width, q, k, dq and dk at another; the scale is that of q's."""
+@functools.lru_cache(maxsize=None)
+def _two_widths(case):
     seq, block_q, block_k, heads, d, dv = TWO_WIDTH_CASES[case]
-    monkeypatch.setattr(flash, "_DIAG_SUB_BLOCK", 8)
     rng = jax.random.PRNGKey(13)
     q = jax.random.normal(jax.random.fold_in(rng, 0), (2, seq, heads, d))
     k = jax.random.normal(jax.random.fold_in(rng, 1), (2, seq, heads, d))
     v = jax.random.normal(jax.random.fold_in(rng, 2), (2, seq, heads, dv))
     w = jax.random.normal(jax.random.fold_in(rng, 3), (2, seq, heads, dv))
     kernel = functools.partial(pallas_flash_attention, causal=True, block_q=block_q, block_k=block_k, interpret=True)
-    out = kernel(q, k, v)
-    assert out.shape == (2, seq, heads, dv)
-    np.testing.assert_allclose(np.asarray(out), np.asarray(manual_attention(q, k, v)), rtol=2e-5, atol=2e-5)
-    loss = lambda q, k, v: (kernel(q, k, v) * w).sum()  # noqa: E731
-    got = _grads_under(backward, monkeypatch, loss, q, k, v, block_q, block_k)
-    want = jax.grad(lambda q, k, v: (manual_attention(q, k, v) * w).sum(), argnums=(0, 1, 2))(q, k, v)
-    assert [g.shape[-1] for g in got] == [d, d, dv]
-    for g, e, name in zip(got, want, "qkv"):
-        np.testing.assert_allclose(np.asarray(g), np.asarray(e), rtol=5e-4, atol=5e-4, err_msg=f"d{name}")
-    if backward == "fused":
-        for g, e, name in zip(got, _grads_under("two_kernels", monkeypatch, loss, q, k, v, block_q, block_k), "qkv"):
-            np.testing.assert_array_equal(np.asarray(g), np.asarray(e), err_msg=f"d{name}")
+    return _values_and_gradients(kernel, manual_attention, q, k, v, w, block_q, block_k)
+
+
+@pytest.mark.parametrize("backward", BACKWARDS)
+@pytest.mark.parametrize("case", sorted(TWO_WIDTH_CASES))
+def test_two_head_sizes_match_manual_attention(case, backward):
+    """v, the output, its cotangent and dv at one width, q, k, dq and dk at another; the scale is that of q's."""
+    seq, _, _, heads, d, dv = TWO_WIDTH_CASES[case]
+    found = _two_widths(case)
+    assert found["out"].shape == (2, seq, heads, dv)
+    assert [g.shape[-1] for g in found[backward]] == [d, d, dv]
+    _held_to_the_oracle(found, backward)
 
 
 def _pallas_calls(fn, *args):
@@ -381,16 +395,19 @@ def test_the_statistics_cross_between_the_kernels_as_rows_of_numbers(call):
         assert len(statistics) == 2 and statistics[0] is lse  # lse as the forward wrote it, then delta
 
 
-@pytest.mark.parametrize("backward", ["fused", "two_kernels"])
-def test_a_row_that_is_no_multiple_of_a_lane_tile(backward, monkeypatch):
-    """200 positions in tiles of 40: the rows of statistics are sliced at lanes no tile boundary falls on (interpreted:
-    on a TPU such a row is one tile, as the dummy forward of 8 is, or its blocks are multiples of 128)."""
+@functools.lru_cache(maxsize=None)
+def _row_of_200():
     q, k, v = _rand_qkv(11, 1, 200, 2, 1, 16)
     w = jax.random.normal(jax.random.PRNGKey(12), q.shape)
     kernel = functools.partial(pallas_flash_attention, causal=True, block_q=40, block_k=40, interpret=True)
-    np.testing.assert_allclose(np.asarray(kernel(q, k, v)), np.asarray(manual_attention(q, k, v)), rtol=2e-5, atol=2e-5)
-    loss = lambda q, k, v: (kernel(q, k, v) * w).sum()  # noqa: E731
-    got = _grads_under(backward, monkeypatch, loss, q, k, v, 40, 40)
-    want = jax.grad(lambda q, k, v: (manual_attention(q, k, v) * w).sum(), argnums=(0, 1, 2))(q, k, v)
-    for g, e, name in zip(got, want, "qkv"):
-        np.testing.assert_allclose(np.asarray(g), np.asarray(e), rtol=5e-4, atol=5e-4, err_msg=f"d{name}")
+    return _values_and_gradients(kernel, manual_attention, q, k, v, w, 40, 40, diag_sub_block=flash._DIAG_SUB_BLOCK)  # the module's own
+
+
+@pytest.mark.parametrize("backward", BACKWARDS)
+def test_a_row_that_is_no_multiple_of_a_lane_tile(backward):
+    """200 positions in tiles of 40: the rows of statistics are sliced at lanes no tile boundary falls on (interpreted:
+    on a TPU such a row is one tile, as the dummy forward of 8 is, or its blocks are multiples of 128)."""
+    found = _row_of_200()
+    np.testing.assert_allclose(found["out"], found["reference"], rtol=2e-5, atol=2e-5)
+    for g, e, name in zip(found[backward], found["oracle"], "qkv"):
+        np.testing.assert_allclose(g, e, rtol=5e-4, atol=5e-4, err_msg=f"d{name}")

@@ -40,7 +40,7 @@ def program(fn, arguments: int = 5):
         out = fn(*xs)
         return sum(jnp.sum(jnp.sin(leaf.astype(jnp.float32))) for leaf in jax.tree.leaves(out)), out
 
-    both = jax.jit(jax.value_and_grad(loss, argnums=tuple(range(arguments)), has_aux=True), compiler_options={"xla_backend_optimization_level": 0})
+    both = jax.jit(jax.value_and_grad(loss, argnums=tuple(range(arguments)), has_aux=True))
     return lambda *xs: (lambda value, grads: (value[1], grads))(*both(*xs))
 
 

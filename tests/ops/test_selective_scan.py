@@ -4,6 +4,8 @@ forms (the plain `lax.scan` form that CPU runs, and the Pallas kernels of
 ops/pallas/selective_scan.py in interpret mode), and the causal depthwise convolution
 against a direct sum."""
 
+import functools
+
 import jax
 import jax.numpy as jnp
 import numpy as np
@@ -28,8 +30,8 @@ def loop(x, dt, a, b, c, h0):
     return jnp.moveaxis(y, 0, 1), h
 
 
-@pytest.fixture(scope="module")
-def inputs():
+@functools.lru_cache(maxsize=None)
+def plain_inputs():
     rng = np.random.default_rng(0)
     f = lambda *shape: jnp.asarray(rng.normal(size=shape), jnp.float32)  # noqa: E731
     return {"x": f(B, S, D), "dt": jnp.asarray(rng.uniform(0.01, 0.5, size=(B, S, D)), jnp.float32),
@@ -37,25 +39,55 @@ def inputs():
             "h0": f(B, D, N), "wy": f(B, S, D), "wh": f(B, D, N)}
 
 
+@pytest.fixture(scope="module")
+def inputs():
+    return plain_inputs()
+
+
+def weighed_scan(v, scan):
+    """`scan`'s values and the gradients in all six arguments of its outputs' sum weighed by `wy` and `wh`, as one jitted program."""
+    def both(*args):
+        weighed = lambda *a: (lambda y, h: (jnp.sum(y * v["wy"]) + jnp.sum(h * v["wh"]), (y, h)))(*scan(*a))  # noqa: E731
+        (_, values), grads = jax.value_and_grad(weighed, argnums=tuple(range(6)), has_aux=True)(*args)
+        return values, grads
+
+    return jax.jit(both)
+
+
+def arguments(v, carried):
+    return (v["x"], v["dt"], v["a"], v["b"], v["c"], v["h0"] if carried else jnp.zeros_like(v["h0"]))
+
+
+@functools.lru_cache(maxsize=None)
+def the_loop(batch, seq, carried):
+    """The loop's values and gradients on the inputs of one shape (seq 0: the plain form's), walked once for all the chunkings held to it."""
+    v = kernel_inputs(batch, seq) if seq else plain_inputs()
+    return jax.tree.map(np.asarray, weighed_scan(v, loop)(*arguments(v, carried)))
+
+
+def held_to_the_loop(got, want, atol_y):
+    (y, h), grads = got
+    (want_y, want_h), want_grads = want
+    np.testing.assert_allclose(y, want_y, atol=atol_y)
+    np.testing.assert_allclose(h, want_h, atol=2e-6)
+    for name, g, w in zip(("x", "dt", "a", "b", "c", "h0"), grads, want_grads):
+        assert float(np.abs(np.asarray(g) - w).max() / np.abs(w).max()) < 2e-6, name
+
+
 # chunks that divide the 37 steps (37, 1), that do not (8, 5), and one longer than the sequence
 @pytest.mark.parametrize("chunk", [8, 37, 5, 1, 64])
 @pytest.mark.parametrize("carried", [False, True], ids=["from_zero", "carried_state"])
 def test_chunked_scan_is_the_loop_in_values_and_gradients(inputs, chunk, carried):
-    v = inputs
-    h0 = v["h0"] if carried else jnp.zeros_like(v["h0"])
-    args = (v["x"], v["dt"], v["a"], v["b"], v["c"], h0)
-    y, h = selective_scan(*args[:5], chunk=chunk, h0=h0 if carried else None)
-    want_y, want_h = loop(*args)
-    np.testing.assert_allclose(y, want_y, atol=2e-6)
-    np.testing.assert_allclose(h, want_h, atol=2e-6)
-
-    weighed = lambda y, h: jnp.sum(y * v["wy"]) + jnp.sum(h * v["wh"])  # noqa: E731
-    got = jax.grad(lambda *a: weighed(*selective_scan(*a[:5], chunk=chunk, h0=a[5])), argnums=range(6))(*args)
-    want = jax.grad(lambda *a: weighed(*loop(*a)), argnums=range(6))(*args)
-    for name, g, w in zip(("x", "dt", "a", "b", "c", "h0"), got, want):
-        assert float(jnp.abs(g - w).max() / jnp.abs(w).max()) < 2e-6, name
+    v, args = inputs, arguments(inputs, carried)
+    want = the_loop(B, 0, carried)
+    if not carried:  # without a state handed in the scan starts from zero
+        y, h = selective_scan(*args[:5], chunk=chunk)
+        np.testing.assert_allclose(y, want[0][0], atol=2e-6)
+        np.testing.assert_allclose(h, want[0][1], atol=2e-6)
+    held_to_the_loop(weighed_scan(v, lambda *a: selective_scan(*a[:5], chunk=chunk, h0=a[5]))(*args), want, atol_y=2e-6)
 
 
+@functools.lru_cache(maxsize=None)
 def kernel_inputs(batch, seq, d_inner=384, d_state=8):
     """d_inner 384 is three blocks of 128 (512 and 256 do not divide it), so the partial dB, dC of the blocks are summed."""
     rng = np.random.default_rng(seq)
@@ -74,20 +106,13 @@ def test_interpreted_kernels_are_the_loop_in_values_and_gradients(seq, chunk, ba
     round-off of another summation order (dA over the steps, dB and dC over lanes and
     blocks) and `exp2(v log2 e)` for `exp(v)`; a dropped term would be 1e-2 and more."""
     v = kernel_inputs(batch, seq)
-    h0 = v["h0"] if carried else jnp.zeros_like(v["h0"])
-    args = (v["x"], v["dt"], v["a"], v["b"], v["c"], h0)
+    args, want = arguments(v, carried), the_loop(batch, seq, carried)
     assert plan_blocks(seq, 384, 8, chunk)[1] == 128
-    kernels = lambda *a: selective_scan(*a[:5], chunk=chunk, h0=a[5], interpret=True)  # noqa: E731
-    y, h = selective_scan(*args[:5], chunk=chunk, h0=h0 if carried else None, interpret=True)
-    want_y, want_h = loop(*args)
-    np.testing.assert_allclose(y, want_y, atol=4e-6)
-    np.testing.assert_allclose(h, want_h, atol=2e-6)
-
-    weighed = lambda y, h: jnp.sum(y * v["wy"]) + jnp.sum(h * v["wh"])  # noqa: E731
-    got = jax.grad(lambda *a: weighed(*kernels(*a)), argnums=range(6))(*args)
-    want = jax.grad(lambda *a: weighed(*loop(*a)), argnums=range(6))(*args)
-    for name, g, w in zip(("x", "dt", "a", "b", "c", "h0"), got, want):
-        assert float(jnp.abs(g - w).max() / jnp.abs(w).max()) < 2e-6, name
+    if not carried:  # without a state handed in the kernels start from zero
+        y, h = selective_scan(*args[:5], chunk=chunk, interpret=True)
+        np.testing.assert_allclose(y, want[0][0], atol=4e-6)
+        np.testing.assert_allclose(h, want[0][1], atol=2e-6)
+    held_to_the_loop(weighed_scan(v, lambda *a: selective_scan(*a[:5], chunk=chunk, h0=a[5], interpret=True))(*args), want, atol_y=4e-6)
 
 
 def test_the_plain_form_runs_off_a_tpu_and_the_kernels_refuse_what_their_layout_cannot_hold(inputs):

@@ -63,6 +63,15 @@ def v5e():
     compilation_cache.reset_cache()
 
 
+# tests/conftest.py makes the suite's CPU compiles cheap through XLA_FLAGS, which libtpu's compiler reads too. A compile for the
+# described chip is the chip's compiler at XLA's own defaults: every one in this file (and `_compiled_cell_step`'s callers) is made here.
+CHIP_DEFAULTS = {"xla_backend_optimization_level": 3, "xla_llvm_disable_expensive_passes": False}
+
+
+def compiled_for_the_chip(lowered):
+    return lowered.compile(compiler_options=CHIP_DEFAULTS)
+
+
 FUSED = ("flash_attention_fwd", "flash_attention_bwd")  # PR 31: the backward is one kernel where a q head's dq row fits VMEM
 TWO_KERNELS = ("flash_attention_fwd", "flash_attention_bwd_dq", "flash_attention_bwd_dkv")
 
@@ -286,7 +295,7 @@ def test_kernel_compiles_for_v5e(v5e, case):
     fn, shapes, kernels = CASES[case]
     chip = SingleDeviceSharding(v5e[0])
     args = [jax.ShapeDtypeStruct(shape, dtype, sharding=chip) for shape, dtype in shapes]
-    text = jax.jit(fn).lower(*args).compile().as_text()  # raises what the chip's compiler would
+    text = compiled_for_the_chip(jax.jit(fn).lower(*args)).as_text()  # raises what the chip's compiler would
     calls = [line for line in text.splitlines() if 'custom_call_target="tpu_custom_call"' in line]
     if isinstance(kernels, tuple):  # which kernels, by the `name=` their calls carry
         assert sorted(re.search(r"(\w+)\)*/pallas_call", line).group(1) for line in calls) == sorted(kernels)
@@ -315,8 +324,8 @@ def test_embedding_gradient_follows_the_compilers_switch(v5e, cell):
     chip = SingleDeviceSharding(v5e[0])
     args = [jax.ShapeDtypeStruct(shape, dtype, sharding=chip)
             for shape, dtype in (((vocab, n_embd), BF16), ((batch, seq), jnp.int32), ((batch, seq, n_embd), BF16))]
-    compiled = lambda lookup: jax.jit(jax.grad(  # noqa: E731
-        lambda table, ids, weights: (lookup(table, ids) * weights).astype(F32).sum())).lower(*args).compile().as_text()
+    compiled = lambda lookup: compiled_for_the_chip(jax.jit(jax.grad(  # noqa: E731
+        lambda table, ids, weights: (lookup(table, ids) * weights).astype(F32).sum())).lower(*args)).as_text()
     text, plan = compiled(embedding_lookup), grad_plan((batch, seq), vocab, n_embd, 2)
     if plan["form"] == "chunked":
         # the dense cell, and since PR 38 the window-and-global cell: 16,384 ids against the 12,288 rows this chip holds
@@ -364,7 +373,7 @@ def test_two_layer_model_forward_backward_compiles_for_v5e(v5e, monkeypatch):
     def loss(params, tokens):
         return model.apply(params, {"input_ids": tokens})["logits"].astype(F32).mean()
 
-    text = jax.jit(jax.grad(loss)).lower(params, tokens).compile().as_text()
+    text = compiled_for_the_chip(jax.jit(jax.grad(loss)).lower(params, tokens)).as_text()
     kernel_lines = [line for line in text.splitlines() if 'custom_call_target="tpu_custom_call"' in line]
     for kernel in ("flash_attention_fwd", "flash_attention_bwd", "fused_rmsnorm_fwd", "fused_rmsnorm_bwd"):
         assert any(f"{kernel})" in line or f"{kernel}/" in line for line in kernel_lines), (kernel, len(kernel_lines))
@@ -409,7 +418,7 @@ def test_the_looped_steps_backward_adds_a_layers_gradient_into_one_stack(v5e, mo
             return out["exits"].astype(F32).mean() + jnp.tanh(out["gate_logits"]).mean()
 
         stack = sum(leaf.size * leaf.dtype.itemsize for leaf in jax.tree.leaves(params["params"]["blocks"]))
-        executable = jax.jit(jax.grad(loss)).lower(params, tokens).compile()
+        executable = compiled_for_the_chip(jax.jit(jax.grad(loss)).lower(params, tokens))
         sums = [line for line in executable.as_text().splitlines()
                 if re.search(rf"= bf16\[{layers},\d+,\d+\]", line) and re.search(r'op_name="[^"]*/loop/while/body/[^"]*add_any"', line)]
         return executable.memory_analysis().temp_size_in_bytes, sums, stack
@@ -434,7 +443,7 @@ def _compiled_cell_step(v5e, monkeypatch, tmp_path, config: str, vocab_size: int
     mix = {"sequences": 8, "size_seed": 1, "doc_len_median": 600, "doc_len_sigma": 1.0, "doc_len_min": 32, "doc_len_max": 8192}
     packed_documents.generate(mix, 1, tmp_path / "data" / "train.pbin", vocab_size=vocab_size, sequence_length=sequence_length)
     repo = os.path.dirname(os.path.dirname(os.path.dirname(os.path.abspath(__file__))))
-    executable = build_lowered_train_step(os.path.join(repo, "benchmark", "configs", config, "train.yaml")).lowered.compile()
+    executable = compiled_for_the_chip(build_lowered_train_step(os.path.join(repo, "benchmark", "configs", config, "train.yaml")).lowered)
     memory = executable.memory_analysis()
     return executable.as_text(), memory.argument_size_in_bytes + memory.output_size_in_bytes + memory.temp_size_in_bytes - memory.alias_size_in_bytes
 

@@ -12,6 +12,8 @@ from pathlib import Path
 import numpy as np
 import pytest
 
+from tests.conftest import CPU_COMPILE_FLAGS, xla_flags
+
 WORKER = Path(__file__).parent / "multiprocess_worker.py"
 
 # Some jaxlib builds cannot run cross-process collectives on the CPU backend at
@@ -42,7 +44,7 @@ def _require_mp_cpu_collectives() -> None:
     when this jaxlib cannot run cross-process CPU collectives at all. One cheap
     psum probe (two bare interpreters) per session, memoized."""
     if not _MP_CPU_PROBE:
-        env = {**_clean_env(), "XLA_FLAGS": "--xla_force_host_platform_device_count=4"}
+        env = {**_clean_env(), "XLA_FLAGS": xla_flags(4)}
         port = _free_port()
         procs = [
             subprocess.Popen(
@@ -64,7 +66,7 @@ def _require_mp_cpu_collectives() -> None:
 def _clean_env():
     env = dict(os.environ)
     env["JAX_PLATFORMS"] = "cpu"
-    env.pop("XLA_FLAGS", None)  # the worker sets its own device count (4 per process)
+    env["XLA_FLAGS"] = CPU_COMPILE_FLAGS  # the worker adds its own device count (4 per process)
     env["PYTHONPATH"] = str(WORKER.parent.parent.parent)
     return env
 
@@ -75,76 +77,66 @@ def _free_port() -> int:
         return s.getsockname()[1]
 
 
-def _parse_loss(out: str) -> float:
-    for line in out.splitlines():
-        if line.startswith("LOSS "):
-            return float(line.split()[1])
-    raise AssertionError(f"no LOSS line in output:\n{out}")
+def _parse_losses(out: str) -> list[float]:
+    losses = [float(line.split()[1]) for line in out.splitlines() if line.startswith("LOSS ")]
+    assert losses, f"no LOSS line in output:\n{out}"
+    return losses
 
 
-def _run_two_process_vs_single(mode: str):
-    _require_mp_cpu_collectives()
-    env = _clean_env()
-    # the oracle recreates the GLOBAL 8-device mesh in one process (2 x 4 below)
-    single = subprocess.run(
+def _start_single(mode: str, env: dict) -> subprocess.Popen:
+    """One process that recreates the GLOBAL 8-device mesh (2 x 4 in a pair). It runs beside the pair it is the oracle of:
+    a test's seconds are its processes' start-ups in a row, and the two sides need nothing of each other."""
+    return subprocess.Popen(
         [sys.executable, str(WORKER), "single", mode],
-        capture_output=True, text=True, timeout=600, env={**env, "MP_WORKER_DEVICES": "8"},
+        stdout=subprocess.PIPE, stderr=subprocess.PIPE, text=True, env={**env, "MP_WORKER_DEVICES": "8"},
     )
-    assert single.returncode == 0, single.stderr[-3000:]
-    oracle = _parse_loss(single.stdout)
 
+
+def _start_pair(mode: str, env: dict) -> list[subprocess.Popen]:
     port = _free_port()
-    procs = [
+    return [
         subprocess.Popen(
             [sys.executable, str(WORKER), str(port), str(pid), "2", mode],
             stdout=subprocess.PIPE, stderr=subprocess.PIPE, text=True, env=env,
         )
         for pid in range(2)
     ]
-    outs = []
+
+
+def _single_losses(proc: subprocess.Popen) -> list[float]:
+    out, err = proc.communicate(timeout=600)
+    assert proc.returncode == 0, err[-3000:]
+    return _parse_losses(out)
+
+
+def _pair_losses(procs: list[subprocess.Popen]) -> list[list[float]]:
+    outs, eids = [], []
     for p in procs:
         out, err = p.communicate(timeout=600)
         _skip_if_mp_cpu_unsupported(err)
         assert p.returncode == 0, err[-3000:]
         assert "COMM OK" in out, f"multi-process communication test failed:\n{out}"
-        outs.append(_parse_loss(out))
+        eids += [line.split(None, 1)[1] for line in out.splitlines() if line.startswith("EID ")]
+        outs.append(_parse_losses(out))
+    # experiment-id sync: process 0 generated it, every process adopted it
+    assert len(eids) == 2 and eids[0] == eids[1], eids
+    return outs
 
+
+def _run_two_process_vs_single(mode: str):
+    _require_mp_cpu_collectives()
+    env = _clean_env()
+    single, pair = _start_single(mode, env), _start_pair(mode, env)
+    (oracle,), outs = _single_losses(single), _pair_losses(pair)
     # every process reports the same global loss, equal to the single-process oracle
-    assert outs[0] == outs[1]
-    assert abs(outs[0] - oracle) < 1e-5, (outs, oracle)
+    assert outs[0] == outs[1] and len(outs[0]) == 1
+    assert abs(outs[0][0] - oracle) < 1e-5, (outs, oracle)
 
 
 def test_two_process_put_batch_matches_single_process():
     # each process fed only its own rows, so agreement proves the local-shard
     # assembly (make_array_from_process_local_data) is right
     _run_two_process_vs_single("dp")
-
-
-def _parse_losses(out: str) -> list[float]:
-    return [float(line.split()[1]) for line in out.splitlines() if line.startswith("LOSS ")]
-
-
-def _run_two_procs(mode: str, env: dict) -> list[list[float]]:
-    _require_mp_cpu_collectives()
-    port = _free_port()
-    procs = [
-        subprocess.Popen(
-            [sys.executable, str(WORKER), str(port), str(pid), "2", mode],
-            stdout=subprocess.PIPE, stderr=subprocess.PIPE, text=True, env=env,
-        )
-        for pid in range(2)
-    ]
-    outs, eids = [], []
-    for p in procs:
-        out, err = p.communicate(timeout=600)
-        _skip_if_mp_cpu_unsupported(err)
-        assert p.returncode == 0, err[-3000:]
-        assert "COMM OK" in out
-        eids += [line.split(None, 1)[1] for line in out.splitlines() if line.startswith("EID ")]
-        outs.append(_parse_losses(out))
-    # experiment-id sync: process 0 generated it, every process adopted it
-    assert len(eids) == 2 and eids[0] == eids[1], eids
-    return outs
 
 
 def test_multiprocess_orbax_checkpoint_save_and_crosstopology_resume(tmp_path):
@@ -157,34 +149,23 @@ def test_multiprocess_orbax_checkpoint_save_and_crosstopology_resume(tmp_path):
     _require_mp_cpu_collectives()
     env = {**_clean_env(), "MP_CKPT_DIR": str(tmp_path)}
 
-    single = subprocess.run(
-        [sys.executable, str(WORKER), "single", "ckpt_oracle"],
-        capture_output=True, text=True, timeout=600, env={**env, "MP_WORKER_DEVICES": "8"},
-    )
-    assert single.returncode == 0, single.stderr[-3000:]
-    oracle = _parse_losses(single.stdout)
+    # phase A: 2-process train + collective save, beside the oracle's five uninterrupted steps
+    single, pair = _start_single("ckpt_oracle", env), _start_pair("ckpt_save", env)
+    oracle, outs = _single_losses(single), _pair_losses(pair)
     assert len(oracle) == 5
-
-    # phase A: 2-process train + collective save
-    outs = _run_two_procs("ckpt_save", env)
     assert outs[0] == outs[1]
     assert np.allclose(outs[0], oracle[:3], atol=1e-5), (outs[0], oracle[:3])
     folders = [p.name for p in tmp_path.iterdir() if p.is_dir()]
     assert any("seen_steps_3-seen_tokens_384-" in f for f in folders), folders
     assert (tmp_path / "last_checkpoint_info.json").exists()
 
-    # phase B1: resume with the SAME process topology (2 x 4 devices)
-    outs2 = _run_two_procs("ckpt_resume", env)
+    # phase B, both readers of the one checkpoint at once: (1) resume with the SAME process topology (2 x 4 devices),
+    # (2) resume SINGLE-process on the 8-device mesh (process count changed)
+    single2, pair2 = _start_single("ckpt_resume", env), _start_pair("ckpt_resume", env)
+    resumed_single, outs2 = _single_losses(single2), _pair_losses(pair2)
     assert outs2[0] == outs2[1]
     assert np.allclose(outs2[0], oracle[3:], atol=1e-5), (outs2[0], oracle[3:])
-
-    # phase B2: resume SINGLE-process on the 8-device mesh (process count changed)
-    single2 = subprocess.run(
-        [sys.executable, str(WORKER), "single", "ckpt_resume"],
-        capture_output=True, text=True, timeout=600, env={**env, "MP_WORKER_DEVICES": "8"},
-    )
-    assert single2.returncode == 0, single2.stderr[-3000:]
-    assert np.allclose(_parse_losses(single2.stdout), oracle[3:], atol=1e-5)
+    assert np.allclose(resumed_single, oracle[3:], atol=1e-5)
 
 
 def test_two_process_hsdp_replicate_axis_crosses_process_boundary():
@@ -210,16 +191,8 @@ def test_single_process_cp_feeder_async_matches_sync():
     cp seq-dim slicing (`local_seq_slice`) runs on the feeder's background thread
     and must be loss-exact vs the inline path — the runnable half of the feeder
     cp contract even on jaxlibs without multiprocess CPU collectives."""
-    env = {**_clean_env(), "MP_WORKER_DEVICES": "8"}
-    outs = []
-    for prefetch in ("0", "2"):
-        p = subprocess.run(
-            [sys.executable, str(WORKER), "single", "feeder_cp"],
-            capture_output=True, text=True, timeout=600,
-            env={**env, "MP_FEEDER_PREFETCH": prefetch},
-        )
-        assert p.returncode == 0, p.stderr[-3000:]
-        outs.append(_parse_losses(p.stdout))
+    procs = [_start_single("feeder_cp", {**_clean_env(), "MP_FEEDER_PREFETCH": prefetch}) for prefetch in ("0", "2")]
+    outs = [_single_losses(p) for p in procs]
     assert len(outs[0]) == 3
     assert outs[0] == outs[1], outs
 
@@ -234,16 +207,10 @@ def test_two_process_cp_feeder_async_matches_sync_and_single_process():
     enqueue-order contract and put_batch's `local_seq_slice`."""
     _require_mp_cpu_collectives()
     env = _clean_env()
-    single = subprocess.run(
-        [sys.executable, str(WORKER), "single", "feeder_cp"],
-        capture_output=True, text=True, timeout=600,
-        env={**env, "MP_WORKER_DEVICES": "8", "MP_FEEDER_PREFETCH": "0"},
-    )
-    assert single.returncode == 0, single.stderr[-3000:]
-    oracle = _parse_losses(single.stdout)
+    single = _start_single("feeder_cp", {**env, "MP_FEEDER_PREFETCH": "0"})
+    pair = _start_pair("feeder_cp", {**env, "MP_FEEDER_PREFETCH": "2"})
+    oracle, outs = _single_losses(single), _pair_losses(pair)
     assert len(oracle) == 3
-
-    outs = _run_two_procs("feeder_cp", {**env, "MP_FEEDER_PREFETCH": "2"})
     assert outs[0] == outs[1]
     assert np.allclose(outs[0], oracle, atol=1e-5), (outs, oracle)
 

@@ -180,9 +180,7 @@ def test_nan_grads_skip_step_finishes_with_finite_loss(workdir):
     assert skipped[0]["in_window"] == 1 and skipped[0]["budget"] == 2
 
 
-@pytest.mark.slow  # ~20 s; anomaly-policy semantics stay pinned fast by
-# tests/resilience/test_anomaly_tracker.py and the raise message by
-# test_trainer_raises_on_nonfinite_grads
+# back in tier 1 since PR 47 (10 s under the suite's compile rule): the one end-to-end run under the default policy
 def test_nan_grads_default_raise_policy_is_legacy_identical(workdir):
     """Under the default policy the same poison must still kill the run with the
     exact legacy message — resilience armed != behavior changed. The legacy

@@ -38,6 +38,7 @@ from modalities_tpu.resilience import PreemptionShutdown
 from modalities_tpu.resilience.events import counts_since, snapshot_counts
 from modalities_tpu.resilience.faults import arm_faults
 from modalities_tpu.resilience.manifest import MANIFEST_FILE_NAME, resolve_resume_folder
+from tests.conftest import xla_flags
 
 CONFIG = Path(__file__).parent.parent.parent / "configs" / "config_lorem_ipsum_tpu.yaml"
 WARMSTART_CONFIG = (
@@ -207,7 +208,7 @@ def test_host_loss_resumes_elastic_on_shrunk_topology(tmp_path):
     def _spawn_host(host_id: int) -> subprocess.Popen:
         env = dict(os.environ)
         env["JAX_PLATFORMS"] = "cpu"
-        env["XLA_FLAGS"] = "--xla_force_host_platform_device_count=4"
+        env["XLA_FLAGS"] = xla_flags(4)
         env["JAX_COORDINATOR_ADDRESS"] = f"127.0.0.1:{port}"
         env["JAX_NUM_PROCESSES"] = "2"
         env["JAX_PROCESS_ID"] = str(host_id)

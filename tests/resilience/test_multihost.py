@@ -22,6 +22,7 @@ import pytest
 
 from modalities_tpu.resilience import RESUMABLE_EXIT_CODE
 from modalities_tpu.resilience.manifest import MANIFEST_FILE_NAME
+from tests.conftest import CPU_COMPILE_FLAGS
 
 WORKER = Path(__file__).parent / "multihost_worker.py"
 CONFIG = Path(__file__).parent.parent.parent / "configs" / "config_lorem_ipsum_tpu.yaml"
@@ -32,7 +33,7 @@ _MP_CPU_UNSUPPORTED = "Multiprocess computations aren't implemented on the CPU b
 def _clean_env():
     env = dict(os.environ)
     env["JAX_PLATFORMS"] = "cpu"
-    env.pop("XLA_FLAGS", None)  # the worker sets its own device count (4 per process)
+    env["XLA_FLAGS"] = CPU_COMPILE_FLAGS  # the worker adds its own device count (4 per process)
     env.pop("MODALITIES_TPU_FAULTS", None)
     env["PYTHONPATH"] = str(WORKER.parent.parent.parent)
     return env

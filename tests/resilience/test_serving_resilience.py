@@ -39,6 +39,7 @@ from modalities_tpu.serving.resilience import (
 from modalities_tpu.serving.fleet.router import FleetRouter, WorkerHandle
 from modalities_tpu.serving.server import ServingHTTPServer
 from modalities_tpu.telemetry.metrics import MetricsRegistry
+from tests.conftest import start_and_await_first_sweep
 from tests.serving.test_fleet_router import _ScriptedWorker, _get
 from tests.serving.test_observability import VOCAB, FakeModel, _tick_clock
 
@@ -78,17 +79,6 @@ def _post(port, path, body, headers=None, timeout=30.0):
         return resp.status, events, resp_headers
     finally:
         conn.close()
-
-
-def _await_first_health_sweep(router):
-    deadline = time.monotonic() + 5.0
-    hb0 = {w.name: w.last_heartbeat for w in router.workers}
-    while time.monotonic() < deadline:
-        if all(w.last_heartbeat > hb0[w.name] for w in router.workers):
-            time.sleep(0.05)
-            return
-        time.sleep(0.01)
-    pytest.fail("first health sweep never completed")
 
 
 # ------------------------------------------------------- resilience primitives
@@ -723,9 +713,8 @@ def test_sse_torn_failover_delivers_exactly_once():
         [WorkerHandle(f"w{i}", "127.0.0.1", s.port) for i, s in enumerate(servers)],
         metrics=MetricsRegistry(), health_interval_s=30.0,
     )
-    router.start()
+    start_and_await_first_sweep(router)
     try:
-        _await_first_health_sweep(router)
         status, events, _ = _post(
             router.port, "/generate", {"prompt": "3 4", "max_new_tokens": 5}
         )
@@ -761,9 +750,8 @@ def test_retry_budget_exhaustion_is_counter_pinned():
         metrics=registry, health_interval_s=30.0,
     )
     router.retry_budget = RetryBudget(ratio=0.0, cap=1.0)  # one funded retry
-    router.start()
+    start_and_await_first_sweep(router)
     try:
-        _await_first_health_sweep(router)
         status, events, _ = _post(
             router.port, "/generate", {"prompt": "x"},
             headers={"X-Deadline-Ms": "60000"},

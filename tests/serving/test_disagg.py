@@ -20,7 +20,6 @@ import copy
 import http.client
 import json
 import threading
-import time
 
 import jax
 import numpy as np
@@ -45,6 +44,7 @@ from modalities_tpu.serving.server import (
     sse_event_bytes,
 )
 from modalities_tpu.telemetry.metrics import MetricsRegistry
+from tests.conftest import start_and_await_first_sweep
 from tests.models.test_gpt2_model import tiny_gpt2
 
 # mixed greedy/sampled, short/multi-block (17 tokens spans 3 blocks at bs=8),
@@ -524,18 +524,6 @@ def _post_generate(port, body, timeout=30.0):
         conn.close()
 
 
-def _wait_first_sweep(router):
-    deadline = time.monotonic() + 5.0
-    hb0 = {w.name: w.last_heartbeat for w in router.workers}
-    while time.monotonic() < deadline:
-        if all(w.last_heartbeat > hb0[w.name] for w in router.workers):
-            break
-        time.sleep(0.01)
-    else:
-        pytest.fail("first health sweep never completed")
-    time.sleep(0.05)
-
-
 def test_decode_leg_failover_replays_same_trace_exact_splice():
     """A decode worker dies after 2 of 4 tokens: the request replays through a
     FRESH prefill on the healthy pair — same trace_id on all four legs, hop
@@ -553,9 +541,8 @@ def test_decode_leg_failover_replays_same_trace_exact_splice():
         metrics=registry,
         health_interval_s=30.0,  # no probe mid-test: failover state stays visible
     )
-    router.start()
+    start_and_await_first_sweep(router)
     try:
-        _wait_first_sweep(router)
         status, events = _post_generate(router.port, {"prompt": "3 4", "max_new_tokens": 5})
         assert status == 200
         streamed = [e["token_id"] for e in events if "token_id" in e]
@@ -594,9 +581,8 @@ def test_rejected_import_keeps_worker_in_rotation_and_replays():
         metrics=MetricsRegistry(),
         health_interval_s=30.0,
     )
-    router.start()
+    start_and_await_first_sweep(router)
     try:
-        _wait_first_sweep(router)
         before = snapshot_counts()
         status, events = _post_generate(router.port, {"prompt": "3 4", "max_new_tokens": 5})
         assert status == 200
@@ -666,9 +652,8 @@ def test_http_two_leg_one_trace_id_and_stitched_tier_tree(
         metrics=MetricsRegistry(),
         health_interval_s=30.0,
     )
-    router.start()
+    start_and_await_first_sweep(router)
     try:
-        _wait_first_sweep(router)
 
         # misrouted tier endpoints refuse loudly instead of half-serving
         for port, path in ((servers[1].port, "/disagg/prefill"),

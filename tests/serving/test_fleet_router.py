@@ -29,6 +29,7 @@ from modalities_tpu.serving.server import (
     sse_event_bytes,
 )
 from modalities_tpu.telemetry.metrics import MetricsRegistry, parse_prometheus_text
+from tests.conftest import start_and_await_first_sweep
 from tests.serving.test_observability import VOCAB, FakeModel
 
 ANSWER = [11, 12, 13, 14, 15]
@@ -170,21 +171,10 @@ def test_mid_stream_failover_splices_one_answer():
         metrics=registry,
         health_interval_s=30.0,  # no probe mid-test: failover state stays visible
     )
-    router.start()
+    # the FIRST health round is over before traffic: a probe in flight during the failover would race the
+    # unhealthy mark (the next sweep is 30 s out, so after this the failover state stays visible)
+    start_and_await_first_sweep(router)
     try:
-        # let the FIRST health sweep finish before traffic: a probe in flight
-        # during the failover would race the unhealthy mark (the next sweep is
-        # 30s out, so after this the failover state stays visible)
-        deadline = time.monotonic() + 5.0
-        hb0 = {w.name: w.last_heartbeat for w in router.workers}
-        while time.monotonic() < deadline:
-            if all(w.last_heartbeat > hb0[w.name] for w in router.workers):
-                break
-            time.sleep(0.01)
-        else:
-            pytest.fail("first health sweep never completed")
-        time.sleep(0.05)  # sweep evaluation phase is sync right after the probes
-
         status, events = _post_generate(router.port, {"prompt": "x", "max_new_tokens": 5})
         assert status == 200
         streamed = [e["token_id"] for e in events if "token_id" in e]
@@ -365,18 +355,8 @@ def test_failover_one_trace_id_across_router_workers_and_stitched_tree(tmp_path)
         ],
         health_interval_s=30.0,
     )
-    router.start()
+    start_and_await_first_sweep(router)
     try:
-        deadline = time.monotonic() + 5.0
-        hb0 = {w.name: w.last_heartbeat for w in router.workers}
-        while time.monotonic() < deadline:
-            if all(w.last_heartbeat > hb0[w.name] for w in router.workers):
-                break
-            time.sleep(0.01)
-        else:
-            pytest.fail("first health sweep never completed")
-        time.sleep(0.05)
-
         status, events = _post_generate(
             router.port, {"prompt": "3 4", "max_new_tokens": 5}
         )

@@ -25,6 +25,7 @@ from modalities_tpu.serving.server import ServingHTTPServer
 from modalities_tpu.telemetry import Telemetry, set_active_telemetry
 from modalities_tpu.telemetry.metrics import MetricsRegistry, parse_prometheus_text
 from modalities_tpu.telemetry.slo import SLOEngine, load_slo_spec
+from tests.conftest import start_and_await_first_sweep
 from tests.serving.test_observability import FakeModel
 
 SLO_SPEC = {"objectives": [{"name": "ttft_p99", "expr": "serve_ttft_seconds p99 < 0.5"}]}
@@ -119,15 +120,7 @@ def test_latency_poisoned_canary_rolls_back_on_slo_with_zero_drops(tmp_path):
             metrics=fleet_registry,
             health_interval_s=0.1,
         )
-        router.start()
-        deadline = time.monotonic() + 5.0
-        hb0 = {w.name: w.last_heartbeat for w in router.workers}
-        while time.monotonic() < deadline:  # first health sweep before traffic
-            if all(w.last_heartbeat > hb0[w.name] for w in router.workers):
-                break
-            time.sleep(0.01)
-        else:
-            pytest.fail("first health sweep never completed")
+        start_and_await_first_sweep(router)  # before traffic
 
         stop = threading.Event()
 
@@ -156,18 +149,18 @@ def test_latency_poisoned_canary_rolls_back_on_slo_with_zero_drops(tmp_path):
         status, health = _get(canary.server.port, "/healthz")
         assert (status, health["status"]) == (200, "degraded")
         assert health["slo_breaching"] == ["ttft_p99"]
-        deadline = time.monotonic() + 5.0
+        # a sweep marks the worker at its probe and counts the degraded at the round's end: wait for both
+        deadline = time.monotonic() + 30.0
         while time.monotonic() < deadline:
             _, table = _get(router.port, "/fleet")
             by_name = {w["name"]: w for w in table["workers"]}
-            if by_name[canary.name]["degraded"]:
+            parsed = parse_prometheus_text(fleet_registry.render())
+            if by_name[canary.name]["degraded"] and parsed["fleet_workers_degraded"][()] == 1.0:
                 break
             time.sleep(0.05)
         else:
-            pytest.fail("router sweep never marked the canary degraded")
+            pytest.fail("router sweep never marked and counted the canary degraded")
         assert by_name[peer.name]["degraded"] is False
-        parsed = parse_prometheus_text(fleet_registry.render())
-        assert parsed["fleet_workers_degraded"][()] == 1.0
         assert parsed["fleet_rollbacks_total"][()] == 1.0
 
         # traffic keeps flowing after the rollback — wait for round-trips, not

@@ -36,6 +36,10 @@ def served_rows(tmp_path_factory):
     # exact size; widths are already at the validator's floor of 128) — keeps
     # the compile out of the tier-1 budget
     cfg["serving_component"]["config"]["model"]["config"]["n_layer"] = 1
+    # the shipped objectives stay armed, their sampler's first tick an hour away: the first request's TTFT is its compile,
+    # and where the replay took over the shipped 5 s (a busy machine) the tick read 5.5 s against `p99 < 0.5`, the brownout
+    # shed the queued third request, and its row said "shed" (PR 47's run; docs/known_failures.md)
+    cfg["serving_component"]["config"]["slo"]["sample_interval_s"] = 3600.0
     cfg_path = workdir / "config_serve.yaml"
     cfg_path.write_text(yaml.safe_dump(cfg))
 
@@ -104,7 +108,7 @@ def test_serve_cli_http_end_to_end_with_sigterm_drain(tmp_path):
     try:
         deadline = time.monotonic() + 240
         while True:  # healthz poll: imports + engine construction dominate
-            assert proc.poll() is None, proc.communicate()[1][-3000:]
+            assert proc.poll() is None, proc.communicate(timeout=30)[1][-3000:]
             try:
                 conn = http.client.HTTPConnection("127.0.0.1", port, timeout=5)
                 conn.request("GET", "/healthz")
@@ -139,4 +143,4 @@ def test_serve_cli_http_end_to_end_with_sigterm_drain(tmp_path):
     finally:
         if proc.poll() is None:
             proc.kill()
-            proc.wait()
+            proc.wait(timeout=30)

@@ -161,7 +161,8 @@ def test_concurrent_updates_lose_nothing():
     for t in threads:
         t.start()
     for t in threads:
-        t.join()
+        t.join(timeout=60.0)
+        assert not t.is_alive(), "a counting thread never finished"
     total = n_threads * per_thread
     assert c.value(reason="0") + c.value(reason="1") == total
     assert h.count() == total

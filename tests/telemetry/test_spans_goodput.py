@@ -60,7 +60,8 @@ def test_background_thread_spans_are_not_timeline():
 
     t = threading.Thread(target=work, name="bg-thread")
     t.start()
-    t.join()
+    t.join(timeout=30.0)
+    assert not t.is_alive(), "the background span's thread never finished"
     (record,) = records
     assert record.thread == "bg-thread" and not record.timeline
     # and the ledger ignores it: overlapped background work must not double-count

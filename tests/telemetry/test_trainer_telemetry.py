@@ -4,6 +4,7 @@ tile wall time, and a wedged step leaves a watchdog artifact containing the
 feeder thread."""
 
 import json
+import re
 import time
 from types import SimpleNamespace
 
@@ -302,7 +303,10 @@ def test_a_stalled_step_raises_the_anomaly_and_names_the_span_that_held_the_exce
         program_log.removeHandler(caplog.handler)
     telemetry.close()
     said = [r.getMessage() for r in caplog.records if "times the usual" in r.getMessage()]
-    assert len(said) == 1 and f"meanwhile: {where} 0.4" in said[0], said  # the log gets the step of seconds, and it alone
+    # the log gets the stalled step, and it alone among the steps of tenths of a second; as with the events below, a loaded
+    # machine may add a milder line (PR 47's run: a neighbour of 38 ms, 2.1 times the usual 17, 23 ms of it in no span)
+    stalled = [line for line in said if float(re.search(r"took ([\d.]+) s", line).group(1)) >= stall_s]
+    assert len(stalled) == 1 and f"meanwhile: {where} 0.4" in stalled[0], said
 
     # the first interval holds the first step (trace + compile) and is kept from the detector
     assert [step for step, _ in observed] == list(range(2, n_steps + 1))

@@ -5,6 +5,7 @@ thread must join cleanly on the normal and the exception-propagation path."""
 import json
 import threading
 import time
+from types import SimpleNamespace
 
 import pytest
 
@@ -52,13 +53,20 @@ def test_deadline_fires_and_artifact_contains_feeder_thread(tmp_path):
     assert len(watchdog.fired_artifacts) == 1
 
 
-def test_heartbeat_under_normal_stepping_never_fires(tmp_path):
+def test_heartbeat_under_normal_stepping_never_fires(tmp_path, monkeypatch):
+    """Seven beats a third of the deadline apart, on a clock the test steps: on the wall clock a busy machine's `sleep(0.05)`
+    that took 0.15 s would have been the watchdog's 'hang' (the poll itself stays real: it sees every stepped instant)."""
+    from modalities_tpu.telemetry import watchdog as watchdog_module
+
+    now = [1000.0]
+    monkeypatch.setattr(watchdog_module, "time", SimpleNamespace(monotonic=lambda: now[0], time=time.time))
     watchdog = Watchdog(deadline_s=0.15, artifact_dir=tmp_path, poll_interval_s=0.01)
     watchdog.start()
     watchdog.arm(step_id=1)
     try:
-        for step in range(1, 8):  # ~0.35s of stepping, each beat well inside the deadline
-            time.sleep(0.05)
+        for step in range(1, 8):  # 0.35 s of stepping, each beat well inside the deadline
+            now[0] += 0.05
+            time.sleep(0.02)  # two polls at this instant
             watchdog.beat(step)
     finally:
         watchdog.stop()
@@ -90,6 +98,10 @@ def test_stop_joins_cleanly_on_normal_exit(tmp_path):
 def test_stop_joins_cleanly_on_exception_propagation(tmp_path):
     """The telemetry close runs in a finally while a training error propagates —
     the watchdog thread must be gone afterwards, not leaked."""
+    def watchdogs():
+        return {t for t in threading.enumerate() if t.name == "telemetry-watchdog"}
+
+    before = watchdogs()  # another test's, in this worker, is not this telemetry's leak
     telemetry = Telemetry(output_folder_path=tmp_path, watchdog_deadline_s=30.0)
     with pytest.raises(RuntimeError, match="train blew up"):
         try:
@@ -99,7 +111,7 @@ def test_stop_joins_cleanly_on_exception_propagation(tmp_path):
         finally:
             telemetry.close()
     assert telemetry._watchdog is not None and not telemetry._watchdog.is_alive
-    assert "telemetry-watchdog" not in [t.name for t in threading.enumerate()]
+    assert watchdogs() <= before
 
 
 def test_disarm_suspends_checking(tmp_path):

@@ -1,7 +1,7 @@
-"""The tier-1 slowest-test artifact hook (tests/conftest.py): session end writes
-the top-N call-phase durations as JSONL so slow-marking rebalances read data
-instead of scrollback. Exercised by driving the hook functions directly against
-a stub session — a real nested pytest run would cost more than the hook saves."""
+"""The tier-1 durations artifact (tests/conftest.py): the controller appends every
+report's seconds as it arrives, so a run the clock cuts leaves what it reached.
+Exercised by driving the hook functions directly against a stub session — a real
+nested pytest run would cost more than the hook saves."""
 
 import json
 import types
@@ -14,43 +14,54 @@ def _stub_session(rootpath):
     return types.SimpleNamespace(config=config)
 
 
-def _stub_report(nodeid, when, duration):
-    return types.SimpleNamespace(nodeid=nodeid, when=when, duration=duration)
+def _stub_report(nodeid, when, duration, outcome="passed"):
+    return types.SimpleNamespace(nodeid=nodeid, when=when, duration=duration, outcome=outcome)
 
 
-def test_durations_artifact_keeps_slowest_call_phases(tmp_path, monkeypatch):
-    monkeypatch.setattr(harness, "_durations", {})
-    monkeypatch.setattr(harness, "_DURATIONS_TOP_N", 2)
-    monkeypatch.setenv(
-        "MODALITIES_TPU_TEST_DURATIONS_PATH", str(tmp_path / "durations.jsonl")
-    )
-    harness.pytest_runtest_logreport(_stub_report("t/a.py::fast", "call", 0.01))
-    harness.pytest_runtest_logreport(_stub_report("t/a.py::slow", "call", 3.5))
-    harness.pytest_runtest_logreport(_stub_report("t/a.py::mid", "call", 1.25))
-    # setup/teardown phases never count toward the wall-time budget
+def _rows(path):
+    return [json.loads(line) for line in path.read_text().splitlines()]
+
+
+def test_durations_artifact_holds_every_report_as_it_arrives(tmp_path, monkeypatch):
+    artifact = tmp_path / "durations.jsonl"
+    artifact.write_text('{"nodeid": "an older run"}\n')
+    monkeypatch.setattr(harness, "_durations_path", None)
+    monkeypatch.setenv("MODALITIES_TPU_TEST_DURATIONS_PATH", str(artifact))
+    harness.pytest_sessionstart(_stub_session(tmp_path))
+    assert artifact.read_text() == ""  # a run's file holds that run alone
+
     harness.pytest_runtest_logreport(_stub_report("t/a.py::slow", "setup", 99.0))
-
-    harness.pytest_sessionfinish(_stub_session(tmp_path), exitstatus=0)
-    rows = [
-        json.loads(line)
-        for line in (tmp_path / "durations.jsonl").read_text().splitlines()
+    harness.pytest_runtest_logreport(_stub_report("t/a.py::slow", "call", 3.5, "failed"))
+    # no session end is needed: a run cut here has both lines
+    assert _rows(artifact) == [
+        {"nodeid": "t/a.py::slow", "when": "setup", "duration_s": 99.0, "outcome": "passed"},
+        {"nodeid": "t/a.py::slow", "when": "call", "duration_s": 3.5, "outcome": "failed"},
     ]
-    assert [r["nodeid"] for r in rows] == ["t/a.py::slow", "t/a.py::mid"]
-    assert rows[0]["duration_s"] == 3.5
+    harness.pytest_runtest_logreport(_stub_report("t/a.py::fast", "call", 0.0104))
+    assert [(r["nodeid"], r["duration_s"]) for r in _rows(artifact)][2:] == [("t/a.py::fast", 0.01)]
 
 
 def test_durations_artifact_disable_and_xdist_worker_skip(tmp_path, monkeypatch):
-    monkeypatch.setattr(harness, "_durations", {"t::x": 1.0})
+    monkeypatch.setattr(harness, "_durations_path", None)
     monkeypatch.setenv("MODALITIES_TPU_TEST_DURATIONS_PATH", "")  # "" disables
-    harness.pytest_sessionfinish(_stub_session(tmp_path), exitstatus=0)
+    harness.pytest_sessionstart(_stub_session(tmp_path))
+    harness.pytest_runtest_logreport(_stub_report("t::x", "call", 1.0))
     assert list(tmp_path.iterdir()) == []
 
     monkeypatch.delenv("MODALITIES_TPU_TEST_DURATIONS_PATH")
     worker = _stub_session(tmp_path)
-    worker.config.workerinput = {"workerid": "gw0"}  # xdist worker: partial view
-    harness.pytest_sessionfinish(worker, exitstatus=0)
+    worker.config.workerinput = {"workerid": "gw0"}  # xdist worker: its reports reach the controller's hook
+    harness.pytest_sessionstart(worker)
+    harness.pytest_runtest_logreport(_stub_report("t::x", "call", 1.0))
     assert list(tmp_path.iterdir()) == []
 
     # default path lands at <rootdir>/test_durations.jsonl
-    harness.pytest_sessionfinish(_stub_session(tmp_path), exitstatus=0)
-    assert (tmp_path / "test_durations.jsonl").exists()
+    harness.pytest_sessionstart(_stub_session(tmp_path))
+    harness.pytest_runtest_logreport(_stub_report("t::x", "call", 1.0))
+    assert [r["nodeid"] for r in _rows(tmp_path / "test_durations.jsonl")] == ["t::x"]
+
+    # an unwritable path never fails the suite
+    monkeypatch.setenv("MODALITIES_TPU_TEST_DURATIONS_PATH", str(tmp_path / "no" / "such" / "dir.jsonl"))
+    harness.pytest_sessionstart(_stub_session(tmp_path))
+    harness.pytest_runtest_logreport(_stub_report("t::y", "call", 1.0))
+    assert harness._durations_path is None

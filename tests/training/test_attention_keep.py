@@ -46,10 +46,6 @@ def walked(jaxpr, calls: dict, named: dict):
     return calls, named
 
 
-# the interpreted kernels compile for seconds at XLA's full effort on a CPU; both programs of a pair get the same little
-FAST_COMPILE = {"xla_backend_optimization_level": 0, "xla_llvm_disable_expensive_passes": True}
-
-
 @pytest.mark.parametrize("kind", sorted(KINDS))
 def test_a_full_remat_block_that_keeps_o_and_lse_runs_the_forward_kernel_once(kernels_interpreted, kind):
     mixer, keys = KINDS[kind]
@@ -69,7 +65,7 @@ def test_a_full_remat_block_that_keeps_o_and_lse_runs_the_forward_kernel_once(ke
 
         traced = jax.jit(jax.grad(loss, argnums=(0, 1))).trace(params, x)
         # the compressed mixer's block is counted and not run (its two programs compile for 9 s): its call is `grouped_heads`' kernel
-        gradients = () if mixer == "cca" else traced.lower().compile(compiler_options=FAST_COMPILE)(params, x)
+        gradients = () if mixer == "cca" else traced.lower().compile()(params, x)
         results[kept] = (*walked(traced.jaxpr.jaxpr, {}, {}), gradients)
     forward = "flash_attention_window_fwd" if mixer == "swa" else "flash_attention_fwd"
     (calls, named, plain), (kept_calls, kept_named, kept) = results[False], results[True]

@@ -273,10 +273,7 @@ def _warmstart(donor_state, fns):
     )
 
 
-@pytest.mark.slow  # ~27 s; the hierarchical-reduction structure stays pinned fast by
-# test_one_cross_slice_reduction_per_optimizer_step +
-# test_reduce_scatter_and_gather_stay_intra_slice (HLO profile on the shared
-# dcn_compiles fixture); the numeric twin runs in the slow tier
+# back in tier 1 since PR 47 (9 s under the suite's compile rule; the HLO profile beside it holds the structure, this the numbers)
 def test_dcn_losses_match_flat_dp_twin():
     """dcn2 x dp4 == dp8 to rtol 1e-5 (3 train steps + eval) — the multi-slice
     acceptance pin. (The ZeRO-1 x dcn composition is pinned structurally above —

@@ -3,6 +3,7 @@ sharding works across dp/tp layouts, grad accumulation invariance
 (mirrors the reference's fsdp2_parallelization equivalence suite, SURVEY.md §4)."""
 
 import contextlib
+import functools
 
 import jax
 import jax.numpy as jnp
@@ -39,6 +40,46 @@ def _builder(model, mesh_handle, acc=1, clip=None):
     )
 
 
+def _losses(fns, raw, steps, state=None):
+    """The losses of `steps` train steps on one batch, from the built state or the one handed in."""
+    state = fns.app_state_handle.state if state is None else state
+    losses = []
+    for _ in range(steps):
+        state, metrics = fns.train_step(state, fns.put_batch(raw))
+        losses.append(float(metrics["loss"]))
+    return losses
+
+
+@functools.lru_cache(maxsize=None)
+def _dp8(seq, **model):
+    """The pure-dp twin every other layout is held to: one toy model at one row length, built and compiled once for all the
+    tests that compare against it (the step donates its state, so the initial state is kept on the host and put again)."""
+    mesh = get_device_mesh(device_type="cpu", data_parallel_shard_degree=8, world_size=8)
+    fns = _builder(tiny_gpt2("pytorch_flash", **model), mesh, clip=1.0).build(seed=0)
+    return fns, jax.device_get(fns.app_state_handle.state)
+
+
+def _dp8_state(fns, initial):
+    return jax.device_put(initial, fns.app_state_handle.state_shardings)
+
+
+def _dp8_losses(raw, steps, **model):
+    fns, initial = _dp8(raw["samples"]["input_ids"].shape[-1], **model)
+    return _losses(fns, raw, steps, _dp8_state(fns, initial))
+
+
+def _layout_losses(mesh, raw, steps, spec=None, **model):
+    """The same toy model, seed and batch under another mesh, with `spec` laid over the model's spec."""
+    model_run = tiny_gpt2("pytorch_flash", **model)
+    if spec:
+        model_run.with_spec_updates(**spec)
+    return _losses(_builder(model_run, mesh, clip=1.0).build(seed=0), raw, steps)
+
+
+def _mesh(**degrees):
+    return get_device_mesh(device_type="cpu", world_size=8, **degrees)
+
+
 def _batch(rng, acc, mb, seq, vocab=128):
     tokens = rng.integers(0, vocab, size=(acc, mb, seq + 1))
     return {
@@ -48,12 +89,10 @@ def _batch(rng, acc, mb, seq, vocab=128):
 
 
 def test_loss_decreases_dp():
-    mesh = get_device_mesh(device_type="cpu", data_parallel_shard_degree=8, world_size=8)
-    model = tiny_gpt2("pytorch_flash")
-    fns = _builder(model, mesh, clip=1.0).build(seed=0)
+    fns, initial = _dp8(16)
     rng = np.random.default_rng(0)
     batch = fns.put_batch(_batch(rng, 1, 8, 16))
-    state = fns.app_state_handle.state
+    state = _dp8_state(fns, initial)
     losses = []
     for _ in range(20):
         state, metrics = fns.train_step(state, batch)
@@ -64,30 +103,17 @@ def test_loss_decreases_dp():
     assert float(metrics["grad_norm"]) > 0
 
 
-@pytest.mark.slow  # ~17 s; TP numerics stay pinned fast by
-# test_loss_parallel_equivalence_and_rule (tp mesh, numerics unchanged) and TP
-# sharding rules by test_tp_placement_colwise_rowwise_and_vocab
+# back in tier 1 since PR 47 (8 s under the suite's compile rule, the dp8 twin shared): the one dp-against-tp loss oracle
 def test_dp_tp_equivalence():
     """Same seed + same data must give identical losses under pure-DP vs DP x TP —
     the TP-correctness oracle (reference test_tensor_parallelism.py:42-120)."""
-    model = tiny_gpt2("pytorch_flash")
-    mesh_dp = get_device_mesh(device_type="cpu", data_parallel_shard_degree=8, world_size=8)
     mesh_tp = get_device_mesh(
         device_type="cpu", data_parallel_shard_degree=4, tensor_parallel_degree=2, world_size=8
     )
     rng = np.random.default_rng(1)
     raw = _batch(rng, 1, 8, 16)
-
-    losses = {}
-    for name, mesh in [("dp", mesh_dp), ("dp_tp", mesh_tp)]:
-        fns = _builder(model, mesh, clip=1.0).build(seed=0)
-        state = fns.app_state_handle.state
-        batch = fns.put_batch(raw)
-        ls = []
-        for _ in range(3):
-            state, metrics = fns.train_step(state, batch)
-            ls.append(float(metrics["loss"]))
-        losses[name] = ls
+    losses = {"dp": _dp8_losses(raw, 3),
+              "dp_tp": _losses(_builder(tiny_gpt2("pytorch_flash"), mesh_tp, clip=1.0).build(seed=0), raw, 3)}
     # this CPU XLA reduces tp-sharded matmuls in a different order (~7e-3 max
     # relative diff measured, docs/known_failures.md round 6) — not a logic bug;
     # the tight pin is the TPU contract
@@ -95,10 +121,7 @@ def test_dp_tp_equivalence():
     np.testing.assert_allclose(losses["dp"], losses["dp_tp"], rtol=tol, atol=tol)
 
 
-@pytest.mark.slow  # ~19 s; microbatch-accumulation numerics stay pinned fast by
-# test_dp_pp_equivalence (PP accumulates per microbatch against the dp8 twin)
-# and the accumulation loop's structural contract by tests/training/
-# test_dcn_hierarchical.py::test_one_cross_slice_reduction_per_optimizer_step
+# back in tier 1 since PR 47 (9 s under the suite's compile rule): the one accumulated-against-whole-batch oracle on a tp mesh
 def test_grad_accumulation_equivalence():
     """acc=2 over half-size microbatches == acc=1 over the full batch."""
     model = tiny_gpt2("pytorch_flash")
@@ -140,7 +163,6 @@ def test_dp_hsdp_equivalence():
     headline layout (model_factory.py:205-211, BASELINE.md HYBRID rows) — params
     shard over dp_shard and replicate over dp_replicate, the batch spans BOTH axes,
     grads all-reduce across replicas. Losses must match pure FSDP exactly."""
-    mesh_dp = get_device_mesh(device_type="cpu", data_parallel_shard_degree=8, world_size=8)
     mesh_hsdp = get_device_mesh(
         device_type="cpu", data_parallel_replicate_degree=2,
         data_parallel_shard_degree=4, world_size=8,
@@ -151,28 +173,15 @@ def test_dp_hsdp_equivalence():
     rng = np.random.default_rng(11)
     raw = _batch(rng, 1, 8, 16)
 
-    losses = {}
-    for name, mesh in [("dp", mesh_dp), ("hsdp", mesh_hsdp)]:
-        fns = _builder(tiny_gpt2("pytorch_flash"), mesh, clip=1.0).build(seed=0)
-        state = fns.app_state_handle.state
-        if name == "hsdp":
-            # batch spans both dp axes: 8 rows -> 2x4 device grid, one row each
-            batch = fns.put_batch(raw)
-            tok_shard = batch["samples"]["input_ids"].sharding
-            assert set(tok_shard.spec[1]) == {"dp_replicate", "dp_shard"}
-            # params: sharded over dp_shard only, REPLICATED over dp_replicate
-            leaves = [x for x in jax.tree.leaves(state.params) if x.ndim >= 2]
-            assert any(
-                "dp_shard" in jax.tree.leaves(tuple(x.sharding.spec)) for x in leaves
-            )
-            assert all(
-                "dp_replicate" not in jax.tree.leaves(tuple(x.sharding.spec)) for x in leaves
-            )
-        ls = []
-        for _ in range(3):
-            state, metrics = fns.train_step(state, fns.put_batch(raw))
-            ls.append(float(metrics["loss"]))
-        losses[name] = ls
+    fns = _builder(tiny_gpt2("pytorch_flash"), mesh_hsdp, clip=1.0).build(seed=0)
+    # batch spans both dp axes: 8 rows -> 2x4 device grid, one row each
+    tok_shard = fns.put_batch(raw)["samples"]["input_ids"].sharding
+    assert set(tok_shard.spec[1]) == {"dp_replicate", "dp_shard"}
+    # params: sharded over dp_shard only, REPLICATED over dp_replicate
+    leaves = [x for x in jax.tree.leaves(fns.app_state_handle.state.params) if x.ndim >= 2]
+    assert any("dp_shard" in jax.tree.leaves(tuple(x.sharding.spec)) for x in leaves)
+    assert all("dp_replicate" not in jax.tree.leaves(tuple(x.sharding.spec)) for x in leaves)
+    losses = {"dp": _dp8_losses(raw, 3), "hsdp": _losses(fns, raw, 3)}
     # same reduction-order divergence class as dp/tp above: loose pin on CPU,
     # tight pin on TPU
     tol = 2e-2 if jax.default_backend() == "cpu" else 3e-4
@@ -208,73 +217,24 @@ def test_unknown_weight_decay_group_raises():
 def test_dp_cp_equivalence():
     """dp8 vs dp2 x cp4 (ring attention) must produce identical losses — the
     CP-vs-single-device oracle for the cp mesh dim."""
-    model = tiny_gpt2("pytorch_flash")
-    mesh_dp = get_device_mesh(device_type="cpu", data_parallel_shard_degree=8, world_size=8)
-    mesh_cp = get_device_mesh(
-        device_type="cpu", data_parallel_shard_degree=2, context_parallel_degree=4, world_size=8
-    )
-    rng = np.random.default_rng(5)
-    raw = _batch(rng, 1, 8, 32)
-
-    losses = {}
-    for name, mesh in [("dp", mesh_dp), ("dp_cp", mesh_cp)]:
-        model_run = tiny_gpt2("pytorch_flash")
-        fns = _builder(model_run, mesh, clip=1.0).build(seed=0)
-        state = fns.app_state_handle.state
-        ls = []
-        for _ in range(3):
-            state, metrics = fns.train_step(state, fns.put_batch(raw))
-            ls.append(float(metrics["loss"]))
-        losses[name] = ls
-    np.testing.assert_allclose(losses["dp"], losses["dp_cp"], rtol=3e-4, atol=3e-4)
+    raw = _batch(np.random.default_rng(5), 1, 8, 32)
+    mesh_cp = _mesh(data_parallel_shard_degree=2, context_parallel_degree=4)
+    np.testing.assert_allclose(_dp8_losses(raw, 3), _layout_losses(mesh_cp, raw, 3), rtol=3e-4, atol=3e-4)
 
 
 def test_dp_pp_equivalence():
     """dp8 vs pp2 x dp4 (GPipe schedule) must produce identical losses — the PP
     fwd/bwd-vs-FSDP oracle (reference test_pp_fwd_bwd_pass.py)."""
-    mesh_dp = get_device_mesh(device_type="cpu", data_parallel_shard_degree=8, world_size=8)
-    mesh_pp = get_device_mesh(
-        device_type="cpu", data_parallel_shard_degree=4, pipeline_parallel_degree=2, world_size=8
-    )
-    rng = np.random.default_rng(6)
-    raw = _batch(rng, 1, 8, 16)
-
-    losses = {}
-    for name, mesh in [("dp", mesh_dp), ("pp_dp", mesh_pp)]:
-        model_run = tiny_gpt2("pytorch_flash")
-        fns = _builder(model_run, mesh, clip=1.0).build(seed=0)
-        state = fns.app_state_handle.state
-        ls = []
-        for _ in range(3):
-            state, metrics = fns.train_step(state, fns.put_batch(raw))
-            ls.append(float(metrics["loss"]))
-        losses[name] = ls
-    np.testing.assert_allclose(losses["dp"], losses["pp_dp"], rtol=3e-4, atol=3e-4)
+    raw = _batch(np.random.default_rng(6), 1, 8, 16)
+    mesh_pp = _mesh(data_parallel_shard_degree=4, pipeline_parallel_degree=2)
+    np.testing.assert_allclose(_dp8_losses(raw, 3), _layout_losses(mesh_pp, raw, 3), rtol=3e-4, atol=3e-4)
 
 
 def test_dp_vs_pp_cp_combined_equivalence():
     """dp8 vs pp2 x dp2 x cp2 — all schedule-bearing parallelism forms composed."""
-    mesh_dp = get_device_mesh(device_type="cpu", data_parallel_shard_degree=8, world_size=8)
-    mesh_mix = get_device_mesh(
-        device_type="cpu",
-        data_parallel_shard_degree=2,
-        context_parallel_degree=2,
-        pipeline_parallel_degree=2,
-        world_size=8,
-    )
-    rng = np.random.default_rng(8)
-    raw = _batch(rng, 1, 8, 32)
-
-    losses = {}
-    for name, mesh in [("dp", mesh_dp), ("mix", mesh_mix)]:
-        fns = _builder(tiny_gpt2("pytorch_flash"), mesh, clip=1.0).build(seed=0)
-        state = fns.app_state_handle.state
-        ls = []
-        for _ in range(2):
-            state, metrics = fns.train_step(state, fns.put_batch(raw))
-            ls.append(float(metrics["loss"]))
-        losses[name] = ls
-    np.testing.assert_allclose(losses["dp"], losses["mix"], rtol=5e-4, atol=5e-4)
+    raw = _batch(np.random.default_rng(8), 1, 8, 32)
+    mesh_mix = _mesh(data_parallel_shard_degree=2, context_parallel_degree=2, pipeline_parallel_degree=2)
+    np.testing.assert_allclose(_dp8_losses(raw, 2), _layout_losses(mesh_mix, raw, 2), rtol=5e-4, atol=5e-4)
 
 
 def test_rope_global_positions_under_pp_cp():
@@ -313,79 +273,29 @@ def test_dp_pp_cp_scheduled_equivalence(schedule):
     """dp8 vs pp2 x dp2 x cp2 under the SCHEDULED executors: ring attention runs
     inside the 1F1B/ZBV shard_map region (cp joins the manual axes; F/B slots go
     unconditional so the ring's collectives execute uniformly — VERDICT r2 #4)."""
-    mesh_dp = get_device_mesh(device_type="cpu", data_parallel_shard_degree=8, world_size=8)
-    mesh_mix = get_device_mesh(
-        device_type="cpu", data_parallel_shard_degree=2, context_parallel_degree=2,
-        pipeline_parallel_degree=2, world_size=8,
-    )
-    rng = np.random.default_rng(9)
-    raw = _batch(rng, 1, 8, 32)
-
-    losses = {}
-    for name, mesh in [("dp", mesh_dp), ("mix", mesh_mix)]:
-        model_run = tiny_gpt2("pytorch_flash", n_layer=4)
-        if name == "mix":
-            model_run.with_spec_updates(pp_schedule=schedule)
-        fns = _builder(model_run, mesh, clip=1.0).build(seed=0)
-        state = fns.app_state_handle.state
-        ls = []
-        for _ in range(3):
-            state, metrics = fns.train_step(state, fns.put_batch(raw))
-            ls.append(float(metrics["loss"]))
-        losses[name] = ls
-    np.testing.assert_allclose(losses["dp"], losses["mix"], rtol=5e-4, atol=5e-4)
+    raw = _batch(np.random.default_rng(9), 1, 8, 32)
+    mesh_mix = _mesh(data_parallel_shard_degree=2, context_parallel_degree=2, pipeline_parallel_degree=2)
+    mix = _layout_losses(mesh_mix, raw, 3, spec={"pp_schedule": schedule}, n_layer=4)
+    np.testing.assert_allclose(_dp8_losses(raw, 3, n_layer=4), mix, rtol=5e-4, atol=5e-4)
 
 
 def test_absolute_positions_under_scheduled_pp_cp():
     """ABSOLUTE position embeddings under 1F1B x cp: the embed stage slices wpe at
     the shard's global offset (local chunks restart at 0 otherwise)."""
-    mesh_dp = get_device_mesh(device_type="cpu", data_parallel_shard_degree=8, world_size=8)
-    mesh_mix = get_device_mesh(
-        device_type="cpu", data_parallel_shard_degree=2, context_parallel_degree=2,
-        pipeline_parallel_degree=2, world_size=8,
-    )
-    rng = np.random.default_rng(10)
-    raw = _batch(rng, 1, 8, 32)
-
-    losses = {}
-    for name, mesh in [("dp", mesh_dp), ("mix", mesh_mix)]:
-        model_run = tiny_gpt2("pytorch_flash", poe_type="ABSOLUTE")
-        if name == "mix":
-            model_run.with_spec_updates(pp_schedule="1f1b")
-        fns = _builder(model_run, mesh, clip=1.0).build(seed=0)
-        state = fns.app_state_handle.state
-        ls = []
-        for _ in range(2):
-            state, metrics = fns.train_step(state, fns.put_batch(raw))
-            ls.append(float(metrics["loss"]))
-        losses[name] = ls
-    np.testing.assert_allclose(losses["dp"], losses["mix"], rtol=5e-4, atol=5e-4)
+    raw = _batch(np.random.default_rng(10), 1, 8, 32)
+    mesh_mix = _mesh(data_parallel_shard_degree=2, context_parallel_degree=2, pipeline_parallel_degree=2)
+    mix = _layout_losses(mesh_mix, raw, 2, spec={"pp_schedule": "1f1b"}, poe_type="ABSOLUTE")
+    np.testing.assert_allclose(_dp8_losses(raw, 2, poe_type="ABSOLUTE"), mix, rtol=5e-4, atol=5e-4)
 
 
 def test_dp_pp_1f1b_equivalence():
     """dp8 vs pp2 x dp4 under the scheduled 1F1B executor: identical losses to pure
     DP — the oracle for the hand-rolled fwd/bwd (reference 1F1B schedule,
     pipeline_parallelism.py:294-337)."""
-    mesh_dp = get_device_mesh(device_type="cpu", data_parallel_shard_degree=8, world_size=8)
-    mesh_pp = get_device_mesh(
-        device_type="cpu", data_parallel_shard_degree=4, pipeline_parallel_degree=2, world_size=8
-    )
-    rng = np.random.default_rng(6)
-    raw = _batch(rng, 1, 8, 16)
-
-    losses = {}
-    for name, mesh in [("dp", mesh_dp), ("pp_1f1b", mesh_pp)]:
-        model_run = tiny_gpt2("pytorch_flash")
-        if name == "pp_1f1b":
-            model_run.with_spec_updates(pp_schedule="1f1b", pp_num_microbatches=4)
-        fns = _builder(model_run, mesh, clip=1.0).build(seed=0)
-        state = fns.app_state_handle.state
-        ls = []
-        for _ in range(3):
-            state, metrics = fns.train_step(state, fns.put_batch(raw))
-            ls.append(float(metrics["loss"]))
-        losses[name] = ls
-    np.testing.assert_allclose(losses["dp"], losses["pp_1f1b"], rtol=3e-4, atol=3e-4)
+    raw = _batch(np.random.default_rng(6), 1, 8, 16)
+    mesh_pp = _mesh(data_parallel_shard_degree=4, pipeline_parallel_degree=2)
+    pp = _layout_losses(mesh_pp, raw, 3, spec={"pp_schedule": "1f1b", "pp_num_microbatches": 4})
+    np.testing.assert_allclose(_dp8_losses(raw, 3), pp, rtol=3e-4, atol=3e-4)
 
 
 def test_pp_1f1b_dropout_deterministic():
@@ -463,56 +373,22 @@ def test_dp_pp_zbv_equivalence(schedule):
     placement (device 0 holds the first AND last stage), direction-aware hops,
     dx-only B slots, and the post-scan weight-grad pass must reproduce pure-DP
     losses exactly."""
-    mesh_dp = get_device_mesh(device_type="cpu", data_parallel_shard_degree=8, world_size=8)
-    mesh_pp = get_device_mesh(
-        device_type="cpu", data_parallel_shard_degree=4, pipeline_parallel_degree=2, world_size=8
-    )
-    rng = np.random.default_rng(23)
-    raw = _batch(rng, 1, 8, 16)
-
-    losses = {}
-    for name, mesh in [("dp", mesh_dp), ("pp_zbv", mesh_pp)]:
-        model_run = tiny_gpt2("pytorch_flash", n_layer=4)  # 4 layers = 2 devices x 2 V-chunks
-        if name == "pp_zbv":
-            model_run.with_spec_updates(
-                pp_schedule=schedule, pp_num_microbatches=4, pp_num_virtual=2
-            )
-        fns = _builder(model_run, mesh, clip=1.0).build(seed=0)
-        state = fns.app_state_handle.state
-        ls = []
-        for _ in range(3):
-            state, metrics = fns.train_step(state, fns.put_batch(raw))
-            ls.append(float(metrics["loss"]))
-        losses[name] = ls
-    np.testing.assert_allclose(losses["dp"], losses["pp_zbv"], rtol=3e-4, atol=3e-4)
+    raw = _batch(np.random.default_rng(23), 1, 8, 16)
+    mesh_pp = _mesh(data_parallel_shard_degree=4, pipeline_parallel_degree=2)
+    # 4 layers = 2 devices x 2 V-chunks
+    pp = _layout_losses(mesh_pp, raw, 3, spec={"pp_schedule": schedule, "pp_num_microbatches": 4, "pp_num_virtual": 2}, n_layer=4)
+    np.testing.assert_allclose(_dp8_losses(raw, 3, n_layer=4), pp, rtol=3e-4, atol=3e-4)
 
 
 def test_dp_pp4_zbv_equivalence():
     """dp8 vs pp4 x dp2 under ZBV: exercises the MIDDLE devices of the V (stages
     strictly between 0 and P-1), which pp=2 never does — simultaneous descend/ascend
     activation receives and cotangent relays without the local turn."""
-    mesh_dp = get_device_mesh(device_type="cpu", data_parallel_shard_degree=8, world_size=8)
-    mesh_pp = get_device_mesh(
-        device_type="cpu", data_parallel_shard_degree=2, pipeline_parallel_degree=4, world_size=8
-    )
-    rng = np.random.default_rng(31)
-    raw = _batch(rng, 1, 8, 16)
-
-    losses = {}
-    for name, mesh in [("dp", mesh_dp), ("pp4_zbv", mesh_pp)]:
-        model_run = tiny_gpt2("pytorch_flash", n_layer=8)  # 8 layers = 4 devices x 2 V-chunks
-        if name == "pp4_zbv":
-            model_run.with_spec_updates(
-                pp_schedule="zbv", pp_num_microbatches=4, pp_num_virtual=2
-            )
-        fns = _builder(model_run, mesh, clip=1.0).build(seed=0)
-        state = fns.app_state_handle.state
-        ls = []
-        for _ in range(2):
-            state, metrics = fns.train_step(state, fns.put_batch(raw))
-            ls.append(float(metrics["loss"]))
-        losses[name] = ls
-    np.testing.assert_allclose(losses["dp"], losses["pp4_zbv"], rtol=3e-4, atol=3e-4)
+    raw = _batch(np.random.default_rng(31), 1, 8, 16)
+    mesh_pp = _mesh(data_parallel_shard_degree=2, pipeline_parallel_degree=4)
+    # 8 layers = 4 devices x 2 V-chunks
+    pp = _layout_losses(mesh_pp, raw, 2, spec={"pp_schedule": "zbv", "pp_num_microbatches": 4, "pp_num_virtual": 2}, n_layer=8)
+    np.testing.assert_allclose(_dp8_losses(raw, 2, n_layer=8), pp, rtol=3e-4, atol=3e-4)
 
 
 def test_pp_zbv_dropout_deterministic():
@@ -547,39 +423,16 @@ def test_dp_pp_equivalence_with_ignore_index(schedule):
     """Unequal valid-token counts across pp microbatches (ignore_index=-100) must not
     skew the scheduled-executor loss: contributions are token-weighted, matching the
     global mean — for 1F1B's fused backward and ZBV's split backward alike."""
-    mesh_dp = get_device_mesh(device_type="cpu", data_parallel_shard_degree=8, world_size=8)
-    mesh_pp = get_device_mesh(
-        device_type="cpu", data_parallel_shard_degree=4, pipeline_parallel_degree=2, world_size=8
-    )
-    rng = np.random.default_rng(13)
-    raw = _batch(rng, 1, 8, 16)
+    raw = _batch(np.random.default_rng(13), 1, 8, 16)
     # heavily mask the first half of the batch -> pp microbatches see very different counts
-    t = raw["targets"]["target_ids"]
-    t[:, :4, 2:] = -100
-    raw["targets"]["target_ids"] = t
-
-    losses = {}
-    for name, mesh in [("dp", mesh_dp), ("pp_sched", mesh_pp)]:
-        model_run = tiny_gpt2("pytorch_flash", n_layer=4)
-        if name == "pp_sched":
-            model_run.with_spec_updates(
-                pp_schedule=schedule,
-                pp_num_microbatches=4,
-                pp_num_virtual=2 if schedule == "zbv" else 1,
-            )
-        fns = _builder(model_run, mesh, clip=1.0).build(seed=0)
-        state = fns.app_state_handle.state
-        ls = []
-        for _ in range(2):
-            state, metrics = fns.train_step(state, fns.put_batch(raw))
-            ls.append(float(metrics["loss"]))
-        losses[name] = ls
-    np.testing.assert_allclose(losses["dp"], losses["pp_sched"], rtol=3e-4, atol=3e-4)
+    raw["targets"]["target_ids"][:, :4, 2:] = -100
+    mesh_pp = _mesh(data_parallel_shard_degree=4, pipeline_parallel_degree=2)
+    spec = {"pp_schedule": schedule, "pp_num_microbatches": 4, "pp_num_virtual": 2 if schedule == "zbv" else 1}
+    pp = _layout_losses(mesh_pp, raw, 2, spec=spec, n_layer=4)
+    np.testing.assert_allclose(_dp8_losses(raw, 2, n_layer=4), pp, rtol=3e-4, atol=3e-4)
 
 
-@pytest.mark.slow  # ~19 s (two 8-way builds); tp-mesh CE numerics stay pinned
-# fast by test_chunked_lm_head_loss_equivalence and the vocab/tp sharding-rule
-# plumbing by test_tp_placement_colwise_rowwise_and_vocab
+# back in tier 1 since PR 47 (8 s under the suite's compile rule): the one run with the logits' vocabulary sharded over tp
 def test_loss_parallel_equivalence_and_rule():
     """enable_loss_parallel shards the LOGITS vocab dim over tp (one sharding rule —
     the GSPMD expression of vocab-parallel CE); numerics must be unchanged."""
@@ -612,28 +465,11 @@ def test_dp_pp_interleaved_1f1b_equivalence():
     """dp8 vs pp2 x dp4 under interleaved 1F1B (2 virtual chunks per device): losses
     must match pure DP — the oracle for virtual-stage layer routing, the chunk-
     advancing wrap hop, and chunk-indexed grads."""
-    mesh_dp = get_device_mesh(device_type="cpu", data_parallel_shard_degree=8, world_size=8)
-    mesh_pp = get_device_mesh(
-        device_type="cpu", data_parallel_shard_degree=4, pipeline_parallel_degree=2, world_size=8
-    )
-    rng = np.random.default_rng(17)
-    raw = _batch(rng, 1, 8, 16)
-
-    losses = {}
-    for name, mesh in [("dp", mesh_dp), ("pp_interleaved", mesh_pp)]:
-        model_run = tiny_gpt2("pytorch_flash", n_layer=4)  # 4 layers = 2 devices x 2 chunks
-        if name == "pp_interleaved":
-            model_run.with_spec_updates(
-                pp_schedule="interleaved_1f1b", pp_num_microbatches=4, pp_num_virtual=2
-            )
-        fns = _builder(model_run, mesh, clip=1.0).build(seed=0)
-        state = fns.app_state_handle.state
-        ls = []
-        for _ in range(3):
-            state, metrics = fns.train_step(state, fns.put_batch(raw))
-            ls.append(float(metrics["loss"]))
-        losses[name] = ls
-    np.testing.assert_allclose(losses["dp"], losses["pp_interleaved"], rtol=3e-4, atol=3e-4)
+    raw = _batch(np.random.default_rng(17), 1, 8, 16)
+    mesh_pp = _mesh(data_parallel_shard_degree=4, pipeline_parallel_degree=2)
+    # 4 layers = 2 devices x 2 chunks
+    pp = _layout_losses(mesh_pp, raw, 3, spec={"pp_schedule": "interleaved_1f1b", "pp_num_microbatches": 4, "pp_num_virtual": 2}, n_layer=4)
+    np.testing.assert_allclose(_dp8_losses(raw, 3, n_layer=4), pp, rtol=3e-4, atol=3e-4)
 
 
 def test_chunked_lm_head_loss_equivalence():
@@ -649,11 +485,12 @@ def test_chunked_lm_head_loss_equivalence():
 
     losses, evals = {}, {}
     for chunk in (None, 8):
-        model_run = tiny_gpt2("pytorch_flash")
-        if chunk is not None:
-            model_run.with_spec_updates(lm_head_chunk_size=chunk)
-        fns = _builder(model_run, mesh, clip=1.0).build(seed=0)
-        state = fns.app_state_handle.state
+        if chunk is None:  # the full-logits path is the shared dp8 twin
+            fns, initial = _dp8(32)
+            state = _dp8_state(fns, initial)
+        else:
+            fns = _builder(tiny_gpt2("pytorch_flash").with_spec_updates(lm_head_chunk_size=chunk), mesh, clip=1.0).build(seed=0)
+            state = fns.app_state_handle.state
         ev_batch = fns.put_batch(
             {"samples": {k: v[0] for k, v in raw["samples"].items()},
              "targets": {k: v[0] for k, v in raw["targets"].items()}},
@@ -884,11 +721,12 @@ def test_chunked_lm_head_ragged_tail():
 
     losses, evals = {}, {}
     for chunk in (None, 5):
-        model_run = tiny_gpt2("pytorch_flash")
-        if chunk is not None:
-            model_run.with_spec_updates(lm_head_chunk_size=chunk)
-        fns = _builder(model_run, mesh, clip=1.0).build(seed=0)
-        state = fns.app_state_handle.state
+        if chunk is None:  # the full-logits path is the shared dp8 twin
+            fns, initial = _dp8(32)
+            state = _dp8_state(fns, initial)
+        else:
+            fns = _builder(tiny_gpt2("pytorch_flash").with_spec_updates(lm_head_chunk_size=chunk), mesh, clip=1.0).build(seed=0)
+            state = fns.app_state_handle.state
         ev_batch = fns.put_batch(
             {"samples": {k: v[0] for k, v in raw["samples"].items()},
              "targets": {k: v[0] for k, v in raw["targets"].items()}},
@@ -938,17 +776,9 @@ def test_fused_rmsnorm_forced_matches_reference():
     rng = np.random.default_rng(45)
     raw = _batch(rng, 1, 8, 32)
 
-    losses = {}
-    for setting, kernels in (("off", contextlib.nullcontext()), ("1", tiers.interpreted_kernels())):
-        with kernels:
-            model_run = tiny_gpt2("pytorch_flash")
-            fns = _builder(model_run, mesh, clip=1.0).build(seed=0)
-            state = fns.app_state_handle.state
-            ls = []
-            for _ in range(3):
-                state, metrics = fns.train_step(state, fns.put_batch(raw))
-                ls.append(float(metrics["loss"]))
-            losses[setting] = ls
+    losses = {"off": _dp8_losses(raw, 3)}
+    with tiers.interpreted_kernels():
+        losses["1"] = _layout_losses(mesh, raw, 3)
     # the kernel's analytic dx differs from autodiff-of-reference at the 1e-5
     # level; three optimizer steps amplify that to ~1e-4
     np.testing.assert_allclose(losses["off"], losses["1"], rtol=5e-4, atol=5e-4)
