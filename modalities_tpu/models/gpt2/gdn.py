@@ -111,11 +111,14 @@ class GatedDeltaNet(nn.Module):
         kernels = rule.walk_kernels(r, rule.groups_of(s)[1], rule.CHUNK, dk, dv, x.dtype)  # of the walk over a group's chunks: the rest of the rule is the plain form
         # of the two norms over a head's channels (`ops/head_norm.py`): none off a TPU and at heads that are no whole lane tiles, where the plain forms below run
         qk_norm, out_norm = head_norm.kernels("l2", (b, s, nk, dk), x.dtype), head_norm.kernels("gated", (b, s, nv, dv), x.dtype)
+        # where the block is rematerialized its recomputed forward runs the rule once more, unless it kept the rule's o and group states
+        # (the trace that initializes the parameters is of no step: it says what a block that is not rematerialized says)
+        forwards, recomputed = rule.RECOMPUTED[spec.remat_keep_rule] if spec.remat_variant and not self.is_initializing() else (2, "")
         get_active_telemetry().emit_event_once("gdn_plan", {  # runs while tracing: once per shape, nothing per step
             "tokens": b * s, "sequence": s, "chunk": rule.CHUNK, "chunks": -(-s // rule.CHUNK), "key_heads": nk, "value_heads": nv,
             "key_dim": dk, "value_dim": dv, "conv_taps": gdn.taps, "conv_width": gdn.conv_width,
-            "state_bytes_a_layer": b * rule.state_bytes(s, nv, dk, dv), "inverse": rule.HOW_T, "backward": rule.BACKWARD[bool(kernels)], "kernels": kernels,
-            "norm_kernels": qk_norm + out_norm,
+            "state_bytes_a_layer": b * rule.state_bytes(s, nv, dk, dv), "inverse": rule.HOW_T, "backward": rule.BACKWARD[bool(kernels)] + recomputed, "kernels": kernels,
+            "norm_kernels": qk_norm + out_norm, "forwards_a_step": forwards,
         })
         param_dtype = jnp.dtype(spec.param_dtype)
 

@@ -500,6 +500,9 @@ class GPT2ModelSpec:
     # not run the forward kernel again. Not a key of any config: `training/activation_checkpointing.attention_keep_plan`
     # decides it from the shapes and the device's bytes when the train step is traced (`training/train_step.py`)
     remat_keep_flash: bool = False
+    # and, of a block whose mixer is the gated delta rule, the rule's o and the state that came into each group of chunks
+    # (`ops/gated_delta_rule.KEPT_OUT` / `KEPT_STATES`): decided in the same place, one rung above the flash kernel's two
+    remat_keep_rule: bool = False
     # fuse lm-head + CE per sequence chunk of this size (train/eval step): the
     # [B,S,V] fp32 logits never materialize — at 32k ctx x 50k vocab that tensor
     # alone is 6.6 GB, more than a v5e can give it. None = whole-sequence logits.
@@ -1268,12 +1271,14 @@ def _remat_block_cls(spec: "GPT2ModelSpec"):
         from modalities_tpu.training.activation_checkpointing import save_list_policy
 
         policy = save_list_policy(spec.remat_save_list)
-    elif spec.remat_variant == "full" and spec.remat_keep_flash:
-        # the block's input and the flash kernel's o and lse; a block with no such call (state-space, the ffn) has no
-        # value under these names and keeps what `None` kept: its input
+    elif spec.remat_variant == "full" and (spec.remat_keep_flash or spec.remat_keep_rule):
+        # the block's input and, as the plan said, the flash kernel's o and lse and the gated delta rule's o and group states;
+        # a block with no such call (state-space, the ffn) has no value under these names and keeps what `None` kept: its input
+        from modalities_tpu.ops import gated_delta_rule as rule
         from modalities_tpu.ops.pallas.flash_attention import KEPT_LSE, KEPT_OUT
 
-        policy = jax.checkpoint_policies.save_only_these_names(KEPT_OUT, KEPT_LSE)
+        names = (KEPT_OUT, KEPT_LSE) * spec.remat_keep_flash + (rule.KEPT_OUT, rule.KEPT_STATES) * spec.remat_keep_rule
+        policy = jax.checkpoint_policies.save_only_these_names(*names)
     return nn.remat(GPT2Block, prevent_cse=False, policy=policy)
 
 
@@ -2059,8 +2064,10 @@ class GPT2LLM(NNModel):
         (what every rematerialized block keeps already), and for each kind of attention layer how many layers, the bytes
         of the kernel's `o` `[rows, H, seq, Dv]` and of `lse` as numbers, `[rows, H, seq]` float32, and the bytes its
         backward holds round the kernel (`backward_bytes`: q, k, v, o and its cotangent, dq, dk and dv a q head, lse and
-        delta as the kernel lays them out: `[rows, H, 1, seq]` rows of float32, dense since PR 42). It is what
-        `training/activation_checkpointing.attention_keep_plan` counts. None where no block is wrapped so: another variant
+        delta as the kernel lays them out: `[rows, H, 1, seq]` rows of float32, dense since PR 42); and, where the stack holds
+        layers of the gated delta rule, under `rule` how many and the bytes a layer of what such a block may keep of
+        `ops/gated_delta_rule.py`: `o` `[rows, seq, Hv, Dv]` and the float32 states that come into the groups of chunks
+        (`states_bytes`). It is what `training/activation_checkpointing.attention_keep_plan` counts. None where no block is wrapped so: another variant
         or none, a looped stack (`_walks_in_place` recomputes by hand and takes no policy), pipeline stages
         (`jax.checkpoint` of their own), ring attention (no call of this kernel), a tier that is not the kernel, and off
         the TPU, where `ops/attention.py` runs XLA's attention and there is no kernel to keep anything of."""
@@ -2079,8 +2086,16 @@ class GPT2LLM(NNModel):
         lse_bytes = b * h * s * 4  # delta's too
         call = {"o_bytes": b * h * s * width_v * itemsize, "lse_bytes": lse_bytes,
                 "backward_bytes": b * s * itemsize * (3 * h * width + 3 * h * width_v + h_kv * (width + width_v)) + 2 * lse_bytes}
-        return {"blocks": spec.n_layer, "calls": [{"kind": kind, "layers": spec.kinds.count(kind), **call} for kind in ("attn", "swa", "cca") if kind in spec.kinds],
+        held = {"blocks": spec.n_layer, "calls": [{"kind": kind, "layers": spec.kinds.count(kind), **call} for kind in ("attn", "swa", "cca") if kind in spec.kinds],
                 "block_input_bytes": math.prod(shard_shape((rows, seq, spec.n_embd), ("batch", "seq", "embed"))) * itemsize}
+        if "gdn" in spec.kinds:  # the rule's o over the row padded to whole groups of chunks, and a float32 state a group and a value head
+            from modalities_tpu.ops import gated_delta_rule as rule
+
+            groups, chunks = rule.groups_of(seq)
+            b, s, hv = shard_shape((rows, groups * chunks * rule.CHUNK, spec.gdn.value_heads), ("batch", None, "heads"))
+            held["rule"] = {"layers": spec.kinds.count("gdn"), "o_bytes": b * s * hv * spec.gdn.value_dim * itemsize,
+                            "states_bytes": b * rule.state_bytes(seq, hv, spec.gdn.key_dim, spec.gdn.value_dim)}
+        return held
 
     def init_params(self, rng):
         dummy = jnp.zeros((1, min(8, self.sequence_length)), dtype=jnp.int32)

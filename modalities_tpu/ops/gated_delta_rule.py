@@ -35,24 +35,35 @@ other products (`k k^T`, `q k^T`, `T` times its two right sides, and the three a
 take operands in the inputs' dtype (bfloat16 in training, `S`, `T`, `U`, `W` rounded to it where they are an
 operand) and accumulate in float32; with float32 inputs every product is float32 at `highest`.
 
-Backward. Autodiff, told what to keep (decided from `memory_analysis()` of the cell's step, PR 44: with the whole
-row's `intra` arrays kept for the backward, the float32 `[C, C]` matrices of the series among them, the step compiled
-to 18.2 GiB against the chip's 15.75). The row is walked in GROUPS of `GROUP_CHUNKS` chunks, an outer `lax.scan` that
-carries the state; a group is what is described above (its chunks' `intra` batched, then the scan over them) and is
-rematerialized (`jax.checkpoint`): the backward keeps one float32 state a group and a head (`state_bytes`) beside the
-rule's inputs, and computes a group's matrices again, one group's working set at a time. Inside a group the arrays
-the walk reads are rounded to the operands' dtype once, and `T = (I + L)^-1` has its own rule (`dL = -T^T dT T^T`: `T`
-is all it keeps of the series). The walk's backward needs the state that came into each chunk, for one group (64 MiB
-at 32 chunks and 32 heads of 128 x 128). The kernels' walk has its own rule (`custom_vjp`) that keeps the walk's
-operands alone: its backward kernel sweeps the group's chunks forward once more with those states kept in VMEM, then
-from the last chunk with `dS` resident. The plain scan's is autodiff over a rematerialized chunk step, which keeps
-the states as its carry, in HBM. `gdn_plan` says which (`kernels`, `backward`).
+Backward. The rule's own (`jax.custom_vjp` on `_rule`, PR 48), in the form autodiff had when it was told what to keep
+(decided from `memory_analysis()` of the cell's step, PR 44: with the whole row's `intra` arrays kept for the backward,
+the float32 `[C, C]` matrices of the series among them, the step compiled to 18.2 GiB against the chip's 15.75). The row
+is walked in GROUPS of `GROUP_CHUNKS` chunks, an outer `lax.scan` that carries the state; a group is what is described
+above (its chunks' `intra` batched, then the scan over them). The forward keeps the rule's inputs and the float32 state
+that came into each group (`state_bytes`: one a group and a head); the backward is the same scan from the last group to
+the first, carrying the state's cotangent: `jax.vjp` of a group from its kept state computes the group's matrices again,
+one group's working set at a time (under the scope `group`), then pulls back. That second forward of every group is what
+keeps the step under the chip's memory, and it stays. A THIRD one went with PR 48: a block under `full` remat used to run
+the whole rule again in its recomputed forward, only to have `o` for the gated norm and the states for this backward. `o`
+and the states go out under the names `KEPT_OUT` and `KEPT_STATES` (`jax.ad_checkpoint.checkpoint_name`), which bind
+nothing outside a `jax.checkpoint` whose policy lists them; where the block's policy does
+(`gpt2_model._remat_block_cls`, decided by `training/activation_checkpointing.attention_keep_plan`: 144 MiB a layer at the
+cell's shapes) its recomputed forward holds no `intra` and no walk. `gdn_plan` says which (`forwards_a_step`, `backward`).
+Inside a group the arrays the walk reads are rounded to the operands' dtype once, and `T = (I + L)^-1` has its own rule
+(`dL = -T^T dT T^T`: `T` is all it keeps of the series). The walk's backward needs the state that came into each chunk,
+for one group (64 MiB at 32 chunks and 32 heads of 128 x 128). The kernels' walk has its own rule (`custom_vjp`) that keeps
+the walk's operands alone: its backward kernel sweeps the group's chunks forward once more with those states kept in VMEM,
+then from the last chunk with `dS` resident. The plain scan's is autodiff over a rematerialized chunk step, which keeps the
+states as its carry, in HBM. `gdn_plan` says which (`kernels`, `backward`).
 """
 
 from __future__ import annotations
 
+import functools
+
 import jax
 import jax.numpy as jnp
+from jax.ad_checkpoint import checkpoint_name
 
 from modalities_tpu.ops import tiers
 from modalities_tpu.telemetry import scopes
@@ -61,10 +72,16 @@ CHUNK = 64
 GROUP_CHUNKS = 32  # chunks a rematerialized group holds: 2,048 positions, 1,024 `[C, C]` systems at 32 heads
 HOW_T = "nilpotent_product"  # how `(I + L)^-1` is computed, for `gdn_plan`
 WALK_KERNELS = ("gated_delta_state_fwd", "gated_delta_state_bwd")
+# what a rematerialized block may keep of a call beside its input (`gpt2_model._remat_block_cls`): o as the rule returns it and the
+# state that came into each group of chunks, so that the block's recomputed forward holds no `intra` and no walk
+KEPT_OUT, KEPT_STATES = "gdn_rule_out", "gdn_rule_states"
 _GROUPS = "rematerialized groups of chunks: a state a group kept, a group's matrices computed again; "
 # what the backward is, for `gdn_plan`, by whether the walk's kernels run
 BACKWARD = {False: _GROUPS + "autodiff over a rematerialized chunk step",
             True: _GROUPS + "the backward kernel sweeps a group's chunks forward, the states a chunk kept in VMEM, then backward"}
+# and what a rematerialized block adds to it, by whether it keeps `KEPT_OUT` and `KEPT_STATES`: the rule's forwards a step
+RECOMPUTED = {False: (3, "; the block's recomputed forward runs the rule once more"),
+              True: (2, "; the block kept o and the group states, its recomputed forward holds no rule")}
 
 
 def groups_of(tokens: int, chunk: int = CHUNK, group: int = GROUP_CHUNKS) -> tuple[int, int]:
@@ -196,28 +213,71 @@ def _group(state, q, k, v, g, beta, chunk: int):
     return state, out
 
 
+def _by_group(a, groups: int):
+    """`[B, S, ...]` as `[groups, B, S / groups, ...]`: what the scan over the groups walks."""
+    return jnp.moveaxis(a.reshape(a.shape[0], groups, a.shape[1] // groups, *a.shape[2:]), 1, 0)
+
+
+def _forward(q, k, v, g, beta, chunk: int, groups: int):
+    """The outer scan over `groups` groups of whole chunks from a state of zeros: o `[B, S, Hv, d_v]` and the state that came
+    into each group `[groups, B, Hk, r, d_k, d_v]` float32, all the rule's backward keeps beside its inputs."""
+    b, s, hk, dk = q.shape
+    hv, dv = v.shape[2], v.shape[3]
+
+    def one(state, xs):
+        state_out, out = _group(state, *xs, chunk)
+        return state_out, (state, out)
+
+    with jax.named_scope(scopes.GDN_STATE):  # the carry from group to group; a group names its own two scopes inside
+        _, (states_in, out) = jax.lax.scan(one, jnp.zeros((b, hk, hv // hk, dk, dv), jnp.float32), tuple(_by_group(a, groups) for a in (q, k, v, g, beta)))
+    return jnp.moveaxis(out, 0, 1).reshape(b, s, hv, dv), states_in
+
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(5, 6))
+def _rule(q, k, v, g, beta, chunk: int, groups: int):
+    """The rule over a row of `groups` groups of whole chunks, with a backward of its own (module docstring, "Backward.")."""
+    return _forward(q, k, v, g, beta, chunk, groups)[0]
+
+
+def _rule_fwd(q, k, v, g, beta, chunk: int, groups: int):
+    out, states_in = _forward(q, k, v, g, beta, chunk, groups)
+    # names bind nothing outside a `jax.checkpoint` whose policy lists them (`gpt2_model._remat_block_cls`)
+    return checkpoint_name(out, KEPT_OUT), (q, k, v, g, beta, checkpoint_name(states_in, KEPT_STATES))
+
+
+def _rule_bwd(chunk: int, groups: int, kept, d_out):
+    """From the last group to the first, carrying the state's cotangent: each group computed again from the state that came
+    into it (`jax.vjp` of `_group`: one group's matrices live at a time), then its backward."""
+    *inputs, states_in = kept
+
+    def one(d_state, xs):
+        state, d_o, *group_inputs = xs
+        # under a scope of its own: `jax.vjp` writes its transforms round the first scope inside it (`jvp(group)/intra/...`)
+        pull = jax.vjp(lambda state, *ins: jax.named_scope(scopes.GDN_GROUP)(_group)(state, *ins, chunk), state, *group_inputs)[1]
+        d_state, *d_inputs = pull((d_state, d_o))
+        return d_state, tuple(d_inputs)
+
+    with jax.named_scope(scopes.GDN_STATE):
+        # nothing reads the state the last group leaves: its cotangent is zero
+        _, d_inputs = jax.lax.scan(one, jnp.zeros_like(states_in[0]), (states_in, *(_by_group(a, groups) for a in (d_out, *inputs))), reverse=True)
+    return tuple(jnp.moveaxis(d, 0, 1).reshape(a.shape) for d, a in zip(d_inputs, inputs))
+
+
+_rule.defvjp(_rule_fwd, _rule_bwd)
+
+
 def gated_delta_rule(q, k, v, g, beta, *, chunk: int = CHUNK, group_chunks: int = GROUP_CHUNKS):
     """q, k `[B, S, Hk, d_k]` (already normalised and scaled by the caller), v `[B, S, Hv, d_v]`, g and beta
     `[B, S, Hv]` float32 (`g <= 0`). Returns o `[B, S, Hv, d_v]` in v's dtype. The chunked form (module docstring)."""
-    b, s, hk, dk = q.shape
-    hv, dv = v.shape[2], v.shape[3]
+    s, hk, hv = q.shape[1], q.shape[2], v.shape[2]
     if hv % hk:
         raise ValueError(f"gated_delta_rule: {hv} value heads do not share {hk} key heads evenly")
     groups, chunks_a_group = groups_of(s, chunk, group_chunks)
-    per_group = chunks_a_group * chunk  # positions a group holds
-    pad = groups * per_group - s
+    pad = groups * chunks_a_group * chunk - s
     if pad:  # positions that change nothing: no key, no correction, no decay
         q, k, v = (jnp.pad(a, ((0, 0), (0, pad), (0, 0), (0, 0))) for a in (q, k, v))
         g, beta = (jnp.pad(a, ((0, 0), (0, pad), (0, 0))) for a in (g, beta))
-    state = jnp.zeros((b, hk, hv // hk, dk, dv), jnp.float32)
-    if groups == 1:
-        out = _group(state, q, k, v, g, beta, chunk)[1]
-    else:
-        by_group = lambda a: jnp.moveaxis(a.reshape(b, groups, per_group, *a.shape[2:]), 1, 0)  # noqa: E731
-        one = jax.checkpoint(lambda state, xs: _group(state, *xs, chunk))
-        with jax.named_scope(scopes.GDN_STATE):  # the carry from group to group; a group names its own two scopes inside
-            _, out = jax.lax.scan(one, state, tuple(by_group(a) for a in (q, k, v, g, beta)))
-        out = jnp.moveaxis(out, 0, 1).reshape(b, groups * per_group, hv, dv)
+    out = _rule(q, k, v, g, beta, chunk, groups)
     return out[:, :s] if pad else out
 
 

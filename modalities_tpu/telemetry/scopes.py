@@ -83,6 +83,7 @@ module name `gdn` in a block's mixer seat:
     GDN_RULE          rule              the chunked rule; under it:
     GDN_INTRA         intra             what a chunk needs but the state, for all chunks at once: `L`, `T = (I + L)^-1`, `U`, `W`, the lower products
     GDN_STATE         state             the walk over the chunks that carries the `[d_k, d_v]` state a head (on a TPU the kernels `gated_delta_state_fwd` / `_bwd`, else a scan), and the carry from group to group
+    GDN_GROUP         group             in the rule's backward alone: one group of chunks computed again from the state that came into it, `intra` and `state` inside it (`.../state/while/body/jvp(group)/intra/...`)
     GDN_OUT_NORM      out_norm          the RMS norm a head of the rule's output, times `silu(z)` (the name of the norm's module)
     GDN_OUT           out               the output projection back to the residual's width (`gdn/out/out_proj`)
 
@@ -188,6 +189,7 @@ GDN_QK_NORM = "qk_norm"
 GDN_RULE = "rule"
 GDN_INTRA = "intra"
 GDN_STATE = "state"
+GDN_GROUP = "group"
 GDN_OUT_NORM = "out_norm"
 GDN_OUT = "out"
 ATTN_GATE = "gate"  # inside `attn`, where the attention's output is gated (`attn_output_gate`)
@@ -205,7 +207,7 @@ MOE_SCOPES = (MOE_ROUTER, MOE_DISPATCH, MOE_EXPERTS, MOE_SHARED, MOE_COMBINE)  #
 
 CCA_SCOPES = (CCA_LATENT, CCA_CONV, CCA_QK_MEAN, CCA_QK_NORM, CCA_VALUE_SHIFT, CCA_OUT)  # on the step only where a layer's mixer is `cca`
 ROUTER_MLP_SCOPES = (ROUTER_DOWN, ROUTER_EDA, ROUTER_MLP)  # on the step only where the router is an MLP over a carried state
-GDN_SCOPES = (GDN_IN_PROJ, GDN_CONV, GDN_GATES, GDN_QK_NORM, GDN_RULE, GDN_INTRA, GDN_STATE, GDN_OUT_NORM, GDN_OUT)  # on the step only where a layer's mixer is `gdn`
+GDN_SCOPES = (GDN_IN_PROJ, GDN_CONV, GDN_GATES, GDN_QK_NORM, GDN_RULE, GDN_INTRA, GDN_STATE, GDN_GROUP, GDN_OUT_NORM, GDN_OUT)  # on the step only where a layer's mixer is `gdn`
 
 # what a path holds beside scopes: the jit wrapper, the plumbing of loops, calls and branches
 _PLUMBING = frozenset(("while", "body", "cond", "closed_call", "checkpoint"))

@@ -161,12 +161,14 @@ class Trainer:
             try:
                 report = step_functions.memscope_report(device_batch)
                 kept, limit = getattr(step_functions, "kept_attention", None), min_bytes_limit()
-                if kept is not None and kept.plan and kept.plan["keep"] and report["predicted_peak_bytes"] > limit:
-                    # the count said the blocks' kept o and lse fit and the compiler says they do not: the step the model
-                    # had before is built in its place (one more trace and lowering), and only that one can fail the check
+                while kept is not None and kept.plan and kept.plan["kept"] and report["predicted_peak_bytes"] > limit:
+                    # the count said what the blocks keep fits and the compiler says it does not: the step one rung down the
+                    # plan's ladder is built in its place (one more trace and lowering a rung), and only the step that keeps
+                    # nothing, the one the model had before, can fail the check
                     logger.warning(
-                        "memscope: the step that keeps %d attention layers' o and lse (%d bytes) is predicted at %d bytes, over "
-                        "the device's %d: building the step without them", kept.plan["layers"], kept.plan["kept_bytes"],
+                        "memscope: the step whose blocks keep %s (%d bytes of attention, %d of the gated delta rule) is predicted at %d "
+                        "bytes, over the device's %d: building the step one rung down", " and ".join(kept.plan["kept"]),
+                        kept.plan["kept_bytes"] * kept.plan["keep"], kept.plan["rule_kept_bytes"] * kept.plan["keep_rule"],
                         report["predicted_peak_bytes"], limit)
                     kept.drop()
                     report = step_functions.memscope_report(device_batch)

@@ -5,11 +5,17 @@ Reference variants -> TPU equivalents:
 - FULL: remat every transformer block (``nn.remat`` around the scanned block). A block keeps its
   input, and since PR 41, where they fit, the flash kernel's ``o`` and ``lse`` (``lse`` as the kernel
   writes it since PR 42, ``[B, H, 1, S]`` rows of numbers), so that the recomputed forward does not run
-  ``flash_attention_fwd`` again: the
-  backward kernels read q, k, v (projections, made again) and those two, which only the forward
-  kernel makes. Who decides is the program, not a key: ``attention_keep_plan`` below, called while
-  the train step is traced (``training/train_step.py``), and ``Trainer._preflight_memscope`` under
-  it, which builds the step without keeping where the compiler finds the keeping one over budget.
+  ``flash_attention_fwd`` again: the backward kernels read q, k, v (projections, made again) and those
+  two, which only the forward kernel makes. Since PR 48 a block whose mixer is the gated delta rule
+  (``ops/gated_delta_rule.py``) also keeps the rule's ``o`` and the float32 state that comes into each
+  group of chunks, so that its recomputed forward holds no ``intra`` and no walk: the rule's backward
+  reads q, k, v, g, beta (made again) and those states, and the gated norm after it reads ``o``. (The
+  rule's backward still computes every group's matrices again from its kept state, one group at a time:
+  that is what keeps the step under the chip's memory.) Who decides is the program, not a key:
+  ``attention_keep_plan`` below, called while the train step is traced (``training/train_step.py``),
+  answers with a rung of one ladder (the flash kernel's two and the rule's two, the flash kernel's
+  alone, nothing), and ``Trainer._preflight_memscope`` under it steps down a rung at a time where the
+  compiler finds the keeping step over budget.
 - SELECTIVE_LAYER (every ac_freq-th block): honored on the unrolled-blocks model
   (``scan_layers=False``) where each layer gets its own remat decision; the
   scan-over-layers representation traces ONE body for every layer, so ac_freq > 1
@@ -77,26 +83,43 @@ KEEP_VERDICTS = ("fits", "over_count", "fell_back_in_preflight", "no_remat")
 KEEP_FLASH_WORKING_SETS, KEEP_BLOCK_WORKING_INPUTS = 2, 26
 
 
+KEEPS = ("flash", "rule")  # what a block may keep beside its input, in the order it is kept: a rung of the ladder is a prefix of these
+
+
 def attention_keep_plan(flash_calls: Optional[dict], *, state_bytes: int, gradient_bytes: int, bytes_limit: Optional[int],
-                        allowed: bool = True) -> dict:
-    """Whether the blocks under `full` remat keep the flash kernel's o and lse beside their input, from what the program
-    sees before it compiles: `flash_calls` (the model's `remat_flash_calls`: the rematerialized attention layers by kind
-    with their o and lse bytes; None or no call: nothing to keep, `no_remat`), the train state's and the gradients' bytes
-    a device, and the device's limit (`telemetry.device_memory.min_bytes_limit()`; None, a CPU: keep). Counted: state,
-    gradients, every block's input, the kept o and lse, and a block's working set as the two constants above have it;
-    `over_count` where that passes the limit. `allowed=False` is the preflight's verdict on a step that kept
-    (`fell_back_in_preflight`). Returns `keep`, `verdict`, `layers` and `kept_bytes` (what keeping would hold, whatever
-    the verdict), `counted_bytes`, `bytes_limit`."""
-    if not flash_calls or not flash_calls["calls"]:
-        return {"keep": False, "verdict": "no_remat", "layers": 0, "kept_bytes": 0, "counted_bytes": 0, "bytes_limit": bytes_limit}
-    calls = flash_calls["calls"]
-    kept = sum(call["layers"] * (call["o_bytes"] + call["lse_bytes"]) for call in calls)
-    working = (KEEP_FLASH_WORKING_SETS * max(call["backward_bytes"] for call in calls)
+                        first_rung: int = 0) -> dict:
+    """What the blocks under `full` remat keep beside their input, from what the program sees before it compiles:
+    `flash_calls` (the model's `remat_flash_calls`: the rematerialized attention layers by kind with their o and lse bytes
+    and, under `rule`, the layers of the gated delta rule with their o and group states; None or neither: nothing to keep,
+    `no_remat`), the train state's and the gradients' bytes a device, and the device's limit
+    (`telemetry.device_memory.min_bytes_limit()`; None, a CPU: keep). Counted: state, gradients, every block's input, what is
+    kept, and a block's working set as the two constants above have it (the rule's backward holds one group's working set
+    whether its block kept or not: the kept bytes are all it adds). The ladder: the flash kernel's two and the rule's two,
+    the flash kernel's alone, nothing (by time saved a kept byte: PERF.md section 6, PR 48); the plan takes the first rung
+    from `first_rung` on whose count is within the limit. `first_rung` above 0 is the preflight's verdict on a step that kept
+    more (`fell_back_in_preflight`); a rung below the first by the count alone is `over_count`. Returns `kept` (the names of
+    `KEEPS` the blocks keep), `rung`, `keep` and `keep_rule` (the same, by name), `verdict`, `layers` and `kept_bytes` (of
+    attention: what keeping would hold, whatever the verdict), `rule_layers` and `rule_kept_bytes` likewise,
+    `counted_bytes` (with everything kept) and `bytes_limit`."""
+    calls = flash_calls["calls"] if flash_calls else []
+    rule = flash_calls.get("rule") if flash_calls else None
+    bytes_of = {"flash": sum(call["layers"] * (call["o_bytes"] + call["lse_bytes"]) for call in calls),
+                "rule": rule["layers"] * (rule["o_bytes"] + rule["states_bytes"]) if rule else 0}
+    sizes = {"layers": sum(call["layers"] for call in calls), "kept_bytes": bytes_of["flash"],
+             "rule_layers": rule["layers"] if rule else 0, "rule_kept_bytes": bytes_of["rule"], "bytes_limit": bytes_limit}
+    names = tuple(name for name in KEEPS if bytes_of[name])
+    if not names:
+        return {"kept": (), "rung": 0, "keep": False, "keep_rule": False, "verdict": "no_remat", **sizes, "counted_bytes": 0}
+    working = (KEEP_FLASH_WORKING_SETS * max((call["backward_bytes"] for call in calls), default=0)
                + KEEP_BLOCK_WORKING_INPUTS * flash_calls["block_input_bytes"])
-    counted = state_bytes + gradient_bytes + flash_calls["blocks"] * flash_calls["block_input_bytes"] + kept + working
-    verdict = "fell_back_in_preflight" if not allowed else "over_count" if bytes_limit is not None and counted > bytes_limit else "fits"
-    return {"keep": verdict == "fits", "verdict": verdict, "layers": sum(call["layers"] for call in calls), "kept_bytes": kept,
-            "counted_bytes": counted, "bytes_limit": bytes_limit}
+    nothing_kept = state_bytes + gradient_bytes + flash_calls["blocks"] * flash_calls["block_input_bytes"] + working
+    counted = [nothing_kept + sum(bytes_of[name] for name in names[:keeps]) for keeps in range(len(names), -1, -1)]  # a rung after the other
+    # the last rung keeps nothing: it fits, or the preflight says that it does not
+    rung = next((rung for rung in range(first_rung, len(names)) if bytes_limit is None or counted[rung] <= bytes_limit), len(names))
+    kept = names[:len(names) - rung]
+    verdict = "fell_back_in_preflight" if first_rung else "over_count" if rung else "fits"
+    return {"kept": kept, "rung": rung, "keep": "flash" in kept, "keep_rule": "rule" in kept, "verdict": verdict, **sizes,
+            "counted_bytes": counted[0]}
 
 
 class ActivationCheckpointing:

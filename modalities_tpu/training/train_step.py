@@ -78,18 +78,19 @@ def _under_scope(tx: optax.GradientTransformation, name: str) -> optax.GradientT
 
 
 class KeptAttention:
-    """What a rematerialized block keeps of its attention in this build's step: the plan as the last trace of the step
-    made it (`training/activation_checkpointing.attention_keep_plan`; None before the first trace), and the way back
-    for a preflight that finds the keeping step over budget: `drop()` has the next trace plan without keeping and
-    forgets the step's traces, so that the next lowering or dispatch builds the step the model had before."""
+    """What a rematerialized block keeps beside its input in this build's step (the flash kernel's o and lse, the gated delta
+    rule's o and group states): the plan as the last trace of the step made it
+    (`training/activation_checkpointing.attention_keep_plan`; None before the first trace), and the way down for a preflight
+    that finds the keeping step over budget: `drop()` has the next trace plan from the rung below the one it took and forgets
+    the step's traces, so that the next lowering or dispatch builds the step that keeps less."""
 
     def __init__(self):
         self.plan: Optional[dict] = None
-        self.allowed = True
+        self.first_rung = 0  # of the plan's ladder: the rungs above it the preflight has found over the device's limit
         self.jitted: list = []  # the build's jitted steps: their caches hold the spec the model had when they were traced
 
     def drop(self) -> None:
-        self.allowed = False
+        self.first_rung = self.plan["rung"] + 1 if self.plan else 1
         for step in self.jitted:
             step.clear_cache()
 
@@ -644,10 +645,10 @@ class TrainStepBuilder:
         kept_attention = KeptAttention()
 
         def plan_kept_attention(state: AppState, microbatch_shape: tuple) -> None:
-            """Runs while the step is traced, before the model is: under `full` remat, whether a block keeps the flash
-            kernel's o and lse beside its input (`attention_keep_plan`: from the calls' shapes, this state's bytes a
-            device and the device's limit; no key of the config). The verdict goes onto the model's spec, which the
-            blocks read as they are traced, into three gauges and one log line."""
+            """Runs while the step is traced, before the model is: under `full` remat, what a block keeps beside its input,
+            the flash kernel's o and lse and the gated delta rule's o and group states (`attention_keep_plan`: from the
+            calls' shapes, this state's bytes a device and the device's limit; no key of the config). The answer goes onto
+            the model's spec, which the blocks read as they are traced, into five gauges and one log line."""
             flash_calls = getattr(model, "remat_flash_calls", None)
             if flash_calls is None or len(microbatch_shape) != 2:
                 return
@@ -661,18 +662,18 @@ class TrainStepBuilder:
                 state_bytes=sum(held(getattr(state, part), getattr(state_shardings, part, None)) for part in ("params", "opt_state")),
                 gradient_bytes=held(jax.tree.map(lambda p: jax.ShapeDtypeStruct(p.shape, reduce_dtype), state.params),
                                     zero_grad_shardings if zero_active else getattr(state_shardings, "params", None)),
-                bytes_limit=min_bytes_limit(), allowed=kept_attention.allowed,
+                bytes_limit=min_bytes_limit(), first_rung=kept_attention.first_rung,
             )
             kept_attention.plan = plan
-            model.with_spec_updates(remat_keep_flash=plan["keep"])
+            model.with_spec_updates(remat_keep_flash=plan["keep"], remat_keep_rule=plan["keep_rule"])
             telemetry = get_active_telemetry()
-            telemetry.metrics.gauge(
-                "train_remat_kept_attention_layers", "Attention layers whose rematerialized block keeps the flash kernel's o and lse"
-            ).set(plan["layers"] * plan["keep"])
-            telemetry.metrics.gauge(
-                "train_remat_kept_attention_bytes", "Bytes a device holds of kept o and lse over those layers"
-            ).set(plan["kept_bytes"] * plan["keep"])
-            verdict = telemetry.metrics.gauge("train_remat_keep_verdict", "1 under the attention keep plan's verdict, 0 under the others")
+            gauge = telemetry.metrics.gauge
+            gauge("train_remat_kept_attention_layers", "Attention layers whose rematerialized block keeps the flash kernel's o and lse").set(plan["layers"] * plan["keep"])
+            gauge("train_remat_kept_attention_bytes", "Bytes a device holds of kept o and lse over those layers").set(plan["kept_bytes"] * plan["keep"])
+            gauge("train_remat_kept_rule_layers", "Layers of the gated delta rule whose rematerialized block keeps the rule's o and group states"
+                  ).set(plan["rule_layers"] * plan["keep_rule"])
+            gauge("train_remat_kept_rule_bytes", "Bytes a device holds of kept o and group states over those layers").set(plan["rule_kept_bytes"] * plan["keep_rule"])
+            verdict = gauge("train_remat_keep_verdict", "1 under the attention keep plan's verdict, 0 under the others")
             for name in KEEP_VERDICTS:
                 verdict.set(float(name == plan["verdict"]), verdict=name)
             telemetry.emit_event_once("attention_keep_plan", plan)

@@ -1,6 +1,7 @@
-"""What keeping the flash kernel's o and lse under `full` remat costs a cell, without a chip: compile the cell's donated
-train step for a *described* v5e with the blocks keeping and not keeping, and print `memory_analysis()` of each beside
-what `training/activation_checkpointing.attention_keep_plan` counted and said at the v5e's limit.
+"""What a block under `full` remat keeps beside its input (the flash kernel's o and lse, the gated delta rule's o and group
+states) costs a cell, without a chip: compile the cell's donated train step for a *described* v5e at every rung of the plan's
+ladder (nothing kept, the flash kernel's two and, where the stack holds rule layers, the rule's two beside them) and print
+`memory_analysis()` of each beside what `training/activation_checkpointing.attention_keep_plan` counted and said at the v5e's limit.
 
     JAX_PLATFORMS=cpu python scripts/attention_keep_sizes.py --configs zaya1-8b-ep2,kanana2-30b-a3b-d9
 
@@ -45,22 +46,23 @@ def main() -> None:
     for config in args.configs.split(","):
         source = REPO / "benchmark" / "configs" / config / "train.yaml"
         apply_xla_flags_from_config(source)
-        for keeping in (False, True):
-            counted = {}
+        first_rung = len(activation_checkpointing.KEEPS)  # from the step that keeps nothing up the ladder to the one that keeps all it can
+        while first_rung >= 0:
+            counted, forced = {}, {}
 
-            def planned(flash_calls, **given):  # the described device reports no limit: count at the v5e's, keep as asked
-                counted.update(plan(flash_calls, **{**given, "bytes_limit": BYTES_LIMIT}))
-                return plan(flash_calls, **{**given, "bytes_limit": None, "allowed": keeping})
+            def planned(flash_calls, **given):  # the described device reports no limit: count at the v5e's, keep the rung asked for
+                counted.update(plan(flash_calls, **{**given, "bytes_limit": BYTES_LIMIT, "first_rung": 0}))
+                forced.update(plan(flash_calls, **{**given, "bytes_limit": None, "first_rung": first_rung}))
+                return dict(forced)
 
             activation_checkpointing.attention_keep_plan = planned
             t0 = time.perf_counter()
             m = build_lowered_train_step(source).lowered.compile().memory_analysis()
             peak = m.argument_size_in_bytes + m.temp_size_in_bytes + m.output_size_in_bytes - m.alias_size_in_bytes
-            print(json.dumps({"config": config, "keeping": keeping and counted["verdict"] != "no_remat", "compiler_peak_bytes": peak,
+            print(json.dumps({"config": config, "keeping": list(forced["kept"]), "compiler_peak_bytes": peak,
                               "over_limit_by": max(peak - BYTES_LIMIT, 0), "plan_at_the_limit": counted,
                               "seconds": round(time.perf_counter() - t0)}), flush=True)
-            if counted["verdict"] == "no_remat":
-                break  # nothing to keep: one program
+            first_rung = forced["rung"] - 1  # `rung` of a plan with nothing to keep is 0: one program
 
 
 if __name__ == "__main__":

@@ -351,6 +351,30 @@ def test_loss_counters_and_every_leafs_gradient_are_the_references(stack, toy):
         assert float(jnp.abs(got_leaves[name] - want_grads[name]).max()) < 1e-3 * float(jnp.abs(want_grads[name]).max()), name
 
 
+def test_under_full_remat_a_block_that_keeps_the_rules_o_and_group_states_gives_the_loss_and_gradients_it_gave(stack, toy, tokens):
+    """PR 48: what the block keeps (`spec.remat_keep_rule`, the plan's to set) are the arrays its recomputed forward made, so loss and every
+    gradient stand; and the traced program holds the rule's `intra` twice (the forward pass, the rule's own backward) where it held it three times."""
+    from tests.ops.test_gated_delta_rule import programs
+
+    model, _, params = toy
+    loss, _, grads = stack[:3]
+    keeping = build().with_spec_updates(compute_dtype="float32", remat_variant="full", remat_keep_rule=True)
+    step = jax.value_and_grad(lambda p, model: program_loss(model, p, tokens), has_aux=True)
+    with HIGHEST:
+        (kept_loss, _), kept_grads = jax.jit(lambda p: step(p, keeping))(params)
+    assert float(kept_loss) == pytest.approx(float(loss), rel=1e-6)
+    for (path, a), b in zip(jax.tree_util.tree_leaves_with_path(kept_grads), jax.tree.leaves(grads)):
+        assert float(jnp.abs(a - b).max()) <= 1e-5 * float(jnp.abs(b).max()) + 1e-8, jax.tree_util.keystr(path)  # `A_log`, `dt_bias`: 2e-7, sums of terms near underflow
+
+    def intra_products(model):
+        names = [str(e.source_info.name_stack) for e in programs(jax.make_jaxpr(lambda p: step(p, model))(params).jaxpr, []) if e.primitive.name == "dot_general"]
+        return sum("intra" in name and "transpose" not in name for name in names)
+
+    recomputing = build().with_spec_updates(compute_dtype="float32", remat_variant="full")
+    plain = build().with_spec_updates(compute_dtype="float32")
+    assert intra_products(recomputing) * 2 == intra_products(keeping) * 3 == intra_products(plain) * 3 > 0
+
+
 def test_the_rule_layers_counters_are_the_mean_over_the_rule_layers_alone(stack):
     """One rule layer and one attention layer: the attention layer's row holds zeros where the rule layer's holds its two, and
     the published mean leaves that row out (`beta` is a sigmoid of small numbers: about a half, not the quarter a mean over
@@ -376,8 +400,9 @@ def test_the_new_scopes_and_the_plan_are_on_the_step(toy, tokens):
             set_active_telemetry(previous)
         events = [json.loads(line) for line in Path(telemetry.sink_path).read_text().splitlines() if line.strip()]
     for name in scopes.GDN_SCOPES:
-        assert f"gdn/{name}/" in text or f"/{name}/" in text, name
-    for path in ("gdn/rule/intra", "gdn/rule/state", "gdn/in_proj/qkvz", "gdn/out/out_proj", "attn/gate", "moe/shared_gate"):
+        assert f"gdn/{name}/" in text or f"/{name}/" in text or f"jvp({name})/" in text, name  # `group`: in the rule's backward alone, under `jax.vjp`'s transforms
+    # a group's `intra` lies inside the scan over the groups since PR 48, one group or several: the scan's body is a function of its own in this text
+    for path in ("gdn/rule/state/while/body/closed_call", '"intra/', "gdn/rule/state", "gdn/in_proj/qkvz", "gdn/out/out_proj", "attn/gate", "moe/shared_gate"):
         assert path in text, path
     plan = next(e for e in events if e.get("name") == "gdn_plan" and e["tokens"] == 2 * SEQ)
     assert plan["chunk"] == 64 and plan["chunks"] == 2 and (plan["key_heads"], plan["value_heads"], plan["key_dim"], plan["value_dim"]) == (2, 4, 16, 16)

@@ -19,6 +19,7 @@ from modalities_tpu.ops import gated_delta_rule as rule
 from modalities_tpu.ops import tiers
 
 B, HK, HV, DK, DV = 2, 2, 4, 16, 8
+WIDE = 128  # the walk's kernels serve head sizes that fill whole lane tiles
 
 
 def inputs(seq: int, g_level: float, seed: int = 0, shape=(B, HK, HV, DK, DV)):
@@ -116,9 +117,95 @@ def test_bfloat16_inputs_take_bfloat16_operands_and_keep_a_float32_state():
     assert rule.state_bytes(16384, 32, 128, 128) == 8 * 32 * 128 * 128 * 4  # a state a group of 32 chunks and a head
 
 
+# ---------------------------------------------------------------- the rule's own backward (PR 48)
+
+
+def autodiff_rule(q, k, v, g, beta, *, chunk, group_chunks):
+    """The form `gated_delta_rule` had before it got a backward of its own: the outer scan over rematerialized groups, left to autodiff."""
+    b, s, hk, dk = q.shape
+    hv, dv = v.shape[2], v.shape[3]
+    groups, chunks_a_group = rule.groups_of(s, chunk, group_chunks)
+    per_group = chunks_a_group * chunk
+    pad = groups * per_group - s
+    if pad:
+        q, k, v = (jnp.pad(a, ((0, 0), (0, pad), (0, 0), (0, 0))) for a in (q, k, v))
+        g, beta = (jnp.pad(a, ((0, 0), (0, pad), (0, 0))) for a in (g, beta))
+    by_group = lambda a: jnp.moveaxis(a.reshape(b, groups, per_group, *a.shape[2:]), 1, 0)  # noqa: E731
+    one = jax.checkpoint(lambda state, xs: rule._group(state, *xs, chunk))
+    _, out = jax.lax.scan(one, jnp.zeros((b, hk, hv // hk, dk, dv), jnp.float32), tuple(by_group(a) for a in (q, k, v, g, beta)))
+    return jnp.moveaxis(out, 0, 1).reshape(b, groups * per_group, hv, dv)[:, :s]
+
+
+# the row, the chunk and the chunks a group; with the kernels in, heads of 128 x 128 and a chunk of whole sublane tiles (16 in bfloat16)
+OWN_BACKWARD = {"one_group": (64, 16, 32), "four_groups_of_two_chunks": (128, 16, 2), "a_row_of_100_is_padded": (100, 16, 2)}
+OWN_BACKWARD_CASES = [(layout, dtype, False) for layout in sorted(OWN_BACKWARD) for dtype in ("float32", "bfloat16")] + [
+    ("one_group", "float32", True), ("four_groups_of_two_chunks", "bfloat16", True), ("a_row_of_100_is_padded", "float32", True)]
+
+
+@pytest.mark.parametrize("layout, dtype, interpreted", OWN_BACKWARD_CASES, ids=lambda value: {True: "kernels_interpreted", False: "plain_walk"}.get(value, value))
+def test_the_rules_own_backward_is_autodiff_of_the_scan_over_groups(layout, dtype, interpreted):
+    """Output and all five gradients: the same arithmetic in the same order, so float32 agrees to rounding and bfloat16 to the file's hold."""
+    import contextlib
+
+    seq, chunk, group = OWN_BACKWARD[layout]
+    xs = inputs(seq, -0.3, shape=(1, 1, 2, WIDE, WIDE) if interpreted else (B, HK, HV, DK, DV))
+    xs = [jnp.asarray(a, dtype) if i < 3 else a for i, a in enumerate(xs)]  # the gates stay float32, as the mixer hands them over
+    with tiers.interpreted_kernels() if interpreted else contextlib.nullcontext():
+        got, got_grads = program(functools.partial(rule.gated_delta_rule, chunk=chunk, group_chunks=group))(*xs)
+        want, want_grads = program(functools.partial(autodiff_rule, chunk=chunk, group_chunks=group))(*xs)
+    hold = 1e-5 if dtype == "float32" else 2e-2
+    assert got.shape == want.shape == xs[2].shape and got.dtype == want.dtype and gap(got, want) <= hold
+    for name, x, a, b in zip("q k v g beta".split(), xs, got_grads, want_grads):
+        assert a.shape == b.shape == x.shape and a.dtype == b.dtype == x.dtype and gap(a, b) <= hold, (name, gap(a, b))
+
+
+def programs(jaxpr, found: list):
+    """Every equation of a program and of the programs nested in it."""
+    for eqn in jaxpr.eqns:
+        found.append(eqn)
+        for value in eqn.params.values():
+            for inner in value if isinstance(value, (list, tuple)) else (value,):
+                inner = getattr(inner, "jaxpr", inner)
+                if hasattr(inner, "eqns"):
+                    programs(inner, found)
+    return found
+
+
+@pytest.mark.parametrize("interpreted", [False, True], ids=["plain_walk", "kernels_interpreted"])
+def test_a_block_that_keeps_o_and_the_group_states_computes_a_group_twice_a_step_and_not_three_times(interpreted):
+    """Traced, not run. A group's `intra` (the inverse's series among its products) stands in the forward pass and in the rule's backward,
+    which computes a group again from the state that came into it; a rematerialized block whose policy does not list the two names holds it
+    a third time, in its recomputed forward. With the kernels in, the forward kernel is counted the same way."""
+    import contextlib
+
+    xs = inputs(64, -0.3, shape=(1, 1, 2, WIDE, WIDE) if interpreted else (B, HK, HV, DK, DV))
+    (b, _, hk, dk), (hv, dv) = xs[0].shape, xs[2].shape[2:]
+    forward = functools.partial(rule.gated_delta_rule, chunk=16, group_chunks=2)
+    loss = lambda *xs: jnp.sum(jnp.sin(forward(*xs)))  # noqa: E731
+
+    def counted(fn):
+        with tiers.interpreted_kernels() if interpreted else contextlib.nullcontext():
+            eqns = programs(jax.make_jaxpr(fn)(*xs).jaxpr, [])
+        products = sum(e.primitive.name == "dot_general" and "intra" in str(e.source_info.name_stack) and "transpose" not in str(e.source_info.name_stack) for e in eqns)
+        kernels = sum(e.primitive.name == "pallas_call" and e.params["name"] == "gated_delta_state_fwd" for e in eqns)
+        names = {e.params["name"]: e.outvars[0].aval for e in eqns if e.primitive.name == "name"}
+        return products, kernels, names
+
+    once, kernel_once, _ = counted(forward)
+    assert once > 0 and kernel_once == int(interpreted)
+    keeping = jax.checkpoint_policies.save_only_these_names(rule.KEPT_OUT, rule.KEPT_STATES)
+    another = jax.checkpoint_policies.save_only_these_names("flash_out", "flash_lse")
+    grad = lambda policy: jax.value_and_grad(jax.checkpoint(loss, policy=policy), argnums=(0, 1, 2, 3, 4))  # noqa: E731
+    for policy, times in ((None, 3), (another, 3), (keeping, 2)):
+        products, kernels, names = counted(grad(policy))
+        assert products == times * once and kernels == times * kernel_once, (times, products, once)
+        assert names[rule.KEPT_OUT].shape == xs[2].shape and names[rule.KEPT_STATES].shape == (2, b, hk, hv // hk, dk, dv)  # a state a group
+        assert names[rule.KEPT_STATES].dtype == jnp.float32
+    assert counted(jax.value_and_grad(loss, argnums=(0, 1, 2, 3, 4)))[:2] == (2 * once, 2 * kernel_once)  # no rematerialized block: forward, and the rule's backward
+
+
 # ---------------------------------------------------------------- the walk's kernels (interpreted: `tiers.interpreted_kernels()`)
 
-WIDE = 128  # the kernels serve head sizes that fill whole lane tiles
 
 
 def prepared(dtype, chunks=3, key_heads=1, r=2, chunk=16, seed=0):

@@ -1,5 +1,6 @@
 """Under `full` remat a block keeps the flash kernel's o and lse beside its input (PR 41): the recomputed forward has no
-use for `flash_attention*_fwd` and the backward holds ONE call of it where it held two. On a CPU the kernels run
+use for `flash_attention*_fwd` and the backward holds ONE call of it where it held two. Since PR 48 a block whose mixer is the
+gated delta rule keeps the rule's o and group states one rung above them (`tests/ops/test_gated_delta_rule.py` counts the rule's forwards). On a CPU the kernels run
 interpreted (the fixture `kernels_interpreted`: `ops/tiers.interpreted_kernels`), through the dispatcher the model calls
 (`ops/attention.flash_attention_or_fallback`), at toy size; what is
 kept is decided by `training/activation_checkpointing.attention_keep_plan`, and `Trainer._preflight_memscope` is the net
@@ -17,6 +18,7 @@ from modalities_tpu.trainer import Trainer
 from modalities_tpu.training.activation_checkpointing import attention_keep_plan
 from modalities_tpu.training.train_step import KeptAttention
 from tests.models.test_gpt2_model import tiny_gpt2
+from tests.ops.test_gated_delta_rule import programs
 
 ROWS, SEQ = 2, 64
 # what sits in the block's mixer seat, and the toy model's keys that put it there
@@ -32,17 +34,11 @@ KINDS = {
 
 def walked(jaxpr, calls: dict, named: dict):
     """Every `pallas_call` by its kernel's name, and every value a `checkpoint_name` marks, through all nested programs."""
-    for eqn in jaxpr.eqns:
+    for eqn in programs(jaxpr, []):
         if eqn.primitive.name == "pallas_call":
-            name = eqn.params["name"]
-            calls[name] = calls.get(name, 0) + 1
+            calls[eqn.params["name"]] = calls.get(eqn.params["name"], 0) + 1
         if eqn.primitive.name == "name":
             named[eqn.params["name"]] = eqn.outvars[0].aval
-        for value in eqn.params.values():
-            for inner in value if isinstance(value, (list, tuple)) else (value,):
-                inner = getattr(inner, "jaxpr", inner)
-                if hasattr(inner, "eqns"):
-                    walked(inner, calls, named)
     return calls, named
 
 
@@ -107,27 +103,73 @@ CELLS = {
         {"kind": "swa", "layers": 9, **ATTENTION, "backward_bytes": 843055104}]}, 5457742856, 3631186944, "fits", 13.92),
     "train-zaya1-8b-8k": ({"blocks": 10, "block_input_bytes": 64 * MIB, "calls": [
         {"kind": "cca", "layers": 10, "o_bytes": 32 * MIB, "lse_bytes": MIB // 2, "backward_bytes": 219152384}]}, 6859299056, 4545393400, "fits", 13.50),
+    # PR 48: one attention layer at 16 heads of 256 and three layers of the gated delta rule, each o `[16384, 32, 128]` bfloat16 and 8 group states of
+    # 32 heads of 128 x 128 float32; 14.38 GiB compiled with both kept, 13.55 with the flash kernel's two or with nothing
+    "train-qwen3next-80b-16k": ({"blocks": 4, "block_input_bytes": 64 * MIB, "calls": [
+        {"kind": "attn", "layers": 1, "o_bytes": 128 * MIB, "lse_bytes": MIB, "backward_bytes": 840957952}],
+        "rule": {"layers": 3, "o_bytes": 128 * MIB, "states_bytes": 16 * MIB}}, 6195794696, 4113281280, "fits", 14.38),
 }
 
 
 @pytest.mark.parametrize("cell", sorted(CELLS))
-def test_the_plan_over_the_six_cells(cell):
+def test_the_plan_over_the_cells(cell):
     calls, state_bytes, gradient_bytes, verdict, compiled_gib = CELLS[cell]
     plan = attention_keep_plan(calls, state_bytes=state_bytes, gradient_bytes=gradient_bytes, bytes_limit=V5E)
     assert plan["verdict"] == verdict and plan["keep"] == (verdict == "fits") and plan["bytes_limit"] == V5E
     if calls is None:
-        assert plan["layers"] == plan["kept_bytes"] == 0
+        assert plan["layers"] == plan["kept_bytes"] == plan["rule_layers"] == plan["rule_kept_bytes"] == 0 and plan["kept"] == () and not plan["keep_rule"]
         return
+    rule = calls.get("rule")
     assert plan["layers"] == sum(call["layers"] for call in calls["calls"])
     assert plan["kept_bytes"] == sum(call["layers"] * (call["o_bytes"] + call["lse_bytes"]) for call in calls["calls"])
-    # the count never reads under the compiler (a `fits` the preflight would overturn costs a second lowering), nor a GiB over it
-    assert compiled_gib - 0.01 <= plan["counted_bytes"] / GIB <= compiled_gib + 1.0
+    assert plan["kept"] == (("flash", "rule") if rule else ("flash",)) and plan["rung"] == 0 and plan["keep_rule"] == bool(rule)
+    assert (plan["rule_layers"], plan["rule_kept_bytes"]) == ((3, 452984832) if rule else (0, 0))
+    # the count never reads under the compiler (a `fits` the preflight would overturn costs a second lowering), nor a GiB over it; but of a rule
+    # layer it holds the kept bytes alone (no third fitted constant), and reads under the compiler there by what a group's working set is
+    assert compiled_gib - (0.8 if rule else 0.01) <= plan["counted_bytes"] / GIB <= compiled_gib + 1.0
     assert (plan["counted_bytes"] > V5E) == (compiled_gib * GIB > V5E)  # and says of each cell what the compiler says
-    # no limit (a CPU): keep; the preflight's verdict on a step that kept: do not
+    # no limit (a CPU): keep; the preflight's verdict on a step that kept: one rung down
     free = attention_keep_plan(calls, state_bytes=state_bytes, gradient_bytes=gradient_bytes, bytes_limit=None)
-    assert free["keep"] and free["verdict"] == "fits" and free["kept_bytes"] == plan["kept_bytes"]
-    dropped = attention_keep_plan(calls, state_bytes=state_bytes, gradient_bytes=gradient_bytes, bytes_limit=None, allowed=False)
-    assert not dropped["keep"] and dropped["verdict"] == "fell_back_in_preflight"
+    assert free["keep"] and free["verdict"] == "fits" and free["kept_bytes"] == plan["kept_bytes"] and free["kept"] == plan["kept"]
+    dropped = attention_keep_plan(calls, state_bytes=state_bytes, gradient_bytes=gradient_bytes, bytes_limit=None, first_rung=len(plan["kept"]))
+    assert not dropped["keep"] and not dropped["keep_rule"] and dropped["kept"] == () and dropped["verdict"] == "fell_back_in_preflight"
+
+
+def test_the_seventh_cells_numbers():
+    """What the issue asks the chip's log to read: one flash call of 135,266,304 bytes, three rule layers of 452,984,832 in all."""
+    calls, state_bytes, gradient_bytes, _, _ = CELLS["train-qwen3next-80b-16k"]
+    plan = attention_keep_plan(calls, state_bytes=state_bytes, gradient_bytes=gradient_bytes, bytes_limit=16909336064)  # the chip's own `bytes_limit`
+    assert (plan["layers"], plan["kept_bytes"], plan["rule_layers"], plan["rule_kept_bytes"]) == (1, 135266304, 3, 452984832)
+    assert plan["verdict"] == "fits" and plan["counted_bytes"] == 14592508936
+    without = attention_keep_plan({k: v for k, v in calls.items() if k != "rule"}, state_bytes=state_bytes, gradient_bytes=gradient_bytes, bytes_limit=V5E)
+    assert plan["counted_bytes"] - without["counted_bytes"] == 452984832  # the kept bytes and nothing else: the rule's backward holds a group's working set either way
+
+
+# a limit a byte under a rung's count, by what the rungs below it count: (limit below the count of everything, of the flash kernel's two, of nothing)
+LADDER = {"all_fits": (None, ("flash", "rule"), "fits"), "between_the_two_rungs": (452984832, ("flash",), "over_count"),
+          "under_both": (452984832 + 135266304, (), "over_count")}
+
+
+@pytest.mark.parametrize("case", sorted(LADDER))
+def test_the_ladder_flash_and_rule_then_flash_alone_then_nothing(case):
+    calls, state_bytes, gradient_bytes, _, _ = CELLS["train-qwen3next-80b-16k"]
+    below, kept, verdict = LADDER[case]
+    everything = attention_keep_plan(calls, state_bytes=state_bytes, gradient_bytes=gradient_bytes, bytes_limit=None)["counted_bytes"]
+    limit = V5E if below is None else everything - below + (below // 2 if case == "between_the_two_rungs" else -1)
+    plan = attention_keep_plan(calls, state_bytes=state_bytes, gradient_bytes=gradient_bytes, bytes_limit=limit)
+    assert plan["kept"] == kept and plan["rung"] == 2 - len(kept) and plan["verdict"] == verdict
+    assert plan["keep"] == ("flash" in kept) and plan["keep_rule"] == ("rule" in kept) and plan["counted_bytes"] == everything
+    # the preflight's steps: from rung 1 the rule is not kept whatever the count, from rung 2 nothing is
+    for first_rung, most in ((1, ("flash",)), (2, ())):
+        stepped = attention_keep_plan(calls, state_bytes=state_bytes, gradient_bytes=gradient_bytes, bytes_limit=limit, first_rung=first_rung)
+        assert stepped["kept"] == kept[:len(most)] and stepped["verdict"] == "fell_back_in_preflight" and stepped["rung"] >= first_rung
+
+
+def test_a_stack_of_rule_layers_alone_keeps_the_rules_two_or_nothing():
+    calls = {"blocks": 3, "block_input_bytes": 64 * MIB, "calls": [], "rule": {"layers": 3, "o_bytes": 128 * MIB, "states_bytes": 16 * MIB}}
+    plan = attention_keep_plan(calls, state_bytes=GIB, gradient_bytes=GIB, bytes_limit=V5E)
+    assert plan["kept"] == ("rule",) and plan["keep_rule"] and not plan["keep"] and plan["verdict"] == "fits" and plan["layers"] == 0
+    assert attention_keep_plan(calls, state_bytes=GIB, gradient_bytes=GIB, bytes_limit=4 * GIB)["kept"] == ()
 
 
 def test_the_third_cell_keeps_because_its_statistics_are_numbers_now():
@@ -140,7 +182,7 @@ def test_the_third_cell_keeps_because_its_statistics_are_numbers_now():
     padded = {**calls, "calls": [{**call, "backward_bytes": operands + 2 * 128 * call["lse_bytes"]}]}
     sizes = dict(state_bytes=state_bytes, gradient_bytes=gradient_bytes, bytes_limit=V5E)
     assert attention_keep_plan(calls, **sizes)["verdict"] == "fits" and attention_keep_plan(padded, **sizes)["verdict"] == "over_count"
-    assert not attention_keep_plan(padded, **sizes)["keep"]
+    assert not attention_keep_plan(padded, **sizes)["keep"] and attention_keep_plan(padded, **sizes)["rung"] == 1
 
 
 def test_a_stack_with_no_attention_layer_under_remat_has_nothing_to_keep():
@@ -165,15 +207,20 @@ def test_the_model_names_the_calls_its_rematerialized_blocks_hold(monkeypatch, k
 
 
 class _Steps:
-    """What `Trainer._preflight_memscope` reads of a build: the reports its lowerings would give, in turn."""
+    """What `Trainer._preflight_memscope` reads of a build: the reports its lowerings would give, in turn, each lowering planning
+    from the rung the preflight left it (`names`: what the top rung keeps)."""
 
-    def __init__(self, *peaks, keep=True):
+    def __init__(self, *peaks, names=("flash",)):
         self.reports = [{"predicted_peak_bytes": peak, "buckets": {}, "context": {}} for peak in peaks]
         self.lower_train_step = object()
-        self.kept_attention = KeptAttention()
-        self.kept_attention.plan = {"keep": keep, "layers": 10, "kept_bytes": 340787200, "verdict": "fits" if keep else "over_count"}
+        self.kept_attention, self.names, self.lowered = KeptAttention(), names, []
 
     def memscope_report(self, batch):
+        rung = min(self.kept_attention.first_rung, len(self.names))
+        kept = self.names[:len(self.names) - rung]
+        self.kept_attention.plan = {"kept": kept, "rung": rung, "keep": "flash" in kept, "keep_rule": "rule" in kept, "layers": 10, "kept_bytes": 340787200,
+                                    "rule_layers": 3, "rule_kept_bytes": 452984832, "verdict": "fell_back_in_preflight" if rung else "fits"}
+        self.lowered.append(kept)
         return self.reports.pop(0)
 
 
@@ -181,32 +228,55 @@ def test_the_preflight_builds_the_step_without_keeping_where_the_keeping_step_is
     monkeypatch.setattr("modalities_tpu.trainer.min_bytes_limit", lambda: V5E)
     monkeypatch.setattr("modalities_tpu.telemetry.memscope.min_bytes_limit", lambda: V5E)
     fits = _Steps(V5E - 1)
-    assert Trainer._preflight_memscope(fits, None)["predicted_peak_bytes"] == V5E - 1 and fits.kept_attention.allowed
+    assert Trainer._preflight_memscope(fits, None)["predicted_peak_bytes"] == V5E - 1 and fits.kept_attention.first_rung == 0
     over = _Steps(V5E + 1, V5E - 2**28)  # the keeping step is over, the step the model had before is not
     assert Trainer._preflight_memscope(over, None)["predicted_peak_bytes"] == V5E - 2**28
-    assert not over.kept_attention.allowed and not over.reports
+    assert over.kept_attention.first_rung == 1 and not over.reports and over.lowered == [("flash",), ()]
     with pytest.raises(FitsCheckFailure):  # only the plain step's report can fail the check
         Trainer._preflight_memscope(_Steps(V5E + 2**28, V5E + 1), None)
-    plain = _Steps(V5E + 1, keep=False)  # a step that keeps nothing has no second form
+    plain = _Steps(V5E + 1, names=())  # a step that keeps nothing has no second form
     with pytest.raises(FitsCheckFailure):
         Trainer._preflight_memscope(plain, None)
-    assert plain.kept_attention.allowed
+    assert plain.kept_attention.first_rung == 0
+
+
+# the peaks the lowerings report, in turn -> what each lowering kept, and whether the last one passes the check
+STEPS_DOWN = {"both_fit": ((V5E - 1,), [("flash", "rule")], True), "the_rule_does_not": ((V5E + 1, V5E - 1), [("flash", "rule"), ("flash",)], True),
+              "neither_does": ((V5E + 2, V5E + 1, V5E - 1), [("flash", "rule"), ("flash",), ()], True),
+              "nor_the_plain_step": ((V5E + 3, V5E + 2, V5E + 1), [("flash", "rule"), ("flash",), ()], False)}
+
+
+@pytest.mark.parametrize("case", sorted(STEPS_DOWN))
+def test_the_preflight_steps_down_one_rung_at_a_time_and_only_the_last_step_can_fail(monkeypatch, case):
+    monkeypatch.setattr("modalities_tpu.trainer.min_bytes_limit", lambda: V5E)
+    monkeypatch.setattr("modalities_tpu.telemetry.memscope.min_bytes_limit", lambda: V5E)
+    peaks, lowered, passes = STEPS_DOWN[case]
+    steps = _Steps(*peaks, names=("flash", "rule"))
+    if passes:
+        assert Trainer._preflight_memscope(steps, None)["predicted_peak_bytes"] == peaks[-1]
+    else:
+        with pytest.raises(FitsCheckFailure):
+            Trainer._preflight_memscope(steps, None)
+    assert steps.lowered == lowered and not steps.reports and steps.kept_attention.first_rung == len(lowered) - 1
 
 
 def test_dropping_has_the_next_trace_plan_without_keeping():
-    """`KeptAttention.drop` forgets the build's traces: the next call traces the step again, and plans it not allowed."""
+    """`KeptAttention.drop` forgets the build's traces: the next call traces the step again, and plans it from one rung down."""
     kept, traced = KeptAttention(), []
 
     @jax.jit
     def step(x):
-        traced.append(kept.allowed)
+        traced.append(kept.first_rung)
+        kept.plan = {"rung": kept.first_rung}
         return x + 1
 
     kept.jitted.append(step)
     step(1.0), step(2.0)
     kept.drop()
     step(3.0)
-    assert traced == [True, False]
+    kept.drop()
+    step(4.0)
+    assert traced == [0, 1, 2]
 
 
 def test_the_step_plans_while_it_is_traced_and_plans_again_once_dropped(kernels_interpreted):
@@ -229,6 +299,7 @@ def test_the_step_plans_while_it_is_traced_and_plans_again_once_dropped(kernels_
         assert plan["verdict"] == "fits" and plan["layers"] == 2 and plan["bytes_limit"] is None and model.config_spec.remat_keep_flash
         gauge = lambda name, **labels: telemetry.metrics.gauge(name).value(**labels)  # noqa: E731
         assert gauge("train_remat_kept_attention_layers") == 2 and gauge("train_remat_kept_attention_bytes") == plan["kept_bytes"] > 0
+        assert gauge("train_remat_kept_rule_layers") == 0 and gauge("train_remat_kept_rule_bytes") == 0 and not model.config_spec.remat_keep_rule  # no rule layer
         assert gauge("train_remat_keep_verdict", verdict="fits") == 1
         fns.kept_attention.drop()
         fns.lower_train_step(batch)
@@ -237,3 +308,41 @@ def test_the_step_plans_while_it_is_traced_and_plans_again_once_dropped(kernels_
         assert gauge("train_remat_keep_verdict", verdict="fits") == 0 and gauge("train_remat_keep_verdict", verdict="fell_back_in_preflight") == 1
     finally:
         set_active_telemetry(previous)
+
+
+def test_a_step_over_rule_layers_keeps_the_rules_two_and_steps_down_a_rung_at_a_time(kernels_interpreted, tmp_path):
+    """The toy of `tests/models/test_gdn_moe.py` (one layer of the gated delta rule, one of attention) lowered, not compiled: the
+    plan's answer on the spec, in the five gauges and in both events, at each of the ladder's three rungs."""
+    import json
+
+    import numpy as np
+
+    from modalities_tpu.telemetry import Telemetry, set_active_telemetry
+    from tests.models.test_gdn_moe import SEQ as ROW, build
+    from tests.training.test_train_step import _batch, _builder
+
+    telemetry = Telemetry(output_folder_path=tmp_path)
+    previous = set_active_telemetry(telemetry)
+    try:
+        model = build(attention_implementation="dao_flash").with_spec_updates(remat_variant="full")
+        fns = _builder(model, None).build(seed=0, materialize=False)
+        batch = _batch(np.random.default_rng(0), 1, 2, ROW, vocab=512)
+        gauge = lambda name: telemetry.metrics.gauge(name).value()  # noqa: E731
+        rule_bytes = 2 * ROW * 4 * 16 * 2 + 2 * 4 * 16 * 16 * 4  # o in bfloat16, one group's state a value head in float32
+        for kept in (("flash", "rule"), ("flash",), ()):
+            fns.lower_train_step(batch)
+            plan, spec = fns.kept_attention.plan, model.config_spec
+            assert plan["kept"] == kept and (spec.remat_keep_flash, spec.remat_keep_rule) == ("flash" in kept, "rule" in kept)
+            assert (plan["layers"], plan["rule_layers"], plan["rule_kept_bytes"]) == (1, 1, rule_bytes)
+            assert plan["verdict"] == ("fits" if len(kept) == 2 else "fell_back_in_preflight")
+            assert gauge("train_remat_kept_rule_layers") == ("rule" in kept) and gauge("train_remat_kept_rule_bytes") == rule_bytes * ("rule" in kept)
+            assert gauge("train_remat_kept_attention_layers") == ("flash" in kept) and gauge("train_remat_kept_attention_bytes") == plan["kept_bytes"] * ("flash" in kept)
+            fns.kept_attention.drop()
+        events = [json.loads(line) for line in telemetry.sink_path.read_text().splitlines() if line.strip()]
+    finally:
+        set_active_telemetry(previous)
+    plans = [e for e in events if e.get("name") == "attention_keep_plan"]
+    assert [tuple(e["kept"]) for e in plans] == [("flash", "rule"), ("flash",), ()] and [e["rung"] for e in plans] == [0, 1, 2]
+    rules = [e for e in events if e.get("name") == "gdn_plan" and e["tokens"] == 2 * ROW]  # once a distinct payload: the keeping step's, then the steps' that do not keep it
+    assert [e["forwards_a_step"] for e in rules] == [2, 3]
+    assert "the block kept o and the group states" in rules[0]["backward"] and "runs the rule once more" in rules[1]["backward"]
