@@ -23,12 +23,6 @@ run prints the `[spans]` table and keeps the result with what the run observed, 
 others. Every metric is `None` on a program without the record (a commit from before
 it) and on a run without a device trace (every CPU run: a host timing from a CPU has no
 place under these names), so the arithmetic is in functions that take the records.
-
-`BENCHMARK.json` lists none of the ten yet: `program_spans.entries.json`, beside this
-file, holds their `per_layer` entries ready to be appended (all four cells each), and
-`tests/benchmark/test_program_spans.py` runs the harness on a root that has them. What
-stands in the way is five tests that hold each accepted cell's list of per-layer metrics
-to an exact set (PERF.md section 7), which only a `benchmark` issue may loosen.
 """
 
 from __future__ import annotations

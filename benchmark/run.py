@@ -44,6 +44,13 @@ class Context:
     scratch: Path
     trace_dir: Path | None
 
+    @property
+    def weights_seed(self) -> int:
+        """What a mode draws the WEIGHTS from: the run's seed, or the cell's own `weights_seed` (its workload file) where
+        the cell's work follows its weights, as an expert cell's routing does: `--seed` then draws the corpus alone, and
+        the runs of a set differ less by the draw (PERF.md section 6, PR 49)."""
+        return int(self.cell.spec.get("weights_seed", self.seed))
+
 
 def _units(root: Path) -> dict[str, str]:
     manifest = load_manifest(root)

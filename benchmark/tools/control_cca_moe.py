@@ -9,8 +9,9 @@ bfloat16 the configuration states) as `compare` would judge them.
 with one step of the equations left out, which `correct` must fail: `no_conv` (q and k are the
 shared mean alone: neither convolution), `no_value_shift` (every value head from the current
 position), `no_qk_mean` (q and k are the convolutions' output alone), `no_eda` (no state handed
-from layer to layer), `full_rotary` (the rotary on a whole head, not its first half). What a
-program with that fault would read, row by row.
+from layer to layer), `full_rotary` (the rotary on a whole head, not its first half), `no_bias_move`
+(the selection bias left where it was). What a program with that fault would read, row by row, the
+routing rows the cell's file gives no limit among them.
 
 The control is simulated, as the other cells' are: the train path has no lower-precision
 path of its own, so nothing of the program runs here. Per seed the tool packs the
@@ -46,6 +47,8 @@ def control(cell, seeds, variant: str = "int8") -> None:
     other, precision = shape, "int8"
     if variant == "full_rotary":  # the rotary turns a whole head
         other, precision = dataclasses.replace(shape, rotated=shape.head_dim), "f32"
+    elif variant == "no_bias_move":  # the selection bias stays where it was: the bias's own row, and the second step's routing, read it
+        other, precision = dataclasses.replace(shape, bias_update_speed=0.0), "f32"
     elif variant != "int8":  # one step of the equations left out (`benchmark/reference/cca_moe_decoder_f32.py`, `shape.without`)
         other, precision = dataclasses.replace(shape, without=(variant,)), "f32"
     profile, mesh = raw["settings"]["step_profile"], raw["device_mesh"]["config"]
@@ -65,13 +68,14 @@ def control(cell, seeds, variant: str = "int8") -> None:
         for step in range(mode.CHECK_STEPS):
             starts = [(step * rows + r) * seq for r in range(rows)]
             batches.append((np.stack([stream[s : s + seq] for s in starts]), np.stack([stream[s + 1 : s + seq + 1] for s in starts])))
-        got = reference.train_steps(other, seed, batches, hyper, precision=precision, keep_first_grad=True)
-        want = reference.train_steps(shape, seed, batches, hyper, other_first_grad=got.pop("first_grad"))
+        weights_seed = int(cell.spec.get("weights_seed", seed))  # the cell's own weights where it names them: the corpus alone follows the seed
+        got = reference.train_steps(other, weights_seed, batches, hyper, precision=precision, keep_first_grad=True)
+        want = reference.train_steps(shape, weights_seed, batches, hyper, other_first_grad=got.pop("first_grad"))
         got.update(loss_start=0.0, loss_end=0.0)
         tokens = rows * seq
         judged = mode.judged_with_routing(got, want, cell.spec["limits"], shape, tokens)
         print("[control] " + json.dumps({"variant": variant, "seed": seed, "seconds": round(time.perf_counter() - t0, 1),
-                                         **{row["name"]: row["value"] for row in judged},
+                                         **{row["name"]: row["value"] for row in (*mode.routing_gaps(got, want, tokens), *judged)},  # held or not
                                          "failed": [row["name"] for row in judged if not row["ok"]],
                                          "grad_norm": [got["grad_norm"], want["grad_norm"]],
                                          "first_grad_by_kind": mode.by_kind_of_leaf(want["first_grad_difference_norms"], want["first_grad_norms"]),
@@ -83,7 +87,7 @@ def main() -> None:
     parser = argparse.ArgumentParser(description=__doc__, formatter_class=argparse.RawDescriptionHelpFormatter)
     parser.add_argument("--workload", required=True)
     parser.add_argument("--seeds", required=True)
-    parser.add_argument("--variant", choices=("int8", "no_conv", "no_value_shift", "no_qk_mean", "no_eda", "full_rotary"), default="int8")
+    parser.add_argument("--variant", choices=("int8", "no_conv", "no_value_shift", "no_qk_mean", "no_eda", "full_rotary", "no_bias_move"), default="int8")
     args = parser.parse_args()
 
     from benchmark.device import require_tpu

@@ -32,6 +32,7 @@ class Event:
     name: str
     start: float  # seconds on the trace's clock
     end: float
+    thread: int = 0  # of a host span: which of the host plane's Python lines it lies on
 
     @property
     def seconds(self) -> float:
@@ -76,8 +77,8 @@ def find_xplane(trace_dir: Path) -> Path:
     return files[-1]
 
 
-def _events(line) -> list[Event]:
-    return [Event(str(e.name), e.start_ns * 1e-9, (e.start_ns + e.duration_ns) * 1e-9) for e in line.events]
+def _events(line, thread: int = 0) -> list[Event]:
+    return [Event(str(e.name), e.start_ns * 1e-9, (e.start_ns + e.duration_ns) * 1e-9, thread) for e in line.events]
 
 
 def load(path: Path) -> Trace:
@@ -98,9 +99,9 @@ def load(path: Path) -> Trace:
                 async_ops=_events(lines[ASYNC_LINE]) if ASYNC_LINE in lines else [],
             ))
         elif plane.name == HOST_PLANE:
-            for line in plane.lines:
+            for thread, line in enumerate(plane.lines):
                 if line.name.startswith(PYTHON_LINE):  # the program's spans: TraceAnnotations of its threads
-                    host_spans.extend(e for e in _events(line) if e.seconds > 0)
+                    host_spans.extend(e for e in _events(line, thread) if e.seconds > 0)
     devices.sort(key=lambda d: d.ordinal)
     return Trace(devices, host_spans)
 
@@ -187,17 +188,21 @@ def module_runs(trace: Trace, pattern: str) -> list[Event]:
 
 
 def _span_over(gap: tuple[float, float], spans: list[Event]) -> str:
-    """The host span that covers most of `gap`; of several that cover it alike, the
-    shortest, which is the innermost and says most about what the host did."""
-    best_key, best_name = None, "(no span)"
+    """The host span that covers most of `gap`, named as the program names it. Of the
+    spans of ONE thread that cover the gap alike, the longest: spans of a thread nest,
+    the program's spans enclose the annotations JAX opens inside them
+    (`metrics_fetch` round `np.asarray(jax.Array)`), and the program's name is the one
+    an operator knows. Between threads the shortest of those: the loop's
+    `metrics_fetch` of one step, not a background thread's span that lasts many."""
+    outermost: dict[int, tuple[float, float, str]] = {}  # by thread: (seconds of the gap covered, the span's own seconds, its name)
     for span in spans:
         overlap = min(gap[1], span.end) - max(gap[0], span.start)
-        if overlap <= 0:
-            continue
-        key = (round(overlap, 6), -span.seconds)
-        if best_key is None or key > best_key:
-            best_key, best_name = key, span.name
-    return best_name
+        if overlap > 0:
+            found = (round(overlap, 6), span.seconds, span.name)
+            outermost[span.thread] = max(found, outermost.get(span.thread, found))
+    if not outermost:
+        return "(no span)"
+    return max(outermost.values(), key=lambda found: (found[0], -found[1]))[2]
 
 
 def idle_gaps(trace: Trace, top: int = 10) -> list[tuple[str, float]]:

@@ -10,6 +10,7 @@ import yaml
 
 from benchmark.weights_cca_moe import CcaMoEShape
 from benchmark.weights_hybrid import resolved
+from tests.benchmark.accepted import ACCEPTED_CELLS, DRIVER_SECONDS, REAL_COST_S, full_check_seconds, holds_at_least, up_to
 
 REPO = Path(__file__).resolve().parents[2]
 CONFIG_DIR = REPO / "benchmark" / "configs" / "zaya1-8b-ep2"
@@ -100,12 +101,12 @@ def test_the_traffic_is_packed_4ks_corpus_letter_for_letter():
 def test_the_cell_joins_the_accepted_lists_and_brings_its_own_metrics():
     manifest = json.loads((REPO / "BENCHMARK.json").read_text())
     listed = {m["name"] for m in manifest["end_to_end"] + manifest["per_layer"] if CELL in m.get("workloads", ())}
-    assert listed == OWN | {"train_tokens_per_s", "train_host_stall_pct", "train_step_ms", "device_idle_pct.train", "fused_ce_roofline",
-                            "moe_load_max_over_mean", "moe_pairs_held_per_token"}
+    assert holds_at_least(listed, OWN | {"train_tokens_per_s", "train_host_stall_pct", "train_step_ms", "device_idle_pct.train", "fused_ce_roofline",
+                                         "moe_load_max_over_mean", "moe_pairs_held_per_token"})
     assert all("workloads" in m for m in manifest["per_layer"]), "every per-layer metric lists its cells"
     for name in OWN:
         entry = next(m for m in manifest["per_layer"] if m["name"] == name)
-        assert entry["workloads"] == [CELL] and entry["moves"] == "train_tokens_per_s"
+        assert holds_at_least(entry["workloads"], [CELL]) and entry["moves"] == "train_tokens_per_s", "a later cell may join a metric's list"
         spec = json.loads((REPO / "benchmark" / "metrics" / f"{name}.json").read_text())
         assert spec.get("rules", "train_cca_moe") == "train_cca_moe"
     rules = json.loads((REPO / "benchmark" / "scopes" / "train_cca_moe.json").read_text())
@@ -117,9 +118,8 @@ def test_the_cell_joins_the_accepted_lists_and_brings_its_own_metrics():
     # the mixer's parts are read apart, the router's three inside it before the rule that takes any `moe/router/`
     assert buckets.index("cca_conv") < buckets.index("cca") < buckets.index("attn") and buckets.index("moe_router_mlp") < buckets.index("moe_router")
     names = [w["name"] for w in manifest["workloads"]]
-    accepted = ["train-2p7b-4k", "train-jamba2-3b-4k", "train-kanana2-30b-8k", "train-ouro-2p6b-4k", "train-mellum2-12b-16k"]
-    assert names[:5] == accepted and names.index(CELL) == 5, "after the cells accepted before it, wherever later cells go"
-    cell = manifest["workloads"][5]
+    assert holds_at_least(names, up_to(ACCEPTED_CELLS, CELL)), "after the cells accepted before it, wherever later cells go"
+    cell = manifest["workloads"][names.index(CELL)]
     assert (cell["config"], cell["traffic"], cell["chips"]) == ("zaya1-8b-ep2", "packed-8k-cca-moe", 1) and len(cell["why"]) <= 200
     config = next(c for c in manifest["configs"] if c["name"] == "zaya1-8b-ep2")
     assert config["reduced"] == ["n_layer", "experts_held", "vocab_size"] and config["file"] == "benchmark/configs/zaya1-8b-ep2/train.yaml" and len(config["why"]) <= 200
@@ -156,14 +156,10 @@ def test_the_scope_rules_read_the_mixers_parts_and_the_routers(tmp_path):
 def test_a_full_check_at_this_cells_real_cost_fits_the_drivers_budget():
     """`test_manifest.py` does the driver's arithmetic with `run_seconds` + 60 = 100 s a run. This cell's runs take longer, as the
     expert, looped and window-and-global cells' do and for their reason (a float32 reference through two gradients at `highest`
-    precision): WARM and COLD below are my chip runs' (PR 40, PERF.md section 2). With the other long cells' beside them the six
-    cells' check stays inside the driver's time."""
+    precision): its warm and cold seconds are my chip runs' (PR 40, PERF.md section 2), in the tests' one table of real costs
+    (`accepted.REAL_COST_S`). With the other long cells' beside them the check of every cell the benchmark has
+    stays inside half of the driver's time."""
     manifest = json.loads((REPO / "BENCHMARK.json").read_text())
-    usual = manifest["run_seconds"] + 60
-    long_cells = {"train-kanana2-30b-8k": (143, 292), "train-ouro-2p6b-4k": (105, 220), "train-mellum2-12b-16k": (150, 330), CELL: (WARM_S, COLD_S)}
-    usual_cells = len(manifest["workloads"]) - len(long_cells)
-    check = 2 * usual + 14 * usual_cells * usual + 2 * 90 * usual_cells + sum(14 * warm + 2 * (cold - warm) for warm, cold in long_cells.values()) + 1200
-    assert check <= 43200 // 2
+    assert CELL in REAL_COST_S
+    assert full_check_seconds(manifest) <= DRIVER_SECONDS // 2
 
-
-WARM_S, COLD_S = 135, 270  # my chip runs, PR 40 (PERF.md section 2): 12 runs of one call, two of them cold, in 1,737 s

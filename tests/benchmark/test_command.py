@@ -13,6 +13,7 @@ import pytest
 from benchmark import run as bench_run
 from benchmark.device import device_info
 from benchmark.manifest import load_cell
+from tests.benchmark.accepted import holds_at_least
 from tests.benchmark.toy import REPO, make_toy_root
 
 CELL = "train-2p7b-4k"
@@ -40,6 +41,27 @@ def test_alone_with_its_own_files_the_command_fails_and_prints_no_result(tmp_pat
     shutil.copy(REPO / "BENCHMARK.json", tmp_path / "BENCHMARK.json")
     proc = command(tmp_path, {k: v for k, v in os.environ.items() if k != "PYTHONPATH"})
     assert proc.returncode != 0 and '"correct"' not in proc.stdout
+
+
+def test_a_metric_appended_for_every_cell_leaves_each_accepted_cells_rows_first_and_in_their_order(tmp_path):
+    """The guard of `accepted.holds_at_least`: what the next PR does to the manifest (one more per-layer entry, with
+    its metric file, listed in all cells) takes nothing from what any accepted cell reported, and moves none of it."""
+    root = make_toy_root(tmp_path)
+    manifest = json.loads((root / "BENCHMARK.json").read_text())
+    cells = [w["name"] for w in manifest["workloads"]]
+    accepted = {name: load_cell(name, root).per_layer for name in cells}
+    (root / "benchmark" / "readers" / "made_up_reader.py").write_text("def read(spec, observed, trace, env):\n    return 1.0\n")
+    (root / "benchmark" / "metrics" / "made_up_count.json").write_text(json.dumps({"reader": "made_up_reader"}))
+    manifest["per_layer"].append({"name": "made_up_count", "unit": "steps", "better": "higher", "source": "program_counter",
+                                  "layer": "trainer loop", "moves": "train_tokens_per_s", "workloads": cells})
+    (root / "BENCHMARK.json").write_text(json.dumps(manifest))
+    for name in cells:
+        cell = load_cell(name, root)
+        assert holds_at_least(cell.per_layer, accepted[name]) and holds_at_least(cell.per_layer, set(accepted[name]))
+        assert cell.per_layer[len(accepted[name]):] == ("made_up_count",) and cell.metric_spec("made_up_count") == {"reader": "made_up_reader"}
+    # what the helper refuses: a name missing, a name moved, a newcomer in front
+    assert not holds_at_least(["a", "c"], ["a", "b"]) and not holds_at_least(["b", "a", "c"], ["a", "b"]) and not holds_at_least(["x", "a", "b"], ["a", "b"])
+    assert not holds_at_least(["a", "c"], {"a", "b"}) and holds_at_least(["c", "b", "a"], {"a", "b"}) and holds_at_least(["a", "b", "c"], ["a", "b"])
 
 
 def test_a_cell_and_a_metric_added_as_files_only_are_found(tmp_path):

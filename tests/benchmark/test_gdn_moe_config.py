@@ -11,6 +11,7 @@ import yaml
 
 from benchmark.weights_gdn_moe import GdnMoEShape
 from benchmark.weights_hybrid import resolved
+from tests.benchmark.accepted import ACCEPTED_CELLS, DRIVER_SECONDS, REAL_COST_S, full_check_seconds, holds_at_least, up_to
 
 REPO = Path(__file__).resolve().parents[2]
 CONFIG = "qwen3-next-80b-a3b-d4"
@@ -33,7 +34,7 @@ OWN = {"train_gdn_fwd_ms", "train_gdn_bwd_ms", "train_gdn_optimizer_ms", "train_
        "train_gdn_layer_carry_ms", "train_gdn_unattributed_pct", "train_gdn_mfu_pct", "gdn_decay_mean", "flash_attention_gdn_roofline"}
 JOINED = {"train_tokens_per_s", "train_host_stall_pct", "train_step_ms", "device_idle_pct.train", "fused_ce_roofline", "moe_load_max_over_mean",
           "moe_pairs_held_per_token", "moe_aux_loss"}
-ACCEPTED = ["train-2p7b-4k", "train-jamba2-3b-4k", "train-kanana2-30b-8k", "train-ouro-2p6b-4k", "train-mellum2-12b-16k", "train-zaya1-8b-8k"]
+ACCEPTED = up_to(ACCEPTED_CELLS, CELL)[:-1]  # the cells accepted before this one
 
 
 def test_the_file_is_json_and_holds_the_sources_numbers_but_for_what_reduced_names():
@@ -54,6 +55,7 @@ def test_the_file_is_json_and_holds_the_sources_numbers_but_for_what_reduced_nam
             "packed_rows", "remat", "lm_head_chunk_size"} <= set(meta["assumed"])
     assert "12 such hosts" in meta["stands_for"] and "expert parallel 8" in meta["stands_for"] and meta["source"] == SOURCE
     assert "15.053 GiB" in meta["memory_analysis"] and "1,028,320,320" in meta["parameters"]
+    assert meta["memory_analysis"].startswith("AS THE STEP STANDS") and "twice a step" in meta["assumed"]["remat"], "what the program does now comes first"
 
 
 def test_the_model_block_reads_every_width_from_the_published_keys():
@@ -110,7 +112,7 @@ def test_the_traffic_is_packed_4ks_corpus_letter_for_letter():
 def test_the_cell_joins_the_accepted_lists_after_the_accepted_cells_and_brings_its_own_metrics():
     manifest = json.loads((REPO / "BENCHMARK.json").read_text())
     listed = {m["name"] for m in manifest["end_to_end"] + manifest["per_layer"] if CELL in m.get("workloads", ())}
-    assert listed == OWN | JOINED
+    assert holds_at_least(listed, OWN | JOINED)
     assert all("workloads" in m for m in manifest["per_layer"]), "every per-layer metric lists its cells"
     for name in JOINED:  # appended to a shared list: the cells it held before come first, in the order they had
         cells = next(m for m in manifest["end_to_end"] + manifest["per_layer"] if m["name"] == name)["workloads"]
@@ -118,7 +120,7 @@ def test_the_cell_joins_the_accepted_lists_after_the_accepted_cells_and_brings_i
     rules = json.loads((REPO / "benchmark" / "scopes" / "train_gdn_moe.json").read_text())
     for name in OWN:
         entry = next(m for m in manifest["per_layer"] if m["name"] == name)
-        assert entry["workloads"][0] == CELL and entry["moves"] == "train_tokens_per_s"
+        assert holds_at_least(entry["workloads"], [CELL]) and entry["moves"] == "train_tokens_per_s"
         spec = json.loads((REPO / "benchmark" / "metrics" / f"{name}.json").read_text())
         assert spec.get("rules", "train_gdn_moe") == "train_gdn_moe"
         if spec["reader"] == "scope_time" and "list" in spec:
@@ -128,7 +130,7 @@ def test_the_cell_joins_the_accepted_lists_after_the_accepted_cells_and_brings_i
     assert buckets.index("gdn_rule_intra") < buckets.index("gdn_rule_state") < buckets.index("gdn_rule") < buckets.index("gdn")
     assert buckets.index("attn_gate") < buckets.index("attn") and buckets.index("moe_shared_gate") < buckets.index("moe_shared") < buckets.index("moe")
     names = [w["name"] for w in manifest["workloads"]]
-    assert names[: len(ACCEPTED)] == ACCEPTED and names.index(CELL) == len(ACCEPTED), "after the cells accepted before it, wherever later cells go"
+    assert holds_at_least(names, [*ACCEPTED, CELL]), "after the cells accepted before it, wherever later cells go"
     cell = manifest["workloads"][names.index(CELL)]
     assert (cell["config"], cell["traffic"], cell["chips"]) == (CONFIG, "packed-16k-gdn-moe", 1) and len(cell["why"]) <= 200
     config = next(c for c in manifest["configs"] if c["name"] == CONFIG)
@@ -147,15 +149,10 @@ def test_the_cells_limits_say_where_each_came_from():
 def test_a_full_check_at_this_cells_real_cost_fits_the_drivers_budget():
     """`test_manifest.py` does the driver's arithmetic with `run_seconds` + 60 = 100 s a run. This cell's runs take longer, as the
     other long cells' do and for their reason (a float32 reference through two gradients at `highest` precision, here with the
-    rule as a recurrence over 16,384 positions): WARM and COLD below are my chip runs' (PR 44, PERF.md section 2). With the other
-    long cells' beside them the check of however many cells the manifest holds stays inside the driver's time."""
+    rule as a recurrence over 16,384 positions): its warm and cold seconds are my chip runs' (PR 44, PERF.md section 2), in the tests'
+    one table of real costs (`accepted.REAL_COST_S`). With the other long cells' beside them the check of every
+    cell the benchmark has stays inside half of the driver's time."""
     manifest = json.loads((REPO / "BENCHMARK.json").read_text())
-    usual = manifest["run_seconds"] + 60
-    long_cells = {"train-kanana2-30b-8k": (143, 292), "train-ouro-2p6b-4k": (105, 220), "train-mellum2-12b-16k": (150, 330),
-                  "train-zaya1-8b-8k": (135, 270), CELL: (WARM_S, COLD_S)}
-    usual_cells = len(manifest["workloads"]) - len(long_cells)
-    check = 2 * usual + 14 * usual_cells * usual + 2 * 90 * usual_cells + sum(14 * warm + 2 * (cold - warm) for warm, cold in long_cells.values()) + 1200
-    assert check <= 43200 // 2
+    assert CELL in REAL_COST_S
+    assert full_check_seconds(manifest) <= DRIVER_SECONDS // 2
 
-
-WARM_S, COLD_S = 131, 353  # my chip runs, PR 44 (PERF.md section 6): the final tree's seven runs in one call, the first of them cold

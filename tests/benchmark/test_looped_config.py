@@ -9,6 +9,7 @@ import yaml
 
 from benchmark.weights_hybrid import resolved
 from benchmark.weights_looped import LoopedShape
+from tests.benchmark.accepted import ACCEPTED_CELLS, ACCEPTED_CONFIGS, DRIVER_SECONDS, REAL_COST_S, full_check_seconds, holds_at_least, up_to
 
 REPO = Path(__file__).resolve().parents[2]
 CONFIG_DIR = REPO / "benchmark" / "configs" / "ouro-2p6b-t4"
@@ -67,11 +68,11 @@ def test_the_cell_joins_the_accepted_lists_and_brings_its_own_metrics():
     own = {"train_looped_fwd_ms", "train_looped_bwd_ms", "train_looped_optimizer_ms", "train_looped_attn_ms", "train_looped_mlp_ms",
            "train_looped_norms_ms", "train_looped_head_loss_ms", "train_looped_loop_carry_ms", "train_looped_unattributed_pct",
            "train_looped_mfu_pct", "loop_expected_exit"}
-    assert listed == own | {"train_tokens_per_s", "train_host_stall_pct", "train_step_ms", "device_idle_pct.train", "fused_ce_roofline",
-                            "flash_attention_roofline"}
+    assert holds_at_least(listed, own | {"train_tokens_per_s", "train_host_stall_pct", "train_step_ms", "device_idle_pct.train", "fused_ce_roofline",
+                                         "flash_attention_roofline"})
     for name in own:
         entry = next(m for m in manifest["per_layer"] if m["name"] == name)
-        assert entry["workloads"] == [cell] and entry["moves"] == "train_tokens_per_s"
+        assert holds_at_least(entry["workloads"], [cell]) and entry["moves"] == "train_tokens_per_s"
         spec = json.loads((REPO / "benchmark" / "metrics" / f"{name}.json").read_text())
         assert spec.get("rules", "train_looped") == "train_looped"
     rules = json.loads((REPO / "benchmark" / "scopes" / "train_looped.json").read_text())
@@ -79,20 +80,17 @@ def test_the_cell_joins_the_accepted_lists_and_brings_its_own_metrics():
         spec = json.loads((REPO / "benchmark" / "metrics" / f"{name}.json").read_text())
         if spec["reader"] == "scope_time" and "list" in spec:
             assert set(spec["buckets"]) <= {bucket for _, bucket in rules[spec["list"]]}, name
-    assert manifest["workloads"][-1]["name"] == cell and manifest["configs"][-1]["name"] == "ouro-2p6b-t4", "new entries at the end of their lists"
+    assert holds_at_least([w["name"] for w in manifest["workloads"]], up_to(ACCEPTED_CELLS, cell)), "after the cells accepted before it, wherever later cells go"
+    assert holds_at_least([c["name"] for c in manifest["configs"]], up_to(ACCEPTED_CONFIGS, "ouro-2p6b-t4"))
 
 
 def test_a_full_check_at_this_cells_real_cost_fits_the_drivers_budget():
     """`test_manifest.py` does the driver's arithmetic with `run_seconds` + 60 = 100 s a run. This cell's runs take
     longer, as the expert cell's do and for its reason (a float32 reference of 1.02 B parameters through two gradients
-    at `highest` precision): WARM and COLD below are my chip runs' (PR 32, PERF.md section 2). With the expert cell's
-    143 / 292 s beside them the four cells' check stays inside a third of the driver's time."""
+    at `highest` precision): its warm and cold seconds are my chip runs' (PR 32, PERF.md section 2), in the tests' one
+    table of real costs (`accepted.REAL_COST_S`). With the other long cells' beside them the check
+    of every cell the benchmark has stays inside half of the driver's time."""
     manifest = json.loads((REPO / "BENCHMARK.json").read_text())
-    usual = manifest["run_seconds"] + 60
-    long_cells = {"train-kanana2-30b-8k": (143, 292), "train-ouro-2p6b-4k": (WARM_S, COLD_S)}
-    usual_cells = len(manifest["workloads"]) - len(long_cells)
-    check = 2 * usual + 14 * usual_cells * usual + 2 * 90 * usual_cells + sum(14 * warm + 2 * (cold - warm) for warm, cold in long_cells.values()) + 1200
-    assert check <= 43200 // 3
+    assert "train-ouro-2p6b-4k" in REAL_COST_S
+    assert full_check_seconds(manifest) <= DRIVER_SECONDS // 2
 
-
-WARM_S, COLD_S = 105, 220  # my chip runs, PR 32: set-up 31-34 s + window 40 + reference 20 + start and teardown; cold: set-up 118 + reference 58 (a first run) or 24

@@ -17,6 +17,7 @@ import yaml
 from benchmark import run as bench_run
 from benchmark.device import device_info
 from benchmark.manifest import load_cell
+from tests.benchmark.accepted import holds_at_least
 from tests.benchmark.toy_gdn_moe import CELL, make_toy_gdn_moe_root
 
 SEED = 2**31 + 5  # the driver's seeds pass 32 signed bits
@@ -62,8 +63,8 @@ def test_sound_run_is_correct_and_reports_the_cells_end_to_end_metrics(root, cap
 def test_the_cell_reads_its_own_rules_file_and_its_own_shares_of_a_peak(root):
     cell = load_cell(CELL, root)
     assert cell.mode == "train_gdn_moe" and cell.chips == 1 and cell.end_to_end == ("train_tokens_per_s", "setup_s")
-    assert set(cell.per_layer) == OWN | {"train_host_stall_pct", "train_step_ms", "fused_ce_roofline", "device_idle_pct.train", "moe_load_max_over_mean",
-                                         "moe_pairs_held_per_token", "moe_aux_loss"}
+    assert holds_at_least(cell.per_layer, OWN | {"train_host_stall_pct", "train_step_ms", "fused_ce_roofline", "device_idle_pct.train", "moe_load_max_over_mean",
+                                                 "moe_pairs_held_per_token", "moe_aux_loss"})
     assert {cell.metric_spec(name)["rules"] for name in cell.per_layer if cell.metric_spec(name)["reader"] == "scope_time"} == {"train_gdn_moe"}
 
 

@@ -14,6 +14,7 @@ import pytest
 from benchmark import run as bench_run
 from benchmark.device import device_info
 from benchmark.manifest import load_cell
+from tests.benchmark.accepted import holds_at_least
 from tests.benchmark.toy_looped import CELL, make_toy_looped_root
 
 SEED = 2**31 + 5  # the driver's seeds pass 32 signed bits
@@ -64,10 +65,10 @@ def test_a_traced_run_reports_what_a_cpu_can_read_and_leaves_the_rest_out(root):
 def test_the_cell_reads_its_own_rules_file_and_the_accepted_shares_of_a_peak(root):
     cell = load_cell(CELL, root)
     assert cell.mode == "train_looped" and cell.chips == 1 and cell.end_to_end == ("train_tokens_per_s", "setup_s")
-    assert set(cell.per_layer) == {"train_host_stall_pct", "train_step_ms", "fused_ce_roofline", "flash_attention_roofline", "device_idle_pct.train",
-                                   "train_looped_fwd_ms", "train_looped_bwd_ms", "train_looped_optimizer_ms", "train_looped_attn_ms",
-                                   "train_looped_mlp_ms", "train_looped_norms_ms", "train_looped_head_loss_ms", "train_looped_loop_carry_ms",
-                                   "train_looped_unattributed_pct", "train_looped_mfu_pct", "loop_expected_exit"}
+    assert holds_at_least(cell.per_layer, {"train_host_stall_pct", "train_step_ms", "fused_ce_roofline", "flash_attention_roofline", "device_idle_pct.train",
+                                           "train_looped_fwd_ms", "train_looped_bwd_ms", "train_looped_optimizer_ms", "train_looped_attn_ms",
+                                           "train_looped_mlp_ms", "train_looped_norms_ms", "train_looped_head_loss_ms", "train_looped_loop_carry_ms",
+                                           "train_looped_unattributed_pct", "train_looped_mfu_pct", "loop_expected_exit"})
     assert {cell.metric_spec(name)["rules"] for name in cell.per_layer if cell.metric_spec(name)["reader"] == "scope_time"} == {"train_looped"}
 
 

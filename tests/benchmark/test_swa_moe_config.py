@@ -10,6 +10,7 @@ import yaml
 
 from benchmark.weights_hybrid import resolved
 from benchmark.weights_swa_moe import SwaMoEShape
+from tests.benchmark.accepted import ACCEPTED_CELLS, DRIVER_SECONDS, REAL_COST_S, full_check_seconds, holds_at_least, up_to
 
 REPO = Path(__file__).resolve().parents[2]
 CONFIG_DIR = REPO / "benchmark" / "configs" / "mellum2-12b-a2p5b-d12"
@@ -80,11 +81,11 @@ def test_the_cell_joins_the_accepted_lists_and_brings_its_own_metrics():
            "train_swa_moe_ms", "train_swa_moe_dispatch_ms", "train_swa_head_loss_ms", "train_swa_layer_carry_ms",
            "train_swa_unattributed_pct", "train_swa_mfu_pct", "moe_pairs_held_per_token", "moe_aux_loss",
            "flash_attention_window_roofline", "flash_attention_global_roofline"}
-    assert listed == own | {"train_tokens_per_s", "train_host_stall_pct", "train_step_ms", "device_idle_pct.train", "fused_ce_roofline",
-                            "moe_load_max_over_mean"}
+    assert holds_at_least(listed, own | {"train_tokens_per_s", "train_host_stall_pct", "train_step_ms", "device_idle_pct.train", "fused_ce_roofline",
+                                         "moe_load_max_over_mean"})
     for name in own:
         entry = next(m for m in manifest["per_layer"] if m["name"] == name)
-        assert entry["workloads"] == [CELL] and entry["moves"] == "train_tokens_per_s"
+        assert holds_at_least(entry["workloads"], [CELL]) and entry["moves"] == "train_tokens_per_s", "a later cell may join a metric's list"
         spec = json.loads((REPO / "benchmark" / "metrics" / f"{name}.json").read_text())
         assert spec.get("rules", "train_swa_moe") == "train_swa_moe"
     rules = json.loads((REPO / "benchmark" / "scopes" / "train_swa_moe.json").read_text())
@@ -96,8 +97,7 @@ def test_the_cell_joins_the_accepted_lists_and_brings_its_own_metrics():
     buckets = [bucket for _, bucket in rules["component"]]
     assert buckets.index("attn_window") < buckets.index("attn") and buckets.index("attn_global") < buckets.index("attn")
     names = [w["name"] for w in manifest["workloads"]]
-    accepted = ["train-2p7b-4k", "train-jamba2-3b-4k", "train-kanana2-30b-8k", "train-ouro-2p6b-4k"]
-    assert names[:4] == accepted and names.index(CELL) == 4, "new entries after the accepted ones, wherever later cells go"
+    assert holds_at_least(names, up_to(ACCEPTED_CELLS, CELL)), "new entries after the accepted ones, wherever later cells go"
 
 
 def test_the_two_rooflines_count_by_label_and_by_positions():
@@ -108,15 +108,9 @@ def test_the_two_rooflines_count_by_label_and_by_positions():
 
 def test_a_full_check_at_this_cells_real_cost_fits_the_drivers_budget():
     """`test_manifest.py` does the driver's arithmetic with `run_seconds` + 60 = 100 s a run. This cell's runs take longer, as the
-    expert and looped cells' do and for their reason (a float32 reference through two gradients at `highest` precision): WARM and COLD
-    below are my chip runs' (PR 38, PERF.md section 2). With the other two long cells' beside them the five cells' check stays inside
-    half of the driver's time."""
+    expert and looped cells' do and for their reason (a float32 reference through two gradients at `highest` precision): its warm and
+    cold seconds are my chip runs' (PR 38, PERF.md section 2), in the tests' one table of real costs (`accepted.REAL_COST_S`). With the other long cells' beside them the check of every cell the benchmark has stays inside half of the driver's time."""
     manifest = json.loads((REPO / "BENCHMARK.json").read_text())
-    usual = manifest["run_seconds"] + 60
-    long_cells = {"train-kanana2-30b-8k": (143, 292), "train-ouro-2p6b-4k": (105, 220), CELL: (WARM_S, COLD_S)}
-    usual_cells = len(manifest["workloads"]) - len(long_cells)
-    check = 2 * usual + 14 * usual_cells * usual + 2 * 90 * usual_cells + sum(14 * warm + 2 * (cold - warm) for warm, cold in long_cells.values()) + 1200
-    assert check <= 43200 // 2
+    assert CELL in REAL_COST_S
+    assert full_check_seconds(manifest) <= DRIVER_SECONDS // 2
 
-
-WARM_S, COLD_S = 150, 330  # my chip runs, PR 38 (PERF.md section 2)

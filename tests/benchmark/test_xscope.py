@@ -10,6 +10,7 @@ import pytest
 
 from benchmark import xscope, xtrace
 from benchmark.manifest import load_cell, load_manifest
+from tests.benchmark.accepted import holds_at_least
 from tests.benchmark.toy import REPO, make_toy_root
 
 CELL = "train-2p7b-4k"
@@ -162,7 +163,7 @@ def root(tmp_path_factory) -> Path:
 def test_each_metric_is_found_by_its_files_and_reads_the_hand_made_trace(name, root, trace, monkeypatch):
     cell = load_cell(CELL, root)
     entry = {m["name"]: m for m in load_manifest(root)["per_layer"]}[name]
-    assert name in cell.per_layer and entry["workloads"] == [CELL] and entry["moves"] == "train_tokens_per_s"
+    assert name in cell.per_layer and holds_at_least(entry["workloads"], [CELL]) and entry["moves"] == "train_tokens_per_s"
     assert (entry["source"], entry["better"], entry["unit"]) == ("device_trace", "lower", "%" if name.endswith("_pct") else "ms")
     spec = cell.metric_spec(name)
     reader = cell.module("readers", spec["reader"])
@@ -187,9 +188,9 @@ def test_the_gauge_reads_high_on_a_stale_table_and_nothing_where_the_profile_nam
 
 def test_the_cells_that_were_there_keep_their_seven_metrics_and_gain_the_eight():
     names = load_cell(CELL, REPO).per_layer
-    assert names[:7] == ("train_host_stall_pct", "train_step_ms", "train_mfu_pct", "train_mfu_ref_pct",
-                         "flash_attention_roofline", "fused_ce_roofline", "device_idle_pct.train")
-    assert set(names[7:]) == set(METRICS) and len(names) == 15
+    assert holds_at_least(names, ["train_host_stall_pct", "train_step_ms", "train_mfu_pct", "train_mfu_ref_pct",
+                                  "flash_attention_roofline", "fused_ce_roofline", "device_idle_pct.train"])
+    assert set(names[7:15]) == set(METRICS) and len(METRICS) == 8, "the eight follow the seven; what later PRs list follows them"
 
 
 def test_describe_scopes_prints_a_page_from_a_table_given_as_hlo_text(tmp_path, capsys, monkeypatch):

@@ -34,6 +34,7 @@ def test_recorded_trace_has_one_device_its_programs_and_the_programs_spans(recor
     runs = xtrace.module_runs(recorded, "train_step")
     assert len(runs) == 2 and runs[0].seconds == pytest.approx(0.34034, abs=1e-4), "one whole step of 340 ms"
     assert {"data_wait", "train_step", "metrics_fetch"} <= {e.name for e in recorded.host_spans}
+    assert xtrace.breakdown(recorded)["idle_gaps"][0][0] == "metrics_fetch", "named as the program names it, not `np.asarray(jax.Array)` inside it"
 
 
 def test_busy_union_own_time_and_idle_share_agree(recorded):
@@ -72,14 +73,16 @@ def test_instruction_names_are_cut_to_labels():
 
 
 def _xspace(planes: dict) -> bytes:
-    """An XSpace from {plane: {line: [(name, start_us, duration_us), ...]}}."""
+    """An XSpace from {plane: {line: [(name, start_us, duration_us), ...]}}; a plane's lines may be a list of
+    (line, events) pairs instead, since the host's Python threads all carry one name."""
     from jax.profiler import ProfileData
 
     text = []
     for plane_id, (plane, lines) in enumerate(planes.items()):
-        names = sorted({name for events in lines.values() for name, _, _ in events})
+        lines = list(lines.items()) if isinstance(lines, dict) else lines
+        names = sorted({name for _, events in lines for name, _, _ in events})
         body = []
-        for line_id, (line, events) in enumerate(lines.items()):
+        for line_id, (line, events) in enumerate(lines):
             rows = "".join(
                 f" events {{ metadata_id: {names.index(name) + 1} offset_ps: {int(start * 1e6)} duration_ps: {int(dur * 1e6)} }}"
                 for name, start, dur in events)
@@ -94,7 +97,10 @@ def by_hand(tmp_path_factory):
     """Two chips, 100 us. Chip 0: compute 0-40, an all-reduce from 30 that the core waits
     for from 40 to 60 (so 20 us of it are exposed), nothing from 60 to 80 while the host
     fetches metrics, compute 80-100. Chip 1: compute 0-50, the same all-reduce in flight
-    30-60 and waited for 50-60 (10 us exposed), compute 60-100."""
+    30-60 and waited for 50-60 (10 us exposed), compute 60-100. The host as the program's
+    loop leaves it: the dispatch (`train_step` with JAX's own `PjitFunction` inside it),
+    then `metrics_fetch` with JAX's `np.asarray(jax.Array)` inside it, and on a second
+    Python thread the feeder's `transfer`, open all the while, round its `shard_args`."""
     device = lambda compute, done, in_flight: {  # noqa: E731
         "XLA Ops": [("%while.1 = () while(", 0, 100)] + [(f"%fusion.{i} = f32[] fusion(", s, d) for i, (s, d) in enumerate(compute)]
         + [("%all-reduce-done.1 = f32[8] all-reduce-done(", *done)],
@@ -104,8 +110,10 @@ def by_hand(tmp_path_factory):
     space = _xspace({
         "/device:TPU:0": device([(0, 40), (80, 20)], (40, 20), (30, 30)),
         "/device:TPU:1": device([(0, 50), (60, 40)], (50, 10), (30, 30)),
-        "/host:CPU": {"python3": [("train_step", 0, 100), ("metrics_fetch", 58, 24), ("data_wait", 95, 2)],
-                      "tf_worker/7": [("tpu::System::Execute", 0, 100)]},
+        "/host:CPU": [("python3", [("train_step", 54, 3), ("PjitFunction(train_step)", 55, 1), ("metrics_fetch", 58, 24),
+                                   ("np.asarray(jax.Array)", 59, 22), ("data_wait", 95, 2)]),
+                      ("python3", [("transfer", 0, 100), ("shard_args", 61, 4)]),
+                      ("tf_worker/7", [("tpu::System::Execute", 0, 100)])],
     })
     path = tmp_path_factory.mktemp("trace") / "by_hand.xplane.pb"
     path.write_bytes(space)
@@ -128,9 +136,27 @@ def test_by_hand_gap_goes_to_the_host_span_open_in_it(by_hand):
                                                     by_hand.devices[0].modules)], by_hand.host_spans)
     assert xtrace.idle_share(without_loop) == pytest.approx(0.2)
     gaps = dict(xtrace.idle_gaps(without_loop))
-    # 60-80 us: `metrics_fetch` (58-82) covers all of it and is shorter than `train_step`, which does too
+    # 60-80 us: `metrics_fetch` (58-82) covers all of it, and so does JAX's `np.asarray(jax.Array)` (59-81) inside it: the
+    # program's span, which encloses JAX's, names the gap; the feeder's `transfer` covers it too, from another thread and
+    # for far longer than the loop's span does
     assert gaps == {"metrics_fetch": pytest.approx(20e-6)}
-    assert {e.name for e in by_hand.host_spans} == {"train_step", "metrics_fetch", "data_wait"}, "Python threads only"
+    assert {e.name for e in by_hand.host_spans} == {"train_step", "PjitFunction(train_step)", "metrics_fetch", "np.asarray(jax.Array)",
+                                                    "data_wait", "transfer", "shard_args"}, "Python threads only"
+    assert len({e.thread for e in by_hand.host_spans}) == 2, "a span keeps the thread it lies on"
+
+
+def test_a_gap_is_named_by_the_programs_span_and_by_jaxs_only_where_no_span_of_the_program_covers_it():
+    span = lambda name, start, end, thread=0: xtrace.Event(name, start, end, thread)  # noqa: E731
+    loop = [span("train_step", 0.0, 3.0), span("PjitFunction(train_step)", 0.5, 2.5), span("metrics_fetch", 4.0, 30.0),
+            span("np.asarray(jax.Array)", 4.5, 29.5), span("np.asarray(jax.Array)", 40.0, 41.0)]
+    feeder = [span("transfer", 0.0, 100.0, 1), span("DevicePutWithSharding", 10.0, 12.0, 1)]
+    name = lambda a, b, spans=loop + feeder: xtrace._span_over((a, b), spans)  # noqa: E731
+    assert name(1.0, 2.0) == "train_step" and name(5.0, 25.0) == "metrics_fetch"
+    assert name(10.5, 11.5) == "metrics_fetch", "the feeder's `transfer` encloses its `DevicePutWithSharding` and outlasts the loop's span: the loop's names it"
+    assert name(40.2, 40.8) == "np.asarray(jax.Array)", "no span of the program covers it: JAX's own names it"
+    assert name(3.2, 3.8) == "transfer" and name(3.2, 3.8, loop) == "(no span)"
+    # a span that covers more of the gap wins over one that encloses it on paper and covers less
+    assert name(29.0, 31.0, loop) == "metrics_fetch" and name(2.0, 4.4, loop) == "train_step"
 
 
 def test_by_hand_exposed_collective(by_hand):
