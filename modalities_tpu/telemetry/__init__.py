@@ -1,9 +1,10 @@
 """Telemetry subsystem: the process's own timeline, goodput ledger, hang watchdog, sink.
 
 The process keeps one record whoever is listening: `spans.PROCESS_LOG` (every finished
-span, from the package's import on) and `compile_log.PROCESS_COMPILES` (every backend
-compile, from this package's import on), both bounded and both on
-`time.perf_counter()`. One `Telemetry` object per run composes the rest:
+span, from the package's import on), `compile_log.PROCESS_COMPILES` (every backend
+compile, from this package's import on) and `collective_plan.PROCESS_PLANS` (the
+collectives of each sharded program the trainer's preflight compiled), all bounded and
+all on `time.perf_counter()`. One `Telemetry` object per run composes the rest:
 
 - `spans.SpanRecorder` — host phases as spans doubling as profiler annotations
 - `goodput.GoodputLedger` — every wall second classified into a bucket
@@ -23,6 +24,7 @@ telemetry calls.
 
 from __future__ import annotations
 
+import json
 import os
 import statistics
 import tempfile
@@ -30,7 +32,7 @@ import time
 from pathlib import Path
 from typing import Callable, Optional, Union
 
-from modalities_tpu.telemetry import compile_log
+from modalities_tpu.telemetry import collective_plan, compile_log
 from modalities_tpu.telemetry.goodput import BUCKETS, GoodputLedger
 from modalities_tpu.telemetry.metrics import MetricsRegistry
 from modalities_tpu.telemetry.sink import TelemetrySink
@@ -194,7 +196,7 @@ class Telemetry:
         """`emit_event` for a fact that code finds while it is traced (a kernel's tile
         plan for a shape): on this instance's sink once per distinct payload, however
         often the shape is traced, and never per step."""
-        key = (name, tuple(sorted(payload.items())))
+        key = (name, json.dumps(payload, sort_keys=True, default=str))  # a payload may nest (a plan's rows)
         if self._sink is None or key in self._emitted_once:
             return
         self._emitted_once.add(key)
@@ -278,6 +280,20 @@ class Telemetry:
             self._sink.emit({"event": "compile", "function": function, "seconds": round(seconds, 6),
                              "cache_hit": cache_hit, "step": self._in_flight,
                              "end_s": round(time.perf_counter() - PROCESS_LOG.origin, 6)})
+
+    def _on_collective_plan(self, plan: dict) -> None:
+        """The collectives of one compiled program, as the process's record forwards them to the active
+        instance (`collective_plan.record_from_compiled`): a step's bytes and count by mesh axis and kind
+        on the scrape surface, and one `collective_plan` event on the sink a distinct plan."""
+        bytes_gauge = self.metrics.gauge(
+            "train_collective_bytes", "Bytes a step of the compiled train step's collectives, by mesh axis and kind")
+        count_gauge = self.metrics.gauge(
+            "train_collective_count", "Collectives a step of the compiled train step, by mesh axis and kind")
+        for key, total in plan["totals"].items():
+            axis, kind = key.split("|")
+            bytes_gauge.set(total["bytes_a_run"], axis=axis, kind=kind)
+            count_gauge.set(total["count_a_run"], axis=axis, kind=kind)
+        self.emit_event_once("collective_plan", collective_plan.event_payload(plan))
 
     # ---------------------------------------------------------------- goodput
 
@@ -516,6 +532,7 @@ def set_active_telemetry(telemetry: Optional[Telemetry]) -> Telemetry:
         if previous.enabled:
             PROCESS_LOG.release()
         compile_log.forward_to(_active._on_compile if _active.enabled else None)
+        collective_plan.forward_to(_active._on_collective_plan if _active.enabled else None)
         _active._claim_process_log()
     return previous
 

@@ -61,6 +61,7 @@ _NAME_TO_BUCKET = {
     "state_init": "init",
     "checkpoint_restore": "init",
     "preflight_memscope": "compile_first_step",
+    "collective_plan": "compile_first_step",  # inside preflight_memscope, on a mesh of several devices
     "first_step": "compile_first_step",
     "train_step": "train_step",
     "metrics_fetch": "train_step",

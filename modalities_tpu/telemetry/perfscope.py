@@ -207,19 +207,53 @@ def _parse_replica_groups(line: str) -> Optional[list[list[int]]]:
     return None
 
 
-def _collective_axis(line: str, mesh_axis_sizes: Optional[dict[str, int]]) -> str:
-    """Name the mesh axis a collective runs over.
+_SOURCE_TARGET_PAIRS_RE = re.compile(r"source_target_pairs=\{(\{[^}]*\}(?:,\s*\{[^}]*\})*)\}")
+_CHANNEL_ID_RE = re.compile(r"channel_id=(\d+)")
 
-    Multi-slice geometry first: when the mesh has a ``dcn`` axis, the replica
-    groups are expanded to explicit partition-id sets and any group spanning
-    >= 2 dcn coordinates lands in the slow-fabric ``dcn`` bucket — regardless
-    of its size, because a size coincidence with an ICI axis must never hide a
-    cross-slice hop (`mesh_axis_sizes` must preserve mesh axis order; partition
-    ids unravel row-major over it, dcn outermost in the canonical order).
-    Intra-slice groups then match ICI axis sizes as before; unmatched sizes
-    keep a `size<g>` tag so the bucket is still stable and greppable."""
+
+def _axes_of_groups(groups: list[list[int]], sizes: dict[str, int]) -> Optional[tuple[list[str], bool]]:
+    """Where replica groups lie in the mesh: (the axes along which a group's members
+    differ, in the mesh's order; whether every group is the whole extent of exactly
+    those axes). A partition id unravels row-major over the axis sizes in the mesh's own
+    axis order, which is the compiled program's device assignment (`mesh.devices.flat`).
+    None where the groups name a partition the mesh does not have: no geometry is known."""
+    names = list(sizes)
+    dims = [sizes[name] for name in names]
+    members = [d for g in groups for d in g]
+    if not members or not dims or max(members) >= math.prod(dims) or min(members) < 0:
+        return None
+    strides = [math.prod(dims[i + 1:]) for i in range(len(dims))]
+    differing: set[int] = set()
+    for group in groups:
+        for i, (size, stride) in enumerate(zip(dims, strides)):
+            if len({(d // stride) % size for d in group}) > 1:
+                differing.add(i)
+    extent = math.prod(dims[i] for i in differing)
+    whole = all(len(set(group)) == extent for group in groups)
+    return [names[i] for i in sorted(differing)], whole
+
+
+def _collective_axis(line: str, mesh_axis_sizes: Optional[dict[str, int]]) -> str:
+    """Name the mesh axis, or axes, a collective runs over, from where its replica groups
+    lie in the mesh (`_axes_of_groups`; `mesh_axis_sizes` must preserve the mesh's axis
+    order): `{{0,1},{2,3}}` on `dp_shard 2 x tp 2` is `tp`, `{{0,2},{1,3}}` is
+    `dp_shard`, `{{0,1,2,3}}` is `dp_shard+tp`, and the iota forms are expanded to the
+    same sets first. A collective-permute is read off its source-target pairs the same
+    way. Two sizes that coincide can so never trade places, which matching by size did
+    on every 2 x 2 mesh.
+
+    The slow fabric is a case of the rule: a group whose members differ along `dcn`
+    lands in the `dcn` bucket whatever else it spans and whatever its size, because a
+    cross-slice hop must never hide in an ICI bucket. Matching by size stays as the
+    fall-back where no geometry is known (no mesh, groups that name partitions the mesh
+    lacks, or groups that are not the whole extent of the axes they differ along), and
+    an unmatched size keeps a `size<g>` tag so the bucket is still stable and greppable."""
     sizes = {k: int(v) for k, v in (mesh_axis_sizes or {}).items()}
     groups = _parse_replica_groups(line)
+    pairs = None  # of a collective-permute, which has no groups
+    m = None if groups else _SOURCE_TARGET_PAIRS_RE.search(line)
+    if m:
+        pairs = [[int(x) for x in pair.split(",") if x.strip()] for pair in re.findall(r"\{([^}]*)\}", m.group(1))]
     if groups:
         group_size = len(groups[0])
     else:
@@ -231,22 +265,18 @@ def _collective_axis(line: str, mesh_axis_sizes: Optional[dict[str, int]]) -> st
             m = _REPLICA_GROUPS_LIT_RE.search(line)
             if m:  # literal format {{0,1},{2,3}}: size of the first group
                 group_size = len([t for t in m.group(1).split(",") if t.strip()])
-    if group_size is None or group_size <= 1:
+    if pairs is None and (group_size is None or group_size <= 1):
         return "all"
-    dcn_size = sizes.get("dcn", 1)
-    geometry_known = bool(groups) and dcn_size > 1
+    found = _axes_of_groups(groups or pairs, sizes) if sizes else None
+    geometry_known = found is not None
     if geometry_known:
-        names = list(sizes)
-        dcn_stride = 1
-        for name in names[names.index("dcn") + 1 :]:
-            dcn_stride *= sizes[name]
-        crossing = any(
-            len({(d // dcn_stride) % dcn_size for d in g}) > 1
-            for g in groups
-            if len(g) > 1
-        )
-        if crossing:
+        axes, whole = found
+        if "dcn" in axes:
             return "dcn"
+        if axes and (whole or pairs is not None):
+            return "+".join(axes)
+    if group_size is None:
+        return "all"
     for axis, size in sorted(sizes.items()):
         if axis == "dcn" and geometry_known:
             continue  # geometry already proved these groups stay intra-slice
@@ -361,6 +391,76 @@ def scope_table(hlo_text: str) -> dict[str, str]:
     return table
 
 
+class _Wrapper(NamedTuple):
+    name: str  # the wrapping instruction: what a device trace prints for the collective inside
+    fused: bool  # a fusion (the chip's compiler), not an `async-start` round a called computation
+    done: bool  # the fusion that completes a collective cut into several (it holds the `AsyncCollectiveDone` custom call)
+    op_name: Optional[str]
+
+
+def _collective_wrappers(instructions: list[_Instruction]) -> dict[str, _Wrapper]:
+    """{computation: the instruction that wraps it}, for the computations a collective can sit in without being an
+    operation of its own: a fused computation (a TPU's optimized module fuses a reduce-scatter into
+    `fusion(...), calls=%all-reduce-scatter.N`, and cuts an asynchronous all-gather into the fusions
+    `async-collective-start.N`, compute fusions that carry its steps, and `async-collective-done.N`, the last known by
+    the `AsyncCollectiveDone` custom call it holds) and the computation an
+    `async-start` calls (the generic asynchronous form of a reduce-scatter or an all-to-all)."""
+    holds_a_collective = {row.computation for row in instructions if row.opcode in _COLLECTIVE_OPS}
+    completes_one = {row.computation for row in instructions
+                     if row.opcode == "custom-call" and 'custom_call_target="AsyncCollectiveDone"' in row.line}
+    return {called: _Wrapper(row.name, row.opcode == "fusion", called in completes_one, _op_name(row.line))
+            for row in instructions if row.opcode in ("fusion", "async-start")
+            for called in _CALLS_RE.findall(row.line) if called in holds_a_collective}
+
+
+_WHILE_RE = re.compile(r"condition=%?([\w.\-]+),\s*body=%?([\w.\-]+)")
+_INT_CONSTANT_RE = re.compile(r"\bconstant\((\d+)\)")
+_CALLED_RE = re.compile(r"(?:calls|to_apply|true_computation|false_computation)=%?([\w.\-]+)|branch_computations=\{([^}]*)\}")
+
+
+def _times_a_run(instructions: list[_Instruction]) -> dict[str, int]:
+    """{computation: how often its instructions run in one execution of the module}: 1 for the entry computation,
+    times the trip count of every loop round it. A loop's trip count is read off its condition where that is the
+    form a scan lowers to (one integer constant, compared `LT` with a counter that starts at 0: the layer scan's
+    `constant(32)`); a loop of any other form counts once, so a number of times is a floor, and a trace has the
+    count that ran (`benchmark/readers/collectives.py` prints both)."""
+    by_computation: dict[str, list[_Instruction]] = {}
+    for row in instructions:
+        by_computation.setdefault(row.computation, []).append(row)
+
+    def trip_count(condition: str) -> int:
+        rows = by_computation.get(condition, [])
+        constants = [int(c) for row in rows if row.opcode == "constant" for c in _INT_CONSTANT_RE.findall(row.rhs)]
+        bounded = any(row.opcode == "compare" and "direction=LT" in row.line for row in rows)
+        return constants[0] if bounded and len(constants) == 1 and constants[0] > 0 else 1
+
+    called_from: dict[str, tuple[str, int]] = {}  # computation -> (the computation that calls it, times a call)
+    for row in instructions:
+        loop = _WHILE_RE.search(row.line) if row.opcode == "while" else None
+        if loop:
+            called_from.setdefault(loop.group(2), (row.computation, trip_count(loop.group(1))))
+            continue
+        for single, several in _CALLED_RE.findall(row.line):
+            for called in ([single] if single else [c.strip().lstrip("%") for c in several.split(",")]):
+                called_from.setdefault(called, (row.computation, 1))
+
+    times: dict[str, int] = {}
+
+    def of(computation: str, depth: int = 0) -> int:
+        if computation not in times:
+            caller = called_from.get(computation)
+            times[computation] = 1 if caller is None or depth > 64 else caller[1] * of(caller[0], depth + 1)
+        return times[computation]
+
+    return {computation: of(computation) for computation in by_computation}
+
+
+def _first_operand(rhs: str, opcode_pos: int) -> Optional[str]:
+    """The name of an instruction's first operand: what a `-done` completes."""
+    found = re.search(r"\(\s*(?:[a-z][a-z0-9]*\[[^\]]*\](?:\{[^}]*\})?\s+)?%?([\w.\-]+)", rhs[opcode_pos:])
+    return found.group(1) if found else None
+
+
 def analyze_hlo_text(
     hlo_text: str,
     mesh_axis_sizes: Optional[dict[str, int]] = None,
@@ -383,9 +483,14 @@ def analyze_hlo_text(
     m = re.search(r"HloModule\s+([\w.\-]+)", hlo_text)
     if m:
         module_name = m.group(1)
+    instructions = list(_instructions(hlo_text))
+    wrappers = _collective_wrappers(instructions)
+    times_a_run = _times_a_run(instructions)
 
     buckets: dict[str, dict] = {}
     by_scope: dict[str, dict] = {}
+    collectives: list[dict] = []  # one row a collective, under the name a device trace prints for it
+    row_of: dict[str, dict] = {}  # by the name of a row's instruction, and by the channel of a fused one
 
     def _bucket(name: str) -> dict:
         b = buckets.get(name)
@@ -393,7 +498,7 @@ def analyze_hlo_text(
             b = buckets[name] = {"ops": 0, "flops": 0, "bytes": 0, "est_time_s": 0.0, "top_ops": []}
         return b
 
-    for current_comp, instr_name, opcode, opcode_pos, rhs, raw_line, _ in _instructions(hlo_text):
+    for current_comp, instr_name, opcode, opcode_pos, rhs, raw_line, _ in instructions:
         if opcode in _SKIP_OPS:
             continue
         in_fusion = current_comp in fused_comps
@@ -401,14 +506,39 @@ def analyze_hlo_text(
         flops, nbytes = _instruction_cost(opcode, raw_line, rhs, opcode_pos)
         if opcode == "fusion":
             flops = 0  # inner ops carry the flops
-        elif in_fusion:
-            nbytes = 0  # the fusion instruction carries the traffic
+        elif in_fusion and opcode not in _COLLECTIVE_OPS:
+            nbytes = 0  # the fusion instruction carries the traffic (a collective's bytes go over the links, not through HBM)
 
         if opcode in _COLLECTIVE_DONE_OPS:
+            started = row_of.get(_first_operand(rhs, opcode_pos))
+            if started is not None:
+                started["done"] = instr_name  # the pair is one row: a trace shows both names
             continue  # cost carried by the matching *-start
         if opcode in _COLLECTIVE_OPS:
-            bucket_name = f"collective:{_collective_axis(raw_line, mesh_axis_sizes)}"
+            wrapper = wrappers.get(current_comp)  # a fusion or an async-start round this computation: what the trace names
+            channel = _CHANNEL_ID_RE.search(raw_line)
+            phase_of = row_of.get(f"channel {channel.group(1)}") if wrapper is not None and wrapper.fused and channel else None
+            if phase_of is not None:
+                # the chip's compiler cuts one collective into fusions (a start, steps fused into compute, a done),
+                # each with its own copy of the instruction on the collective's channel: one row, counted once
+                if wrapper.done:
+                    phase_of["done"] = wrapper.name
+                else:
+                    phase_of["steps"].append(wrapper.name)
+                continue
+            axis = _collective_axis(raw_line, mesh_axis_sizes)
+            bucket_name = f"collective:{axis}"
             est = nbytes / hw.collective_bw + hw.collective_latency_s
+            kind = opcode[: -len("-start")] if opcode.endswith("-start") else opcode
+            if wrapper is not None and "reduce-scatter" in current_comp and kind == "all-reduce":
+                kind = "reduce-scatter"  # the chip's form of one: an all-reduce and the slice of it, in one fusion
+            row = {"name": wrapper.name if wrapper is not None else instr_name, "done": None, "steps": [], "kind": kind,
+                   "axis": axis, "bytes": nbytes, "times": times_a_run.get(current_comp, 1),
+                   "scope": scope_path(_op_name(raw_line) or (wrapper.op_name if wrapper is not None else None))}
+            collectives.append(row)
+            row_of[row["name"]] = row
+            if wrapper is not None and wrapper.fused and channel:
+                row_of[f"channel {channel.group(1)}"] = row
         elif opcode in _HOST_OPS:
             bucket_name = "host_transfer"
             est = nbytes / hw.hbm_bw
@@ -471,6 +601,7 @@ def analyze_hlo_text(
         "hw": hw.as_dict(),
         "buckets": {k: buckets[k] for k in sorted(buckets)},
         "by_scope": {k: {**v, "est_time_s": round(v["est_time_s"], 12)} for k, v in sorted(by_scope.items())},
+        "collectives": collectives,
         "total": total,
     }
 

@@ -139,14 +139,14 @@ class Trainer:
     def _preflight_memscope(
         step_functions: StepFunctions, device_batch, telemetry: Optional[Telemetry] = None
     ) -> Optional[dict]:
-        """Static memscope report + fits-check before the first dispatch. Only
-        runs where it can act: a backend with a bytes_limit (TPU) and a check
-        mode other than off — on CPU this is a no-op, so e2e tests pay nothing.
-        A FitsCheckFailure propagates (fail-fast is the point); any other
-        failure degrades to 'no static report', never a dead run. Where it runs it
-        is the span `preflight_memscope`: `memscope_report` compiles the step ahead
-        of time (`lower_train_step(...).compile()`), before the first dispatch
-        compiles or loads the `jit` path's executable inside `first_step`."""
+        """Static memscope report + fits-check before the first dispatch. Only runs where it can act: a backend with a
+        bytes_limit (TPU) and a check mode other than off — on CPU this is a no-op, so e2e tests pay nothing. A
+        FitsCheckFailure propagates (fail-fast is the point); any other failure degrades to 'no static report', never a
+        dead run. Where it runs it is the span `preflight_memscope`: `memscope_report` compiles the step ahead of time
+        (`lower_train_step(...).compile()`), before the first dispatch compiles or loads the `jit` path's executable
+        inside `first_step`; on a mesh of several devices the same compiled step's collectives are then recorded, in
+        the child span `collective_plan` (`_with_collective_plan`, below). This function keeps its line count: the
+        frames of `train` and of the lowering it calls are part of every step's compile-cache key."""
         from modalities_tpu.telemetry.memscope import FITS_CHECK_ENV
 
         mode = (os.environ.get(FITS_CHECK_ENV) or "fail").strip().lower()
@@ -176,7 +176,7 @@ class Trainer:
                 logger.exception("memscope: static report failed; fits-check skipped")
                 return None
             preflight_fits_check(report)
-            return report
+            return _with_collective_plan(report, step_functions, telemetry)
 
     def train(
         self,
@@ -694,3 +694,23 @@ class Trainer:
         the tightest remaining on-device allocation margin. None when the backend
         does not report a bytes_limit (CPU), so the key is simply absent there."""
         return hbm_headroom_mb()
+
+
+def _with_collective_plan(report: dict, step_functions, telemetry: Telemetry) -> dict:
+    """`report`, after the collectives of the compiled step `memscope_report` handed on have been recorded
+    (`telemetry/collective_plan.py`: the process's record, the sink's event, the gauges), inside the span
+    `collective_plan` so that a timeline shows what the walk over the optimized HLO costs. Only where the mesh has
+    more than one device: a program on one device holds no collective, `memscope_report` hands nothing on there, and
+    no span opens. A failure costs the plan, never the run. At the end of the file: see `_preflight_memscope`."""
+    compiled = getattr(step_functions, "preflight_compiled", None)
+    if compiled is None:
+        return report
+    step_functions.preflight_compiled = None  # the executable was the preflight's own: nothing else holds it
+    with telemetry.span("collective_plan"):
+        try:
+            from modalities_tpu.telemetry.collective_plan import record_from_compiled
+
+            record_from_compiled(compiled, {k: int(v) for k, v in step_functions.mesh_handle.mesh.shape.items()})
+        except Exception:
+            logger.exception("collective plan: the walk over the compiled step failed; no plan recorded")
+    return report

@@ -163,10 +163,7 @@ class StepFunctions:
                 "memscope_report needs the AOT lowering surface; this StepFunctions "
                 "was built without lower_train_step"
             )
-        from modalities_tpu.telemetry.memscope import (
-            memscope_from_compiled,
-            train_step_known_bytes,
-        )
+        from modalities_tpu.telemetry.memscope import memscope_from_compiled, train_step_known_bytes
 
         known = train_step_known_bytes(self.app_state_handle, self.mesh_handle)
         degrees = getattr(self.mesh_handle, "degrees", None) or {}
@@ -180,18 +177,21 @@ class StepFunctions:
                 "remat_variant", None,
             ),
         }
-        return memscope_from_compiled(
-            self.lower_train_step(batch_abstract).compile(), known, context
+        # The lowering below stays on the line it had (184): a traced frame's line is part of the compile cache's key.
+        # On a mesh of several devices the compiled step is handed on, for its collectives (`Trainer._preflight_memscope`
+        # reads them off it and drops it: `telemetry/collective_plan.py`); a program on one device holds none.
+        compiled = (
+            self.lower_train_step(batch_abstract).compile()
         )
+        self.preflight_compiled = compiled if self.mesh_handle is not None and self.mesh_handle.mesh.size > 1 else None
+        return memscope_from_compiled(compiled, known, context)
 
 
 class TrainStepBuilder:
     """Assembles model + loss + optimizer + schedule + mesh into jitted step functions.
 
-    This is where the registry's model-transform descriptors (sharding, remat, mixed
-    precision) are applied — the JAX counterpart of the reference's in-place wrapper
-    chain fsdp2_wrapped -> activation_checkpointed -> compiled (model_factory.py).
-    """
+    This is where the registry's model-transform descriptors (sharding, remat, mixed precision) are applied — the JAX
+    counterpart of the reference's in-place wrapper chain fsdp2_wrapped -> activation_checkpointed -> compiled (model_factory.py)."""
 
     def __init__(
         self,

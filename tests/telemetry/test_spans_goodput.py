@@ -85,7 +85,8 @@ def test_span_survives_exception_and_still_records():
 
 EXPECTED_BUCKET = {
     "backend_start": "init", "build_components": "init", "init": "init", "state_init": "init",
-    "checkpoint_restore": "init", "preflight_memscope": "compile_first_step", "first_step": "compile_first_step",
+    "checkpoint_restore": "init", "preflight_memscope": "compile_first_step", "collective_plan": "compile_first_step",
+    "first_step": "compile_first_step",
     "train_step": "train_step", "metrics_fetch": "train_step",  # device wait = goodput
     "data_wait": "data_stall", "eval": "eval", "checkpoint_save": "checkpoint", "checkpoint_drain": "checkpoint",
     "publish": "publish", "preempt": "recovery", "ckpt_retry": "recovery", "serve": "serve", "tune": "other",
