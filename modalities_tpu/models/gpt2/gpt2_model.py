@@ -782,7 +782,8 @@ class QuantDenseGeneral(nn.Module):
         return y
 
 
-def _dense_general(spec, features, name, kernel_axes, dtype):
+def _dense_general(spec, features, name, kernel_axes, dtype, dot_general=None):
+    """`dot_general`: the product where it is not `jax.lax.dot_general` (a sequence-parallel region's: `_seq_region`)."""
     bias_axes = kernel_axes[1:] if isinstance(features, tuple) else (kernel_axes[-1],)
     if getattr(spec, "quant_weights", "none") != "none":
         return QuantDenseGeneral(
@@ -802,7 +803,32 @@ def _dense_general(spec, features, name, kernel_axes, dtype):
         bias_init=nn.with_logical_partitioning(nn.initializers.zeros, bias_axes),
         dtype=dtype,
         param_dtype=jnp.dtype(spec.param_dtype),
+        dot_general=dot_general,
     )
+
+
+def _seq_region(spec, x, *split_over_tp):
+    """The sequence-parallel region (`parallel/sharding.seq_region`) for the products that take `x` [B, S, E] in or
+    give it out, or None, which leaves them `jax.lax.dot_general` and their collectives the partitioner's: always for
+    quantized weights, whose product is a kernel of its own."""
+    from modalities_tpu.parallel.sharding import seq_region
+
+    return seq_region(x.shape[0], x.shape[1], *split_over_tp) if spec.quant_weights == "none" else None
+
+
+def _column_products(module, region, x, products):
+    """`products(x, dot_general)`, a mixer's or an MLP's column-parallel products of the block's normed rows `x`,
+    behind the region's opening edge: the rows are gathered over tp once for all of them, and the gather and the
+    products are one checkpoint, so that what the backward keeps is the split rows and it gathers them again (the
+    products themselves keep nothing else and are not run twice). With no region: `products(x, None)`."""
+    if region is None:
+        return products(x, None)
+    from modalities_tpu.parallel.sharding import column_product, gather_seq
+
+    def seq_gathered(_, rows):  # the name the scope takes: `attn.seq_gathered/q_attn/...`
+        return jax.tree.map(lambda out: out[0], products(gather_seq(region, rows), column_product(region)))
+
+    return nn.remat(seq_gathered, prevent_cse=True)(module, x)
 
 
 class CausalSelfAttention(nn.Module):
@@ -825,13 +851,17 @@ class CausalSelfAttention(nn.Module):
         spec = self.spec
         head_dim = spec.head_dim
         window = spec.sliding_window if self.kind == "swa" else None
-        q = _dense_general(spec, (spec.n_head_q, 2 * head_dim if spec.attn_output_gate else head_dim), "q_attn",
-                           ("embed", "heads", "head_dim"), x.dtype)(x)
+
+        def products(rows, dot):
+            return (_dense_general(spec, (spec.n_head_q, 2 * head_dim if spec.attn_output_gate else head_dim), "q_attn",
+                                   ("embed", "heads", "head_dim"), x.dtype, dot)(rows),
+                    _dense_general(spec, (spec.n_head_kv, head_dim), "k_attn", ("embed", "kv_heads", "head_dim"), x.dtype, dot)(rows),
+                    _dense_general(spec, (spec.n_head_kv, head_dim), "v_attn", ("embed", "kv_heads", "head_dim"), x.dtype, dot)(rows))
+
+        q, k, v = _column_products(self, _seq_region(spec, x, spec.n_head_q, spec.n_head_kv), x, products)
         gate = None
         if spec.attn_output_gate:  # a head's `2 * head_dim` are its query, then its gate
             q, gate = q[..., :head_dim], q[..., head_dim:]
-        k = _dense_general(spec, (spec.n_head_kv, head_dim), "k_attn", ("embed", "kv_heads", "head_dim"), x.dtype)(x)
-        v = _dense_general(spec, (spec.n_head_kv, head_dim), "v_attn", ("embed", "kv_heads", "head_dim"), x.dtype)(x)
 
         if spec.use_qk_norm and spec.qk_norm is not None:
             q = build_norm(spec.qk_norm, "q_norm", dtype=x.dtype)(q)
@@ -1119,6 +1149,9 @@ class CausalSelfAttention(nn.Module):
                 name="c_proj",
             )(y)
         else:
+            from modalities_tpu.parallel.sharding import scatter_seq
+
+            region = _seq_region(spec, x, spec.n_head_q)
             out = nn.DenseGeneral(
                 features=spec.n_embd,
                 axis=(-2, -1),
@@ -1130,6 +1163,7 @@ class CausalSelfAttention(nn.Module):
                 bias_init=nn.with_logical_partitioning(nn.initializers.zeros, ("embed",)),
                 dtype=x.dtype,
                 param_dtype=jnp.dtype(spec.param_dtype),
+                dot_general=None if region is None else scatter_seq(region),
             )(y)
         return nn.Dropout(rate=spec.dropout)(out, deterministic=self.deterministic or spec.dropout == 0.0)
 
@@ -1142,18 +1176,23 @@ class MLP(nn.Module):
 
     @nn.compact
     def __call__(self, x):
+        from modalities_tpu.parallel.sharding import scatter_seq
+
         spec = self.spec
-        if spec.activation == ActivationType.GELU.value:
-            h = _dense_general(spec, spec.ffn_hidden, "c_fc", ("embed", "mlp"), x.dtype)(x)
+        gelu = spec.activation == ActivationType.GELU.value
+        hidden = spec.ffn_hidden if gelu else spec.swiglu_hidden
+        region = _seq_region(spec, x, hidden)
+        scatter = None if region is None else scatter_seq(region)
+        if gelu:
+            h = _column_products(self, region, x, lambda rows, dot: _dense_general(spec, hidden, "c_fc", ("embed", "mlp"), x.dtype, dot)(rows))
             h = with_logical_constraint(h, ("batch", "seq", "mlp"), spec)
-            out = _dense_general(spec, spec.n_embd, "c_proj", ("mlp", "embed"), x.dtype)(nn.gelu(h))
+            out = _dense_general(spec, spec.n_embd, "c_proj", ("mlp", "embed"), x.dtype, scatter)(nn.gelu(h))
         else:  # swiglu / fused_swiglu
-            hidden = spec.swiglu_hidden
-            w = _dense_general(spec, hidden, "W", ("embed", "mlp"), x.dtype)(x)
-            v = _dense_general(spec, hidden, "V", ("embed", "mlp"), x.dtype)(x)
+            w, v = _column_products(self, region, x, lambda rows, dot: (_dense_general(spec, hidden, "W", ("embed", "mlp"), x.dtype, dot)(rows),
+                                                                        _dense_general(spec, hidden, "V", ("embed", "mlp"), x.dtype, dot)(rows)))
             h = nn.silu(w) * v
             h = with_logical_constraint(h, ("batch", "seq", "mlp"), spec)
-            out = _dense_general(spec, spec.n_embd, "W_2", ("mlp", "embed"), x.dtype)(h)
+            out = _dense_general(spec, spec.n_embd, "W_2", ("mlp", "embed"), x.dtype, scatter)(h)
         return nn.Dropout(rate=spec.dropout)(out, deterministic=self.deterministic or spec.dropout == 0.0)
 
 
@@ -1190,7 +1229,7 @@ class GPT2Block(nn.Module):
         stack this is, for the one thing a scanned block cannot know otherwise (`scale_residual_merge`: the first layer's
         first merge)."""
         spec = self.spec
-        x = with_logical_constraint(x, ("batch", "seq", "embed"), spec)
+        x = with_logical_constraint(x, ("batch", "seq_sp", "embed"), spec)
         h = build_norm(spec.attn_norm, "attention_norm", dtype=x.dtype)(x)
         key_temperature = mixer_counted = None
         if self.mixer == "cca":
@@ -1533,7 +1572,7 @@ def _walks_in_place(spec: "GPT2ModelSpec", deterministic: bool, carry_dtype):
 
     def close(shared, u):
         h = build_norm(spec.lm_head_norm, "lm_head_norm").apply({"params": shared["lm_head_norm"]}, u)
-        h = with_logical_constraint(h, ("batch", "seq", "embed"))
+        h = with_logical_constraint(h, ("batch", "seq_sp", "embed"))
         gate = _exit_gate().apply({"params": shared[scopes.EXIT_GATE]}, h.astype(jnp.float32))[..., 0] if gated else None
         return h.astype(carry_dtype), (h, gate)
 
@@ -1657,7 +1696,7 @@ class GPT2Module(nn.Module):
             with jax.named_scope(scopes.LAYER_CARRY):
                 u, _ = scanned(carry, None)
             h = build_norm(spec.lm_head_norm, "lm_head_norm")(u)
-            h = with_logical_constraint(h, ("batch", "seq", "embed"))
+            h = with_logical_constraint(h, ("batch", "seq_sp", "embed"))
             gate = _exit_gate()(h.astype(jnp.float32))[..., 0] if loop.exit_gate else None
             return h.astype(carry_dtype), (h, gate)
 
@@ -1707,6 +1746,9 @@ class GPT2Module(nn.Module):
         with jax.named_scope(scopes.WTE):
             wte_lookup = with_logical_constraint(wte, ("vocab", "embed_lookup"), explicit=True)
             x = embedding_lookup(wte_lookup, input_ids).astype(compute_dtype)
+            # the lookup's own output, whole over tp: the partial sums of a table whose vocabulary tp splits are summed
+            # once; the stream's split below is then a slice a chip (asked for here, a reduce-scatter comes with
+            # collective-permutes of the whole output round it, forward and backward: PERF.md, PR 51)
             x = with_logical_constraint(x, ("batch", "seq", "embed"))
         if spec.poe_type == PositionTypes.ABSOLUTE.value:
             wpe = self.param(
@@ -1737,7 +1779,7 @@ class GPT2Module(nn.Module):
             else:
                 x = x + wpe[None, : input_ids.shape[1], :].astype(compute_dtype)
         x = nn.Dropout(rate=spec.dropout)(x, deterministic=self.deterministic or spec.dropout == 0.0)
-        x = with_logical_constraint(x, ("batch", "seq", "embed"))
+        x = with_logical_constraint(x, ("batch", "seq_sp", "embed"))
 
         if self.decode or self.slot_spec is not None:
             refuse_serving(spec)
@@ -1869,7 +1911,7 @@ class GPT2Module(nn.Module):
 
         if spec.loop is None:  # a looped model's final norm closes every walk (`_walks`)
             x = build_norm(spec.lm_head_norm, "lm_head_norm")(x)
-            x = with_logical_constraint(x, ("batch", "seq", "embed"))
+            x = with_logical_constraint(x, ("batch", "seq_sp", "embed"))
         if self.output_hidden:
             return x
         if spec.use_weight_tying:
@@ -2087,7 +2129,7 @@ class GPT2LLM(NNModel):
         call = {"o_bytes": b * h * s * width_v * itemsize, "lse_bytes": lse_bytes,
                 "backward_bytes": b * s * itemsize * (3 * h * width + 3 * h * width_v + h_kv * (width + width_v)) + 2 * lse_bytes}
         held = {"blocks": spec.n_layer, "calls": [{"kind": kind, "layers": spec.kinds.count(kind), **call} for kind in ("attn", "swa", "cca") if kind in spec.kinds],
-                "block_input_bytes": math.prod(shard_shape((rows, seq, spec.n_embd), ("batch", "seq", "embed"))) * itemsize}
+                "block_input_bytes": math.prod(shard_shape((rows, seq, spec.n_embd), ("batch", "seq_sp", "embed"))) * itemsize}
         if "gdn" in spec.kinds:  # the rule's o over the row padded to whole groups of chunks, and a float32 state a group and a value head
             from modalities_tpu.ops import gated_delta_rule as rule
 

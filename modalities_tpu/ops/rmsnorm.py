@@ -25,8 +25,8 @@ def resolve_rmsnorm_block_rows(n_embd: int, dtype) -> int:
 
 
 # how the rows of a norm's input lie on the mesh, by rank: the residual stream
-# [B, S, E] (split over the sequence too, as sequence parallelism does) and the
-# per-head q/k of QK-norm [B, S, H, D]; anything else is split over its batch only
+# [B, S, E], its rows "seq_sp" (over cp then tp: as the model leaves them between a block's products), and the
+# per-head q/k of QK-norm [B, S, H, D], inside a mixer: rows over cp alone, heads over tp; anything else over its batch only
 _ROW_AXES = {3: ("batch", "seq_sp", None), 4: ("batch", "seq", "heads", None)}
 
 

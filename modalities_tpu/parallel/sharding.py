@@ -14,14 +14,14 @@ Default rule set (reference parity):
 - TP: q/k/v + W/V/c_fc colwise => "heads"/"kv_heads"/"mlp" on tp; c_proj/W_2 rowwise
   (input sharded) — same effective layout as the reference plan; embedding/lm_head on
   "vocab" over tp (vocab-parallel lookup + XLA-inserted psum).
-- SP: activations sharded on "seq" over tp between blocks (norm inputs), matching
-  SequenceParallel in the reference plan; batch is sharded over (dp_replicate,
-  dp_shard) and "seq" additionally over cp for context parallelism.
+- SP: the residual stream's rows are "seq_sp", split over (cp, tp) between a block's products, as
+  SequenceParallel in the reference plan (`gather_seq` / `scatter_seq`, at the end of this file);
+  batch is sharded over (dp_replicate, dp_shard), and "seq", the rows inside a product, over cp alone.
 """
 
 from __future__ import annotations
 
-from typing import Optional
+from typing import NamedTuple, Optional
 
 import jax
 import numpy as np
@@ -32,7 +32,7 @@ from modalities_tpu.running_env.device_mesh import DeviceMeshHandle
 LogicalRules = tuple[tuple[str, Optional[str | tuple[str, ...]]], ...]
 
 
-def default_logical_axis_rules(mesh_handle: DeviceMeshHandle, sequence_parallel: bool = True) -> LogicalRules:
+def default_logical_axis_rules(mesh_handle: DeviceMeshHandle) -> LogicalRules:
     axis_names = mesh_handle.axis_names
     has = lambda n: n in axis_names and mesh_handle.degrees.get(n, 1) > 1  # noqa: E731
 
@@ -49,8 +49,8 @@ def default_logical_axis_rules(mesh_handle: DeviceMeshHandle, sequence_parallel:
 
     rules: list[tuple[str, Optional[str | tuple[str, ...]]]] = [
         ("batch", batch_axes if batch_axes else None),
-        # sequence dim of activations: context parallelism shards it over cp; with TP
-        # sequence-parallel regions use "seq_sp"
+        # "seq": the rows INSIDE a mixer or an MLP, whole over tp (its heads / hidden split there), over cp alone;
+        # "seq_sp": the rows of the residual stream and of the norms BETWEEN the products, over cp then tp
         ("seq", cp),
         ("seq_sp", tuple(a for a in (cp, tp) if a) or None),
         # parameters: FSDP over dp_shard on the "embed" dim, TP on head/mlp/vocab dims
@@ -375,3 +375,114 @@ def installed_axis_size(name: str) -> int:
     """The size of mesh axis `name` in the mesh the step installed with `activation_rules`; 1 with none installed or no such axis."""
     state = getattr(_ACTIVATION_RULES, "state", None)
     return int(state[1].shape.get(name, 1)) if state else 1
+
+
+# ------------------------------------------------------------ sequence-parallel regions
+# Megatron's sequence parallelism (arXiv 2205.05198; the reference's SequenceParallel plan): between a block's
+# products the residual stream's rows are split over tp ("seq_sp"), and a region of tensor parallelism (a mixer's or
+# an MLP's products, the heads or the hidden split over tp) has two edges. `gather_seq` before its column-parallel
+# products: an all-gather of the rows over tp, whose cotangent is reduce-scattered. `scatter_seq` for its row-parallel
+# product: the product a shard and a reduce-scatter of the partial sums, whose cotangent is all-gathered. Asked for
+# with a sharding constraint alone the partitioner splits the stream and still writes an all-reduce and a slice, and
+# the chip's compiler takes a `psum_scatter` over the sequence dimension apart into the same two; scattered over a
+# LEADING dimension it stays one `reduce-scatter` instruction (PERF.md, PR 51), so both edges move the tp shards'
+# blocks of rows through a leading axis. The regions are manual over the whole mesh, as `per_shard`'s are (a bfloat16
+# sum inside a partly manual one trips a check of XLA's on the CPU: parallel/pipeline.py): a weight comes in whole
+# on its embed dim, gathered over dp_shard by the partitioner at the region's edge as it was before the product, and
+# its gradient leaves summed over the batch's axes.
+
+
+class SeqRegion(NamedTuple):
+    mesh: Mesh
+    batch: Optional[tuple[str, ...]]  # what "batch" splits the rows' first dim over
+    axes: tuple[str, ...]  # what "seq_sp" splits the rows over: cp where the mesh has one, then tp
+
+    @property
+    def cp(self) -> Optional[tuple[str, ...]]:
+        return tuple(a for a in self.axes if a != "tp") or None
+
+
+def seq_region(batch: int, seq_len: int, *split_over_tp: int) -> Optional[SeqRegion]:
+    """The region the step's mesh allows for the products of `batch` rows of `seq_len` whose heads or hidden dims are
+    `split_over_tp`, or None: no rules installed, a mesh whose tp is 1, an enclosing manual region (pp, cp), a mesh
+    axis that neither "batch" nor "seq_sp" names (dcn), rows that their axes do not divide or a dim that tp does not.
+    With None a caller's products are `jax.lax.dot_general` as they stand and the collectives the partitioner's."""
+    from modalities_tpu.parallel.jax_compat import manual_axes
+
+    state = getattr(_ACTIVATION_RULES, "state", None)
+    if not state or manual_axes():
+        return None
+    rules, mesh = state
+    table = dict(rules)
+    over = lambda axes: int(np.prod([mesh.shape[a] for a in axes], dtype=np.int64))  # noqa: E731
+    names = lambda entry: () if entry is None else entry if isinstance(entry, tuple) else (entry,)  # noqa: E731
+    rows, axes = names(table.get("batch")), names(table.get("seq_sp"))
+    if "tp" not in axes or any(size > 1 and name not in rows + axes for name, size in mesh.shape.items()):
+        return None
+    if batch % over(rows) or seq_len % over(axes) or any(dim % mesh.shape["tp"] for dim in split_over_tp):
+        return None
+    return SeqRegion(mesh, rows or None, axes)
+
+
+def _manual(region: SeqRegion, fn, in_specs, out_specs):
+    from modalities_tpu.parallel.jax_compat import shard_map
+
+    return shard_map(fn, mesh=region.mesh, in_specs=in_specs, out_specs=out_specs, axis_names=frozenset(region.mesh.axis_names))
+
+
+def gather_seq(region: SeqRegion, x):
+    """`x` [B, S, E], rows split over "seq_sp" -> [tp, B, S, E], the leading axis split over tp: every tp shard's own
+    copy of the rows (of its cp shard's), for `column_product`. A copy a shard and not one replicated array, so that
+    the cotangent is a partial sum a shard, which this function's transpose reduce-scatters: once for all the
+    products that read the copy. Wrap the gather and its products in one `jax.checkpoint` and the backward gathers
+    again from the split rows where it would keep the copy."""
+    tp = region.mesh.shape["tp"]
+
+    def gather(rows):  # [b, s, E] -> [1, b, tp * s, E]
+        b, s, e = rows.shape
+        return jax.lax.all_gather(rows, "tp", axis=0, tiled=False).transpose(1, 0, 2, 3).reshape(1, b, tp * s, e)
+
+    return _manual(region, gather, P(region.batch, region.axes, None), P("tp", region.batch, region.cp, None))(x)
+
+
+def column_product(region: SeqRegion):
+    """A `dot_general` (what `flax.linen.DenseGeneral(dot_general=...)` takes) for a column-parallel product of
+    `gather_seq`'s copies [tp, B, S, E] with a kernel [E, features...] whose first feature dim is split over tp:
+    [1, B, S, features...], each shard's features from its own copy, so the copies' axis comes out as one (what a bias
+    broadcasts against; the caller drops it)."""
+
+    def dot_general(lhs, rhs, dimension_numbers, precision=None, preferred_element_type=None):
+        if dimension_numbers != (((lhs.ndim - 1,), (0,)), ((), ())):
+            raise ValueError(f"a column-parallel product contracts the copies' last dim with the kernel's first, not {dimension_numbers}")
+
+        def product(copy, kernel):
+            return jax.lax.dot_general(copy, kernel, dimension_numbers, precision=precision, preferred_element_type=preferred_element_type)
+
+        in_specs = (P("tp", region.batch, region.cp, None), P(None, "tp"))
+        return _manual(region, product, in_specs, P(None, region.batch, region.cp, "tp"))(lhs, rhs)
+
+    return dot_general
+
+
+def scatter_seq(region: SeqRegion):
+    """A `dot_general` for a row-parallel product and the region's closing edge: `lhs` [B, S, features...] contracted
+    over its features, the first of them split over tp, with a kernel [features..., E]; the shards' partial sums are
+    reduce-scattered over tp, so the result [B, S, E] comes out with its rows split over "seq_sp" (a bias is added
+    after, by the caller, as it is after the partitioner's sum)."""
+    tp = region.mesh.shape["tp"]
+
+    def dot_general(lhs, rhs, dimension_numbers, precision=None, preferred_element_type=None):
+        contracted = tuple(range(2, lhs.ndim))
+        if dimension_numbers != ((contracted, tuple(range(len(contracted)))), ((), ())):
+            raise ValueError(f"a row-parallel product contracts all of [B, S, features...]'s features with the kernel's first dims, not {dimension_numbers}")
+
+        def product(rows, kernel):
+            partial = jax.lax.dot_general(rows, kernel, dimension_numbers, precision=precision, preferred_element_type=preferred_element_type)
+            b, s, e = partial.shape
+            blocks = partial.reshape(b, tp, s // tp, e).transpose(1, 0, 2, 3)  # a tp shard's block of rows along a leading axis
+            return jax.lax.psum_scatter(blocks, "tp", scatter_dimension=0, tiled=False)
+
+        in_specs = (P(region.batch, region.cp, "tp"), P("tp"))
+        return _manual(region, product, in_specs, P(region.batch, region.axes, None))(lhs, rhs)
+
+    return dot_general

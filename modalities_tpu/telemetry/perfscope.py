@@ -209,6 +209,7 @@ def _parse_replica_groups(line: str) -> Optional[list[list[int]]]:
 
 _SOURCE_TARGET_PAIRS_RE = re.compile(r"source_target_pairs=\{(\{[^}]*\}(?:,\s*\{[^}]*\})*)\}")
 _CHANNEL_ID_RE = re.compile(r"channel_id=(\d+)")
+_CHAIN_ID_RE = re.compile(r"chain_id=\"(\d+)\"")
 
 
 def _axes_of_groups(groups: list[list[int]], sizes: dict[str, int]) -> Optional[tuple[list[str], bool]]:
@@ -393,6 +394,7 @@ def scope_table(hlo_text: str) -> dict[str, str]:
 
 class _Wrapper(NamedTuple):
     name: str  # the wrapping instruction: what a device trace prints for the collective inside
+    computation: Optional[str]  # where the wrapping instruction sits: the pieces of one cut collective sit in one
     fused: bool  # a fusion (the chip's compiler), not an `async-start` round a called computation
     done: bool  # the fusion that completes a collective cut into several (it holds the `AsyncCollectiveDone` custom call)
     op_name: Optional[str]
@@ -408,7 +410,7 @@ def _collective_wrappers(instructions: list[_Instruction]) -> dict[str, _Wrapper
     holds_a_collective = {row.computation for row in instructions if row.opcode in _COLLECTIVE_OPS}
     completes_one = {row.computation for row in instructions
                      if row.opcode == "custom-call" and 'custom_call_target="AsyncCollectiveDone"' in row.line}
-    return {called: _Wrapper(row.name, row.opcode == "fusion", called in completes_one, _op_name(row.line))
+    return {called: _Wrapper(row.name, row.computation, row.opcode == "fusion", called in completes_one, _op_name(row.line))
             for row in instructions if row.opcode in ("fusion", "async-start")
             for called in _CALLS_RE.findall(row.line) if called in holds_a_collective}
 
@@ -517,7 +519,12 @@ def analyze_hlo_text(
         if opcode in _COLLECTIVE_OPS:
             wrapper = wrappers.get(current_comp)  # a fusion or an async-start round this computation: what the trace names
             channel = _CHANNEL_ID_RE.search(raw_line)
-            phase_of = row_of.get(f"channel {channel.group(1)}") if wrapper is not None and wrapper.fused and channel else None
+            # the pieces of one cut collective share its channel; a `shard_map`'s collectives all come with channel 1, so
+            # the compiler's own number for the cut (`chain_id`, counted a computation) tells two of those apart
+            chain = _CHAIN_ID_RE.search(raw_line)
+            cut = (f"channel {channel.group(1)}" + (f" chain {chain.group(1)} in {wrapper.computation}" if chain else "")
+                   if wrapper is not None and wrapper.fused and channel else None)
+            phase_of = row_of.get(cut)
             if phase_of is not None:
                 # the chip's compiler cuts one collective into fusions (a start, steps fused into compute, a done),
                 # each with its own copy of the instruction on the collective's channel: one row, counted once
@@ -527,9 +534,15 @@ def analyze_hlo_text(
                     phase_of["steps"].append(wrapper.name)
                 continue
             axis = _collective_axis(raw_line, mesh_axis_sizes)
+            kind = opcode[: -len("-start")] if opcode.endswith("-start") else opcode
+            if kind == "reduce-scatter":
+                # counted by its operand, what a chip puts in, as a fused one is (the all-reduce inside the chip's
+                # `all-reduce-scatter` fusion has the operand's shape): the output times the group. The line's other
+                # shapes are not operands (the emitter's notes print `original_shape: ...`).
+                groups = _parse_replica_groups(raw_line)
+                nbytes = sum(b for pos, _, b in _line_shapes(rhs) if pos < opcode_pos) * (len(groups[0]) if groups else 1)
             bucket_name = f"collective:{axis}"
             est = nbytes / hw.collective_bw + hw.collective_latency_s
-            kind = opcode[: -len("-start")] if opcode.endswith("-start") else opcode
             if wrapper is not None and "reduce-scatter" in current_comp and kind == "all-reduce":
                 kind = "reduce-scatter"  # the chip's form of one: an all-reduce and the slice of it, in one fusion
             row = {"name": wrapper.name if wrapper is not None else instr_name, "done": None, "steps": [], "kind": kind,
@@ -537,8 +550,8 @@ def analyze_hlo_text(
                    "scope": scope_path(_op_name(raw_line) or (wrapper.op_name if wrapper is not None else None))}
             collectives.append(row)
             row_of[row["name"]] = row
-            if wrapper is not None and wrapper.fused and channel:
-                row_of[f"channel {channel.group(1)}"] = row
+            if cut is not None:
+                row_of[cut] = row
         elif opcode in _HOST_OPS:
             bucket_name = "host_transfer"
             est = nbytes / hw.hbm_bw

@@ -203,7 +203,6 @@ class TrainStepBuilder:
         gradient_acc_steps: int = 1,
         grad_clip_norm: Optional[float] = None,
         grad_clipper=None,
-        sequence_parallel: bool = True,
         expose_grads: bool = False,
         anomaly_policy: Optional[str] = None,
         stop_consensus: bool = False,
@@ -238,7 +237,7 @@ class TrainStepBuilder:
             raise ValueError(f"zero_stage must be 0 or 1, got {resolved_zero}")
         self.zero_stage = resolved_zero
         self.rules = (
-            default_logical_axis_rules(mesh_handle, sequence_parallel) if mesh_handle is not None else ()
+            default_logical_axis_rules(mesh_handle) if mesh_handle is not None else ()
         )
 
     # ------------------------------------------------------------------ build

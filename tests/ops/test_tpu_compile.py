@@ -430,20 +430,29 @@ def test_the_looped_steps_backward_adds_a_layers_gradient_into_one_stack(v5e, mo
     assert by_walk - in_place >= stack, (by_walk, in_place, stack)
 
 
-def _compiled_cell_step(v5e, monkeypatch, tmp_path, config: str, vocab_size: int, sequence_length: int):
+def _compiled_cell_step(v5e, monkeypatch, tmp_path, config: str, vocab_size: int, sequence_length: int, chips: int = 1, layers=None):
     """The whole donated train step of `benchmark/configs/<config>/train.yaml` through the recipe's own components, compiled for
-    the described v5e: its text, and the compiler's peak (arguments + outputs + temporaries - aliases)."""
+    the described v5e (`chips` of its four, `layers` deep where not the YAML's own depth): its text, and the compiler's peak
+    (arguments + outputs + temporaries - aliases)."""
+    import yaml
+
     from benchmark.traffic import packed_documents
     from modalities_tpu.ops.pallas import autotune
     from modalities_tpu.utils.recipe_validation import build_lowered_train_step
 
-    monkeypatch.setattr(jax, "devices", lambda *args, **kwargs: list(v5e[:1]))
+    monkeypatch.setattr(jax, "devices", lambda *args, **kwargs: list(v5e[:chips]))
     autotune.clear_cache()
     monkeypatch.chdir(tmp_path)  # the YAML's paths are relative; the corpus only has to exist and hold a step's rows
     mix = {"sequences": 8, "size_seed": 1, "doc_len_median": 600, "doc_len_sigma": 1.0, "doc_len_min": 32, "doc_len_max": 8192}
     packed_documents.generate(mix, 1, tmp_path / "data" / "train.pbin", vocab_size=vocab_size, sequence_length=sequence_length)
     repo = os.path.dirname(os.path.dirname(os.path.dirname(os.path.abspath(__file__))))
-    executable = compiled_for_the_chip(build_lowered_train_step(os.path.join(repo, "benchmark", "configs", config, "train.yaml")).lowered)
+    path = os.path.join(repo, "benchmark", "configs", config, "train.yaml")
+    if layers is not None:
+        raw = yaml.safe_load(open(path).read())
+        raw["model_raw"]["config"]["n_layer"] = layers
+        path = tmp_path / "train.yaml"
+        path.write_text(yaml.safe_dump(raw, sort_keys=False))
+    executable = compiled_for_the_chip(build_lowered_train_step(path).lowered)
     memory = executable.memory_analysis()
     return executable.as_text(), memory.argument_size_in_bytes + memory.output_size_in_bytes + memory.temp_size_in_bytes - memory.alias_size_in_bytes
 
@@ -459,3 +468,25 @@ def test_the_compressed_convolutional_attention_cells_step_compiles_for_v5e(v5e,
     for scope in ("cca/conv", "cca/qk_norm", "cca/value_shift", "moe/router/router/eda", "moe/router/router/mlp", "residual/attn_merge"):
         assert f"/{scope}/" in text, scope
     assert 12.0 * 2**30 < peak < 14.7 * 2**30, peak / 2**30  # meta.json, memory_analysis: 12.76 GiB at 10 layers
+
+
+def test_the_mesh_cells_step_reduce_scatters_its_row_parallel_products_over_tp(v5e, monkeypatch, tmp_path):
+    """The four-chip cell's recipe (`benchmark/configs/modalities-2p7b-x4/train.yaml`: the 2.7B widths over `dp_shard 2 x tp 2`) at
+    depth 2, compiled for the described `v5e:2x2`, read as the program's own record reads it (`collective_plan.plan_from_hlo_text`).
+    What only the chip's compiler says of a sequence-parallel region's edges (PR 51: a sharding constraint alone splits the stream
+    and leaves the partitioner's all-reduce and slice; a `psum_scatter` over the sequence dimension is taken apart into the same
+    two; over a leading dimension it stays): inside the layer scans no all-reduce over tp, four reduce-scatters a layer (the row
+    products' forward, the gathers' backward) and six all-gathers (the two gathers forward, again in the backward, and the row
+    products' cotangents); the gradients still leave over dp_shard as reduce-scatters; no more memory than the partitioner's form."""
+    from modalities_tpu.telemetry.collective_plan import plan_from_hlo_text
+
+    layers, rows_seq_embd = 2, 1 * 4096 * 2560 * 2  # a dp_shard group's one row of 4,096 positions, bfloat16
+    text, peak = _compiled_cell_step(v5e, monkeypatch, tmp_path, "modalities-2p7b-x4", 50304, 4096, chips=4, layers=layers)
+    plan = plan_from_hlo_text(text, {"dp_shard": 2, "tp": 2})
+    in_a_block = [row for row in plan["rows"] if "blocks/block/" in row["scope"] and row["bytes"] == rows_seq_embd]
+    by_kind = {kind: sum(row["times"] for row in in_a_block if row["axis"] == "tp" and row["kind"] == kind)
+               for kind in ("all-reduce", "reduce-scatter", "all-gather")}
+    assert by_kind == {"all-reduce": 0, "reduce-scatter": 4 * layers, "all-gather": 6 * layers}, (by_kind, plan["totals"])
+    assert {row["name"].split(".")[0] for row in in_a_block if row["kind"] == "reduce-scatter"} == {"reduce_scatter"}  # an instruction of its own
+    assert plan["totals"]["dp_shard|reduce-scatter"]["count_a_run"] == 6 * layers + 1  # a weight's gradient; q and k share one, and the table's
+    assert peak <= 2_225_738_752, peak  # the parent's peak at this depth (`scripts/collective_plan_for.py`, PR 51)

@@ -162,6 +162,99 @@ def test_a_wrapped_collective_is_one_row_under_the_name_the_trace_prints():
     assert plan["bytes_a_run"] == sum(r["bytes"] * r["times"] for r in plan["rows"])
 
 
+# what a `shard_map`'s collectives look like in a chip's module (PR 51): every one on channel 1, two of them cut into a start and
+# a done fusion in ONE loop body and told apart by the compiler's `chain_id`, and a bare `reduce-scatter` whose emitter's notes
+# print the operand's shape a second time
+SHARD_MAP_FORMS = """
+HloModule jit_train_step, entry_computation_layout={(bf16[1,1,8,16])->bf16[1,1,8,16]}
+
+%add (x: bf16[], y: bf16[]) -> bf16[] {
+  %x = bf16[] parameter(0)
+  %y = bf16[] parameter(1)
+  ROOT %sum = bf16[] add(%x, %y)
+}
+
+%start_mlp (p: bf16[1,1,8,16]) -> (bf16[1,1,8,16], bf16[2,1,8,16]) {
+  %p = bf16[1,1,8,16]{3,2,1,0} parameter(0)
+  %all-gather.135 = bf16[2,1,8,16]{3,2,1,0} all-gather(%p), channel_id=1, replica_groups={{0,1},{2,3}}, dimensions={0}, use_global_device_ids=true, frontend_attributes={chain_id="0"}, metadata={op_name="jit(train_step)/transpose(jvp(GPT2Module))/layer_carry/while/body/closed_call/blocks/block/mlp/checkpoint/rematted_computation/mlp.seq_gathered/shard_map/all_gather"}
+  ROOT %custom-call.1 = (bf16[1,1,8,16]{3,2,1,0}, bf16[2,1,8,16]{3,2,1,0}) custom-call(%all-gather.135), custom_call_target="AsyncCollectiveStart"
+}
+
+%done_mlp (p: bf16[1,1,8,16], q: bf16[2,1,8,16]) -> bf16[2,1,8,16] {
+  %p = bf16[1,1,8,16]{3,2,1,0} parameter(0)
+  %q = bf16[2,1,8,16]{3,2,1,0} parameter(1)
+  %all-gather.139 = bf16[2,1,8,16]{3,2,1,0} all-gather(%p), channel_id=1, replica_groups={{0,1},{2,3}}, dimensions={0}, use_global_device_ids=true, frontend_attributes={chain_id="0"}
+  ROOT %custom-call.2 = bf16[2,1,8,16]{3,2,1,0} custom-call(%p, %q, %all-gather.139), custom_call_target="AsyncCollectiveDone"
+}
+
+%start_attn (p: bf16[1,1,8,16]) -> (bf16[1,1,8,16], bf16[2,1,8,16]) {
+  %p = bf16[1,1,8,16]{3,2,1,0} parameter(0)
+  %all-gather.163 = bf16[2,1,8,16]{3,2,1,0} all-gather(%p), channel_id=1, replica_groups={{0,1},{2,3}}, dimensions={0}, use_global_device_ids=true, frontend_attributes={chain_id="4"}, metadata={op_name="jit(train_step)/transpose(jvp(GPT2Module))/layer_carry/while/body/closed_call/blocks/block/attn/checkpoint/rematted_computation/attn.seq_gathered/shard_map/all_gather"}
+  ROOT %custom-call.3 = (bf16[1,1,8,16]{3,2,1,0}, bf16[2,1,8,16]{3,2,1,0}) custom-call(%all-gather.163), custom_call_target="AsyncCollectiveStart"
+}
+
+%done_attn (p: bf16[1,1,8,16], q: bf16[2,1,8,16]) -> bf16[2,1,8,16] {
+  %p = bf16[1,1,8,16]{3,2,1,0} parameter(0)
+  %q = bf16[2,1,8,16]{3,2,1,0} parameter(1)
+  %all-gather.167 = bf16[2,1,8,16]{3,2,1,0} all-gather(%p), channel_id=1, replica_groups={{0,1},{2,3}}, dimensions={0}, use_global_device_ids=true, frontend_attributes={chain_id="4"}
+  ROOT %custom-call.4 = bf16[2,1,8,16]{3,2,1,0} custom-call(%p, %q, %all-gather.167), custom_call_target="AsyncCollectiveDone"
+}
+
+%body (carry: (s32[], bf16[1,1,8,16])) -> (s32[], bf16[1,1,8,16]) {
+  %carry = (s32[], bf16[1,1,8,16]{3,2,1,0}) parameter(0)
+  %i = s32[] get-tuple-element(%carry), index=0
+  %h = bf16[1,1,8,16]{3,2,1,0} get-tuple-element(%carry), index=1
+  %async-collective-start = (bf16[1,1,8,16]{3,2,1,0}, bf16[2,1,8,16]{3,2,1,0}) fusion(%h), kind=kCustom, calls=%start_mlp
+  %a.0 = bf16[1,1,8,16]{3,2,1,0} get-tuple-element(%async-collective-start), index=0
+  %a.1 = bf16[2,1,8,16]{3,2,1,0} get-tuple-element(%async-collective-start), index=1
+  %async-collective-start.4 = (bf16[1,1,8,16]{3,2,1,0}, bf16[2,1,8,16]{3,2,1,0}) fusion(%h), kind=kCustom, calls=%start_attn
+  %b.0 = bf16[1,1,8,16]{3,2,1,0} get-tuple-element(%async-collective-start.4), index=0
+  %b.1 = bf16[2,1,8,16]{3,2,1,0} get-tuple-element(%async-collective-start.4), index=1
+  %async-collective-done = bf16[2,1,8,16]{3,2,1,0} fusion(%a.0, %a.1), kind=kCustom, calls=%done_mlp
+  %async-collective-done.4 = bf16[2,1,8,16]{3,2,1,0} fusion(%b.0, %b.1), kind=kCustom, calls=%done_attn
+  %reduce_scatter.66 = bf16[1,1,8,16]{3,2,1,0} reduce-scatter(%async-collective-done.4), channel_id=1, replica_groups={{0,1},{2,3}}, use_global_device_ids=true, dimensions={0}, to_apply=%add, metadata={op_name="jit(train_step)/jvp(GPT2Module)/layer_carry/while/body/closed_call/blocks/block/attn/attn._project_out/c_proj/shard_map/reduce_scatter"}, backend_config={"collective_algorithm_config":{"emitter":"SingleInputAllReduceScatterFusion","debug":"Type: 1D; original_shape: bf16[2,1,8,16]{3,2,1,0:T(8,128)(2,1)}; sharding_dim: 0"}}
+  %one = s32[] constant(1)
+  %next = s32[] add(%i, %one)
+  ROOT %out = (s32[], bf16[1,1,8,16]{3,2,1,0}) tuple(%next, %reduce_scatter.66)
+}
+
+%cond (carry: (s32[], bf16[1,1,8,16])) -> pred[] {
+  %carry = (s32[], bf16[1,1,8,16]{3,2,1,0}) parameter(0)
+  %i = s32[] get-tuple-element(%carry), index=0
+  %layers = s32[] constant(3)
+  ROOT %lt = pred[] compare(%i, %layers), direction=LT
+}
+
+ENTRY %main (h: bf16[1,1,8,16]) -> bf16[1,1,8,16] {
+  %h = bf16[1,1,8,16]{3,2,1,0} parameter(0)
+  %zero = s32[] constant(0)
+  %init = (s32[], bf16[1,1,8,16]{3,2,1,0}) tuple(%zero, %h)
+  %while.1 = (s32[], bf16[1,1,8,16]{3,2,1,0}) while(%init), condition=%cond, body=%body
+  ROOT %result = bf16[1,1,8,16]{3,2,1,0} get-tuple-element(%while.1), index=1
+}
+"""
+
+
+def test_two_cut_collectives_on_one_channel_are_two_rows_and_a_reduce_scatter_counts_its_operand():
+    plan = collective_plan.plan_from_hlo_text(SHARD_MAP_FORMS, MESH)
+    rows = {row["name"]: row for row in plan["rows"]}
+    assert set(rows) == {"async-collective-start", "async-collective-start.4", "reduce_scatter.66"}
+    assert (rows["async-collective-start"]["done"], rows["async-collective-start"]["steps"]) == ("async-collective-done", [])
+    assert (rows["async-collective-start.4"]["done"], rows["async-collective-start.4"]["steps"]) == ("async-collective-done.4", [])
+    assert rows["async-collective-start"]["scope"].endswith("blocks/block/mlp/rematted_computation/mlp.seq_gathered/shard_map")
+    assert rows["async-collective-start.4"]["scope"].endswith("blocks/block/attn/rematted_computation/attn.seq_gathered/shard_map")
+    scatter = rows["reduce_scatter.66"]  # what a chip puts in, [2, 1, 8, 16] bfloat16, as a fused one counts: not the output, not the notes' shape too
+    assert (scatter["kind"], scatter["axis"], scatter["bytes"], scatter["times"]) == ("reduce-scatter", "tp", 2 * 8 * 16 * 2, 3)
+    assert plan["totals"]["tp|all-gather"] == {"count": 2, "bytes": 2 * 512, "count_a_run": 6, "bytes_a_run": 6 * 512}
+    assert plan["totals"]["tp|reduce-scatter"] == {"count": 1, "bytes": 512, "count_a_run": 3, "bytes_a_run": 3 * 512}
+
+
+def test_a_reduce_scatter_is_counted_one_way_fused_or_bare():
+    rows = {row["name"]: row for row in collective_plan.plan_from_hlo_text(CHIP_FORMS, MESH)["rows"]}
+    assert rows["fusion.30"]["bytes"] == 8 * 32 * 2  # the all-reduce inside the chip's fusion has the operand's shape
+    assert rows["reduce-scatter-start.1"]["bytes"] == 8 * 16 * 4  # a bare one: its output [4, 16] float32 times the two of its group
+
+
 # ------------------------------------------------------------------ a real compile, on four devices and on one
 
 
