@@ -103,7 +103,9 @@ class AttentionConfig(BaseModel):
 SLIDING, FULL = "sliding_attention", "full_attention"  # a layer's kind of attention, as `layer_types` publishes it
 HYBRID = "hybrid"  # `model_type: zaya`'s one kind of layer: compressed convolutional attention (`cca_config`), then the expert layer
 LINEAR = "linear_attention"  # `model_type: qwen3_next`'s layers between the `full_attention` ones: the gated delta rule's mixer (`gdn_config`)
-LayerType = Literal["sliding_attention", "full_attention", "hybrid", "linear_attention"]
+MAMBA, ATTENTION = "mamba", "attention"  # `model_type: granitemoehybrid`'s two: the Mamba-2 mixer (`ssd_config`), and plain attention over all that came before
+LayerType = Literal["sliding_attention", "full_attention", "hybrid", "linear_attention", "mamba", "attention"]
+MULTIPLIERS = ("embedding_multiplier", "residual_multiplier", "attention_multiplier", "logits_scaling")  # `granitemoehybrid`'s four scalars, config and spec alike
 
 
 class RopeParameters(BaseModel):
@@ -291,6 +293,45 @@ class GPT2LLMConfig(BaseModel):
     # (`zero_centered`), the gated shared expert `moe_config`'s. All unset: the tree and the program of before.
     gdn_config: Optional[GDNConfig] = None
     attn_output_gate: bool = False
+    # `model_type: granitemoehybrid` (PR 52). `layer_types` of `mamba` puts the Mamba-2 mixer (models/gpt2/ssd.py) in the mixer seat, its
+    # heads, state, taps and chunk in `ssd_config` (validated as `ssd.SSDConfig` where set: a model without such a layer imports nothing
+    # of it); the `attention` layers beside them are the plain attention's. The four multipliers, keys as the source names them:
+    # the table's output times `embedding_multiplier`, each of a block's two branches times `residual_multiplier` before it is added,
+    # the attention's scores times `attention_multiplier` in place of `1 / sqrt(head_dim)`, the logits divided by `logits_scaling`
+    # (applied to the normed hidden state before the head: the same function, and the fused cross entropy needs no scale). The
+    # shared expert's share of its width is `moe_config`'s (`shared_expert_shards`). All unset: the tree and the program of before.
+    ssd_config: Optional[dict] = None
+    embedding_multiplier: Optional[Annotated[float, Field(gt=0.0)]] = None
+    residual_multiplier: Optional[Annotated[float, Field(gt=0.0)]] = None
+    attention_multiplier: Optional[Annotated[float, Field(gt=0.0)]] = None
+    logits_scaling: Optional[Annotated[float, Field(gt=0.0)]] = None
+
+    @model_validator(mode="after")
+    def check_mamba_layers(self) -> "GPT2LLMConfig":
+        mamba = self.layer_types is not None and MAMBA in self.layer_types
+        if mamba != (self.ssd_config is not None):
+            raise ValueError("ssd_config gives a mamba layer its heads, state, taps and chunk: layer_types of mamba and ssd_config go together")
+        kinds = set(self.layer_types or ())
+        if kinds & {MAMBA, ATTENTION} and kinds - {MAMBA, ATTENTION}:
+            raise ValueError("layer_types: mamba and attention layers stand beside each other or alone; beside sliding_attention, full_attention, "
+                             "hybrid or linear_attention layers they are not written")
+        multipliers = [name for name in MULTIPLIERS if getattr(self, name) is not None]
+        if multipliers and (self.loop_config is not None or self.scale_residual_merge or self.mla_config is not None or self.cca_config is not None):
+            raise ValueError(f"{', '.join(multipliers)} beside loop_config, scale_residual_merge, mla_config or cca_config: a scalar on the table, "
+                             "the branches, the scores or the logits is written for the plain block and the plain attention, in a stack walked once")
+        if not mamba:
+            return self
+        from modalities_tpu.models.gpt2.ssd import SSDConfig
+
+        SSDConfig(**self.ssd_config)  # refuses an unknown key, and several groups, by name
+        beside = [name for name, value in (("attn_layer_period", self.attn_layer_period), ("ssm_config", self.ssm_config), ("mla_config", self.mla_config),
+                                           ("loop_config", self.loop_config), ("cca_config", self.cca_config), ("gdn_config", self.gdn_config),
+                                           ("sliding_window", self.sliding_window), ("rope_parameters", self.rope_parameters)) if value is not None]
+        if beside:
+            raise ValueError(f"ssd_config beside {', '.join(beside)}: the Mamba-2 mixer shares a stack with plain attention over all that came "
+                             "before, spelled by layer_types, in a stack walked once; with the Mamba-1 mixer's period, latent or compressed "
+                             "attention, the gated delta rule, a loop, a window or a rotary by kind of layer it is not written")
+        return self
 
     @model_validator(mode="after")
     def check_linear_layers(self) -> "GPT2LLMConfig":
@@ -550,6 +591,13 @@ class GPT2ModelSpec:
     # attention's output under a gate read off a `q_attn` twice as wide
     gdn: Optional[GDNSpec] = None
     attn_output_gate: bool = False
+    # the Mamba-2 mixer in the layers whose mixer is "ssd" (`layer_types`: `mamba`; an `ssd.SSDSpec`), and the four scalar multipliers
+    # on the table's output, a block's branches, the attention's scores and the logits (None: none, the program of before)
+    ssd: Optional[object] = None
+    embedding_multiplier: Optional[float] = None
+    residual_multiplier: Optional[float] = None
+    attention_multiplier: Optional[float] = None
+    logits_scaling: Optional[float] = None
 
     @property
     def router_state_width(self) -> int:
@@ -560,13 +608,26 @@ class GPT2ModelSpec:
     def counter_row_width(self) -> int:
         """What an expert layer's block hands up a pass: `COUNTERS`, the router's columns' loads, a matrix softmax router's
         balance term, and after them the mean key temperature where the block's mixer is `cca`, or the two of `gdn.COUNTERS`
-        where the stack holds the gated delta rule's mixer (zeros in the row of a layer whose mixer is another)."""
+        where the stack holds the gated delta rule's mixer, or the one of `ssd.COUNTERS` where it holds the Mamba-2 mixer (zeros
+        in the row of a layer whose mixer is another)."""
         return len(COUNTERS) + self.moe.router_width + self.moe.counts_aux_loss + (self.cca is not None) + self.mixer_counters
 
     @property
+    def mixer_counter_names(self) -> tuple[str, ...]:
+        """What a row holds after the expert layer's own: the counters of the stack's `gdn` layers, or of its `ssd` layers."""
+        if self.ssd is not None:
+            from modalities_tpu.models.gpt2.ssd import COUNTERS as SSD_COUNTERS
+
+            return SSD_COUNTERS
+        return GDN_COUNTERS if self.gdn is not None else ()
+
+    @property
     def mixer_counters(self) -> int:
-        """The entries a row holds after the expert layer's own for the stack's `gdn` layers."""
-        return len(GDN_COUNTERS) if self.gdn is not None else 0
+        return len(self.mixer_counter_names)
+
+    @property
+    def has_multipliers(self) -> bool:
+        return any(getattr(self, name) is not None for name in MULTIPLIERS)
 
     @property
     def head_dim(self) -> int:
@@ -656,6 +717,11 @@ class GPT2ModelSpec:
                 self.scale_residual_merge,
                 self.gdn,
                 self.attn_output_gate,
+                self.ssd,
+                self.embedding_multiplier,
+                self.residual_multiplier,
+                self.attention_multiplier,
+                self.logits_scaling,
             )
         )
 
@@ -896,7 +962,7 @@ class CausalSelfAttention(nn.Module):
             y = causal_attention(
                 q, k, v, impl=spec.attention_impl, window=window, kept=spec.remat_keep_flash, cp_axis=spec.context_parallel_axis,
                 dropout_rate=spec.dropout if dropping else 0.0, dropout_rng=self.make_rng("dropout") if dropping else None,
-                flash=flash_attention,
+                flash=flash_attention, sm_scale=spec.attention_multiplier,
             )
 
             # named save point for selective-op remat (reference SAVE_DICT saves the SDPA
@@ -1219,8 +1285,13 @@ class GPT2Block(nn.Module):
     deterministic: bool = True
     decode: bool = False
     slot_spec: Optional[SlotDecodeSpec] = None
-    mixer: str = "attn"  # what sits in the mixer seat: "attn", "ssm", "swa" (attention under the spec's window), "cca" or "gdn"
+    mixer: str = "attn"  # what sits in the mixer seat: "attn", "ssm", "swa" (attention under the spec's window), "cca", "gdn" or "ssd"
     ffn: str = "mlp"  # what sits in the feed-forward seat: "mlp" or "moe"; with "moe" the block returns (x, what the layer counted)
+
+    def _merge(self, x, branch):
+        """The residual stream plus a branch: times `residual_multiplier` where the config has one, in float32 and rounded once."""
+        factor = self.spec.residual_multiplier
+        return x + branch if factor is None else (x.astype(jnp.float32) + factor * branch.astype(jnp.float32)).astype(x.dtype)
 
     @nn.compact
     def __call__(self, x, slot=None, positions=None, router_state=None, layer_index=None):
@@ -1236,6 +1307,10 @@ class GPT2Block(nn.Module):
             a, key_temperature = CompressedConvAttention(spec, self.deterministic, name=scopes.CCA)(h)
         elif self.mixer == "gdn":
             a, mixer_counted = GatedDeltaNet(spec, self.deterministic, name=scopes.GDN)(h)
+        elif self.mixer == "ssd":
+            from modalities_tpu.models.gpt2.ssd import Mamba2Mixer
+
+            a, mixer_counted = Mamba2Mixer(spec, self.deterministic, name=scopes.SSD)(h)
         elif self.mixer == "ssm":
             a = MambaMixer(spec, name=scopes.SSM)(h)
             a = nn.Dropout(rate=spec.dropout)(a, deterministic=self.deterministic or spec.dropout == 0.0)
@@ -1256,7 +1331,7 @@ class GPT2Block(nn.Module):
             if spec.scale_residual_merge:
                 x = _ResidualMerge(name="attn_merge")(x, a, passes=None if layer_index is None else layer_index == 0)
             else:
-                x = x + a
+                x = self._merge(x, a)
         h2 = build_norm(spec.ffn_norm, "ffn_norm", dtype=x.dtype)(x)
         counters = None
         if self.ffn == "moe" and spec.router_state_width:
@@ -1268,10 +1343,10 @@ class GPT2Block(nn.Module):
         if spec.post_ffn_norm is not None:
             m = build_norm(spec.post_ffn_norm, scopes.POST_FFN_NORM, dtype=x.dtype)(m)
         with jax.named_scope(scopes.RESIDUAL):
-            x = _ResidualMerge(name="ffn_merge")(x, m) if spec.scale_residual_merge else x + m
+            x = _ResidualMerge(name="ffn_merge")(x, m) if spec.scale_residual_merge else self._merge(x, m)
         if counters is not None and key_temperature is not None:
             counters = jnp.concatenate([counters, key_temperature[None]])
-        if counters is not None and spec.mixer_counters:  # a `gdn` layer's two; zeros from a layer of the same stack whose mixer is another
+        if counters is not None and spec.mixer_counters:  # a `gdn` layer's two or an `ssd` layer's one; zeros from a layer of the same stack whose mixer is another
             counters = jnp.concatenate([counters, mixer_counted if mixer_counted is not None else jnp.zeros((spec.mixer_counters,), jnp.float32)])
         if spec.debug_print_activations == "shape":
             jax.debug.print(
@@ -1499,6 +1574,30 @@ _NO_STATE_ACROSS_A_SHARD_EDGE = (
     "first position to its last: under context parallelism a shard would need the state and the last taps of the shard before (a hand-off "
     "along the cp axis beside parallel/ring_attention.py's ring), which is not written. Run it without a cp axis."
 )
+_NO_SSD_STATE_CACHE = (
+    "this model has Mamba-2 layers (ssd_config, layer_types: mamba), and serving them needs a cache of the convolution's last mamba_d_conv - 1 "
+    "inputs (x, B and C) and of the [heads, mamba_d_head, mamba_d_state] state of every sequence and layer beside the attention layers' keys "
+    "and values, which serving/paged_cache.py and serving/engine.py do not have: it trains, it does not decode"
+)
+_NO_SSD_STATE_ACROSS_A_SHARD_EDGE = (
+    "this model has Mamba-2 layers (ssd_config), whose state and whose convolution over the sequence run from the row's first position to "
+    "its last: under context parallelism a shard would need the state and the last taps of the shard before (the state's hand-off along "
+    "the cp axis beside parallel/ring_attention.py's ring), which is not written. Run it without a cp axis."
+)
+_NO_NORM_SUM_ACROSS_TP = (
+    "this model has Mamba-2 layers (ssd_config) with mamba_n_groups 1: split over a tp axis the heads would share one B and C and the gated "
+    "norm's mean square would run across the shards (one scalar a token to all-reduce before the norm's scale), which is not written. Run it "
+    "without a tp axis; a chip's share of the heads is ssd_config.heads_held."
+)
+_NO_MULTIPLIERS_IN_THE_CACHED_FORWARD = (
+    "this model scales its table's output, its branches, its scores or its logits (embedding_multiplier, residual_multiplier, "
+    "attention_multiplier, logits_scaling), and the cached forwards of serving (the decode, slot and paged paths of CausalSelfAttention, "
+    "whose masked softmax divides by sqrt(head_dim)) do not carry the scores' scale: it trains, it does not decode"
+)
+_NO_MULTIPLIERS_IN_STAGES = (
+    "this model scales its table's output and its logits (embedding_multiplier, logits_scaling); pipeline parallelism embeds and projects in "
+    "stage functions of its own (GPT2LLM.pp_stage_fns), which do not carry them. Run it without a pp axis."
+)
 _NO_GATE_IN_THE_CACHED_FORWARD = (
     "this model gates its attention's output (attn_output_gate), and the cached forwards of serving (the decode, slot and paged paths "
     "of CausalSelfAttention) do not carry the gate to the output projection: it trains, it does not decode"
@@ -1520,7 +1619,7 @@ def refuse_uneven_heads(spec: "GPT2ModelSpec") -> None:
 
 KEY_TEMPERATURE = "cca_key_temperature"  # counted by a model whose mixer is `cca`: the mean of the learned key temperatures
 
-_MIXER_OF = {SLIDING: "swa", FULL: "attn", HYBRID: "cca", LINEAR: "gdn"}  # a published layer type as the block's mixer seat names it
+_MIXER_OF = {SLIDING: "swa", FULL: "attn", HYBRID: "cca", LINEAR: "gdn", MAMBA: "ssd", ATTENTION: "attn"}  # a published layer type as the block's mixer seat names it
 
 
 def refuse_serving(spec: "GPT2ModelSpec") -> None:
@@ -1528,6 +1627,7 @@ def refuse_serving(spec: "GPT2ModelSpec") -> None:
     for missing, reason in ((spec.has_ssm, _NO_RECURRENT_STATE_CACHE), (spec.mla is not None, _NO_LATENT_CACHE),
                             (spec.has_window, _NO_CACHE_BY_LAYER_KIND), (spec.cca is not None, _NO_CONV_AND_SHIFT_STATE_CACHE),
                             (spec.gdn is not None, _NO_MATRIX_STATE_CACHE), (spec.attn_output_gate, _NO_GATE_IN_THE_CACHED_FORWARD),
+                            (spec.ssd is not None, _NO_SSD_STATE_CACHE), (spec.has_multipliers, _NO_MULTIPLIERS_IN_THE_CACHED_FORWARD),
                             (spec.has_moe, _NO_DECODE_THROUGH_DISPATCH), (spec.loop is not None, _NO_CACHE_ENTRY_PER_WALK)):
         if missing:
             raise NotImplementedError(reason)
@@ -1745,7 +1845,10 @@ class GPT2Module(nn.Module):
         # at scale that all-gathers [B,S,E] per step instead of the [V,E] table
         with jax.named_scope(scopes.WTE):
             wte_lookup = with_logical_constraint(wte, ("vocab", "embed_lookup"), explicit=True)
-            x = embedding_lookup(wte_lookup, input_ids).astype(compute_dtype)
+            x = embedding_lookup(wte_lookup, input_ids)
+            if spec.embedding_multiplier is not None:
+                x = x.astype(jnp.float32) * spec.embedding_multiplier
+            x = x.astype(compute_dtype)
             # the lookup's own output, whole over tp: the partial sums of a table whose vocabulary tp splits are summed
             # once; the stream's split below is then a slice a chip (asked for here, a reduce-scatter comes with
             # collective-permutes of the whole output round it, forward and backward: PERF.md, PR 51)
@@ -1795,6 +1898,15 @@ class GPT2Module(nn.Module):
             if spec.context_parallel_axis is not None:
                 raise NotImplementedError(_NO_STATE_ACROSS_A_SHARD_EDGE)
             refuse_uneven_heads(spec)
+        if spec.ssd is not None:
+            from modalities_tpu.parallel.sharding import installed_axis_size
+
+            if spec.context_parallel_axis is not None:
+                raise NotImplementedError(_NO_SSD_STATE_ACROSS_A_SHARD_EDGE)
+            if installed_axis_size("tp") > 1:
+                raise NotImplementedError(_NO_NORM_SUM_ACROSS_TP)
+        if spec.has_multipliers and spec.pipeline_axis is not None:
+            raise NotImplementedError(_NO_MULTIPLIERS_IN_STAGES)
         if spec.pipeline_axis is not None and (spec.has_moe or spec.mla is not None or len(spec.stack_runs) > 1):
             raise NotImplementedError(
                 "pipeline parallelism splits ONE stack of equal dense-decoder layers over its stages; a model whose "
@@ -1911,6 +2023,8 @@ class GPT2Module(nn.Module):
 
         if spec.loop is None:  # a looped model's final norm closes every walk (`_walks`)
             x = build_norm(spec.lm_head_norm, "lm_head_norm")(x)
+            if spec.logits_scaling is not None:  # `logits / s` is `(h / s) W^T`: on the hidden state, so that every head (fused, chunked, dense) is scaled
+                x = x / spec.logits_scaling
             x = with_logical_constraint(x, ("batch", "seq_sp", "embed"))
         if self.output_hidden:
             return x
@@ -1936,6 +2050,15 @@ class GPT2Module(nn.Module):
                 param_dtype=param_dtype,
             )(x.astype(jnp.float32))
         return with_logical_constraint(logits, ("batch", "seq", "vocab_logits"))
+
+
+def _ssd_spec(ssd_config):
+    """The Mamba-2 mixer's sizes from `ssd_config`; None, and nothing of `models/gpt2/ssd.py` imported, where there is none."""
+    if ssd_config is None:
+        return None
+    from modalities_tpu.models.gpt2.ssd import SSDSpec
+
+    return SSDSpec.from_config(ssd_config)
 
 
 class GPT2LLM(NNModel):
@@ -1982,6 +2105,11 @@ class GPT2LLM(NNModel):
         scale_residual_merge: bool = False,
         gdn_config: Optional[GDNConfig | dict] = None,
         attn_output_gate: bool = False,
+        ssd_config: Optional[dict] = None,
+        embedding_multiplier: Optional[float] = None,
+        residual_multiplier: Optional[float] = None,
+        attention_multiplier: Optional[float] = None,
+        logits_scaling: Optional[float] = None,
     ):
         super().__init__(
             sample_key=sample_key,
@@ -2017,6 +2145,10 @@ class GPT2LLM(NNModel):
                 # and the shared expert's gate `w_g [d, 1]`
                 "gdn_vectors": [r".*/gdn/(A_log|dt_bias|conv_kernel|out_norm_scale)$"],
                 "shared_expert_gate": [r".*/moe/shared_gate$"],
+                # what a `granitemoehybrid` model adds (PR 52). Matrices, which a recipe decays: the Mamba-2 mixer's two projections
+                "ssd_projections": [r".*/ssd/(in_proj|out_proj)/kernel$"],
+                # and what it does not: the decay's `A_log`, the skip `D`, `dt_bias`, the convolution's taps and bias, the gated norm's scale
+                "ssd_vectors": [r".*/ssd/(A_log|D|dt_bias|conv_kernel|conv_bias|norm_scale)$"],
             },
         )
         if n_head_q % n_head_kv != 0:
@@ -2082,6 +2214,9 @@ class GPT2LLM(NNModel):
             scale_residual_merge=scale_residual_merge,
             gdn=GDNSpec.from_config(gdn_config) if gdn_config is not None else None,
             attn_output_gate=attn_output_gate,
+            ssd=_ssd_spec(ssd_config),
+            embedding_multiplier=embedding_multiplier, residual_multiplier=residual_multiplier,
+            attention_multiplier=attention_multiplier, logits_scaling=logits_scaling,
         )
         self.sequence_length = sequence_length
         self.vocab_size = vocab_size
@@ -2175,8 +2310,8 @@ class GPT2LLM(NNModel):
             aux[SKIP_SHARE] = ()
         if spec.cca is not None:  # the mean key temperature of compressed convolutional attention, over heads and layers
             aux[KEY_TEMPERATURE] = ()
-        if spec.mixer_counters:  # the gated delta rule's mean decay and mean beta, over tokens, heads and its layers
-            aux.update({name: () for name in GDN_COUNTERS})
+        # the gated delta rule's mean decay and mean beta, or the Mamba-2 mixer's mean decay, over tokens, heads and its layers
+        aux.update({name: () for name in spec.mixer_counter_names})
         return {**{name: () for name in COUNTERS}, EXPERT_LOAD: (spec.ffn_kinds.count("moe"), spec.moe.router_width), **aux}
 
     @property
@@ -2205,11 +2340,12 @@ class GPT2LLM(NNModel):
             counted[SKIP_SHARE] = jnp.mean(loads[:, -1] / jnp.maximum(jnp.sum(loads, axis=1), 1.0))
         if self.config_spec.cca is not None:
             counted[KEY_TEMPERATURE] = rows[:, -1].mean()
-        if self.config_spec.mixer_counters:  # the mean over the expert layers whose mixer is `gdn` (a row a layer, in the stack's order)
+        if self.config_spec.mixer_counters:  # the mean over the expert layers whose mixer counts (a row a layer, in the stack's order)
             spec = self.config_spec
-            of_gdn = [row for row, layer in enumerate(i for i, ffn in enumerate(spec.ffn_kinds) if ffn == "moe") if spec.kinds[layer] == "gdn"]
-            for column, name in enumerate(GDN_COUNTERS, start=-len(GDN_COUNTERS)):
-                counted[name] = rows[jnp.asarray(of_gdn), column].mean() if of_gdn else jnp.zeros((), jnp.float32)
+            counting = "ssd" if spec.ssd is not None else "gdn"
+            of_mixer = [row for row, layer in enumerate(i for i, ffn in enumerate(spec.ffn_kinds) if ffn == "moe") if spec.kinds[layer] == counting]
+            for column, name in enumerate(spec.mixer_counter_names, start=-spec.mixer_counters):
+                counted[name] = rows[jnp.asarray(of_mixer), column].mean() if of_mixer else jnp.zeros((), jnp.float32)
         return (out if hidden else {self.prediction_key: out}), counted
 
     def loss_from_layers(self, counted: dict):

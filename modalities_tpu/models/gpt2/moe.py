@@ -10,7 +10,8 @@ On `x [T, d]`, with `E = n_routed_experts`, `k = num_experts_per_tok`:
     expert e = W_2_e(silu(W_e x) * (V_e x))        d -> moe_intermediate_size -> d
     out      = sum over the chosen experts of w * expert(x) + shared(x)
     shared   = one SwiGLU d -> n_shared_experts * moe_intermediate_size -> d, on every token (`shared_expert_intermediate_size`
-               gives the width where the source names it so); with `shared_expert_gate` times `sigmoid(x w_g)`, `w_g [d, 1]`
+               gives the width where the source names it so); with `shared_expert_gate` times `sigmoid(x w_g)`, `w_g [d, 1]`;
+               with `shared_expert_shards` n, one of n equal slices of that width (a chip's share, as the routed experts have theirs)
 
 `n_group` and `topk_group` other than 1 (group-limited routing) are not written and are
 refused. No token is dropped; there is no capacity.
@@ -132,6 +133,10 @@ class MoEConfig(BaseModel):
     # expert's output times `sigmoid(x w_g)`, `w_g [d, 1]` without bias, a token's own scalar.
     shared_expert_intermediate_size: Optional[Annotated[int, Field(strict=True, ge=1)]] = None
     shared_expert_gate: bool = False
+    # `model_type: granitemoehybrid` (PR 52): the shared expert's share. This layer holds ONE of that many equal slices of the shared
+    # expert's published width (columns of `W` and `V`, rows of `W_2`), as a chip of a tensor-parallel group would; the width above
+    # stays as published. What the other slices would add is the other chips' to add (the exchange is not written). 1: the whole width.
+    shared_expert_shards: Annotated[int, Field(strict=True, ge=1)] = 1
 
     @model_validator(mode="after")
     def refuse_what_is_not_written(self) -> "MoEConfig":
@@ -156,6 +161,9 @@ class MoEConfig(BaseModel):
             raise ValueError("moe_config: expert_offset + experts_held exceeds n_routed_experts")
         if self.shared_expert_intermediate_size is not None and self.n_shared_experts:
             raise ValueError("moe_config: shared_expert_intermediate_size and n_shared_experts both give the shared expert its width; set one")
+        shared_width = self.shared_expert_intermediate_size or self.n_shared_experts * self.moe_intermediate_size
+        if shared_width % self.shared_expert_shards:
+            raise ValueError(f"moe_config.shared_expert_shards {self.shared_expert_shards} does not divide the shared expert's width {shared_width}")
         if self.shared_expert_gate and not (self.shared_expert_intermediate_size or self.n_shared_experts):
             raise ValueError("moe_config.shared_expert_gate gates a shared expert: give shared_expert_intermediate_size (or n_shared_experts)")
         if self.router == "matrix" and (self.router_hidden_size is not None or self.use_eda or self.use_mod):
@@ -180,7 +188,7 @@ class MoESpec:
     n_routed_experts: int
     num_experts_per_tok: int
     moe_intermediate_size: int
-    shared_hidden: int  # n_shared_experts * moe_intermediate_size; 0: no shared expert
+    shared_hidden: int  # the shared expert's width as held: the published one (or n_shared_experts * moe_intermediate_size) over `shared_expert_shards`; 0: no shared expert
     first_k_dense_replace: int
     routed_scaling_factor: float
     norm_topk_prob: bool
@@ -219,7 +227,7 @@ class MoESpec:
         return cls(
             n_routed_experts=config.n_routed_experts, num_experts_per_tok=config.num_experts_per_tok,
             moe_intermediate_size=config.moe_intermediate_size,
-            shared_hidden=config.shared_expert_intermediate_size or config.n_shared_experts * config.moe_intermediate_size,
+            shared_hidden=(config.shared_expert_intermediate_size or config.n_shared_experts * config.moe_intermediate_size) // config.shared_expert_shards,
             first_k_dense_replace=config.first_k_dense_replace, routed_scaling_factor=float(config.routed_scaling_factor),
             norm_topk_prob=config.norm_topk_prob,
             experts_held=config.n_routed_experts if config.experts_held is None else config.experts_held,

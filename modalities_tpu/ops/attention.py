@@ -121,7 +121,7 @@ def flash_attention_or_fallback(q, k, v, causal: bool = True, sm_scale: float | 
     )(q, k, v)
 
 
-def masked_attention(q, k, v, mask, dropout_rate: float = 0.0, dropout_rng=None):
+def masked_attention(q, k, v, mask, dropout_rate: float = 0.0, dropout_rng=None, sm_scale: Optional[float] = None):
     """einsum + fp32 softmax attention with an explicit boolean mask — [Sq, Sk]
     shared across the batch, or [B, Sq, Sk] per-batch-row (slot decode: each slot
     attends up to its own cache length).
@@ -134,7 +134,8 @@ def masked_attention(q, k, v, mask, dropout_rate: float = 0.0, dropout_rng=None)
     hkv = k.shape[2]
     group = hq // hkv
     qg = q.reshape(b, sq, hkv, group, d)
-    logits = jnp.einsum("bshgd,bthd->bhgst", qg, k).astype(jnp.float32) / math.sqrt(d)
+    logits = jnp.einsum("bshgd,bthd->bhgst", qg, k).astype(jnp.float32)
+    logits = logits / math.sqrt(d) if sm_scale is None else logits * sm_scale
     mask_b = mask[None, None, None, :, :] if mask.ndim == 2 else mask[:, None, None, :, :]
     logits = jnp.where(mask_b, logits, jnp.finfo(jnp.float32).min)
     probs = jax.nn.softmax(logits, axis=-1)
@@ -151,25 +152,26 @@ def masked_attention(q, k, v, mask, dropout_rate: float = 0.0, dropout_rng=None)
     return out.reshape(b, sq, hq, v.shape[-1])
 
 
-def manual_attention(q, k, v, dropout_rate: float = 0.0, dropout_rng=None, window: Optional[int] = None):
+def manual_attention(q, k, v, dropout_rate: float = 0.0, dropout_rng=None, window: Optional[int] = None, sm_scale: Optional[float] = None):
     """Oracle attention: causal mask over a square sequence (reference :595-658); under `window`
     a position sees itself and the `window - 1` before it."""
     s = q.shape[1]
     mask = jnp.tril(jnp.ones((s, s), dtype=bool))
     if window is not None:
         mask = mask & ~jnp.tril(jnp.ones((s, s), dtype=bool), -window)
-    return masked_attention(q, k, v, mask, dropout_rate=dropout_rate, dropout_rng=dropout_rng)
+    return masked_attention(q, k, v, mask, dropout_rate=dropout_rate, dropout_rng=dropout_rng, sm_scale=sm_scale)
 
 
-def sdpa_attention(q, k, v):
+def sdpa_attention(q, k, v, sm_scale: Optional[float] = None):
     """XLA-fused scaled dot product attention with native GQA support."""
-    return jax.nn.dot_product_attention(q, k, v, is_causal=True)
+    return jax.nn.dot_product_attention(q, k, v, is_causal=True, scale=sm_scale)
 
 
-def flash_attention(q, k, v, window: Optional[int] = None, kept: bool = False):
+def flash_attention(q, k, v, window: Optional[int] = None, kept: bool = False, sm_scale: Optional[float] = None):
     """The `dao_flash` rung: the Pallas kernels on a TPU, SDPA off it (under a window or two widths, the masked softmax
-    written out). `kept`: the call sits in a rematerialized block that keeps the kernel's o and lse (`spec.remat_keep_flash`)."""
-    return flash_attention_or_fallback(q, k, v, causal=True, window=window, kept=kept)
+    written out). `kept`: the call sits in a rematerialized block that keeps the kernel's o and lse (`spec.remat_keep_flash`).
+    `sm_scale`: the scores' scale where it is not `1 / sqrt(D)` (`attention_multiplier`)."""
+    return flash_attention_or_fallback(q, k, v, causal=True, sm_scale=sm_scale, window=window, kept=kept)
 
 
 def takes_kernel(impl: str, dropout_rate: float = 0.0, cp_axis: Optional[str] = None) -> bool:
@@ -179,9 +181,10 @@ def takes_kernel(impl: str, dropout_rate: float = 0.0, cp_axis: Optional[str] = 
 
 
 def causal_attention(q, k, v, *, impl: str, window: Optional[int] = None, kept: bool = False, dropout_rate: float = 0.0,
-                     dropout_rng=None, cp_axis: Optional[str] = None, flash=flash_attention):
+                     dropout_rng=None, cp_axis: Optional[str] = None, flash=flash_attention, sm_scale: Optional[float] = None):
     """The attention function of a training forward, for every mixer: q `[B,S,Hq,D]`, k `[B,S,Hkv,D]`, v `[B,S,Hkv,Dv]`
-    -> `[B,S,Hq,Dv]`, scores scaled by `1 / sqrt(D)`, position i seeing 0..i (the last `window` of them under one).
+    -> `[B,S,Hq,Dv]`, scores scaled by `1 / sqrt(D)` (by `sm_scale` where a config gives the scale itself: `attention_multiplier`; the
+    ring carries none and refuses one), position i seeing 0..i (the last `window` of them under one).
 
     `impl` is the config's `attention_implementation` (`manual` | `pytorch_flash` | `dao_flash`); `dropout_rate` is the
     attention-probability dropout in force for this call (0 where the module is deterministic: the reference passes
@@ -200,7 +203,11 @@ def causal_attention(q, k, v, *, impl: str, window: Optional[int] = None, kept: 
     5. else XLA's fused SDPA.
 
     `flash` is `gpt2_model`'s own name for rung 3, which `tests/benchmark/` replaces to drop a window; no other caller passes it."""
+    scaled = {} if sm_scale is None else {"sm_scale": sm_scale}  # a call without a scale of its own is the call it always was
     if cp_axis is not None:
+        if sm_scale is not None:
+            raise NotImplementedError("a scale on the scores other than 1 / sqrt(head_dim) (attention_multiplier) is not written for ring "
+                                      "attention (context parallelism). Run it without a cp mesh axis.")
         if dropout_rate > 0.0:
             raise NotImplementedError(
                 "attention-probability dropout (dropout > 0) is not implemented for "
@@ -221,9 +228,9 @@ def causal_attention(q, k, v, *, impl: str, window: Optional[int] = None, kept: 
                 "or pytorch_flash (both apply the reference's attention-weight "
                 "dropout semantics), or set dropout: 0.0."
             )
-        return manual_attention(q, k, v, dropout_rate=dropout_rate, dropout_rng=dropout_rng, window=window)
+        return manual_attention(q, k, v, dropout_rate=dropout_rate, dropout_rng=dropout_rng, window=window, **scaled)
     if takes_kernel(impl):
-        return flash(q, k, v, window, kept=True) if kept else flash(q, k, v, window)
+        return flash(q, k, v, window, kept=True, **scaled) if kept else flash(q, k, v, window, **scaled)
     if impl == AttentionImplementation.MANUAL.value or window is not None or v.shape[-1] != q.shape[-1]:
-        return manual_attention(q, k, v, window=window)
-    return sdpa_attention(q, k, v)
+        return manual_attention(q, k, v, window=window, **scaled)
+    return sdpa_attention(q, k, v, **scaled)

@@ -4,7 +4,7 @@ Whether a model's RMS norms take it is `ops/tiers.py`'s one rule, asked where th
 (`models/components/layer_norms.build_norm`: on a TPU; elsewhere the reference linen norms, so
 CPU tier-1 numerics are the seed's). A call that gets here runs the kernel, interpreted off a TPU.
 
-Block size: the tuning table (`ops/pallas/autotune.blocks`), else 256 rows.
+Block size: the tuning table (`ops/pallas/autotune.blocks`), else 256 rows; fewer where the width would not leave them room in VMEM.
 """
 
 from __future__ import annotations
@@ -20,8 +20,17 @@ from modalities_tpu.ops.pallas import autotune
 DEFAULT_BLOCK_ROWS = 256
 
 
+# what the backward kernel holds in VMEM for an element of its block of rows (x, dy and dx twice over, the float32 working copies):
+# the compiler asked 23.99 MB of scoped VMEM for 256 rows of 4096 (PR 52), against the 16 MiB a kernel may have
+VMEM_BYTES_AN_ELEMENT, SCOPED_VMEM_BYTES = 24, 16 * 2**20
+
+
 def resolve_rmsnorm_block_rows(n_embd: int, dtype) -> int:
-    return autotune.blocks("fused_rmsnorm", f"e{autotune.shape_bucket(n_embd)}", dtype, block_rows=DEFAULT_BLOCK_ROWS)[0]
+    """The table's rows a block, halved until the backward kernel's block fits its scoped VMEM: 256 up to a width of 2730, 128 at 4096."""
+    rows = autotune.blocks("fused_rmsnorm", f"e{autotune.shape_bucket(n_embd)}", dtype, block_rows=DEFAULT_BLOCK_ROWS)[0]
+    while rows > 8 and rows * n_embd * VMEM_BYTES_AN_ELEMENT > SCOPED_VMEM_BYTES:
+        rows //= 2
+    return rows
 
 
 # how the rows of a norm's input lie on the mesh, by rank: the residual stream

@@ -87,6 +87,21 @@ module name `gdn` in a block's mixer seat:
     GDN_OUT_NORM      out_norm          the RMS norm a head of the rule's output, times `silu(z)` (the name of the norm's module)
     GDN_OUT           out               the output projection back to the residual's width (`gdn/out/out_proj`)
 
+The Mamba-2 mixer (`ssd_config`, a `mamba` layer's; `models/gpt2/ssd.py`, `ops/ssd.py`), under the module name `ssd` in a block's
+mixer seat:
+
+    SSD_IN_PROJ       in_proj           the one projection of the block's normed input: z, x, B, C and dt together (`ssd/in_proj/in_proj`)
+    SSD_CONV          conv              the causal depthwise convolution over x, B and C together, its bias and its SiLU
+    SSD_SCAN          scan              the chunked (state-space dual) form, with `dt`'s softplus and the log decays before it; under it:
+    SSD_INTRA         intra             inside a chunk, for all chunks at once: the sums of the log decays, `L`, `C B^T`, `Y_in`
+    SSD_STATE         state             the chunks' own states `S_c`, the pass over the chunks that carries `[heads, d_head, d_state]`, `Y_off`
+    SSD_GATE          gate              the skip `D x`, `silu(z)` and the RMS norm over the inner width held, after the gate
+    SSD_OUT_PROJ      out_proj          the output projection back to the residual's width (`ssd/out_proj/out_proj`)
+
+The four multipliers (`embedding_multiplier`, `residual_multiplier`, `attention_multiplier`, `logits_scaling`) ride in the scopes that were
+there: the table's output in `wte`, a branch's factor in `residual`, the scores' in the attention's own, the logits' on the normed hidden
+state before the head.
+
 Attention with an output gate (`attn_output_gate`): `attn/gate` holds the sigmoid of the gate half of `q_attn` times the attention's output.
 An expert layer whose shared expert is gated (`shared_expert_gate`): `moe/shared_gate` holds the `[d, 1]` product, its sigmoid and the multiply.
 
@@ -192,6 +207,14 @@ GDN_STATE = "state"
 GDN_GROUP = "group"
 GDN_OUT_NORM = "out_norm"
 GDN_OUT = "out"
+SSD = "ssd"  # the mixer's module name in the block's seat (a `mamba` layer)
+SSD_IN_PROJ = "in_proj"
+SSD_CONV = "conv"
+SSD_SCAN = "scan"
+SSD_INTRA = "intra"
+SSD_STATE = "state"
+SSD_GATE = "gate"
+SSD_OUT_PROJ = "out_proj"
 ATTN_GATE = "gate"  # inside `attn`, where the attention's output is gated (`attn_output_gate`)
 MOE_SHARED_GATE = "shared_gate"  # inside `moe`, where the shared expert is gated (`shared_expert_gate`)
 
@@ -208,6 +231,7 @@ MOE_SCOPES = (MOE_ROUTER, MOE_DISPATCH, MOE_EXPERTS, MOE_SHARED, MOE_COMBINE)  #
 CCA_SCOPES = (CCA_LATENT, CCA_CONV, CCA_QK_MEAN, CCA_QK_NORM, CCA_VALUE_SHIFT, CCA_OUT)  # on the step only where a layer's mixer is `cca`
 ROUTER_MLP_SCOPES = (ROUTER_DOWN, ROUTER_EDA, ROUTER_MLP)  # on the step only where the router is an MLP over a carried state
 GDN_SCOPES = (GDN_IN_PROJ, GDN_CONV, GDN_GATES, GDN_QK_NORM, GDN_RULE, GDN_INTRA, GDN_STATE, GDN_GROUP, GDN_OUT_NORM, GDN_OUT)  # on the step only where a layer's mixer is `gdn`
+SSD_SCOPES = (SSD_IN_PROJ, SSD_CONV, SSD_SCAN, SSD_INTRA, SSD_STATE, SSD_GATE, SSD_OUT_PROJ)  # on the step only where a layer's mixer is `ssd`
 
 # what a path holds beside scopes: the jit wrapper, the plumbing of loops, calls and branches
 _PLUMBING = frozenset(("while", "body", "cond", "closed_call", "checkpoint"))

@@ -77,6 +77,10 @@ class GPT2MFUCalculator(MFUCalculatorIF):
     `n_head_q * head_dim`. The shared expert, its gate and the router are in `6N` whole; of the held experts a token passes
     `num_experts_per_tok * experts_held / columns`.
 
+    A Mamba-2 layer (`ssd_config`) is counted as it is held: its two projections and its taps are parameters a token multiplies
+    (in `6N`), and the chunked form's products a chunk (`C B^T` once, and a head's `(L o C B^T) X`, its own state `X^T B` and
+    `C H`) come to `ssd_scan_flops_per_token` forward, times 3 for a step.
+
     A looped model (`loop_config`) uses a parameter once for every walk, and `6N` would count it
     once: its required operations are `6 x a layer's kernels x L x T` + `6 x T x L x s x h` (the
     causal half of attention, a layer application) + `6 x T x E x V` (the head, once an exit) a
@@ -123,6 +127,9 @@ class GPT2MFUCalculator(MFUCalculatorIF):
             self.attention_width = spec.n_head_q * (mla.qk_head_dim + mla.v_head_dim)
         gdn = getattr(spec, "gdn", None)
         self.rule_flops_per_token = kinds.count("gdn") * gdn_rule_flops_per_token(gdn) if gdn is not None else 0.0
+        ssd = getattr(spec, "ssd", None)
+        if ssd is not None:
+            self.rule_flops_per_token += kinds.count("ssd") * ssd_scan_flops_per_token(ssd)
         self.looped_flops_per_token = None
         loop = getattr(spec, "loop", None)
         if loop is not None:
@@ -151,6 +158,14 @@ def gdn_rule_flops_per_token(gdn, chunk: int = 64) -> float:
     a_head = (2 * 2 * c * c * dk / share + (2 * int(math.log2(c)) - 1) * 2 * c ** 3 + 2 * c * c * (dk + dv)
               + 3 * 2 * c * dk * dv + 2 * c * c * dv)
     return gdn.value_heads * a_head / c
+
+
+def ssd_scan_flops_per_token(ssd) -> float:
+    """Forward operations a token of one layer's chunked Mamba-2 recurrence (`ops/ssd.py`), beside its projections and taps
+    (which are parameters): a chunk of `Q` positions takes `C B^T` once (`2 Q^2 N`), and a held head `(L o C B^T) X`
+    (`2 Q^2 P`), its own state `X^T B` and `C H` against the state that came in (`2 Q P N` each); divided by `Q`."""
+    q, p, n = ssd.chunk, ssd.head_dim, ssd.state
+    return (2 * q * q * n + ssd.heads_held * (2 * q * q * p + 2 * 2 * q * p * n)) / q
 
 
 def _count_params(model) -> Optional[int]:
